@@ -1,0 +1,586 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, one TPU chip, three phases through the entry points a user
+calls, at the full width of models the repo supports (weights random, made
+from a seed):
+
+  train/resnet50   static Program -> fluid.Executor(TPUPlace(0)), b128 224px AMP
+  train/bert-base  dygraph jit_train_step, 12x768, b38 s512, AMP-O2, dropout on
+  serve/decoder    export_decoder -> ServingEngine(place=TPUPlace(0)) at
+                   GPT-2-small width, mixed-length requests, paged decode
+
+Each phase checks its result by the repo's own means (losses finite and
+falling; served tokens against ``greedy_reference`` on the same chip) and
+proves from the lowered program's text that its Pallas kernel is in the
+program — a kernel that silently gave way to the jnp path fails the phase.
+Any exception in any phase ends the run non-zero.  The per-phase lines are
+smoke observations (compile seconds, steady step ms, peak bytes), not
+benchmark metrics.  The last line of stdout is the contract's JSON object.
+
+    python chip_smoke.py              # one chip, all three phases
+    python chip_smoke.py --chips 4    # four chips: DP-4 ResNet-50 against the
+                                      # one-chip trajectory, tp=4 decode
+                                      # against tp=1 — and nothing else
+
+With no TPU the script exits non-zero before any phase.  Rehearsal off the
+chip (tiny sizes, kernels interpreted) is asked for on its own command line:
+
+    JAX_PLATFORMS=cpu PT_PALLAS_INTERPRET=1 PT_FLASH_ATTENTION=1 \\
+    FLAGS_tpu_nhwc=1 FLAGS_tpu_fuse=1 \\
+    python chip_smoke.py --size tiny --rehearse-on-cpu
+"""
+from __future__ import annotations
+
+import argparse
+import gc
+import json
+import os
+import shutil
+import statistics
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+# Sizes are the script's, never the library's.  "full" is what the chip is
+# asked; "tiny" exists for the CPU rehearsal (interpret mode has no PRNG, so
+# tiny BERT keeps attention dropout off and forces the flash kernel at s=128).
+SIZES = {
+    "full": {
+        # lr 0.01 as __graft_entry__ trains it: on ONE repeated batch lr 0.1
+        # (the ImageNet schedule's value) diverges within three steps
+        "resnet": dict(depth=50, image=224, classes=1000, batch=128, steps=6,
+                       lr=0.01),
+        "bert": dict(cfg={}, seq=512, batch=38, steps=6),
+        "serve": dict(
+            cfg=dict(vocab_size=50257, hidden=768, num_heads=12,
+                     num_layers=12, max_seq_len=1024),
+            num_pages=2048, page_size=16, token_budget=1024, max_batch=8,
+            # two prefill buckets (32, 512) and one block-table width (32
+            # pages) keep the number of compiled shapes small
+            prompts=[300, 20, 280, 31, 17, 400, 25, 270], new_tokens=16),
+    },
+    "tiny": {
+        "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
+                       lr=0.01),
+        "bert": dict(
+            cfg=dict(vocab_size=128, hidden_size=32, num_hidden_layers=2,
+                     num_attention_heads=2, intermediate_size=64,
+                     max_position_embeddings=128,
+                     attention_probs_dropout_prob=0.0),
+            seq=128, batch=2, steps=5),
+        "serve": dict(
+            cfg=dict(vocab_size=128, hidden=32, num_heads=4, num_layers=2,
+                     max_seq_len=128),
+            num_pages=64, page_size=8, token_budget=128, max_batch=8,
+            prompts=[40, 5, 36, 9, 7, 50, 6, 34], new_tokens=6),
+    },
+}
+
+# A served token may differ from the reference's argmax only on a near-tie:
+# its reference logit must be within this much of the reference maximum
+# (logits of the seeded model have unit scale).
+LOGIT_TIE_TOL = 5e-2
+
+
+def say(**fields):
+    print(json.dumps(fields), flush=True)
+
+
+class Watch:
+    """Compilations and persistent-cache traffic, from JAX's own monitoring
+    events, and the lowered text of every program (jax_dump_ir_to)."""
+
+    def __init__(self, jax, dump_dir):
+        self.compiles = self.hits = self.misses = 0
+        self.compile_s = 0.0
+        self.dump_dir = dump_dir
+        jax.config.update("jax_dump_ir_to", dump_dir)
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.compile_s += duration
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def mark(self):
+        return (self.compiles, self.compile_s, self.hits, self.misses,
+                set(os.listdir(self.dump_dir)))
+
+    def since(self, mark):
+        """(counters, lowered programs dumped) since ``mark``."""
+        c, s, h, m, files = mark
+        return ({"compilations": self.compiles - c,
+                 "backend_compile_s": round(self.compile_s - s, 2),
+                 "cache_hits": self.hits - h,
+                 "cache_misses": self.misses - m},
+                sorted(set(os.listdir(self.dump_dir)) - files))
+
+
+class Ctx:
+    """What every phase needs: the place, the watch, the device report."""
+
+    def __init__(self, args):
+        import jax
+
+        import paddle_tpu as pt
+
+        self.jax, self.pt = jax, pt
+        self.rehearsal = args.rehearse_on_cpu
+        self.sizes = SIZES[args.size]
+        dev = jax.devices()[0]
+        if dev.platform != "tpu" and not self.rehearsal:
+            sys.exit(f"chip_smoke: needs a TPU, JAX found platform "
+                     f"{dev.platform!r} ({dev.device_kind}); nothing was run")
+        self.place = pt.CPUPlace() if self.rehearsal else pt.TPUPlace(0)
+        self.device = self.place.jax_device()
+        self.interpreted = os.environ.get("PT_PALLAS_INTERPRET") == "1"
+        self.dump_dir = tempfile.mkdtemp(prefix="chip_smoke_ir_")
+        self.watch = Watch(jax, self.dump_dir)
+        self.cache_dir = pt.COMPILE_CACHE_DIR
+
+    def cache_entries(self):
+        return len(os.listdir(self.cache_dir)) \
+            if os.path.isdir(self.cache_dir) else 0
+
+    def memory(self, device_id=0):
+        """The allocator's own counters; a backend that reports none is an
+        error on the chip (the rehearsal's CPU reports none)."""
+        if self.rehearsal:
+            return {"peak_bytes_in_use": "not measured (cpu rehearsal)"}
+        s = self.pt.memory_stats(device_id)
+        if s["source"] != "pjrt" or "peak_bytes_in_use" not in s:
+            raise RuntimeError(f"device {device_id} reports no allocator "
+                               f"statistics: {s}")
+        return {"peak_bytes_in_use": s["peak_bytes_in_use"],
+                "bytes_in_use": s["bytes_in_use"]}
+
+    def require_kernels(self, phase, modules, kernels):
+        """Most calls of each kernel in one lowered program among
+        ``modules``: the Mosaic custom call carrying the kernel's name —
+        or, when kernels are interpreted (CPU rehearsal), its name scope.
+        A kernel found in none fails the phase."""
+        needles = {k: f"/{k}/pallas_call" if self.interpreted
+                   else f'kernel_name = "{k}"' for k in kernels}
+        found = dict.fromkeys(kernels, 0)
+        for m in modules:
+            with open(os.path.join(self.dump_dir, m)) as f:
+                text = f.read()
+            if self.interpreted or "@tpu_custom_call" in text:
+                for k, needle in needles.items():
+                    found[k] = max(found[k], text.count(needle))
+        missing = [k for k, n in found.items() if n == 0]
+        if missing:
+            raise RuntimeError(
+                f"{phase}: kernel(s) {missing} are not in any program the "
+                f"phase compiled — the jnp path took their place")
+        return found
+
+    def close(self):
+        shutil.rmtree(self.dump_dir, ignore_errors=True)
+
+
+def train_phase(ctx, phase, mark, run_step, steps, kernels, report):
+    """Drive ``run_step`` (returns the loss as a float: a host read, so a
+    step's wall time is the device's) ``steps`` times; the first two are
+    warm-up (trace + compile; the dygraph step compiles twice, its
+    optimizer state being born in the first call).  Losses must be finite
+    and fall, the kernels must be in a lowered program, and nothing may
+    compile after warm-up."""
+    losses, ms, warm = [], [], None
+    for i in range(steps):
+        if i == 2:
+            warm = ctx.watch.mark()
+        t0 = time.perf_counter()
+        losses.append(run_step())
+        ms.append((time.perf_counter() - t0) * 1e3)
+    if not np.isfinite(losses).all():
+        raise RuntimeError(f"{phase}: non-finite loss {losses}")
+    if not losses[-1] < losses[0]:
+        raise RuntimeError(f"{phase}: loss did not fall {losses}")
+    seen, modules = ctx.watch.since(mark)
+    say(phase=phase, **report, losses=[round(v, 4) for v in losses],
+        first_step_s=round(ms[0] / 1e3, 2),
+        steady_step_ms=round(statistics.median(ms[2:]), 2), **seen,
+        compilations_after_warmup=ctx.watch.since(warm)[0]["compilations"],
+        kernel_calls=ctx.require_kernels(phase, modules, kernels),
+        **ctx.memory())
+
+
+# ---------------------------------------------------------------------------
+# train/resnet50 — and its data-parallel twin for --chips 4
+# ---------------------------------------------------------------------------
+def build_resnet(size):
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models.resnet import build_resnet as net
+
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 1
+    with fluid.program_guard(main, startup):
+        img = fluid.layers.data("img", [3, size["image"], size["image"]])
+        label = fluid.layers.data("label", [1], dtype="int64")
+        loss, _, _, _ = net(img, label, depth=size["depth"],
+                            class_num=size["classes"])
+        opt = fluid.contrib.mixed_precision.decorate(
+            fluid.optimizer.MomentumOptimizer(size["lr"], 0.9))
+        opt.minimize(loss)
+    rng = np.random.RandomState(0)
+    feed = {"img": rng.rand(size["batch"], 3, size["image"],
+                            size["image"]).astype(np.float32),
+            "label": rng.randint(0, size["classes"],
+                                 (size["batch"], 1)).astype(np.int32)}
+    return main, startup, loss, feed
+
+
+def phase_resnet(ctx):
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.framework.scope import Scope, scope_guard
+
+    size = ctx.sizes["resnet"]
+    main, startup, loss, feed = build_resnet(size)
+    exe = fluid.Executor(ctx.place)
+    mark = ctx.watch.mark()
+    with scope_guard(Scope()):
+        exe.run(startup)
+        # staged once, as bench.py does: the step is measured, not the feed
+        feed = {k: ctx.jax.device_put(v, ctx.device) for k, v in feed.items()}
+        train_phase(
+            ctx, "train/resnet50", mark,
+            lambda: float(exe.run(main, feed=feed,
+                                  fetch_list=[loss.name])[0]),
+            size["steps"], ["bn_act_fwd", "bn_act_bwd"], size)
+
+
+def phase_dp4(ctx):
+    """DP-4 ResNet-50 (global batch as above) against the one-chip loss
+    trajectory, both in this process, from one set of initial weights."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.framework.scope import Scope, scope_guard
+
+    jax = ctx.jax
+    phase, size = "dp4/resnet50", ctx.sizes["resnet"]
+    steps = max(3, size["steps"] - 2)
+    main, startup, loss, feed = build_resnet(size)
+    exe = fluid.Executor(ctx.place)
+    one = Scope()
+    with scope_guard(one):
+        exe.run(startup)
+        init = {k: np.asarray(v) for k, v in one.items()
+                if not k.startswith("@")}
+        single = [float(exe.run(main, feed=feed, fetch_list=[loss.name])[0])
+                  for _ in range(steps)]
+    del one
+    exe.close()
+    gc.collect()
+
+    exe = fluid.Executor(ctx.place)
+    mark = ctx.watch.mark()
+    prog = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name)
+    four = Scope()
+    for k, v in init.items():
+        four.set(k, v)
+    ms = []
+    with scope_guard(four):
+        dp = []
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            out = exe.run(prog, feed=feed, fetch_list=[loss.name])[0]
+            dp.append(float(np.mean(out)))
+            ms.append((time.perf_counter() - t0) * 1e3)
+        # state really is on four distinct devices, and the step's
+        # compiled text holds the gradient all-reduce
+        spread = device_spread(ctx, [v for _, v in four.items()
+                                     if isinstance(v, jax.Array)])
+        jitted, state_spec, feed_spec = prog.__dict__["_last_exec"]
+        hlo = jitted.lower(state_spec, feed_spec).compile().as_text()
+    if "all-reduce" not in hlo:
+        raise RuntimeError(f"{phase}: no all-reduce in the compiled step")
+    # Step 1 checks the sharded forward (global-batch BN statistics under
+    # GSPMD) in bf16; later steps get the envelope dryrun_multichip uses:
+    # an untrained deep BN+ReLU net amplifies reduction-order noise.
+    if not np.isfinite(dp).all():
+        raise RuntimeError(f"{phase}: non-finite loss {dp}")
+    if abs(single[0] - dp[0]) > 1e-2 * abs(single[0]):
+        raise RuntimeError(f"{phase}: step-1 loss diverged, one chip "
+                           f"{single} four {dp}")
+    for a, b in zip(single[1:], dp[1:]):
+        if abs(a - b) > max(1e-3, 0.2 * abs(a)):
+            raise RuntimeError(f"{phase}: trajectory diverged, one chip "
+                               f"{single} four {dp}")
+    seen, _ = ctx.watch.since(mark)
+    say(phase=phase, **{**size, "steps": steps},
+        one_chip_losses=[round(v, 4) for v in single],
+        dp4_losses=[round(v, 4) for v in dp],
+        step1_absdiff=abs(single[0] - dp[0]),
+        first_step_s=round(ms[0] / 1e3, 2), steady_step_ms=round(ms[-1], 2),
+        all_reduces=hlo.count(" all-reduce("),
+        custom_calls=hlo.count("tpu_custom_call"), **seen, **spread)
+
+
+def device_spread(ctx, arrays):
+    """Every array lives on all of the host's devices (sharded or
+    replicated there, never parked on one), and the allocator's
+    bytes_in_use is roughly level across them."""
+    jax = ctx.jax
+    devs = jax.devices()
+    for a in arrays:
+        on = {s.device for s in a.addressable_shards}
+        if on != set(devs):
+            raise RuntimeError(f"array {a.shape} lives on {len(on)} of "
+                               f"{len(devs)} devices")
+    if ctx.rehearsal:
+        return {"arrays_on_all_devices": len(arrays)}
+    used = [ctx.memory(i)["bytes_in_use"] for i in range(len(devs))]
+    # chip 0 also hosted the one-chip leg and may keep a little more; the
+    # others must agree with each other and none may sit nearly empty
+    rest = used[1:]
+    if max(rest) > 1.1 * min(rest) or min(used) < 0.5 * max(rest):
+        raise RuntimeError(f"bytes_in_use is lopsided across devices: {used}")
+    return {"arrays_on_all_devices": len(arrays), "bytes_in_use": used}
+
+
+# ---------------------------------------------------------------------------
+# train/bert-base
+# ---------------------------------------------------------------------------
+def phase_bert(ctx):
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.dygraph import guard, jit_train_step
+    from paddle_tpu.models.bert import BertConfig, BertForPretraining
+
+    size = ctx.sizes["bert"]
+    cfg = BertConfig(**size["cfg"])
+    rng = np.random.RandomState(0)
+    shape = (size["batch"], size["seq"])
+    ids = ctx.jax.device_put(
+        rng.randint(0, cfg.vocab_size, shape).astype(np.int32), ctx.device)
+    labels = ctx.jax.device_put(
+        rng.randint(0, cfg.vocab_size, shape).astype(np.int32), ctx.device)
+    mark = ctx.watch.mark()
+    with guard(ctx.place):
+        model = BertForPretraining(cfg)
+        opt = fluid.optimizer.AdamOptimizer(
+            1e-4, parameter_list=model.parameters())
+        train = jit_train_step(model, opt, lambda m, i, l: m(i, l),
+                               amp=True, amp_level="O2")
+        # ids/labels are committed to the place's device, so the jitted
+        # step runs there.  s=512 is one 512-block per axis: the
+        # single-block forward and the fused (saved-lse) backward
+        train_phase(
+            ctx, "train/bert-base", mark,
+            lambda: float(train(ids, labels).numpy()), size["steps"],
+            ["flash_fwd_single", "flash_bwd_fused"],
+            dict(layers=cfg.num_hidden_layers, hidden=cfg.hidden_size,
+                 heads=cfg.num_attention_heads, seq=size["seq"],
+                 batch=size["batch"],
+                 attn_dropout=cfg.attention_probs_dropout_prob))
+
+
+# ---------------------------------------------------------------------------
+# serve/decoder — and tp=4 against tp=1 for --chips 4
+# ---------------------------------------------------------------------------
+def serve(ctx, model_dir, size, tp):
+    """Submit the requests and drive step() until the engine is idle, as
+    examples/serve_decoder_lm.py does.  Returns (engine, requests, steady
+    decode summary: the engine steps after the first that compiled
+    nothing)."""
+    from paddle_tpu.inference.serving import Request, ServingEngine
+
+    eng = ServingEngine(model_dir=model_dir, place=ctx.place, tp=tp,
+                        num_pages=size["num_pages"],
+                        page_size=size["page_size"],
+                        max_batch=size["max_batch"],
+                        token_budget=size["token_budget"])
+    rng = np.random.RandomState(0)
+    reqs = [Request(i, rng.randint(0, eng.cfg.vocab_size, size=n).tolist(),
+                    max_new_tokens=size["new_tokens"])
+            for i, n in enumerate(size["prompts"])]
+    for r in reqs:
+        eng.submit(r)
+    steps = []
+    while eng.has_work():
+        c0, t0 = ctx.watch.compiles, time.perf_counter()
+        eng.step()          # ends in a host read of the step's tokens
+        steps.append(((time.perf_counter() - t0) * 1e3,
+                      ctx.watch.compiles - c0))
+    for r in reqs:
+        if len(r.out_tokens) != size["new_tokens"]:
+            raise RuntimeError(f"request {r.req_id} finished with "
+                               f"{len(r.out_tokens)} tokens, asked "
+                               f"{size['new_tokens']}")
+    quiet = [ms for ms, compiled in steps[1:] if compiled == 0]
+    return eng, reqs, {
+        "engine_steps": len(steps), "steps_without_compilation": len(quiet),
+        "steady_decode_step_ms":
+            round(statistics.median(quiet), 2) if quiet else None}
+
+
+def reference_gap(core, prompt, out):
+    """Teacher-forced agreement of ``out`` with the reference program on
+    ``core``: per position, how far the reference logit of the served token
+    sits below the reference maximum (0.0 = the reference's own argmax)."""
+    gaps, seq = [], list(prompt)
+    for tok in out:
+        logits = core.reference_logits(seq)
+        if not np.isfinite(logits).all():
+            raise RuntimeError("non-finite reference logits")
+        gaps.append(float(logits.max() - logits[tok]))
+        seq.append(tok)
+    return gaps
+
+
+def compare_tokens(phase, what, core, prompt, got, want):
+    """Token identity, or — where precision flips a near-tie — every served
+    token within LOGIT_TIE_TOL of the reference maximum.  Never skipped."""
+    if got == want:
+        return {"compared": what, "agreement": "token-identical"}
+    gaps = reference_gap(core, prompt, got)
+    if max(gaps) > LOGIT_TIE_TOL:
+        raise RuntimeError(
+            f"{phase}: {what}: served {got} vs {want}; reference logit "
+            f"gaps {gaps} exceed the near-tie tolerance {LOGIT_TIE_TOL}")
+    return {"compared": what, "agreement": "logits-within-tolerance",
+            "tolerance": LOGIT_TIE_TOL, "worst_gap": max(gaps),
+            "positions_not_argmax": sum(g > 0 for g in gaps)}
+
+
+def export(size):
+    from paddle_tpu.inference.serving import DecoderConfig, export_decoder
+
+    model_dir = tempfile.mkdtemp(prefix="chip_smoke_decoder_")
+    export_decoder(model_dir, DecoderConfig(**size["cfg"]), seed=0)
+    return model_dir
+
+
+def phase_serve(ctx):
+    phase, size = "serve/decoder", ctx.sizes["serve"]
+    model_dir = export(size)
+    try:
+        mark = ctx.watch.mark()
+        eng, reqs, steady = serve(ctx, model_dir, size, tp=1)
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(phase, modules, ["paged_decode"])
+        oracle = eng.core.greedy_reference(reqs[0].prompt,
+                                           size["new_tokens"])
+        verdict = compare_tokens(phase, "request 0 vs greedy_reference",
+                                 eng.core, reqs[0].prompt,
+                                 reqs[0].out_tokens, oracle)
+    finally:
+        shutil.rmtree(model_dir, ignore_errors=True)
+    say(phase=phase, **size["cfg"], num_pages=size["num_pages"],
+        page_size=size["page_size"], prompts=size["prompts"],
+        new_tokens=size["new_tokens"], scheduler=eng.stats,
+        kv_peak_pages=eng.kv.stats()["peak_pages"], **steady, **seen,
+        kernel_calls=kernels, **verdict, **ctx.memory())
+
+
+def phase_tp4(ctx):
+    """tp=4 decode against tp=1 tokens for the same requests."""
+    phase, size = "tp4/decoder", ctx.sizes["serve"]
+    model_dir = export(size)
+    try:
+        one, reqs1, _ = serve(ctx, model_dir, size, tp=1)
+        mark = ctx.watch.mark()
+        four, reqs4, steady = serve(ctx, model_dir, size, tp=4)
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(phase, modules, ["paged_decode"])
+        verdicts = [compare_tokens(phase, f"request {a.req_id} tp=4 vs tp=1",
+                                   one.core, a.prompt, b.out_tokens,
+                                   a.out_tokens)
+                    for a, b in zip(reqs1, reqs4)]
+    finally:
+        shutil.rmtree(model_dir, ignore_errors=True)
+    # weights and KV pages really are spread: every sharded var holds a
+    # quarter per device, on four distinct devices
+    core = four.core
+    sharded = [n for n in core.scope.local_var_names()
+               if core._tp_spec(n) is not None]
+    if not any(n.startswith("kv_") for n in sharded):
+        raise RuntimeError(f"{phase}: no KV pool is sharded")
+    for n in sharded:
+        a = core.scope.get(n)
+        if {s.data.nbytes * 4 for s in a.addressable_shards} != {a.nbytes}:
+            raise RuntimeError(f"{phase}: {n} is not split four ways")
+    del one
+    gc.collect()
+    spread = device_spread(ctx, [core.scope.get(n) for n in sharded])
+    worst = max((v.get("worst_gap", 0.0) for v in verdicts), default=0.0)
+    say(phase=phase, **size["cfg"], requests=len(reqs4),
+        token_identical=sum(v["agreement"] == "token-identical"
+                            for v in verdicts),
+        within_tolerance=sum(v["agreement"] != "token-identical"
+                             for v in verdicts),
+        tolerance=LOGIT_TIE_TOL, worst_gap=worst,
+        sharded_vars=len(sharded), **steady, **seen, kernel_calls=kernels,
+        **spread)
+
+
+# ---------------------------------------------------------------------------
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: only the DP-4 and tp=4 paths and what they "
+                         "are compared with")
+    ap.add_argument("--size", choices=sorted(SIZES), default="full")
+    ap.add_argument("--rehearse-on-cpu", action="store_true",
+                    help="skip the TPU assertion (and the device's memory "
+                         "counters): a rehearsal, never a result")
+    args = ap.parse_args(argv)
+
+    ctx = Ctx(args)
+    jax = ctx.jax
+    try:
+        if jax.device_count() < args.chips:
+            sys.exit(f"chip_smoke: --chips {args.chips} but JAX has "
+                     f"{jax.device_count()} device(s)")
+        import jaxlib
+
+        t0 = time.perf_counter()
+        entries0 = ctx.cache_entries()
+        say(phase="start", jax=jax.__version__, jaxlib=jaxlib.__version__,
+            libtpu=_libtpu_version(), platform=ctx.device.platform,
+            device_kind=ctx.device.device_kind, devices=jax.device_count(),
+            size=args.size, rehearsal=ctx.rehearsal,
+            compile_cache_dir=ctx.cache_dir, cache_entries_before=entries0,
+            note="smoke observations, not benchmark metrics")
+        phases = (phase_dp4, phase_tp4) if args.chips == 4 else \
+            (phase_resnet, phase_bert, phase_serve)
+        for phase in phases:
+            phase(ctx)
+            gc.collect()
+        say(phase="end", wall_s=round(time.perf_counter() - t0, 1),
+            compilations=ctx.watch.compiles,
+            backend_compile_s=round(ctx.watch.compile_s, 1),
+            cache_hits=ctx.watch.hits, cache_misses=ctx.watch.misses,
+            compile_cache_dir=ctx.cache_dir,
+            cache_entries_before=entries0,
+            cache_entries_after=ctx.cache_entries())
+    finally:
+        ctx.close()
+    if ctx.rehearsal:
+        # not the contract's line: a rehearsal is never a result
+        say(rehearsal="passed", platform=ctx.device.platform)
+        return
+    say(ok=True, device={"platform": ctx.device.platform,
+                         "kind": ctx.device.device_kind,
+                         "count": len(jax.devices())})
+
+
+def _libtpu_version():
+    try:
+        import libtpu
+    except ImportError:
+        return None
+    return getattr(libtpu, "__version__", None)
+
+
+if __name__ == "__main__":
+    main()

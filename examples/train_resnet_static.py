@@ -5,8 +5,9 @@ Run:  python examples/train_resnet_static.py [--depth 50] [--batch 128]
 
 The static Program compiles to ONE XLA executable per feed signature
 (whole-program jit with buffer donation); AMP runs matmuls/convs in
-bf16 with f32 master weights. `--tiny` shrinks everything for a smoke
-run on CPU.
+bf16 with f32 master weights. The run names `TPUPlace(0)` and fails on a
+host with no chip; `--tiny` shrinks everything for a smoke run on the
+CPU.
 """
 from __future__ import annotations
 
@@ -52,7 +53,7 @@ def main():
             opt = fluid.contrib.mixed_precision.decorate(opt)
         opt.minimize(loss)
 
-    place = pt.TPUPlace(0) if pt.is_compiled_with_tpu() else pt.CPUPlace()
+    place = pt.CPUPlace() if args.tiny else pt.TPUPlace(0)
     exe = fluid.Executor(place)
     exe.run(startup)
     rng = np.random.RandomState(0)

@@ -76,7 +76,7 @@ def main():
             fleet.distributed_optimizer(
                 fluid.optimizer.SGDOptimizer(0.05)).minimize(loss)
         exe = fluid.Executor(
-            pt.TPUPlace(0) if pt.is_compiled_with_tpu() else pt.CPUPlace())
+            pt.CPUPlace() if args.tiny else pt.TPUPlace(0))
         with scope_guard(Scope()):
             exe.run(startup)
             fleet.init_worker()

@@ -34,6 +34,15 @@ def launch():
     args = _parse_args()
     nproc = args.nproc_per_node
     total = nproc * args.num_hosts
+    if nproc > 1:
+        from ..framework.place import host_tpu_chips
+
+        if host_tpu_chips():
+            # a chip belongs to ONE process: the first child would take
+            # every local chip and the rest fail or hang at start-up
+            sys.exit("paddle_tpu.distributed.launch: --nproc_per_node="
+                     f"{nproc} on a TPU host — use 1; the in-process "
+                     "mesh covers all local chips")
 
     if total <= 1:
         env = dict(os.environ)

@@ -98,11 +98,7 @@ class core:
     def get_tpu_device_count():
         import jax
 
-        try:
-            devs = [d for d in jax.devices() if d.platform != "cpu"]
-            return len(devs)
-        except Exception:
-            return 0
+        return jax.device_count() if is_compiled_with_tpu() else 0
 
     get_cuda_device_count = get_tpu_device_count
 

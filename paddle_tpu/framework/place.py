@@ -9,8 +9,6 @@ swap, per the north star).
 """
 from __future__ import annotations
 
-import functools
-
 
 class Place:
     device_id: int = 0
@@ -42,7 +40,9 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The accelerator place — `fluid.TPUPlace()` per the north star."""
+    """The accelerator place — `fluid.TPUPlace()` per the north star.
+    It resolves to TPU ``device_id`` or raises: never to another chip,
+    never to the CPU."""
 
     def __init__(self, device_id: int = 0):
         self.device_id = int(device_id)
@@ -50,17 +50,21 @@ class TPUPlace(Place):
     def jax_device(self):
         import jax
 
-        devs = _accelerator_devices()
-        if not devs:
+        if not is_compiled_with_tpu():
             raise RuntimeError(
-                "TPUPlace requested but no accelerator device is available"
-            )
-        return devs[self.device_id % len(devs)]
+                "TPUPlace requested but JAX's default backend is "
+                f"{jax.default_backend()!r}, not a TPU")
+        devs = jax.devices()
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: device id out of range, this host has "
+                f"{len(devs)} TPU device(s)")
+        return devs[self.device_id]
 
 
 class CUDAPlace(TPUPlace):
-    """Compatibility alias: reference scripts using CUDAPlace(0) run on the
-    accelerator (or CPU if none) without modification."""
+    """Compatibility alias: reference scripts using CUDAPlace(0) run on
+    the TPU without modification."""
 
 
 class TPUPinnedPlace(CPUPlace):
@@ -71,28 +75,34 @@ class TPUPinnedPlace(CPUPlace):
 CUDAPinnedPlace = TPUPinnedPlace
 
 
-@functools.lru_cache(maxsize=1)
-def _accelerator_devices():
+def is_compiled_with_tpu() -> bool:
+    """THE accelerator predicate — places, the flags' "auto" values and
+    the Pallas kernels' engage checks all ask this one question: is
+    JAX's default backend a TPU?"""
     import jax
 
-    devs = jax.devices()
-    if devs and devs[0].platform != "cpu":
-        return tuple(devs)
-    return ()
-
-
-def is_compiled_with_tpu() -> bool:
-    return bool(_accelerator_devices())
+    return jax.default_backend() == "tpu"
 
 
 # Reference API-compat name.
-def is_compiled_with_cuda() -> bool:
-    return bool(_accelerator_devices())
+is_compiled_with_cuda = is_compiled_with_tpu
+
+
+def host_tpu_chips() -> int:
+    """TPU chips on this host's PCI bus, by JAX's own sysfs scan.  It
+    initialises no backend, so a launcher (or a test that starts a
+    native PJRT client) can ask without taking the chip — one process
+    holds a chip, and a parent that touched it starves its children."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
 
 
 def _get_paddle_place(place):
     """Normalize str/None/Place to a Place (reference: framework.py helpers)."""
     if place is None:
+        # no place named: the first device of JAX's default backend.
+        # Entry points that report a device number name TPUPlace(0).
         return TPUPlace(0) if is_compiled_with_tpu() else CPUPlace()
     if isinstance(place, Place):
         return place

@@ -55,7 +55,7 @@ import numpy as np
 
 from ..framework.core import Program
 from ..framework.dtype import VarType, convert_dtype
-from ..framework.place import CPUPlace, TPUPlace
+from ..framework.place import CPUPlace
 from ..framework.scope import Scope, scope_guard
 from ..executor import Executor
 from ..profiler import RecordEvent, instant_event, is_profiler_enabled
@@ -792,7 +792,7 @@ def _trace_submit(req: Request):
 
 def _trace_reject(req: Request, reason: str, reason_code: str = "unservable"):
     """A request rejected at submit still gets a (one-span) trace: the
-    finish/reject leg of the span taxonomy.  ``reason_code`` is the
+    finish/reject leg of the span tree.  ``reason_code`` is the
     machine-readable reject reason (pool / budget / max_seq_len) —
     the span-side mirror of ``serving_rejects_total{reason=}``."""
     if not tracing.enabled() or not tracing.sampled(req.req_id):
@@ -810,7 +810,7 @@ def _trace_reject(req: Request, reason: str, reason_code: str = "unservable"):
 def _trace_shed(req: Request, now: float):
     """A shed request closes its open wait span (queue_wait, or the
     preempted span of an evicted run) and its root with
-    ``status="shed"`` — the third terminal leg of the span taxonomy,
+    ``status="shed"`` — the third terminal leg of the span tree,
     distinct from finish and reject.  The SLO tracker is deliberately
     NOT fed: a shed request is excluded from the goodput denominators
     (the policy refused the work; nothing was served late)."""
@@ -1060,13 +1060,9 @@ class _EngineCore:
         if kv_dtype not in ("float32", "bfloat16", "int8"):
             raise ValueError(f"bad kv_cache_dtype {kv_dtype!r}")
         self.kv_dtype = kv_dtype
-        if place is None:
-            import paddle_tpu as pt
-
-            place = TPUPlace(0) if pt.is_compiled_with_tpu() else CPUPlace()
-        self.place = place
-        self.scope = Scope()
         self.exe = Executor(place)
+        self.place = place = self.exe.place
+        self.scope = Scope()
         self.prefill_bucket_min = prefill_bucket_min
         if kv_budget_mb and kv_budget_mb > 0:
             # pool sizing from a FIXED byte budget: page count is what
@@ -1524,22 +1520,31 @@ class _EngineCore:
         return [[int(flat[i * S + j]) for j in range(len(d) + 1)]
                 for i, (_st, d) in enumerate(items)]
 
-    def reference_next_token(self, seq: Sequence[int]) -> int:
-        """One full-recompute next-token step of the reference program
-        (the one-at-a-time oracle)."""
+    def _reference_run(self, seq: Sequence[int], fetch_list):
+        """One full-recompute step of the reference program over
+        ``seq`` (the one-at-a-time oracle)."""
         L = len(seq)
         S = _pow2_bucket(L, self.prefill_bucket_min, None)
         toks = np.zeros((1, S), np.int32)
         toks[0, :L] = seq
         pos = np.minimum(np.arange(S, dtype=np.int32),
                          self.cfg.max_seq_len - 1)[None]
-        out = self.exe.run(
+        return self.exe.run(
             self.ref_prog,
             feed={"tokens": toks, "positions": pos,
                   "attn_mask": _causal_mask(S),
                   "last_index": np.array([L - 1], np.int32)},
-            fetch_list=self.ref_fetch, scope=self.scope)
-        return int(out[0][0])
+            fetch_list=fetch_list, scope=self.scope)
+
+    def reference_next_token(self, seq: Sequence[int]) -> int:
+        return int(self._reference_run(seq, self.ref_fetch)[0][0])
+
+    def reference_logits(self, seq: Sequence[int]) -> np.ndarray:
+        """The reference program's next-token logits after ``seq`` —
+        what parity checks compare where an argmax could flip on a
+        near-tie."""
+        out = self._reference_run(seq, [self.ref_prog._srv_logits])
+        return np.asarray(out[0]).reshape(-1)
 
     def greedy_reference(self, prompt: Sequence[int],
                          max_new_tokens: int) -> List[int]:

@@ -44,13 +44,6 @@ def _in_shard_map(axis):
         return False
 
 
-def _axis_size(axis):
-    """Bound-axis size; jax<=0.4.x has no lax.axis_size."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def _allreduce(reduce_fn):
     def lower(ctx):
         x = ctx.in_("X")
@@ -71,7 +64,7 @@ def _c_allreduce_sum(ctx):
         if ctx.attr("use_mean", False):
             # mean without knowing nranks at graph-build time (the DGC
             # optimizer's dense path)
-            x = x / _axis_size(axis)
+            x = x / lax.axis_size(axis)
     ctx.set_out("Out", x)
 op("c_allreduce_max", no_grad=True)(_allreduce(lambda x, a: lax.pmax(x, a)))
 op("c_allreduce_min", no_grad=True)(_allreduce(lambda x, a: lax.pmin(x, a)))
@@ -83,14 +76,13 @@ op("allreduce", no_grad=True)(_allreduce(lambda x, a: lax.psum(x, a)))
 
 def _static_axis_size(axis):
     """Axis size as a python int (needed for reshape chunk counts): the
-    registered mesh knows it at trace time; psum(1) only yields a traced
-    value."""
+    registered mesh knows it at trace time."""
     from ..parallel.mesh import current_mesh
 
     mesh = current_mesh()
     if mesh is not None and axis in mesh.shape:
         return int(mesh.shape[axis])
-    return int(_axis_size(axis))
+    return int(lax.axis_size(axis))
 
 
 def _bf16_wire_psum(flat, axis):
@@ -234,7 +226,7 @@ def _c_split(ctx):
         from ..parallel.mesh import current_mesh
 
         idx = lax.axis_index(axis)
-        nranks = _axis_size(axis)
+        nranks = lax.axis_size(axis)
         d = jnp.shape(x)[-1] // nranks
         x = lax.dynamic_slice_in_dim(x, idx * d, d, axis=-1)
     ctx.set_out("Out", x)
@@ -250,7 +242,7 @@ def _alltoall(ctx):
     x = ctx.in_("X")
     axis = _axis(ctx)
     if _in_shard_map(axis):
-        n = _axis_size(axis)
+        n = lax.axis_size(axis)
         xs = jnp.reshape(x, (n, jnp.shape(x)[0] // n) + jnp.shape(x)[1:])
         xs = lax.all_to_all(xs, axis, split_axis=0, concat_axis=0, tiled=False)
         x = jnp.reshape(xs, (-1,) + jnp.shape(x)[1:])

@@ -164,21 +164,6 @@ def _static_trip_count(ctx, cb, bb):
     return max(0, -(-(lim - i0) // st))
 
 
-def _guard_body_root(outs):
-    """XLA:CPU-only workaround: a while body like `i = cond(p, a, b)`
-    leaves the body computation rooted at a kConditional after tuple
-    simplification, which CHECK-fails jaxlib 0.4.x's
-    while_loop_constant_sinking pass (while_body_root->opcode() ==
-    kTuple) and SIGABRTs the process.  An optimization_barrier on the
-    carry keeps the root a tuple.  TPU/GPU are unaffected, and the
-    barrier would inhibit constant sinking there — so gate on backend."""
-    import jax
-
-    if jax.default_backend() == "cpu":
-        return lax.optimization_barrier(outs)
-    return outs
-
-
 def _host_while(cb, bb, base_env, carry_names, cond_out, body_out_names,
                 init, on_step=None):
     """The ONE host while-loop protocol (forward host path and the grad
@@ -304,7 +289,7 @@ def _while_loop(ctx):
         local = dict(base_env)
         local.update(zip(carry_names, carry))
         _run_block(bb, local)
-        return _guard_body_root(tuple(local[n] for n in body_out_names))
+        return tuple(local[n] for n in body_out_names)
 
     outs = lax.while_loop(cond_fun, body_fun, init)
     ctx.set_out("Out", list(outs))
@@ -541,8 +526,7 @@ def _while(ctx):
         local[cond_name] = carry[0]
         local.update(zip(carry_names, carry[1:]))
         _run_block(bb, local)
-        return _guard_body_root(
-            (local[cond_name],) + tuple(local[n] for n in carry_names))
+        return (local[cond_name],) + tuple(local[n] for n in carry_names)
 
     outs = lax.while_loop(cond_fun, body_fun, init)
     # carried vars keep their own names (reference While mutates in place)

@@ -33,7 +33,7 @@ import numpy as np
 from jax import lax
 
 from .registry import op
-from .collective_ops import _axis, _axis_size, _in_shard_map
+from .collective_ops import _axis, _in_shard_map
 
 
 def _effective_k(step, numel, sparsity, rampup_begin, rampup_step, k_max):
@@ -118,7 +118,7 @@ def _dgc(ctx):
         pre = step < jnp.int32(rampup_begin)
         if _in_shard_map(axis):
             dense = lax.psum(jnp.where(pre, g, jnp.zeros_like(g)), axis)
-            dense = dense / _axis_size(axis)
+            dense = dense / lax.axis_size(axis)
         else:
             dense = g
         u_out = jnp.where(pre, u_prev, u_out)

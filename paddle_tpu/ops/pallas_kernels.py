@@ -33,11 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pragma: no cover - import guard for non-TPU-capable builds
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from ..framework.place import is_compiled_with_tpu
 
 LANES = 128
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -49,11 +47,7 @@ def _interpret() -> bool:
 
 
 def _use_pallas() -> bool:
-    if pltpu is None:
-        return False
-    if _interpret():
-        return True
-    return jax.default_backend() == "tpu"
+    return _interpret() or is_compiled_with_tpu()
 
 
 def _pick_block(seq: int, candidates=(512, 256, 128)) -> int | None:
@@ -284,6 +278,7 @@ def _flash_fwd(q, k, v, bias, scale, causal, block_q, block_k,
                                   causal=causal,
                                   dropout_rate=dropout_rate),
                 3, bias is not None, dropout_rate > 0.0),
+            name="flash_fwd_single",
             grid=(b, h),
             in_specs=in_specs,
             out_specs=[
@@ -319,6 +314,7 @@ def _flash_fwd(q, k, v, bias, scale, causal, block_q, block_k,
         3, bias is not None, dropout_rate > 0.0)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -504,6 +500,7 @@ def _flash_bwd_fused(q, k, v, bias, lse, do, delta, scale, causal,
             functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                               dropout_rate=dropout_rate),
             6, bias is not None, has_drop),
+        name="flash_bwd_fused",
         grid=(b, h),
         in_specs=in_specs,
         out_specs=[
@@ -565,6 +562,7 @@ def _flash_bwd(q, k, v, bias, o, lse, do, scale, causal, block_q, block_k,
                               block_q=block_q, block_k=block_k, n_kv=nk,
                               dropout_rate=dropout_rate),
             5, bias is not None, has_drop),
+        name="flash_bwd_dq",
         grid=(b, h, nq, nk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, block_q, d), _q_idx),
@@ -602,6 +600,7 @@ def _flash_bwd(q, k, v, bias, o, lse, do, scale, causal, block_q, block_k,
                               block_q=block_q, block_k=block_k, n_q=nq,
                               dropout_rate=dropout_rate),
             6, bias is not None, has_drop),
+        name="flash_bwd_dkv",
         grid=(b, h, nk, nq),
         in_specs=in_specs,
         out_specs=[
@@ -877,7 +876,7 @@ def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
 
     @pl.when(start < ctx)
     def _page():
-        q = q_ref[0]                                   # (1, d)
+        q = q_ref[0, 0]                                # (1, d)
         k = k_ref[0, 0]                                # (page_size, d)
         v = v_ref[0, 0]
         if quant:
@@ -905,7 +904,7 @@ def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
     def _done():
         l_fin = l_scr[...]
         l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
 
 
 def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
@@ -917,7 +916,7 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
     quant = k_scale is not None
 
     def _q_idx(b, h, i, bt, cl, *_):
-        return (b, h, 0)
+        return (b, h, 0, 0)
 
     def _kv_idx(b, h, i, bt, cl, *_):
         # the page to stream is data-dependent: the block table is a
@@ -929,12 +928,15 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
         # (kv_heads, num_pages) f32 tables indexed per (head, page)
         num_scalar_prefetch=4 if quant else 2,
         grid=(n_seqs, n_heads, n_pages),
+        # q/out ride as (seqs, heads, 1, d): Mosaic wants a block's last
+        # two dims (8, 128)-aligned or equal to the array's, and a
+        # (1, 1, d) block of a (seqs, heads, d) array is neither
         in_specs=[
-            pl.BlockSpec((1, 1, d), _q_idx),
+            pl.BlockSpec((1, 1, 1, d), _q_idx),
             pl.BlockSpec((1, 1, page_size, d), _kv_idx),
             pl.BlockSpec((1, 1, page_size, d), _kv_idx),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), _q_idx),
+        out_specs=pl.BlockSpec((1, 1, 1, d), _q_idx),
         scratch_shapes=[
             pltpu.VMEM((1, LANES), jnp.float32),
             pltpu.VMEM((1, LANES), jnp.float32),
@@ -945,16 +947,20 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
         functools.partial(_paged_decode_kernel, scale=scale,
                           page_size=page_size, n_pages=n_pages,
                           group=group, quant=quant),
+        name="paged_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_seqs, n_heads, 1, d), q.dtype),
         interpret=_interpret(),
     )
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
+    q4 = q[:, :, None, :]
     if quant:
-        return call(bt, cl, k_scale.astype(jnp.float32),
-                    v_scale.astype(jnp.float32), q, k_pages, v_pages)
-    return call(bt, cl, q, k_pages, v_pages)
+        out = call(bt, cl, k_scale.astype(jnp.float32),
+                   v_scale.astype(jnp.float32), q4, k_pages, v_pages)
+    else:
+        out = call(bt, cl, q4, k_pages, v_pages)
+    return out[:, :, 0, :]
 
 
 # ==========================================================================
@@ -1007,10 +1013,37 @@ def _epilogue_engages() -> bool:
     return _use_pallas() or force == "1"
 
 
-def apply_act(y, act: str):
+# erf as the f32 rational x*P(x^2)/Q(x^2) on [-4, 4] (the classic
+# single-precision form XLA's own erf expands to; max abs error 4.5e-7
+# against math.erf): Mosaic lowers neither erf nor erfc.
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08,
+          -2.10102402082508e-06, -5.69250639462346e-05,
+          -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def _erf_f32(x):
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.full_like(x, _ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    q = jnp.full_like(x, _ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return x * p / q
+
+
+def apply_act(y, act: str, in_kernel: bool = False):
     """The in-kernel (and fallback) activation menu.  ``relu`` uses the
     exact ``jnp.maximum(y, 0)`` form of the fused BN ops so kernel and
-    fallback stay term-for-term identical."""
+    fallback stay term-for-term identical.  ``gelu`` is the exact (erf)
+    form: the fallback keeps ``jax.nn.gelu`` — bitwise the unfused op —
+    and the kernel body (``in_kernel``, f32 accumulator) spells erf out
+    in primitives Mosaic has."""
     if not act:
         return y
     if act == "relu":
@@ -1020,6 +1053,8 @@ def apply_act(y, act: str):
     if act == "tanh":
         return jnp.tanh(y)
     if act == "gelu":
+        if in_kernel:
+            return 0.5 * y * (1.0 + _erf_f32(y * (2.0 ** -0.5)))
         return jax.nn.gelu(y, approximate=False)
     raise NotImplementedError(f"fused epilogue act {act!r}")
 
@@ -1031,7 +1066,9 @@ def _act_mask_grad(y, dy, act: str):
     if not act:
         return dy
     if act == "relu":
-        return jnp.where(y > jnp.zeros((), y.dtype), dy,
+        # compared in f32 (exact for bf16 y): v5e's vector unit has no
+        # bf16 compare and Mosaic refuses one
+        return jnp.where(y.astype(jnp.float32) > 0.0, dy,
                          jnp.zeros((), dy.dtype))
     raise NotImplementedError(f"fused epilogue act grad {act!r}")
 
@@ -1119,6 +1156,7 @@ def bn_act_apply(x, a, b, z=None, act="relu", c_axis=1):
         _wrap_optional_mid(
             functools.partial(_scale_shift_act_kernel, act=act),
             3, z is not None),
+        name="bn_act_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=dat_spec,
@@ -1172,6 +1210,7 @@ def bn_act_bwd_apply(y, dy, x, cg, mean, cx, c0, act="relu", c_axis=1,
         if want_g else
         (lambda *refs: _bn_act_bwd_kernel(*refs, None, act=act,
                                           want_g=False)),
+        name="bn_act_bwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -1201,7 +1240,7 @@ def _matmul_bias_act_kernel(x_ref, w_ref, b_ref, o_ref, acc_scr, *,
     @pl.when(ki == n_k - 1)
     def _done():
         y = acc_scr[...] + b_ref[...].astype(jnp.float32)
-        o_ref[...] = apply_act(y, act).astype(o_ref.dtype)
+        o_ref[...] = apply_act(y, act, in_kernel=True).astype(o_ref.dtype)
 
 
 def matmul_bias_act(x, w, bias, act=""):
@@ -1220,6 +1259,7 @@ def matmul_bias_act(x, w, bias, act=""):
     out_dtype = jnp.result_type(x, w)
     return pl.pallas_call(
         functools.partial(_matmul_bias_act_kernel, act=act, n_k=k // bk),
+        name="matmul_bias_act",
         grid=(m // bm, n // bn, k // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, ki: (i, ki)),

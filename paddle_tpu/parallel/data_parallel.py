@@ -149,7 +149,7 @@ def _update_shard_rows(op_, block, ndev):
 
 def _sharded_opt_state(ops, block, ndev):
     """Optimizer-state var names eligible for ZeRO-1 sharding on the
-    pjit path: leading dim divisible by the mesh (jax 0.4.x has no
+    pjit path: leading dim divisible by the mesh (jax has no
     uneven shards) and no explicit tensor-parallel annotation to
     respect.  GSPMD owns the update semantics there, so any op with
     derived state slots qualifies (including LAMB and the fused
@@ -476,12 +476,14 @@ def _compile_dp_miss(compiled_program, executor, program, feed,
         # set) and may escalate the parallel plan in auto mode
         relief_mode = str(flag("memory_relief", "off") or "off")
         axis0 = "dp" if "dp" in mesh.axis_names else mesh.axis_names[0]
+        use_shard_map = _program_has_collectives(program)
         rewritten = executor._apply_ir_passes(
             program, fetch_names, feed_names=tuple(sorted(set(feed))),
             scope=scope,
             relief_ctx={"ndev": int(mesh.shape[axis0]),
-                        "use_shard_map": _program_has_collectives(program),
-                        "allow_escalate": relief_mode == "auto"})
+                        "use_shard_map": use_shard_map,
+                        "allow_escalate": relief_mode == "auto"},
+            auto_partitioned=not use_shard_map)
     if rewritten is not program:
         # the clone preserves block structure, so specs map block-by-
         # block (a global-block-only lookup would drop sub-block specs)
@@ -733,15 +735,14 @@ def _compile_dp_miss(compiled_program, executor, program, feed,
         state_specs = {n: (P(axis) if n in sm_sharded else P())
                        for n in state_in}
         feed_specs = {k: P(axis) for k in feed}
-        from .mesh import shard_map_compat
-
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(state_specs, feed_specs),
             out_specs=(tuple(P(axis) for _ in fetch_names),
                        {n: (P(axis) if n in sm_sharded else P())
                         for n in state_out}),
+            check_vma=False,
         )
         jitted = jax.jit(fn)
 

@@ -16,21 +16,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check=False):
-    """Version-portable shard_map: newer jax exposes ``jax.shard_map``
-    with ``check_vma``; 0.4.x ships it as
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
-
-
 class MeshRegistry:
     def __init__(self):
         self._meshes: Dict[str, "jax.sharding.Mesh"] = {}

@@ -34,7 +34,6 @@ from __future__ import annotations
 import dataclasses
 from functools import partial
 
-from .mesh import shard_map_compat
 from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -391,10 +390,11 @@ def spmd_pipeline(stage_fn, stage_params, microbatches, mesh, axis: str = "pp",
         )
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(params_spec, mb_spec),
         out_specs=mb_spec,
+        check_vma=False,
     )
     def run(params_local, mbs):
         params_k = jax.tree.map(lambda x: x[0], params_local)

@@ -24,8 +24,6 @@ from __future__ import annotations
 
 from functools import partial
 
-from .mesh import shard_map_compat
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -69,10 +67,11 @@ def ring_attention(q, k, v, mesh, axis: str = "sp", causal: bool = False,
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, axis, None, None),) * 3,
         out_specs=P(None, axis, None, None),
+        check_vma=False,
     )
     def run(ql, kl, vl):
         i = lax.axis_index(axis)
@@ -112,10 +111,11 @@ def ulysses_attention(q, k, v, mesh, axis: str = "sp",
     scale = (1.0 / d ** 0.5) if scale is None else scale
 
     @partial(
-        shard_map_compat,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(None, axis, None, None),) * 3,
         out_specs=P(None, axis, None, None),
+        check_vma=False,
     )
     def run(ql, kl, vl):
         # [b, seq/s, h, d] -> [b, seq, h/s, d]

@@ -356,40 +356,29 @@ _DEFAULTS: Dict[str, Any] = {
 }
 
 
-def nhwc_enabled(place=None) -> bool:
-    """Resolve FLAGS_tpu_nhwc against the executor place ("auto" means
-    on-accelerator only; truthy forces on, falsy off)."""
-    v = flag("tpu_nhwc")
+def _auto_on_tpu(name: str, place) -> bool:
+    """A tri-state lever flag against the executor place: "auto" means
+    on for a TPU place only; truthy forces on, falsy off."""
+    v = flag(name)
     if isinstance(v, str):
         s = v.strip().lower()
         if s == "auto":
-            if place is None:
-                return False
-            try:
-                return place.jax_device().platform != "cpu"
-            except Exception:
-                return False
+            return (place is not None
+                    and place.jax_device().platform == "tpu")
         return s in ("1", "true", "yes", "on")
     return bool(v)
+
+
+def nhwc_enabled(place=None) -> bool:
+    """FLAGS_tpu_nhwc resolved against the executor place."""
+    return _auto_on_tpu("tpu_nhwc", place)
 
 
 def tpu_fuse_enabled(place=None) -> bool:
-    """Resolve FLAGS_tpu_fuse against the executor place ("auto" means
-    on-accelerator only; truthy forces on, falsy off) — the same
+    """FLAGS_tpu_fuse resolved against the executor place — the same
     contract as :func:`nhwc_enabled` so the two fusion levers A/B the
     same way."""
-    v = flag("tpu_fuse")
-    if isinstance(v, str):
-        s = v.strip().lower()
-        if s == "auto":
-            if place is None:
-                return False
-            try:
-                return place.jax_device().platform != "cpu"
-            except Exception:
-                return False
-        return s in ("1", "true", "yes", "on")
-    return bool(v)
+    return _auto_on_tpu("tpu_fuse", place)
 
 
 def _coerce(cur, val):

@@ -338,28 +338,12 @@ def test_verify_logits_match_reference():
     eng.generate(prompts, max_new_tokens=8)
     assert "logits" in rec, "no verify call carried a draft"
 
-    def ref_logits(seq):
-        L = len(seq)
-        S = _pow2_bucket(L, core.prefill_bucket_min, None)
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :L] = seq
-        pos = np.minimum(np.arange(S, dtype=np.int32),
-                         core.cfg.max_seq_len - 1)[None]
-        from paddle_tpu.inference.serving import _causal_mask
-        out = core.exe.run(
-            core.ref_prog,
-            feed={"tokens": toks, "positions": pos,
-                  "attn_mask": _causal_mask(S),
-                  "last_index": np.array([L - 1], np.int32)},
-            fetch_list=[core.ref_prog._srv_logits], scope=core.scope)
-        return np.asarray(out[0])[0]
-
     S = rec["S"]
     logits = rec["logits"]
     for i, (prefix, draft) in enumerate(rec["ctx"]):
         for j in range(len(draft) + 1):
             got = logits[i * S + j]
-            want = ref_logits(prefix + draft[:j])
+            want = core.reference_logits(prefix + draft[:j])
             np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 

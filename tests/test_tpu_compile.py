@@ -1,0 +1,171 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+No chip is attached under tier-1, but the chip's compiler is installed:
+``jax.experimental.topologies`` describes a ``v5e:2x2`` slice and
+``jit(...).lower(shapes).compile()`` raises what the real chip would
+raise (block shapes Mosaic refuses, primitives it cannot lower, VMEM /
+SMEM overruns).  Interpret mode sees none of that, so these compiles
+guard every kernel ``chip_smoke.py`` reaches, at the smoke's widths.
+Nothing runs — numerics stay with the interpret-mode tests.
+"""
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops import pallas_kernels as pk
+from paddle_tpu.utils.cost_model import FUSABLE_ACTS
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip's sharding; the persistent compile cache
+    is off around the module (an entry compiled for a described device
+    cannot be read back without one — the next run would only warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    from paddle_tpu.framework.place import host_tpu_chips
+
+    if host_tpu_chips():
+        # describing a topology loads libtpu into THIS process for good,
+        # and libtpu serves one process: every later child that needs
+        # the chip (the native-runtime tests) would be locked out
+        pytest.skip("a TPU host: ask the chip itself (chip_smoke.py)")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no topology support here
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Lower+compile ``fn`` for the described chip; returns the
+    compiled program's text (the kernel shows as ``tpu_custom_call``)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# --- the smoke's widths ----------------------------------------------------
+BERT = (38, 12, 512, 64)          # ERNIE/BERT-base b38 s512
+DEC_SEQS, DEC_HEADS, DEC_D = 8, 12, 64     # GPT-2-small decode batch
+PAGE, POOL_PAGES, TABLE_W = 16, 4096, 64   # 64 pages = max_seq_len 1024
+FFN = (19456, 768, 3072)          # 38*512 rows through the BERT FFN
+# NHWC activations the layout pass hands the epilogue kernels at b128
+RESNET_STAGES = [(128, 56, 56, 64), (128, 7, 7, 2048)]
+
+
+def _flash_case(causal, shape, dropout, multi_block):
+    b, h, s, d = shape
+    blk = 512
+    assert (s // blk > 1) == multi_block
+
+    def f(q, k, v, bias, seed, do):
+        o, lse = pk._flash_fwd(q, k, v, bias, d ** -0.5, causal, blk, blk,
+                               dropout, seed)
+        return pk._flash_bwd(q, k, v, bias, o, lse, do, d ** -0.5, causal,
+                             blk, blk, dropout, seed)
+
+    bf = jnp.bfloat16
+    return f, [(shape, bf)] * 3 + [((b, s), jnp.float32),
+                                   ((1,), jnp.float32), (shape, bf)]
+
+
+def _paged_case(kv_dtype):
+    quant = kv_dtype == jnp.int8
+
+    def f(q, kp, vp, bt, cl, *scales):
+        ks, vs = scales if quant else (None, None)
+        return pk._paged_decode_call(q, kp, vp, bt, cl, DEC_D ** -0.5,
+                                     k_scale=ks, v_scale=vs)
+
+    pool = ((DEC_HEADS, POOL_PAGES, PAGE, DEC_D), kv_dtype)
+    shapes = [((DEC_SEQS, DEC_HEADS, DEC_D), jnp.float32), pool, pool,
+              ((DEC_SEQS, TABLE_W), jnp.int32), ((DEC_SEQS,), jnp.int32)]
+    if quant:
+        shapes += [((DEC_HEADS, POOL_PAGES), jnp.float32)] * 2
+    return f, shapes
+
+
+def _bn_fwd_case(shape, residual):
+    c = shape[-1]
+    bf = jnp.bfloat16
+
+    def f(x, a, b, *z):
+        out = pk.bn_act_apply(x, a, b, z[0] if z else None, act="relu",
+                              c_axis=3)
+        assert out is not None, "bn_act_apply gave way to the jnp path"
+        return out
+
+    return f, [(shape, bf), ((c,), bf), ((c,), bf)] + \
+        ([(shape, bf)] if residual else [])
+
+
+def _bn_bwd_case(shape, want_g):
+    c = shape[-1]
+    bf = jnp.bfloat16
+
+    def f(y, dy, x, cg, mean, cx, c0):
+        out = pk.bn_act_bwd_apply(y, dy, x, cg, mean, cx, c0, act="relu",
+                                  c_axis=3, want_g=want_g)
+        assert out is not None, "bn_act_bwd_apply gave way to the jnp path"
+        return out[0] if not want_g else out
+
+    return f, [(shape, bf)] * 3 + [((c,), bf), ((c,), bf), ((c,), bf),
+                                   ((c,), jnp.float32)]
+
+
+def _matmul_case(act):
+    m, k, n = FFN
+    bf = jnp.bfloat16
+
+    def f(x, w, b):
+        out = pk.matmul_bias_act(x, w, b, act=act)
+        assert out is not None, "matmul_bias_act gave way to the jnp path"
+        return out
+
+    return f, [((m, k), bf), ((k, n), bf), ((n,), bf)]
+
+
+CASES = {
+    "flash-bert-dropout": functools.partial(
+        _flash_case, False, BERT, 0.1, False),
+    "flash-causal-s1024-multiblock": functools.partial(
+        _flash_case, True, (4, 12, 1024, 64), 0.0, True),
+    "flash-s1024-dropout-multiblock": functools.partial(
+        _flash_case, False, (4, 12, 1024, 64), 0.1, True),
+    "paged-f32": functools.partial(_paged_case, jnp.float32),
+    "paged-bf16": functools.partial(_paged_case, jnp.bfloat16),
+    "paged-int8": functools.partial(_paged_case, jnp.int8),
+    **{f"bn_act-fwd-{'x'.join(map(str, s))}{'-res' if r else ''}":
+       functools.partial(_bn_fwd_case, s, r)
+       for s in RESNET_STAGES for r in (False, True)},
+    **{f"bn_act-bwd-{'x'.join(map(str, s))}{'-g' if g else ''}":
+       functools.partial(_bn_bwd_case, s, g)
+       for s in RESNET_STAGES for g in (False, True)},
+    **{f"matmul_bias_act-{a or 'none'}": functools.partial(_matmul_case, a)
+       for a in ("",) + FUSABLE_ACTS},        # "" = bias only
+}
+
+
+@pytest.mark.parametrize("name", list(CASES))
+def test_kernel_compiles_for_v5e(name, v5e, monkeypatch):
+    # default_backend() is "cpu" here, so the engage checks would pick
+    # the jnp path: steer them from the test, never through an option
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    fn, shapes = CASES[name]()
+    text = _compile(fn, v5e, *shapes)
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"

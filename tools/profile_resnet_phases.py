@@ -57,7 +57,7 @@ def profile_cell(nhwc, session, batch, image, steps, repeats, amp):
     flags._flags["FLAGS_tpu_step_session"] = session
 
     main, startup, loss = build(image, amp)
-    place = pt.TPUPlace(0) if pt.is_compiled_with_tpu() else pt.CPUPlace()
+    place = pt.TPUPlace(0)
     exe = fluid.Executor(place)
     device = place.jax_device()
     rng = np.random.RandomState(0)

@@ -28,7 +28,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def run_resnet(steps=8, batch=128, image=224, amp=True, depth=50):
+def run_resnet(steps=8, batch=128, image=224, amp=True, depth=50,
+               place=None):
     import jax
     import numpy as np
 
@@ -46,7 +47,7 @@ def run_resnet(steps=8, batch=128, image=224, amp=True, depth=50):
         if amp:
             opt = fluid.contrib.mixed_precision.decorate(opt)
         opt.minimize(loss)
-    place = pt.TPUPlace(0) if pt.is_compiled_with_tpu() else pt.CPUPlace()
+    place = place or pt.TPUPlace(0)
     exe = fluid.Executor(place)
     exe.run(startup)
     rng = np.random.RandomState(0)
@@ -186,8 +187,10 @@ def main():
         steps = 2
         top_n = top_n or 10
         which = "resnet18_quick"
+        import paddle_tpu as pt
+
         step = run_resnet(steps=steps, batch=4, image=32, amp=False,
-                          depth=18)
+                          depth=18, place=pt.CPUPlace())
     elif which == "ernie":
         step = run_ernie()
     else:

@@ -56,8 +56,7 @@ def export_encoder(model_dir, seq, hidden=256, heads=4, layers=2):
             h = fluid.layers.elementwise_add(
                 h, fluid.layers.fc(ff, hidden, num_flatten_dims=2))
         out = fluid.layers.reduce_mean(h, dim=[2])
-    exe = fluid.Executor(
-        pt.TPUPlace(0) if pt.is_compiled_with_tpu() else pt.CPUPlace())
+    exe = fluid.Executor(pt.TPUPlace(0))
     exe.run(startup)
     fluid.io.save_inference_model(model_dir, ["x"], [out], exe,
                                   main_program=main)
@@ -66,12 +65,9 @@ def export_encoder(model_dir, seq, hidden=256, heads=4, layers=2):
 def run_one(model_dir, seq, batch, steps, with_mha_pass):
     from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
 
-    import paddle_tpu as pt
-
     config = AnalysisConfig(model_dir)
     config.switch_use_feed_fetch_ops(False)
-    if pt.is_compiled_with_tpu():
-        config.enable_tpu()
+    config.enable_tpu()
     if not with_mha_pass:
         config.pass_builder().delete_pass("fuse_multihead_attention_pass")
     pred = create_paddle_predictor(config)

@@ -8,7 +8,7 @@ SLO-aware-admission rung will stand on:
   breakdown recomputed from each request's recorded span tree
   (utils/tracing.py), with TTFT, token count, preemption cycles and the
   admission OUTCOME (admitted / shed / rejected — the r18 overload-
-  protection taxonomy);
+  protection outcomes);
 * **SLO accounting** — declared TTFT / per-token targets, the
   rolling-window error-budget burn rate and goodput (requests/tokens
   served within SLO vs total) from utils/telemetry.py's SLOTracker;
