@@ -22,7 +22,7 @@ XLA so updates are in-place in HBM.
 from __future__ import annotations
 
 import logging
-import time
+import threading
 import weakref
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -34,10 +34,57 @@ from .framework.dtype import to_numpy_dtype
 from .framework.place import CPUPlace, Place, _get_paddle_place
 from .framework.scope import LoDTensor, Scope, global_scope
 from .ops import registry
+from .profiler import RecordEvent
+from .utils import telemetry as tm
 
 logger = logging.getLogger(__name__)
 
 RNG_VAR = registry.LowerCtx.RNG_VAR
+
+# ---- compile counters the program owns -----------------------------------
+# JAX reports how long it traced a function to a jaxpr and lowered the
+# jaxpr to MLIR, with the function's name.  One listener adds up the
+# seconds of the compiled steps themselves (``pt_<label>``, named by
+# ``named_step`` below): a user's own jax.jit or a plain reference model
+# is left out, and so are the nested traces of jnp functions, whose time
+# the step's own event already holds.  Compiles are rare: always on.
+_JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE_TM = tm.Handles(
+    trace=("counter", "executor_jax_trace_seconds_total",
+           "seconds JAX spent tracing the op lowerings of a compiled "
+           "step (Executor.run, the DP step) to a jaxpr"),
+    lower=("counter", "executor_jax_lower_seconds_total",
+           "seconds JAX spent lowering a compiled step's jaxpr to an "
+           "MLIR module"))
+
+
+def _on_jax_duration(event, duration, fun_name="", **_):
+    if event == _JAX_TRACE_EVENT:
+        if fun_name.startswith("pt_"):
+            _COMPILE_TM.current().trace.inc(duration)
+    elif event == _JAX_LOWER_EVENT:
+        if fun_name.startswith("jit(pt_"):
+            _COMPILE_TM.current().lower.inc(duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def program_label(program) -> str:
+    """The program's form (``main`` unless its builder said otherwise:
+    the serving forms are ``prefill``, ``decode``, ``chunk``, ``verify``,
+    ``reference``).  Names the compiled step and the spans; never
+    derived from a shape."""
+    return getattr(program, "_label", "main")
+
+
+def named_step(fn, label: str):
+    """``fn`` renamed ``pt_<label>``: ``jax.jit`` calls its module
+    ``jit_<function name>``, so the device trace's module line and the
+    HLO dumps say which program ran instead of ``jit_fn`` for all."""
+    fn.__name__ = fn.__qualname__ = f"pt_{label}"
+    return fn
 
 
 class _Compiled:
@@ -193,7 +240,6 @@ def double_buffered_feeds(feeds, stager: FeedStager):
     caller's thread: identical values (the rollback contract the tests
     pin), no overlap.  ``feeds`` is any iterable of feed dicts; staging
     errors surface on the consumer thread at the offending batch."""
-    from .utils import telemetry as tm
     from .utils.flags import flag as _flag
 
     it = iter(feeds)
@@ -353,10 +399,32 @@ class Executor:
         # the same shapes must not both pay the XLA compile or race the
         # cache insert; steady-state runs only pay an uncontended
         # acquire
-        import threading
-
         self._compile_lock = threading.Lock()
         self._closed = False
+        self._step_no = 0
+        # the step path's instruments, resolved here and not by name on
+        # every step (FLAGS_telemetry honoured: utils/telemetry.Handles)
+        self._tm = tm.Handles(
+            hits=("counter", "executor_compile_cache_hits_total",
+                  "Executor._compile cache hits"),
+            misses=("counter", "executor_compile_cache_misses_total",
+                    "Executor._compile cache misses (fresh trace+jit "
+                    "construction)"),
+            build_s=("histogram", "executor_compile_build_s",
+                     "IR-pipeline + trace/jit construction seconds per "
+                     "cache miss (XLA compilation itself is lazy: it "
+                     "lands in the first step's executor_step_s)"),
+            invalidations=(
+                "counter", "executor_step_session_invalidations_total",
+                "step sessions dropped because the scope was mutated "
+                "outside the executor's own writeback"),
+            feed_conversions=(
+                "counter", "executor_feed_conversions_total",
+                "feed arrays cast to the program dtype on the step path "
+                "(stage the right dtype to avoid the copy)"),
+            step_s=("histogram", "executor_step_s",
+                    "Executor.run wall seconds (host dispatch; device "
+                    "work may still be in flight — fetches are lazy)"))
 
     def _nhwc_enabled(self) -> bool:
         """FLAGS_tpu_nhwc resolved against this executor's place
@@ -421,12 +489,16 @@ class Executor:
 
             return run_pipeline(self, program, feed, fetch_list, scope,
                                 return_numpy)
-        scope = scope or global_scope()
-        feed = dict(feed or {})
-        fetch_names = [_fetch_name(f) for f in (fetch_list or [])]
-
-        compiled = self._compile(program, feed, fetch_names, scope)
-        return self._execute(compiled, feed, fetch_names, scope, return_numpy, program)
+        self._step_no += 1
+        with RecordEvent("executor/step") as step:
+            if step.recording:
+                step.set(program=program_label(program), step=self._step_no)
+            scope = scope or global_scope()
+            feed = dict(feed or {})
+            fetch_names = [_fetch_name(f) for f in (fetch_list or [])]
+            compiled = self._compile(program, feed, fetch_names, scope)
+            return self._execute(compiled, feed, fetch_names, scope,
+                                 return_numpy, program)
 
     # ------------------------------------------------------------------
     def _compile(self, program: Program, feed, fetch_names, scope) -> _Compiled:
@@ -479,17 +551,28 @@ class Executor:
                # tensor-parallel serving: the same program compiled over
                # a different mesh/degree is a different executable
                self._tp_signature(program))
-        from .utils import telemetry as tm
-
+        handles = self._tm.current()
         hit = self._cache.get(key)
         if hit is not None:
-            tm.counter("executor_compile_cache_hits_total",
-                       "Executor._compile cache hits").inc()
+            handles.hits.inc()
             return hit
-        tm.counter("executor_compile_cache_misses_total",
-                   "Executor._compile cache misses (fresh trace+jit "
-                   "construction)").inc()
-        build_t0 = time.perf_counter()
+        handles.misses.inc()
+        with RecordEvent("executor/compile", timed=True) as build:
+            compiled = self._compile_miss(
+                program, feed, fetch_names, scope, check_nan_inf,
+                unused_check, donate)
+            self._cache[key] = compiled
+        handles.build_s.observe(build.end - build.begin)
+        return compiled
+
+    def _compile_miss(self, program: Program, feed, fetch_names, scope,
+                      check_nan_inf, unused_check, donate) -> _Compiled:
+        """A cache miss: the IR passes and the construction of the step
+        function (XLA compilation itself is lazy: it lands in the first
+        call)."""
+        from .framework import numerics as _numerics
+
+        label = program_label(program)
 
         tp_shard = getattr(program, "_tp_shard", None)
         src_block = program.global_block()
@@ -629,7 +712,7 @@ class Executor:
                             for op_ in seg_ops:
                                 registry.run_op(op_, env, block)
                             return {n: env[n] for n in outs if n in env}
-                        return jax.jit(seg_fn)
+                        return jax.jit(named_step(seg_fn, label + "_seg"))
 
                     jitted_segs[i] = (tuple(needed), make_seg())
 
@@ -670,13 +753,6 @@ class Executor:
             compiled.feed_plan = feed_plan
             compiled._memory_plan = mem_plan
             compiled.numerics = n_layout
-            self._cache[key] = compiled
-            tm.histogram(
-                "executor_compile_build_s",
-                "IR-pipeline + trace/jit construction seconds per cache "
-                "miss (XLA compilation itself is lazy: it lands in the "
-                "first step's executor_step_s)").observe(
-                    time.perf_counter() - build_t0)
             return compiled
 
         # Donate only buffers that are both read and re-written (params,
@@ -722,6 +798,7 @@ class Executor:
             fn = jax.shard_map(fn, mesh=tp_shard["mesh"],
                                in_specs=in_specs, out_specs=out_specs,
                                check_vma=False)
+        fn = named_step(fn, label)
 
         if check_nan_inf:
             # FLAGS_check_nan_inf (reference: operator.cc:1020
@@ -729,7 +806,8 @@ class Executor:
             # checkify so they survive jit, then re-raise on host.
             from jax.experimental import checkify
 
-            checked = checkify.checkify(fn, errors=checkify.user_checks)
+            checked = named_step(
+                checkify.checkify(fn, errors=checkify.user_checks), label)
             # no donation here: when the check raises, the scope still
             # points at the input buffers — donating them would brick the
             # session on backends that honor donation, defeating the
@@ -754,13 +832,6 @@ class Executor:
         compiled.feed_plan = feed_plan
         compiled._memory_plan = mem_plan
         compiled.numerics = n_layout
-        self._cache[key] = compiled
-        tm.histogram(
-            "executor_compile_build_s",
-            "IR-pipeline + trace/jit construction seconds per cache "
-            "miss (XLA compilation itself is lazy: it lands in the "
-            "first step's executor_step_s)").observe(
-                time.perf_counter() - build_t0)
         return compiled
 
     # ------------------------------------------------------------------
@@ -892,9 +963,7 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _execute(self, compiled, feed, fetch_names, scope, return_numpy, program):
-        from .utils import telemetry as tm
-
-        step_t0 = time.perf_counter()
+        handles = self._tm.current()
         device = self.place.jax_device()
         tp_shard = getattr(compiled, "tp_shard", None)
         if tp_shard is not None:
@@ -919,24 +988,31 @@ class Executor:
         hybrid = compiled.hybrid
         feed_vals = {}
         n_feed_conv = 0
-        for k, v in feed.items():
-            if isinstance(v, LoDTensor):
-                v = v.value()
-            if isinstance(v, jax.Array):
-                # already on device: skip even the device_put no-op when
-                # placement matches (the bench/reader staged path)
-                feed_vals[k] = v if v.devices() == {device} \
-                    else jax.device_put(v, device)
-                continue
-            arr = np.asarray(v)
-            want = plan.get(k)
-            if want is not None and arr.dtype != want:
-                arr = arr.astype(want)
-                n_feed_conv += 1
-            # hybrid (PS) programs: keep feeds host-side — host ops (e.g.
-            # distributed_lookup_table reading feed ids) then cost no D2H
-            # round-trip; jit segments device_put what they consume
-            feed_vals[k] = arr if hybrid else jax.device_put(arr, device)
+        with RecordEvent("executor/feed", timed=True) as feed_span:
+            for k, v in feed.items():
+                if isinstance(v, LoDTensor):
+                    v = v.value()
+                if isinstance(v, jax.Array):
+                    # already on device: skip even the device_put no-op
+                    # when placement matches (the bench/reader staged path)
+                    feed_vals[k] = v if v.devices() == {device} \
+                        else jax.device_put(v, device)
+                    continue
+                arr = np.asarray(v)
+                want = plan.get(k)
+                if want is not None and arr.dtype != want:
+                    arr = arr.astype(want)
+                    n_feed_conv += 1
+                # hybrid (PS) programs: keep feeds host-side — host ops
+                # (e.g. distributed_lookup_table reading feed ids) then
+                # cost no D2H round-trip; jit segments device_put what
+                # they consume
+                feed_vals[k] = arr if hybrid else jax.device_put(arr, device)
+            if feed_span.recording:
+                feed_span.set(
+                    bytes=int(sum(getattr(v, "nbytes", 0)
+                                  for v in feed_vals.values())),
+                    arrays_cast=n_feed_conv)
 
         def state_val(name, donated=False):
             if name == RNG_VAR:
@@ -965,43 +1041,42 @@ class Executor:
                     else jax.device_put(val, device)
             return val
 
-        from .profiler import RecordEvent
         from .utils.flags import flag as _flag
 
         use_session = not hybrid and bool(_flag("tpu_step_session", True))
 
+        def bind():
+            """The step's state: hybrid programs read the scope; the hot
+            path has its mut/ro partition precomputed at compile time
+            and binds from the step session when the scope hasn't been
+            touched since our own writeback — zero scope reads a step."""
+            if hybrid:
+                return {n: state_val(n) for n in compiled.state_in}, None
+            sess = compiled.session if use_session else None
+            bound = None
+            if (sess is not None and sess.scope_ref() is scope
+                    and sess.stamp == Scope.mutation_counter):
+                bound = sess.deref()
+            if bound is not None:
+                return bound
+            if sess is not None:
+                # stale — drop promptly (an external scope write
+                # invalidated the device-resident binding)
+                compiled.session = None
+                handles.invalidations.inc()
+            return ({n: state_val(n, donated=True)
+                     for n in compiled.donatable},
+                    {n: state_val(n) for n in compiled.readonly})
+
         def dispatch():
             with RecordEvent("executor_run"):
-                if hybrid:
-                    state_vals = {n: state_val(n)
-                                  for n in compiled.state_in}
-                    f, ns = compiled.fn(feed_vals, state_vals)
-                    return f, ns, None
-                # hot path: mut/ro partition precomputed at compile
-                # time; the state binding itself comes from the step
-                # session when the scope hasn't been touched since our
-                # own writeback — zero scope reads per step
-                sess = compiled.session if use_session else None
-                bound = None
-                if (sess is not None and sess.scope_ref() is scope
-                        and sess.stamp == Scope.mutation_counter):
-                    bound = sess.deref()
-                if bound is not None:
-                    mut, ro = bound
-                else:
-                    if sess is not None:
-                        # stale — drop promptly (an external scope write
-                        # invalidated the device-resident binding)
-                        compiled.session = None
-                        tm.counter(
-                            "executor_step_session_invalidations_total",
-                            "step sessions dropped because the scope was "
-                            "mutated outside the executor's own "
-                            "writeback").inc()
-                    mut = {n: state_val(n, donated=True)
-                           for n in compiled.donatable}
-                    ro = {n: state_val(n) for n in compiled.readonly}
-                f, ns = compiled.fn(mut, ro, feed_vals)
+                with RecordEvent("executor/bind"):
+                    mut, ro = bind()
+                with RecordEvent("executor/call"):
+                    if hybrid:
+                        f, ns = compiled.fn(feed_vals, mut)
+                    else:
+                        f, ns = compiled.fn(mut, ro, feed_vals)
                 return f, ns, ro
 
         try:
@@ -1039,51 +1114,51 @@ class Executor:
             # one forced device sync — armed-probe cost only.
             from .framework import numerics as nm
 
-            nm.on_step(compiled.numerics, np.asarray(fetched[-1]),
-                       where="executor")
+            with RecordEvent("executor/probe"):
+                nm.on_step(compiled.numerics, np.asarray(fetched[-1]),
+                           where="executor")
             fetched = fetched[:-1]
-        scope_set = scope.set
-        for name, val in new_state.items():
-            scope_set(name, val)
-        if use_session:
-            # rebind next step's state from this step's outputs: the
-            # donated input buffers are dead, their replacements are in
-            # new_state (now also held by the scope); read-only state is
-            # still alive as-is
-            try:
-                mut_refs = {n: weakref.ref(new_state[n])
-                            for n in compiled.donatable}
-            except (KeyError, TypeError):
-                # a donated var wasn't produced, or a state value isn't
-                # weakref-able (SelectedRows pytree) — no session
+        with RecordEvent("executor/writeback", timed=True) as writeback:
+            scope_set = scope.set
+            for name, val in new_state.items():
+                scope_set(name, val)
+            if use_session:
+                # rebind next step's state from this step's outputs: the
+                # donated input buffers are dead, their replacements are
+                # in new_state (now also held by the scope); read-only
+                # state is still alive as-is
+                try:
+                    mut_refs = {n: weakref.ref(new_state[n])
+                                for n in compiled.donatable}
+                except (KeyError, TypeError):
+                    # a donated var wasn't produced, or a state value
+                    # isn't weakref-able (SelectedRows pytree) — no
+                    # session
+                    compiled.session = None
+                else:
+                    compiled.session = _StateSession(
+                        weakref.ref(scope), Scope.mutation_counter,
+                        mut_refs, ro_bound)
+            elif not hybrid:
                 compiled.session = None
-            else:
-                compiled.session = _StateSession(
-                    weakref.ref(scope), Scope.mutation_counter,
-                    mut_refs, ro_bound)
-        elif not hybrid:
-            compiled.session = None
 
-        if n_feed_conv:
-            tm.counter("executor_feed_conversions_total",
-                       "feed arrays cast to the program dtype on the "
-                       "step path (stage the right dtype to avoid "
-                       "the copy)").inc(n_feed_conv)
-        tm.histogram("executor_step_s",
-                     "Executor.run wall seconds (host dispatch; device "
-                     "work may still be in flight — fetches are "
-                     "lazy)").observe(time.perf_counter() - step_t0)
+            if n_feed_conv:
+                handles.feed_conversions.inc(n_feed_conv)
+        # feed to write-back, from the spans' own stamps: the host's
+        # dispatch, not the fetch's wait for the device
+        handles.step_s.observe(writeback.end - feed_span.begin)
 
         if fetch_names:
-            if return_numpy:
-                return [as_numpy(v) for v in fetched]
-            # keep device arrays lazy — no host sync until .numpy().
-            # SelectedRows fetches densify (still lazy on device) so the
-            # LoDTensor surface stays array-like.
-            from .framework.selected_rows import SelectedRows
+            with RecordEvent("executor/fetch"):
+                if return_numpy:
+                    return [as_numpy(v) for v in fetched]
+                # keep device arrays lazy — no host sync until .numpy().
+                # SelectedRows fetches densify (still lazy on device) so
+                # the LoDTensor surface stays array-like.
+                from .framework.selected_rows import SelectedRows
 
-            return [LoDTensor(v.to_dense() if isinstance(v, SelectedRows)
-                              else v) for v in fetched]
+                return [LoDTensor(v.to_dense() if isinstance(v, SelectedRows)
+                                  else v) for v in fetched]
         return None
 
     # ------------------------------------------------------------------

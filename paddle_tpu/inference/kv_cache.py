@@ -153,6 +153,36 @@ class PagedKVCache:
 
             prefix_cache = bool(flag("kv_prefix_cache", False))
         self.prefix_cache = bool(prefix_cache)
+        from ..utils import telemetry as tm
+
+        # the allocator's instruments, resolved once and not by name at
+        # every mutation (a decode step mutates once a sequence); each
+        # is published from its first use, so the prefix and
+        # quantization gauges exist only where engaged
+        self._tm = tm.Handles(
+            pages_in_use=("gauge", "kv_pool_pages_in_use",
+                          "KV pages currently owned by live sequences"),
+            utilization=("gauge", "kv_pool_utilization",
+                         "fraction of KV pool pages in use"),
+            fragmentation=("gauge", "kv_pool_fragmentation",
+                           "fraction of owned KV slots holding no token "
+                           "(tail-of-page waste)"),
+            prefix_cached=("gauge", "kv_prefix_cached_pages",
+                           "refcount-0 pages kept as evictable "
+                           "prefix-cache entries"),
+            prefix_shared=("gauge", "kv_prefix_shared_pages",
+                           "pages currently mapped by more than one live "
+                           "sequence"),
+            quant_scale_bytes=("gauge", "kv_quant_scale_bytes",
+                               "per-side per-layer scale-pool bytes "
+                               "backing the quantized KV pool"),
+            quant_capacity=("gauge", "kv_quant_capacity_tokens",
+                            "token slots the quantized pool holds at its "
+                            "fixed byte budget"),
+            alloc=("counter", "kv_pool_pages_alloc_total",
+                   "KV pages handed out"),
+            freed=("counter", "kv_pool_pages_freed_total",
+                   "KV pages returned to the pool"))
         self.seed = int(seed)
         self._free: deque = deque(range(config.num_pages))
         self._seqs: Dict[object, _Seq] = {}
@@ -237,34 +267,20 @@ class PagedKVCache:
         """Pool state -> telemetry registry (r13): the gauges mirror
         what ``stats()`` computes, updated at every allocator mutation
         so a mid-run snapshot is never stale."""
-        from ..utils import telemetry as tm
-
-        tm.gauge("kv_pool_pages_in_use",
-                 "KV pages currently owned by live sequences").set(
-                     self.pages_in_use)
-        tm.gauge("kv_pool_utilization",
-                 "fraction of KV pool pages in use").set(self.utilization())
-        tm.gauge("kv_pool_fragmentation",
-                 "fraction of owned KV slots holding no token "
-                 "(tail-of-page waste)").set(self.fragmentation())
+        handles = self._tm.current()
+        handles.pages_in_use.set(self.pages_in_use)
+        handles.utilization.set(self.utilization())
+        handles.fragmentation.set(self.fragmentation())
         if self.prefix_cache:
-            tm.gauge("kv_prefix_cached_pages",
-                     "refcount-0 pages kept as evictable prefix-cache "
-                     "entries").set(len(self._cached_free))
-            tm.gauge("kv_prefix_shared_pages",
-                     "pages currently mapped by more than one live "
-                     "sequence").set(
-                         sum(1 for r in self._refs.values() if r > 1))
+            handles.prefix_cached.set(len(self._cached_free))
+            handles.prefix_shared.set(
+                sum(1 for r in self._refs.values() if r > 1))
         if self.config.dtype != "float32":
             # published only when quantization is engaged, so the
             # default-f32 gauge namespace stays byte-identical
-            tm.gauge("kv_quant_scale_bytes",
-                     "per-side per-layer scale-pool bytes backing the "
-                     "quantized KV pool").set(self.config.scale_bytes())
-            tm.gauge("kv_quant_capacity_tokens",
-                     "token slots the quantized pool holds at its fixed "
-                     "byte budget").set(
-                         self.config.num_pages * self.config.page_size)
+            handles.quant_scale_bytes.set(self.config.scale_bytes())
+            handles.quant_capacity.set(
+                self.config.num_pages * self.config.page_size)
 
     # -- page pool internals ----------------------------------------------
     def _evict_key(self, page: int):
@@ -398,10 +414,7 @@ class PagedKVCache:
             self.alloc_count += 1
         self.peak_pages = max(self.peak_pages, self.pages_in_use)
         if need:
-            from ..utils import telemetry as tm
-
-            tm.counter("kv_pool_pages_alloc_total",
-                       "KV pages handed out").inc(need)
+            self._tm.current().alloc.inc(need)
         slots = np.empty(n_tokens, np.int32)
         for j in range(n_tokens):
             pos = s.length + j
@@ -565,10 +578,7 @@ class PagedKVCache:
                 self._used[s.pages[-1]] = new_len % ps
         if released:
             self.free_count += released
-            from ..utils import telemetry as tm
-
-            tm.counter("kv_pool_pages_freed_total",
-                       "KV pages returned to the pool").inc(released)
+            self._tm.current().freed.inc(released)
         self._publish_gauges()
 
     def take_forks(self) -> List[Tuple[int, int, int]]:
@@ -602,10 +612,7 @@ class PagedKVCache:
                         self._used.pop(page, None)
         self.free_count += released
         if released:
-            from ..utils import telemetry as tm
-
-            tm.counter("kv_pool_pages_freed_total",
-                       "KV pages returned to the pool").inc(released)
+            self._tm.current().freed.inc(released)
             self._publish_gauges()
 
     # -- views for the decode step ----------------------------------------

@@ -47,7 +47,6 @@ from __future__ import annotations
 
 import json
 import os
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -421,6 +420,7 @@ def build_decoder_program(cfg: DecoderConfig, mode: str,
     H, D, h = cfg.num_heads // tp, cfg.head_dim, cfg.hidden
     hl = h // tp
     prog = Program()
+    prog._label = mode  # names the compiled step pt_<mode> and its spans
     b = _B(prog)
     params = {n: b.param(n, s) for n, s in decoder_param_specs(cfg).items()}
 
@@ -743,6 +743,37 @@ class _SeqState:
     last_token: int = 0
 
 
+# The schedulers' per-step instruments, each resolved at its first use
+# (FLAGS_telemetry honoured) and not looked up by name under the
+# registry's lock for every token: utils/telemetry.Handles.  A family
+# nothing touched (no preemption, spec decode off) is not published.
+_TM = tm.Handles(
+    token_latency=("histogram", "serving_token_latency_s",
+                   "per-token latency (inter-token gap; first token from "
+                   "arrival)"),
+    ttft=("histogram", "serving_ttft_s",
+          "time to first token from arrival"),
+    admitted=("counter", "serving_admitted_total",
+              "requests admitted (prefilled)"),
+    prefill_tokens=("counter", "serving_prefill_tokens_total",
+                    "prompt tokens prefilled"),
+    preempted=("counter", "serving_preempted_total",
+               "sequences preempted to the waiting queue on pool "
+               "exhaustion"),
+    decode_steps=("counter", "serving_decode_steps_total",
+                  "batched decode steps run"),
+    decode_tokens=("counter", "serving_decode_tokens_total",
+                   "tokens produced by decode steps"),
+    finished=("counter", "serving_finished_total",
+              "requests finished (pages evicted on finish)"),
+    spec_proposed=("counter", "spec_proposed_total",
+                   "draft tokens proposed to spec-decode verify"),
+    spec_accepted=("counter", "spec_accepted_total",
+                   "draft tokens accepted by spec-decode verify"),
+    spec_accept_rate=("gauge", "spec_accept_rate",
+                      "cumulative spec-decode draft acceptance rate"))
+
+
 def _observe_token(req: Request, now: float):
     """Per-token latency into the registry, with loadgen's exact
     convention (utils/loadgen.py latency_report): every token's gap
@@ -761,13 +792,10 @@ def _observe_token(req: Request, now: float):
     # the histogram -> trace exemplar link: a traced request's latency
     # observation carries its trace id, so a p99 bucket names a trace
     ex = req.trace.trace_id if req.trace is not None else None
-    tm.histogram("serving_token_latency_s",
-                 "per-token latency (inter-token gap; first token from "
-                 "arrival)").observe(gap, exemplar=ex)
+    handles = _TM.current()
+    handles.token_latency.observe(gap, exemplar=ex)
     if first:
-        tm.histogram("serving_ttft_s",
-                     "time to first token from arrival").observe(
-                         gap, exemplar=ex)
+        handles.ttft.observe(gap, exemplar=ex)
     req._tm_last = now
 
 
@@ -835,16 +863,20 @@ def _trace_backpressure(req: Request, kind: str):
         tr._wait.attrs[kind] = tr._wait.attrs.get(kind, 0) + 1
 
 
-def _trace_admit(req: Request, now: float, wall0: float, wall1: float,
+def _trace_admit(req: Request, now: float, job: "_PrefillJob",
                  cached: int = 0, chunks: int = 0):
     """Successful prefill: close the open wait span (queue_wait, or the
     preempted span of a resume cycle) and record the prefill span with
-    its real wall bounds.  ``cached``/``chunks`` annotate prefix-cache
-    hits and chunked prefills — attrs appear ONLY when the features
-    engaged, so flag-off span streams stay byte-identical to r18."""
+    its real wall bounds: the end of the job's last ``engine/prefill``
+    span and, before it, the summed time of all its slices (a 5-chunk
+    prefill reports 5 chunks' worth of wall).  ``cached``/``chunks``
+    annotate prefix-cache hits and chunked prefills — attrs appear ONLY
+    when the features engaged, so flag-off span streams stay
+    byte-identical to r18."""
     tr = req.trace
     if tr is None:
         return
+    wall0, wall1 = job.wall_end - job.wall_s, job.wall_end
     tr.end(tr._wait, t=now)
     tr._wait = None
     attrs = {"prompt_tokens": len(req.prompt),
@@ -942,15 +974,17 @@ def _worst_case_pages(req: Request, kv_config: KVCacheConfig) -> int:
 class _PrefillJob:
     """In-flight prefill of one request: ``pos`` tokens are already in
     the pool (prefix-cache hit + completed chunks), ``first_token`` is
-    set when the final slice ran.  ``wall_s`` accumulates every
-    slice's wall time so the prefill span covers ALL chunks, not just
-    the completing one."""
+    set when the final slice ran.  For a traced request ``wall_s``
+    accumulates every slice's ``engine/prefill`` span so the request's
+    prefill span covers ALL chunks, not just the completing one, and
+    ``wall_end`` is the end of the last."""
     req: Request
     pos: int = 0
     hit: int = 0
     chunks: int = 0
     first_token: Optional[int] = None
     wall_s: float = 0.0
+    wall_end: Optional[float] = None
 
 
 _FORK_COPY = None
@@ -1087,6 +1121,9 @@ class _EngineCore:
         self.kv = PagedKVCache(self.kv_config, prefix_cache=prefix_cache,
                                seed=prefix_seed)
         self._chunk = None   # (prog, feeds, fetch) — built on first use
+        # (begin, end) of the last engine/decode span when a request in
+        # its batch was traced, else (None, None)
+        self.decode_wall = (None, None)
         self._verify = None  # spec-decode verify form — built on first use
 
         self._tp_rules = decoder_tp_rules(cfg, kv_dtype=kv_dtype) \
@@ -1309,20 +1346,42 @@ class _EngineCore:
         n = remaining if max_tokens is None else \
             min(int(max_tokens), remaining)
         chunk = req.prompt[job.pos:job.pos + n]
-        slots = self.kv.append_tokens(req.req_id, n, tokens=chunk)
-        if slots is None:
-            return None
-        if job.chunks == 0:
-            # the FIRST slice that actually lands confirms the hit:
-            # counting here (not at acquire) keeps blocked-admission
-            # acquire/release retries out of the hit accounting
-            self.kv.commit_prefix_hit(req.req_id)
-        wall_t0 = time.perf_counter()
-        self._apply_forks()
-        final = job.pos + n == L
-        if job.pos == 0 and final:
-            # cold whole-prompt prefill: the classic (MHA-fused) path,
-            # bit-identical to the pre-chunking engine
+        # the request's trace (utils/tracing.py) takes its prefill wall
+        # bounds from this span: timed for a traced request even while
+        # no profiler records
+        traced = req.trace is not None
+        with RecordEvent("engine/prefill", "serving", timed=traced) as span:
+            with RecordEvent("engine/feed_build", "serving"):
+                slots = self.kv.append_tokens(req.req_id, n, tokens=chunk)
+                if slots is None:
+                    return None
+                if job.chunks == 0:
+                    # the FIRST slice that actually lands confirms the
+                    # hit: counting here (not at acquire) keeps blocked-
+                    # admission acquire/release retries out of the hit
+                    # accounting
+                    self.kv.commit_prefix_hit(req.req_id)
+                self._apply_forks()
+            final = job.pos + n == L
+            if job.pos == 0 and final:
+                tok = self._run_whole(req, slots, span)
+            else:
+                tok = self._run_chunk(req, job.pos, chunk, slots, span)
+        if traced:
+            job.wall_s += span.end - span.begin
+            job.wall_end = span.end
+        job.pos += n
+        job.chunks += 1
+        if final:
+            job.first_token = tok
+            return True
+        return False
+
+    def _run_whole(self, req: Request, slots, span) -> int:
+        """Cold whole-prompt prefill: the classic (MHA-fused) path,
+        bit-identical to the pre-chunking engine."""
+        with RecordEvent("engine/feed_build", "serving"):
+            L = len(req.prompt)
             S = _pow2_bucket(L, self.prefill_bucket_min, None)
             toks = np.zeros((1, S), np.int32)
             toks[0, :L] = req.prompt
@@ -1336,56 +1395,56 @@ class _EngineCore:
                     "last_index": np.array([L - 1], np.int32)}
             if self.sampling is not None:
                 feed["sample_seeds"] = np.array([self._lane(req)], np.int32)
-            with RecordEvent("prefill", cat="serving"):
-                out = self.exe.run(
-                    self.prefill_prog, feed=feed,
-                    fetch_list=self.prefill_fetch, scope=self.scope)
-            tok = int(out[0][0])
-        else:
-            tok = self._run_chunk(req, job.pos, chunk, slots)
-        job.wall_s += time.perf_counter() - wall_t0
-        job.pos += n
-        job.chunks += 1
-        if final:
-            job.first_token = tok
-            return True
-        return False
+        if span.recording:
+            span.set(req=str(req.req_id), prompt_tokens=L, bucket=S)
+        with RecordEvent("prefill", cat="serving"):
+            out = self.exe.run(
+                self.prefill_prog, feed=feed,
+                fetch_list=self.prefill_fetch, scope=self.scope)
+        return int(out[0][0])
 
-    def _run_chunk(self, req: Request, pos: int, chunk, slots) -> int:
+    def _run_chunk(self, req: Request, pos: int, chunk, slots, span) -> int:
         """One prompt slice at offset ``pos``: the slice's K/V enter
         the pool, its attention runs over the pool-resident prefix plus
         itself through the request's block table.  Bucketed in slice
         length AND block-table width, so the jit cache stays bounded."""
-        prog, _feeds, fetch = self.chunk_prog_parts
-        n = len(chunk)
-        S = _pow2_bucket(n, self.prefill_bucket_min, None)
-        toks = np.zeros((1, S), np.int32)
-        toks[0, :n] = chunk
-        posf = np.minimum(pos + np.arange(S, dtype=np.int32),
-                          self.cfg.max_seq_len - 1)[None]
-        W = _pow2_bucket(self.kv.num_pages_of(req.req_id))
-        C = W * self.kv_config.page_size
-        tables = self.kv.block_table(req.req_id, W)
-        slot_map = np.full(S, self.kv_config.pad_slot, np.int32)
-        slot_map[:n] = slots
-        # causal + context-bound mask over the gathered pool window:
-        # slice position pos+i attends pool slots 0..pos+i (block-table
-        # order IS token order); everything else — tail garbage, padded
-        # table entries, padded slice rows — is masked
-        cols = np.arange(C, dtype=np.int64)[None, :]
-        rows = np.arange(S, dtype=np.int64)[:, None]
-        mask = np.where(cols <= pos + rows, 0.0, NEG_INF) \
-            .astype(np.float32)[None, None]
-        feed = {"tokens": toks, "positions": posf,
-                "attn_mask": mask, "slot_mapping": slot_map,
-                "chunk_tables": tables,
-                "last_index": np.array([n - 1], np.int32)}
-        if self.sampling is not None:
-            # the slice's token lands at absolute position pos+n; only
-            # the FINAL slice's draw is consumed (pos+n == len(prompt)),
-            # so its lane matches the monolithic prefill's exactly
-            feed["sample_seeds"] = np.array(
-                [rng_lane(self.sample_seed, req.req_id, pos + n)], np.int32)
+        with RecordEvent("engine/feed_build", "serving"):
+            prog, _feeds, fetch = self.chunk_prog_parts
+            n = len(chunk)
+            S = _pow2_bucket(n, self.prefill_bucket_min, None)
+            toks = np.zeros((1, S), np.int32)
+            toks[0, :n] = chunk
+            posf = np.minimum(pos + np.arange(S, dtype=np.int32),
+                              self.cfg.max_seq_len - 1)[None]
+            W = _pow2_bucket(self.kv.num_pages_of(req.req_id))
+            C = W * self.kv_config.page_size
+            tables = self.kv.block_table(req.req_id, W)
+            slot_map = np.full(S, self.kv_config.pad_slot, np.int32)
+            slot_map[:n] = slots
+            # causal + context-bound mask over the gathered pool window:
+            # slice position pos+i attends pool slots 0..pos+i (block-
+            # table order IS token order); everything else — tail
+            # garbage, padded table entries, padded slice rows — is
+            # masked
+            cols = np.arange(C, dtype=np.int64)[None, :]
+            rows = np.arange(S, dtype=np.int64)[:, None]
+            mask = np.where(cols <= pos + rows, 0.0, NEG_INF) \
+                .astype(np.float32)[None, None]
+            feed = {"tokens": toks, "positions": posf,
+                    "attn_mask": mask, "slot_mapping": slot_map,
+                    "chunk_tables": tables,
+                    "last_index": np.array([n - 1], np.int32)}
+            if self.sampling is not None:
+                # the slice's token lands at absolute position pos+n;
+                # only the FINAL slice's draw is consumed (pos+n ==
+                # len(prompt)), so its lane matches the monolithic
+                # prefill's exactly
+                feed["sample_seeds"] = np.array(
+                    [rng_lane(self.sample_seed, req.req_id, pos + n)],
+                    np.int32)
+        if span.recording:
+            span.set(req=str(req.req_id), prompt_tokens=n, bucket=S,
+                     table_width=W)
         with RecordEvent("prefill_chunk", cat="serving"):
             out = self.exe.run(prog, feed=feed,
                                fetch_list=fetch, scope=self.scope)
@@ -1397,60 +1456,70 @@ class _EngineCore:
         so the retry re-acquires them instead of recomputing."""
         self.kv.free_sequence(job.req.req_id)
 
-    def prefill(self, req: Request) -> Optional[int]:
-        """Write the prompt's K/V into the pool and return the first
-        generated token; None when the pool can't hold the prompt
-        (admission backpressure — with prefix caching off, nothing is
-        mutated; with it on, acquired prefix pages are released back to
-        the cache)."""
+    def prefill_job(self, req: Request) -> Optional[_PrefillJob]:
+        """Write the prompt's K/V into the pool and return the finished
+        job (its ``first_token`` is the first generated token; a traced
+        request's wall bounds ride on it); None when the pool can't
+        hold the prompt (admission backpressure — with prefix caching
+        off, nothing is mutated; with it on, acquired prefix pages are
+        released back to the cache)."""
         job = self.start_prefill(req)
         if self.advance_prefill(job) is None:
             if job.hit:
                 self.kv.free_sequence(req.req_id)
             return None
-        return job.first_token
+        return job
 
     def decode_batch(self, states: Sequence[_SeqState]) -> List[int]:
         """One continuous decode step for ``states`` (each sequence's
         pending token enters the pool, then attends at its true length).
         The caller guarantees page capacity.  Feed shapes bucket to the
         next power of two in batch AND block-table width, so the jit
-        cache is bounded by (log max_batch x log max_pages) shapes."""
+        cache is bounded by (log max_batch x log max_pages) shapes.
+        ``decode_wall`` keeps the ``engine/decode`` span's stamps for
+        the traced requests' decode-step spans."""
         B = len(states)
-        Bp = _pow2_bucket(max(B, 1))
-        toks = np.zeros(Bp, np.int32)
-        pos = np.zeros(Bp, np.int32)
-        slot_map = np.full(Bp, self.kv_config.pad_slot, np.int32)
-        ctx = np.ones(Bp, np.int32)
-        for i, st in enumerate(states):
-            toks[i] = st.last_token
-            pos[i] = min(self.kv.context_len(st.req.req_id),
-                         self.cfg.max_seq_len - 1)
-            slots = self.kv.append_tokens(st.req.req_id, 1,
-                                          tokens=[st.last_token])
-            assert slots is not None, "caller must reserve pages"
-            slot_map[i] = slots[0]
-            ctx[i] = self.kv.context_len(st.req.req_id)
-        self._apply_forks()
-        W = _pow2_bucket(max(
-            (self.kv.num_pages_of(st.req.req_id) for st in states),
-            default=1))
-        tables = np.zeros((Bp, W), np.int32)
-        for i, st in enumerate(states):
-            tables[i] = self.kv.block_table(st.req.req_id, W)
-        feed = {"tokens": toks, "positions": pos,
-                "block_tables": tables,
-                "context_lens": ctx, "slot_mapping": slot_map}
-        if self.sampling is not None:
-            lanes = np.zeros(Bp, np.int32)
-            for i, st in enumerate(states):
-                lanes[i] = self._lane(st.req)
-            feed["sample_seeds"] = lanes
-        with RecordEvent("decode_batch", cat="serving"):
-            out = self.exe.run(
-                self.decode_prog, feed=feed,
-                fetch_list=self.decode_fetch, scope=self.scope)
-        return [int(out[0][i]) for i in range(B)]
+        traced = any(st.req.trace is not None for st in states)
+        with RecordEvent("engine/decode", "serving", timed=traced) as span:
+            with RecordEvent("engine/feed_build", "serving"):
+                Bp = _pow2_bucket(max(B, 1))
+                toks = np.zeros(Bp, np.int32)
+                pos = np.zeros(Bp, np.int32)
+                slot_map = np.full(Bp, self.kv_config.pad_slot, np.int32)
+                ctx = np.ones(Bp, np.int32)
+                for i, st in enumerate(states):
+                    toks[i] = st.last_token
+                    pos[i] = min(self.kv.context_len(st.req.req_id),
+                                 self.cfg.max_seq_len - 1)
+                    slots = self.kv.append_tokens(st.req.req_id, 1,
+                                                  tokens=[st.last_token])
+                    assert slots is not None, "caller must reserve pages"
+                    slot_map[i] = slots[0]
+                    ctx[i] = self.kv.context_len(st.req.req_id)
+                self._apply_forks()
+                W = _pow2_bucket(max(
+                    (self.kv.num_pages_of(st.req.req_id) for st in states),
+                    default=1))
+                tables = np.zeros((Bp, W), np.int32)
+                for i, st in enumerate(states):
+                    tables[i] = self.kv.block_table(st.req.req_id, W)
+                feed = {"tokens": toks, "positions": pos,
+                        "block_tables": tables,
+                        "context_lens": ctx, "slot_mapping": slot_map}
+                if self.sampling is not None:
+                    lanes = np.zeros(Bp, np.int32)
+                    for i, st in enumerate(states):
+                        lanes[i] = self._lane(st.req)
+                    feed["sample_seeds"] = lanes
+            if span.recording:
+                span.set(batch=B, padded_batch=Bp, table_width=W)
+            with RecordEvent("decode_batch", cat="serving"):
+                out = self.exe.run(
+                    self.decode_prog, feed=feed,
+                    fetch_list=self.decode_fetch, scope=self.scope)
+            toks_out = [int(out[0][i]) for i in range(B)]
+        self.decode_wall = (span.begin, span.end)
+        return toks_out
 
     def verify_batch(self, items) -> List[List[int]]:
         """One spec-decode verify step: ``items`` is a list of
@@ -1464,61 +1533,75 @@ class _EngineCore:
         the first j draft tokens, so accept-prefix comparison against
         it is exact.  Feed shapes bucket in batch, chunk length AND
         block-table width (all powers of two), keeping the jit cache
-        bounded like every other serving form."""
-        prog, _feeds, fetch = self.verify_prog_parts
+        bounded like every other serving form.  ``decode_wall`` as in
+        ``decode_batch``."""
         B = len(items)
-        Bp = _pow2_bucket(max(B, 1))
-        S = _pow2_bucket(max(1 + len(d) for _, d in items))
-        toks = np.zeros((Bp, S), np.int32)
-        posf = np.zeros((Bp, S), np.int32)
-        slot_map = np.full(Bp * S, self.kv_config.pad_slot, np.int32)
-        pos0 = []
-        for i, (st, draft) in enumerate(items):
-            rid = st.req.req_id
-            chunk = [int(st.last_token)] + [int(t) for t in draft]
-            n = len(chunk)
-            p0 = self.kv.context_len(rid)
-            pos0.append(p0)
-            slots = self.kv.append_tokens(rid, n, tokens=chunk)
-            assert slots is not None, "caller must reserve pages"
-            toks[i, :n] = chunk
-            posf[i] = np.minimum(p0 + np.arange(S, dtype=np.int32),
-                                 self.cfg.max_seq_len - 1)
-            slot_map[i * S:i * S + n] = slots
-        self._apply_forks()
-        W = _pow2_bucket(max(
-            (self.kv.num_pages_of(st.req.req_id) for st, _ in items),
-            default=1))
-        C = W * self.kv_config.page_size
-        tables = np.zeros((Bp, W), np.int32)
-        for i, (st, _d) in enumerate(items):
-            tables[i] = self.kv.block_table(st.req.req_id, W)
-        # per-row causal + context-bound mask (the chunk form's rule,
-        # one slice per batch row); padded batch rows are fully masked
-        # — softmax over finite NEG_INF stays NaN-free by construction
-        cols = np.arange(C, dtype=np.int64)[None, None, :]
-        rows = np.arange(S, dtype=np.int64)[None, :, None]
-        base = np.asarray(pos0 + [-1] * (Bp - B),
-                          dtype=np.int64)[:, None, None]
-        mask = np.where(cols <= base + rows, 0.0, NEG_INF) \
-            .astype(np.float32)[:, None]
-        feed = {"tokens": toks, "positions": posf, "attn_mask": mask,
-                "slot_mapping": slot_map, "verify_tables": tables}
-        if self.sampling is not None:
-            lanes = np.zeros(Bp * S, np.int32)
-            for i, (st, draft) in enumerate(items):
-                for j in range(len(draft) + 1):
-                    # row j draws the token the sequence would emit at
-                    # absolute position len(prompt)+len(out)+j — the
-                    # SAME lane monolithic decode would use there
-                    lanes[i * S + j] = self._lane(st.req, j)
-            feed["sample_seeds"] = lanes
-        with RecordEvent("verify_batch", cat="serving"):
-            out = self.exe.run(prog, feed=feed,
-                               fetch_list=fetch, scope=self.scope)
-        flat = out[0]
-        return [[int(flat[i * S + j]) for j in range(len(d) + 1)]
-                for i, (_st, d) in enumerate(items)]
+        traced = any(st.req.trace is not None for st, _ in items)
+        with RecordEvent("engine/decode", "serving", timed=traced) as span:
+            with RecordEvent("engine/feed_build", "serving"):
+                prog, _feeds, fetch = self.verify_prog_parts
+                Bp = _pow2_bucket(max(B, 1))
+                S = _pow2_bucket(max(1 + len(d) for _, d in items))
+                toks = np.zeros((Bp, S), np.int32)
+                posf = np.zeros((Bp, S), np.int32)
+                slot_map = np.full(Bp * S, self.kv_config.pad_slot,
+                                   np.int32)
+                pos0 = []
+                for i, (st, draft) in enumerate(items):
+                    rid = st.req.req_id
+                    chunk = [int(st.last_token)] + [int(t) for t in draft]
+                    n = len(chunk)
+                    p0 = self.kv.context_len(rid)
+                    pos0.append(p0)
+                    slots = self.kv.append_tokens(rid, n, tokens=chunk)
+                    assert slots is not None, "caller must reserve pages"
+                    toks[i, :n] = chunk
+                    posf[i] = np.minimum(
+                        p0 + np.arange(S, dtype=np.int32),
+                        self.cfg.max_seq_len - 1)
+                    slot_map[i * S:i * S + n] = slots
+                self._apply_forks()
+                W = _pow2_bucket(max(
+                    (self.kv.num_pages_of(st.req.req_id)
+                     for st, _ in items), default=1))
+                C = W * self.kv_config.page_size
+                tables = np.zeros((Bp, W), np.int32)
+                for i, (st, _d) in enumerate(items):
+                    tables[i] = self.kv.block_table(st.req.req_id, W)
+                # per-row causal + context-bound mask (the chunk form's
+                # rule, one slice per batch row); padded batch rows are
+                # fully masked — softmax over finite NEG_INF stays
+                # NaN-free by construction
+                cols = np.arange(C, dtype=np.int64)[None, None, :]
+                rows = np.arange(S, dtype=np.int64)[None, :, None]
+                base = np.asarray(pos0 + [-1] * (Bp - B),
+                                  dtype=np.int64)[:, None, None]
+                mask = np.where(cols <= base + rows, 0.0, NEG_INF) \
+                    .astype(np.float32)[:, None]
+                feed = {"tokens": toks, "positions": posf,
+                        "attn_mask": mask, "slot_mapping": slot_map,
+                        "verify_tables": tables}
+                if self.sampling is not None:
+                    lanes = np.zeros(Bp * S, np.int32)
+                    for i, (st, draft) in enumerate(items):
+                        for j in range(len(draft) + 1):
+                            # row j draws the token the sequence would
+                            # emit at absolute position
+                            # len(prompt)+len(out)+j — the SAME lane
+                            # monolithic decode would use there
+                            lanes[i * S + j] = self._lane(st.req, j)
+                    feed["sample_seeds"] = lanes
+            if span.recording:
+                span.set(batch=B, padded_batch=Bp, table_width=W,
+                         chunk=S)
+            with RecordEvent("verify_batch", cat="serving"):
+                out = self.exe.run(prog, feed=feed,
+                                   fetch_list=fetch, scope=self.scope)
+            flat = out[0]
+            targets = [[int(flat[i * S + j]) for j in range(len(d) + 1)]
+                       for i, (_st, d) in enumerate(items)]
+        self.decode_wall = (span.begin, span.end)
+        return targets
 
     def _reference_run(self, seq: Sequence[int], fetch_list):
         """One full-recompute step of the reference program over
@@ -1724,25 +1807,43 @@ class ServingEngine:
         admit (in policy order, up to the token budget and pool
         capacity), prefill the admissions, decode every running
         sequence once, evict finishes.  Returns this step's emitted
-        tokens."""
-        events: List[StepEvent] = []
+        tokens.
+
+        Spans (profiler.RecordEvent, lane "serving"): ``engine/step``
+        holds ``engine/schedule`` (shed, order, admission checks,
+        preemption), one ``engine/prefill`` a prefill or chunk and one
+        ``engine/decode`` (both opened by the core, around feed
+        building and the program call), and ``engine/emit`` (token
+        append, latency observation, finish bookkeeping)."""
         self._step_no += 1
-        # chaos serving faults (pool_spike / req_burst bookkeeping) —
-        # a single cached None check when FLAGS_chaos is unset
-        chaos.on_serving_step(self, self._step_no)
-        # --- shedding: the policy gives up queued requests whose SLO
-        # is no longer reachable BEFORE paying admission for them ------
-        for req in self.policy.shed(self, now):
-            self._shed(req, now)
-        # --- admission: every decode step takes new work, in policy
-        # order (fifo: submit order — order() is a no-op) --------------
-        self.policy.order(self, now)
-        # settle last step's verify debt: tokens a verify call emitted
-        # beyond one-per-sequence charge THIS step's budget, so spec
-        # decode pays accepted+1 exactly like the monolithic paths
-        # (_spec_debt is always 0 with spec off — the term vanishes)
-        budget = self.token_budget - len(self.running) - self._spec_debt
-        self._spec_debt = 0
+        with RecordEvent("engine/step", "serving") as span:
+            if span.recording:
+                span.set(step=self._step_no, running=len(self.running),
+                         waiting=len(self.waiting))
+            return self._step(now)
+
+    def _step(self, now: float) -> List[StepEvent]:
+        events: List[StepEvent] = []
+        handles = _TM.current()
+        with RecordEvent("engine/schedule", "serving"):
+            # chaos serving faults (pool_spike / req_burst bookkeeping)
+            # — a single cached None check when FLAGS_chaos is unset
+            chaos.on_serving_step(self, self._step_no)
+            # --- shedding: the policy gives up queued requests whose
+            # SLO is no longer reachable BEFORE paying admission for
+            # them ---------------------------------------------------
+            for req in self.policy.shed(self, now):
+                self._shed(req, now)
+            # --- admission: every decode step takes new work, in
+            # policy order (fifo: submit order — order() is a no-op) --
+            self.policy.order(self, now)
+            # settle last step's verify debt: tokens a verify call
+            # emitted beyond one-per-sequence charge THIS step's
+            # budget, so spec decode pays accepted+1 exactly like the
+            # monolithic paths (_spec_debt is always 0 with spec off —
+            # the term vanishes)
+            budget = self.token_budget - len(self.running) - self._spec_debt
+            self._spec_debt = 0
         prefilled_this_step = 0
         # --- in-flight chunked prefill: one budget-sized slice per
         # step, ahead of new admissions (it reached the head first);
@@ -1757,23 +1858,25 @@ class ServingEngine:
                     budget)
             if n > 0:
                 r = self.core.advance_prefill(job, n)
-                if r is None:
-                    # pool can no longer cover the slice: release the
-                    # pages (the prefix cache keeps finished slices
-                    # warm) and requeue at the head
-                    self.core.abort_prefill(job)
-                    self.waiting.insert(0, job.req)
-                    self._prefill_job = None
-                    _trace_backpressure(job.req, "prefill_backpressure")
-                else:
-                    # the completing slice also emits the first output
-                    # token — charge its +1 like the monolithic paths
-                    budget -= n + (1 if r else 0)
-                    prefilled_this_step += n
-                    self._count_prefill(n, job)
-                    if r:
+                with RecordEvent("engine/emit", "serving"):
+                    if r is None:
+                        # pool can no longer cover the slice: release
+                        # the pages (the prefix cache keeps finished
+                        # slices warm) and requeue at the head
+                        self.core.abort_prefill(job)
+                        self.waiting.insert(0, job.req)
                         self._prefill_job = None
-                        self._admit_job(job, now, events)
+                        _trace_backpressure(job.req, "prefill_backpressure")
+                    else:
+                        # the completing slice also emits the first
+                        # output token — charge its +1 like the
+                        # monolithic paths
+                        budget -= n + (1 if r else 0)
+                        prefilled_this_step += n
+                        self._count_prefill(n, job)
+                        if r:
+                            self._prefill_job = None
+                            self._admit_job(job, now, events)
         while (self.waiting and len(self.running) < self.max_batch
                and self._prefill_job is None):
             req = self.waiting[0]
@@ -1781,66 +1884,73 @@ class ServingEngine:
             if not self.prefill_chunk and not self.kv.prefix_cache:
                 # the exact pre-feature (r18) admission path — pinned
                 # byte-identical when both flags are off
-                if cost > budget:
-                    break
-                if not self._admission_fits(req):
-                    _trace_backpressure(req, "admission_backpressure")
-                    break  # pool backpressure: retry next step
-                wall0 = time.perf_counter()
-                tok = self.core.prefill(req)
-                if tok is None:
+                with RecordEvent("engine/schedule", "serving"):
+                    if cost > budget:
+                        break
+                    if not self._admission_fits(req):
+                        _trace_backpressure(req, "admission_backpressure")
+                        break  # pool backpressure: retry next step
+                job = self.core.prefill_job(req)
+                if job is None:
                     _trace_backpressure(req, "prefill_backpressure")
                     break  # pool backpressure: retry next step
-                _trace_admit(req, now, wall0, time.perf_counter())
-                self.waiting.pop(0)
-                budget -= cost
-                prefilled_this_step += len(req.prompt)
-                req.admitted_at = now if req.admitted_at is None else \
-                    req.admitted_at
-                self.stats["admitted"] += 1
-                self.stats["prefill_tokens"] += len(req.prompt)
-                tm.counter("serving_admitted_total",
-                           "requests admitted (prefilled)").inc()
-                tm.counter("serving_prefill_tokens_total",
-                           "prompt tokens prefilled").inc(len(req.prompt))
-                if is_profiler_enabled():
-                    instant_event("admit", cat="serving",
-                                  args={"req": str(req.req_id),
-                                        "prompt": len(req.prompt)})
-                st = _SeqState(req, tok)
-                req.out_tokens.append(tok)
-                _observe_token(req, now)
-                if self.core._finished(req, tok):
-                    events.append(self._finish(st, tok, now))
-                else:
-                    events.append(StepEvent(req.req_id, tok, False, now))
-                    self.running.append(st)
+                with RecordEvent("engine/emit", "serving"):
+                    tok = job.first_token
+                    _trace_admit(req, now, job)
+                    self.waiting.pop(0)
+                    budget -= cost
+                    prefilled_this_step += len(req.prompt)
+                    req.admitted_at = now if req.admitted_at is None else \
+                        req.admitted_at
+                    self.stats["admitted"] += 1
+                    self.stats["prefill_tokens"] += len(req.prompt)
+                    handles.admitted.inc()
+                    handles.prefill_tokens.inc(len(req.prompt))
+                    if is_profiler_enabled():
+                        instant_event("admit", cat="serving",
+                                      args={"req": str(req.req_id),
+                                            "prompt": len(req.prompt)})
+                    st = _SeqState(req, tok)
+                    req.out_tokens.append(tok)
+                    _observe_token(req, now)
+                    if self.core._finished(req, tok):
+                        events.append(self._finish(st, tok, now))
+                    else:
+                        events.append(
+                            StepEvent(req.req_id, tok, False, now))
+                        self.running.append(st)
                 continue
             # feature path: prefix-cache hits shrink the admission cost
             # to the COMPUTED suffix, and long suffixes go through the
             # chunked path (one slice per step)
-            # gate with a READ-ONLY hit estimate first: acquiring and
-            # releasing prefix pages on every blocked step would churn
-            # the allocator (and re-hash the prompt) for nothing
-            est_hit = self.kv.match_prefix(req.prompt[:-1])[0] \
-                if self.kv.prefix_cache and len(req.prompt) > 1 else 0
-            if not self._admission_fits(req, len(req.prompt) - est_hit):
-                _trace_backpressure(req, "admission_backpressure")
-                break
-            job = self.core.start_prefill(req)
-            remaining = len(req.prompt) - job.pos
-            # chunk whenever the remainder exceeds the chunk budget OR
-            # can't fit this step's token budget whole — the second arm
-            # is what keeps a prompt with remaining in [budget,
-            # prefill_chunk] schedulable instead of head-of-line
-            # blocking forever (submit waived the budget reject)
-            if self.prefill_chunk and (remaining > self.prefill_chunk
-                                       or remaining + 1 > budget):
-                n = min(self.prefill_chunk, remaining, budget)
-                if n <= 0:
+            with RecordEvent("engine/schedule", "serving"):
+                # gate with a READ-ONLY hit estimate first: acquiring
+                # and releasing prefix pages on every blocked step
+                # would churn the allocator (and re-hash the prompt)
+                # for nothing
+                est_hit = self.kv.match_prefix(req.prompt[:-1])[0] \
+                    if self.kv.prefix_cache and len(req.prompt) > 1 else 0
+                if not self._admission_fits(req, len(req.prompt) - est_hit):
+                    _trace_backpressure(req, "admission_backpressure")
+                    break
+                job = self.core.start_prefill(req)
+                remaining = len(req.prompt) - job.pos
+                # chunk whenever the remainder exceeds the chunk budget
+                # OR can't fit this step's token budget whole — the
+                # second arm is what keeps a prompt with remaining in
+                # [budget, prefill_chunk] schedulable instead of
+                # head-of-line blocking forever (submit waived the
+                # budget reject)
+                chunked = bool(self.prefill_chunk) and (
+                    remaining > self.prefill_chunk
+                    or remaining + 1 > budget)
+                n = min(self.prefill_chunk, remaining, budget) \
+                    if chunked else remaining
+                if (n <= 0) if chunked else (remaining + 1 > budget):
                     self.core.abort_prefill(job)
                     break  # wait for budget headroom
-                r = self.core.advance_prefill(job, n)
+            r = self.core.advance_prefill(job, n if chunked else None)
+            with RecordEvent("engine/emit", "serving"):
                 if r is None:
                     self.core.abort_prefill(job)
                     _trace_backpressure(req, "prefill_backpressure")
@@ -1851,70 +1961,54 @@ class ServingEngine:
                 self._count_prefill(n, job)
                 if r:
                     self._admit_job(job, now, events)
-                    continue
-                self._prefill_job = job
-                # one chunked prefill in flight at a time: admission
-                # resumes when it completes (loop condition above)
-            else:
-                if remaining + 1 > budget:
-                    self.core.abort_prefill(job)
-                    break
-                r = self.core.advance_prefill(job)
-                if r is None:
-                    self.core.abort_prefill(job)
-                    _trace_backpressure(req, "prefill_backpressure")
-                    break
-                self.waiting.pop(0)
-                budget -= remaining + 1
-                prefilled_this_step += remaining
-                self._count_prefill(remaining, job)
-                self._admit_job(job, now, events)
+                else:
+                    self._prefill_job = job
+                    # one chunked prefill in flight at a time: admission
+                    # resumes when it completes (loop condition above)
         # --- preemption: decoding adds one token per running seq --------
-        while self.running and not self._can_grow_all():
-            # fifo: index -1 (youngest); slo_aware: least lost work
-            victim = self.running.pop(self.policy.victim_index(self.running))
-            self.kv.free_sequence(victim.req.req_id)
-            victim.req.out_tokens = []
-            victim.req._tm_last = None
-            victim.req._tm_gaps = []
-            victim.req.preemptions += 1
-            _trace_preempt(victim.req, now)
-            self.waiting.insert(0, victim.req)
-            self.stats["preempted"] += 1
-            tm.counter("serving_preempted_total",
-                       "sequences preempted to the waiting queue on "
-                       "pool exhaustion").inc()
-            if is_profiler_enabled():
-                instant_event("preempt", cat="serving",
-                              args={"req": str(victim.req.req_id)})
+        with RecordEvent("engine/schedule", "serving"):
+            while self.running and not self._can_grow_all():
+                # fifo: index -1 (youngest); slo_aware: least lost work
+                victim = self.running.pop(
+                    self.policy.victim_index(self.running))
+                self.kv.free_sequence(victim.req.req_id)
+                victim.req.out_tokens = []
+                victim.req._tm_last = None
+                victim.req._tm_gaps = []
+                victim.req.preemptions += 1
+                _trace_preempt(victim.req, now)
+                self.waiting.insert(0, victim.req)
+                self.stats["preempted"] += 1
+                handles.preempted.inc()
+                if is_profiler_enabled():
+                    instant_event("preempt", cat="serving",
+                                  args={"req": str(victim.req.req_id)})
         # --- decode ------------------------------------------------------
         if self.running and self.spec_k:
             events.extend(self._spec_decode_step(now))
         elif self.running:
             chaos.on_decode_step()
-            wall0 = time.perf_counter()
             toks = self.core.decode_batch(self.running)
-            self.stats["decode_steps"] += 1
-            self.stats["decode_tokens"] += len(self.running)
-            _trace_decode(self.running, toks, now, wall0,
-                          time.perf_counter(), self.stats["decode_steps"],
-                          tp=self.core.tp)
-            tm.counter("serving_decode_steps_total",
-                       "batched decode steps run").inc()
-            tm.counter("serving_decode_tokens_total",
-                       "tokens produced by decode steps").inc(
-                           len(self.running))
-            still = []
-            for st, tok in zip(self.running, toks):
-                st.req.out_tokens.append(tok)
-                st.last_token = tok
-                _observe_token(st.req, now)
-                if self.core._finished(st.req, tok):
-                    events.append(self._finish(st, tok, now))
-                else:
-                    events.append(StepEvent(st.req.req_id, tok, False, now))
-                    still.append(st)
-            self.running = still
+            with RecordEvent("engine/emit", "serving"):
+                self.stats["decode_steps"] += 1
+                self.stats["decode_tokens"] += len(self.running)
+                _trace_decode(self.running, toks, now,
+                              *self.core.decode_wall,
+                              self.stats["decode_steps"], tp=self.core.tp)
+                handles.decode_steps.inc()
+                handles.decode_tokens.inc(len(self.running))
+                still = []
+                for st, tok in zip(self.running, toks):
+                    st.req.out_tokens.append(tok)
+                    st.last_token = tok
+                    _observe_token(st.req, now)
+                    if self.core._finished(st.req, tok):
+                        events.append(self._finish(st, tok, now))
+                    else:
+                        events.append(
+                            StepEvent(st.req.req_id, tok, False, now))
+                        still.append(st)
+                self.running = still
         self.stats["max_prefill_step_tokens"] = max(
             self.stats["max_prefill_step_tokens"], prefilled_this_step)
         return events
@@ -1941,41 +2035,47 @@ class ServingEngine:
         zero-accept step emits exactly one token per sequence —
         baseline step count and budget accounting."""
         events: List[StepEvent] = []
+        handles = _TM.current()
         chaos.on_decode_step()
         batch = self.running
-        # page capacity: the preemption loop guaranteed one token of
-        # growth per sequence; drafts spend only what remains AFTER
-        # those base reservations, each shrinking until it fits (a
-        # draft can never steal another sequence's guaranteed token)
-        bases = [self.kv.pages_needed(st.req.req_id, 1)
-                 + self.kv.cow_fork_need(st.req.req_id, 1)
-                 for st in batch]
-        avail = self.kv.num_free_pages - sum(bases)
-        drafts: List[List[int]] = []
-        for st, base in zip(batch, bases):
-            req = st.req
-            # never draft past max_new_tokens - 1: the verify's bonus
-            # token always lands, so a full accept finishes exactly AT
-            # the cap, never beyond it
-            cap = min(self.spec_k,
-                      req.max_new_tokens - len(req.out_tokens) - 1)
-            d = [int(t) for t in self.proposer.propose(req, cap)][:cap] \
-                if cap > 0 else []
-            while d:
-                extra = (self.kv.pages_needed(req.req_id, 1 + len(d))
-                         + self.kv.cow_fork_need(req.req_id, 1 + len(d))
-                         - base)
-                if extra <= avail:
-                    avail -= extra
-                    break
-                d.pop()
-            drafts.append(d)
-        wall0 = time.perf_counter()
+        with RecordEvent("engine/draft", "serving"):
+            # page capacity: the preemption loop guaranteed one token of
+            # growth per sequence; drafts spend only what remains AFTER
+            # those base reservations, each shrinking until it fits (a
+            # draft can never steal another sequence's guaranteed token)
+            bases = [self.kv.pages_needed(st.req.req_id, 1)
+                     + self.kv.cow_fork_need(st.req.req_id, 1)
+                     for st in batch]
+            avail = self.kv.num_free_pages - sum(bases)
+            drafts: List[List[int]] = []
+            for st, base in zip(batch, bases):
+                req = st.req
+                # never draft past max_new_tokens - 1: the verify's bonus
+                # token always lands, so a full accept finishes exactly AT
+                # the cap, never beyond it
+                cap = min(self.spec_k,
+                          req.max_new_tokens - len(req.out_tokens) - 1)
+                d = [int(t) for t in self.proposer.propose(req, cap)][:cap] \
+                    if cap > 0 else []
+                while d:
+                    extra = (self.kv.pages_needed(req.req_id, 1 + len(d))
+                             + self.kv.cow_fork_need(req.req_id, 1 + len(d))
+                             - base)
+                    if extra <= avail:
+                        avail -= extra
+                        break
+                    d.pop()
+                drafts.append(d)
         targets = self.core.verify_batch(list(zip(batch, drafts)))
-        wall1 = time.perf_counter()
+        with RecordEvent("engine/emit", "serving"):
+            self._emit_verified(batch, drafts, targets, now, events,
+                                handles)
+        return events
+
+    def _emit_verified(self, batch, drafts, targets, now, events, handles):
+        """Accept, emit and account one verify call's tokens."""
         self.stats["decode_steps"] += 1
-        tm.counter("serving_decode_steps_total",
-                   "batched decode steps run").inc()
+        handles.decode_steps.inc()
         # per sequence: accept while the target agrees with the draft,
         # then pre-truncate the emission at max_new_tokens / EOS so the
         # token stream ends exactly where monolithic decode would stop
@@ -1990,8 +2090,8 @@ class ServingEngine:
             if self.cfg.eos_id in emit:
                 emit = emit[:emit.index(self.cfg.eos_id) + 1]
             emits.append(emit)
-        _trace_decode(batch, [e[-1] for e in emits], now, wall0, wall1,
-                      self.stats["decode_steps"],
+        _trace_decode(batch, [e[-1] for e in emits], now,
+                      *self.core.decode_wall, self.stats["decode_steps"],
                       spec=[(len(d), a) for d, a in zip(drafts, accepts)],
                       tp=self.core.tp)
         still = []
@@ -2021,18 +2121,12 @@ class ServingEngine:
         self.stats["spec_proposed"] += n_prop
         self.stats["spec_accepted"] += n_acc
         self._spec_debt = used - len(batch)
-        tm.counter("serving_decode_tokens_total",
-                   "tokens produced by decode steps").inc(used)
-        tm.counter("spec_proposed_total",
-                   "draft tokens proposed to spec-decode verify").inc(n_prop)
-        tm.counter("spec_accepted_total",
-                   "draft tokens accepted by spec-decode verify").inc(n_acc)
+        handles.decode_tokens.inc(used)
+        handles.spec_proposed.inc(n_prop)
+        handles.spec_accepted.inc(n_acc)
         if self.stats["spec_proposed"]:
-            tm.gauge("spec_accept_rate",
-                     "cumulative spec-decode draft acceptance rate").set(
-                         self.stats["spec_accepted"]
-                         / self.stats["spec_proposed"])
-        return events
+            handles.spec_accept_rate.set(
+                self.stats["spec_accepted"] / self.stats["spec_proposed"])
 
     def _count_prefill(self, n: int, job: _PrefillJob):
         """Feature-path prefill accounting: ``prefill_tokens`` counts
@@ -2042,24 +2136,17 @@ class ServingEngine:
         self.stats["prefill_chunks"] += 1
         if job.chunks == 1 and job.hit:
             self.stats["prefill_hit_tokens"] += job.hit
-        tm.counter("serving_prefill_tokens_total",
-                   "prompt tokens prefilled").inc(n)
+        _TM.current().prefill_tokens.inc(n)
 
     def _admit_job(self, job: _PrefillJob, now: float, events: list):
         """Completed prefill job -> running sequence (the feature-path
-        twin of the inline r18 admission bookkeeping).  The prefill
-        span's wall bounds are synthesized from the job's accumulated
-        slice time, so a 5-chunk prefill reports 5 chunks' worth of
-        wall, not the last slice's."""
+        twin of the inline r18 admission bookkeeping)."""
         req, tok = job.req, job.first_token
-        wall1 = time.perf_counter()
-        _trace_admit(req, now, wall1 - job.wall_s, wall1,
-                     cached=job.hit, chunks=job.chunks)
+        _trace_admit(req, now, job, cached=job.hit, chunks=job.chunks)
         req.admitted_at = now if req.admitted_at is None else \
             req.admitted_at
         self.stats["admitted"] += 1
-        tm.counter("serving_admitted_total",
-                   "requests admitted (prefilled)").inc()
+        _TM.current().admitted.inc()
         if is_profiler_enabled():
             instant_event("admit", cat="serving",
                           args={"req": str(req.req_id),
@@ -2136,8 +2223,7 @@ class ServingEngine:
         self.kv.free_sequence(st.req.req_id)
         st.req.finished_at = now
         self.stats["finished"] += 1
-        tm.counter("serving_finished_total",
-                   "requests finished (pages evicted on finish)").inc()
+        _TM.current().finished.inc()
         _trace_finish(st.req, now)
         if is_profiler_enabled():
             instant_event("evict", cat="serving",
@@ -2203,6 +2289,14 @@ class StaticBatchingEngine:
         return bool(self.waiting or self.group)
 
     def step(self, now: float = 0.0) -> List[StepEvent]:
+        """One iteration, under the continuous engine's span names
+        (``engine/step`` holding the core's ``engine/prefill`` /
+        ``engine/decode`` and ``engine/emit``)."""
+        _TM.current()
+        with RecordEvent("engine/step", "serving"):
+            return self._step(now)
+
+    def _step(self, now: float) -> List[StepEvent]:
         events: List[StepEvent] = []
         if not self.group:
             self._reserved_pages = 0
@@ -2213,47 +2307,50 @@ class StaticBatchingEngine:
                         > self.core.kv_config.num_pages:
                     break  # group is as large as worst-case capacity allows
                 self._reserved_pages += worst
-                wall0 = time.perf_counter()
-                tok = self.core.prefill(req)
-                if tok is None:
+                job = self.core.prefill_job(req)
+                if job is None:
                     break
-                _trace_admit(req, now, wall0, time.perf_counter())
-                self.waiting.pop(0)
-                req.admitted_at = now
-                self.stats["admitted"] += 1
-                self.stats["prefill_tokens"] += len(req.prompt)
-                st = _SeqState(req, tok)
-                req.out_tokens.append(tok)
-                _observe_token(req, now)
-                if self.core._finished(req, tok):
-                    self.core.kv.free_sequence(req.req_id)
-                    req.finished_at = now
-                    self.stats["finished"] += 1
-                    _trace_finish(req, now)
-                    events.append(StepEvent(req.req_id, tok, True, now))
-                else:
-                    events.append(StepEvent(req.req_id, tok, False, now))
-                    self.group.append(st)
+                with RecordEvent("engine/emit", "serving"):
+                    tok = job.first_token
+                    _trace_admit(req, now, job)
+                    self.waiting.pop(0)
+                    req.admitted_at = now
+                    self.stats["admitted"] += 1
+                    self.stats["prefill_tokens"] += len(req.prompt)
+                    st = _SeqState(req, tok)
+                    req.out_tokens.append(tok)
+                    _observe_token(req, now)
+                    if self.core._finished(req, tok):
+                        self.core.kv.free_sequence(req.req_id)
+                        req.finished_at = now
+                        self.stats["finished"] += 1
+                        _trace_finish(req, now)
+                        events.append(StepEvent(req.req_id, tok, True, now))
+                    else:
+                        events.append(
+                            StepEvent(req.req_id, tok, False, now))
+                        self.group.append(st)
             return events
-        wall0 = time.perf_counter()
         toks = self.core.decode_batch(self.group)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_tokens"] += len(self.group)
-        _trace_decode(self.group, toks, now, wall0, time.perf_counter(),
-                      self.stats["decode_steps"], tp=self.core.tp)
-        still = []
-        for st, tok in zip(self.group, toks):
-            st.req.out_tokens.append(tok)
-            st.last_token = tok
-            _observe_token(st.req, now)
-            if self.core._finished(st.req, tok):
-                self.core.kv.free_sequence(st.req.req_id)
-                st.req.finished_at = now
-                self.stats["finished"] += 1
-                _trace_finish(st.req, now)
-                events.append(StepEvent(st.req.req_id, tok, True, now))
-            else:
-                events.append(StepEvent(st.req.req_id, tok, False, now))
-                still.append(st)
-        self.group = still
+        with RecordEvent("engine/emit", "serving"):
+            self.stats["decode_steps"] += 1
+            self.stats["decode_tokens"] += len(self.group)
+            _trace_decode(self.group, toks, now, *self.core.decode_wall,
+                          self.stats["decode_steps"], tp=self.core.tp)
+            still = []
+            for st, tok in zip(self.group, toks):
+                st.req.out_tokens.append(tok)
+                st.last_token = tok
+                _observe_token(st.req, now)
+                if self.core._finished(st.req, tok):
+                    self.core.kv.free_sequence(st.req.req_id)
+                    st.req.finished_at = now
+                    self.stats["finished"] += 1
+                    _trace_finish(st.req, now)
+                    events.append(StepEvent(st.req.req_id, tok, True, now))
+                else:
+                    events.append(
+                        StepEvent(st.req.req_id, tok, False, now))
+                    still.append(st)
+            self.group = still
         return events

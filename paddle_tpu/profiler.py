@@ -28,6 +28,16 @@ Perfetto file shows training, serving and RPC activity side by side
 Zero-duration decisions (admit/preempt/evict, chaos drops) are
 *instant* events (``ph: "i"``).
 
+One span primitive, two sinks (PR 24): a ``RecordEvent`` is recorded while
+this profiler is enabled OR while a JAX profiler session is active
+(``jax.profiler.start_trace``, which ``enable_profiler(trace_dir=...)`` also
+starts).  During a session the span is, besides its record here, a
+``jax.profiler.TraceAnnotation`` named ``pt/<name>`` carrying its ``args``:
+it lies in the ``.xplane.pb`` beside the device's operations, on their clock.
+With neither on, entering a span is that one check.  Completed events live in
+a bounded ring; ``dropped_events()`` counts what an hour-long session pushed
+out.
+
 Closing the calibration loop: ``disable_profiler`` feeds the measured
 ``executor_run`` step time (and the per-op means of the summary) into
 ``utils.cost_model.set_measured_profile``, so the next
@@ -36,19 +46,22 @@ rates instead of the hand-set defaults.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 __all__ = [
     "RecordEvent", "record_event", "instant_event", "counter_event",
     "complete_event",
     "enable_profiler", "disable_profiler", "reset_profiler",
     "start_profiler", "stop_profiler", "profiler", "is_profiler_enabled",
-    "get_events", "npu_profiler", "cuda_profiler", "LANES",
+    "get_events", "dropped_events", "npu_profiler", "cuda_profiler", "LANES",
 ]
 
 #: lane -> chrome-trace pid.  Lanes not listed get pids allocated past
@@ -62,17 +75,31 @@ _state = threading.local()
 _GLOBAL_LOCK = threading.Lock()
 _ENABLED = False
 _TRACE_DIR: Optional[str] = None
-_EVENTS: List[dict] = []  # completed events: name, cat, ts, dur, tid, depth
+# completed events: name, cat, ts, dur, tid, depth, parent (+ args).  A
+# ring of the newest 65,536: a serving step records some 25 spans, so it
+# holds the last half hour of a busy engine
+_EVENTS: Deque[dict] = collections.deque(maxlen=1 << 16)
+_DROPPED = 0  # events the ring pushed out since the last reset
+#: a JAX profiler session is active (about 40 ns a call)
+_session_active = _Annotation.is_enabled
 #: every thread's live event stack, keyed by thread ident — the
 #: thread-local fast path aliases these lists.  Kept globally so
 #: reset_profiler can clear a stack left behind by a thread that died
 #: (or errored) mid-event: before r13 such a leftover skewed ``depth``
 #: for the next session on a reused (pool) thread, and the dead
 #: thread's stack leaked.
-_STACKS: Dict[int, List[dict]] = {}
+_STACKS: Dict[int, List[str]] = {}
 
 
-def _stack() -> List[dict]:
+def _append(ev: dict):
+    global _DROPPED
+    with _GLOBAL_LOCK:
+        if len(_EVENTS) == _EVENTS.maxlen:
+            _DROPPED += 1
+        _EVENTS.append(ev)
+
+
+def _stack() -> List[str]:
     stack = getattr(_state, "stack", None)
     if stack is None:
         stack = _state.stack = []
@@ -88,40 +115,81 @@ def is_profiler_enabled() -> bool:
 class RecordEvent:
     """RAII host-event marker (reference: platform/profiler.h RecordEvent;
     used as ``with profiler.RecordEvent("fwd"): ...``).  Nested events
-    form a tree via depth; no-op when the profiler is off.  ``cat``
-    picks the timeline lane ("host" unless a runtime says otherwise)."""
+    form a tree via ``depth`` and ``parent`` (the enclosing span of this
+    thread); ``cat`` picks the timeline lane ("host" unless a runtime
+    says otherwise); ``args`` is a small dict of ids and counts, never
+    arrays.
 
-    def __init__(self, name: str, cat: str = "host"):
+    Recorded while the profiler is enabled or a JAX profiler session is
+    active (then also as the ``TraceAnnotation`` ``pt/<name>``); a no-op
+    otherwise.  ``begin``/``end`` are the span's own ``perf_counter``
+    stamps: a caller that needs the times of the call it wraps reads
+    them here instead of timing the call again, and passes
+    ``timed=True`` where it needs them even while nothing records."""
+
+    __slots__ = ("name", "cat", "args", "begin", "end", "recording",
+                 "_timed", "_note")
+
+    def __init__(self, name: str, cat: str = "host",
+                 args: Optional[dict] = None, timed: bool = False):
         self.name = name
         self.cat = cat
-        self._begin = None
+        self.args = args
+        self.begin = self.end = None
+        self.recording = False
+        self._timed = timed
+        self._note = None
+
+    def set(self, **args):
+        """Add attributes known only inside the span (a byte count, a
+        bucket).  Callers guard with ``if span.recording:`` so the off
+        path builds nothing."""
+        self.args = {**self.args, **args} if self.args else args
+        if self._note is not None:
+            self._note.set_metadata(**args)
 
     def __enter__(self):
-        if _ENABLED:
-            self._begin = time.perf_counter()
-            _stack().append({"name": self.name})
+        session = _session_active()
+        if _ENABLED or session:
+            self.recording = True
+            if session:
+                self._note = _Annotation("pt/" + self.name,
+                                         **(self.args or {}))
+                self._note.__enter__()
+            _stack().append(self.name)
+            self.begin = time.perf_counter()
+        elif self._timed:
+            self.begin = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        if self._begin is None:
+        if not self.recording:
+            if self._timed:
+                self.end = time.perf_counter()
             return False
-        begin, self._begin = self._begin, None
-        end = time.perf_counter()
+        self.end = end = time.perf_counter()
+        self.recording = False
+        note, self._note = self._note, None
+        if note is not None:
+            note.__exit__(*exc)
         stack = _stack()
         if stack:
             # empty = reset_profiler cleared this thread's stack while
             # the event was in flight (cross-thread reset): record the
             # completion at depth 0 instead of crashing the worker
             stack.pop()
-        with _GLOBAL_LOCK:
-            _EVENTS.append({
-                "name": self.name,
-                "cat": self.cat,
-                "ts": begin,
-                "dur": end - begin,
-                "tid": threading.get_ident(),
-                "depth": len(stack),
-            })
+        ev = {
+            "name": self.name,
+            "cat": self.cat,
+            "ts": self.begin,
+            "dur": end - self.begin,
+            "tid": threading.get_ident(),
+            "depth": len(stack),
+            "parent": stack[-1] if stack else None,
+        }
+        if self.args:
+            ev["args"] = dict(self.args)
+        _append(ev)
         return False
 
 
@@ -145,8 +213,7 @@ def instant_event(name: str, cat: str = "host",
     }
     if args:
         ev["args"] = dict(args)
-    with _GLOBAL_LOCK:
-        _EVENTS.append(ev)
+    _append(ev)
 
 
 def counter_event(name: str, values: dict, cat: str = "memory",
@@ -165,8 +232,7 @@ def counter_event(name: str, values: dict, cat: str = "memory",
         "dur": 0.0, "tid": threading.get_ident(), "depth": 0, "ph": "C",
         "args": {k: float(v) for k, v in values.items()},
     }
-    with _GLOBAL_LOCK:
-        _EVENTS.append(ev)
+    _append(ev)
 
 
 def complete_event(name: str, cat: str = "host", ts: float = 0.0,
@@ -187,8 +253,7 @@ def complete_event(name: str, cat: str = "host", ts: float = 0.0,
     }
     if args:
         ev["args"] = dict(args)
-    with _GLOBAL_LOCK:
-        _EVENTS.append(ev)
+    _append(ev)
 
 
 def enable_profiler(state: str = "All", trace_dir: Optional[str] = None):
@@ -216,8 +281,10 @@ def reset_profiler():
     AND every thread's live event stack — a stack abandoned mid-event
     (crashed thread, unexited manual ``__enter__``) must not skew depth
     for the next session (regression-tested)."""
+    global _DROPPED
     with _GLOBAL_LOCK:
         _EVENTS.clear()
+        _DROPPED = 0
         live = {t.ident for t in threading.enumerate()}
         for ident in list(_STACKS):
             _STACKS[ident].clear()     # aliased by that thread's local
@@ -267,9 +334,15 @@ def stop_profiler(sorted_key: Optional[str] = None,
 
 
 def get_events() -> List[dict]:
-    """Copy of the completed-event list (tools/tests introspection)."""
+    """Copy of the completed-event ring, oldest first (tools, tests and
+    the benchmark's per-layer readers)."""
     with _GLOBAL_LOCK:
         return [dict(e) for e in _EVENTS]
+
+
+def dropped_events() -> int:
+    """Completed events the ring pushed out since the last reset."""
+    return _DROPPED
 
 
 def _feed_calibration(summary: List[dict]):

@@ -46,7 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "NOOP", "MAX_SERIES",
     "LABEL_DENYLIST", "SLOTargets", "SLOTracker", "UNSET",
-    "enabled", "registry", "counter", "gauge", "histogram",
+    "enabled", "registry", "counter", "gauge", "histogram", "Handles",
     "snapshot", "to_prometheus", "default_buckets",
     "slo_tracker", "reset_slo",
 ]
@@ -397,6 +397,7 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._families: Dict[str, _Family] = {}
+        self.generation = 0   # bumped by clear(): resolved handles go stale
 
     def _family(self, name: str, kind: str, help_: str,
                 labels: Sequence[str]) -> _Family:
@@ -442,6 +443,7 @@ class Registry:
         """Drop everything (tests: a pristine registry)."""
         with self._lock:
             self._families.clear()
+            self.generation += 1
 
     # -- export ------------------------------------------------------------
     def snapshot(self) -> Dict:
@@ -547,6 +549,40 @@ def gauge(name, help="", labels=()):
 
 def histogram(name, help="", labels=()):
     return _REGISTRY.histogram(name, help, labels) if enabled() else NOOP
+
+
+class Handles:
+    """One owner's instruments (an Executor, the serving schedulers),
+    resolved once instead of looked up by name under the registry's lock
+    on every step.  ``current()`` costs one flag read and a comparison;
+    it forgets what it resolved when ``FLAGS_telemetry`` flipped or the
+    registry was cleared, so both stay honoured as with the by-name
+    factories.  An instrument is made at its first use, as the by-name
+    factories made it: a family nothing touched yet is not published.
+    A spec is ``attr=(kind, name, help[, labels])``."""
+
+    def __init__(self, **specs):
+        self._specs = specs
+        self._stamp = None
+
+    def current(self) -> "Handles":
+        stamp = (enabled(), _REGISTRY.generation)
+        if stamp != self._stamp:
+            for attr in self._specs:
+                self.__dict__.pop(attr, None)
+            self._stamp = stamp
+        return self
+
+    def __getattr__(self, attr):
+        # reached only for an instrument not made yet (or no spec at all)
+        try:
+            kind, *spec = self.__dict__["_specs"][attr]
+        except KeyError:
+            raise AttributeError(attr) from None
+        made = {"counter": counter, "gauge": gauge,
+                "histogram": histogram}[kind](*spec)
+        self.__dict__[attr] = made
+        return made
 
 
 def snapshot() -> Dict:
