@@ -13,6 +13,11 @@ Each phase checks its result by the repo's own means (losses finite and
 falling; served tokens against ``greedy_reference`` on the same chip) and
 proves from the lowered program's text that its Pallas kernel is in the
 program — a kernel that silently gave way to the jnp path fails the phase.
+The serve phase also reads its prefill and decode programs as the chip's
+compiler left them: the append may move no whole KV pool (a prefill program
+holds no pool-sized copy at all, a decode program at most the re-layout of
+``paged_decode``'s operands), so "the append writes the pool in place" is
+something a run checks.
 Any exception in any phase ends the run non-zero.  The per-phase lines are
 smoke observations (compile seconds, steady step ms, peak bytes), not
 benchmark metrics.  The last line of stdout is the contract's JSON object.
@@ -33,8 +38,10 @@ from __future__ import annotations
 
 import argparse
 import gc
+import glob
 import json
 import os
+import re
 import shutil
 import statistics
 import sys
@@ -90,12 +97,24 @@ def say(**fields):
 
 class Watch:
     """Compilations and persistent-cache traffic, from JAX's own monitoring
-    events, and the lowered text of every program (jax_dump_ir_to)."""
+    events, the lowered text of every program (jax_dump_ir_to), and the
+    compiled text of the serving programs (``want_compiled_text``)."""
 
-    def __init__(self, jax, dump_dir):
+    @staticmethod
+    def want_compiled_text(compiled_dir):
+        """Ask XLA, before JAX starts, to write the serving step programs
+        (``jit_pt_prefill`` / ``jit_pt_decode``, executor.named_step) as it
+        compiled them: layout copies exist only after its optimizations."""
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_dump_to={compiled_dir} --xla_dump_hlo_as_text"
+            " --xla_dump_hlo_module_re=.*pt_(prefill|decode).*").strip()
+
+    def __init__(self, jax, dump_dir, compiled_dir):
         self.compiles = self.hits = self.misses = 0
         self.compile_s = 0.0
         self.dump_dir = dump_dir
+        self.compiled_dir = compiled_dir
         jax.config.update("jax_dump_ir_to", dump_dir)
         jax.monitoring.register_event_duration_secs_listener(self._duration)
         jax.monitoring.register_event_listener(self._event)
@@ -124,11 +143,79 @@ class Watch:
                  "cache_misses": self.misses - m},
                 sorted(set(os.listdir(self.dump_dir)) - files))
 
+    def compiled_texts(self):
+        """{file name: text} of every program XLA compiled and dumped."""
+        texts = {}
+        for path in sorted(glob.glob(os.path.join(
+                self.compiled_dir, "*after_optimizations.txt"))):
+            with open(path) as f:
+                texts[os.path.basename(path)] = f.read()
+        return texts
+
+
+def pool_makers(text, pool_shape):
+    """The instructions of one compiled program whose result holds an array
+    the size of a KV pool — in the pool's shape, its flat form ``(kv_heads,
+    slots, d)`` or its page-minor view ``(kv_heads, page_size, d, pages)`` —
+    as ``[(opcode, line)]``: what a layout copy, a transpose or a scatter of
+    a whole pool would show up as."""
+    n_kv, n_pages, page_size, d = pool_shape
+    dims = re.compile(r"\[(%d,%d,%d,%d|%d,%d,%d|%d,%d,%d,%d)\]" % (
+        n_kv, n_pages, page_size, d, n_kv, n_pages * page_size, d,
+        n_kv, page_size, d, n_pages))
+    made = []
+    for line in text.splitlines():
+        head, sep, rhs = line.partition(" = ")
+        if not sep or not head.lstrip().startswith(("%", "ROOT ")):
+            continue
+        if rhs.startswith("("):         # a tuple type: skip to its close
+            depth = 0
+            for end, ch in enumerate(rhs):
+                depth += (ch == "(") - (ch == ")")
+                if depth == 0:
+                    break
+        else:
+            end = rhs.find(" ")
+        if dims.search(rhs[:end + 1]):
+            made.append((rhs[end + 1:].lstrip().split("(", 1)[0], line))
+    return made
+
+
+def pool_traffic(text, pool_shape):
+    """``(moved, prefetched, held)`` for one compiled program: the
+    pool-sized results that cost the device a pass over a pool, the count of
+    XLA's own asynchronous moves of a pool into its scoped memory (``S(1)``:
+    ``slice-start``/``copy-start`` pairs and the ``ConcatBitcast`` joining
+    them — the compiler's choice of schedule, overlapped with compute, seen
+    at the smoke's 2048 pages), and the parameter layouts the pools are held
+    in.  Free are parameters, the append kernel (its output aliases its pool
+    operand), bitcasts (the page-minor view) and taking tuples apart and
+    together."""
+    free = ("get-tuple-element", "tuple", "bitcast")
+    moved, prefetched, held = [], 0, set()
+    for op, line in pool_makers(text, pool_shape):
+        if op == "parameter":
+            kind = line.partition(" = ")[2].split(" ", 1)[0]
+            if not kind.startswith("("):        # a loop body's tuple
+                held.add(kind)
+        elif op in free or op == "custom-call" and "kv_append" in line:
+            continue
+        elif op.endswith(("-start", "-done")) or "ConcatBitcast" in line:
+            prefetched += op.endswith("-start")
+        else:
+            moved.append((op, line))
+    return moved, prefetched, sorted(held)
+
 
 class Ctx:
     """What every phase needs: the place, the watch, the device report."""
 
     def __init__(self, args):
+        self.tmp = tempfile.mkdtemp(prefix="chip_smoke_ir_")
+        self.dump_dir = os.path.join(self.tmp, "lowered")
+        compiled_dir = os.path.join(self.tmp, "compiled")
+        os.mkdir(self.dump_dir)
+        Watch.want_compiled_text(compiled_dir)
         import jax
 
         import paddle_tpu as pt
@@ -143,8 +230,7 @@ class Ctx:
         self.place = pt.CPUPlace() if self.rehearsal else pt.TPUPlace(0)
         self.device = self.place.jax_device()
         self.interpreted = os.environ.get("PT_PALLAS_INTERPRET") == "1"
-        self.dump_dir = tempfile.mkdtemp(prefix="chip_smoke_ir_")
-        self.watch = Watch(jax, self.dump_dir)
+        self.watch = Watch(jax, self.dump_dir, compiled_dir)
         self.cache_dir = pt.COMPILE_CACHE_DIR
 
     def cache_entries(self):
@@ -166,9 +252,10 @@ class Ctx:
     def require_kernels(self, phase, modules, kernels):
         """Most calls of each kernel in one lowered program among
         ``modules``: the Mosaic custom call carrying the kernel's name —
-        or, when kernels are interpreted (CPU rehearsal), its name scope.
+        or, when kernels are interpreted (CPU rehearsal), its name scope
+        (a kernel inside a nested ``jit`` is counted once, at its body).
         A kernel found in none fails the phase."""
-        needles = {k: f"/{k}/pallas_call" if self.interpreted
+        needles = {k: f'{k}/pallas_call"' if self.interpreted
                    else f'kernel_name = "{k}"' for k in kernels}
         found = dict.fromkeys(kernels, 0)
         for m in modules:
@@ -184,8 +271,41 @@ class Ctx:
                 f"phase compiled — the jnp path took their place")
         return found
 
+    def require_pool_in_place(self, phase, pool_shape, n_pools):
+        """Every prefill and decode program the phase compiled, read as the
+        chip's compiler left it.  The append moves no pool: a program
+        without ``paged_decode`` (prefill) may hold NO pool-sized result
+        that costs a pass over a pool.  A decode program may hold one
+        ``copy`` a pool and nothing else: the re-layout of ``paged_decode``'s
+        operand where the chip holds the pool page-minor (head_dim under the
+        128 lanes; none where it holds it row-major).  Interpreted kernels
+        (the CPU rehearsal) are XLA loops over the pool, so there the
+        programs are read and counted and nothing is required of them."""
+        texts = self.watch.compiled_texts()
+        if not texts:
+            raise RuntimeError(f"{phase}: XLA dumped no compiled prefill or "
+                               f"decode program to read")
+        copies, prefetches, held = 0, 0, set()
+        for name, text in texts.items():
+            moved, prefetched, layouts = pool_traffic(text, pool_shape)
+            held.update(layouts)
+            prefetches = max(prefetches, prefetched)
+            allowed = n_pools if "paged_decode" in text else 0
+            if not self.interpreted and (
+                    len(moved) > allowed
+                    or any(op != "copy" for op, _ in moved)):
+                raise RuntimeError(
+                    f"{phase}: {name} moves a whole KV pool {len(moved)} "
+                    f"time(s), {allowed} allowed (paged_decode's operands "
+                    f"only), the first: "
+                    f"{[line.strip()[:200] for _, line in moved[:3]]}")
+            copies = max(copies, len(moved))
+        return {"programs_read": len(texts), "pools_held_as": sorted(held),
+                "most_pool_copies_in_a_program": copies,
+                "most_async_pool_prefetches_in_a_program": prefetches}
+
     def close(self):
-        shutil.rmtree(self.dump_dir, ignore_errors=True)
+        shutil.rmtree(self.tmp, ignore_errors=True)
 
 
 def train_phase(ctx, phase, mark, run_step, steps, kernels, report):
@@ -461,13 +581,25 @@ def export(size):
 
 
 def phase_serve(ctx):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax = ctx.jax
     phase, size = "serve/decoder", ctx.sizes["serve"]
     model_dir = export(size)
+    # a program read back from the persistent cache is not compiled, so
+    # XLA would dump nothing to read: this phase compiles its own
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
     try:
         mark = ctx.watch.mark()
         eng, reqs, steady = serve(ctx, model_dir, size, tp=1)
         seen, modules = ctx.watch.since(mark)
-        kernels = ctx.require_kernels(phase, modules, ["paged_decode"])
+        kernels = ctx.require_kernels(phase, modules,
+                                      ["paged_decode", "kv_append"])
+        in_place = ctx.require_pool_in_place(
+            phase, eng.core.kv_config.pool_shape(),
+            n_pools=2 * eng.cfg.num_layers)
         oracle = eng.core.greedy_reference(reqs[0].prompt,
                                            size["new_tokens"])
         verdict = compare_tokens(phase, "request 0 vs greedy_reference",
@@ -475,11 +607,13 @@ def phase_serve(ctx):
                                  reqs[0].out_tokens, oracle)
     finally:
         shutil.rmtree(model_dir, ignore_errors=True)
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
     say(phase=phase, **size["cfg"], num_pages=size["num_pages"],
         page_size=size["page_size"], prompts=size["prompts"],
         new_tokens=size["new_tokens"], scheduler=eng.stats,
         kv_peak_pages=eng.kv.stats()["peak_pages"], **steady, **seen,
-        kernel_calls=kernels, **verdict, **ctx.memory())
+        kernel_calls=kernels, **in_place, **verdict, **ctx.memory())
 
 
 def phase_tp4(ctx):
@@ -491,7 +625,8 @@ def phase_tp4(ctx):
         mark = ctx.watch.mark()
         four, reqs4, steady = serve(ctx, model_dir, size, tp=4)
         seen, modules = ctx.watch.since(mark)
-        kernels = ctx.require_kernels(phase, modules, ["paged_decode"])
+        kernels = ctx.require_kernels(phase, modules,
+                                      ["paged_decode", "kv_append"])
         verdicts = [compare_tokens(phase, f"request {a.req_id} tp=4 vs tp=1",
                                    one.core, a.prompt, b.out_tokens,
                                    a.out_tokens)

@@ -13,7 +13,9 @@ not ``batch * max_seq``.
 The allocator here is pure host bookkeeping (page free list + per-
 sequence page lists); the device pools live in the serving scope as
 ordinary persistable vars that ``kv_cache_append`` updates in place
-under buffer donation.  All decisions are deterministic: pages are
+(the executor donates them to the step program, and the append kernel
+writes its pages through an alias of the pool: ops/paged_ops.py).
+All decisions are deterministic: pages are
 handed out FIFO (fresh ids ascending, freed pages reused in free
 order), so a seeded request trace yields a bit-identical allocation
 sequence — the property the scheduler-determinism tests pin.

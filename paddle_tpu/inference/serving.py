@@ -4,8 +4,10 @@ Grows AnalysisPredictor's one-shot run() into a serving engine
 (ROADMAP direction 1, "millions of users" made measurable):
 
 * **Paged KV cache** — inference/kv_cache.py allocator over device pool
-  vars the ``kv_cache_append`` op updates in place (donated buffers:
-  the pool never copies).
+  vars the ``kv_cache_append`` op updates in place: the executor
+  donates each pool to the step program (a var both read and written)
+  and the append kernel aliases its pool operand onto its output,
+  moving only the blocks it writes (ops/paged_ops.py).
 * **Continuous (inflight) batching** — new requests are admitted at
   EVERY decode step up to a token budget, finished sequences are
   evicted (pages freed) immediately, and pool exhaustion mid-decode

@@ -4,11 +4,20 @@ Three ops, shared by the continuous-batching engine
 (inference/serving.py) over the pools the paged allocator
 (inference/kv_cache.py) manages:
 
-* ``kv_cache_append`` — scatter this step's new K/V vectors into the
+* ``kv_cache_append`` — write this step's new K/V vectors into the
   preallocated device pools at allocator-assigned flat slots.  In-place
   on the pool vars (output name == input name, the registry's in-place
-  convention), so under buffer donation the update is a
-  dynamic-update-slice in HBM — the pool is never copied.
+  convention).  What makes that true on the chip is two aliases: the
+  executor donates a var that a program both reads and writes, and the
+  append kernel (ops/pallas_kernels.py ``kv_append``) aliases its pool
+  operand onto its output and moves only the blocks it writes, in the
+  layout the chip holds the pool in — the append reads and writes no
+  whole pool (``chip_smoke.py`` checks the compiled programs; a prefill
+  program holds no pool-sized copy at all).  An XLA scatter here cost
+  two pool-sized layout copies per pool per call.  What is left is
+  ``paged_attention``'s: where head_dim is under the 128 lanes the chip
+  holds the pool page-minor, and the decode kernel's operands are
+  re-laid row-major once a call.
 * ``paged_attention`` — each decode query gathers K/V through its block
   table at its true length (ops/pallas_kernels.py: Pallas kernel on
   TPU, gather fallback on CPU with identical semantics).
@@ -44,6 +53,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .registry import op
+from .pallas_kernels import kv_append as _kv_append_impl
 from .pallas_kernels import paged_attention as _paged_attention_impl
 
 INT8_QMAX = 127.0
@@ -56,7 +66,7 @@ def _quant_scatter(pool, scales, new, slots, page_size):
     touched-page requant (module docstring); pad-sentinel slots drop
     out of every scatter (mode='drop') and gather via a clipped index
     whose result is then dropped too."""
-    n_kv, n_pages, _, d = pool.shape
+    n_kv, n_pages = pool.shape[:2]
     pages = slots // page_size                      # sentinel -> n_pages (OOB)
     safe_pages = jnp.minimum(pages, n_pages - 1)    # gather-safe alias
     # reset-on-open: any slot at page offset 0 recycles its page
@@ -86,9 +96,9 @@ def _quant_scatter(pool, scales, new, slots, page_size):
     denom = jnp.where(slot_scale > 0, slot_scale, 1.0)
     q = jnp.clip(jnp.round(new / denom[..., None] * INT8_QMAX),
                  -INT8_QMAX, INT8_QMAX).astype(pool.dtype)
-    flat = pool.reshape(n_kv, n_pages * page_size, d)
-    flat = flat.at[:, slots, :].set(q, mode="drop")
-    return flat.reshape(pool.shape), new_scales
+    # the rows take the same write as every other storage type
+    (pool,) = _kv_append_impl((pool,), (q.transpose(1, 0, 2),), slots)
+    return pool, new_scales
 
 
 @op("kv_cache_append", no_grad=True,
@@ -110,7 +120,7 @@ def _kv_cache_append(ctx):
     slots = ctx.in_("SlotMapping").astype(jnp.int32)
     k_pool = ctx.in_("KCache")
     v_pool = ctx.in_("VCache")
-    n_kv, n_pages, page_size, d = k_pool.shape
+    page_size = k_pool.shape[2]
 
     if ctx.has_input("KScale"):
         kq, ks = _quant_scatter(
@@ -125,16 +135,12 @@ def _kv_cache_append(ctx):
         ctx.set_out("VScaleOut", vs)
         return
 
-    def scatter(pool, new):
-        flat = pool.reshape(n_kv, n_pages * page_size, d)
-        # (tokens, kv_heads, d) -> (kv_heads, tokens, d); 'drop' makes
-        # the pad sentinel (== n_pages * page_size) a no-op
-        flat = flat.at[:, slots, :].set(
-            new.astype(pool.dtype).transpose(1, 0, 2), mode="drop")
-        return flat.reshape(pool.shape)
-
-    ctx.set_out("KCacheOut", scatter(k_pool, k))
-    ctx.set_out("VCacheOut", scatter(v_pool, v))
+    # K and V in one call: one kernel a layer
+    k_pool, v_pool = _kv_append_impl(
+        (k_pool, v_pool),
+        (k.astype(k_pool.dtype), v.astype(v_pool.dtype)), slots)
+    ctx.set_out("KCacheOut", k_pool)
+    ctx.set_out("VCacheOut", v_pool)
 
 
 @op("paged_attention", no_grad=True,

@@ -964,6 +964,167 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
 
 
 # ==========================================================================
+# KV-pool append — the serving-runtime write
+# ==========================================================================
+# An XLA scatter into a ``(kv_heads, num_pages, page_size, head_dim)``
+# pool is re-laid around on the chip (the compiler wants the update
+# window's dims minor) and back: two pool-sized copies per pool per
+# program call, whatever the number of rows written.  This kernel moves
+# only the blocks it writes, WHERE THE POOL LIES, and its output aliases
+# the pool operand, so with the pool donated to the program the append
+# reads and writes no whole pool.
+#
+# Where the pool lies is the chip's choice, by shape.  With head_dim a
+# multiple of the 128 lanes it lies row-major, in the tiles
+# ``paged_decode`` reads, and a token's write is one row of its page's
+# ``(kv_heads, 1, page_size, d)`` block.  With head_dim under the lanes
+# (GPT-2's 64) the chip keeps the PAGE axis minor instead of padding
+# every row to 128 (``{1,3,2,0:T(8,128)}``, ``chip_smoke.py`` prints it):
+# there the kernel works on the transposed view ``(kv_heads, page_size,
+# d, num_pages)``, which is the same bytes (XLA makes the transpose a
+# bitcast), and a token's write is one lane of a ``(kv_heads, 1, d,
+# 128)`` block.  Handing that pool to a kernel in the row-major form
+# instead costs a re-layout of the whole pool on the way in and another
+# on the way out; ``paged_decode`` still pays the first (ROADMAP Queue 1
+# item 1(b)), the append pays neither.
+#
+# One grid step per token, tokens ordered by the block they write so
+# that a block's tokens are adjacent: the block is fetched when the walk
+# enters it, each of its tokens selects its row (lane) into the resident
+# block, and the block is written back when the walk leaves it (Pallas
+# skips the copies while a block index repeats).  A block is therefore
+# read once and written once per call however its tokens were spread
+# over the feed, which also keeps the block pipeline's prefetch from
+# ever reading a block whose write-back is still in flight.  Pad
+# sentinel tokens sort last, stay on the last block a real token wrote
+# and select nothing.
+
+
+def _kv_append_kernel(keys_ref, sel_ref, order_ref, *refs, page_minor):
+    """Grid step ``i`` writes sorted token ``i``: ``refs`` are, for each
+    pool, the token's ``(1, kv_heads, 1, d)`` rows, then each pool's
+    block as read, then each pool's block to write.  ``sel_ref[i]`` is
+    the row of the block the token writes (``page_minor``: the lane),
+    -1 for a pad token."""
+    del order_ref                       # the rows' index map reads it
+    n = len(refs) // 3
+    rows, blocks_in, blocks_out = refs[:n], refs[n:2 * n], refs[2 * n:]
+    i = pl.program_id(0)
+    sel = sel_ref[i]
+
+    @pl.when(jnp.logical_or(
+        i == 0, keys_ref[i] != keys_ref[jnp.maximum(i - 1, 0)]))
+    def _open():
+        for src, dst in zip(blocks_in, blocks_out):
+            dst[...] = src[...]
+
+    @pl.when(sel >= 0)
+    def _write():
+        for row, dst in zip(rows, blocks_out):
+            cur = dst[...]
+            # the select runs 32 bits wide: the chip's vector unit has
+            # no 16- or 8-bit compare/select, and both casts are exact
+            wide = (jnp.float32 if jnp.issubdtype(cur.dtype, jnp.floating)
+                    else jnp.int32)
+            new = row[0].astype(wide)                   # (kv_heads, 1, d)
+            if page_minor:
+                # the row's d values go down the sublanes of one lane:
+                # turn (1, d) into (d, 1) through the diagonal of (d, d)
+                n_kv, _, d = new.shape
+                diag = (lax.broadcasted_iota(jnp.int32, (n_kv, d, d), 1)
+                        == lax.broadcasted_iota(jnp.int32, (n_kv, d, d), 2))
+                new = jnp.sum(jnp.where(diag, new, 0), axis=2, keepdims=True)
+            at = lax.broadcasted_iota(
+                jnp.int32, cur.shape, 3 if page_minor else 2) == sel
+            dst[...] = jnp.where(at, new[:, None],
+                                 cur.astype(wide)).astype(cur.dtype)
+
+
+@functools.partial(jax.jit, static_argnames="page_minor")
+def _kv_append_call(pools, rows, slots, page_minor):
+    """``pools``: tuple of ``(kv_heads, num_pages, page_size, d)`` pools
+    of one shape; ``rows``: per pool ``(tokens, kv_heads, d)`` in the
+    pool's dtype; ``slots``: ``(tokens,)`` int32.  Jitted so that the
+    layers of one program share one trace and one Mosaic lowering."""
+    n_kv, n_pages, page_size, d = pools[0].shape
+    valid = (slots >= 0) & (slots < n_pages * page_size)
+    page, off = lax.div(slots, page_size), lax.rem(slots, page_size)
+    if page_minor:
+        # a block is (offset, 128 pages): n_pages // LANES blocks a row
+        per_off = n_pages // LANES
+        key, sel = off * per_off + lax.div(page, LANES), lax.rem(page, LANES)
+        block = (n_kv, 1, d, LANES)
+        pools = [p.transpose(0, 2, 3, 1) for p in pools]
+
+        def _block_idx(i, keys, sel, order):
+            return (0, lax.div(keys[i], per_off), 0,
+                    lax.rem(keys[i], per_off))
+    else:
+        key, sel = page, off
+        block = (n_kv, 1, page_size, d)
+
+        def _block_idx(i, keys, sel, order):
+            return (0, keys[i], 0, 0)
+
+    def _row_idx(i, keys, sel, order):
+        return (order[i], 0, 0, 0)
+
+    # pads sort last and ride on the last block a real token wrote
+    # (block 0 when the feed is all padding: read, written back as is)
+    order = jnp.argsort(jnp.where(valid, key, jnp.iinfo(jnp.int32).max))
+    order = order.astype(jnp.int32)
+    key = jnp.where(valid, key, jnp.max(jnp.where(valid, key, 0)))[order]
+    sel = jnp.where(valid, sel, -1)[order]
+    # rows ride as (tokens, kv_heads, 1, d): a block's last two dims
+    # must be (8, 128)-aligned or the array's own (as paged_decode's q)
+    row_spec = pl.BlockSpec((1, n_kv, 1, d), _row_idx)
+    block_spec = pl.BlockSpec(block, _block_idx)
+    n = len(pools)
+    out = pl.pallas_call(
+        functools.partial(_kv_append_kernel, page_minor=page_minor),
+        name="kv_append",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slots.shape[0],),
+            in_specs=[row_spec] * n + [block_spec] * n,
+            out_specs=[block_spec] * n),
+        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in pools],
+        # operand indices count the three scalar-prefetch arguments
+        input_output_aliases={3 + n + j: j for j in range(n)},
+        interpret=_interpret(),
+    )(key, sel, order, *[r[:, :, None, :] for r in rows], *pools)
+    return [o.transpose(0, 3, 1, 2) for o in out] if page_minor else out
+
+
+def kv_append(pools, rows, slots):
+    """Write ``rows[j][t]`` (``(tokens, kv_heads, d)``, the pool's
+    dtype) to flat slot ``slots[t]`` of ``pools[j]`` (``(kv_heads,
+    num_pages, page_size, d)``), for every pool of the tuple; a slot
+    outside the pool (the allocator's pad sentinel, ``num_pages *
+    page_size``) drops its row.  Returns the new pools.
+
+    Engages like :func:`paged_attention`: the Pallas kernel on TPU (or
+    under PT_PALLAS_INTERPRET=1) when page_size and head_dim are
+    multiples of 8; elsewhere a scatter by ``(page, offset)`` on the
+    4-D pool — the same result, and the tests' reference.  The kernel
+    takes the pool in the view the chip holds it in (section comment):
+    page-minor when head_dim leaves lanes empty (and, a block's sublane
+    axis there, is whole tiles for every storage type: 32 rows of int8)
+    and the pages fill whole lane blocks; row-major otherwise."""
+    _, n_pages, page_size, d = pools[0].shape
+    slots = slots.astype(jnp.int32)
+    if _use_pallas() and d % 8 == 0 and page_size % 8 == 0:
+        return tuple(_kv_append_call(
+            tuple(pools), tuple(rows), slots,
+            page_minor=d % LANES != 0 and d % 32 == 0
+            and n_pages % LANES == 0))
+    # 'drop' makes the sentinel (page == num_pages) a no-op
+    page, off = slots // page_size, slots % page_size
+    return tuple(p.at[:, page, off, :].set(r.transpose(1, 0, 2), mode="drop")
+                 for p, r in zip(pools, rows))
+
+
+# ==========================================================================
 # Fused epilogues (r14) — conv+BN+act and matmul+bias+act
 # ==========================================================================
 # The profile-ranked fusion layer (utils/cost_model.rank_fusion_candidates

@@ -99,6 +99,18 @@ def _paged_case(kv_dtype):
     return f, shapes
 
 
+def _append_case(kv_dtype, tokens, head_dim=DEC_D):
+    """K and V of one layer in one call, at a decode batch's and at a
+    prefill bucket's slot count; head_dim 64 takes the kernel's page-minor
+    view of the pool, 128 its row-major one."""
+    def f(kp, vp, k, v, slots):
+        return pk.kv_append((kp, vp), (k, v), slots)
+
+    pool = ((DEC_HEADS, POOL_PAGES, PAGE, head_dim), kv_dtype)
+    rows = ((tokens, DEC_HEADS, head_dim), kv_dtype)
+    return f, [pool, pool, rows, rows, ((tokens,), jnp.int32)]
+
+
 def _bn_fwd_case(shape, residual):
     c = shape[-1]
     bf = jnp.bfloat16
@@ -149,6 +161,10 @@ CASES = {
     "paged-f32": functools.partial(_paged_case, jnp.float32),
     "paged-bf16": functools.partial(_paged_case, jnp.bfloat16),
     "paged-int8": functools.partial(_paged_case, jnp.int8),
+    **{f"kv_append-{jnp.dtype(t).name}-{n}-d{d}":
+       functools.partial(_append_case, t, n, d)
+       for t in (jnp.float32, jnp.bfloat16, jnp.int8) for n in (64, 1024)
+       for d in (DEC_D, 128)},
     **{f"bn_act-fwd-{'x'.join(map(str, s))}{'-res' if r else ''}":
        functools.partial(_bn_fwd_case, s, r)
        for s in RESNET_STAGES for r in (False, True)},
@@ -169,3 +185,56 @@ def test_kernel_compiles_for_v5e(name, v5e, monkeypatch):
     fn, shapes = CASES[name]()
     text = _compile(fn, v5e, *shapes)
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+
+
+@pytest.mark.parametrize("head_dim,held,decode_copies", [
+    # head_dim under the 128 lanes: the chip holds the pool page-minor
+    # ({1,3,2,0}); paged_decode's two operands are re-laid, the append's none
+    (DEC_D, (0, 2, 3, 1), 2),
+    # lane-full rows: held row-major, the tiles both kernels address
+    (128, (0, 1, 2, 3), 0),
+])
+def test_append_writes_the_pool_where_the_chip_holds_it(
+        head_dim, held, decode_copies, v5e, monkeypatch):
+    """One layer of the prefill program (append alone) and of the decode
+    program (append, then paged decode), the pools donated and pinned to
+    the layout the chip's compiler itself gives a pool of that shape: the
+    append moves no whole pool and needs no temporary; what the decode
+    program copies is paged_decode's operands and nothing else."""
+    from jax.experimental.layout import Format, Layout
+
+    from chip_smoke import pool_traffic     # the reader the smoke uses
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    pool_shape = (DEC_HEADS, POOL_PAGES, PAGE, head_dim)
+    pinned = Format(Layout(major_to_minor=held, tiling=((8, 128),)), v5e)
+
+    def arg(shape, dtype, sharding=v5e):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    pool = arg(pool_shape, jnp.float32, pinned)
+    rows = arg((DEC_SEQS, DEC_HEADS, head_dim), jnp.float32)
+    ints = arg((DEC_SEQS,), jnp.int32)
+
+    def prefill(kp, vp, k, v, slots):
+        return pk.kv_append((kp, vp), (k, v), slots)
+
+    def decode(kp, vp, k, v, slots, q, bt, cl):
+        kp, vp = pk.kv_append((kp, vp), (k, v), slots)
+        return kp, vp, pk.paged_attention(q, kp, vp, bt, cl)
+
+    compiled = jax.jit(prefill, donate_argnums=(0, 1),
+                       out_shardings=(pinned, pinned)
+                       ).lower(pool, pool, rows, rows, ints).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    moved, _, at_rest = pool_traffic(compiled.as_text(), pool_shape)
+    assert moved == [] and len(at_rest) == 1, (moved, at_rest)
+
+    compiled = jax.jit(decode, donate_argnums=(0, 1),
+                       out_shardings=(pinned, pinned, v5e)
+                       ).lower(pool, pool, rows, rows, ints, rows,
+                               arg((DEC_SEQS, TABLE_W), jnp.int32),
+                               ints).compile()
+    moved, _, _ = pool_traffic(compiled.as_text(), pool_shape)
+    assert [op for op, _ in moved] == ["copy"] * decode_copies, moved
