@@ -14,10 +14,10 @@ falling; served tokens against ``greedy_reference`` on the same chip) and
 proves from the lowered program's text that its Pallas kernel is in the
 program — a kernel that silently gave way to the jnp path fails the phase.
 The serve phase also reads its prefill and decode programs as the chip's
-compiler left them: the append may move no whole KV pool (a prefill program
-holds no pool-sized copy at all, a decode program at most the re-layout of
-``paged_decode``'s operands), so "the append writes the pool in place" is
-something a run checks.
+compiler left them: a KV pool stored lane-full must be held in the compiler's
+own row-major layout, and no program may copy, reshape or transpose a whole
+pool, so "both pool kernels work where the chip holds the pool" is something
+a run checks.
 Any exception in any phase ends the run non-zero.  The per-phase lines are
 smoke observations (compile seconds, steady step ms, peak bytes), not
 benchmark metrics.  The last line of stdout is the contract's JSON object.
@@ -77,10 +77,12 @@ SIZES = {
                      max_position_embeddings=128,
                      attention_probs_dropout_prob=0.0),
             seq=128, batch=2, steps=5),
+        # head_dim 64 and pages of 16, as "full": the pools are stored
+        # lane-full, so the rehearsal drives the kernels' packed form
         "serve": dict(
-            cfg=dict(vocab_size=128, hidden=32, num_heads=4, num_layers=2,
+            cfg=dict(vocab_size=128, hidden=256, num_heads=4, num_layers=2,
                      max_seq_len=128),
-            num_pages=64, page_size=8, token_budget=128, max_batch=8,
+            num_pages=64, page_size=16, token_budget=128, max_batch=8,
             prompts=[40, 5, 36, 9, 7, 50, 6, 34], new_tokens=6),
     },
 }
@@ -153,16 +155,27 @@ class Watch:
         return texts
 
 
-def pool_makers(text, pool_shape):
+def pool_forms(stored, head_dim):
+    """The dims an array the size of a KV pool can show in a compiled
+    program, first the shape the pool is stored in (``KVCacheConfig.
+    pool_shape``), then what a reshape or transpose of a whole pool would
+    make of it: the logical ``(kv_heads, pages, page_size, d)``, the flat
+    ``(kv_heads, slots, d)`` and the page-minor view ``(kv_heads, page_size,
+    d, pages)``."""
+    n_kv, n_pages, rows, width = stored
+    page_size = rows * width // head_dim
+    forms = [stored, (n_kv, n_pages, page_size, head_dim),
+             (n_kv, n_pages * page_size, head_dim),
+             (n_kv, page_size, head_dim, n_pages)]
+    return [",".join(map(str, f)) for f in dict.fromkeys(forms)]
+
+
+def pool_makers(text, forms):
     """The instructions of one compiled program whose result holds an array
-    the size of a KV pool — in the pool's shape, its flat form ``(kv_heads,
-    slots, d)`` or its page-minor view ``(kv_heads, page_size, d, pages)`` —
-    as ``[(opcode, line)]``: what a layout copy, a transpose or a scatter of
-    a whole pool would show up as."""
-    n_kv, n_pages, page_size, d = pool_shape
-    dims = re.compile(r"\[(%d,%d,%d,%d|%d,%d,%d|%d,%d,%d,%d)\]" % (
-        n_kv, n_pages, page_size, d, n_kv, n_pages * page_size, d,
-        n_kv, page_size, d, n_pages))
+    the size of a KV pool, in any of ``forms`` (:func:`pool_forms`), as
+    ``[(opcode, dims, line)]``: what a layout copy, a reshape, a transpose
+    or a scatter of a whole pool would show up as."""
+    dims = re.compile(r"\[(%s)\]" % "|".join(forms))
     made = []
     for line in text.splitlines():
         head, sep, rhs = line.partition(" = ")
@@ -176,29 +189,36 @@ def pool_makers(text, pool_shape):
                     break
         else:
             end = rhs.find(" ")
-        if dims.search(rhs[:end + 1]):
-            made.append((rhs[end + 1:].lstrip().split("(", 1)[0], line))
+        found = dims.search(rhs[:end + 1])
+        if found:
+            made.append((rhs[end + 1:].lstrip().split("(", 1)[0],
+                         found.group(1), line))
     return made
 
 
-def pool_traffic(text, pool_shape):
+def pool_traffic(text, forms, views_free=True):
     """``(moved, prefetched, held)`` for one compiled program: the
-    pool-sized results that cost the device a pass over a pool, the count of
-    XLA's own asynchronous moves of a pool into its scoped memory (``S(1)``:
-    ``slice-start``/``copy-start`` pairs and the ``ConcatBitcast`` joining
-    them — the compiler's choice of schedule, overlapped with compute, seen
-    at the smoke's 2048 pages), and the parameter layouts the pools are held
-    in.  Free are parameters, the append kernel (its output aliases its pool
-    operand), bitcasts (the page-minor view) and taking tuples apart and
-    together."""
-    free = ("get-tuple-element", "tuple", "bitcast")
+    pool-sized results that cost the device a pass over a pool (or, with
+    ``views_free`` off, that show a pool in another form than the stored
+    one, whatever it costs), the count of XLA's own asynchronous moves of a
+    pool into its scoped memory (``S(1)``: ``slice-start``/``copy-start``
+    pairs and the ``ConcatBitcast`` joining them — the compiler's choice of
+    schedule, overlapped with compute, seen at the smoke's 2048 pages), and
+    the parameter layouts the pools are held in.  Free are parameters, the
+    append kernel (its output aliases its pool operand), taking tuples apart
+    and together, and bitcasts: all of them where the kernel may work on a
+    view (a pool the chip holds page-minor), only those that keep the stored
+    dims where it may not."""
+    free = ("get-tuple-element", "tuple")
     moved, prefetched, held = [], 0, set()
-    for op, line in pool_makers(text, pool_shape):
+    for op, dims, line in pool_makers(text, forms):
         if op == "parameter":
             kind = line.partition(" = ")[2].split(" ", 1)[0]
             if not kind.startswith("("):        # a loop body's tuple
                 held.add(kind)
         elif op in free or op == "custom-call" and "kv_append" in line:
+            continue
+        elif op == "bitcast" and (views_free or dims == forms[0]):
             continue
         elif op.endswith(("-start", "-done")) or "ConcatBitcast" in line:
             prefetched += op.endswith("-start")
@@ -271,36 +291,53 @@ class Ctx:
                 f"phase compiled — the jnp path took their place")
         return found
 
-    def require_pool_in_place(self, phase, pool_shape, n_pools):
+    def require_pool_in_place(self, phase, kv_config, n_pools):
         """Every prefill and decode program the phase compiled, read as the
-        chip's compiler left it.  The append moves no pool: a program
-        without ``paged_decode`` (prefill) may hold NO pool-sized result
-        that costs a pass over a pool.  A decode program may hold one
-        ``copy`` a pool and nothing else: the re-layout of ``paged_decode``'s
-        operand where the chip holds the pool page-minor (head_dim under the
-        128 lanes; none where it holds it row-major).  Interpreted kernels
-        (the CPU rehearsal) are XLA loops over the pool, so there the
-        programs are read and counted and nothing is required of them."""
+        chip's compiler left it.  A pool stored lane-full
+        (``kv_config.pool_shape()``: rows of 128 lanes) must be held in the
+        compiler's own row-major layout, where both pool kernels work, and
+        NO program may hold a pool-sized result but the append's: no copy,
+        and no reshape or transpose of a whole pool either, free or not (a
+        view of the pool in another form is how the re-layout comes back).
+        A pool that could not be stored so (head_dim under the lanes, pages
+        not whole tiles: the chip holds it page-minor) is given the old
+        allowance: the append still moves none, a decode program may hold
+        one ``copy`` a pool, ``paged_decode``'s operand, and bitcast views
+        are free.  Interpreted kernels (the CPU rehearsal) are XLA loops
+        over the pool, so there the programs are read and counted and
+        nothing is required of them."""
         texts = self.watch.compiled_texts()
         if not texts:
             raise RuntimeError(f"{phase}: XLA dumped no compiled prefill or "
                                f"decode program to read")
+        stored = kv_config.pool_shape()
+        forms = pool_forms(stored, kv_config.head_dim)
+        lane_full = stored[3] % 128 == 0
         copies, prefetches, held = 0, 0, set()
         for name, text in texts.items():
-            moved, prefetched, layouts = pool_traffic(text, pool_shape)
+            moved, prefetched, layouts = pool_traffic(
+                text, forms, views_free=not lane_full)
             held.update(layouts)
             prefetches = max(prefetches, prefetched)
-            allowed = n_pools if "paged_decode" in text else 0
+            allowed = n_pools if "paged_decode" in text and not lane_full \
+                else 0
             if not self.interpreted and (
                     len(moved) > allowed
                     or any(op != "copy" for op, _ in moved)):
                 raise RuntimeError(
-                    f"{phase}: {name} moves a whole KV pool {len(moved)} "
-                    f"time(s), {allowed} allowed (paged_decode's operands "
-                    f"only), the first: "
+                    f"{phase}: {name} copies, reshapes or transposes a "
+                    f"whole KV pool {len(moved)} time(s), {allowed} "
+                    f"allowed, the first: "
                     f"{[line.strip()[:200] for _, line in moved[:3]]}")
             copies = max(copies, len(moved))
-        return {"programs_read": len(texts), "pools_held_as": sorted(held),
+        not_default = [h for h in held if "{3,2,1,0" not in h]
+        if lane_full and not_default and not self.rehearsal:
+            raise RuntimeError(
+                f"{phase}: a lane-full pool {stored} is not held in the "
+                f"compiler's row-major layout: {not_default}")
+        return {"pool_stored_shape": list(stored),
+                "pool_tokens_per_row": kv_config.tokens_per_row,
+                "programs_read": len(texts), "pools_held_as": sorted(held),
                 "most_pool_copies_in_a_program": copies,
                 "most_async_pool_prefetches_in_a_program": prefetches}
 
@@ -598,8 +635,7 @@ def phase_serve(ctx):
         kernels = ctx.require_kernels(phase, modules,
                                       ["paged_decode", "kv_append"])
         in_place = ctx.require_pool_in_place(
-            phase, eng.core.kv_config.pool_shape(),
-            n_pools=2 * eng.cfg.num_layers)
+            phase, eng.core.kv_config, n_pools=2 * eng.cfg.num_layers)
         oracle = eng.core.greedy_reference(reqs[0].prompt,
                                            size["new_tokens"])
         verdict = compare_tokens(phase, "request 0 vs greedy_reference",

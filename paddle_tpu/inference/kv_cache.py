@@ -3,12 +3,13 @@
 vLLM-style paged memory (PAPERS.md: Ragged Paged Attention, arXiv
 2604.15464): the device KV cache is a fixed pool of ``num_pages`` pages
 of ``page_size`` token slots each, laid out ``(kv_heads, num_pages,
-page_size, head_dim)`` per layer (the layout ops/pallas_kernels.py
-``paged_attention`` consumes).  Sequences own PAGES, not a contiguous
-max-seq strip: appending a token allocates a page only when the
-sequence's last page is full, finishing a sequence returns its pages
-immediately — so pool capacity is bounded by the sum of TRUE lengths,
-not ``batch * max_seq``.
+page_size, head_dim)`` per layer (stored with rows that fill the 128
+lanes where head_dim leaves lanes empty, ``KVCacheConfig.pool_shape``:
+the form ops/pallas_kernels.py ``paged_attention`` consumes).
+Sequences own PAGES, not a contiguous max-seq strip: appending a token
+allocates a page only when the sequence's last page is full, finishing
+a sequence returns its pages immediately — so pool capacity is bounded
+by the sum of TRUE lengths, not ``batch * max_seq``.
 
 The allocator here is pure host bookkeeping (page free list + per-
 sequence page lists); the device pools live in the serving scope as
@@ -67,6 +68,9 @@ import numpy as np
 
 __all__ = ["KVCacheConfig", "PagedKVCache"]
 
+# the chip's vector tile: 128 lanes a row, rows in groups of 8
+_LANES, _TILE_ROWS = 128, 8
+
 
 @dataclass(frozen=True)
 class KVCacheConfig:
@@ -90,9 +94,35 @@ class KVCacheConfig:
         pages store ``round(x / scale * 127)`` per (kv_head, page))."""
         return self.dtype == "int8"
 
+    @property
+    def tokens_per_row(self) -> int:
+        """Consecutive tokens of a page that share one stored row: ``128
+        / head_dim`` where head_dim is under the 128 lanes, divides them,
+        and a page is whole (8, 128) tiles (the tile the chip's compiler
+        gives float32, bfloat16 and int8 pools alike, narrower types
+        packed inside it); else 1, a row a token."""
+        t = _LANES // self.head_dim if self.head_dim < _LANES \
+            and _LANES % self.head_dim == 0 else 1
+        whole = (self.page_size * self.head_dim) % (_TILE_ROWS * _LANES) == 0
+        return t if whole else 1
+
     def pool_shape(self):
-        return (self.num_kv_heads, self.num_pages, self.page_size,
-                self.head_dim)
+        """The shape a pool is STORED in (scope, programs, kernels).
+        Logically a pool is ``(kv_heads, num_pages, page_size,
+        head_dim)`` — the contract of the allocator, its flat slots,
+        the scale pools and page copies, all of which address whole
+        pages or tokens by number.  Stored, its rows fill the lanes:
+        ``tokens_per_row`` tokens side by side, ``(kv_heads, num_pages,
+        page_size * head_dim / 128, 128)``, a row-major bitcast of the
+        logical pool.  The chip's compiler holds that shape row-major
+        in exact tiles by its own choice, which is where both pool
+        kernels work; a head_dim-64 pool in the logical shape it holds
+        page-minor, and ``paged_decode`` cost a re-layout of every pool
+        on every call.  With ``tokens_per_row`` 1 (head_dim 128 and
+        over, the tiny test models) the two shapes are one."""
+        t = self.tokens_per_row
+        return (self.num_kv_heads, self.num_pages, self.page_size // t,
+                self.head_dim * t)
 
     def make_pool(self) -> np.ndarray:
         """One zeroed host-side pool (K or V, one layer); the engine
@@ -169,6 +199,9 @@ class PagedKVCache:
             fragmentation=("gauge", "kv_pool_fragmentation",
                            "fraction of owned KV slots holding no token "
                            "(tail-of-page waste)"),
+            tokens_per_row=("gauge", "kv_pool_tokens_per_row",
+                            "tokens side by side in one stored pool row "
+                            "(over 1: the pool is stored lane-full)"),
             prefix_cached=("gauge", "kv_prefix_cached_pages",
                            "refcount-0 pages kept as evictable "
                            "prefix-cache entries"),
@@ -185,6 +218,8 @@ class PagedKVCache:
                    "KV pages handed out"),
             freed=("counter", "kv_pool_pages_freed_total",
                    "KV pages returned to the pool"))
+        # fixed for the pool's life: says whether the lane-full form engaged
+        self._tm.current().tokens_per_row.set(config.tokens_per_row)
         self.seed = int(seed)
         self._free: deque = deque(range(config.num_pages))
         self._seqs: Dict[object, _Seq] = {}
@@ -647,6 +682,8 @@ class PagedKVCache:
     def stats(self) -> dict:
         return {
             "dtype": self.config.dtype,
+            "pool_stored_shape": list(self.config.pool_shape()),
+            "pool_tokens_per_row": self.config.tokens_per_row,
             "scale_bytes": self.config.scale_bytes(),
             "effective_capacity_tokens":
                 self.config.num_pages * self.config.page_size,

@@ -285,7 +285,11 @@ def _kv_gather_deq(b: _B, pool, scale, tables, kv_dtype, tag):
     then ``kv_dequant`` back to f32 (int8: the SAME gather applied to
     the scale pool rides along, so each page meets its own scale).  The
     f32 path emits the plain gather — byte-identical to the unquantized
-    program."""
+    program.  The gather runs on the pool AS STORED (``KVCacheConfig.
+    pool_shape``: pages on axis 1, a page ``(rows, width)``); the callers'
+    reshape of the GATHERED pages to ``(…, tokens, D)`` reads them back
+    as token rows, both forms being row-major — never a reshape of a
+    pool."""
     g = b.tmp(tag)
     b.op("gather", {"X": [pool], "Index": [tables]}, {"Out": [g]},
          {"axis": 1})
@@ -464,7 +468,7 @@ def build_decoder_program(cfg: DecoderConfig, mode: str,
             q4 = b.transpose(b.reshape(q, [0, 0, H, D]), [0, 2, 1, 3],
                              f"l{i}_q4")                 # (1, H, S, D)
             kg = _kv_gather_deq(b, kc, ksc, tables, kv_dtype,
-                                f"l{i}_kg")              # (H, W, ps, D)
+                                f"l{i}_kg")      # (H, W) + a stored page
             k4 = b.reshape(kg, [1, H, -1, D], f"l{i}_k4")  # (1, H, C, D)
             vg = _kv_gather_deq(b, vc, vsc, tables, kv_dtype,
                                 f"l{i}_vg")
@@ -528,10 +532,10 @@ def build_decoder_program(cfg: DecoderConfig, mode: str,
             _kv_append(b, k3, v3, slot_map, kc, vc, ksc, vsc)
             q4 = b.transpose(b.reshape(q, [0, 0, H, D]), [0, 2, 1, 3],
                              f"l{i}_q4")                    # (B, H, S, D)
-            # per-row block-table gather: (H, P, ps, D) indexed by the
-            # (B, W) tables -> (H, B, W, ps, D) (dequantized back to f32
-            # for quantized storage), batch-major, flattened to each
-            # row's context window
+            # per-row block-table gather: the stored pool (H, P, rows,
+            # width) indexed by the (B, W) tables -> (H, B, W, rows,
+            # width) (dequantized back to f32 for quantized storage),
+            # batch-major, flattened to each row's context window
             kg = _kv_gather_deq(b, kc, ksc, tables, kv_dtype, f"l{i}_kg")
             k4 = b.reshape(b.transpose(kg, [1, 0, 2, 3, 4]),
                            [0, 0, -1, D], f"l{i}_k4")       # (B, H, C, D)
@@ -1651,7 +1655,8 @@ class _EngineCore:
     def kv_pool_resident_bytes(self) -> int:
         """PER-DEVICE bytes pinned by the paged K/V pools for the
         engine's lifetime: 2 pools (K and V) per layer at the
-        allocator's fixed shape, PLUS the int8 scale pools when the
+        allocator's fixed shape (stored lane-full or not, the same
+        bytes: no padding), PLUS the int8 scale pools when the
         storage is quantized — the ``kv_pool`` resident block the
         static planner (framework/memory_plan.py) charges against the
         HBM budget.  Under TP the pools (and scale pools) shard on

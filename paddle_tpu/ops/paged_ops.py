@@ -12,12 +12,8 @@ Three ops, shared by the continuous-batching engine
   append kernel (ops/pallas_kernels.py ``kv_append``) aliases its pool
   operand onto its output and moves only the blocks it writes, in the
   layout the chip holds the pool in — the append reads and writes no
-  whole pool (``chip_smoke.py`` checks the compiled programs; a prefill
-  program holds no pool-sized copy at all).  An XLA scatter here cost
-  two pool-sized layout copies per pool per call.  What is left is
-  ``paged_attention``'s: where head_dim is under the 128 lanes the chip
-  holds the pool page-minor, and the decode kernel's operands are
-  re-laid row-major once a call.
+  whole pool.  An XLA scatter here cost two pool-sized layout copies per
+  pool per call.
 * ``paged_attention`` — each decode query gathers K/V through its block
   table at its true length (ops/pallas_kernels.py: Pallas kernel on
   TPU, gather fallback on CPU with identical semantics).
@@ -25,6 +21,18 @@ Three ops, shared by the continuous-batching engine
   the gathered per-(kv_head, page) scales), so the chunk / spec-verify
   dense-attention forms accumulate in full precision regardless of the
   storage dtype.
+
+A pool reaches these ops AS STORED (``KVCacheConfig.pool_shape``):
+logically ``(kv_heads, num_pages, page_size, head_dim)``; where head_dim
+is under the 128 lanes and a page is whole tiles, ``(kv_heads,
+num_pages, page_size * head_dim / 128, 128)``, a row-major bitcast with
+``128 / head_dim`` tokens side by side in a row.  The chip's compiler
+holds that shape row-major by its own choice, and both kernels work on
+it there: no program copies, reshapes or transposes a whole pool
+(``chip_smoke.py`` reads the compiled prefill and decode programs and
+refuses one that does).  head_dim comes from K / Q, never from the
+pool; page numbers address axis 1 in either form; whatever needs
+``(tokens, head_dim)`` rows reshapes GATHERED pages.
 
 Quantized storage (``FLAGS_kv_cache_dtype``): bf16 pools need no extra
 state — the existing ``astype(pool.dtype)`` on write and a cast on read
@@ -110,8 +118,8 @@ def _kv_cache_append(ctx):
     (``page_id * page_size + offset``) from the allocator — an
     out-of-range slot (``num_pages * page_size``, the allocator's pad
     sentinel) drops the write, so bucket-padded positions never touch
-    the pool; KCache/VCache ``(kv_heads, num_pages, page_size,
-    head_dim)`` pools; optional KScale/VScale ``(kv_heads, num_pages)``
+    the pool; KCache/VCache pools as stored (module docstring);
+    optional KScale/VScale ``(kv_heads, num_pages)``
     f32 scale pools (int8 storage only).  Outputs KCacheOut/VCacheOut
     (+ KScaleOut/VScaleOut when scales are present) alias the pool vars
     (in-place update)."""
@@ -120,7 +128,8 @@ def _kv_cache_append(ctx):
     slots = ctx.in_("SlotMapping").astype(jnp.int32)
     k_pool = ctx.in_("KCache")
     v_pool = ctx.in_("VCache")
-    page_size = k_pool.shape[2]
+    # a stored page is (rows, width) whatever the tokens a row
+    page_size = k_pool.shape[2] * k_pool.shape[3] // k.shape[-1]
 
     if ctx.has_input("KScale"):
         kq, ks = _quant_scatter(

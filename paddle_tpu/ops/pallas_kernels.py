@@ -760,16 +760,43 @@ def flash_attention_bwd_res(q, k, v, out, lse, do, bias=None, causal=False,
 # ==========================================================================
 # Ragged paged attention (decode) — the serving-runtime kernel
 # ==========================================================================
-# KV pools are laid out ``(kv_heads, num_pages, page_size, head_dim)``:
+# KV pools are LOGICALLY ``(kv_heads, num_pages, page_size, head_dim)``:
 # head-major so each (seq, head, page) grid step reads one contiguous
-# (page_size, head_dim) tile, page-granular so the serving allocator
-# (inference/kv_cache.py) can hand pages to sequences in any order.
+# page, page-granular so the serving allocator (inference/kv_cache.py)
+# can hand pages to sequences in any order.  They are STORED with rows
+# that fill the 128 lanes wherever head_dim leaves lanes empty
+# (``KVCacheConfig.pool_shape``): ``(kv_heads, num_pages, page_size *
+# head_dim / 128, 128)``, ``t = 128 / head_dim`` consecutive tokens of a
+# page side by side in a row — a row-major bitcast of the logical pool,
+# and the shape the chip's compiler holds row-major in exact (8, 128)
+# tiles by its own choice (a ``head_dim``-64 pool in the logical shape
+# it holds page-minor, and every kernel that wants pages costs a
+# re-layout of the whole pool).  Every function here takes the stored
+# pool and reads ``t`` from the shapes: the pool's last axis over
+# head_dim, which ``q`` (the append: the rows) carries.  ``t = 1`` is the
+# logical shape itself.
 # Each decode query attends at its TRUE length: the grid walks only
 # ``block_tables.shape[1]`` pages (the scheduler buckets that to the
 # longest ACTIVE sequence, never the model max), whole pages past
 # ``context_lens[b]`` are skipped before their tiles are touched, and
 # the tail page masks per-token — mixed-length batches never pad to
 # max-seq (Ragged Paged Attention, arXiv 2604.15464).
+
+
+def _pool_tiles_ok(d: int, page_rows: int, width: int) -> bool:
+    """Whether the pool kernels can address a stored pool's pages: a
+    page's rows whole sublane groups of 8, and head_dim a multiple of 8
+    unless the pool is lane-full (there it only has to divide the
+    lanes, which being stored that way says)."""
+    return page_rows % 8 == 0 and (d % 8 == 0 or width != d)
+
+
+def _in_lane_group(shape, lo, d: int):
+    """Mask over ``shape``: the lanes ``[lo, lo + d)`` of the last axis,
+    one token's place in a lane-full row (``lo``: a scalar, or an array
+    that broadcasts against ``shape``)."""
+    lane = lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane >= lo) & (lane < lo + d)
 
 
 def _gqa_group(n_heads: int, n_kv: int) -> int:
@@ -796,7 +823,10 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     semantics, so tier-1 exercises the same op contract.
 
     q: (num_seqs, q_heads, head_dim) — one decode token per sequence.
-    k_pages/v_pages: (kv_heads, num_pages, page_size, head_dim) pools.
+    k_pages/v_pages: (kv_heads, num_pages, page_size, head_dim) pools,
+    or their lane-full stored form (section comment): the gathered pages
+    are read back as (tokens, head_dim) rows either way, a row-major
+    reshape of the gather's result and never of a pool.
     block_tables: (num_seqs, pages_per_seq) int32 — pool page ids, in
     sequence order; entries past the sequence's last page must hold any
     valid page id (the scheduler pads with 0) — they are masked out.
@@ -812,12 +842,12 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     f32 path is untouched (the cast is a trace-time no-op).
     """
     n_seqs, n_heads, d = q.shape
-    n_kv, _, page_size, _ = k_pages.shape
+    n_kv = k_pages.shape[0]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     group = _gqa_group(n_heads, n_kv)
     flat = block_tables.reshape(-1)
-    # (kv_heads, seqs*pages, page_size, d) — sized by the BUCKETED table
+    # (kv_heads, seqs*pages, rows, width) — sized by the BUCKETED table
     # width (longest active sequence), not the model max
     k = jnp.take(k_pages, flat, axis=1)
     v = jnp.take(v_pages, flat, axis=1)
@@ -841,10 +871,26 @@ def paged_attention_reference(q, k_pages, v_pages, block_tables,
     return jnp.einsum("bhk,bhkd->bhd", p.astype(v.dtype), v).astype(q.dtype)
 
 
-def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
+def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant,
+                         d):
     """One (seq, head, page) step of the ragged decode walk: online
-    softmax over the page's (page_size, d) K/V tile, accumulated in VMEM
-    scratch exactly like the flash kernel's kv walk.
+    softmax over the page's K/V tile, accumulated in VMEM scratch
+    exactly like the flash kernel's kv walk.
+
+    The tile is the page as the pool stores it, ``(rows, width)`` with
+    ``t = width // d`` tokens side by side in a row (``t = 1``: the
+    logical ``(page_size, d)``).  ``q`` arrives as ``(t, width)``, the
+    query in lane group ``g`` of row ``g`` and zero elsewhere, so ONE
+    contraction over the lanes gives the ``(t, rows)`` scores of the
+    tile: entry ``(g, r)`` is the token at offset ``r * t + g``.  Each
+    lane group is its own softmax stream down the walk — row ``g`` of
+    the ``m``/``l``/``acc`` scratch — so a step reduces along the lanes
+    only, as the ``t = 1`` walk does.  ``p @ v`` is ``(t, width)``, of
+    which row ``g`` is right in lane group ``g`` alone (elsewhere it
+    pairs a token's weight with its neighbours' values: finite, carried
+    along, never read).  The last step joins the streams by their
+    maxima and keeps each row's own lane group; the wrapper folds the
+    ``t`` groups of the ``(1, width)`` result into ``d`` lanes.
 
     ``quant`` (static): two extra scalar-prefetch refs carry the
     per-(kv_head, page) int8 absmax scales; the page's K/V tiles
@@ -857,6 +903,7 @@ def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
     else:
         (bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
          m_scr, l_scr, acc_scr) = refs
+    t = k_ref.shape[-1] // d
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -876,8 +923,8 @@ def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
 
     @pl.when(start < ctx)
     def _page():
-        q = q_ref[0, 0]                                # (1, d)
-        k = k_ref[0, 0]                                # (page_size, d)
+        q = q_ref[0, 0]                                # (t, width)
+        k = k_ref[0, 0]                                # (rows, width)
         v = v_ref[0, 0]
         if quant:
             k = k.astype(jnp.float32) * k_deq
@@ -887,9 +934,14 @@ def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
             v = v.astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        cols = start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < ctx, s, DEFAULT_MASK_VALUE)
-        m_prev = m_scr[...]                            # (1, 128) lane-bcast
+        pos = start + lax.broadcasted_iota(jnp.int32, s.shape, 1) * t
+        if t > 1:
+            pos += lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        # a stream whose tokens so far are all past the context carries
+        # the mask value as its maximum: finite, and its weight at the
+        # join is exp(mask - a true score) = 0
+        s = jnp.where(pos < ctx, s, DEFAULT_MASK_VALUE)
+        m_prev = m_scr[...]                            # (t, 128) lane-bcast
         l_prev = l_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -902,15 +954,28 @@ def _paged_decode_kernel(*refs, scale, page_size, n_pages, group, quant):
 
     @pl.when(i == n_pages - 1)
     def _done():
-        l_fin = l_scr[...]
+        l_fin, acc = l_scr[...], acc_scr[...]
+        if t > 1:
+            m = m_scr[...]
+            # -inf only where no page was walked (a padded row of the
+            # batch, context 0): weight 0, so the row reads 0 as at t = 1
+            w = jnp.where(m == -jnp.inf, 0.0,
+                          jnp.exp(m - jnp.max(m, axis=0, keepdims=True)))
+            l_fin = jnp.sum(w * l_fin, axis=0, keepdims=True)
+            own = _in_lane_group(
+                acc.shape, lax.broadcasted_iota(jnp.int32, acc.shape, 0) * d,
+                d)
+            acc = jnp.sum(jnp.where(own, acc * w, 0.0), axis=0,
+                          keepdims=True)
         l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
-        o_ref[0, 0] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc / l_safe[:, :1]).astype(o_ref.dtype)
 
 
 def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
                        scale, k_scale=None, v_scale=None):
     n_seqs, n_heads, d = q.shape
-    n_kv, _, page_size, _ = k_pages.shape
+    n_kv, _, rows, width = k_pages.shape
+    t = width // d                  # tokens a stored row (section comment)
     group = _gqa_group(n_heads, n_kv)
     n_pages = block_tables.shape[1]
     quant = k_scale is not None
@@ -928,65 +993,77 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
         # (kv_heads, num_pages) f32 tables indexed per (head, page)
         num_scalar_prefetch=4 if quant else 2,
         grid=(n_seqs, n_heads, n_pages),
-        # q/out ride as (seqs, heads, 1, d): Mosaic wants a block's last
-        # two dims (8, 128)-aligned or equal to the array's, and a
-        # (1, 1, d) block of a (seqs, heads, d) array is neither
+        # q/out ride as (seqs, heads, t | 1, width): Mosaic wants a
+        # block's last two dims (8, 128)-aligned or equal to the
+        # array's, and a (1, width) block of a (seqs, heads, width)
+        # array is neither
         in_specs=[
-            pl.BlockSpec((1, 1, 1, d), _q_idx),
-            pl.BlockSpec((1, 1, page_size, d), _kv_idx),
-            pl.BlockSpec((1, 1, page_size, d), _kv_idx),
+            pl.BlockSpec((1, 1, t, width), _q_idx),
+            pl.BlockSpec((1, 1, rows, width), _kv_idx),
+            pl.BlockSpec((1, 1, rows, width), _kv_idx),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, d), _q_idx),
+        out_specs=pl.BlockSpec((1, 1, 1, width), _q_idx),
         scratch_shapes=[
-            pltpu.VMEM((1, LANES), jnp.float32),
-            pltpu.VMEM((1, LANES), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((t, LANES), jnp.float32),
+            pltpu.VMEM((t, LANES), jnp.float32),
+            pltpu.VMEM((t, width), jnp.float32),
         ],
     )
     call = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=scale,
-                          page_size=page_size, n_pages=n_pages,
-                          group=group, quant=quant),
+                          page_size=rows * t, n_pages=n_pages,
+                          group=group, quant=quant, d=d),
         name="paged_decode",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_seqs, n_heads, 1, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_seqs, n_heads, 1, width),
+                                       q.dtype),
         interpret=_interpret(),
     )
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
     q4 = q[:, :, None, :]
+    if t > 1:
+        # the query in lane group g of row g, zero elsewhere
+        q4 = (jnp.eye(t, dtype=q.dtype)[:, :, None]
+              * q4[:, :, :, None, :]).reshape(n_seqs, n_heads, t, width)
     if quant:
         out = call(bt, cl, k_scale.astype(jnp.float32),
                    v_scale.astype(jnp.float32), q4, k_pages, v_pages)
     else:
         out = call(bt, cl, q4, k_pages, v_pages)
-    return out[:, :, 0, :]
+    out = out[:, :, 0, :]
+    if t > 1:
+        # each lane group holds the sum over its own tokens
+        out = out.reshape(n_seqs, n_heads, t, d).sum(axis=2)
+    return out
 
 
 # ==========================================================================
 # KV-pool append — the serving-runtime write
 # ==========================================================================
-# An XLA scatter into a ``(kv_heads, num_pages, page_size, head_dim)``
-# pool is re-laid around on the chip (the compiler wants the update
-# window's dims minor) and back: two pool-sized copies per pool per
-# program call, whatever the number of rows written.  This kernel moves
-# only the blocks it writes, WHERE THE POOL LIES, and its output aliases
-# the pool operand, so with the pool donated to the program the append
-# reads and writes no whole pool.
+# An XLA scatter into a pool is re-laid around on the chip (the compiler
+# wants the update window's dims minor) and back: two pool-sized copies
+# per pool per program call, whatever the number of rows written.  This
+# kernel moves only the blocks it writes, WHERE THE POOL LIES, and its
+# output aliases the pool operand, so with the pool donated to the
+# program the append reads and writes no whole pool.
 #
-# Where the pool lies is the chip's choice, by shape.  With head_dim a
-# multiple of the 128 lanes it lies row-major, in the tiles
-# ``paged_decode`` reads, and a token's write is one row of its page's
-# ``(kv_heads, 1, page_size, d)`` block.  With head_dim under the lanes
-# (GPT-2's 64) the chip keeps the PAGE axis minor instead of padding
-# every row to 128 (``{1,3,2,0:T(8,128)}``, ``chip_smoke.py`` prints it):
-# there the kernel works on the transposed view ``(kv_heads, page_size,
-# d, num_pages)``, which is the same bytes (XLA makes the transpose a
-# bitcast), and a token's write is one lane of a ``(kv_heads, 1, d,
-# 128)`` block.  Handing that pool to a kernel in the row-major form
-# instead costs a re-layout of the whole pool on the way in and another
-# on the way out; ``paged_decode`` still pays the first (ROADMAP Queue 1
-# item 1(b)), the append pays neither.
+# Where the pool lies is the chip's choice, by shape.  A pool whose rows
+# fill the 128 lanes — the stored form ``(kv_heads, num_pages, rows,
+# 128)`` of every pool with head_dim under the lanes and pages of whole
+# tiles (``KVCacheConfig.pool_shape``), or head_dim a multiple of 128 —
+# lies row-major, in the tiles ``paged_decode`` reads: a token's write is
+# head_dim lanes of one row of its page's ``(kv_heads, 1, rows, width)``
+# block (``t = width // head_dim`` tokens a row; all of the row where
+# ``t = 1``).  What is left for the third view is a pool that could not
+# be stored lane-full: head_dim 32, 64 or 96 with ``page_size *
+# head_dim`` not a multiple of 1024 (page_size 8 at head_dim 64) or
+# head_dim 96.  There the chip keeps the PAGE axis minor instead of
+# padding every row to 128 (``{1,3,2,0:T(8,128)}``) and the kernel works
+# on the transposed view ``(kv_heads, page_size, d, num_pages)``, which
+# is the same bytes (XLA makes the transpose a bitcast), a token's write
+# being one lane of a ``(kv_heads, 1, d, 128)`` block; ``paged_decode``
+# is handed a re-laid copy of such a pool, the append is not.
 #
 # One grid step per token, tokens ordered by the block they write so
 # that a block's tokens are adjacent: the block is fetched when the walk
@@ -1000,12 +1077,14 @@ def _paged_decode_call(q, k_pages, v_pages, block_tables, context_lens,
 # and select nothing.
 
 
-def _kv_append_kernel(keys_ref, sel_ref, order_ref, *refs, page_minor):
+def _kv_append_kernel(keys_ref, sel_ref, order_ref, *refs, page_minor, d):
     """Grid step ``i`` writes sorted token ``i``: ``refs`` are, for each
-    pool, the token's ``(1, kv_heads, 1, d)`` rows, then each pool's
+    pool, the token's ``(1, kv_heads, 1, width)`` rows, then each pool's
     block as read, then each pool's block to write.  ``sel_ref[i]`` is
-    the row of the block the token writes (``page_minor``: the lane),
-    -1 for a pad token."""
+    the token's offset in its page (``page_minor``: the lane of its
+    page), -1 for a pad token.  ``d`` is head_dim: a row of the block
+    holds ``width // d`` tokens, and the token's row arrives with its
+    values repeated in every lane group."""
     del order_ref                       # the rows' index map reads it
     n = len(refs) // 3
     rows, blocks_in, blocks_out = refs[:n], refs[n:2 * n], refs[2 * n:]
@@ -1026,27 +1105,36 @@ def _kv_append_kernel(keys_ref, sel_ref, order_ref, *refs, page_minor):
             # no 16- or 8-bit compare/select, and both casts are exact
             wide = (jnp.float32 if jnp.issubdtype(cur.dtype, jnp.floating)
                     else jnp.int32)
-            new = row[0].astype(wide)                   # (kv_heads, 1, d)
+            new = row[0].astype(wide)               # (kv_heads, 1, width)
             if page_minor:
                 # the row's d values go down the sublanes of one lane:
                 # turn (1, d) into (d, 1) through the diagonal of (d, d)
-                n_kv, _, d = new.shape
+                n_kv = new.shape[0]
                 diag = (lax.broadcasted_iota(jnp.int32, (n_kv, d, d), 1)
                         == lax.broadcasted_iota(jnp.int32, (n_kv, d, d), 2))
                 new = jnp.sum(jnp.where(diag, new, 0), axis=2, keepdims=True)
-            at = lax.broadcasted_iota(
-                jnp.int32, cur.shape, 3 if page_minor else 2) == sel
+                at = lax.broadcasted_iota(jnp.int32, cur.shape, 3) == sel
+            else:
+                t = cur.shape[3] // d
+                at = lax.broadcasted_iota(
+                    jnp.int32, cur.shape, 2) == lax.div(sel, t)
+                if t > 1:
+                    at &= _in_lane_group(cur.shape, lax.rem(sel, t) * d, d)
             dst[...] = jnp.where(at, new[:, None],
                                  cur.astype(wide)).astype(cur.dtype)
 
 
 @functools.partial(jax.jit, static_argnames="page_minor")
 def _kv_append_call(pools, rows, slots, page_minor):
-    """``pools``: tuple of ``(kv_heads, num_pages, page_size, d)`` pools
-    of one shape; ``rows``: per pool ``(tokens, kv_heads, d)`` in the
-    pool's dtype; ``slots``: ``(tokens,)`` int32.  Jitted so that the
-    layers of one program share one trace and one Mosaic lowering."""
-    n_kv, n_pages, page_size, d = pools[0].shape
+    """``pools``: tuple of stored pools of one shape, ``(kv_heads,
+    num_pages, page_rows, width)``; ``rows``: per pool ``(tokens,
+    kv_heads, d)`` in the pool's dtype; ``slots``: ``(tokens,)`` int32.
+    Jitted so that the layers of one program share one trace and one
+    Mosaic lowering."""
+    n_kv, n_pages, page_rows, width = pools[0].shape
+    d = rows[0].shape[-1]
+    t = width // d
+    page_size = page_rows * t
     valid = (slots >= 0) & (slots < n_pages * page_size)
     page, off = lax.div(slots, page_size), lax.rem(slots, page_size)
     if page_minor:
@@ -1061,7 +1149,7 @@ def _kv_append_call(pools, rows, slots, page_minor):
                     lax.rem(keys[i], per_off))
     else:
         key, sel = page, off
-        block = (n_kv, 1, page_size, d)
+        block = (n_kv, 1, page_rows, width)
 
         def _block_idx(i, keys, sel, order):
             return (0, keys[i], 0, 0)
@@ -1075,13 +1163,15 @@ def _kv_append_call(pools, rows, slots, page_minor):
     order = order.astype(jnp.int32)
     key = jnp.where(valid, key, jnp.max(jnp.where(valid, key, 0)))[order]
     sel = jnp.where(valid, sel, -1)[order]
-    # rows ride as (tokens, kv_heads, 1, d): a block's last two dims
-    # must be (8, 128)-aligned or the array's own (as paged_decode's q)
-    row_spec = pl.BlockSpec((1, n_kv, 1, d), _row_idx)
+    # rows ride as (tokens, kv_heads, 1, width), a token's values in every
+    # lane group of its row (the kernel selects the token's own): a
+    # block's last two dims must be (8, 128)-aligned or the array's own
+    # (as paged_decode's q)
+    row_spec = pl.BlockSpec((1, n_kv, 1, width), _row_idx)
     block_spec = pl.BlockSpec(block, _block_idx)
     n = len(pools)
     out = pl.pallas_call(
-        functools.partial(_kv_append_kernel, page_minor=page_minor),
+        functools.partial(_kv_append_kernel, page_minor=page_minor, d=d),
         name="kv_append",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -1092,36 +1182,52 @@ def _kv_append_call(pools, rows, slots, page_minor):
         # operand indices count the three scalar-prefetch arguments
         input_output_aliases={3 + n + j: j for j in range(n)},
         interpret=_interpret(),
-    )(key, sel, order, *[r[:, :, None, :] for r in rows], *pools)
+    )(key, sel, order,
+      *[jnp.tile(r, t)[:, :, None, :] for r in rows], *pools)
     return [o.transpose(0, 3, 1, 2) for o in out] if page_minor else out
 
 
 def kv_append(pools, rows, slots):
     """Write ``rows[j][t]`` (``(tokens, kv_heads, d)``, the pool's
-    dtype) to flat slot ``slots[t]`` of ``pools[j]`` (``(kv_heads,
-    num_pages, page_size, d)``), for every pool of the tuple; a slot
-    outside the pool (the allocator's pad sentinel, ``num_pages *
-    page_size``) drops its row.  Returns the new pools.
+    dtype) to flat slot ``slots[t]`` of ``pools[j]``, for every pool of
+    the tuple; a slot outside the pool (the allocator's pad sentinel,
+    ``num_pages * page_size``) drops its row.  Returns the new pools.
+
+    A pool arrives as it is stored (the paged-attention section
+    comment): ``(kv_heads, num_pages, page_rows, width)`` with ``width
+    // d`` tokens a row — ``(…, page_size, d)`` itself, or its lane-full
+    form ``(…, page_size * d / 128, 128)``.
 
     Engages like :func:`paged_attention`: the Pallas kernel on TPU (or
-    under PT_PALLAS_INTERPRET=1) when page_size and head_dim are
-    multiples of 8; elsewhere a scatter by ``(page, offset)`` on the
-    4-D pool — the same result, and the tests' reference.  The kernel
-    takes the pool in the view the chip holds it in (section comment):
-    page-minor when head_dim leaves lanes empty (and, a block's sublane
-    axis there, is whole tiles for every storage type: 32 rows of int8)
-    and the pages fill whole lane blocks; row-major otherwise."""
-    _, n_pages, page_size, d = pools[0].shape
+    under PT_PALLAS_INTERPRET=1) when a page's rows are whole sublane
+    groups of 8 (``_pool_tiles_ok``); elsewhere a scatter by
+    ``(page, offset)`` — the same result, and the tests' reference.  The
+    kernel takes the pool in the view the chip holds it in (section
+    comment): row-major for a lane-full pool; page-minor for what
+    could not be stored lane-full though head_dim leaves lanes empty
+    (there a block's sublane axis is whole tiles for every storage
+    type, 32 rows of int8, and the pages must fill whole lane blocks)."""
+    _, n_pages, page_rows, width = pools[0].shape
+    d = rows[0].shape[-1]
+    t = width // d
     slots = slots.astype(jnp.int32)
-    if _use_pallas() and d % 8 == 0 and page_size % 8 == 0:
+    if _use_pallas() and _pool_tiles_ok(d, page_rows, width):
         return tuple(_kv_append_call(
             tuple(pools), tuple(rows), slots,
-            page_minor=d % LANES != 0 and d % 32 == 0
+            page_minor=width % LANES != 0 and d % 32 == 0
             and n_pages % LANES == 0))
     # 'drop' makes the sentinel (page == num_pages) a no-op
-    page, off = slots // page_size, slots % page_size
-    return tuple(p.at[:, page, off, :].set(r.transpose(1, 0, 2), mode="drop")
-                 for p, r in zip(pools, rows))
+    page, off = slots // (page_rows * t), slots % (page_rows * t)
+    if t == 1:
+        return tuple(
+            p.at[:, page, off, :].set(r.transpose(1, 0, 2), mode="drop")
+            for p, r in zip(pools, rows))
+    # a token's d values are lanes [g * d, (g + 1) * d) of its row
+    lanes = (off % t * d)[:, None] + jnp.arange(d)
+    return tuple(
+        p.at[:, page[:, None], (off // t)[:, None], lanes].set(
+            r.transpose(1, 0, 2), mode="drop")
+        for p, r in zip(pools, rows))
 
 
 # ==========================================================================
@@ -1447,15 +1553,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     kernel past the backend check (combine with PT_PALLAS_INTERPRET=1
     off-TPU — a forced kernel on plain CPU fails loudly rather than
     silently measuring the fallback).  Hard shape constraints always
-    gate: head_dim and page_size multiples of 8 (sublane), q_heads a
-    multiple of kv_heads; anything else falls back."""
+    gate: head_dim and a page's stored rows multiples of 8 (sublane),
+    q_heads a multiple of kv_heads; anything else falls back.  The pools
+    arrive as stored (the section comment): logical, or lane-full."""
     n_seqs, n_heads, d = q.shape
-    n_kv = k_pages.shape[0]
-    page_size = k_pages.shape[2]
+    n_kv, _, page_rows, width = k_pages.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     force = os.environ.get("PT_PAGED_ATTENTION")
-    shape_ok = (d % 8 == 0 and page_size % 8 == 0 and n_heads % n_kv == 0)
+    shape_ok = _pool_tiles_ok(d, page_rows, width) and n_heads % n_kv == 0
     eligible = shape_ok and (_use_pallas() or force == "1")
     if force == "0" or not eligible:
         return paged_attention_reference(q, k_pages, v_pages, block_tables,

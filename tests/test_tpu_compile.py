@@ -83,6 +83,14 @@ def _flash_case(causal, shape, dropout, multi_block):
                                    ((1,), jnp.float32), (shape, bf)]
 
 
+def _stored(head_dim, page_size=PAGE, kv_dtype="float32"):
+    """The shape the engine stores a pool of that geometry in."""
+    from paddle_tpu.inference.kv_cache import KVCacheConfig
+
+    return KVCacheConfig(POOL_PAGES, page_size, DEC_HEADS, head_dim,
+                         dtype=jnp.dtype(kv_dtype).name).pool_shape()
+
+
 def _paged_case(kv_dtype):
     quant = kv_dtype == jnp.int8
 
@@ -91,7 +99,7 @@ def _paged_case(kv_dtype):
         return pk._paged_decode_call(q, kp, vp, bt, cl, DEC_D ** -0.5,
                                      k_scale=ks, v_scale=vs)
 
-    pool = ((DEC_HEADS, POOL_PAGES, PAGE, DEC_D), kv_dtype)
+    pool = (_stored(DEC_D, kv_dtype=kv_dtype), kv_dtype)
     shapes = [((DEC_SEQS, DEC_HEADS, DEC_D), jnp.float32), pool, pool,
               ((DEC_SEQS, TABLE_W), jnp.int32), ((DEC_SEQS,), jnp.int32)]
     if quant:
@@ -99,14 +107,16 @@ def _paged_case(kv_dtype):
     return f, shapes
 
 
-def _append_case(kv_dtype, tokens, head_dim=DEC_D):
+def _append_case(kv_dtype, tokens, head_dim=DEC_D, page_size=PAGE):
     """K and V of one layer in one call, at a decode batch's and at a
-    prefill bucket's slot count; head_dim 64 takes the kernel's page-minor
-    view of the pool, 128 its row-major one."""
+    prefill bucket's slot count, on the pool as the engine stores it:
+    head_dim 64 lane-full (two tokens a row), 128 as it is, and head_dim
+    64 with pages of 8 (half a tile: not stored lane-full) in the kernel's
+    page-minor view."""
     def f(kp, vp, k, v, slots):
         return pk.kv_append((kp, vp), (k, v), slots)
 
-    pool = ((DEC_HEADS, POOL_PAGES, PAGE, head_dim), kv_dtype)
+    pool = (_stored(head_dim, page_size, kv_dtype), kv_dtype)
     rows = ((tokens, DEC_HEADS, head_dim), kv_dtype)
     return f, [pool, pool, rows, rows, ((tokens,), jnp.int32)]
 
@@ -165,6 +175,9 @@ CASES = {
        functools.partial(_append_case, t, n, d)
        for t in (jnp.float32, jnp.bfloat16, jnp.int8) for n in (64, 1024)
        for d in (DEC_D, 128)},
+    **{f"kv_append-{jnp.dtype(t).name}-64-d{DEC_D}-page8":
+       functools.partial(_append_case, t, 64, DEC_D, 8)
+       for t in (jnp.float32, jnp.bfloat16, jnp.int8)},
     **{f"bn_act-fwd-{'x'.join(map(str, s))}{'-res' if r else ''}":
        functools.partial(_bn_fwd_case, s, r)
        for s in RESNET_STAGES for r in (False, True)},
@@ -187,54 +200,69 @@ def test_kernel_compiles_for_v5e(name, v5e, monkeypatch):
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
 
 
-@pytest.mark.parametrize("head_dim,held,decode_copies", [
-    # head_dim under the 128 lanes: the chip holds the pool page-minor
-    # ({1,3,2,0}); paged_decode's two operands are re-laid, the append's none
-    (DEC_D, (0, 2, 3, 1), 2),
-    # lane-full rows: held row-major, the tiles both kernels address
-    (128, (0, 1, 2, 3), 0),
+@pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("head_dim,page_size,decode_copies", [
+    # head_dim under the 128 lanes, a page whole tiles: stored lane-full,
+    # held row-major by the compiler's own choice, nothing re-laid
+    (DEC_D, PAGE, 0),
+    # lane-full rows as they are: the same
+    (128, PAGE, 0),
+    # half a tile a page: stored as the logical shape, which the chip holds
+    # page-minor ({1,3,2,0}); the append works on that view, paged_decode's
+    # two operands are re-laid
+    (DEC_D, 8, 2),
 ])
-def test_append_writes_the_pool_where_the_chip_holds_it(
-        head_dim, held, decode_copies, v5e, monkeypatch):
+def test_pool_kernels_work_where_the_chip_holds_the_pool(
+        head_dim, page_size, decode_copies, kv_dtype, v5e, monkeypatch):
     """One layer of the prefill program (append alone) and of the decode
-    program (append, then paged decode), the pools donated and pinned to
-    the layout the chip's compiler itself gives a pool of that shape: the
-    append moves no whole pool and needs no temporary; what the decode
-    program copies is paged_decode's operands and nothing else."""
-    from jax.experimental.layout import Format, Layout
-
-    from chip_smoke import pool_traffic     # the reader the smoke uses
+    program (append, then paged decode), the pools donated, in the shape
+    the engine stores them in and in whatever layout the chip's compiler
+    gives that shape — no layout is asked for anywhere: neither kernel
+    moves, reshapes or transposes a whole pool and the append needs no
+    temporary; a pool that cannot be stored lane-full costs paged_decode's
+    operand copies and nothing else."""
+    from chip_smoke import pool_forms, pool_traffic  # the smoke's reader
 
     monkeypatch.setattr(pk, "_use_pallas", lambda: True)
     monkeypatch.setattr(pk, "_interpret", lambda: False)
-    pool_shape = (DEC_HEADS, POOL_PAGES, PAGE, head_dim)
-    pinned = Format(Layout(major_to_minor=held, tiling=((8, 128),)), v5e)
+    stored = _stored(head_dim, page_size, kv_dtype)
+    lane_full = stored[3] % 128 == 0
+    assert lane_full == (decode_copies == 0)
+    forms = pool_forms(stored, head_dim)
 
-    def arg(shape, dtype, sharding=v5e):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
 
-    pool = arg(pool_shape, jnp.float32, pinned)
-    rows = arg((DEC_SEQS, DEC_HEADS, head_dim), jnp.float32)
+    pool = arg(stored, kv_dtype)
+    rows = arg((DEC_SEQS, DEC_HEADS, head_dim), kv_dtype)
     ints = arg((DEC_SEQS,), jnp.int32)
+    scales = [arg((DEC_HEADS, POOL_PAGES), jnp.float32)] * 2 \
+        if kv_dtype == "int8" else []
 
     def prefill(kp, vp, k, v, slots):
         return pk.kv_append((kp, vp), (k, v), slots)
 
-    def decode(kp, vp, k, v, slots, q, bt, cl):
+    def decode(kp, vp, k, v, slots, q, bt, cl, *sc):
         kp, vp = pk.kv_append((kp, vp), (k, v), slots)
-        return kp, vp, pk.paged_attention(q, kp, vp, bt, cl)
+        ks, vs = sc or (None, None)
+        return kp, vp, pk.paged_attention(q, kp, vp, bt, cl,
+                                          k_scale=ks, v_scale=vs)
 
-    compiled = jax.jit(prefill, donate_argnums=(0, 1),
-                       out_shardings=(pinned, pinned)
+    compiled = jax.jit(prefill, donate_argnums=(0, 1)
                        ).lower(pool, pool, rows, rows, ints).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
-    moved, _, at_rest = pool_traffic(compiled.as_text(), pool_shape)
+    moved, _, at_rest = pool_traffic(compiled.as_text(), forms,
+                                     views_free=not lane_full)
     assert moved == [] and len(at_rest) == 1, (moved, at_rest)
+    # the compiler's own choice for the stored shape
+    assert ("{3,2,1,0" in at_rest[0]) == lane_full, at_rest
 
-    compiled = jax.jit(decode, donate_argnums=(0, 1),
-                       out_shardings=(pinned, pinned, v5e)
-                       ).lower(pool, pool, rows, rows, ints, rows,
+    compiled = jax.jit(decode, donate_argnums=(0, 1)
+                       ).lower(pool, pool, rows, rows, ints,
+                               arg((DEC_SEQS, DEC_HEADS, head_dim),
+                                   jnp.float32),
                                arg((DEC_SEQS, TABLE_W), jnp.int32),
-                               ints).compile()
-    moved, _, _ = pool_traffic(compiled.as_text(), pool_shape)
+                               ints, *scales).compile()
+    moved, _, _ = pool_traffic(compiled.as_text(), forms,
+                               views_free=not lane_full)
     assert [op for op, _ in moved] == ["copy"] * decode_copies, moved
