@@ -407,7 +407,7 @@ def phase_resnet(ctx):
     mark = ctx.watch.mark()
     with scope_guard(Scope()):
         exe.run(startup)
-        # staged once, as bench.py does: the step is measured, not the feed
+        # staged once: the step is measured, not the feed
         feed = {k: ctx.jax.device_put(v, ctx.device) for k, v in feed.items()}
         train_phase(
             ctx, "train/resnet50", mark,

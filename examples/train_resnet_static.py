@@ -72,7 +72,8 @@ def main():
                   f"acc1 {float(np.asarray(out[1])):.3f}", flush=True)
     dt = time.perf_counter() - t0
     print(f"{args.steps} steps, {args.batch * args.steps / dt:.1f} img/s "
-          "(incl. host feeds; see bench.py for the device-staged number)")
+          "(incl. host feeds; the benchmark cell is resnet50.train-b128, "
+          "python3 benchmark/run.py)")
 
 
 if __name__ == "__main__":

@@ -716,8 +716,8 @@ class PSClient:
 
     def rpc_count(self) -> int:
         """Total completed client round trips (JSON control path +
-        native data plane) — the RTT-per-step accounting bench.py's
-        widedeep mode reports (BASELINE metric #5).  A call that
+        native data plane) — the RTT-per-step accounting of the
+        wide_deep path (BASELINE metric #5).  A call that
         succeeds after N transport retries counts ONE completed round
         trip (plus N in ``retry_count()``): the metric is end-to-end
         RPCs, not wire attempts."""

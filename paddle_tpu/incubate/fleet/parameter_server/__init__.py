@@ -102,6 +102,13 @@ class FleetTranspiler(Fleet):
             AsyncCommunicator, GeoSgdCommunicator, HalfAsyncCommunicator)
 
         mode = getattr(t, "mode", DistributedMode.SYNC)
+        if mode == DistributedMode.SYNC and self.worker_num() > 1:
+            # init_dense overwrites the table: a worker that pushed its
+            # first gradients before trainer 0's values arrived would
+            # lose them.  Sync rounds already meet at barriers, so every
+            # worker is there to meet at this one (reference: trainer0
+            # bcast + barrier).
+            self._client.barrier()
         if mode == DistributedMode.ASYNC:
             runtime.set_communicator(
                 AsyncCommunicator(self._client).start())

@@ -31,7 +31,6 @@ from .serving import (
     DecoderConfig,
     Request,
     ServingEngine,
-    StaticBatchingEngine,
     export_decoder,
 )
 
@@ -42,7 +41,7 @@ __all__ = [
     "load_ptw", "save_ptw",
     # serving runtime (r12)
     "KVCacheConfig", "PagedKVCache", "DecoderConfig", "Request",
-    "ServingEngine", "StaticBatchingEngine", "export_decoder",
+    "ServingEngine", "export_decoder",
     # admission/preemption policy engine (r18)
     "AdmissionPolicy", "FIFOPolicy", "SLOAwarePolicy", "RequestRejected",
     "get_policy",

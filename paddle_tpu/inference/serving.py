@@ -70,7 +70,7 @@ from .spec_decode import NGramProposer, Proposer, SamplingParams, \
 
 __all__ = [
     "DecoderConfig", "Request", "StepEvent", "ServingEngine",
-    "StaticBatchingEngine", "export_decoder", "load_decoder_config",
+    "export_decoder", "load_decoder_config",
     "build_decoder_program", "init_decoder_weights", "RequestRejected",
     "SamplingParams", "decoder_tp_rules", "validate_tp_degree",
     "SERVING_TP_AXIS", "SERVING_TP_RING_ID",
@@ -1668,7 +1668,7 @@ class _EngineCore:
         return 2 * self.cfg.num_layers * per_pool // self.tp
 
     def memory_stats(self) -> dict:
-        """The serving-side memory section (tools/serving_bench.py):
+        """The serving-side memory section (tools/mem_report.py):
         fixed pool residency, the allocator's peak page usage converted
         to bytes, weight bytes, and the device's measured view."""
         from ..utils.memory import measured_peak
@@ -2260,104 +2260,3 @@ class ServingEngine:
             self.submit(r)
         self.run_to_completion()
         return [r.out_tokens for r in reqs]
-
-
-class StaticBatchingEngine:
-    """The A/B baseline: fixed batches run to FULL completion before
-    the next batch forms — no admission mid-decode, stragglers hold
-    their batch slots.  Shares the _EngineCore (same model, same
-    kernels); only the policy differs.
-
-    Group formation reserves WORST-CASE pages (prompt + max_new_tokens)
-    per member — the classic static-batching contract — so mid-decode
-    growth can never exhaust the pool (the continuous engine handles
-    that case with preemption; this baseline has no such mechanism)."""
-
-    def __init__(self, core: _EngineCore, batch_size: int = 8):
-        self.core = core
-        self.batch_size = batch_size
-        self.waiting: List[Request] = []
-        self.group: List[_SeqState] = []
-        self._reserved_pages = 0
-        self.stats = {"admitted": 0, "finished": 0, "decode_steps": 0,
-                      "decode_tokens": 0, "prefill_tokens": 0}
-
-    def submit(self, req: Request):
-        try:
-            _reject_unservable(req, self.core.cfg, self.core.kv_config)
-        except ValueError as e:
-            _count_reject(e)
-            _trace_reject(req, str(e), getattr(e, "reason", "unservable"))
-            raise
-        _trace_submit(req)
-        self.waiting.append(req)
-
-    def has_work(self) -> bool:
-        return bool(self.waiting or self.group)
-
-    def step(self, now: float = 0.0) -> List[StepEvent]:
-        """One iteration, under the continuous engine's span names
-        (``engine/step`` holding the core's ``engine/prefill`` /
-        ``engine/decode`` and ``engine/emit``)."""
-        _TM.current()
-        with RecordEvent("engine/step", "serving"):
-            return self._step(now)
-
-    def _step(self, now: float) -> List[StepEvent]:
-        events: List[StepEvent] = []
-        if not self.group:
-            self._reserved_pages = 0
-            while self.waiting and len(self.group) < self.batch_size:
-                req = self.waiting[0]
-                worst = _worst_case_pages(req, self.core.kv_config)
-                if self._reserved_pages + worst \
-                        > self.core.kv_config.num_pages:
-                    break  # group is as large as worst-case capacity allows
-                self._reserved_pages += worst
-                job = self.core.prefill_job(req)
-                if job is None:
-                    break
-                with RecordEvent("engine/emit", "serving"):
-                    tok = job.first_token
-                    _trace_admit(req, now, job)
-                    self.waiting.pop(0)
-                    req.admitted_at = now
-                    self.stats["admitted"] += 1
-                    self.stats["prefill_tokens"] += len(req.prompt)
-                    st = _SeqState(req, tok)
-                    req.out_tokens.append(tok)
-                    _observe_token(req, now)
-                    if self.core._finished(req, tok):
-                        self.core.kv.free_sequence(req.req_id)
-                        req.finished_at = now
-                        self.stats["finished"] += 1
-                        _trace_finish(req, now)
-                        events.append(StepEvent(req.req_id, tok, True, now))
-                    else:
-                        events.append(
-                            StepEvent(req.req_id, tok, False, now))
-                        self.group.append(st)
-            return events
-        toks = self.core.decode_batch(self.group)
-        with RecordEvent("engine/emit", "serving"):
-            self.stats["decode_steps"] += 1
-            self.stats["decode_tokens"] += len(self.group)
-            _trace_decode(self.group, toks, now, *self.core.decode_wall,
-                          self.stats["decode_steps"], tp=self.core.tp)
-            still = []
-            for st, tok in zip(self.group, toks):
-                st.req.out_tokens.append(tok)
-                st.last_token = tok
-                _observe_token(st.req, now)
-                if self.core._finished(st.req, tok):
-                    self.core.kv.free_sequence(st.req.req_id)
-                    st.req.finished_at = now
-                    self.stats["finished"] += 1
-                    _trace_finish(st.req, now)
-                    events.append(StepEvent(st.req.req_id, tok, True, now))
-                else:
-                    events.append(
-                        StepEvent(st.req.req_id, tok, False, now))
-                    still.append(st)
-            self.group = still
-        return events

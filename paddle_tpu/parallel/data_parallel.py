@@ -461,8 +461,7 @@ def _compile_dp_miss(compiled_program, executor, program, feed,
     label = "dp_" + program_label(program)
     cache = compiled_program.__dict__.setdefault("_dp_cache", {})
     # the chosen plan (or None under flag-driven config) is attached for
-    # introspection: bench.py scaling's plan=auto mode and the tests
-    # read it back
+    # introspection: tests/test_plan_search.py reads it back
     chosen = (plan_report or {}).get("chosen") if plan is not None else None
     compiled_program.__dict__["_plan"] = chosen
     compiled_program.__dict__.setdefault("_plans", {})[key] = chosen

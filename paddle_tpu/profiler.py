@@ -348,10 +348,10 @@ def dropped_events() -> int:
 def _feed_calibration(summary: List[dict]):
     """Profiled step -> cost model: the MIN ``executor_run`` wall time
     becomes the measured step time — the steady-state floor, so a
-    compile-dominated first step can't poison the calibration (same
-    best-of discipline bench.py applies).  Per-name means ride along
-    for finer consumers.  Best-effort: calibration must never break a
-    profiling session."""
+    compile-dominated first step can't poison the calibration (best
+    of several, as tools/dp_comm_stats.py reads a trace).  Per-name
+    means ride along for finer consumers.  Best-effort: calibration
+    must never break a profiling session."""
     try:
         row = next((r for r in summary if r["name"] == "executor_run"), None)
         if row is None:

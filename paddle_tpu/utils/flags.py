@@ -13,15 +13,8 @@ from typing import Any, Dict
 
 _DEFAULTS: Dict[str, Any] = {
     "FLAGS_check_nan_inf": False,          # flags.cc:44
-    "FLAGS_benchmark": False,
-    "FLAGS_eager_delete_tensor_gb": 0.0,   # GC threshold — XLA-managed, stat only
-    "FLAGS_allocator_strategy": "xla_bfc",  # allocator is XLA's; exposed for parity
-    "FLAGS_fraction_of_gpu_memory_to_use": 0.92,
     "FLAGS_fraction_of_cpu_memory_to_use": 1.0,   # cpu_info.cc:70
     "FLAGS_initial_cpu_memory_in_mb": 500,        # cpu_info.cc:81
-    "FLAGS_cudnn_deterministic": False,
-    "FLAGS_enable_parallel_graph": False,
-    "FLAGS_sync_nccl_allreduce": True,
     "FLAGS_communicator_max_merge_var_num": 20,
     "FLAGS_communicator_send_queue_size": 20,
     "FLAGS_communicator_independent_recv_thread": True,
@@ -38,10 +31,8 @@ _DEFAULTS: Dict[str, Any] = {
     "FLAGS_rpc_deadline": 180000,
     "FLAGS_rpc_retry_times": 3,
     "FLAGS_rpc_retry_backoff_ms": 50,
-    "FLAGS_use_pinned_memory": True,
     "FLAGS_seed": 0,
     "FLAGS_enable_unused_var_check": False,
-    "FLAGS_tpu_matmul_precision": "default",  # TPU-native: bf16 matmul control
     "FLAGS_tpu_donate_buffers": True,
     # training-time IR fusion pipeline (reference: build_strategy
     # fuse_bn_act_ops / fuse_bn_add_act_ops); applied by the Executor at
@@ -178,7 +169,7 @@ _DEFAULTS: Dict[str, Any] = {
     # requests that must meet the targets; 1-objective is the error
     # budget the burn rate is measured against) and the rolling
     # request window the burn rate is computed over.  Tools (slo_report
-    # / serving_bench) override these per run via
+    # / overload_bench) override these per run via
     # telemetry.slo_tracker().configure().
     "FLAGS_slo_ttft_ms": 0.0,
     "FLAGS_slo_token_ms": 0.0,

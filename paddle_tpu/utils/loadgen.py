@@ -1,15 +1,23 @@
-"""Open-loop load generation + latency reporting for the serving bench.
+"""A seeded open-loop trace and a reference accounting of what a replay
+of it produced.  NOT a benchmark: timing on the chip is
+benchmark/lib/loadgen.py's, and speed is PERF_LEDGER.jsonl's.
 
-Shared by tools/serving_bench.py and tools/serving_ab.py so the
-serving numbers join the bench trajectory with ONE report format
-(the stable one-line JSON convention bench.py established).
+What it is for: ``latency_report`` and ``per_request_latency`` are
+computed from the raw token stamps of a replay, by code that shares
+nothing with the engine — the INDEPENDENT accounting the tests hold the
+engine's own telemetry histograms and ``SLOTracker`` to
+(tests/test_telemetry.py, tests/test_request_tracing.py;
+tests/test_spec_decode.py takes its repeat-heavy prompts from
+``poisson_trace``), and what tools/slo_report.py reconciles a run
+against.  A reference that tests compare against is not a
+simplification target.  ``emit_json`` is the one-line ``TAG={json}``
+the tools under tools/ end with.
 
 Open-loop means arrivals are a Poisson process fixed in advance by a
 seed — the generator never waits for the system (closed-loop load
 hides queueing collapse: a slow server slows its own offered load).
-The driver replays the trace against an engine exposing
-``submit(request)`` / ``step(now)`` / ``has_work()`` (both
-ServingEngine and StaticBatchingEngine do), stamping real wall-clock
+``replay_trace`` drives an engine exposing ``submit(request)`` /
+``step(now)`` / ``has_work()`` (ServingEngine), stamping wall-clock
 times on every emitted token.
 """
 from __future__ import annotations
@@ -135,8 +143,8 @@ def replay_trace(engine, trace: Sequence[TraceEntry],
 
 
 def pct(xs: List[float], q: float) -> float:
-    """Percentile with the empty-list NaN convention every serving
-    report shares (serving_bench and serving_ab)."""
+    """Percentile with the empty-list NaN convention of the reports
+    below."""
     return float(np.percentile(np.asarray(xs), q)) if xs else float("nan")
 
 
@@ -215,8 +223,8 @@ def per_request_latency(raw: Dict) -> Dict:
 
 
 def emit_json(tag: str, payload: Dict) -> str:
-    """The stable one-line ``TAG={json}`` convention bench.py uses —
-    greppable by the driver, diffable across rounds."""
+    """The stable one-line ``TAG={json}`` the tools end with —
+    greppable, diffable across runs."""
     line = tag + "=" + json.dumps(payload, sort_keys=True)
     print(line)
     return line

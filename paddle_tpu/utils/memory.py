@@ -2,11 +2,11 @@
 half of the r15 memory-observability layer.
 
 Reference: paddle/fluid/memory/allocation/allocator_facade.h and the
-stat surface behind FLAGS_fraction_of_gpu_memory_to_use.  On TPU the
-allocator is XLA's BFC — we expose its PJRT per-device statistics when
-the backend reports them, and fall back to an exact census of this
-client's live device arrays otherwise (the CPU backend exports no
-allocator counters).
+stat surface behind its FLAGS_fraction_of_gpu_memory_to_use (the
+reference's flag; none here).  On TPU the allocator is XLA's BFC — we
+expose its PJRT per-device statistics when the backend reports them,
+and fall back to an exact census of this client's live device arrays
+otherwise (the CPU backend exports no allocator counters).
 
 The live-arrays census is **shard-aware** (r15): a replicated array
 contributes its full bytes to every device it lives on, but a

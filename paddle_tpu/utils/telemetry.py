@@ -30,8 +30,8 @@ is a no-op returning ``NOOP`` itself.  No allocation happens per call on
 the off path, and no registry state is touched, so ``FLAGS_telemetry=0``
 restores prior behavior bit-for-bit (pinned by test).
 
-``snapshot()`` returns one JSON-able dict (the ``telemetry`` section
-bench.py / tools/serving_bench.py append to their BENCH artifacts);
+``snapshot()`` returns one JSON-able dict (tools/overload_bench.py and
+the debris dumps read it);
 ``to_prometheus()`` renders the standard text exposition.
 """
 from __future__ import annotations
@@ -688,7 +688,7 @@ class SLOTracker:
 
     def reset(self):
         """Zero the accounting, keep the declared targets (the
-        between-warmup-and-measured reset serving_bench does)."""
+        between-warmup-and-measured reset tools/slo_report.py does)."""
         with self._lock:
             self._window.clear()
             self._zero_locked()
@@ -785,7 +785,7 @@ class SLOTracker:
                     if self._prompt_tokens else 0.0)
 
     def report(self) -> Dict:
-        """The ``slo`` section serving_bench / slo_report emit."""
+        """The ``slo`` section tools/slo_report.py emits."""
         g = self.goodput()
         with self._lock:
             window_n = len(self._window)

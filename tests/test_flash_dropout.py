@@ -6,7 +6,7 @@ the saved per-step seed).
 CPU runs exercise the reference fallback + the op/grad plumbing; the
 kernel-level checks (determinism, mask coordination, grad parity) need a
 real TPU and are skipped elsewhere — tools/validate_flash_dropout.py is
-the on-device harness and its r3 results are recorded in BENCHMARKS.md.
+the on-device harness.
 """
 import numpy as np
 import pytest

@@ -185,14 +185,20 @@ def test_ps_server_in_separate_process():
         server.wait()
 
 
-def test_ps_two_trainers_sync_parity():
+@pytest.mark.parametrize("trainer0_late_s", [0.0, 5.0])
+def test_ps_two_trainers_sync_parity(trainer0_late_s):
     """The test_dist_base.py:933 check_with_place layout for real: a PS
     server process + TWO trainer processes over localhost, sync mode.
     Each round both trainers pull w_t, compute their half-shard mean
     grads g0/g1, and push; barriers separate rounds, so the trajectory
     is exactly w_{t+1} = w_t - lr*(g0 + g1).  The oracle replicates
     that locally with a two-branch loss (sum of per-half means) and the
-    per-trainer loss curves must match."""
+    per-trainer loss curves must match.
+
+    The trajectory may not depend on which trainer is up first: with
+    trainer 0 started late, trainer 1 reaches its first push before
+    trainer 0's initial values are on the server (what a loaded machine
+    does to the undelayed case now and then)."""
     port = _free_port()
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
@@ -214,14 +220,16 @@ def test_ps_two_trainers_sync_parity():
                 time.sleep(0.2)
         else:
             raise TimeoutError("PS server never opened its port")
-        trainers = []
-        for tid in range(2):
+        trainers = [None, None]
+        for tid in (1, 0):
+            if tid == 0:
+                time.sleep(trainer0_late_s)
             tenv = dict(env)
             tenv["PADDLE_TRAINER_ID"] = str(tid)
-            trainers.append(subprocess.Popen(
+            trainers[tid] = subprocess.Popen(
                 [sys.executable, RUNNER, "ps_trainer"], env=tenv,
                 stdout=subprocess.PIPE, stderr=subprocess.PIPE,
-                text=True, cwd=HERE))
+                text=True, cwd=HERE)
         outs = []
         try:
             for t in trainers:

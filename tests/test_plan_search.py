@@ -91,7 +91,7 @@ def _conv_probe(collective):
 
 def _hand_sweep(use_shard_map):
     """The hand-flag configurations the acceptance criterion names:
-    the bench.py scaling MODES grid (stage x bucket x prefetch)."""
+    the stage x bucket x prefetch grid."""
     sweep = []
     buckets = ("0", "4.0", "32.0", "auto") if use_shard_map else ("32.0",)
     for stage in (0, 1, 2, 3):

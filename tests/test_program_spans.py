@@ -18,8 +18,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu import profiler
 from paddle_tpu.framework.scope import Scope, scope_guard
 from paddle_tpu.inference.serving import (DecoderConfig, Request,
-                                          ServingEngine,
-                                          StaticBatchingEngine)
+                                          ServingEngine)
 from paddle_tpu.utils import telemetry as tm
 
 CFG = DecoderConfig(vocab_size=64, hidden=32, num_heads=4, num_layers=2,
@@ -206,19 +205,6 @@ def test_engine_step_span_tree_admission_and_decode(tmp_path):
             "pt/decode_batch", "pt/engine/emit", "pt/executor/step"} \
         <= set(notes)
     assert notes["pt/engine/decode"][0]["batch"] == 2
-
-
-def test_static_engine_steps_use_the_same_names(tmp_path):
-    eng = _engine()
-    static = StaticBatchingEngine(eng.core, batch_size=2)
-    static.submit(Request("s", [5, 6, 7], 3))
-    profiler.enable_profiler("All")
-    static.step(0.0)
-    static.step(0.0)
-    profiler.disable_profiler(print_summary=False)
-    names = [n for n, _ in _tree(profiler.get_events())]
-    assert names.count("engine/step") == 2
-    assert "engine/prefill" in names and "engine/decode" in names
 
 
 def test_nothing_is_recorded_with_session_and_profiler_off():

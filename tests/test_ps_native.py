@@ -129,8 +129,8 @@ def test_json_fallback_parity(server):
 
 def test_rpc_round_trip_counter(server):
     """rpc_count() tracks completed client round trips on BOTH wire
-    paths — the RTT-per-step accounting bench.py's widedeep mode
-    reports (BASELINE metric #5, VERDICT r5 Weak #2)."""
+    paths — the RTT-per-step accounting of the wide_deep path
+    (BASELINE metric #5)."""
     c = PSClient([server.endpoint])
     n0 = c.rpc_count()
     c.create_dense("w", 8, optimizer="sgd", lr=0.5)

@@ -19,11 +19,7 @@ Oracles:
 * AnalysisPredictor.clone() shares the parent's compiled executables
   (zero new jit traces on a clone's run).
 """
-import json
-import os
 import re
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -33,13 +29,11 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference.kv_cache import KVCacheConfig, PagedKVCache
 from paddle_tpu.inference.serving import (
-    DecoderConfig, Request, ServingEngine, StaticBatchingEngine,
-    _EngineCore, export_decoder, load_decoder_config,
+    DecoderConfig, Request, ServingEngine, export_decoder,
+    load_decoder_config,
 )
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.ops.registry import eager_call
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 CFG = DecoderConfig(vocab_size=64, hidden=32, num_heads=4, num_layers=2,
                     max_seq_len=128)
@@ -251,22 +245,6 @@ def test_scheduler_determinism_seeded_trace():
     assert a == b                  # events, scheduler stats, kv counters
 
 
-def test_static_batching_same_tokens_different_schedule():
-    from paddle_tpu.inference.serving import init_decoder_weights
-
-    prompts = _mixed_prompts(seed=13)
-    core = _EngineCore(CFG, init_decoder_weights(CFG, 0), num_pages=32,
-                       page_size=8, prefill_bucket_min=8)
-    eng = StaticBatchingEngine(core, batch_size=4)
-    reqs = [Request(i, list(p), 5) for i, p in enumerate(prompts)]
-    for r in reqs:
-        eng.submit(r)
-    while eng.has_work():
-        eng.step()
-    oracle = [core.greedy_reference(p, 5) for p in prompts]
-    assert [r.out_tokens for r in reqs] == oracle
-
-
 def test_pool_exhaustion_rejects_oversized_request():
     eng = make_engine(num_pages=4, page_size=4)   # 16 slots total
     with pytest.raises(ValueError):
@@ -293,26 +271,6 @@ def test_submit_rejects_prompt_over_token_budget():
     eng.submit(Request(1, [1, 2, 3], 2))             # 3+1 <= 8 is fine
     eng.run_to_completion()
     assert eng.stats["finished"] == 1
-
-
-def test_static_batching_small_pool_never_crashes():
-    # worst-case page reservation at group formation: mid-decode growth
-    # can never exhaust the pool (no backpressure mechanism exists in
-    # the static baseline — exhaustion used to assert)
-    from paddle_tpu.inference.serving import init_decoder_weights
-
-    core = _EngineCore(CFG, init_decoder_weights(CFG, 0), num_pages=4,
-                       page_size=4, prefill_bucket_min=8)
-    eng = StaticBatchingEngine(core, batch_size=4)
-    reqs = [Request(i, [1 + i, 2, 3], 8) for i in range(4)]  # worst 3 pages
-    for r in reqs:
-        eng.submit(r)
-    while eng.has_work():
-        eng.step()                  # pool fits ONE worst-case at a time
-    oracle = [core.greedy_reference(r.prompt, 8) for r in reqs]
-    assert [r.out_tokens for r in reqs] == oracle
-    with pytest.raises(ValueError):
-        eng.submit(Request(9, list(range(14)), 8))   # unservable alone
 
 
 def test_donated_state_is_never_a_host_alias():
@@ -404,6 +362,27 @@ def test_decode_program_is_padding_free():
 
 
 # ==========================================================================
+# the serving pass pipeline: fused attention in the full-sequence forms
+# ==========================================================================
+@pytest.mark.parametrize("form", ["prefill_prog", "ref_prog"])
+def test_full_sequence_forms_run_fused_attention(form):
+    """``fuse_multihead_attention_pass`` fires on the engine's own
+    full-sequence programs: one fused op a layer, nothing left of the
+    matmul -> softmax -> matmul chain it replaced."""
+    core = make_engine().core
+
+    def count(prog, op_type):
+        return sum(op.type == op_type for op in prog.global_block().ops)
+
+    prog = getattr(core, form)
+    assert count(prog, "fused_multihead_attention") == CFG.num_layers
+    assert count(prog, "softmax") == 0
+    assert core.mha_fused == sum(
+        count(p, "fused_multihead_attention")
+        for p in (core.prefill_prog, core.ref_prog))
+
+
+# ==========================================================================
 # predictor clone: shared executables
 # ==========================================================================
 def test_predictor_clone_does_not_recompile(tmp_path, monkeypatch):
@@ -444,50 +423,3 @@ def test_predictor_clone_does_not_recompile(tmp_path, monkeypatch):
     assert not jit_calls, "clone run re-traced/recompiled the program"
     assert len(pred._exe._cache) == n_cached
     np.testing.assert_array_equal(first, second)
-
-
-# ==========================================================================
-# CI smoke: the end-to-end bench in bounded subprocess (PJRT-safe CPU)
-# ==========================================================================
-def test_serving_bench_quick_subprocess():
-    bound = int(os.environ.get("PD_SERVING_TIMEOUT", 300))
-    r = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "serving_bench.py"),
-         "--quick", "--json"],
-        cwd=ROOT, capture_output=True, text=True, timeout=bound,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
-    line = [ln for ln in r.stdout.splitlines()
-            if ln.startswith("SERVING=")][-1]
-    rep = json.loads(line[len("SERVING="):])
-    assert rep["token_identical_vs_one_at_a_time"] is True
-    assert rep["continuous"]["unfinished"] == 0
-    assert rep["static"]["unfinished"] == 0
-    assert rep["continuous"]["total_tokens"] == rep["static"]["total_tokens"]
-    assert rep["continuous"]["tokens_per_s"] > 0
-    assert rep["mha_fused_ops"] > 0            # the pass fired in serving
-    # r13: the BENCH artifact carries the registry snapshot — the same
-    # counters/histograms the report's numbers come from
-    for eng in ("continuous", "static"):
-        snap = rep["telemetry"][eng]
-        observed = snap["serving_token_latency_s"]["series"][0]["count"]
-        # equal when nothing was preempted (the quick config never is);
-        # an online observer can only over-count vs the retroactive report
-        assert observed >= rep[eng]["total_tokens"]
-        if rep["scheduler"]["preempted"] == 0:
-            assert observed == rep[eng]["total_tokens"]
-        assert "executor_step_s" in snap
-    assert rep["telemetry"]["continuous"]["serving_admitted_total"][
-        "series"][0]["value"] == rep["scheduler"]["admitted"]
-    # r24: quick mode arms --tp 2 — the tensor_parallel section's own
-    # oracles (token identity vs tp=1 AND vs the greedy reference, tp x
-    # page capacity at fixed per-device budget, a feasible TP plan with
-    # tp=1 rows rejected before compile)
-    tps = rep["tensor_parallel"]
-    assert tps["tp"] == 2
-    assert tps["identity"]["tp_vs_tp1"] is True
-    assert tps["identity"]["tp_vs_reference"] is True
-    assert tps["capacity"]["ratio_x"] >= tps["capacity"]["expected_x"]
-    assert tps["plan"]["chosen_tp"] == 2
-    assert tps["plan"]["infeasible"] is False
-    assert tps["plan"]["n_rejected_before_compile"] > 0

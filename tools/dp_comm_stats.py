@@ -310,9 +310,8 @@ def timeline_stats(program, nranks, cost_model=None):
 def measured_step_ms_from_trace(path: str) -> float:
     """MIN ``executor_run`` duration (ms) out of a profiler chrome
     trace — the steady-state step floor (a compile-dominated first
-    step must not poison the calibration; bench.py's best-of
-    discipline).  Raises SystemExit(2) on an unloadable trace or one
-    with no executor_run events (progcheck convention: non-zero on bad
+    step must not poison the calibration: best of several).  Raises
+    SystemExit(2) on an unloadable trace or one with no executor_run events (progcheck convention: non-zero on bad
     input)."""
     try:
         from trace_report import TraceInvalid, load_trace

@@ -35,8 +35,8 @@ Usage:
 (pjit and shard_map/fleet-collective).  One stable ``MEM={json}`` line
 (the BENCH/SERVING convention) carries every row plus the check
 verdicts.  The tool re-execs itself into a subprocess with a forced
-``--ndev`` virtual CPU mesh when the current process has fewer devices
-(the bench.py scaling pattern); on a real chip run it inline.
+``--ndev`` virtual CPU mesh when the current process has fewer devices;
+on a real chip run it inline.
 """
 from __future__ import annotations
 
@@ -77,8 +77,8 @@ def build_args():
 
 
 def _respawn(args, argv):
-    """bench.py scaling pattern: force an ndev-device CPU mesh in a
-    child process when this one can't provide it."""
+    """Force an ndev-device CPU mesh in a child process when this one
+    can't provide it."""
     import subprocess
 
     env = dict(os.environ)

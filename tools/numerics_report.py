@@ -13,8 +13,7 @@ signal layer the quantization/remat rungs will stand on:
   end-to-end oracle: the injection must show up as nonfinite stats, a
   monitor trip, and (with ``--debris-dir``) a flight-recorder dump.
 
-The last line is the stable one-line ``NUMERICS={json}`` (bench.py
-convention).
+The last line is the stable one-line ``NUMERICS={json}``.
 
 Usage:
   python tools/numerics_report.py [--steps 8] [--layers 3] [--width 16]

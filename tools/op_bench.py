@@ -36,8 +36,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
 
 
-# the 20 hottest ops across the ResNet-50 / ERNIE / wide_deep benches
-# (per BENCHMARKS.md profiles), with representative shapes
+# 20 hot ops of the ResNet-50 / ERNIE / wide_deep steps, with
+# representative shapes
 DEFAULT_CONFIG = [
     {"op": "conv2d", "inputs": {"Input": {"shape": [32, 64, 56, 56]},
                                 "Filter": {"shape": [64, 64, 3, 3]}},

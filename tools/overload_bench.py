@@ -28,8 +28,8 @@ stalls decode wall time.  Both policies replay the SAME schedule.
 Everything that decides scheduling — arrivals, prompts, the logical
 clock, burn rate (computed over logical-time TTFTs), shed and
 preemption choices — is a pure function of the seed, so the
-``OVERLOAD={json}`` payload is stable run to run (the bench.py
-convention; tools/slo_report.py explains single runs per-request).
+``OVERLOAD={json}`` payload is stable run to run
+(tools/slo_report.py explains single runs per-request).
 
 Usage:
   python tools/overload_bench.py [--requests 48] [--rate 100] [--seed 0]

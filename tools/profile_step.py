@@ -1,5 +1,5 @@
 """Profile one model's train step on the attached chip and print a
-per-fusion device-time table (the r2 BENCHMARKS.md breakdown, scripted).
+per-fusion device-time table.
 
 Usage: python tools/profile_step.py [resnet50|ernie] [--steps N]
            [--top-ops N] [--quick]
@@ -69,7 +69,7 @@ def run_resnet(steps=8, batch=128, image=224, amp=True, depth=50,
 
 
 def run_ernie(steps=8, batch=None, seq=512, attn_dropout=True):
-    # defaults track bench.py's headline ERNIE config (r5: b38, AMP O2)
+    # defaults: chip_smoke.py's BERT-base phase (b38, s512, AMP O2)
     batch = batch or int(os.environ.get("BENCH_BATCH", "38"))
     import numpy as np
 
@@ -95,7 +95,7 @@ def run_ernie(steps=8, batch=None, seq=512, attn_dropout=True):
     def step():
         return fn(ids, labels)
 
-    step.fn = fn  # the raw (ids, labels) -> loss step (soak_ernie reuses it)
+    step.fn = fn  # the raw (ids, labels) -> loss step
     return step
 
 

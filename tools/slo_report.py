@@ -20,8 +20,8 @@ SLO-aware-admission rung will stand on:
   with the engine's admit/preempt/finish counters
   (``spans_reconcile``).
 
-The last line is the stable one-line ``SLO={json}`` (bench.py
-convention).
+The last line is the stable one-line ``SLO={json}``
+(``utils/loadgen.py`` ``emit_json``).
 
 Usage:
   python tools/slo_report.py [--requests 16] [--rate 50] [--seed 0]

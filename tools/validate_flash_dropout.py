@@ -1,6 +1,6 @@
 """On-device validation harness for the flash-attention dropout kernel.
 
-Run on a real TPU.  Checks (r3 results in BENCHMARKS.md):
+Run on a real TPU.  Checks:
 1. rate=0 kernel output + analytic grads match attention_reference;
 2. same-seed determinism / different-seed divergence;
 3. E[dropout output] over seeds approaches the undropped output;
