@@ -420,6 +420,7 @@ def phase_dp4(ctx):
     """DP-4 ResNet-50 (global batch as above) against the one-chip loss
     trajectory, both in this process, from one set of initial weights."""
     import paddle_tpu.fluid as fluid
+    from paddle_tpu import profiler
     from paddle_tpu.framework.scope import Scope, scope_guard
 
     jax = ctx.jax
@@ -448,17 +449,26 @@ def phase_dp4(ctx):
     ms = []
     with scope_guard(four):
         dp = []
+        profiler.enable_profiler()      # the program's spans, host side only
         for _ in range(steps):
             t0 = time.perf_counter()
             out = exe.run(prog, feed=feed, fetch_list=[loss.name])[0]
             dp.append(float(np.mean(out)))
             ms.append((time.perf_counter() - t0) * 1e3)
+        placed = [e["args"]["arrays"] for e in profiler.get_events()
+                  if e["name"] == "executor/bind"]
+        profiler.disable_profiler(print_summary=False)
+        # the step session: the first step places the whole state, every
+        # later one binds it from the step before and places nothing
+        if placed[0] == 0 or any(placed[1:]):
+            raise RuntimeError(f"{phase}: arrays placed a step {placed}, "
+                               f"want all on the first and 0 after")
         # state really is on four distinct devices, and the step's
         # compiled text holds the gradient all-reduce
         spread = device_spread(ctx, [v for _, v in four.items()
                                      if isinstance(v, jax.Array)])
-        jitted, state_spec, feed_spec = prog.__dict__["_last_exec"]
-        hlo = jitted.lower(state_spec, feed_spec).compile().as_text()
+        jitted, *abstract_args = prog.__dict__["_last_exec"]
+        hlo = jitted.lower(*abstract_args).compile().as_text()
     if "all-reduce" not in hlo:
         raise RuntimeError(f"{phase}: no all-reduce in the compiled step")
     # Step 1 checks the sharded forward (global-batch BN statistics under
@@ -479,7 +489,7 @@ def phase_dp4(ctx):
         dp4_losses=[round(v, 4) for v in dp],
         step1_absdiff=abs(single[0] - dp[0]),
         first_step_s=round(ms[0] / 1e3, 2), steady_step_ms=round(ms[-1], 2),
-        all_reduces=hlo.count(" all-reduce("),
+        arrays_placed=placed, all_reduces=hlo.count(" all-reduce("),
         custom_calls=hlo.count("tpu_custom_call"), **seen, **spread)
 
 

@@ -176,18 +176,24 @@ def device_put_owned(value, device):
     had churned the heap).  This helper re-copies through XLA whenever
     the fast path aliased the host buffer, so the result is always
     safe to donate; backends whose arrays expose no host pointer (TPU:
-    device_put is a real H2D copy) pass through untouched."""
+    device_put is a real H2D copy) pass through untouched.  ``device``
+    is a device or, for the data-parallel step, a ``Sharding``."""
     import jax.numpy as jnp
 
     arr = np.asarray(value)
     out = jax.device_put(arr, device)
-    try:
-        aliased = out.unsafe_buffer_pointer() == arr.ctypes.data
-    except Exception:
-        # cannot PROVE ownership: on host-memory backends assume the
-        # worst and copy (cheap, staging-time only); accelerator
-        # device_put is a real H2D transfer by construction
-        aliased = getattr(device, "platform", "cpu") == "cpu"
+    if isinstance(device, jax.sharding.Sharding):
+        # the data-parallel step's placement: each shard may alias its
+        # slice of the host buffer, so ask the platform and not a pointer
+        aliased = next(iter(device.device_set)).platform == "cpu"
+    else:
+        try:
+            aliased = out.unsafe_buffer_pointer() == arr.ctypes.data
+        except Exception:
+            # cannot PROVE ownership: on host-memory backends assume the
+            # worst and copy (cheap, staging-time only); accelerator
+            # device_put is a real H2D transfer by construction
+            aliased = getattr(device, "platform", "cpu") == "cpu"
     if aliased:
         out = jnp.copy(out)
     return out

@@ -25,13 +25,16 @@ fetches), so a fetched scalar loss has shape (ndev,).
 """
 from __future__ import annotations
 
+import dataclasses
+import weakref
 from typing import Any, Dict, List
 
 import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..executor import named_step, program_label
+from ..executor import (_StateSession, device_put_owned, named_step,
+                        program_label)
 from ..framework.scope import LoDTensor
 from ..ops import registry
 from ..profiler import RecordEvent
@@ -39,6 +42,31 @@ from . import partition_rules
 from .mesh import default_dp_mesh
 
 RNG_VAR = registry.LowerCtx.RNG_VAR
+
+
+@dataclasses.dataclass(slots=True)
+class _CompiledDP:
+    """One compiled data-parallel step (a ``_dp_cache`` entry): the
+    executor's ``_Compiled`` for a mesh.  ``fn(mut, ro, feed)`` takes
+    the state split at compile time into ``donatable`` (read and
+    rewritten: parameters, moments, BN statistics, the RNG key) and
+    ``readonly``; every state output is pinned to ``shardings``, the
+    placement its input has, so a step's outputs are the next step's
+    inputs as they stand and ``session`` (executor._StateSession)
+    carries them across."""
+
+    fn: Any                     # the jitted step
+    donatable: tuple
+    readonly: tuple
+    use_shard_map: bool
+    shardings: Dict[str, NamedSharding]     # by state name, in and out
+    feed_sharding: NamedSharding            # the batch axis over the mesh
+    feed_plan: dict
+    numerics: Any               # probe layout (framework/numerics.py)
+    session: Any = None
+    # (fn, *abstract arguments): built at the entry's first step, again
+    # only when a walk binds another shape
+    last_exec: Any = None
 
 
 def _program_has_collectives(program) -> bool:
@@ -426,6 +454,8 @@ def _compile_dp(compiled_program, executor, program, feed, fetch_names,
            # probe config + armed chaos NaN injection (see the
            # executor compile key for the step-K recompile contract)
            _numerics.probe_signature(), _chaos.nan_poison_target(),
+           # donation is compiled into the step (see the executor key)
+           bool(flag("tpu_donate_buffers")),
            # the resolved plan stays LAST: introspection (tests,
            # dp_comm_stats --plan) reads key[-1] as the plan tuple
            plan.as_tuple() if plan is not None else None)
@@ -730,67 +760,68 @@ def _compile_dp_miss(compiled_program, executor, program, feed,
         new_state = {n: env[n] for n in state_out if n in env}
         return fetched, new_state
 
+    # what the step rewrites (parameters, moments, BN statistics, the RNG
+    # key) is donated and carried across steps by the session; what it
+    # only reads stays the scope's live buffer and is never donated
+    written = set(state_out)
+    donatable = tuple(n for n in state_in if n in written)
+    readonly = tuple(n for n in state_in if n not in written)
+
     if use_shard_map:
-        def shard_fn(state_vals, feed_vals):
-            fetched, new_state = body(state_vals, feed_vals, per_shard=True)
+        def shard_fn(mut_vals, ro_vals, feed_vals):
+            fetched, new_state = body({**ro_vals, **mut_vals}, feed_vals,
+                                      per_shard=True)
             # stack per-shard fetches on a new leading axis
             fetched = tuple(f[None] for f in fetched)
             return fetched, new_state
 
         sm_sharded = opt_sharded | sharded_params
-        state_specs = {n: (P(axis) if n in sm_sharded else P())
-                       for n in state_in}
-        feed_specs = {k: P(axis) for k in feed}
+
+        def sm_spec(name):
+            return P(axis) if name in sm_sharded else P()
+
         fn = jax.shard_map(
             shard_fn,
             mesh=mesh,
-            in_specs=(state_specs, feed_specs),
+            in_specs=({n: sm_spec(n) for n in donatable},
+                      {n: sm_spec(n) for n in readonly},
+                      {k: P(axis) for k in feed}),
             out_specs=(tuple(P(axis) for _ in fetch_names),
-                       {n: (P(axis) if n in sm_sharded else P())
-                        for n in state_out}),
+                       {n: sm_spec(n) for n in state_out}),
             check_vma=False,
         )
-        jitted = jax.jit(named_step(fn, label))
 
         def state_sharding(name):  # noqa: F811 — shard_map placement
             """Scope values enter pre-placed to match the in_specs: the
             ZeRO-sharded names arrive split over dp (1/ndev resident
             bytes per device), everything else replicated."""
-            return NamedSharding(mesh, P(axis) if name in sm_sharded
-                                 else P())
+            return NamedSharding(mesh, sm_spec(name))
     else:
-        def global_fn(state_vals, feed_vals):
-            return body(state_vals, feed_vals, per_shard=False)
+        def fn(mut_vals, ro_vals, feed_vals):
+            return body({**ro_vals, **mut_vals}, feed_vals, per_shard=False)
 
-        named_step(global_fn, label)
-
-        state_shardings = {n: state_sharding(n) for n in state_in}
-        feed_shardings = {k: NamedSharding(mesh, P(axis)) for k in feed}
-        if opt_sharded or sharded_params:
-            # pin sharded state on the way OUT too, or jit's default
-            # layout choice could all-gather the moments back after the
-            # update and erase the 1/ndev memory win (fetches stay
-            # unconstrained — the None prefix)
-            jitted = jax.jit(
-                global_fn,
-                in_shardings=(state_shardings, feed_shardings),
-                out_shardings=(None,
-                               {n: state_sharding(n) for n in state_out}),
-            )
-        else:
-            jitted = jax.jit(
-                global_fn,
-                in_shardings=(state_shardings, feed_shardings),
-            )
+    # every state output is pinned to the placement its input has (and
+    # not left to jit's choice, which could also all-gather ZeRO-sharded
+    # moments back after the update): a step's outputs are then the next
+    # step's inputs as they stand.  Fetches stay unconstrained (the None
+    # prefix).
+    shardings = {n: state_sharding(n) for n in written.union(state_in)}
+    feed_sharding = NamedSharding(mesh, P(axis))
+    jitted = jax.jit(
+        named_step(fn, label),
+        in_shardings=({n: shardings[n] for n in donatable},
+                      {n: shardings[n] for n in readonly},
+                      {k: feed_sharding for k in feed}),
+        out_shardings=(None, {n: shardings[n] for n in state_out}),
+        donate_argnums=(0,) if flag("tpu_donate_buffers") else ())
 
     # feed-conversion plan (target numpy dtype per feed name), computed
     # once per compilation — same helper as the single-device executor
     from ..executor import build_feed_plan
 
-    feed_plan = build_feed_plan(block, feed)
-
-    entry = (jitted, state_in, state_out, use_shard_map, state_sharding,
-             axis, feed_plan, n_layout)
+    entry = _CompiledDP(jitted, donatable, readonly, use_shard_map,
+                        shardings, feed_sharding,
+                        build_feed_plan(block, feed), n_layout)
     cache[key] = entry
     return entry
 
@@ -814,10 +845,66 @@ def run_data_parallel(compiled, executor, feed, fetch_list, scope, return_numpy)
                             scope, return_numpy)
 
 
+def _bind_state(entry, executor, program, scope, use_session):
+    """The step's state as ``(mut, ro, arrays placed)``.  While the scope
+    still carries the stamp of our own write-back it comes from the step
+    session: no scope read, nothing placed.  Otherwise the walk: every
+    value read from the scope and put where the step wants it (host
+    values that will be donated through ``device_put_owned``)."""
+    from ..framework.scope import Scope
+
+    sess = entry.session if use_session else None
+    if sess is not None:
+        if sess.scope_ref() is scope and sess.stamp == Scope.mutation_counter:
+            bound = sess.deref()
+            if bound is not None:
+                return bound[0], bound[1], 0
+        # stale: something outside our write-back wrote a scope
+        entry.session = None
+        executor._tm.current().invalidations.inc()
+
+    def placed(name, donated):
+        val = scope.get(name)
+        if name == RNG_VAR:
+            if val is None:
+                val = jax.random.key(program.random_seed or 0)
+        elif val is None:
+            raise RuntimeError(
+                f"Variable {name!r} has no value in scope — run the "
+                f"startup program first"
+            )
+        if isinstance(val, LoDTensor):
+            val = val.numpy()
+        if donated and isinstance(val, np.ndarray):
+            return device_put_owned(val, entry.shardings[name])
+        return jax.device_put(val, entry.shardings[name])
+
+    mut = {n: placed(n, True) for n in entry.donatable}
+    ro = {n: placed(n, False) for n in entry.readonly}
+    return mut, ro, len(mut) + len(ro)
+
+
+def _abstract(tree):
+    """Shape, dtype and sharding of every array, and no live buffer: a
+    kept argument would pin a stale copy of the model on the devices."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=getattr(a, "sharding", None)), tree)
+
+
+def _describes(specs, args) -> bool:
+    """Whether the kept abstract arguments still describe ``args`` (the
+    shardings are the entry's own and cannot differ)."""
+    leaves = jax.tree_util.tree_leaves
+    return all(s.shape == a.shape and s.dtype == a.dtype
+               for s, a in zip(leaves(specs), leaves(args)))
+
+
 def _run_dp_step(compiled, executor, program, feed, fetch_list, scope,
                  return_numpy):
-    from ..framework.scope import global_scope
+    from ..framework.scope import Scope, global_scope
     from ..executor import as_numpy, _fetch_name
+    from ..utils.flags import flag
 
     with RecordEvent("dp/lookup"):
         scope = scope or global_scope()
@@ -832,19 +919,16 @@ def _run_dp_step(compiled, executor, program, feed, fetch_list, scope,
             mesh = default_dp_mesh(ndev)
             compiled.__dict__["_mesh"] = mesh
 
-        jitted, state_in, state_out, use_shard_map, state_sharding, axis, \
-            feed_plan, n_layout = _compile_dp(compiled, executor, program,
-                                              feed, fetch_names, scope, mesh)
-
-    batch_sharding = NamedSharding(mesh, P(axis))
-    repl = NamedSharding(mesh, P())
+        entry = _compile_dp(compiled, executor, program, feed, fetch_names,
+                            scope, mesh)
+        use_session = bool(flag("tpu_step_session", True))
 
     feed_vals = {}
     with RecordEvent("executor/feed") as feed_span:
         n_cast = 0
         for k, v in feed.items():
             arr = as_numpy(v) if isinstance(v, LoDTensor) else np.asarray(v)
-            want = feed_plan.get(k)
+            want = entry.feed_plan.get(k)
             if want is not None and arr.dtype != want:
                 arr = arr.astype(want)
                 n_cast += 1
@@ -853,35 +937,20 @@ def _run_dp_step(compiled, executor, program, feed, fetch_list, scope,
                     f"feed {k!r} batch {arr.shape[0]} not divisible by "
                     f"{mesh.size} devices"
                 )
-            feed_vals[k] = jax.device_put(arr, batch_sharding)
+            feed_vals[k] = jax.device_put(arr, entry.feed_sharding)
         if feed_span.recording:
             feed_span.set(bytes=int(sum(v.nbytes for v in feed_vals.values())),
                           arrays_cast=n_cast)
 
-    state_vals = {}
     with RecordEvent("executor/bind") as bind_span:
-        for name in state_in:
-            if name == RNG_VAR:
-                val = scope.get(RNG_VAR)
-                if val is None:
-                    val = jax.random.key(program.random_seed or 0)
-                state_vals[name] = jax.device_put(val, repl)
-                continue
-            val = scope.get(name)
-            if val is None:
-                raise RuntimeError(
-                    f"Variable {name!r} has no value in scope — run the "
-                    f"startup program first"
-                )
-            if isinstance(val, LoDTensor):
-                val = val.numpy()
-            state_vals[name] = jax.device_put(val, state_sharding(name))
+        mut, ro, n_placed = _bind_state(entry, executor, program, scope,
+                                        use_session)
         if bind_span.recording:
-            bind_span.set(arrays=len(state_vals))
+            bind_span.set(arrays=n_placed)
 
     try:
         with RecordEvent("executor/call"):
-            fetched, new_state = jitted(state_vals, feed_vals)
+            fetched, new_state = entry.fn(mut, ro, feed_vals)
     except Exception as e:
         from ..framework import memory_plan as _mp
         from ..framework import numerics as _nm
@@ -905,7 +974,7 @@ def _run_dp_step(compiled, executor, program, feed, fetch_list, scope,
 
         if _chaos_mod.nan_poison_target() is not None:
             _chaos_mod.consume_nan_poison()
-    if n_layout:
+    if entry.numerics:
         # probe stream: the stats vector rides the fetch tail.  Its
         # partials are cross-shard-combined in-program, so on the
         # shard_map path every stacked row is identical — row 0 is THE
@@ -914,29 +983,41 @@ def _run_dp_step(compiled, executor, program, feed, fetch_list, scope,
 
         with RecordEvent("executor/probe"):
             sv = np.asarray(fetched[-1])
-            _nm.on_step(n_layout, sv[0] if use_shard_map else sv,
+            _nm.on_step(entry.numerics, sv[0] if entry.use_shard_map else sv,
                         where="data_parallel")
         fetched = fetched[:-1]
 
-    # keep the call handle + ABSTRACT args (shape/dtype/sharding, not
-    # the live buffers — those would pin a stale full copy of model
-    # state on device for the program's lifetime): verify_overlap.py
-    # re-lowers this step AOT to inspect the compiled HLO
-    def _spec(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                    sharding=getattr(a, "sharding", None))
-
+    # the call handle + ABSTRACT arguments, for whoever re-lowers this
+    # step AOT to read the compiled HLO (tools/verify_overlap.py, the
+    # benchmark): built at the entry's first step, and again only when
+    # a walk has bound another shape (a session step binds the outputs
+    # of the same program; the feed's shapes are in the compile key)
     with RecordEvent("dp/handle"):
-        compiled.__dict__["_last_exec"] = (
-            jitted, jax.tree_util.tree_map(_spec, state_vals),
-            jax.tree_util.tree_map(_spec, feed_vals))
+        if entry.last_exec is None or (
+                n_placed and not _describes(entry.last_exec[1:3], (mut, ro))):
+            entry.last_exec = (entry.fn, _abstract(mut), _abstract(ro),
+                               _abstract(feed_vals))
+        compiled.__dict__["_last_exec"] = entry.last_exec
     with RecordEvent("executor/writeback"):
-        # drop this step's references first: the replaced state then dies
+        # drop this step's references first: what was not donated dies
         # here, at scope.set, and not unnamed when the function returns
-        # (428 arrays: 5 ms of a ResNet-50 step on four chips)
-        del state_vals, feed_vals
+        del mut, feed_vals
         for name, val in new_state.items():
             scope.set(name, val)
+        entry.session = None
+        if use_session:
+            # the next step binds from this step's outputs (the scope
+            # holds the same objects): weakly, so that an abandoned
+            # session never pins a second copy of the model
+            try:
+                entry.session = _StateSession(
+                    weakref.ref(scope), Scope.mutation_counter,
+                    {n: weakref.ref(new_state[n]) for n in entry.donatable},
+                    ro)
+            except (KeyError, TypeError):
+                # a donated var was not produced, or a state value cannot
+                # be weakly referenced (a SelectedRows pytree): no session
+                pass
 
     if fetch_names:
         with RecordEvent("executor/fetch"):

@@ -294,6 +294,208 @@ def test_pjit_sharding_rollback_replicated():
 
 
 # --------------------------------------------------------------------------
+# the data-parallel step session: state bound once, donated, rebound from
+# the step's own outputs (the one-chip _StateSession on a mesh)
+# --------------------------------------------------------------------------
+SESSION_CASES = [pytest.param(False, 0, id="pjit"),
+                 pytest.param(False, 1, id="zero1"),
+                 pytest.param(False, 2, id="zero2"),
+                 pytest.param(False, 3, id="zero3"),
+                 pytest.param(True, 0, id="shard_map"),
+                 pytest.param(True, 3, id="shard_map_zero3")]
+
+
+def _invalidations():
+    from paddle_tpu.utils import telemetry
+
+    fam = telemetry.registry().snapshot().get(
+        "executor_step_session_invalidations_total")
+    return fam["series"][0]["value"] if fam and fam["series"] else 0
+
+
+def _session_run(program, stage, session, steps=6, poke_at=None):
+    """`steps` DP steps of `program` (as `_staged_program` returns it)
+    from its initial weights, under the repo's profiler.  Gives the raw
+    fetched losses, the final state, the arrays `executor/bind` placed
+    on each step, the session invalidations the run counted, the scope,
+    the compiled entry and `step()` for one more step.  `poke_at`:
+    before that step one parameter is overwritten through `scope.set`."""
+    from paddle_tpu import profiler
+
+    main, _, loss, init = program
+    mesh_mod.registry().clear()
+    mesh_mod.init_mesh()
+    _flags.set_flags({"dp_sharding": stage, "tpu_step_session": session})
+    xs, ys = _data(16)
+    exe = pt.Executor(pt.CPUPlace())
+    scope = Scope()
+    for k, v in init.items():
+        scope.set(k, v.copy())
+    compiled = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name)
+    poked = sorted(k for k in init if k.endswith(".w_0"))[0]
+
+    def step():
+        return np.asarray(exe.run(compiled, feed={"x": xs, "y": ys},
+                                  fetch_list=[loss], scope=scope)[0])
+
+    counted = _invalidations()
+    profiler.enable_profiler()
+    try:
+        losses = []
+        for i in range(steps):
+            if i == poke_at:
+                scope.set(poked, np.full_like(init[poked], 0.25))
+            losses.append(step())
+        placed = [e["args"]["arrays"] for e in profiler.get_events()
+                  if e["name"] == "executor/bind"]
+    finally:
+        profiler.disable_profiler(print_summary=False)
+        profiler.reset_profiler()
+    entry, = compiled.__dict__["_dp_cache"].values()
+    return SimpleNamespace(
+        losses=losses, placed=placed, scope=scope, entry=entry, step=step,
+        invalidations=_invalidations() - counted,
+        # copies: on XLA:CPU a numpy view of a buffer keeps it from
+        # being donated
+        state={k: np.array(v) for k, v in scope.items()
+               if not k.startswith("@")})
+
+
+def _assert_same_run(a, b):
+    for x, y in zip(a.losses, b.losses):
+        np.testing.assert_array_equal(x, y)
+    assert a.state.keys() == b.state.keys()
+    for k in a.state:
+        np.testing.assert_array_equal(a.state[k], b.state[k], err_msg=k)
+
+
+@pytest.mark.parametrize("collective,stage", SESSION_CASES)
+def test_dp_step_session_bit_identical_to_the_walk(collective, stage):
+    """With the session a step binds its state from the step before and
+    places nothing; losses and final state are, bit for bit, those of
+    the scope walk (FLAGS_tpu_step_session=0), and the step is compiled
+    once: its outputs come back under the shardings its inputs have."""
+    program = _staged_program(collective)
+    on = _session_run(program, stage, session=1)
+    off = _session_run(program, stage, session=0)
+    _assert_same_run(on, off)
+    n_state = len(on.entry.donatable) + len(on.entry.readonly)
+    assert n_state > 20
+    assert on.placed == [n_state, 0, 0, 0, 0, 0]
+    assert off.placed == [n_state] * 6
+    assert (on.invalidations, off.invalidations) == (0, 0)
+    assert on.entry.fn._cache_size() == off.entry.fn._cache_size() == 1
+    assert on.entry.session is not None and off.entry.session is None
+    assert on.losses[0].shape == ((8,) if collective else ())
+
+
+@pytest.mark.parametrize("collective,stage", SESSION_CASES)
+def test_dp_step_session_honours_a_scope_write(collective, stage):
+    """A `scope.set` of a parameter between two steps drops the session
+    once: the next step walks the scope and trains on the written value,
+    and the session is back the step after."""
+    program = _staged_program(collective)
+    plain = _session_run(program, stage, session=1)
+    on = _session_run(program, stage, session=1, poke_at=3)
+    off = _session_run(program, stage, session=0, poke_at=3)
+    _assert_same_run(on, off)
+    n_state = on.placed[0]
+    assert on.placed == [n_state, 0, 0, n_state, 0, 0]
+    assert (on.invalidations, off.invalidations) == (1, 0)
+    np.testing.assert_array_equal(np.stack(on.losses[:3]),
+                                  np.stack(plain.losses[:3]))
+    assert not np.array_equal(on.losses[3], plain.losses[3])
+
+
+@pytest.mark.parametrize("collective,stage", SESSION_CASES)
+def test_dp_step_donates_its_state_and_keeps_one_copy(collective, stage):
+    """After a step the arrays the step before left in the scope are
+    deleted (donated), what the step only reads is not, the scope holds
+    the session's own objects, and no second copy of the state is alive;
+    an overwritten scope frees the state although a session remains."""
+    import gc
+
+    import jax
+
+    program = _staged_program(collective)
+    gc.collect()
+    before = jax.live_arrays()          # held, so no id below is reused
+    run = _session_run(program, stage, session=1, steps=3)
+    entry, scope = run.entry, run.scope
+    held = {n: scope.get(n) for n in entry.donatable}
+    kept = entry.session.ro             # the device copies of host values
+    assert len(held) > 20 and kept.keys() == set(entry.readonly)
+    run.step()
+    assert all(v.is_deleted() for v in held.values())
+    assert not any(v.is_deleted() for v in kept.values())
+    mut, _ = entry.session.deref()
+    assert all(mut[n] is scope.get(n) for n in entry.donatable)
+    state_bytes = sum(v.nbytes for v in mut.values())
+    del held, mut
+    gc.collect()
+    known = {id(a) for a in before}
+    alive = sum(a.nbytes for a in jax.live_arrays() if id(a) not in known)
+    assert state_bytes <= alive < 1.5 * state_bytes
+    # an abandoned session pins nothing: the scope's entries were the
+    # only strong references to what the step rewrites
+    for n in entry.donatable:
+        scope.set(n, None)
+    gc.collect()
+    assert entry.session.deref() is None
+    alive = sum(a.nbytes for a in jax.live_arrays() if id(a) not in known)
+    assert alive < 0.5 * state_bytes
+
+
+def test_dp_step_with_selected_rows_state_walks_every_step():
+    """A state value that cannot be weakly referenced (a SelectedRows
+    pytree) means no session, as on one chip: every step walks the
+    scope, and the value is still carried from step to step."""
+    import jax.numpy as jnp
+
+    from paddle_tpu import profiler
+    from paddle_tpu.framework import unique_name
+    from paddle_tpu.framework.selected_rows import SelectedRows
+
+    unique_name.switch()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data("x", [4])
+        total = fluid.layers.reduce_sum(x)
+        sparse = main.global_block().create_var(
+            name="sparse_state", shape=[6, 2], dtype="float32",
+            persistable=True)
+        main.global_block().append_op(
+            type="scale", inputs={"X": [sparse]}, outputs={"Out": [sparse]},
+            attrs={"scale": 0.5})
+    mesh_mod.init_mesh()
+    exe = pt.Executor(pt.CPUPlace())
+    scope = Scope()
+    scope.set("sparse_state", SelectedRows(
+        jnp.asarray([1, 4], jnp.int32), jnp.ones((2, 2), jnp.float32), 6))
+    compiled = fluid.CompiledProgram(main).with_data_parallel()
+    counted = _invalidations()
+    profiler.enable_profiler()
+    try:
+        for _ in range(3):
+            exe.run(compiled, feed={"x": np.ones((8, 4), np.float32)},
+                    fetch_list=[total], scope=scope)
+        placed = [e["args"]["arrays"] for e in profiler.get_events()
+                  if e["name"] == "executor/bind"]
+    finally:
+        profiler.disable_profiler(print_summary=False)
+        profiler.reset_profiler()
+    entry, = compiled.__dict__["_dp_cache"].values()
+    assert entry.donatable == ("sparse_state",)
+    assert placed == [1, 1, 1] and entry.session is None
+    assert _invalidations() == counted
+    got = scope.get("sparse_state")
+    assert isinstance(got, SelectedRows)
+    np.testing.assert_array_equal(np.asarray(got.values),
+                                  np.full((2, 2), 0.125, np.float32))
+
+
+# --------------------------------------------------------------------------
 # ZeRO-1: dygraph fused-Adam flat buffers
 # --------------------------------------------------------------------------
 def _dygraph_train(flip_on_at=None, flip_off_at=None, steps=14):

@@ -375,11 +375,20 @@ def test_launch_ps_spawns_role_env(tmp_path):
     from paddle_tpu.distributed.launch_ps import _parse_args, start_procs
 
     script = tmp_path / "probe.py"
+    # start_procs reaps the servers when the last trainer exits: a trainer
+    # stays until both servers have written their line (a loaded host
+    # starts them late, and a reaped server leaves an empty log)
     script.write_text(
-        "import json, os, sys\n"
+        "import json, os, sys, time\n"
         "print(json.dumps({k: os.environ.get(k) for k in ("
         "'TRAINING_ROLE', 'PADDLE_TRAINER_ID', 'PADDLE_PORT',"
-        "'PADDLE_PSERVERS_IP_PORT_LIST', 'PADDLE_TRAINERS_NUM')}))\n")
+        "'PADDLE_PSERVERS_IP_PORT_LIST', 'PADDLE_TRAINERS_NUM')}))\n"
+        "logs = os.path.join(os.path.dirname(sys.argv[0]), 'logs')\n"
+        "end = time.time() + 60\n"
+        "while os.environ['TRAINING_ROLE'] == 'TRAINER' and time.time() < end"
+        " and not all(os.path.exists(p) and os.path.getsize(p) for p in ("
+        "os.path.join(logs, f'serverlog.{i}') for i in range(2))):\n"
+        "    time.sleep(0.05)\n")
     args = _parse_args([
         "--server_num", "2", "--worker_num", "2",
         "--start_port", "16170",

@@ -151,13 +151,46 @@ def test_dp_step_span_tree_on_two_virtual_devices(tmp_path):
         ("executor/fetch", "executor/step")]
     step = next(e for e in events if e["name"] == "executor/step")
     assert step["args"]["program"] == "dp_main"
+    # a session step: the state is the step before's, nothing is placed
     assert next(e for e in events if e["name"] == "executor/bind")[
-        "args"]["arrays"] > 0
+        "args"] == {"arrays": 0}
     assert {"pt/dp/lookup", "pt/dp/handle", "pt/executor/step"} \
         <= set(session.annotations())
     # the compiled step says which program it is
     jitted, *specs = prog.__dict__["_last_exec"]
     assert "jit_pt_dp_main" in jitted.lower(*specs).as_text()
+
+
+def test_dp_last_exec_is_abstract_and_kept_across_session_steps():
+    """`_last_exec` is the step's call handle with abstract arguments:
+    built when the entry first runs, the same objects after any number
+    of steps that bind the same shapes (nothing is rebuilt a step),
+    holding no live buffer, and enough to compile the step's text,
+    gradient all-reduce included."""
+    main, startup, loss, feed = _mlp()
+    exe = fluid.Executor(pt.CPUPlace())
+    prog = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name, places=[pt.CPUPlace(), pt.CPUPlace()])
+    scope = Scope()
+    with scope_guard(scope):
+        exe.run(startup)
+        exe.run(prog, feed=feed, fetch_list=[loss])
+        first = prog.__dict__["_last_exec"]
+        for _ in range(4):
+            exe.run(prog, feed=feed, fetch_list=[loss])
+        last = prog.__dict__["_last_exec"]
+        assert len(last) == len(first) and all(
+            a is b for a, b in zip(first, last))
+        jitted, *specs = last
+        leaves = jax.tree_util.tree_leaves(specs)
+        assert len(leaves) > 4 and all(
+            type(leaf) is jax.ShapeDtypeStruct for leaf in leaves)
+        assert "all-reduce" in jitted.lower(*specs).compile().as_text()
+        # a walk keeps them too while it binds the same shapes, with the
+        # session off as well as after a scope write
+        scope.set("@poke", np.zeros(1, np.float32))
+        exe.run(prog, feed=feed, fetch_list=[loss])
+        assert prog.__dict__["_last_exec"] is first
 
 
 def test_engine_step_span_tree_admission_and_decode(tmp_path):

@@ -141,8 +141,8 @@ def verify_program(nranks=8, layers=10, width=64, mb=None, stage=None,
     exe.run(compiled, feed={"x": xs, "y": ys}, fetch_list=[loss],
             scope=scope)
 
-    jitted, state_vals, feed_vals = compiled.__dict__["_last_exec"]
-    hlo = jitted.lower(state_vals, feed_vals).compile().as_text()
+    jitted, *abstract_args = compiled.__dict__["_last_exec"]
+    hlo = jitted.lower(*abstract_args).compile().as_text()
     result = check_hlo_overlap(hlo)
     result["hlo_bytes"] = len(hlo)
 
