@@ -67,6 +67,20 @@ SIZES = {
             # two prefill buckets (32, 512) and one block-table width (32
             # pages) keep the number of compiled shapes small
             prompts=[300, 20, 280, 31, 17, 400, 25, 270], new_tokens=16),
+        # the latent-attention / sparse-expert decoder at JoyAI-LLM-Flash's
+        # published widths and a small depth: the dense layer, one expert
+        # layer with all 256 experts, the MTP block (6.2 GB of bfloat16)
+        "mla": dict(
+            cfg=dict(vocab_size=129280, hidden=2048, num_heads=32,
+                     num_layers=2, first_k_dense=1, intermediate=7168,
+                     moe_intermediate=768, n_routed_experts=256,
+                     n_shared_experts=1, num_experts_per_tok=8,
+                     q_lora_rank=1536, kv_lora_rank=512,
+                     qk_nope_head_dim=128, qk_rope_head_dim=64,
+                     v_head_dim=128, rope_theta=32e6, max_seq_len=1024,
+                     weights_dtype="bfloat16", mtp_layers=1),
+            num_pages=512, page_size=16, token_budget=1024, max_batch=4,
+            prompts=[300, 20, 280, 31], new_tokens=8),
     },
     "tiny": {
         "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
@@ -84,8 +98,22 @@ SIZES = {
                      max_seq_len=128),
             num_pages=64, page_size=16, token_budget=128, max_batch=8,
             prompts=[40, 5, 36, 9, 7, 50, 6, 34], new_tokens=6),
+        # widths the three kernels engage at (8 heads, lanes of 128)
+        "mla": dict(
+            cfg=dict(vocab_size=256, hidden=128, num_heads=8, num_layers=2,
+                     first_k_dense=1, intermediate=256, moe_intermediate=128,
+                     n_routed_experts=8, num_experts_per_tok=2,
+                     q_lora_rank=64, kv_lora_rank=32, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16, max_seq_len=128,
+                     weights_dtype="bfloat16", mtp_layers=1),
+            num_pages=64, page_size=16, token_budget=128, max_batch=4,
+            prompts=[40, 5, 36, 9], new_tokens=6),
     },
 }
+
+# The MLA decoder's served logits against its float32 reference, bfloat16
+# weights and cache: the limits of benchmark/configs/joyai-llm-flash.json
+MLA_LOGIT_ABS_TOL, MLA_ROUTE_SLACK_TOL = 0.06, 0.008
 
 # A served token may differ from the reference's argmax only on a near-tie:
 # its reference logit must be within this much of the reference maximum
@@ -196,7 +224,7 @@ def pool_makers(text, forms):
     return made
 
 
-def pool_traffic(text, forms, views_free=True):
+def pool_traffic(text, forms, views_free=True, append="kv_append"):
     """``(moved, prefetched, held)`` for one compiled program: the
     pool-sized results that cost the device a pass over a pool (or, with
     ``views_free`` off, that show a pool in another form than the stored
@@ -216,7 +244,7 @@ def pool_traffic(text, forms, views_free=True):
             kind = line.partition(" = ")[2].split(" ", 1)[0]
             if not kind.startswith("("):        # a loop body's tuple
                 held.add(kind)
-        elif op in free or op == "custom-call" and "kv_append" in line:
+        elif op in free or op == "custom-call" and append in line:
             continue
         elif op == "bitcast" and (views_free or dims == forms[0]):
             continue
@@ -291,7 +319,8 @@ class Ctx:
                 f"phase compiled — the jnp path took their place")
         return found
 
-    def require_pool_in_place(self, phase, kv_config, n_pools):
+    def require_pool_in_place(self, phase, kv_config, n_pools,
+                              append="kv_append"):
         """Every prefill and decode program the phase compiled, read as the
         chip's compiler left it.  A pool stored lane-full
         (``kv_config.pool_shape()``: rows of 128 lanes) must be held in the
@@ -316,7 +345,7 @@ class Ctx:
         copies, prefetches, held = 0, 0, set()
         for name, text in texts.items():
             moved, prefetched, layouts = pool_traffic(
-                text, forms, views_free=not lane_full)
+                text, forms, views_free=not lane_full, append=append)
             held.update(layouts)
             prefetches = max(prefetches, prefetched)
             allowed = n_pools if "paged_decode" in text and not lane_full \
@@ -662,6 +691,123 @@ def phase_serve(ctx):
         kernel_calls=kernels, **in_place, **verdict, **ctx.memory())
 
 
+def phase_mla(ctx):
+    """The latent-attention / sparse-expert decoder through ServingEngine:
+    its three kernels in the lowered programs, no operation of pool size in
+    the compiled ones, the served logits against the plain reference, and
+    pipelined steps and the MTP drafter leaving greedy tokens unchanged."""
+    import importlib.util
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu.inference.mla_decoder import (MLADecoderConfig,
+                                                  MTPDrafter,
+                                                  init_mla_weights)
+    from paddle_tpu.inference.serving import Request, ServingEngine
+
+    jax = ctx.jax
+    phase, size = "serve/mla", ctx.sizes["mla"]
+    cfg = MLADecoderConfig(**size["cfg"])
+    spec = importlib.util.spec_from_file_location(
+        "reference_joyai", os.path.join(os.path.dirname(os.path.abspath(
+            __file__)), "benchmark", "reference", "joyai-llm-flash.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    ref_cfg = cfg.source_config()
+    weights = {n: jax.device_put(w, ctx.device)
+               for n, w in init_mla_weights(cfg, 0).items()}
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in size["prompts"]]
+
+    def engine(**kw):
+        eng = ServingEngine(
+            cfg=cfg, weights=weights, kv_dtype="bfloat16", place=ctx.place,
+            num_pages=size["num_pages"], page_size=size["page_size"],
+            max_batch=size["max_batch"], token_budget=size["token_budget"],
+            **kw)
+        eng.core.keep_scores = True
+        return eng
+
+    def drive(eng):
+        reqs = [Request(i, p, size["new_tokens"])
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_to_completion()
+        return reqs
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        mark = ctx.watch.mark()
+        plain = engine()
+        reqs = drive(plain)
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(
+            phase, modules, ["mla_decode", "latent_append", "moe_gmm"])
+        in_place = ctx.require_pool_in_place(
+            phase, plain.core.kv_config,
+            n_pools=len(cfg.cache_pool_names()), append="latent_append")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    worst, slack = 0.0, 0.0
+    for r in reqs:
+        got, routes = plain.core.served_scores(r.req_id)
+        ref = reference.served_token_scores(
+            weights, ref_cfg, r.prompt, r.out_tokens, routes, pad_to=512)
+        worst = max(worst, float(np.abs(got[:, 0] - ref["logit"]).max()),
+                    float(np.abs(got[:, 1] - ref["lse"]).max()))
+        slack = max(slack, float(ref["slack"].max()))
+    if worst > MLA_LOGIT_ABS_TOL or slack > MLA_ROUTE_SLACK_TOL:
+        raise RuntimeError(
+            f"{phase}: served logits lie {worst} from the reference (limit "
+            f"{MLA_LOGIT_ABS_TOL}), routing slack {slack} (limit "
+            f"{MLA_ROUTE_SLACK_TOL})")
+    del plain
+    gc.collect()
+    # pipelined steps (tokens stay on the device between calls): the same
+    # tokens by the same schedule
+    piped = engine(pipeline=True)
+    piped_reqs = drive(piped)
+    if [r.out_tokens for r in piped_reqs] != [r.out_tokens for r in reqs]:
+        raise RuntimeError(f"{phase}: pipelined steps changed the tokens "
+                           f"served: {[r.out_tokens for r in piped_reqs]} "
+                           f"vs {[r.out_tokens for r in reqs]}")
+    del piped
+    gc.collect()
+    # the drafter on: the same tokens, and its logits against the reference
+    drafter = MTPDrafter()
+    spec_eng = engine(spec_k=1, proposer=drafter)
+    spec_reqs = drive(spec_eng)
+    if [r.out_tokens for r in spec_reqs] != [r.out_tokens for r in reqs]:
+        raise RuntimeError(f"{phase}: the MTP drafter changed the tokens "
+                           f"served: {[r.out_tokens for r in spec_reqs]} vs "
+                           f"{[r.out_tokens for r in reqs]}")
+    r0 = Request("mtp", prompts[3], 2)
+    spec_eng.core.kv.append_tokens("mtp", len(r0.prompt), tokens=r0.prompt)
+    hidden = np.asarray(reference.hidden_states(
+        weights, np.asarray(r0.prompt, np.int32), ref_cfg)[0])
+    drafter.after_prefill(r0, hidden, reqs[3].out_tokens[0],
+                          keep_logits=True)
+    want = np.asarray(reference.mtp_logits_all_positions(
+        weights, r0.prompt + [reqs[3].out_tokens[0]], ref_cfg))
+    mtp_gap = float(np.abs(drafter.last_logits - want).max())
+    if mtp_gap > MLA_LOGIT_ABS_TOL:
+        raise RuntimeError(f"{phase}: the MTP module's logits lie {mtp_gap} "
+                           f"from the reference's")
+    say(phase=phase, **size["cfg"], num_pages=size["num_pages"],
+        prompts=size["prompts"], new_tokens=size["new_tokens"],
+        scheduler=spec_eng.stats, **seen, kernel_calls=kernels, **in_place,
+        served_logits_worst_gap=worst, route_slack=slack,
+        mtp_logits_worst_gap=mtp_gap,
+        drafter="tokens identical with the drafter on and off",
+        pipeline="tokens identical with pipelined steps on and off",
+        **ctx.memory())
+
+
 def phase_tp4(ctx):
     """tp=4 decode against tp=1 tokens for the same requests."""
     phase, size = "tp4/decoder", ctx.sizes["serve"]
@@ -711,6 +857,10 @@ def main(argv=None):
                     help="4: only the DP-4 and tp=4 paths and what they "
                          "are compared with")
     ap.add_argument("--size", choices=sorted(SIZES), default="full")
+    ap.add_argument("--only", default=None,
+                    help="comma-separated phases to run instead of all of "
+                         "the chip count's (resnet, bert, serve, mla, dp4, "
+                         "tp4)")
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="skip the TPU assertion (and the device's memory "
                          "counters): a rehearsal, never a result")
@@ -733,7 +883,10 @@ def main(argv=None):
             compile_cache_dir=ctx.cache_dir, cache_entries_before=entries0,
             note="smoke observations, not benchmark metrics")
         phases = (phase_dp4, phase_tp4) if args.chips == 4 else \
-            (phase_resnet, phase_bert, phase_serve)
+            (phase_resnet, phase_bert, phase_serve, phase_mla)
+        if args.only:
+            phases = [globals()["phase_" + name]
+                      for name in args.only.split(",")]
         for phase in phases:
             phase(ctx)
             gc.collect()

@@ -203,6 +203,39 @@ def _t_paged_attention(op_, block, ndev, assumed_batch):
     return total
 
 
+def _slot_bytes(op_, block, assumed_batch, slot):
+    return sum(var_bytes(block, n, assumed_batch) or 0
+               for n in op_.inputs.get(slot, []))
+
+
+def _t_mla_paged_attention(op_, block, ndev, assumed_batch):
+    """As ``paged_attention``: the gather fallback materializes each
+    row's latent rows over the block-table width, bounded above by the
+    pool it gathers from (the kernel streams pages)."""
+    return _slot_bytes(op_, block, assumed_batch, "Cache")
+
+
+def _t_mla_prefill_attention(op_, block, ndev, assumed_batch):
+    """``W_kvb`` widens the latent rows to per-head keys and values, and
+    queries, keys and values are laid heads-first for the kernel: about
+    four arrays the size of QNope beyond the declared slots (the jnp
+    fallback's score blocks are smaller at any prompt a test runs)."""
+    return 4 * _slot_bytes(op_, block, assumed_batch, "QNope")
+
+
+def _t_moe_experts(op_, block, ndev, assumed_batch):
+    """Every token is copied once per chosen expert into expert order,
+    passed through the grouped matmuls and copied back: about three
+    arrays of k times X's bytes (sorted rows, the down projection's
+    rows, their unsorted copy; the gated middle is narrower)."""
+    idx = op_.inputs.get("Idx", [])
+    v = block._find_var_recursive(idx[0]) if idx else None
+    shape = getattr(v, "shape", None) or ()
+    k = shape[-1] if shape and isinstance(shape[-1], int) \
+        and shape[-1] > 0 else 8
+    return 3 * k * _slot_bytes(op_, block, assumed_batch, "X")
+
+
 def _t_sample_token(op_, block, ndev, assumed_batch):
     """The top-k/top-p filters sort the logits rows and build filtered
     copies before the categorical draw: ~3 logits-sized f32 temporaries
@@ -281,6 +314,9 @@ TRANSIENT_BYTES = {
     "c_concat": _t_allgather,          # all-gather then concat: same peak
     "coalesce_tensor": _t_coalesce,
     "paged_attention": _t_paged_attention,
+    "mla_paged_attention": _t_mla_paged_attention,
+    "mla_prefill_attention": _t_mla_prefill_attention,
+    "moe_experts": _t_moe_experts,
     "sample_token": _t_sample_token,
     "while": _t_subblock,
     "while_loop": _t_subblock,
@@ -387,6 +423,8 @@ unique_with_counts unpool unsqueeze unsqueeze2 unstack
 update_loss_scaling var_conv_2d warpctc
 where where_index while_loop_grad write_to_array yolo_box yolov3_loss
 select_input select_output kv_cache_append kv_dequant
+latent_cache_append matmul_f32acc moe_router rms_norm rope_interleaved
+slot_is_live swiglu token_score
 allreduce alltoall barrier broadcast c_allreduce_max c_allreduce_min
 c_allreduce_prod c_allreduce_sum c_broadcast c_comm_init c_comm_init_all
 c_gen_nccl_id c_identity c_reducescatter c_split c_sync_calc_stream
@@ -404,7 +442,8 @@ fusion_squared_mat_sub fusion_transpose_flatten_concat
 """.split())
 # Audit notes (what kept suspects OFF the default list): in-place
 # psum-style allreduces write their input (no second buffer);
-# `kv_cache_append` scatters in place into the donated pool;
+# `kv_cache_append` and `latent_cache_append` write in place into the
+# donated pool; `moe_router`'s scores are (rows, experts), under its X;
 # `kv_dequant` is an elementwise cast(+scale) into its declared slot;
 # `c_identity`/`c_split` are views.  ON the explicit table instead:
 # fused bucket collectives (flat concat payload), `c_allgather` /

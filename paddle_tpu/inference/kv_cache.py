@@ -223,6 +223,10 @@ class PagedKVCache:
         self.seed = int(seed)
         self._free: deque = deque(range(config.num_pages))
         self._seqs: Dict[object, _Seq] = {}
+        # the sum of every live sequence's length, kept as they change:
+        # ``fragmentation`` is published on every append, and summing the
+        # sequences there made a decode step of n sequences n * n
+        self._live_tokens = 0
         # CoW / prefix-index state (all empty — and untouched — when
         # prefix_cache is off, so the legacy path stays byte-identical)
         self._refs: Dict[int, int] = {}            # page -> refcount
@@ -271,7 +275,7 @@ class PagedKVCache:
         if self.prefix_cache:
             tokens = sum(self._used.get(p, 0) for p in self._refs)
         else:
-            tokens = sum(s.length for s in self._seqs.values())
+            tokens = self._live_tokens
         return 1.0 - tokens / (used_pages * self.config.page_size)
 
     def pages_needed(self, seq_id, n_tokens: int) -> int:
@@ -452,11 +456,20 @@ class PagedKVCache:
         self.peak_pages = max(self.peak_pages, self.pages_in_use)
         if need:
             self._tm.current().alloc.inc(need)
-        slots = np.empty(n_tokens, np.int32)
-        for j in range(n_tokens):
-            pos = s.length + j
-            slots[j] = s.pages[pos // ps] * ps + pos % ps
+        if n_tokens > 32:
+            # a prompt: the same slots as the loop below, in one pass
+            pos = s.length + np.arange(n_tokens)
+            first = s.length // ps
+            pages = np.asarray(s.pages[first:], np.int64)
+            slots = (pages[pos // ps - first] * ps + pos % ps) \
+                .astype(np.int32)
+        else:
+            slots = np.empty(n_tokens, np.int32)
+            for j in range(n_tokens):
+                pos = s.length + j
+                slots[j] = s.pages[pos // ps] * ps + pos % ps
         s.length += n_tokens
+        self._live_tokens += n_tokens
         if self.prefix_cache:
             # only pages covering the appended range can change — a
             # whole-sequence rescan here would be O(len^2) host work
@@ -525,6 +538,7 @@ class PagedKVCache:
                 s.pending_shared += 1
             self._refs[page] = prev + 1
         s.pages = list(pages)
+        self._live_tokens += hit - s.length
         s.length = hit
         s.tokens = [int(t) for t in tokens]
         s.pending_hit = hit
@@ -593,6 +607,7 @@ class PagedKVCache:
                     self._free.append(page)
                     if self.prefix_cache:
                         self._used.pop(page, None)
+        self._live_tokens -= s.length - new_len
         s.length = new_len
         if self.prefix_cache and not s.opaque:
             s.tokens = s.tokens[:new_len]
@@ -633,6 +648,7 @@ class PagedKVCache:
         s = self._seqs.pop(seq_id, None)
         if s is None:
             return
+        self._live_tokens -= s.length
         released = 0
         for page in s.pages:
             self._refs[page] = self._refs.get(page, 1) - 1

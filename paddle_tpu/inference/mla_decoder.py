@@ -1,0 +1,692 @@
+"""A latent-attention (MLA) decoder with sigmoid-routed experts, a shared
+expert and a multi-token-prediction head: the DeepSeek-V3-shaped block
+(arXiv:2405.04434 section 2.1, arXiv:2412.19437 sections 2.1-2.2) as a
+model description ``ServingEngine`` serves through the same seam as
+``DecoderConfig`` (inference/serving.py): parameter specs, program forms,
+cache pools.
+
+Per layer ``h = x + MLA(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
+first ``first_k_dense`` layers' FFN is a SwiGLU of width ``intermediate``,
+the rest the routed experts plus the shared expert.  No biases, untied
+head.  The cache holds one row ``[c_kv | k_r]`` a token and layer
+(``kv_lora_rank + qk_rope_head_dim`` values), shared by every head:
+``c_kv`` after its norm, ``k_r`` after RoPE.
+
+Forms: ``reference`` and ``prefill`` run the EXPANDED attention (``W_kvb``
+widens the latent rows to per-head keys and values) over one whole prompt;
+``decode`` and ``verify`` run the ABSORBED attention over the paged latent
+pool (``mla_decode``), a verify row being a decode row whose context ends
+at its own position — so a drafted token is scored by the very kernel
+that would have decoded it.  ``chunk`` (prefix cache, chunked prefill) is
+not built: the engine refuses those flags for this model at construction.
+
+Types: parameters in ``weights_dtype``; every matmul takes operands of
+that type and accumulates in float32; the residual stream, norms, router
+scores and softmax are float32.
+
+The multi-token-prediction module (``mtp_layers`` 1) is one more block
+with its own cache rows (layer index ``num_layers``) behind ``h' = W_p
+[RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))]``; :class:`MTPDrafter` runs it as
+an engine drafter on the speculative path.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Dict, List
+
+import numpy as np
+
+from ..framework.core import Program
+from ..framework.dtype import VarType, convert_dtype
+from .kv_cache import KVCacheConfig
+from .spec_decode import Proposer
+
+__all__ = ["MLADecoderConfig", "MTPDrafter", "init_mla_weights"]
+
+
+@dataclass(frozen=True)
+class MLADecoderConfig:
+    vocab_size: int = 128
+    hidden: int = 64
+    num_heads: int = 4
+    num_layers: int = 3
+    first_k_dense: int = 1
+    intermediate: int = 128          # the dense layers' SwiGLU width
+    moe_intermediate: int = 32       # one expert's (and the shared one's)
+    n_routed_experts: int = 8
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 2
+    q_lora_rank: int = 32
+    kv_lora_rank: int = 16
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    max_seq_len: int = 256
+    eos_id: int = -1
+    weights_dtype: str = "float32"
+    mtp_layers: int = 0              # 1: the MTP block's weights and cache
+
+    # -- the seam ServingEngine asks a model description through ---------
+    @property
+    def latent_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The width a latent row is STORED at: whole 128-lane tiles where
+        it is wider than one (576 -> 640: the chip pads the lanes of a
+        576-wide array to 640 anyway, and cannot copy 576 of them)."""
+        w = self.latent_width
+        return w if w <= 128 else -(-w // 128) * 128
+
+    @property
+    def param_dtype(self) -> str:
+        return self.weights_dtype
+
+    def param_specs(self) -> Dict[str, tuple]:
+        return mla_param_specs(self)
+
+    def build_program(self, mode: str, sampling=None,
+                      kv_dtype: str = "float32", tp: int = 1) -> tuple:
+        return build_mla_program(self, mode, sampling=sampling,
+                                 kv_dtype=kv_dtype)
+
+    def validate(self, tp: int = 1, kv_dtype: str = "float32",
+                 prefix_cache: bool = False, prefill_chunk: int = 0):
+        """What this model is not served with, refused at construction."""
+        if int(tp or 1) != 1:
+            raise ValueError("the MLA decoder has no tensor-parallel rules: "
+                             "serving_tp must be 1")
+        if kv_dtype == "int8":
+            raise ValueError("the latent pool has no int8 storage: "
+                             "kv_dtype must be float32 or bfloat16")
+        if prefix_cache or prefill_chunk:
+            raise ValueError(
+                "the MLA decoder builds no 'chunk' program form: prefix "
+                "caching and chunked prefill are refused for this model")
+
+    def tp_rules(self, kv_dtype: str = "float32") -> Dict[str, tuple]:
+        return {}
+
+    def kv_cache_config(self, num_pages: int, page_size: int,
+                        kv_dtype: str) -> KVCacheConfig:
+        """One latent row a token: a single 'head' of ``latent_row``."""
+        return KVCacheConfig(
+            num_pages=num_pages, page_size=page_size, num_kv_heads=1,
+            head_dim=self.latent_row,
+            num_layers=self.num_layers + self.mtp_layers, dtype=kv_dtype)
+
+    def cache_pool_names(self) -> List[str]:
+        return [f"kv_lat_{i}"
+                for i in range(self.num_layers + self.mtp_layers)]
+
+    def kv_token_bytes(self, kv_dtype: str, tp: int = 1) -> int:
+        return (self.num_layers + self.mtp_layers) * self.latent_row \
+            * np.dtype(kv_dtype).itemsize
+
+    # -- the source's names (its config.json), which the configuration
+    # file, the plain reference and the tests speak ----------------------
+    _SOURCE_KEYS = {
+        "vocab_size": "vocab_size", "hidden": "hidden_size",
+        "num_heads": "num_attention_heads",
+        "num_layers": "num_hidden_layers",
+        "first_k_dense": "first_k_dense_replace",
+        "intermediate": "intermediate_size",
+        "moe_intermediate": "moe_intermediate_size",
+        "n_routed_experts": "n_routed_experts",
+        "n_shared_experts": "n_shared_experts",
+        "num_experts_per_tok": "num_experts_per_tok",
+        "q_lora_rank": "q_lora_rank", "kv_lora_rank": "kv_lora_rank",
+        "qk_nope_head_dim": "qk_nope_head_dim",
+        "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+        "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+        "routed_scaling_factor": "routed_scaling_factor",
+        "norm_topk_prob": "norm_topk_prob",
+    }
+
+    def source_config(self) -> dict:
+        """This model under the source's key names."""
+        return {theirs: getattr(self, ours)
+                for ours, theirs in self._SOURCE_KEYS.items()}
+
+    @classmethod
+    def from_source(cls, source: dict, **ours) -> "MLADecoderConfig":
+        """From a ``config.json`` of the source's shape; ``ours`` gives what
+        it does not say (``max_seq_len``, ``weights_dtype``, ...)."""
+        return cls(**{mine: source[theirs]
+                      for mine, theirs in cls._SOURCE_KEYS.items()}, **ours)
+
+
+def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
+    h, heads = cfg.hidden, cfg.num_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    p = f"dec_l{i}_"
+    specs = {
+        p + "attn_norm_scale": (h,),
+        p + "wq_a": (h, cfg.q_lora_rank),
+        p + "q_norm_scale": (cfg.q_lora_rank,),
+        p + "wq_b": (cfg.q_lora_rank, heads * qk),
+        p + "wkv_a": (h, cfg.latent_width),
+        p + "kv_norm_scale": (cfg.kv_lora_rank,),
+        p + "wkv_b": (cfg.kv_lora_rank,
+                      heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        p + "wo": (heads * cfg.v_head_dim, h),
+        p + "ffn_norm_scale": (h,),
+    }
+    if not moe:
+        f = cfg.intermediate
+        specs.update({p + "w_gate": (h, f), p + "w_up": (h, f),
+                      p + "w_down": (f, h)})
+        return specs
+    f, e = cfg.moe_intermediate, cfg.n_routed_experts
+    fs = f * cfg.n_shared_experts
+    specs.update({
+        p + "router": (h, e), p + "router_bias": (e,),
+        p + "experts_gate": (e, h, f), p + "experts_up": (e, h, f),
+        p + "experts_down": (e, f, h),
+        p + "shared_gate": (h, fs), p + "shared_up": (h, fs),
+        p + "shared_down": (fs, h),
+    })
+    return specs
+
+
+def mla_param_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
+    """name -> shape of every weight, the MTP module's included where the
+    configuration holds it."""
+    h = cfg.hidden
+    specs = {"dec_embed": (cfg.vocab_size, h), "dec_head": (h, cfg.vocab_size),
+             "dec_norm_scale": (h,)}
+    for i in range(cfg.num_layers):
+        specs.update(_layer_specs(cfg, i, moe=i >= cfg.first_k_dense))
+    if cfg.mtp_layers:
+        specs.update({"mtp_hnorm_scale": (h,), "mtp_enorm_scale": (h,),
+                      "mtp_proj": (2 * h, h), "mtp_norm_scale": (h,)})
+        specs.update(_layer_specs(cfg, cfg.num_layers, moe=True))
+    return specs
+
+
+def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
+                     ) -> Dict[str, np.ndarray]:
+    """Seeded weights for tests and smokes: norm scales 1, the router's
+    correction bias small and seeded, the rest normal over sqrt(fan-in)
+    (the fan-in is the second-to-last axis: weights multiply on the
+    right)."""
+    rng = np.random.RandomState(seed)
+    out = {}
+    for name, shape in mla_param_specs(cfg).items():
+        if name.endswith("_scale"):
+            w = np.ones(shape, np.float32)
+        elif name.endswith("router_bias"):
+            w = (0.01 * rng.randn(*shape)).astype(np.float32)
+        elif name == "dec_embed":
+            w = rng.randn(*shape).astype(np.float32)
+        else:
+            w = (rng.randn(*shape) / np.sqrt(shape[-2])).astype(np.float32)
+        out[name] = w.astype(np.dtype(cfg.weights_dtype))
+    return out
+
+
+# ==========================================================================
+# Program builders
+# ==========================================================================
+class _MB:
+    """The block builder of serving.py's ``_B`` plus this model's
+    composites.  Parameters take the configuration's weights type."""
+
+    def __init__(self, program: Program, cfg: MLADecoderConfig):
+        from .serving import _B
+
+        self.b = _B(program)
+        self.cfg = cfg
+        self.wdt = convert_dtype(cfg.weights_dtype)
+        for name, shape in mla_param_specs(cfg).items():
+            self.b.param(name, shape, dtype=self.wdt)
+
+    def op(self, *a, **kw):
+        self.b.op(*a, **kw)
+
+    def tmp(self, tag):
+        return self.b.tmp(tag)
+
+    # ``part`` names whose time an op is in the device trace (a named
+    # scope): mla_part, moe_part, dense_ffn, head
+    def mm(self, x, w, tag, part):
+        o = self.tmp(tag)
+        self.op("matmul_f32acc", {"X": [x], "Y": [w]}, {"Out": [o]},
+                {"part": part})
+        return o
+
+    def norm(self, x, scale, tag, part):
+        o = self.tmp(tag)
+        self.op("rms_norm", {"X": [x], "Scale": [scale]}, {"Y": [o]},
+                {"epsilon": float(self.cfg.rms_norm_eps), "part": part})
+        return o
+
+    def rope(self, x, positions, tag):
+        o = self.tmp(tag)
+        self.op("rope_interleaved", {"X": [x], "Positions": [positions]},
+                {"Out": [o]}, {"theta": float(self.cfg.rope_theta),
+                               "part": "mla_part"})
+        return o
+
+    def split(self, x, sizes, tag):
+        outs = [self.tmp(f"{tag}_{j}") for j in range(len(sizes))]
+        self.op("split", {"X": [x]}, {"Out": outs},
+                {"axis": -1, "sections": list(sizes), "num": 0})
+        return outs
+
+    def swiglu_ffn(self, x, gate, up, down, tag, part):
+        g = self.mm(x, gate, tag + "_g", part)
+        u = self.mm(x, up, tag + "_u", part)
+        a = self.tmp(tag + "_act")
+        self.op("swiglu", {"Gate": [g], "Up": [u]}, {"Out": [a]},
+                {"part": part})
+        return self.mm(a, down, tag + "_d", part)
+
+    def latents(self, i, hn, positions):
+        """Layer ``i``'s queries and latent row of the normed rows ``hn``
+        (n, hidden): ``(q_nope, q_rope) (n, heads, dn | dr)``, ``c_kv``
+        (n, r) normed, ``k_r`` (n, dr) after RoPE."""
+        cfg, p, b = self.cfg, f"dec_l{i}_", self.b
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        part = "mla_part"
+        cq = self.norm(self.mm(hn, p + "wq_a", f"l{i}_cq", part),
+                       p + "q_norm_scale", f"l{i}_cqn", part)
+        q = b.reshape(self.mm(cq, p + "wq_b", f"l{i}_q", part),
+                      [-1, cfg.num_heads, dn + dr], f"l{i}_q3")
+        q_nope, q_rope = self.split(q, [dn, dr], f"l{i}_qs")
+        q_rope = self.rope(q_rope, positions, f"l{i}_qr")
+        c_kv, k_r = self.split(self.mm(hn, p + "wkv_a", f"l{i}_kva", part),
+                               [cfg.kv_lora_rank, dr], f"l{i}_kvs")
+        c_kv = self.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv", part)
+        k_r = b.reshape(self.rope(b.reshape(k_r, [-1, 1, dr], f"l{i}_kr3"),
+                                  positions, f"l{i}_krr"),
+                        [-1, dr], f"l{i}_kr")
+        return q_nope, q_rope, c_kv, k_r
+
+    def pool(self, i, kv_dtype):
+        return self.b.param(f"kv_lat_{i}", (), dtype=convert_dtype(kv_dtype))
+
+    def append(self, i, c_kv, k_r, slot_map, kv_dtype):
+        pool = self.pool(i, kv_dtype)
+        self.op("latent_cache_append",
+                {"CKV": [c_kv], "KRope": [k_r], "SlotMapping": [slot_map],
+                 "Cache": [pool]}, {"CacheOut": [pool]})
+        return pool
+
+    def attn_attrs(self):
+        cfg = self.cfg
+        return {"v_head_dim": int(cfg.v_head_dim), "scale": float(
+            (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)}
+
+    def block(self, i, hid, positions, attend, valid, counts, routes=None):
+        """One pre-norm block over rows ``hid`` (n, hidden).  ``attend``
+        maps ``(i, q_nope, q_rope, c_kv, k_r)`` to the attention's output
+        (n, heads * dv); ``valid`` (or None) marks the rows that are real
+        tokens; an expert layer appends its per-expert counts to
+        ``counts``."""
+        cfg, p, b = self.cfg, f"dec_l{i}_", self.b
+        hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an", "mla_part")
+        att = attend(i, *self.latents(i, hn, positions))
+        hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o", "mla_part"),
+                    f"l{i}_res1")
+        dense = i < cfg.first_k_dense
+        part = "dense_ffn" if dense else "moe_part"
+        hn2 = self.norm(hid, p + "ffn_norm_scale", f"l{i}_fn", part)
+        if dense:
+            ff = self.swiglu_ffn(hn2, p + "w_gate", p + "w_up", p + "w_down",
+                                 f"l{i}_ff", part)
+        else:
+            idx, wgt = self.tmp(f"l{i}_ridx"), self.tmp(f"l{i}_rw")
+            self.op("moe_router",
+                    {"X": [hn2], "Gate": [p + "router"],
+                     "Bias": [p + "router_bias"]},
+                    {"Idx": [idx], "Weight": [wgt]},
+                    {"top_k": int(cfg.num_experts_per_tok),
+                     "routed_scaling_factor":
+                         float(cfg.routed_scaling_factor),
+                     "norm_topk_prob": bool(cfg.norm_topk_prob)})
+            routed, cnt = self.tmp(f"l{i}_moe"), self.tmp(f"l{i}_cnt")
+            ins = {"X": [hn2], "Idx": [idx], "Weight": [wgt],
+                   "WGate": [p + "experts_gate"], "WUp": [p + "experts_up"],
+                   "WDown": [p + "experts_down"]}
+            if valid is not None:
+                ins["Valid"] = [valid]
+            self.op("moe_experts", ins, {"Out": [routed], "Counts": [cnt]})
+            counts.append(cnt)
+            if routes is not None:
+                routes.append(idx)
+            shared = self.swiglu_ffn(hn2, p + "shared_gate", p + "shared_up",
+                                     p + "shared_down", f"l{i}_sh", part)
+            ff = b.add(routed, shared, f"l{i}_ff")
+        return b.add(hid, ff, f"l{i}_res2")
+
+    def stacked(self, per_layer, name):
+        """The expert layers' small int32 results as one fetch, layers
+        first."""
+        out = self.b.blk.create_var(name=name, dtype=VarType.INT32).name
+        self.op("stack", {"X": list(per_layer)}, {"Y": [out]}, {"axis": 0})
+        return out
+
+    def live_rows(self, slot_map, layer, kv_dtype):
+        """Rows whose slot lies in the pool are real tokens; bucket padding
+        carries the pad sentinel, the first slot past it."""
+        o = self.tmp("valid")
+        self.op("slot_is_live", {"SlotMapping": [slot_map],
+                                 "Cache": [self.pool(layer, kv_dtype)]},
+                {"Out": [o]})
+        return o
+
+
+def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
+                      kv_dtype: str = "float32") -> tuple:
+    """One program form of the decoder: ``(program, feeds, fetches)``.
+    Besides its token the program carries ``_srv_logits`` (the parity
+    hook), ``_srv_hidden`` (the rows' last hidden state before the final
+    norm: what the MTP drafter consumes), ``_srv_counts`` (tokens per
+    expert by expert layer) and ``_srv_score`` (each emitted token's logit
+    and the row's log-sum-exp, two floats a row)."""
+    from .serving import _emit_head, _sampled
+
+    if mode == "chunk":
+        raise ValueError("the MLA decoder builds no 'chunk' form")
+    if mode == "mtp":
+        return _build_mtp_program(cfg, sampling, kv_dtype)
+    if mode not in ("reference", "prefill", "decode", "verify"):
+        raise ValueError(f"bad mode {mode!r}")
+    if kv_dtype not in ("float32", "bfloat16"):
+        raise ValueError(f"bad kv_dtype {kv_dtype!r}")
+    if _sampled(sampling) and mode == "reference":
+        raise ValueError("the reference form is the greedy oracle; "
+                         "sampling applies to serving forms only")
+    prog = Program()
+    prog._label = mode
+    m = _MB(prog, cfg)
+    b = m.b
+    whole = mode in ("reference", "prefill")
+    seeds = None
+    if whole:
+        tokens = b.feed("tokens", (1, -1), VarType.INT32)
+        positions = b.feed("positions", (1, -1), VarType.INT32)
+        last_index = b.feed("last_index", (1,), VarType.INT32)
+        feeds = ["tokens", "positions", "last_index"]
+        if mode == "prefill":
+            slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
+            feeds.append("slot_mapping")
+        if _sampled(sampling):
+            seeds = b.feed("sample_seeds", (1,), VarType.INT32)
+            feeds.append("sample_seeds")
+    elif mode == "decode":
+        tokens = b.feed("tokens", (-1,), VarType.INT32)
+        positions = b.feed("positions", (-1,), VarType.INT32)
+        tables = b.feed("block_tables", (-1, -1), VarType.INT32)
+        ctx_lens = b.feed("context_lens", (-1,), VarType.INT32)
+        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
+        feeds = ["tokens", "positions", "block_tables", "context_lens",
+                 "slot_mapping"]
+        if _sampled(sampling):
+            seeds = b.feed("sample_seeds", (-1,), VarType.INT32)
+            feeds.append("sample_seeds")
+    else:
+        tokens = b.feed("tokens", (-1, -1), VarType.INT32)          # (B, S)
+        positions = b.feed("positions", (-1, -1), VarType.INT32)
+        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)     # (B*S,)
+        tables = b.feed("verify_tables", (-1, -1), VarType.INT32)   # (B, W)
+        feeds = ["tokens", "positions", "slot_mapping", "verify_tables"]
+        if _sampled(sampling):
+            seeds = b.feed("sample_seeds", (-1,), VarType.INT32)
+            feeds.append("sample_seeds")
+
+    flat_tok = b.reshape(tokens, [-1], "tok_flat")
+    flat_pos = b.reshape(positions, [-1], "pos_flat")
+    hid = b.tmp("h0")
+    m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [flat_tok]},
+         {"Out": [hid]})
+    hid32 = b.tmp("h0_f32")
+    m.op("cast", {"X": [hid]}, {"Out": [hid32]},
+         {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
+    hid = hid32
+    if mode == "verify":
+        # a verify row's context ends at its own position
+        ctx_lens = b.tmp("ctx_from_pos")
+        m.op("scale", {"X": [flat_pos]}, {"Out": [ctx_lens]},
+             {"scale": 1.0, "bias": 1.0, "bias_after_scale": True})
+
+    attrs = m.attn_attrs()
+
+    def attend(i, q_nope, q_rope, c_kv, k_r):
+        out = b.tmp(f"l{i}_att")
+        if mode != "reference":
+            pool = m.append(i, c_kv, k_r, slot_map, kv_dtype)
+        if whole:
+            m.op("mla_prefill_attention",
+                 {"QNope": [q_nope], "QRope": [q_rope], "CKV": [c_kv],
+                  "KRope": [k_r], "WKVB": [f"dec_l{i}_wkv_b"]},
+                 {"Out": [out]}, attrs)
+        else:
+            m.op("mla_paged_attention",
+                 {"QNope": [q_nope], "QRope": [q_rope], "Cache": [pool],
+                  "BlockTables": [tables], "ContextLens": [ctx_lens],
+                  "WKVB": [f"dec_l{i}_wkv_b"]}, {"Out": [out]}, attrs)
+        return out
+
+    valid = None
+    if mode != "reference":
+        valid = m.live_rows(slot_map, 0, kv_dtype)
+    counts: List[str] = []
+    routes: List[str] = []
+    for i in range(cfg.num_layers):
+        hid = m.block(i, hid, flat_pos, attend, valid, counts, routes)
+    prog._srv_hidden = hid
+    if whole:
+        last = b.tmp("hlast")
+        m.op("gather", {"X": [hid], "Index": [last_index]}, {"Out": [last]},
+             {"axis": 0})
+        hid = last
+        # the routing of the one row that emits
+        picked = []
+        for j, r in enumerate(routes):
+            o = b.tmp(f"route_last_{j}")
+            m.op("gather", {"X": [r], "Index": [last_index]}, {"Out": [o]},
+                 {"axis": 0})
+            picked.append(o)
+        routes = picked
+    out_name = "next_token" if whole else "next_tokens"
+    logits = m.mm(m.norm(hid, "dec_norm_scale", "fnorm", "head"),
+                  "dec_head", "logits", "head")
+    _emit_head(b, logits, out_name, sampling, seeds)
+    score = b.blk.create_var(name="token_score", dtype=VarType.FP32).name
+    m.op("token_score", {"Logits": [logits], "Token": [out_name]},
+         {"Out": [score]})
+    prog._srv_params = dict.fromkeys(mla_param_specs(cfg))
+    prog._srv_logits = logits
+    prog._srv_score = score
+    # (expert layers, experts): the tokens each expert received; (expert
+    # layers, rows, k): the experts each emitting row was routed to
+    prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
+    prog._srv_routes = m.stacked(routes, "token_routes") if routes else None
+    prog._tp_degree = 1
+    return prog, feeds, [out_name]
+
+
+def _build_mtp_program(cfg: MLADecoderConfig, sampling, kv_dtype: str):
+    """The drafter's program, rows flat: row ``n`` is position ``p`` of
+    some sequence with the main model's hidden state there, the token at
+    ``p + 1``, its own block table and a context that ends at ``p``.  Every
+    row's latent enters the MTP layer's pool first, so the rows of one
+    prompt (contexts 1, 2, ...) are its causal prefill and the rows of a
+    batch its decode step.  Emits each row's draft of the token at ``p +
+    2`` (greedy: a draft is a guess, the verify samples)."""
+    from .serving import _emit_head
+
+    if not cfg.mtp_layers:
+        raise ValueError("this configuration holds no MTP module")
+    prog = Program()
+    prog._label = "mtp"
+    m = _MB(prog, cfg)
+    b = m.b
+    hidden = b.feed("hidden", (-1, cfg.hidden), VarType.FP32)
+    tokens = b.feed("tokens", (-1,), VarType.INT32)
+    positions = b.feed("positions", (-1,), VarType.INT32)
+    tables = b.feed("block_tables", (-1, -1), VarType.INT32)
+    ctx_lens = b.feed("context_lens", (-1,), VarType.INT32)
+    slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
+    feeds = ["hidden", "tokens", "positions", "block_tables",
+             "context_lens", "slot_mapping"]
+    emb = b.tmp("mtp_emb")
+    m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [tokens]},
+         {"Out": [emb]})
+    emb32 = b.tmp("mtp_emb_f32")
+    m.op("cast", {"X": [emb]}, {"Out": [emb32]},
+         {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
+    both = b.tmp("mtp_cat")
+    m.op("concat", {"X": [m.norm(hidden, "mtp_hnorm_scale", "mtp_hn", "mtp"),
+                          m.norm(emb32, "mtp_enorm_scale", "mtp_en", "mtp")]},
+         {"Out": [both]}, {"axis": -1})
+    hid = m.mm(both, "mtp_proj", "mtp_in", "mtp")
+    layer = cfg.num_layers
+    attrs = m.attn_attrs()
+
+    def attend(i, q_nope, q_rope, c_kv, k_r):
+        pool = m.append(i, c_kv, k_r, slot_map, kv_dtype)
+        out = b.tmp(f"l{i}_att")
+        m.op("mla_paged_attention",
+             {"QNope": [q_nope], "QRope": [q_rope], "Cache": [pool],
+              "BlockTables": [tables], "ContextLens": [ctx_lens],
+              "WKVB": [f"dec_l{i}_wkv_b"]}, {"Out": [out]}, attrs)
+        return out
+
+    valid = m.live_rows(slot_map, layer, kv_dtype)
+    counts: List[str] = []
+    hid = m.block(layer, hid, positions, attend, valid, counts)
+    logits = m.mm(m.norm(hid, "mtp_norm_scale", "mtp_fnorm", "head"),
+                  "dec_head", "mtp_logits", "head")
+    _emit_head(b, logits, "draft_tokens", None, None)
+    prog._srv_params = dict.fromkeys(mla_param_specs(cfg))
+    prog._srv_logits = logits
+    prog._tp_degree = 1
+    return prog, feeds, ["draft_tokens"]
+
+
+# ==========================================================================
+# The MTP drafter
+# ==========================================================================
+class MTPDrafter(Proposer):
+    """The model's own multi-token-prediction module as an engine drafter
+    (k = 1).  Unlike a token-history proposer it consumes the main model's
+    hidden states, which the engine hands it after each prefill and each
+    verify call (``after_prefill`` / ``after_verify``); ``propose`` returns
+    the draft those left behind.  Greedy acceptance is exact match, so the
+    served tokens are those of plain decode whatever it drafts."""
+
+    name = "mtp"
+    wants_hidden = True
+
+    def __init__(self):
+        self.core = None
+        self._draft: Dict[object, int] = {}
+        self.last_logits = None          # (rows, vocab) of the last call
+
+    def bind(self, core):
+        """Build the drafter's program on the engine's scope and pools."""
+        self.core = core
+        self.prog, self.feeds, self.fetch = core.cfg.build_program(
+            "mtp", kv_dtype=core.kv_dtype)
+        core.keep_hidden = True
+
+    def propose(self, req, k: int) -> List[int]:
+        d = self._draft.get(req.req_id)
+        return [d] if d is not None and k > 0 else []
+
+    def _run(self, hidden, tokens, positions, tables, ctx, slots,
+             keep_logits: bool = False):
+        from .serving import _pow2_bucket
+
+        core, n = self.core, len(tokens)
+        width = tables.shape[1]
+        npad = _pow2_bucket(max(n, 1))
+        feed = {
+            "hidden": np.zeros((npad, core.cfg.hidden), np.float32),
+            "tokens": np.zeros(npad, np.int32),
+            "positions": np.zeros(npad, np.int32),
+            "block_tables": np.zeros((npad, width), np.int32),
+            "context_lens": np.ones(npad, np.int32),
+            "slot_mapping": np.full(npad, core.kv_config.pad_slot, np.int32),
+        }
+        for key, val in (("hidden", hidden), ("tokens", tokens),
+                         ("positions", positions), ("block_tables", tables),
+                         ("context_lens", ctx), ("slot_mapping", slots)):
+            feed[key][:n] = val
+        fetch = list(self.fetch) + ([self.prog._srv_logits]
+                                    if keep_logits else [])
+        out = core.exe.run(self.prog, feed=feed, fetch_list=fetch,
+                           scope=core.scope)
+        if keep_logits:
+            self.last_logits = np.asarray(out[1])[:n]
+        return np.asarray(out[0])[:n]
+
+    def _slots(self, req_id, positions):
+        core = self.core
+        ps = core.kv_config.page_size
+        width = -(-(int(max(positions)) + 1) // ps)
+        table = core.kv.block_table(req_id, width)
+        pos = np.asarray(positions)
+        return table[pos // ps] * ps + pos % ps
+
+    def after_prefill(self, req, hidden, first_token: int,
+                      keep_logits: bool = False):
+        """``hidden`` (L, hidden): the prompt's rows.  Row ``i`` pairs with
+        the token at ``i + 1`` (the first generated token for the last
+        row); the last row's output drafts the second generated token."""
+        from .serving import _pow2_bucket
+
+        core, L = self.core, len(req.prompt)
+        tokens = list(req.prompt[1:]) + [int(first_token)]
+        pos = np.arange(L, dtype=np.int32)
+        W = _pow2_bucket(core.kv.num_pages_of(req.req_id))
+        tables = np.broadcast_to(core.kv.block_table(req.req_id, W), (L, W))
+        out = self._run(np.asarray(hidden)[:L], tokens, pos, tables, pos + 1,
+                        self._slots(req.req_id, pos), keep_logits)
+        self._draft[req.req_id] = int(out[-1])
+
+    def after_verify(self, items, hidden, chunk: int, accepts, emits):
+        """``items`` as ``verify_batch`` took them, ``hidden`` its rows
+        ``(B * chunk, hidden)``; sequence ``i`` emitted ``emits[i]`` after
+        accepting ``accepts[i]`` drafts.  The rows of the positions that
+        now hold served tokens enter the MTP layer; the last one's output
+        drafts the next step."""
+        from .serving import _pow2_bucket
+
+        core = self.core
+        alive = set(core.kv.live_sequences())    # a finished one was freed
+        live = [(i, st) for i, (st, _d) in enumerate(items)
+                if st.req.req_id in alive]
+        if not live:
+            return
+        W = _pow2_bucket(max(core.kv.num_pages_of(st.req.req_id)
+                             for _, st in live))
+        rows, tokens, pos, tabs, slots, owners = [], [], [], [], [], []
+        for i, st in live:
+            rid = st.req.req_id
+            n = min(accepts[i] + 1, len(emits[i]))
+            # after the verify's roll-back the context ends at the last
+            # accepted position: the served rows are its last n
+            at = core.kv.context_len(rid) - n + np.arange(n, dtype=np.int32)
+            rows += [i * chunk + j for j in range(n)]
+            tokens += [int(t) for t in emits[i][:n]]
+            pos += at.tolist()
+            tabs += [core.kv.block_table(rid, W)] * n
+            slots += self._slots(rid, at).tolist()
+            owners += [rid] * n
+        pos = np.asarray(pos, np.int32)
+        out = self._run(np.asarray(hidden)[rows], tokens, pos,
+                        np.stack(tabs), pos + 1, slots)
+        for k, rid in enumerate(owners):
+            self._draft[rid] = int(out[k])       # the last row of each wins
+
+    def forget(self, req_id):
+        self._draft.pop(req_id, None)

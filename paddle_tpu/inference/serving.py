@@ -116,6 +116,46 @@ class DecoderConfig:
     def from_dict(cls, d: dict) -> "DecoderConfig":
         return cls(**{k: d[k] for k in cls().to_dict() if k in d})
 
+    # -- the seam: what the engine asks of a model description ------------
+    # (parameter specs, program forms, cache pools).  Another decoder
+    # answers the same questions from a module of its own
+    # (inference/mla_decoder.py); the engine never asks which it serves.
+    param_dtype = "float32"
+
+    def param_specs(self) -> Dict[str, tuple]:
+        return decoder_param_specs(self)
+
+    def init_weights(self, seed: int = 0) -> Dict[str, np.ndarray]:
+        return init_decoder_weights(self, seed)
+
+    def build_program(self, mode: str, sampling=None,
+                      kv_dtype: str = "float32", tp: int = 1) -> tuple:
+        return build_decoder_program(self, mode, sampling=sampling,
+                                     kv_dtype=kv_dtype, tp=tp)
+
+    def validate(self, tp: int = 1, **_served_with) -> None:
+        validate_tp_degree(self, tp)
+
+    def tp_rules(self, kv_dtype: str = "float32") -> Dict[str, tuple]:
+        return decoder_tp_rules(self, kv_dtype=kv_dtype)
+
+    def kv_cache_config(self, num_pages: int, page_size: int,
+                        kv_dtype: str) -> KVCacheConfig:
+        return KVCacheConfig(
+            num_pages=num_pages, page_size=page_size,
+            num_kv_heads=self.num_heads, head_dim=self.head_dim,
+            num_layers=self.num_layers, dtype=kv_dtype)
+
+    def cache_pool_names(self) -> List[str]:
+        """The pool vars of the serving forms, a K and a V a layer."""
+        return [f"kv_{side}_{i}" for i in range(self.num_layers)
+                for side in ("k", "v")]
+
+    def kv_token_bytes(self, kv_dtype: str, tp: int = 1) -> int:
+        """Bytes one token holds in one device's pools, all layers."""
+        return (2 * self.num_layers * (self.num_heads // tp)
+                * self.head_dim * np.dtype(kv_dtype).itemsize)
+
 
 def decoder_param_specs(cfg: DecoderConfig) -> Dict[str, tuple]:
     """name -> shape for every weight var (shared by all three program
@@ -747,6 +787,11 @@ class StepEvent:
 class _SeqState:
     req: Request
     last_token: int = 0
+    # pipelined steps only (``ServingEngine(pipeline=True)``): the row of the
+    # core's token board that holds this sequence's pending token, and how
+    # many tokens have been dispatched for it (``req.out_tokens`` lags a step)
+    lane: int = -1
+    sent: int = 0
 
 
 # The schedulers' per-step instruments, each resolved at its first use
@@ -1012,6 +1057,31 @@ def _fork_copy_fn():
     return _FORK_COPY
 
 
+_BOARD_FNS = None
+
+
+def _board_fns():
+    """Jitted ``(take, put)`` over the token board, a device vector with one
+    row a running sequence: ``take(board, idx)`` is the decode call's
+    ``tokens`` feed (a row index past the board reads 0: padding), and
+    ``put(board, idx, toks)`` writes a call's tokens back (such an index
+    writes nothing).  With them a token goes from the call that made it to
+    the call that consumes it without the host in between."""
+    global _BOARD_FNS
+    if _BOARD_FNS is None:
+        import jax
+
+        def take(board, idx):
+            return board.at[idx].get(mode="fill", fill_value=0)
+
+        def put(board, idx, toks):
+            return board.at[idx].set(
+                toks.reshape(-1).astype(board.dtype), mode="drop")
+
+        _BOARD_FNS = (jax.jit(take), jax.jit(put, donate_argnums=0))
+    return _BOARD_FNS
+
+
 def _reject_unservable(req: Request, cfg: DecoderConfig,
                        kv_config: KVCacheConfig):
     """Shared submit-time gate: a request that cannot complete even
@@ -1067,7 +1137,7 @@ class _EngineCore:
         if tp is None:
             tp = int(flag("serving_tp", 1) or 1)
         self.tp = int(tp)
-        validate_tp_degree(cfg, self.tp)  # bugfix rider: fail loud here
+        cfg.validate(tp=self.tp)  # bugfix rider: fail loud here
         self.tp_mesh = None
         if self.tp > 1:
             import jax as _jax
@@ -1115,25 +1185,38 @@ class _EngineCore:
             # kv_heads, so each device stores num_heads/tp of every
             # page — the same per-device budget buys tp x more pages
             # (the capacity headline; == the legacy expression at tp=1)
-            page_bytes = (2 * cfg.num_layers * (cfg.num_heads // self.tp)
-                          * page_size * cfg.head_dim
-                          * np.dtype(kv_dtype).itemsize)
+            page_bytes = page_size * cfg.kv_token_bytes(kv_dtype, self.tp)
             num_pages = max(1, int(kv_budget_mb * (1 << 20)) // page_bytes)
         self.kv_budget_mb = float(kv_budget_mb or 0.0)
-        self.kv_config = KVCacheConfig(
-            num_pages=num_pages, page_size=page_size,
-            num_kv_heads=cfg.num_heads, head_dim=cfg.head_dim,
-            num_layers=cfg.num_layers, dtype=kv_dtype)
+        self.kv_config = cfg.kv_cache_config(num_pages, page_size, kv_dtype)
         self.kv = PagedKVCache(self.kv_config, prefix_cache=prefix_cache,
                                seed=prefix_seed)
+        # what this model is not served with, refused here and loudly
+        cfg.validate(tp=self.tp, kv_dtype=kv_dtype,
+                     prefix_cache=self.kv.prefix_cache)
+        # what a program offers beyond its tokens, kept on request: the
+        # rows' last hidden state (a drafter that consumes it asks), each
+        # emitted token's logit and log-sum-exp by request (a check of the
+        # served logits asks: ``served_scores``); ``last`` holds the last
+        # call's extras, on the device
+        self.keep_hidden = False
+        self.keep_scores = False
+        self.last: Dict[str, object] = {}
+        self.token_scores: Dict[object, list] = {}   # req -> [(call, row)]
+        self._score_calls: list = []                 # (score, routes) a call
+        self._moe_stats: Dict[str, Dict[str, float]] = {}
+        self._moe_pending: list = []                 # (phase, counts) a call
+        self.moe_calls: Optional[list] = None   # a list: every call's counts
         self._chunk = None   # (prog, feeds, fetch) — built on first use
         # (begin, end) of the last engine/decode span when a request in
         # its batch was traced, else (None, None)
         self.decode_wall = (None, None)
         self._verify = None  # spec-decode verify form — built on first use
+        # the token board (``open_board``): with it a call's tokens stay on
+        # the device and the calls return device arrays that nobody has read
+        self.board = None
 
-        self._tp_rules = decoder_tp_rules(cfg, kv_dtype=kv_dtype) \
-            if self.tp > 1 else {}
+        self._tp_rules = cfg.tp_rules(kv_dtype) if self.tp > 1 else {}
         self.ref_prog, self.ref_feeds, self.ref_fetch = \
             self._build_form("reference")
         self.prefill_prog, self.prefill_feeds, self.prefill_fetch = \
@@ -1179,24 +1262,12 @@ class _EngineCore:
                 return dev
         for name, arr in weights.items():
             self.scope.set(name, jax.device_put(arr, dev_of(name)))
-        for i in range(cfg.num_layers):
+        for name in self._pool_names():
             # the pools are DONATED every prefill/decode step: they must
             # be XLA-owned buffers, never zero-copy host aliases
-            self.scope.set(f"kv_k_{i}",
-                           device_put_owned(self.kv_config.make_pool(),
-                                            dev_of(f"kv_k_{i}")))
-            self.scope.set(f"kv_v_{i}",
-                           device_put_owned(self.kv_config.make_pool(),
-                                            dev_of(f"kv_v_{i}")))
-            if self.kv_config.quantized:
-                self.scope.set(
-                    f"kv_k_scale_{i}",
-                    device_put_owned(self.kv_config.make_scale_pool(),
-                                     dev_of(f"kv_k_scale_{i}")))
-                self.scope.set(
-                    f"kv_v_scale_{i}",
-                    device_put_owned(self.kv_config.make_scale_pool(),
-                                     dev_of(f"kv_v_scale_{i}")))
+            pool = self.kv_config.make_scale_pool() if "_scale_" in name \
+                else self.kv_config.make_pool()
+            self.scope.set(name, device_put_owned(pool, dev_of(name)))
         if self.tp > 1:
             # engage-only telemetry (the flag-off registry is untouched):
             # the TP degree gauge plus each device's share of the pool
@@ -1219,9 +1290,16 @@ class _EngineCore:
 
         with scope_guard(scope):
             pt_io.load_inference_model(model_dir, exe)
-        weights = {n: np.asarray(scope.get(n))
-                   for n in decoder_param_specs(cfg)}
+        weights = {n: np.asarray(scope.get(n)) for n in cfg.param_specs()}
         return cls(cfg, weights, **kw)
+
+    def _pool_names(self) -> List[str]:
+        """Every pool var of the model's cache, the int8 scale pools
+        (``kv_k_3`` -> ``kv_k_scale_3``) after the pools they scale."""
+        names = list(self.cfg.cache_pool_names())
+        if self.kv_config.quantized:
+            names += ["_scale_".join(n.rsplit("_", 1)) for n in names]
+        return names
 
     def _tp_spec(self, name: str):
         """Partition spec for one weight/pool var (None = replicated),
@@ -1243,9 +1321,8 @@ class _EngineCore:
         collectives on the serving ring), annotates every weight/pool
         var with its partition-rule placement, and tags the program
         with the mesh so the executor compiles it under shard_map."""
-        prog, feeds, fetch = build_decoder_program(
-            self.cfg, mode, sampling=sampling, kv_dtype=kv_dtype,
-            tp=self.tp)
+        prog, feeds, fetch = self.cfg.build_program(
+            mode, sampling=sampling, kv_dtype=kv_dtype, tp=self.tp)
         if self.tp > 1:
             from ..framework.ir import get_pass
             from ..parallel.tensor_parallel import apply_tensor_parallel
@@ -1294,6 +1371,32 @@ class _EngineCore:
                                             kv_dtype=self.kv_dtype)
         return self._verify
 
+    def open_board(self, lanes: int):
+        """Keep the tokens on the device from here on (the engine's
+        pipelined steps): ``prefill_job`` and ``decode_batch`` dispatch and
+        return device arrays without reading them, and a running sequence's
+        pending token lives in its lane of ``board`` (``_SeqState.lane``).
+        Every shape of the board's two functions is compiled here, so none
+        compiles while serving."""
+        import jax
+
+        take, put = _board_fns()
+        dev = self.place.jax_device()
+        self._board_pad = int(lanes)     # a row index that names no lane
+        self.board = jax.device_put(np.zeros(lanes, np.int32), dev)
+        b = 1
+        while True:
+            idx = np.full(b, self._board_pad, np.int32)
+            self.board = put(self.board, idx, take(self.board, idx))
+            if b >= lanes:
+                break
+            b *= 2
+
+    def board_put(self, lanes: Sequence[int], toks):
+        """``toks`` (a device array of a call's tokens) into ``lanes``."""
+        self.board = _board_fns()[1](
+            self.board, np.asarray(lanes, np.int32), toks)
+
     def _lane(self, req: Request, offset: int = 0) -> int:
         """RNG lane for the token ``offset`` positions past the
         request's next emission — ``len(prompt) + len(out_tokens)`` is
@@ -1311,14 +1414,9 @@ class _EngineCore:
         if not forks:
             return
         fn = _fork_copy_fn()
-        names = [f"kv_{side}_{i}" for i in range(self.cfg.num_layers)
-                 for side in ("k", "v")]
-        if self.kv_config.quantized:
-            # pages AND their scales copy verbatim — a fork never
-            # requantizes, so shared pages stay bit-stable (pinned)
-            names += [f"kv_{side}_scale_{i}"
-                      for i in range(self.cfg.num_layers)
-                      for side in ("k", "v")]
+        # pages AND their scales copy verbatim — a fork never
+        # requantizes, so shared pages stay bit-stable (pinned)
+        names = self._pool_names()
         for src, dst, _used in forks:
             s = np.int32(src)
             d = np.int32(dst)
@@ -1396,18 +1494,20 @@ class _EngineCore:
             slot_map = np.full(S, self.kv_config.pad_slot, np.int32)
             slot_map[:L] = slots
             feed = {"tokens": toks, "positions": pos,
-                    "attn_mask": _causal_mask(S),
                     "slot_mapping": slot_map,
                     "last_index": np.array([L - 1], np.int32)}
+            if "attn_mask" in self.prefill_feeds:
+                # a form that takes no mask builds it from the positions
+                feed["attn_mask"] = _causal_mask(S)
             if self.sampling is not None:
                 feed["sample_seeds"] = np.array([self._lane(req)], np.int32)
         if span.recording:
             span.set(req=str(req.req_id), prompt_tokens=L, bucket=S)
         with RecordEvent("prefill", cat="serving"):
-            out = self.exe.run(
-                self.prefill_prog, feed=feed,
-                fetch_list=self.prefill_fetch, scope=self.scope)
-        return int(out[0][0])
+            out = self._run(self.prefill_prog, feed, self.prefill_fetch,
+                            "prefill")
+        self._note_scores([req.req_id], fresh=True)
+        return out[0] if self.board is not None else int(out[0][0])
 
     def _run_chunk(self, req: Request, pos: int, chunk, slots, span) -> int:
         """One prompt slice at offset ``pos``: the slice's K/V enter
@@ -1483,8 +1583,12 @@ class _EngineCore:
         next power of two in batch AND block-table width, so the jit
         cache is bounded by (log max_batch x log max_pages) shapes.
         ``decode_wall`` keeps the ``engine/decode`` span's stamps for
-        the traced requests' decode-step spans."""
+        the traced requests' decode-step spans.  With the token board open
+        the pending tokens are read from their lanes and the new ones
+        written back there, and what returns is the call's device array of
+        tokens, unread."""
         B = len(states)
+        lazy = self.board is not None
         traced = any(st.req.trace is not None for st in states)
         with RecordEvent("engine/decode", "serving", timed=traced) as span:
             with RecordEvent("engine/feed_build", "serving"):
@@ -1494,11 +1598,12 @@ class _EngineCore:
                 slot_map = np.full(Bp, self.kv_config.pad_slot, np.int32)
                 ctx = np.ones(Bp, np.int32)
                 for i, st in enumerate(states):
-                    toks[i] = st.last_token
+                    toks[i] = st.lane if lazy else st.last_token
                     pos[i] = min(self.kv.context_len(st.req.req_id),
                                  self.cfg.max_seq_len - 1)
-                    slots = self.kv.append_tokens(st.req.req_id, 1,
-                                                  tokens=[st.last_token])
+                    slots = self.kv.append_tokens(
+                        st.req.req_id, 1,
+                        tokens=None if lazy else [st.last_token])
                     assert slots is not None, "caller must reserve pages"
                     slot_map[i] = slots[0]
                     ctx[i] = self.kv.context_len(st.req.req_id)
@@ -1509,6 +1614,9 @@ class _EngineCore:
                 tables = np.zeros((Bp, W), np.int32)
                 for i, st in enumerate(states):
                     tables[i] = self.kv.block_table(st.req.req_id, W)
+                if lazy:
+                    toks[B:] = self._board_pad
+                    rows, toks = toks, _board_fns()[0](self.board, toks)
                 feed = {"tokens": toks, "positions": pos,
                         "block_tables": tables,
                         "context_lens": ctx, "slot_mapping": slot_map}
@@ -1520,10 +1628,14 @@ class _EngineCore:
             if span.recording:
                 span.set(batch=B, padded_batch=Bp, table_width=W)
             with RecordEvent("decode_batch", cat="serving"):
-                out = self.exe.run(
-                    self.decode_prog, feed=feed,
-                    fetch_list=self.decode_fetch, scope=self.scope)
-            toks_out = [int(out[0][i]) for i in range(B)]
+                out = self._run(self.decode_prog, feed, self.decode_fetch,
+                                "decode")
+            if lazy:
+                self.board_put(rows, out[0])
+                toks_out = out[0]
+            else:
+                toks_out = [int(out[0][i]) for i in range(B)]
+            self._note_scores([st.req.req_id for st in states])
         self.decode_wall = (span.begin, span.end)
         return toks_out
 
@@ -1582,11 +1694,12 @@ class _EngineCore:
                 rows = np.arange(S, dtype=np.int64)[None, :, None]
                 base = np.asarray(pos0 + [-1] * (Bp - B),
                                   dtype=np.int64)[:, None, None]
-                mask = np.where(cols <= base + rows, 0.0, NEG_INF) \
-                    .astype(np.float32)[:, None]
                 feed = {"tokens": toks, "positions": posf,
-                        "attn_mask": mask, "slot_mapping": slot_map,
-                        "verify_tables": tables}
+                        "slot_mapping": slot_map, "verify_tables": tables}
+                if "attn_mask" in _feeds:
+                    feed["attn_mask"] = np.where(
+                        cols <= base + rows, 0.0, NEG_INF) \
+                        .astype(np.float32)[:, None]
                 if self.sampling is not None:
                     lanes = np.zeros(Bp * S, np.int32)
                     for i, (st, draft) in enumerate(items):
@@ -1601,13 +1714,121 @@ class _EngineCore:
                 span.set(batch=B, padded_batch=Bp, table_width=W,
                          chunk=S)
             with RecordEvent("verify_batch", cat="serving"):
-                out = self.exe.run(prog, feed=feed,
-                                   fetch_list=fetch, scope=self.scope)
+                out = self._run(prog, feed, fetch, "decode")
+            self.last["chunk"] = S
             flat = out[0]
             targets = [[int(flat[i * S + j]) for j in range(len(d) + 1)]
                        for i, (_st, d) in enumerate(items)]
         self.decode_wall = (span.begin, span.end)
         return targets
+
+    def _run(self, prog, feed, fetch, phase: str):
+        """One call of a serving form.  A program may offer more than its
+        tokens (``_srv_hidden``, ``_srv_score``, ``_srv_routes``,
+        ``_srv_counts``: the MLA decoder's forms do, GPT-2's offer none and
+        are run exactly as before).  What is offered and wanted rides on
+        the same call and STAYS ON THE DEVICE (``self.last``, and the logs
+        below): a call's one host read is its tokens, as ever."""
+        extras = {}
+        if self.keep_hidden and getattr(prog, "_srv_hidden", None):
+            extras["hidden"] = prog._srv_hidden
+        if self.keep_scores and getattr(prog, "_srv_score", None):
+            extras["score"] = prog._srv_score
+            if getattr(prog, "_srv_routes", None):
+                extras["routes"] = prog._srv_routes
+        if getattr(prog, "_srv_counts", None):
+            extras["counts"] = prog._srv_counts
+        if not extras and self.board is None:
+            self.last = {}
+            return self.exe.run(prog, feed=feed, fetch_list=fetch,
+                                scope=self.scope)
+        out = self.exe.run(prog, feed=feed,
+                           fetch_list=list(fetch) + list(extras.values()),
+                           scope=self.scope, return_numpy=False)
+        self.last = {k: t.value() for k, t in zip(extras, out[len(fetch):])}
+        if "counts" in self.last:
+            self._moe_pending.append((phase, self.last["counts"]))
+        if "score" in self.last:
+            self._score_calls.append((self.last["score"],
+                                      self.last.get("routes")))
+        if self.board is not None:
+            return [t.value() for t in out[:len(fetch)]]
+        # the host waits for the device here: the executor's own fetch span
+        # closed on arrays it did not read
+        with RecordEvent("executor/fetch"):
+            return [np.asarray(t) for t in out[:len(fetch)]]
+
+    def _note_scores(self, req_ids, fresh: bool = False):
+        """Row ``i`` of the call just made emitted a token of
+        ``req_ids[i]``: remember where its score lies.  ``fresh`` opens the
+        request's record anew (a prefill: a resumed request's earlier
+        scores go with its earlier tokens)."""
+        if not (self.keep_scores and "score" in self.last):
+            return
+        call = len(self._score_calls) - 1
+        for i, rid in enumerate(req_ids):
+            if fresh:
+                self.token_scores[rid] = []
+            self.token_scores.setdefault(rid, []).append((call, i))
+
+    def served_scores(self, req_id):
+        """For every token served to ``req_id`` (``keep_scores`` on): its
+        ``(logit, row log-sum-exp)`` as the program that emitted it computed
+        them, ``(tokens, 2)``, and the experts its row was routed to,
+        ``(tokens, expert layers, k)`` (None where the model routes
+        nothing).  Read from the device here, not when served."""
+        at = self.token_scores[req_id]
+        host = {c: (np.asarray(self._score_calls[c][0]),
+                    None if self._score_calls[c][1] is None
+                    else np.asarray(self._score_calls[c][1]))
+                for c in {c for c, _ in at}}
+        scores = np.stack([host[c][0][i] for c, i in at])
+        if host[at[0][0]][1] is None:
+            return scores, None
+        return scores, np.stack([host[c][1][:, i] for c, i in at])
+
+    @property
+    def moe_stats(self) -> Dict[str, Dict[str, float]]:
+        """By phase (``prefill``, ``decode``): expert layers run, experts
+        that received a token and the fullest expert's load over the mean,
+        each summed over expert layers and calls (a reader divides by
+        ``layer_steps``).  Reading it reads the calls' counts off the
+        device: they are logged there, and nothing reads them while
+        serving."""
+        pending, self._moe_pending = self._moe_pending, []
+        calls = [(phase, np.asarray(c, np.float64)) for phase, c in pending]
+        if self.moe_calls is not None:
+            self.moe_calls += calls
+        for phase, sums in self.expert_sums(calls).items():
+            st = self._moe_stats.setdefault(phase, dict.fromkeys(sums, 0.0))
+            for key, value in sums.items():
+                st[key] += value
+                tm.counter("moe_" + key, "expert layers run / experts that "
+                           "received a token / fullest expert's tokens over "
+                           "the mean: summed over expert layers and calls",
+                           labels=("phase",)).labels(phase=phase).inc(value)
+        return self._moe_stats
+
+    @staticmethod
+    def expert_sums(calls) -> Dict[str, Dict[str, float]]:
+        """``calls``: ``(phase, counts)`` with ``counts`` (expert layers,
+        experts) the tokens each expert received in one program call.  By
+        phase: ``layer_steps``, ``experts_touched`` and
+        ``expert_load_max_over_mean`` summed over the expert layers that
+        received any token (a call of padding alone counts nothing)."""
+        out: Dict[str, Dict[str, float]] = {}
+        for phase, counts in calls:
+            counts = counts[counts.sum(axis=1) > 0]
+            if not len(counts):
+                continue
+            st = out.setdefault(phase, {
+                "layer_steps": 0.0, "experts_touched": 0.0,
+                "expert_load_max_over_mean": 0.0})
+            st["layer_steps"] += len(counts)
+            st["experts_touched"] += float((counts > 0).sum())
+            st["expert_load_max_over_mean"] += float(
+                (counts.max(axis=1) / counts.mean(axis=1)).sum())
+        return out
 
     def _reference_run(self, seq: Sequence[int], fetch_list):
         """One full-recompute step of the reference program over
@@ -1618,12 +1839,12 @@ class _EngineCore:
         toks[0, :L] = seq
         pos = np.minimum(np.arange(S, dtype=np.int32),
                          self.cfg.max_seq_len - 1)[None]
-        return self.exe.run(
-            self.ref_prog,
-            feed={"tokens": toks, "positions": pos,
-                  "attn_mask": _causal_mask(S),
-                  "last_index": np.array([L - 1], np.int32)},
-            fetch_list=fetch_list, scope=self.scope)
+        feed = {"tokens": toks, "positions": pos,
+                "last_index": np.array([L - 1], np.int32)}
+        if "attn_mask" in self.ref_feeds:
+            feed["attn_mask"] = _causal_mask(S)
+        return self.exe.run(self.ref_prog, feed=feed,
+                            fetch_list=fetch_list, scope=self.scope)
 
     def reference_next_token(self, seq: Sequence[int]) -> int:
         return int(self._reference_run(seq, self.ref_fetch)[0][0])
@@ -1665,7 +1886,7 @@ class _EngineCore:
         per_pool = int(np.prod(self.kv_config.pool_shape())) * \
             np.dtype(self.kv_config.dtype).itemsize
         per_pool += self.kv_config.scale_bytes()
-        return 2 * self.cfg.num_layers * per_pool // self.tp
+        return len(self.cfg.cache_pool_names()) * per_pool // self.tp
 
     def memory_stats(self) -> dict:
         """The serving-side memory section (tools/mem_report.py):
@@ -1674,11 +1895,9 @@ class _EngineCore:
         from ..utils.memory import measured_peak
 
         ps = self.kv.stats()
-        token_bytes = (2 * self.cfg.num_layers * self.cfg.num_heads
-                       * self.cfg.head_dim
-                       * np.dtype(self.kv_config.dtype).itemsize)
+        token_bytes = self.cfg.kv_token_bytes(self.kv_config.dtype)
         weights = 0
-        for n in decoder_param_specs(self.cfg):
+        for n in self.cfg.param_specs():
             v = self.scope.get(n)
             if v is not None and hasattr(v, "nbytes"):
                 nb = int(v.nbytes)  # global bytes (sharded or not)
@@ -1728,7 +1947,8 @@ class ServingEngine:
                  prefill_chunk: Optional[int] = None,
                  spec_k: Optional[int] = None,
                  proposer=None,
-                 sampling: Optional[SamplingParams] = None, **core_kw):
+                 sampling: Optional[SamplingParams] = None,
+                 pipeline: int = 0, **core_kw):
         from ..utils.flags import flag
 
         if sampling is None:
@@ -1747,7 +1967,7 @@ class ServingEngine:
             if cfg is None:
                 raise ValueError("need cfg or model_dir")
             self.core = _EngineCore(
-                cfg, weights or init_decoder_weights(cfg, seed), **core_kw)
+                cfg, weights or cfg.init_weights(seed), **core_kw)
         self.cfg = self.core.cfg
         self.kv = self.core.kv
         self.kv_dtype = self.core.kv_dtype
@@ -1760,11 +1980,17 @@ class ServingEngine:
         if spec_k is None:
             spec_k = int(flag("spec_decode_k", 0) or 0)
         self.spec_k = max(int(spec_k), 0)
+        self.cfg.validate(prefill_chunk=self.prefill_chunk)
         if isinstance(proposer, str):
             proposer = get_proposer(proposer)
         self.proposer: Optional[Proposer] = \
             proposer if proposer is not None else \
             (NGramProposer() if self.spec_k else None)
+        if hasattr(self.proposer, "bind"):
+            # a drafter that runs a program of its own on the engine's
+            # scope and pools (the MTP module): it is told of each prefill
+            # and each verify call, whose hidden states it consumes
+            self.proposer.bind(self.core)
         # verify-call budget debt: tokens a verify emitted BEYOND the
         # one-per-sequence this step's budget already charged; settled
         # against the NEXT step's budget, so a verify call charges
@@ -1781,6 +2007,39 @@ class ServingEngine:
                       "spec_proposed": 0, "spec_accepted": 0}
         self._step_no = 0
         self._submit_seq = 0
+        # pipelined steps: step N is dispatched before the tokens of step
+        # N - pipeline are read (``True``: 1), so the device never waits for
+        # the host's bookkeeping, nor, at 2, for a host that stalls a step long
+        self.pipeline = max(int(pipeline), 0)
+        # per step in flight: its calls, (device tokens, [(state, last?)])
+        self._in_flight: List[list] = []
+        if self.pipeline:
+            self._open_pipeline()
+
+    def _open_pipeline(self):
+        """``pipeline=True`` holds only where a step's schedule does not
+        depend on the tokens it makes: refuse the rest here, loudly."""
+        from ..utils.flags import flag
+
+        why = [what for what, on in (
+            ("sampled decoding (a lane counts emitted tokens)",
+             self.sampling is not None),
+            ("speculative decoding", bool(self.spec_k)),
+            ("chunked prefill", bool(self.prefill_chunk)),
+            ("the prefix cache (it indexes token values)",
+             self.kv.prefix_cache),
+            ("an EOS token (a finish the host must see)",
+             self.cfg.eos_id >= 0),
+            ("tensor parallelism", self.core.tp > 1),
+            (f"admission policy {self.policy.name!r} (it reads token "
+             f"times)", self.policy.name != "fifo"),
+            ("request tracing", bool(flag("trace_requests", 0))),
+        ) if on]
+        if why:
+            raise ValueError("ServingEngine(pipeline=True) cannot be served "
+                             "with: " + "; ".join(why))
+        self._free_lanes = list(range(self.max_batch - 1, -1, -1))
+        self.core.open_board(self.max_batch)
 
     # -- API ---------------------------------------------------------------
     def submit(self, req: Request):
@@ -1806,7 +2065,7 @@ class ServingEngine:
         self.waiting.append(req)
 
     def has_work(self) -> bool:
-        return bool(self.waiting or self.running
+        return bool(self.waiting or self.running or self._in_flight
                     or self._prefill_job is not None)
 
     def step(self, now: float = 0.0) -> List[StepEvent]:
@@ -1827,6 +2086,8 @@ class ServingEngine:
             if span.recording:
                 span.set(step=self._step_no, running=len(self.running),
                          waiting=len(self.waiting))
+            if self.pipeline:
+                return self._step_pipelined(now)
             return self._step(now)
 
     def _step(self, now: float) -> List[StepEvent]:
@@ -1920,6 +2181,7 @@ class ServingEngine:
                     st = _SeqState(req, tok)
                     req.out_tokens.append(tok)
                     _observe_token(req, now)
+                    self._tell_drafter_of_prefill(req, tok)
                     if self.core._finished(req, tok):
                         events.append(self._finish(st, tok, now))
                     else:
@@ -1983,6 +2245,8 @@ class ServingEngine:
                 victim.req._tm_last = None
                 victim.req._tm_gaps = []
                 victim.req.preemptions += 1
+                if hasattr(self.proposer, "forget"):
+                    self.proposer.forget(victim.req.req_id)
                 _trace_preempt(victim.req, now)
                 self.waiting.insert(0, victim.req)
                 self.stats["preempted"] += 1
@@ -2018,6 +2282,121 @@ class ServingEngine:
                 self.running = still
         self.stats["max_prefill_step_tokens"] = max(
             self.stats["max_prefill_step_tokens"], prefilled_this_step)
+        return events
+
+    def _step_pipelined(self, now: float) -> List[StepEvent]:
+        """``_step`` with the host off the device's path: the same
+        admissions, prefills, preemptions and decode in the same order and
+        through the same calls, but no call's tokens are read before the
+        calls of the next ``pipeline`` steps are dispatched.  A token goes from the call that
+        made it to the call that consumes it on the device (the core's
+        token board); everything the schedule needs is known without it
+        (``_open_pipeline`` refused what is not): a sequence ends when its
+        count is full, and its pages are freed when its last call is
+        dispatched, which the device runs before any call that takes them.
+        So the schedule and every token are ``_step``'s, and a client has a
+        step's tokens ``pipeline`` steps later: the events returned are
+        those of that earlier step (and of all in flight, once nothing is
+        left to dispatch)."""
+        events: List[StepEvent] = []
+        handles = _TM.current()
+        calls = []
+        with RecordEvent("engine/schedule", "serving"):
+            chaos.on_serving_step(self, self._step_no)
+            budget = self.token_budget - len(self.running)
+        prefilled_this_step = 0
+        while self.waiting and len(self.running) < self.max_batch:
+            req = self.waiting[0]
+            cost = len(req.prompt) + 1
+            with RecordEvent("engine/schedule", "serving"):
+                if cost > budget or not self._admission_fits(req):
+                    break
+            job = self.core.prefill_job(req)
+            if job is None:
+                break  # pool backpressure: retry next step
+            with RecordEvent("engine/emit", "serving"):
+                self.waiting.pop(0)
+                budget -= cost
+                prefilled_this_step += len(req.prompt)
+                req.admitted_at = now if req.admitted_at is None else \
+                    req.admitted_at
+                self.stats["admitted"] += 1
+                self.stats["prefill_tokens"] += len(req.prompt)
+                handles.admitted.inc()
+                handles.prefill_tokens.inc(len(req.prompt))
+                st = _SeqState(req, sent=1)
+                last = st.sent >= req.max_new_tokens
+                if last:
+                    self.kv.free_sequence(req.req_id)
+                else:
+                    st.lane = self._free_lanes.pop()
+                    self.core.board_put([st.lane], job.first_token)
+                    self.running.append(st)
+                calls.append((job.first_token, [(st, last)]))
+        with RecordEvent("engine/schedule", "serving"):
+            if self.running and not self._can_grow_all():
+                # a victim's tokens are with the client before it goes back
+                # to the queue, as in ``_step``: read all that is in flight
+                events.extend(self._deliver(self._in_flight + [calls], now))
+                self._in_flight, calls = [], []
+            while self.running and not self._can_grow_all():
+                victim = self.running.pop(
+                    self.policy.victim_index(self.running))
+                self.kv.free_sequence(victim.req.req_id)
+                self._free_lanes.append(victim.lane)
+                victim.req.out_tokens = []
+                victim.req._tm_last = None
+                victim.req._tm_gaps = []
+                victim.req.preemptions += 1
+                self.waiting.insert(0, victim.req)
+                self.stats["preempted"] += 1
+                handles.preempted.inc()
+        if self.running:
+            chaos.on_decode_step()
+            toks = self.core.decode_batch(self.running)
+            with RecordEvent("engine/emit", "serving"):
+                self.stats["decode_steps"] += 1
+                self.stats["decode_tokens"] += len(self.running)
+                handles.decode_steps.inc()
+                handles.decode_tokens.inc(len(self.running))
+                rows, still = [], []
+                for st in self.running:
+                    st.sent += 1
+                    last = st.sent >= st.req.max_new_tokens
+                    rows.append((st, last))
+                    if last:
+                        self.kv.free_sequence(st.req.req_id)
+                        self._free_lanes.append(st.lane)
+                    else:
+                        still.append(st)
+                self.running = still
+                calls.append((toks, rows))
+        self.stats["max_prefill_step_tokens"] = max(
+            self.stats["max_prefill_step_tokens"], prefilled_this_step)
+        # the device has this step's calls: now wait for an earlier step's
+        self._in_flight.append(calls)
+        keep = self.pipeline if self.waiting or self.running else 0
+        due = max(len(self._in_flight) - keep, 0)
+        events.extend(self._deliver(self._in_flight[:due], now))
+        del self._in_flight[:due]
+        return events
+
+    def _deliver(self, steps, now: float) -> List[StepEvent]:
+        """Read the tokens of the calls of dispatched ``steps`` and do for
+        each what ``_step`` does when it has a token: the request's record,
+        the latency observation, the event, the finish."""
+        events = []
+        for toks, rows in (call for calls in steps for call in calls):
+            with RecordEvent("executor/fetch"):
+                toks = np.asarray(toks).reshape(-1)
+            with RecordEvent("engine/emit", "serving"):
+                for (st, last), tok in zip(rows, toks.tolist()):
+                    st.req.out_tokens.append(tok)
+                    st.last_token = tok
+                    _observe_token(st.req, now)
+                    events.append(
+                        self._finish(st, tok, now, free=False) if last
+                        else StepEvent(st.req.req_id, tok, False, now))
         return events
 
     def _spec_decode_step(self, now: float) -> List[StepEvent]:
@@ -2073,10 +2452,17 @@ class ServingEngine:
                         break
                     d.pop()
                 drafts.append(d)
-        targets = self.core.verify_batch(list(zip(batch, drafts)))
+        items = list(zip(batch, drafts))
+        targets = self.core.verify_batch(items)
+        hidden, chunk = self.core.last.get("hidden"), \
+            self.core.last.get("chunk")
         with RecordEvent("engine/emit", "serving"):
-            self._emit_verified(batch, drafts, targets, now, events,
-                                handles)
+            accepts, emits = self._emit_verified(
+                batch, drafts, targets, now, events, handles)
+        told = getattr(self.proposer, "after_verify", None)
+        if told is not None:
+            with RecordEvent("engine/draft", "serving"):
+                told(items, hidden, chunk, accepts, emits)
         return events
 
     def _emit_verified(self, batch, drafts, targets, now, events, handles):
@@ -2134,6 +2520,16 @@ class ServingEngine:
         if self.stats["spec_proposed"]:
             handles.spec_accept_rate.set(
                 self.stats["spec_accepted"] / self.stats["spec_proposed"])
+        # the same counts by who drafted: an n-gram lookup and the model's
+        # own MTP module accept at rates that say different things
+        drafter = getattr(self.proposer, "name",
+                          type(self.proposer).__name__)
+        for name, value in (("spec_drafted_by_drafter_total", n_prop),
+                            ("spec_accepted_by_drafter_total", n_acc)):
+            tm.counter(name, "speculative tokens, by drafter",
+                       labels=("drafter",)).labels(
+                           drafter=drafter).inc(value)
+        return accepts, emits
 
     def _count_prefill(self, n: int, job: _PrefillJob):
         """Feature-path prefill accounting: ``prefill_tokens`` counts
@@ -2161,11 +2557,19 @@ class ServingEngine:
         st = _SeqState(req, tok)
         req.out_tokens.append(tok)
         _observe_token(req, now)
+        self._tell_drafter_of_prefill(req, tok)
         if self.core._finished(req, tok):
             events.append(self._finish(st, tok, now))
         else:
             events.append(StepEvent(req.req_id, tok, False, now))
             self.running.append(st)
+
+    def _tell_drafter_of_prefill(self, req: Request, tok: int):
+        """A drafter that consumes hidden states is handed the prompt's
+        (the prefill just run left them in ``core.last``)."""
+        told = getattr(self.proposer, "after_prefill", None)
+        if told is not None and self.spec_k:
+            told(req, self.core.last["hidden"], tok)
 
     def _can_grow_all(self) -> bool:
         need = sum(self.kv.pages_needed(st.req.req_id, 1)
@@ -2226,8 +2630,12 @@ class ServingEngine:
                           args={"req": str(req.req_id),
                                 "waited": round(now - req.arrival_time, 6)})
 
-    def _finish(self, st: _SeqState, tok: int, now: float) -> StepEvent:
-        self.kv.free_sequence(st.req.req_id)
+    def _finish(self, st: _SeqState, tok: int, now: float,
+                free: bool = True) -> StepEvent:
+        if free:    # a pipelined step freed the pages at dispatch
+            self.kv.free_sequence(st.req.req_id)
+        if hasattr(self.proposer, "forget"):
+            self.proposer.forget(st.req.req_id)
         st.req.finished_at = now
         self.stats["finished"] += 1
         _TM.current().finished.inc()

@@ -39,3 +39,4 @@ from . import long_tail_ops  # noqa: F401
 from . import parity_ops  # noqa: F401
 from . import paged_ops  # noqa: F401
 from . import sampling_ops  # noqa: F401
+from . import mla_ops  # noqa: F401

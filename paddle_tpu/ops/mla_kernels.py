@@ -1,0 +1,551 @@
+"""Pallas TPU kernels of the latent-attention / sparse-expert decoder
+(inference/mla_decoder.py), each beside the jnp composition that is its
+CPU fallback and its test oracle.
+
+* ``mla_decode`` — absorbed latent attention over the paged latent pool:
+  every head of a sequence scores the same latent row (``c_kv`` | ``k_r``),
+  so a grid step holds all heads of one sequence and walks SEVERAL pages:
+  the pages are fetched by hand (``make_async_copy`` through the block
+  table) into one of two VMEM buffers, the next step's pages in flight
+  while this step's are scored, and both products are MXU matmuls
+  (``(heads, 576) x (576, tokens)`` and ``(heads, tokens) x (tokens,
+  512)``).  ``paged_decode``'s one page a grid step would be 190 steps a
+  sequence and layer at a 3 k context.
+* ``mla_prefill`` — expanded causal attention of one whole prompt, a head
+  and a block of query rows a grid step, the key blocks above the
+  diagonal neither fetched nor scored; the rotary part of the score is a
+  second product against the one ``k_r`` all heads share, so no key of
+  192 lanes a head is ever put together.
+* ``latent_append`` — a token's latent row into its page, the pool
+  aliased onto the output as ``kv_append`` does: only the pages written
+  move.
+* ``moe_gmm`` — the grouped expert matmul: rows sorted by expert, one
+  visit per (row tile, expert) pair that share rows, an expert's weight
+  tile fetched once however many row tiles it spans and never where it
+  received no row.  ``gated`` computes ``silu(x @ wg) * (x @ wu)`` in one
+  pass over ``x``.
+
+The latent pool is stored ``(1, num_pages, page_size, width)``, a row
+``[c_kv | k_r | zeros]`` with ``width`` the 576 values of the published
+widths rounded up to whole 128-lane tiles, 640.  Asked in the sandbox
+(PR 32), the chip's compiler holds a ``[pages, 16, 576]`` bfloat16 array
+in ``T(8,128)(2,1)`` tiles with the lanes padded to 640 all the same, a
+copy out of it cannot take 576 lanes (Mosaic: a slice must be aligned to
+the tiling), and XLA re-laid the 576-wide pool around the append (a
+pool-sized temporary); two pools (512 and 64) pad the 64 to a whole
+tile, the same 640 lanes.  So the padding is stated in the shape: one
+pool, one copy a page, exact tiles, and no program re-lays it.  Nothing
+but these kernels touches the pool on the chip.
+
+Engage rules follow ``paged_attention``: kernel on TPU or under
+``PT_PALLAS_INTERPRET=1``, the jnp composition elsewhere.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_kernels import DEFAULT_MASK_VALUE, LANES, _interpret, _use_pallas
+
+#: pages one ``mla_decode`` grid step scores (256 tokens at 16 a page): a
+#: grid step costs about a third of a microsecond whether or not its chunk
+#: is live, and a 512-page table is 32 steps a sequence at this size
+DECODE_PAGES_PER_STEP = 16
+
+
+# ==========================================================================
+# mla_decode
+# ==========================================================================
+def mla_decode_reference(q_lat, q_rope, pool, block_tables, context_lens,
+                         scale):
+    """Gather oracle and CPU fallback.  ``q_lat`` (n, heads, r), ``q_rope``
+    (n, heads, dr), ``pool`` (1, pages, page_size, r + dr), ``block_tables``
+    (n, w), ``context_lens`` (n,) true lengths, the current token's row
+    already in the pool.  Returns ``o_lat`` (n, heads, r) float32: the
+    softmax-weighted sum of the latent rows, before ``W_kvb^V``."""
+    n, _, r = q_lat.shape
+    rows = jnp.take(pool[0], block_tables.reshape(-1), axis=0)
+    rows = rows.reshape(n, -1, pool.shape[-1]).astype(jnp.float32)
+    c, kr = rows[..., :r], rows[..., r:r + q_rope.shape[-1]]
+    s = jnp.einsum("nhr,ntr->nht", q_lat.astype(jnp.float32), c) \
+        + jnp.einsum("nhd,ntd->nht", q_rope.astype(jnp.float32), kr)
+    s = s * scale
+    pos = lax.broadcasted_iota(jnp.int32, (n, 1, s.shape[-1]), 2)
+    s = jnp.where(pos < context_lens[:, None, None], s, DEFAULT_MASK_VALUE)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("nht,ntr->nhr", p, c)
+
+
+def _mla_decode_kernel(bt_ref, cl_ref, ql_ref, qr_ref, pool_ref, o_ref,
+                       buf, sem, m_scr, l_scr, acc_scr, *, scale, pages,
+                       page_size, n_chunks, rank, rope):
+    """Grid step ``(b, i)``: chunk ``i`` (``pages`` pages) of sequence
+    ``b``.  Chunk ``i`` lies in buffer ``i % 2``; it was started by step
+    ``i - 1`` (by this step where ``i == 0``), and this step starts chunk
+    ``i + 1`` before it waits, so the fetch runs under the matmuls.  A
+    chunk wholly past the context is neither fetched nor scored."""
+    b, i = pl.program_id(0), pl.program_id(1)
+    ctx = cl_ref[b]
+    tokens = pages * page_size
+
+    def copies(chunk, slot):
+        return [pltpu.make_async_copy(
+            pool_ref.at[0, bt_ref[b, chunk * pages + j]],
+            buf.at[slot, pl.ds(j * page_size, page_size)],
+            sem.at[slot, j]) for j in range(pages)]
+
+    def live(chunk):
+        return chunk * tokens < ctx
+
+    @pl.when(i == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+        @pl.when(live(0))
+        def _first():
+            for c in copies(0, 0):
+                c.start()
+
+    @pl.when(jnp.logical_and(i + 1 < n_chunks, live(i + 1)))
+    def _next():
+        for c in copies(i + 1, (i + 1) % 2):
+            c.start()
+
+    @pl.when(live(i))
+    def _score():
+        slot = i % 2
+        for c in copies(i, slot):
+            c.wait()
+        rows = buf[slot]                                 # (tokens, r + dr)
+        c_kv, k_r = rows[:, :rank], rows[:, rank:rank + rope]
+        q_lat, q_rope = ql_ref[0], qr_ref[0]             # (heads, r | dr)
+        s = lax.dot_general(q_lat, c_kv, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s += lax.dot_general(q_rope, k_r, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        s = s * scale
+        pos = i * tokens + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < ctx, s, DEFAULT_MASK_VALUE)
+        m_prev, l_prev = m_scr[...], l_scr[...]          # lane-broadcast
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next[:, :1])
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        # a row of the chunk past the context may hold anything (a page
+        # never written): its weight is exactly 0, but 0 * NaN is NaN
+        c_safe = jnp.where(
+            i * tokens + lax.broadcasted_iota(jnp.int32, c_kv.shape, 0)
+            < ctx, c_kv.astype(jnp.float32), 0.0).astype(c_kv.dtype)
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + lax.dot_general(
+            p.astype(c_kv.dtype), c_safe, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_next
+
+    @pl.when(i == n_chunks - 1)
+    def _done():
+        l_fin = l_scr[...]
+        l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
+        o_ref[0] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
+
+
+def _mla_decode_call(q_lat, q_rope, pool, block_tables, context_lens, scale):
+    n, heads, rank = q_lat.shape
+    rope = q_rope.shape[-1]
+    _, _, page_size, width = pool.shape
+    w = block_tables.shape[1]
+    pages = min(DECODE_PAGES_PER_STEP, w)
+    n_chunks = -(-w // pages)
+    if n_chunks * pages != w:
+        block_tables = jnp.pad(block_tables,
+                               ((0, 0), (0, n_chunks * pages - w)))
+    tokens = pages * page_size
+
+    def _q_idx(b, i, bt, cl):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n, n_chunks),
+        in_specs=[
+            pl.BlockSpec((1, heads, rank), _q_idx),
+            pl.BlockSpec((1, heads, rope), _q_idx),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, heads, rank), _q_idx),
+        scratch_shapes=[
+            pltpu.VMEM((2, tokens, width), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, pages)),
+            pltpu.VMEM((heads, LANES), jnp.float32),
+            pltpu.VMEM((heads, LANES), jnp.float32),
+            pltpu.VMEM((heads, rank), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_decode_kernel, scale=scale, pages=pages,
+                          page_size=page_size, n_chunks=n_chunks, rank=rank,
+                          rope=rope),
+        name="mla_decode",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, heads, rank), jnp.float32),
+        interpret=_interpret(),
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+      q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), pool)
+
+
+def mla_decode(q_lat, q_rope, pool, block_tables, context_lens, scale):
+    """Absorbed latent attention of one query row a sequence over the paged
+    latent pool (shapes as :func:`mla_decode_reference`).  The kernel wants
+    a page of whole sublane groups and heads a multiple of 8."""
+    _, heads, _ = q_lat.shape
+    page_size = pool.shape[2]
+    if _use_pallas() and page_size % 8 == 0 and heads % 8 == 0:
+        return _mla_decode_call(q_lat, q_rope, pool, block_tables,
+                                context_lens, scale=float(scale))
+    return mla_decode_reference(q_lat, q_rope, pool, block_tables,
+                                context_lens, scale)
+
+
+# ==========================================================================
+# mla_prefill
+# ==========================================================================
+#: query rows (and key rows) a grid step of ``mla_prefill`` scores
+PREFILL_BLOCK = 512
+
+
+def mla_prefill_reference(q_nope, q_rope, k_nope, k_r, v, scale):
+    """Oracle and fallback: causal attention of one sequence, heads first.
+    ``q_nope``/``k_nope`` (heads, s, dn), ``q_rope`` (heads, s, dr), ``k_r``
+    (s, dr) shared by the heads, ``v`` (heads, s, dv).  Query blocks of
+    ``PREFILL_BLOCK`` rows each see the keys up to their own end (static
+    slices), so the scores of a long prompt are never one array.  Returns
+    (heads, s, dv) float32."""
+    s = q_nope.shape[1]
+    bq = min(PREFILL_BLOCK, s)
+    out = []
+    for lo in range(0, s, bq):
+        hi = min(lo + bq, s)
+        sc = jnp.einsum("hqd,hkd->hqk", q_nope[:, lo:hi], k_nope[:, :hi],
+                        preferred_element_type=jnp.float32)
+        sc += jnp.einsum("hqd,kd->hqk", q_rope[:, lo:hi], k_r[:hi],
+                         preferred_element_type=jnp.float32)
+        rows = lo + lax.broadcasted_iota(jnp.int32, (hi - lo, hi), 0)
+        cols = lax.broadcasted_iota(jnp.int32, (hi - lo, hi), 1)
+        sc = jnp.where(cols <= rows, sc * scale, -jnp.inf)
+        p = jax.nn.softmax(sc, axis=-1)
+        out.append(jnp.einsum("hqk,hkd->hqd", p.astype(v.dtype), v[:, :hi],
+                              preferred_element_type=jnp.float32))
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+
+
+def _mla_prefill_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                        m_scr, l_scr, acc_scr, *, scale, block, n_blocks):
+    """Grid step ``(h, qi, ki)``: query block ``qi`` of head ``h`` against
+    key block ``ki``, online softmax down ``ki``.  Blocks above the
+    diagonal do nothing (their index maps stay on the diagonal block, so
+    nothing is fetched for them either)."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(ki <= qi)
+    def _score():
+        v = v_ref[0]
+        s = lax.dot_general(qn_ref[0], kn_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        s += lax.dot_general(qr_ref[0], kr_ref[...], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+        rows = qi * block + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        cols = ki * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(rows >= cols, s * scale, DEFAULT_MASK_VALUE)
+        m_prev, l_prev = m_scr[...], l_scr[...]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(s - m_next[:, :1])
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha[:, :1] + lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[...] = m_next
+
+    @pl.when(ki == n_blocks - 1)
+    def _done():
+        o_ref[0] = (acc_scr[...] / l_scr[...][:, :1]).astype(o_ref.dtype)
+
+
+def _mla_prefill_call(q_nope, q_rope, k_nope, k_r, v, scale):
+    heads, s, dn = q_nope.shape
+    dr, dv = q_rope.shape[-1], v.shape[-1]
+    block = min(PREFILL_BLOCK, s)
+    n = s // block
+
+    def _q_idx(h, qi, ki):
+        return (h, qi, 0)
+
+    def _k_idx(h, qi, ki):
+        return (h, jnp.minimum(ki, qi), 0)
+
+    return pl.pallas_call(
+        functools.partial(_mla_prefill_kernel, scale=scale, block=block,
+                          n_blocks=n),
+        name="mla_prefill",
+        grid=(heads, n, n),
+        in_specs=[
+            pl.BlockSpec((1, block, dn), _q_idx),
+            pl.BlockSpec((1, block, dr), _q_idx),
+            pl.BlockSpec((1, block, dn), _k_idx),
+            pl.BlockSpec((block, dr),
+                         lambda h, qi, ki: (jnp.minimum(ki, qi), 0)),
+            pl.BlockSpec((1, block, dv), _k_idx),
+        ],
+        out_specs=pl.BlockSpec((1, block, dv), _q_idx),
+        out_shape=jax.ShapeDtypeStruct((heads, s, dv), jnp.float32),
+        scratch_shapes=[
+            pltpu.VMEM((block, LANES), jnp.float32),
+            pltpu.VMEM((block, LANES), jnp.float32),
+            pltpu.VMEM((block, dv), jnp.float32),
+        ],
+        interpret=_interpret(),
+    )(q_nope, q_rope, k_nope, k_r, v)
+
+
+def mla_prefill(q_nope, q_rope, k_nope, k_r, v, scale):
+    """Expanded causal attention of one prompt (shapes as
+    :func:`mla_prefill_reference`).  The kernel wants the prompt's bucket in
+    whole blocks: a multiple of ``PREFILL_BLOCK``, or one block of whole
+    lane tiles."""
+    s = q_nope.shape[1]
+    if _use_pallas() and (s % PREFILL_BLOCK == 0
+                          or (s < PREFILL_BLOCK and s % LANES == 0)):
+        return _mla_prefill_call(q_nope, q_rope, k_nope, k_r, v,
+                                 float(scale))
+    return mla_prefill_reference(q_nope, q_rope, k_nope, k_r, v, scale)
+
+
+# ==========================================================================
+# latent_append
+# ==========================================================================
+def _pad_rows(pool, rows):
+    """``rows`` (tokens, values) widened with zeros to the pool's row."""
+    pad = pool.shape[-1] - rows.shape[-1]
+    rows = rows.astype(pool.dtype)
+    return jnp.pad(rows, ((0, 0), (0, pad))) if pad else rows
+
+
+def latent_append_reference(pool, rows, slots):
+    """Scatter oracle and CPU fallback: ``rows`` (tokens, width) to flat
+    ``slots`` of ``pool`` (1, pages, page_size, width); a slot outside the
+    pool (the allocator's pad sentinel) drops its row."""
+    page_size = pool.shape[2]
+    rows = _pad_rows(pool, rows)
+    page, off = slots // page_size, slots % page_size
+    return pool.at[0, page, off, :].set(rows.astype(pool.dtype), mode="drop")
+
+
+def _latent_append_kernel(page_ref, off_ref, order_ref, row_ref, blk_in,
+                          blk_out):
+    """Grid step ``i`` writes sorted token ``i`` into its page's block:
+    the block is copied in when the walk enters the page and written back
+    when it leaves (Pallas skips both while the block index repeats)."""
+    del order_ref
+    i = pl.program_id(0)
+    off = off_ref[i]
+
+    @pl.when(jnp.logical_or(
+        i == 0, page_ref[i] != page_ref[jnp.maximum(i - 1, 0)]))
+    def _open():
+        blk_out[...] = blk_in[...]
+
+    @pl.when(off >= 0)
+    def _write():
+        cur = blk_out[0, 0]                              # (page_size, width)
+        at = lax.broadcasted_iota(jnp.int32, cur.shape, 0) == off
+        # 32 bits wide: the vector unit has no 16-bit compare/select
+        blk_out[0, 0] = jnp.where(
+            at, row_ref[0].astype(jnp.float32),
+            cur.astype(jnp.float32)).astype(cur.dtype)
+
+
+def _latent_append_call(pool, rows, slots):
+    _, n_pages, page_size, width = pool.shape
+    valid = (slots >= 0) & (slots < n_pages * page_size)
+    page, off = lax.div(slots, page_size), lax.rem(slots, page_size)
+    # pads sort last and ride on the last page a real token wrote
+    order = jnp.argsort(jnp.where(valid, page, jnp.iinfo(jnp.int32).max))
+    order = order.astype(jnp.int32)
+    page = jnp.where(valid, page, jnp.max(jnp.where(valid, page, 0)))[order]
+    off = jnp.where(valid, off, -1)[order]
+    block = pl.BlockSpec((1, 1, page_size, width),
+                         lambda i, page, off, order: (0, page[i], 0, 0))
+    return pl.pallas_call(
+        _latent_append_kernel,
+        name="latent_append",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(slots.shape[0],),
+            in_specs=[pl.BlockSpec((1, 1, width),
+                                   lambda i, page, off, order:
+                                   (order[i], 0, 0)),
+                      block],
+            out_specs=block),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},     # after the three prefetch operands
+        interpret=_interpret(),
+    )(page, off, order, _pad_rows(pool, rows)[:, None, :], pool)
+
+
+def latent_append(pool, rows, slots):
+    """Write ``rows[t]`` to flat slot ``slots[t]`` of the latent pool and
+    return the new pool (the operand, aliased, on the chip)."""
+    slots = slots.astype(jnp.int32)
+    if _use_pallas() and pool.shape[2] % 8 == 0:
+        return _latent_append_call(pool, rows, slots)
+    return latent_append_reference(pool, rows, slots)
+
+
+# ==========================================================================
+# moe_gmm
+# ==========================================================================
+def moe_gmm_reference(x, weights, group_sizes, gated: bool,
+                      out_dtype=jnp.float32):
+    """Oracle and CPU fallback: ``x`` (rows, k) sorted by expert,
+    ``weights`` one ``(experts, k, n)`` array (two where ``gated``),
+    ``group_sizes`` (experts,) rows each expert owns, in order.  Row ``i``
+    of the result is ``x[i] @ w[expert of i]``; rows past the groups are
+    zero.  One expert at a time would be a loop of 256; this is a masked
+    sum over experts in blocks, which the tests' sizes afford."""
+    rows = x.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    owner = jnp.searchsorted(ends, jnp.arange(rows), side="right")
+    live = (jnp.arange(rows) < ends[-1])[:, None]
+    owner = jnp.minimum(owner, group_sizes.shape[0] - 1)
+
+    def one(w):
+        return jnp.einsum("mk,mkn->mn", x.astype(jnp.float32),
+                          jnp.take(w, owner, axis=0).astype(jnp.float32))
+
+    if gated:
+        g, u = one(weights[0]), one(weights[1])
+        y = jax.nn.silu(g) * u
+    else:
+        y = one(weights[0])
+    return jnp.where(live, y, 0.0).astype(out_dtype)
+
+
+def _moe_gmm_kernel(group_ref, tile_ref, ends_ref, n_ref, x_ref, *refs,
+                    gated, tm):
+    """Grid step ``(n, v)``: visit ``v`` pairs row tile ``tile[v]`` with
+    expert ``group[v]``; the rows of the tile that the expert owns take
+    ``x @ w[expert]``, the rest keep what an earlier visit of the tile
+    wrote (zero on the tile's first visit)."""
+    o_ref = refs[-1]
+    v = pl.program_id(1)
+    g, t = group_ref[v], tile_ref[v]
+
+    @pl.when(jnp.logical_or(
+        v == 0, t != tile_ref[jnp.maximum(v - 1, 0)]))
+    def _open():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(v < n_ref[0])
+    def _visit():
+        x = x_ref[...]
+        y = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
+        if gated:
+            u = jnp.dot(x, refs[1][0], preferred_element_type=jnp.float32)
+            y = y * jax.nn.sigmoid(y) * u
+        row = t * tm + lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        lo = jnp.where(g == 0, 0, ends_ref[jnp.maximum(g - 1, 0)])
+        mine = (row >= lo) & (row < ends_ref[g])
+        o_ref[...] = jnp.where(mine, y, o_ref[...].astype(jnp.float32)) \
+            .astype(o_ref.dtype)
+
+
+def _pick_tn(n: int) -> int:
+    for tn in (512, 384, 256, 128):
+        if n % tn == 0:
+            return tn
+    return n
+
+
+def _moe_gmm_call(x, weights, group_sizes, gated, out_dtype):
+    rows, k = x.shape
+    experts, _, n = weights[0].shape
+    # a tile of 128 rows where the groups are that large (prefill); small
+    # tiles where a step has a handful of rows an expert (decode), which
+    # is bound by the weights it streams and not by the MXU's fill
+    tm = 128 if rows >= 16 * experts else min(32, -(-rows // 16) * 16)
+    padded = -(-rows // tm) * tm
+    if padded != rows:
+        x = jnp.pad(x, ((0, padded - rows), (0, 0)))
+    tiles, tn = padded // tm, _pick_tn(n)
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tm
+    n_tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    visits = tiles + experts - 1                # the most there can be
+    group = jnp.repeat(jnp.arange(experts, dtype=jnp.int32), n_tiles,
+                       total_repeat_length=visits)
+    v_start = jnp.cumsum(n_tiles) - n_tiles
+    tile = first[group] + jnp.arange(visits, dtype=jnp.int32) - v_start[group]
+    n_visits = jnp.sum(n_tiles)
+    # visits past the last real one stay on its blocks and compute nothing
+    last = jnp.maximum(n_visits - 1, 0)
+    live = jnp.arange(visits) < n_visits
+    group = jnp.clip(jnp.where(live, group, group[last]), 0, experts - 1)
+    tile = jnp.clip(jnp.where(live, tile, tile[last]), 0, tiles - 1)
+
+    def _w_idx(j, v, group, tile, ends, nv):
+        return (group[v], 0, j)
+
+    def _x_idx(j, v, group, tile, ends, nv):
+        return (tile[v], 0)
+
+    def _o_idx(j, v, group, tile, ends, nv):
+        return (tile[v], j)
+
+    w_spec = pl.BlockSpec((1, k, tn), _w_idx)
+    out = pl.pallas_call(
+        functools.partial(_moe_gmm_kernel, gated=gated, tm=tm),
+        name="moe_gmm",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n // tn, visits),
+            in_specs=[pl.BlockSpec((tm, k), _x_idx)]
+            + [w_spec] * len(weights),
+            out_specs=pl.BlockSpec((tm, tn), _o_idx)),
+        out_shape=jax.ShapeDtypeStruct((padded, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+    )(group, tile, ends, n_visits[None].astype(jnp.int32), x, *weights)
+    # a tile no expert owns a row of was never visited: its rows are
+    # whatever the buffer held
+    keep = (jnp.arange(padded) < ends[-1])[:, None]
+    return jnp.where(keep, out, jnp.zeros((), out.dtype))[:rows]
+
+
+def moe_gmm(x, weights, group_sizes, gated: bool = False,
+            out_dtype=jnp.float32):
+    """Grouped matmul over rows sorted by expert (shapes as
+    :func:`moe_gmm_reference`): operands in the weights' type, accumulated
+    in float32, the result in ``out_dtype``.  The kernel wants the
+    contraction and the output width in whole lanes."""
+    k, n = weights[0].shape[1:]
+    x = x.astype(weights[0].dtype)
+    if _use_pallas() and k % LANES == 0 and n % LANES == 0:
+        return _moe_gmm_call(x, tuple(weights), group_sizes, gated=gated,
+                             out_dtype=jnp.dtype(out_dtype))
+    return moe_gmm_reference(x, weights, group_sizes, gated, out_dtype)

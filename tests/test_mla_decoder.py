@@ -1,0 +1,447 @@
+"""The latent-attention / sparse-expert decoder (inference/mla_decoder.py)
+against its plain reference (benchmark/reference/joyai-llm-flash.py), at a
+small size on the CPU: logits (not tokens) of prefill then decode through the
+paged latent cache, the two attention forms against each other, the rotary
+embedding, the router's properties, the MTP module and its drafter, and the
+kernels' bodies in the interpreter.
+"""
+import dataclasses
+import importlib.util
+import os
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.inference.mla_decoder import (MLADecoderConfig, MTPDrafter,
+                                              init_mla_weights)
+from paddle_tpu.inference.serving import Request, ServingEngine
+from paddle_tpu.ops import mla_kernels, mla_ops
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_reference():
+    path = os.path.join(ROOT, "benchmark", "reference", "joyai-llm-flash.py")
+    spec = importlib.util.spec_from_file_location("ref_joyai", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _load_reference()
+
+
+def ref_cfg(cfg: MLADecoderConfig) -> dict:
+    """The configuration under the source's key names, as the reference
+    reads it."""
+    return cfg.source_config()
+
+
+# hidden 64, 4 heads, q_lora 32, kv_lora 16, nope 16, rope 8, v 16, 8 experts
+# top-2, 1 dense + 2 expert layers, vocabulary 128: the dataclass's defaults
+TINY = MLADecoderConfig()
+PROMPT_LENS = (5, 8, 9, 17, 30)       # page_size 8: under, at, over, 2+, 3+
+
+
+def make_engine(cfg, dtype="float32", seed=0, **kw):
+    cfg = dataclasses.replace(cfg, weights_dtype=dtype)
+    weights = init_mla_weights(cfg, seed)
+    kw.setdefault("num_pages", 64)
+    eng = ServingEngine(cfg=cfg, weights=weights, kv_dtype=dtype, page_size=8,
+                        max_batch=4, token_budget=128, **kw)
+    eng.core.keep_scores = True
+    return eng, cfg, weights
+
+
+def prompts_of(seed, lens=PROMPT_LENS, vocab=128):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, vocab, size=n).tolist() for n in lens]
+
+
+def served_against_reference(eng, cfg, weights, reqs):
+    """For every served token of ``reqs``: |engine - reference| of its logit
+    and of its row's log-sum-exp, the reference routed as the engine was;
+    and the worst routing slack."""
+    worst, slack = 0.0, 0.0
+    for r in reqs:
+        got, routes = eng.core.served_scores(r.req_id)
+        assert len(got) == len(r.out_tokens)
+        ref = REF.served_token_scores(weights, ref_cfg(cfg), r.prompt,
+                                      r.out_tokens, routes)
+        assert ref["finite"]
+        worst = max(worst, float(np.abs(got[:, 0] - ref["logit"]).max()),
+                    float(np.abs(got[:, 1] - ref["lse"]).max()))
+        slack = max(slack, float(ref["slack"].max()))
+    return worst, slack
+
+
+# float32: the two differ by summation order alone.  bfloat16: weights and
+# latent rows hold 8 bits of mantissa (a rounding of 2^-9 relative a matmul
+# operand), so logits of unit scale move by about 1e-2; 8e-2 leaves room for
+# the worst row of a test's few hundred and is a fifth of what a wrong page,
+# a missing term or one mis-routed expert moves them by (0.3 and more)
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-4), ("bfloat16", 8e-2)])
+def test_prefill_then_decode_logits_match_reference(dtype, tol):
+    eng, cfg, weights = make_engine(TINY, dtype)
+    reqs = [Request(i, p, 12) for i, p in enumerate(prompts_of(1))]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_to_completion()
+    assert eng.stats["preempted"] == 0
+    worst, slack = served_against_reference(eng, cfg, weights, reqs)
+    assert worst <= tol, worst
+    # the engine's choice of experts is the reference's, to within what
+    # the precision moves a score
+    assert slack <= (1e-5 if dtype == "float32" else 2e-2), slack
+
+
+def test_preempted_and_resumed_requests_match_reference():
+    # 12 pages of 8: four prompts of 17-20 tokens fit, their decodes do not
+    eng, cfg, weights = make_engine(TINY, num_pages=12)
+    reqs = [Request(i, p, 14) for i, p in
+            enumerate(prompts_of(2, lens=(17, 18, 19, 20)))]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_to_completion()
+    assert eng.stats["preempted"] > 0
+    assert all(len(r.out_tokens) == 14 for r in reqs)
+    worst, _ = served_against_reference(eng, cfg, weights, reqs)
+    assert worst <= 2e-4, worst
+
+
+@pytest.mark.parametrize("n", [1, 7, 16, 33])
+def test_reference_form_logits_match_reference(n):
+    """The full-sequence program form (the export form) against the
+    reference's full forward pass, every logit of the last row."""
+    eng, cfg, weights = _shared_engine()
+    seq = prompts_of(3, lens=(n,))[0]
+    got = eng.core.reference_logits(seq)
+    want = np.asarray(REF.logits_all_positions(weights, seq, ref_cfg(cfg)))[-1]
+    np.testing.assert_allclose(got, want, atol=2e-4)
+
+
+_ENGINE = {}
+
+
+def _shared_engine():
+    if "e" not in _ENGINE:
+        _ENGINE["e"] = make_engine(TINY)
+    return _ENGINE["e"]
+
+
+def test_generate_is_the_reference_forms_greedy_decode():
+    eng, _cfg, _w = _shared_engine()
+    prompts = prompts_of(4, lens=(6, 21))
+    outs = eng.generate(prompts, 8)
+    assert outs == [eng.core.greedy_reference(p, 8) for p in prompts]
+
+
+# -- the two attention forms ---------------------------------------------
+@pytest.mark.parametrize("ctx", [1, 8, 13, 40])
+def test_absorbed_attention_is_expanded_attention(ctx):
+    rng = np.random.RandomState(ctx)
+    heads, dn, dr, dv, rank, ps = 4, 16, 8, 16, 16, 8
+    q_nope = jnp.asarray(rng.randn(ctx, heads, dn), jnp.float32)
+    q_rope = jnp.asarray(rng.randn(ctx, heads, dr), jnp.float32)
+    c_kv = jnp.asarray(rng.randn(ctx, rank), jnp.float32)
+    k_r = jnp.asarray(rng.randn(ctx, dr), jnp.float32)
+    w_kvb = jnp.asarray(rng.randn(rank, heads * (dn + dv)) / 4, jnp.float32)
+    scale = (dn + dr) ** -0.5
+    want = mla_ops.mla_expanded_attention(q_nope, q_rope, c_kv, k_r, w_kvb,
+                                          dv, scale)[-1]
+    # the same rows through a paged pool, pages in a shuffled order
+    n_pages = -(-ctx // ps)
+    table = rng.permutation(n_pages + 3)[:n_pages].astype(np.int32)
+    slots = jnp.asarray(table[np.arange(ctx) // ps] * ps
+                        + np.arange(ctx) % ps, jnp.int32)
+    pool = jnp.zeros((1, n_pages + 3, ps, rank + dr), jnp.float32)
+    pool = mla_kernels.latent_append(
+        pool, jnp.concatenate([c_kv, k_r], axis=-1), slots)
+    got = mla_ops.mla_absorbed_attention(
+        q_nope[-1:], q_rope[-1:], pool, jnp.asarray(table)[None],
+        jnp.asarray([ctx], jnp.int32), w_kvb, dv, scale)[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# -- rotary embedding --------------------------------------------------------
+def test_rope_rotates_interleaved_pairs_at_an_offset():
+    rng = np.random.RandomState(0)
+    x = rng.randn(5, 2, 8).astype(np.float32)
+    pos = np.arange(5) + 1000
+    theta = 32e6
+    got = np.asarray(mla_ops.rope_interleaved(jnp.asarray(x),
+                                              jnp.asarray(pos), theta))
+    want = np.empty_like(x)
+    for i in range(4):
+        ang = pos * theta ** (-2.0 * i / 8)
+        a, b = x[..., 2 * i], x[..., 2 * i + 1]
+        c, s = np.cos(ang)[:, None], np.sin(ang)[:, None]
+        want[..., 2 * i], want[..., 2 * i + 1] = a * c - b * s, a * s + b * c
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_rope_scores_depend_on_the_distance_alone():
+    rng = np.random.RandomState(1)
+    q = jnp.asarray(rng.randn(1, 1, 8), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 1, 8), jnp.float32)
+
+    def score(pq, pk):
+        return float(jnp.sum(
+            mla_ops.rope_interleaved(q, jnp.asarray([pq]), 1e4)
+            * mla_ops.rope_interleaved(k, jnp.asarray([pk]), 1e4)))
+
+    assert abs(score(7, 3) - score(107, 103)) < 1e-4
+    assert abs(score(7, 3) - score(7, 4)) > 1e-3
+
+
+# -- the router ------------------------------------------------------------
+def _route(x, gate, bias, k=2, scaling=2.5, norm=True):
+    idx, w = mla_ops.route(jnp.asarray(x), jnp.asarray(gate),
+                           jnp.asarray(bias), k, scaling, norm)
+    return np.asarray(idx), np.asarray(w)
+
+
+def test_router_bias_moves_the_choice_and_not_the_weight():
+    rng = np.random.RandomState(0)
+    x = rng.randn(6, 16).astype(np.float32)
+    gate = (rng.randn(16, 8) / 4).astype(np.float32)
+    idx0, _ = _route(x, gate, np.zeros(8, np.float32), norm=False,
+                     scaling=1.0)
+    bias = np.zeros(8, np.float32)
+    bias[5] = 10.0                            # expert 5 now wins everywhere
+    idx, w = _route(x, gate, bias, norm=False, scaling=1.0)
+    assert (idx == 5).any(axis=1).all() and not (idx0 == 5).any(axis=1).all()
+    scores = 1 / (1 + np.exp(-(x @ gate)))
+    np.testing.assert_allclose(w, np.take_along_axis(scores, idx, 1),
+                               atol=1e-6)     # the score, without the bias
+
+
+def test_router_normalises_then_scales():
+    rng = np.random.RandomState(1)
+    x = rng.randn(5, 16).astype(np.float32)
+    gate = (rng.randn(16, 8) / 4).astype(np.float32)
+    _, w = _route(x, gate, np.zeros(8, np.float32), scaling=2.5)
+    np.testing.assert_allclose(w.sum(axis=1), 2.5, atol=1e-5)
+
+
+def _experts(n, experts=8, h=16, f=8, seed=0):
+    rng = np.random.RandomState(seed)
+    return (rng.randn(n, h).astype(np.float32),
+            (rng.randn(experts, h, f) / 4).astype(np.float32),
+            (rng.randn(experts, h, f) / 4).astype(np.float32),
+            (rng.randn(experts, f, h) / 3).astype(np.float32))
+
+
+def _dense_experts(x, idx, w, wg, wu, wd):
+    out = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for e, we in zip(idx[t], w[t]):
+            g, u = x[t] @ wg[e], x[t] @ wu[e]
+            out[t] += we * ((g / (1 + np.exp(-g)) * u) @ wd[e])
+    return out
+
+
+def test_every_token_to_the_same_experts_drops_none():
+    x, wg, wu, wd = _experts(40)
+    idx = np.tile(np.array([[1, 6]], np.int32), (40, 1))
+    w = np.full((40, 2), 1.25, np.float32)
+    y, counts = mla_ops.experts_forward(*map(jnp.asarray,
+                                             (x, idx, w, wg, wu, wd)))
+    assert np.asarray(counts).tolist() == [0, 40, 0, 0, 0, 0, 40, 0]
+    np.testing.assert_allclose(np.asarray(y),
+                               _dense_experts(x, idx, w, wg, wu, wd),
+                               atol=1e-4)
+
+
+def test_an_expert_with_no_token_gives_no_nan_and_padding_routes_nowhere():
+    x, wg, wu, wd = _experts(6, seed=3)
+    wg[4] = np.nan                            # expert 4 receives nothing
+    idx = np.array([[0, 1], [2, 3], [5, 6], [7, 0], [4, 4], [1, 2]], np.int32)
+    w = np.full((6, 2), 0.5, np.float32)
+    valid = np.array([1, 1, 1, 1, 0, 1], bool)   # the row sent to 4 is padding
+    y, counts = mla_ops.experts_forward(
+        *map(jnp.asarray, (x, idx, w, wg, wu, wd)), jnp.asarray(valid))
+    y = np.asarray(y)
+    assert np.isfinite(y).all() and np.asarray(counts)[4] == 0
+    assert int(np.asarray(counts).sum()) == 10
+    np.testing.assert_array_equal(y[4], 0.0)
+    keep = [0, 1, 2, 3, 5]
+    np.testing.assert_allclose(
+        y[keep], _dense_experts(x[keep], idx[keep], w[keep],
+                                np.nan_to_num(wg), wu, wd), atol=1e-4)
+
+
+def test_engine_counts_experts_by_phase():
+    eng, _cfg, _w = make_engine(TINY)
+    eng.generate(prompts_of(5, lens=(9, 12)), 4)
+    st = eng.core.moe_stats
+    assert st["prefill"]["layer_steps"] == 2 * 2      # 2 prompts x 2 layers
+    assert st["decode"]["layer_steps"] == 3 * 2       # 3 decode steps
+    # a step of two rows, top-2: at most four experts a layer
+    assert 0 < st["decode"]["experts_touched"] <= 4 * 3 * 2
+    assert st["decode"]["expert_load_max_over_mean"] >= \
+        st["decode"]["layer_steps"]
+
+
+# -- multi-token prediction --------------------------------------------------
+MTP = MLADecoderConfig(mtp_layers=1)
+
+
+def test_mtp_logits_match_reference():
+    eng, cfg, weights = make_engine(MTP, spec_k=1, proposer=MTPDrafter())
+    drafter = eng.proposer
+    prompt = prompts_of(6, lens=(19,))[0]
+    req = Request(0, prompt, 4)
+    eng.submit(req)
+    eng.step()                                # prefill, then one verify
+    first = req.out_tokens[0]
+    # the drafter's rows over the prompt, again, keeping their logits
+    job_hidden = np.asarray(REF.hidden_states(
+        weights, jnp.asarray(prompt, jnp.int32), ref_cfg(cfg))[0])
+    want = np.asarray(REF.mtp_logits_all_positions(
+        weights, prompt + [first], ref_cfg(cfg)))
+    eng2, _, _ = make_engine(MTP, spec_k=1, proposer=MTPDrafter())
+    r2 = Request(0, prompt, 4)
+    eng2.submit(r2)
+    eng2.core.kv.append_tokens(0, len(prompt), tokens=prompt)
+    eng2.proposer.after_prefill(r2, job_hidden, first, keep_logits=True)
+    np.testing.assert_allclose(eng2.proposer.last_logits, want, atol=3e-4)
+    assert drafter.propose(req, 1) != [] or len(req.out_tokens) >= 4
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_mtp_drafter_leaves_greedy_tokens_unchanged(seed):
+    prompts = prompts_of(10 + seed, lens=(5, 16, 23))
+    plain, _, _ = make_engine(MTP, seed=seed)
+    spec, _, _ = make_engine(MTP, seed=seed, spec_k=1, proposer=MTPDrafter())
+    want = plain.generate(prompts, 10)
+    got = spec.generate(prompts, 10)
+    assert got == want
+    assert spec.stats["spec_proposed"] > 0
+
+
+def test_mtp_weights_load_only_with_the_module():
+    from paddle_tpu.inference.mla_decoder import mla_param_specs
+
+    assert not any(n.startswith("mtp_") for n in mla_param_specs(TINY))
+    assert "mtp_proj" in mla_param_specs(MTP)
+    assert len(MTP.cache_pool_names()) == len(TINY.cache_pool_names()) + 1
+
+
+# -- what the engine refuses for this model ----------------------------------
+@pytest.mark.parametrize("kw,match", [
+    ({"prefix_cache": True}, "chunk"),
+    ({"prefill_chunk": 16}, "chunk"),
+    ({"kv_dtype": "int8"}, "int8"),
+    ({"tp": 2}, "tensor-parallel"),
+])
+def test_engine_refuses_what_the_model_has_no_form_for(kw, match):
+    cfg = TINY
+    with pytest.raises(ValueError, match=match):
+        ServingEngine(cfg=cfg, weights=init_mla_weights(cfg, 0),
+                      **{"kv_dtype": "float32", "num_pages": 16,
+                         "page_size": 8, **kw})
+
+
+def test_pool_is_one_latent_row_a_token_and_layer():
+    eng, cfg, _ = _shared_engine()
+    pool = eng.core.scope.get("kv_lat_0")
+    assert pool.shape == (1, 64, 8, cfg.kv_lora_rank + cfg.qk_rope_head_dim)
+    wide = MLADecoderConfig(kv_lora_rank=512, qk_rope_head_dim=64)
+    assert wide.latent_width == 576 and wide.latent_row == 640
+    assert wide.kv_cache_config(4, 16, "bfloat16").pool_shape() == \
+        (1, 4, 16, 640)
+
+
+# -- the kernels' bodies, in the interpreter ---------------------------------
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+
+
+@pytest.mark.parametrize("lens,width", [((5, 70, 96), 12), ((1,), 1),
+                                        ((64, 33), 8)])
+def test_mla_decode_kernel(interpreted, lens, width):
+    rng = np.random.RandomState(len(lens))
+    n, heads, rank, rope, ps, pages = len(lens), 8, 16, 8, 8, 40
+    pool = jnp.asarray(rng.randn(1, pages, ps, rank + rope), jnp.float32)
+    q_lat = jnp.asarray(rng.randn(n, heads, rank), jnp.float32)
+    q_rope = jnp.asarray(rng.randn(n, heads, rope), jnp.float32)
+    tables = jnp.asarray(rng.randint(0, pages, (n, width)), jnp.int32)
+    ctx = jnp.asarray(lens, jnp.int32)
+    got = mla_kernels.mla_decode(q_lat, q_rope, pool, tables, ctx, 0.2)
+    want = mla_kernels.mla_decode_reference(q_lat, q_rope, pool, tables, ctx,
+                                            0.2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_mla_decode_kernel_ignores_what_lies_past_the_context(interpreted):
+    rng = np.random.RandomState(0)
+    pool = np.asarray(rng.randn(1, 6, 8, 24), np.float32)
+    pool[0, 3:] = np.nan                      # pages never written
+    tables = jnp.asarray([[0, 1, 2, 3, 4, 5, 3, 4, 5, 3, 4, 5]], jnp.int32)
+    q_lat = jnp.asarray(rng.randn(1, 8, 16), jnp.float32)
+    q_rope = jnp.asarray(rng.randn(1, 8, 8), jnp.float32)
+    out = mla_kernels.mla_decode(q_lat, q_rope, jnp.asarray(pool), tables,
+                                 jnp.asarray([20], jnp.int32), 0.2)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("s", [128, 1024, 1536])
+def test_mla_prefill_kernel(interpreted, s):
+    """One block, whole blocks, and blocks the diagonal cuts."""
+    rng = np.random.RandomState(s)
+    heads, dn, dr, dv = 4, 16, 8, 16
+    q_nope, k_nope = (jnp.asarray(rng.randn(heads, s, dn), jnp.float32)
+                      for _ in range(2))
+    q_rope = jnp.asarray(rng.randn(heads, s, dr), jnp.float32)
+    k_r = jnp.asarray(rng.randn(s, dr), jnp.float32)
+    v = jnp.asarray(rng.randn(heads, s, dv), jnp.float32)
+    got = mla_kernels.mla_prefill(q_nope, q_rope, k_nope, k_r, v, 0.2)
+    want = mla_kernels.mla_prefill_reference(q_nope, q_rope, k_nope, k_r, v,
+                                             0.2)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_latent_append_kernel(interpreted):
+    rng = np.random.RandomState(0)
+    pool = jnp.asarray(rng.randn(1, 10, 8, 24), jnp.float32)
+    rows = jnp.asarray(rng.randn(7, 24), jnp.float32)
+    slots = jnp.asarray([3, 8, 9, 80, 17, 2, 79], jnp.int32)   # 80: the pad
+    got = mla_kernels.latent_append(pool, rows, slots)
+    want = mla_kernels.latent_append_reference(pool, rows, slots)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("sizes", [(0, 5, 40, 0, 3, 0, 17, 1),
+                                   (0, 0, 0, 0, 0, 0, 0, 0),
+                                   (66, 0, 0, 0, 0, 0, 0, 0),
+                                   (300, 1, 0, 200, 0, 0, 11, 0)])
+@pytest.mark.parametrize("gated", [True, False])
+def test_moe_gmm_kernel(interpreted, sizes, gated):
+    rng = np.random.RandomState(sum(sizes))
+    rows, k, n = sum(sizes) + 14, 128, 256        # 14 rows no expert owns
+    x = jnp.asarray(rng.randn(rows, k), jnp.float32)
+    ws = tuple(jnp.asarray(rng.randn(8, k, n) / 12, jnp.float32)
+               for _ in range(2 if gated else 1))
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    got = mla_kernels.moe_gmm(x, ws, group_sizes, gated)
+    want = mla_kernels.moe_gmm_reference(x, ws, group_sizes, gated)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    assert (np.asarray(got)[sum(sizes):] == 0).all()
+
+
+def test_engine_through_the_kernels_matches_reference(interpreted):
+    """Widths the kernels engage at (heads 8, lanes of 128), bfloat16 as
+    served: prefill, decode and the append run their kernel bodies."""
+    cfg = MLADecoderConfig(hidden=128, num_heads=8, moe_intermediate=128,
+                           intermediate=256, num_layers=2)
+    eng, cfg, weights = make_engine(cfg, "bfloat16")
+    reqs = [Request(i, p, 5) for i, p in
+            enumerate(prompts_of(7, lens=(9, 20)))]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_to_completion()
+    worst, _ = served_against_reference(eng, cfg, weights, reqs)
+    assert worst <= 8e-2, worst

@@ -979,6 +979,21 @@ COVERED_ELSEWHERE = {
     # sweep harness)
     "kv_cache_append": "test_serving",
     "paged_attention": "test_serving",
+    # the latent-attention / sparse-expert decoder's ops —
+    # tests/test_mla_decoder.py (each against the plain reference of
+    # benchmark/reference/joyai-llm-flash.py or a dense oracle; pool state
+    # and routing don't fit the one-op sweep harness)
+    "matmul_f32acc": "test_mla_decoder",
+    "rms_norm": "test_mla_decoder",
+    "rope_interleaved": "test_mla_decoder",
+    "swiglu": "test_mla_decoder",
+    "moe_router": "test_mla_decoder",
+    "moe_experts": "test_mla_decoder",
+    "mla_prefill_attention": "test_mla_decoder",
+    "mla_paged_attention": "test_mla_decoder",
+    "latent_cache_append": "test_mla_decoder",
+    "slot_is_live": "test_mla_decoder",
+    "token_score": "test_mla_decoder",
     # in-program sampling head — tests/test_spec_decode.py (RNG-lane
     # determinism + filter-support oracles; the categorical draw has no
     # closed-form reference for the one-op sweep harness)
