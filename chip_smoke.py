@@ -77,10 +77,15 @@ SIZES = {
                      n_shared_experts=1, num_experts_per_tok=8,
                      q_lora_rank=1536, kv_lora_rank=512,
                      qk_nope_head_dim=128, qk_rope_head_dim=64,
-                     v_head_dim=128, rope_theta=32e6, max_seq_len=1024,
+                     v_head_dim=128, rope_theta=32e6, max_seq_len=2048,
                      weights_dtype="bfloat16", mtp_layers=1),
-            num_pages=512, page_size=16, token_budget=1024, max_batch=4,
-            prompts=[300, 20, 280, 31], new_tokens=8),
+            # the long prompt is served by the engines without a drafter
+            # (the drafter's program has a table row a prompt position,
+            # and 2,048 of 128 pages do not fit the chip's scalar memory):
+            # its table is two of mla_decode's chunks wide, the others'
+            # contexts and three rows of padding fill one each
+            num_pages=512, page_size=16, token_budget=2048, max_batch=8,
+            prompts=[300, 20, 280, 31], long_prompt=1100, new_tokens=8),
     },
     "tiny": {
         "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
@@ -107,13 +112,19 @@ SIZES = {
                      qk_rope_head_dim=8, v_head_dim=16, max_seq_len=128,
                      weights_dtype="bfloat16", mtp_layers=1),
             num_pages=64, page_size=16, token_budget=128, max_batch=4,
-            prompts=[40, 5, 36, 9], new_tokens=6),
+            prompts=[40, 5, 36, 9], long_prompt=70, new_tokens=6),
     },
 }
 
 # The MLA decoder's served logits against its float32 reference, bfloat16
 # weights and cache: the limits of benchmark/configs/joyai-llm-flash.json
 MLA_LOGIT_ABS_TOL, MLA_ROUTE_SLACK_TOL = 0.06, 0.008
+
+# mla_decode's grid at the "full" sizes: contexts of 300, 20, 280, 31 and
+# 1,100 tokens and three rows of padding are 1 + 1 + 1 + 1 + 2 + 3 chunks
+# that hold context of the 16 the eight tables span: the grid is 0.5625 of
+# the tables, and never over this
+MLA_GRID_OVER_TABLES_MOST = 0.6
 
 # A served token may differ from the reference's argmax only on a near-tie:
 # its reference logit must be within this much of the reference maximum
@@ -318,6 +329,23 @@ class Ctx:
                 f"{phase}: kernel(s) {missing} are not in any program the "
                 f"phase compiled — the jnp path took their place")
         return found
+
+    def require_one_call_a_layer(self, phase, kernel, layers):
+        """Every compiled program that holds ``kernel`` runs it once a
+        layer and behind no branch, ``layers`` naming the depths the
+        phase's programs have.  Returns the most calls found in a program
+        (0 where kernels are interpreted: XLA loops, no call)."""
+        most = 0
+        for name, text in self.watch.compiled_texts().items():
+            calls = sum(line.lstrip().startswith(f"%{kernel}")
+                        and " custom-call(" in line
+                        for line in text.splitlines())
+            if calls and (calls not in layers or " conditional(" in text):
+                raise RuntimeError(
+                    f"{phase}: {name} holds {calls} call(s) of {kernel}, "
+                    f"not one a layer ({layers}), or holds a conditional")
+            most = max(most, calls)
+        return most
 
     def require_pool_in_place(self, phase, kv_config, n_pools,
                               append="kv_append"):
@@ -719,6 +747,8 @@ def phase_mla(ctx):
     rng = np.random.RandomState(0)
     prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
                for n in size["prompts"]]
+    long_prompt = rng.randint(0, cfg.vocab_size,
+                              size=size["long_prompt"]).tolist()
 
     def engine(**kw):
         eng = ServingEngine(
@@ -729,7 +759,7 @@ def phase_mla(ctx):
         eng.core.keep_scores = True
         return eng
 
-    def drive(eng):
+    def drive(eng, prompts=prompts):
         reqs = [Request(i, p, size["new_tokens"])
                 for i, p in enumerate(prompts)]
         for r in reqs:
@@ -743,13 +773,15 @@ def phase_mla(ctx):
     try:
         mark = ctx.watch.mark()
         plain = engine()
-        reqs = drive(plain)
+        reqs = drive(plain, prompts + [long_prompt])
         seen, modules = ctx.watch.since(mark)
         kernels = ctx.require_kernels(
             phase, modules, ["mla_decode", "latent_append", "moe_gmm"])
         in_place = ctx.require_pool_in_place(
             phase, plain.core.kv_config,
             n_pools=len(cfg.cache_pool_names()), append="latent_append")
+        decode_calls = ctx.require_one_call_a_layer(
+            phase, "mla_decode", (cfg.num_layers, cfg.mtp_layers))
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         cc.reset_cache()
@@ -766,12 +798,24 @@ def phase_mla(ctx):
             f"{phase}: served logits lie {worst} from the reference (limit "
             f"{MLA_LOGIT_ABS_TOL}), routing slack {slack} (limit "
             f"{MLA_ROUTE_SLACK_TOL})")
+    # what mla_decode walked: its grid is the chunks that hold context,
+    # here fewer than the chunks the tables span
+    walk = plain.stats["kernels"].get("decode")
+    if walk is None:
+        raise RuntimeError(f"{phase}: the decode form counted no mla_decode "
+                           f"call: {plain.stats['kernels']}")
+    grid_share = (walk["mla_decode_grid_steps"]
+                  / walk["mla_decode_table_chunks"])
+    if ctx.sizes is SIZES["full"] and grid_share > MLA_GRID_OVER_TABLES_MOST:
+        raise RuntimeError(
+            f"{phase}: mla_decode's grid ran {grid_share:.3f} of the chunks "
+            f"its tables span, over {MLA_GRID_OVER_TABLES_MOST}: {walk}")
     del plain
     gc.collect()
     # pipelined steps (tokens stay on the device between calls): the same
     # tokens by the same schedule
     piped = engine(pipeline=True)
-    piped_reqs = drive(piped)
+    piped_reqs = drive(piped, [r.prompt for r in reqs])
     if [r.out_tokens for r in piped_reqs] != [r.out_tokens for r in reqs]:
         raise RuntimeError(f"{phase}: pipelined steps changed the tokens "
                            f"served: {[r.out_tokens for r in piped_reqs]} "
@@ -782,7 +826,8 @@ def phase_mla(ctx):
     drafter = MTPDrafter()
     spec_eng = engine(spec_k=1, proposer=drafter)
     spec_reqs = drive(spec_eng)
-    if [r.out_tokens for r in spec_reqs] != [r.out_tokens for r in reqs]:
+    if [r.out_tokens for r in spec_reqs] \
+            != [r.out_tokens for r in reqs[:len(prompts)]]:
         raise RuntimeError(f"{phase}: the MTP drafter changed the tokens "
                            f"served: {[r.out_tokens for r in spec_reqs]} vs "
                            f"{[r.out_tokens for r in reqs]}")
@@ -799,8 +844,10 @@ def phase_mla(ctx):
         raise RuntimeError(f"{phase}: the MTP module's logits lie {mtp_gap} "
                            f"from the reference's")
     say(phase=phase, **size["cfg"], num_pages=size["num_pages"],
-        prompts=size["prompts"], new_tokens=size["new_tokens"],
-        scheduler=spec_eng.stats, **seen, kernel_calls=kernels, **in_place,
+        prompts=size["prompts"], long_prompt=len(long_prompt),
+        new_tokens=size["new_tokens"], scheduler=spec_eng.stats, **seen, kernel_calls=kernels, **in_place,
+        mla_decode_calls_a_program=decode_calls, mla_decode_walk=walk,
+        mla_decode_grid_over_tables=grid_share,
         served_logits_worst_gap=worst, route_slack=slack,
         mtp_logits_worst_gap=mtp_gap,
         drafter="tokens identical with the drafter on and off",

@@ -31,6 +31,7 @@ an engine drafter on the speculative path.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, List
 
@@ -38,6 +39,7 @@ import numpy as np
 
 from ..framework.core import Program
 from ..framework.dtype import VarType, convert_dtype
+from ..ops import mla_kernels
 from .kv_cache import KVCacheConfig
 from .spec_decode import Proposer
 
@@ -382,6 +384,29 @@ class _MB:
         return o
 
 
+def _decode_walk(feed, kv_config, *, verify: bool, heads: int, layers: int):
+    """``prog._srv_kernel_stats`` of the decode and verify forms: what the
+    call's ``mla_decode`` kernels walk, from the contexts the call is fed
+    and the sizes the kernel's wrapper uses (``mla_kernels.
+    decode_chunks``), summed over the layers: the grid's steps (the chunks
+    that hold context) and the chunks the tables span (what every row
+    walking its whole table would take).  None where the kernel does not
+    engage."""
+    if not mla_kernels.decode_engages(kv_config.page_size, heads):
+        return None
+    if verify:      # a verify row's context ends at its own position
+        ctx = np.asarray(feed["positions"]).reshape(-1) + 1
+        width = feed["verify_tables"].shape[1]
+    else:
+        ctx = np.asarray(feed["context_lens"])
+        width = feed["block_tables"].shape[1]
+    steps, spanned = mla_kernels.decode_walk_counts(ctx, width,
+                                                    kv_config.page_size)
+    return {"mla_decode_calls": layers,
+            "mla_decode_grid_steps": layers * steps,
+            "mla_decode_table_chunks": layers * spanned}
+
+
 def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
     """One program form of the decoder: ``(program, feeds, fetches)``.
@@ -389,7 +414,8 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     hook), ``_srv_hidden`` (the rows' last hidden state before the final
     norm: what the MTP drafter consumes), ``_srv_counts`` (tokens per
     expert by expert layer) and ``_srv_score`` (each emitted token's logit
-    and the row's log-sum-exp, two floats a row)."""
+    and the row's log-sum-exp, two floats a row); the decode and verify
+    forms ``_srv_kernel_stats`` (:func:`_decode_walk`)."""
     from .serving import _emit_head, _sampled
 
     if mode == "chunk":
@@ -509,6 +535,10 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     # layers, rows, k): the experts each emitting row was routed to
     prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
     prog._srv_routes = m.stacked(routes, "token_routes") if routes else None
+    if not whole:
+        prog._srv_kernel_stats = functools.partial(
+            _decode_walk, verify=mode == "verify", heads=cfg.num_heads,
+            layers=cfg.num_layers)
     prog._tp_degree = 1
     return prog, feeds, [out_name]
 

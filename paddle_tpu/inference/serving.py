@@ -1207,6 +1207,9 @@ class _EngineCore:
         self._moe_stats: Dict[str, Dict[str, float]] = {}
         self._moe_pending: list = []                 # (phase, counts) a call
         self.moe_calls: Optional[list] = None   # a list: every call's counts
+        # what a program's kernels report of their own work, summed by
+        # phase (``prog._srv_kernel_stats``): host integers, no device read
+        self.kernel_stats: Dict[str, Dict[str, int]] = {}
         self._chunk = None   # (prog, feeds, fetch) — built on first use
         # (begin, end) of the last engine/decode span when a request in
         # its batch was traced, else (None, None)
@@ -1728,7 +1731,12 @@ class _EngineCore:
         ``_srv_counts``: the MLA decoder's forms do, GPT-2's offer none and
         are run exactly as before).  What is offered and wanted rides on
         the same call and STAYS ON THE DEVICE (``self.last``, and the logs
-        below): a call's one host read is its tokens, as ever."""
+        below): a call's one host read is its tokens, as ever.  A form
+        that knows what its kernels will walk says so from the feed
+        (``_srv_kernel_stats``): summed by phase into ``kernel_stats``."""
+        walk = getattr(prog, "_srv_kernel_stats", None)
+        if walk is not None:
+            self._note_kernel_stats(phase, walk(feed, self.kv_config))
         extras = {}
         if self.keep_hidden and getattr(prog, "_srv_hidden", None):
             extras["hidden"] = prog._srv_hidden
@@ -1757,6 +1765,17 @@ class _EngineCore:
         # closed on arrays it did not read
         with RecordEvent("executor/fetch"):
             return [np.asarray(t) for t in out[:len(fetch)]]
+
+    def _note_kernel_stats(self, phase: str, stats):
+        if not stats:
+            return
+        st = self.kernel_stats.setdefault(phase, dict.fromkeys(stats, 0))
+        for key, value in stats.items():
+            st[key] += value
+            tm.counter(key, "a serving form's own count of its kernels' "
+                       "work (calls, grid steps, the chunks its tables "
+                       "span), summed over layers and calls",
+                       labels=("phase",)).labels(phase=phase).inc(value)
 
     def _note_scores(self, req_ids, fresh: bool = False):
         """Row ``i`` of the call just made emitted a token of
@@ -2004,7 +2023,10 @@ class ServingEngine:
                       "shed": 0, "decode_steps": 0, "prefill_tokens": 0,
                       "decode_tokens": 0, "prefill_hit_tokens": 0,
                       "prefill_chunks": 0, "max_prefill_step_tokens": 0,
-                      "spec_proposed": 0, "spec_accepted": 0}
+                      "spec_proposed": 0, "spec_accepted": 0,
+                      # by phase, what the forms' kernels say of their own
+                      # work (the core's dict itself: it fills as calls go)
+                      "kernels": self.core.kernel_stats}
         self._step_no = 0
         self._submit_seq = 0
         # pipelined steps: step N is dispatched before the tokens of step

@@ -4,13 +4,20 @@ CPU fallback and its test oracle.
 
 * ``mla_decode`` — absorbed latent attention over the paged latent pool:
   every head of a sequence scores the same latent row (``c_kv`` | ``k_r``),
-  so a grid step holds all heads of one sequence and walks SEVERAL pages:
-  the pages are fetched by hand (``make_async_copy`` through the block
-  table) into one of two VMEM buffers, the next step's pages in flight
-  while this step's are scored, and both products are MXU matmuls
-  (``(heads, 576) x (576, tokens)`` and ``(heads, tokens) x (tokens,
-  512)``).  ``paged_decode``'s one page a grid step would be 190 steps a
-  sequence and layer at a 3 k context.
+  so a grid step holds all heads of one row and scores one CHUNK of its
+  context, several pages fetched by hand (``make_async_copy`` through the
+  block table) into one of two VMEM buffers; both products are MXU
+  matmuls (``(heads, 576) x (576, tokens)`` and ``(heads, tokens) x
+  (tokens, 512)``).  The grid is a work list: one step for every (row,
+  chunk) that holds context, rows in order, built on the device from the
+  context lengths (:func:`decode_work_list`), and exactly as long as
+  that list (its length is a value on the device: no bound to prove, no
+  step that holds nothing), so a batch of ragged contexts under a table
+  padded to its longest walks what it holds and no more, whatever pages
+  the tables share.  Each visit starts the next visit's copies whatever
+  row that is, so a row's first chunk is in flight under the last chunk
+  of the row before, and a chunk is fetched by groups of pages, as many
+  as hold context.
 * ``mla_prefill`` — expanded causal attention of one whole prompt, a head
   and a block of query rows a grid step, the key blocks above the
   diagonal neither fetched nor scored; the rotary part of the score is a
@@ -52,10 +59,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import DEFAULT_MASK_VALUE, LANES, _interpret, _use_pallas
 
-#: pages one ``mla_decode`` grid step scores (256 tokens at 16 a page): a
-#: grid step costs about a third of a microsecond whether or not its chunk
-#: is live, and a 512-page table is 32 steps a sequence at this size
-DECODE_PAGES_PER_STEP = 16
+#: pages one ``mla_decode`` grid step scores (1,024 tokens at 16 a page).
+#: Measured on the chip (PR 33, 126 rows at 2.7 k of context): a step that
+#: holds context costs about half a microsecond whatever it scores (the
+#: wait, two matmuls and the softmax between them in one dependent chain)
+#: and about 32 ns a page it fetches (the copies: 625 GB/s); a step that
+#: holds none cost about 5 ns.  16 pages a step read 1.44 ms a call, 32
+#: 1.09, 64 1.0 and 128 1.08 (half a chunk a row is fetched for nothing),
+#: and with the fetch cut to the groups that hold context 64 read 0.88,
+#: 128 0.84 (0.35 and 0.40 at 700 tokens of context)
+DECODE_PAGES_PER_STEP = 64
+#: pages one group of a chunk's copies moves (256 tokens): the groups past
+#: the context are not fetched, a loop over groups and not over pages
+DECODE_PAGES_PER_FETCH = 16
 
 
 # ==========================================================================
@@ -81,98 +97,181 @@ def mla_decode_reference(q_lat, q_rope, pool, block_tables, context_lens,
     return jnp.einsum("nht,ntr->nhr", p, c)
 
 
-def _mla_decode_kernel(bt_ref, cl_ref, ql_ref, qr_ref, pool_ref, o_ref,
-                       buf, sem, m_scr, l_scr, acc_scr, *, scale, pages,
-                       page_size, n_chunks, rank, rope):
-    """Grid step ``(b, i)``: chunk ``i`` (``pages`` pages) of sequence
-    ``b``.  Chunk ``i`` lies in buffer ``i % 2``; it was started by step
-    ``i - 1`` (by this step where ``i == 0``), and this step starts chunk
-    ``i + 1`` before it waits, so the fetch runs under the matmuls.  A
-    chunk wholly past the context is neither fetched nor scored."""
-    b, i = pl.program_id(0), pl.program_id(1)
+def decode_chunks(width, step=None):
+    """``(pages, n_chunks)``: a grid step of ``mla_decode`` scores a chunk
+    of ``pages`` pages, and a table ``width`` pages wide is ``n_chunks``
+    of them (``step``: the module's pages a step unless given)."""
+    pages = min(step or DECODE_PAGES_PER_STEP, width)
+    return pages, -(-width // pages)
+
+
+def live_chunks(context_lens, tokens, n_chunks):
+    """Chunks of ``tokens`` positions each row's context reaches into: at
+    least one (a row of no context still opens and writes its output), at
+    most the table's.  Plain operators: numpy on the host, jnp traced."""
+    return (-(-context_lens // tokens)).clip(1, n_chunks)
+
+
+def decode_work_list(context_lens, tokens, n_chunks):
+    """The walk of ``mla_decode`` as ``(row, chunk, n_live)``: every (row,
+    chunk) pair that holds context once, rows in order, a row's chunks
+    ascending.  The lists are as long as every row walking its whole
+    table (static); their first ``n_live`` entries are the walk, and the
+    rest repeat the last of them."""
+    cnt = live_chunks(context_lens.astype(jnp.int32), tokens, n_chunks)
+    ends = jnp.cumsum(cnt)
+    n_live = ends[-1]
+    v = jnp.minimum(jnp.arange(cnt.shape[0] * n_chunks, dtype=jnp.int32),
+                    n_live - 1)
+    row = jnp.sum(ends[None, :] <= v[:, None], axis=1, dtype=jnp.int32)
+    chunk = v - (ends - cnt)[row]
+    return row, chunk, n_live
+
+
+def decode_walk_counts(context_lens, width, page_size):
+    """What one ``mla_decode`` call over these contexts (a host array, one
+    a row) and tables ``width`` pages wide walks, by the sizes the
+    kernel's wrapper uses: ``(grid steps, chunks the tables span)``; the
+    grid is the list of chunks that hold context and no longer."""
+    pages, n_chunks = decode_chunks(width)
+    live = int(live_chunks(context_lens, pages * page_size, n_chunks).sum())
+    return live, len(context_lens) * n_chunks
+
+
+def _mla_decode_kernel(bt_ref, cl_ref, row_ref, chunk_ref, nl_ref, ql_ref,
+                       qr_ref, pool_ref, o_ref, buf, sem, m_scr, l_scr,
+                       acc_scr, *, scale, pages, fetch, rank, rope, reps):
+    """Grid step ``v`` of ``n_live``: chunk ``chunk[v]`` (``pages`` pages)
+    of row ``row[v]``; the grid is as long as the list, so every step
+    holds context.  Visit ``v`` lies in buffer ``v % 2``; it was started by
+    visit ``v - 1`` WHATEVER ROW that was (by itself where ``v == 0``), and
+    starts visit ``v + 1`` before it waits, so a row's first chunk is in
+    flight under the last chunk of the row before.  A chunk is fetched by
+    groups of ``fetch`` pages, as many as hold context: what the buffer
+    holds past them is stale, and masked like the rest of a last page.
+    The running max, sum and accumulator open on a row's first visit and
+    are written out on its last."""
+    v = pl.program_id(0)
+    n_live = nl_ref[0]
+    b, i = row_ref[v], chunk_ref[v]
     ctx = cl_ref[b]
-    tokens = pages * page_size
+    page_size = buf.shape[3]
+    tokens, span = pages * page_size, fetch * page_size
 
-    def copies(chunk, slot):
-        return [pltpu.make_async_copy(
-            pool_ref.at[0, bt_ref[b, chunk * pages + j]],
-            buf.at[slot, pl.ds(j * page_size, page_size)],
-            sem.at[slot, j]) for j in range(pages)]
+    def groups_of(u):
+        """Groups of visit ``u`` that hold context; at least one: a row of
+        no context still has its one visit."""
+        left = cl_ref[row_ref[u]] - chunk_ref[u] * tokens
+        return jnp.clip((left + span - 1) // span, 1, pages // fetch)
 
-    def live(chunk):
-        return chunk * tokens < ctx
+    def start(u):
+        """Start the copies of visit ``u``: a loop over its groups, a
+        group's copies unrolled (a rolled loop a page is slow).  What is
+        indexed by a traced value is taken once a group and the pages
+        under it by plain integers: a kernel's trace costs by the traced
+        index, and no compile cache keeps a trace."""
+        table, first = row_ref[u] // reps, chunk_ref[u] * pages
+        slot = u % 2
+
+        def group(g, carry):
+            base = first + g * fetch
+            rows, done = buf.at[slot, g], sem.at[slot, g]
+            for j in range(fetch):
+                pltpu.make_async_copy(
+                    pool_ref.at[0, bt_ref[table, base + j]], rows.at[j],
+                    done).start()
+            return carry
+
+        lax.fori_loop(0, groups_of(u), group, 0)
+
+    def wait(u):
+        """Wait for visit ``u``: a group's copies signal one semaphore, so
+        one wait the size of the group's rows takes them all."""
+        n_groups, slot = groups_of(u), u % 2
+        for g in range(pages // fetch):
+            rows = buf.at[slot, g]
+
+            @pl.when(g < n_groups)
+            def _():
+                pltpu.make_async_copy(rows, rows, sem.at[slot, g]).wait()
+
+    @pl.when(v == 0)
+    def _first():
+        start(0)
+
+    @pl.when(v + 1 < n_live)
+    def _next():
+        start(v + 1)
 
     @pl.when(i == 0)
-    def _init():
+    def _open():
         m_scr[...] = jnp.full(m_scr.shape, -jnp.inf, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-        @pl.when(live(0))
-        def _first():
-            for c in copies(0, 0):
-                c.start()
+    wait(v)
+    rows = buf[v % 2].reshape(tokens, buf.shape[-1])     # (tokens, r + dr)
+    c_kv, k_r = rows[:, :rank], rows[:, rank:rank + rope]
+    q_lat, q_rope = ql_ref[0], qr_ref[0]                 # (heads, r | dr)
+    s = lax.dot_general(q_lat, c_kv, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    s += lax.dot_general(q_rope, k_r, (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    s = s * scale
+    pos = i * tokens + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(pos < ctx, s, DEFAULT_MASK_VALUE)
+    m_prev, l_prev = m_scr[...], l_scr[...]              # lane-broadcast
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next[:, :1])
+    l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    # a row of the chunk past the context may hold anything (a page never
+    # written, a group not fetched): its weight is exactly 0, but 0 * NaN
+    # is NaN
+    c_safe = jnp.where(
+        i * tokens + lax.broadcasted_iota(jnp.int32, c_kv.shape, 0) < ctx,
+        c_kv.astype(jnp.float32), 0.0).astype(c_kv.dtype)
+    acc_scr[...] = acc_scr[...] * alpha[:, :1] + lax.dot_general(
+        p.astype(c_kv.dtype), c_safe, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = m_next
 
-    @pl.when(jnp.logical_and(i + 1 < n_chunks, live(i + 1)))
-    def _next():
-        for c in copies(i + 1, (i + 1) % 2):
-            c.start()
-
-    @pl.when(live(i))
-    def _score():
-        slot = i % 2
-        for c in copies(i, slot):
-            c.wait()
-        rows = buf[slot]                                 # (tokens, r + dr)
-        c_kv, k_r = rows[:, :rank], rows[:, rank:rank + rope]
-        q_lat, q_rope = ql_ref[0], qr_ref[0]             # (heads, r | dr)
-        s = lax.dot_general(q_lat, c_kv, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        s += lax.dot_general(q_rope, k_r, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        s = s * scale
-        pos = i * tokens + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < ctx, s, DEFAULT_MASK_VALUE)
-        m_prev, l_prev = m_scr[...], l_scr[...]          # lane-broadcast
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next[:, :1])
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        # a row of the chunk past the context may hold anything (a page
-        # never written): its weight is exactly 0, but 0 * NaN is NaN
-        c_safe = jnp.where(
-            i * tokens + lax.broadcasted_iota(jnp.int32, c_kv.shape, 0)
-            < ctx, c_kv.astype(jnp.float32), 0.0).astype(c_kv.dtype)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + lax.dot_general(
-            p.astype(c_kv.dtype), c_safe, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_next
-
-    @pl.when(i == n_chunks - 1)
+    # the row's last visit: the next one is another row's, or none
+    @pl.when(jnp.logical_or(
+        v + 1 == n_live,
+        row_ref[jnp.minimum(v + 1, row_ref.shape[0] - 1)] != b))
     def _done():
         l_fin = l_scr[...]
         l_safe = jnp.where(l_fin == 0.0, 1.0, l_fin)
         o_ref[0] = (acc_scr[...] / l_safe[:, :1]).astype(o_ref.dtype)
 
 
-def _mla_decode_call(q_lat, q_rope, pool, block_tables, context_lens, scale):
+@functools.partial(jax.jit, static_argnames=("scale", "step", "fetch"))
+def _mla_decode_call(q_lat, q_rope, pool, block_tables, context_lens, *,
+                     scale, step, fetch):
+    """The kernel's call, ``step`` pages a chunk fetched by groups of
+    ``fetch``, over a grid of exactly the list's ``n_live`` steps (a grid
+    may be as long as a value on the device says).  Under a ``jit`` of its
+    own: the layers of a program share one trace and one lowering of it (a
+    kernel's trace is Python time that no compile cache keeps)."""
     n, heads, rank = q_lat.shape
     rope = q_rope.shape[-1]
     _, _, page_size, width = pool.shape
     w = block_tables.shape[1]
-    pages = min(DECODE_PAGES_PER_STEP, w)
-    n_chunks = -(-w // pages)
+    pages, n_chunks = decode_chunks(w, step)
+    fetch = fetch if pages % fetch == 0 else pages
     if n_chunks * pages != w:
         block_tables = jnp.pad(block_tables,
                                ((0, 0), (0, n_chunks * pages - w)))
-    tokens = pages * page_size
+    row, chunk, n_live = decode_work_list(context_lens, pages * page_size,
+                                          n_chunks)
 
-    def _q_idx(b, i, bt, cl):
-        return (b, 0, 0)
+    def _q_idx(v, bt_ref, cl_ref, row_ref, chunk_ref, nl_ref):
+        return (row_ref[v], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n, n_chunks),
+        num_scalar_prefetch=5,
+        grid=(n_live,),
         in_specs=[
             pl.BlockSpec((1, heads, rank), _q_idx),
             pl.BlockSpec((1, heads, rope), _q_idx),
@@ -180,8 +279,9 @@ def _mla_decode_call(q_lat, q_rope, pool, block_tables, context_lens, scale):
         ],
         out_specs=pl.BlockSpec((1, heads, rank), _q_idx),
         scratch_shapes=[
-            pltpu.VMEM((2, tokens, width), pool.dtype),
-            pltpu.SemaphoreType.DMA((2, pages)),
+            pltpu.VMEM((2, pages // fetch, fetch, page_size, width),
+                       pool.dtype),
+            pltpu.SemaphoreType.DMA((2, pages // fetch)),
             pltpu.VMEM((heads, LANES), jnp.float32),
             pltpu.VMEM((heads, LANES), jnp.float32),
             pltpu.VMEM((heads, rank), jnp.float32),
@@ -189,25 +289,38 @@ def _mla_decode_call(q_lat, q_rope, pool, block_tables, context_lens, scale):
     )
     return pl.pallas_call(
         functools.partial(_mla_decode_kernel, scale=scale, pages=pages,
-                          page_size=page_size, n_chunks=n_chunks, rank=rank,
-                          rope=rope),
+                          fetch=fetch, rank=rank, rope=rope,
+                          reps=n // block_tables.shape[0]),
         name="mla_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, heads, rank), jnp.float32),
         interpret=_interpret(),
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), pool)
+    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32), row,
+      chunk, n_live[None], q_lat.astype(pool.dtype),
+      q_rope.astype(pool.dtype), pool)
+
+
+def decode_engages(page_size, heads) -> bool:
+    """Whether ``mla_decode`` runs its kernel for these sizes here."""
+    return _use_pallas() and page_size % 8 == 0 and heads % 8 == 0
 
 
 def mla_decode(q_lat, q_rope, pool, block_tables, context_lens, scale):
     """Absorbed latent attention of one query row a sequence over the paged
-    latent pool (shapes as :func:`mla_decode_reference`).  The kernel wants
-    a page of whole sublane groups and heads a multiple of 8."""
-    _, heads, _ = q_lat.shape
+    latent pool (shapes as :func:`mla_decode_reference`; ``block_tables``
+    may hold fewer rows than ``q_lat``: one table for ``n / rows``
+    consecutive rows each, a verify call).  The kernel wants a page of
+    whole sublane groups and heads a multiple of 8."""
+    n, heads, _ = q_lat.shape
     page_size = pool.shape[2]
-    if _use_pallas() and page_size % 8 == 0 and heads % 8 == 0:
+    if decode_engages(page_size, heads):
         return _mla_decode_call(q_lat, q_rope, pool, block_tables,
-                                context_lens, scale=float(scale))
+                                context_lens, scale=float(scale),
+                                step=DECODE_PAGES_PER_STEP,
+                                fetch=DECODE_PAGES_PER_FETCH)
+    if block_tables.shape[0] != n:
+        block_tables = jnp.repeat(block_tables, n // block_tables.shape[0],
+                                  axis=0)
     return mla_decode_reference(q_lat, q_rope, pool, block_tables,
                                 context_lens, scale)
 

@@ -213,9 +213,6 @@ def mla_absorbed_attention(q_nope, q_rope, pool, block_tables, context_lens,
     heads * dv) float32."""
     n, heads, dn = q_nope.shape
     rank, cd = w_kvb.shape[0], w_kvb.dtype
-    if block_tables.shape[0] != n:
-        block_tables = jnp.repeat(block_tables, n // block_tables.shape[0],
-                                  axis=0)
     w = w_kvb.reshape(rank, heads, dn + v_dim)
     with jax.named_scope("mla_absorb"):
         q_lat = jnp.einsum("nhd,rhd->nhr", q_nope.astype(cd), w[..., :dn],

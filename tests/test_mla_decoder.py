@@ -360,20 +360,139 @@ def interpreted(monkeypatch):
     monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
 
 
-@pytest.mark.parametrize("lens,width", [((5, 70, 96), 12), ((1,), 1),
-                                        ((64, 33), 8)])
-def test_mla_decode_kernel(interpreted, lens, width):
+def _decode_case(lens, width, pages, tables, reps):
+    """Operands of one ``mla_decode`` call: ``lens`` the rows' contexts,
+    tables ``width`` pages wide over a pool of ``pages`` (``random``:
+    drawn with repeats, so rows share pages; ``own``: every table's pages
+    its own; ``reps`` consecutive rows read one table)."""
     rng = np.random.RandomState(len(lens))
-    n, heads, rank, rope, ps, pages = len(lens), 8, 16, 8, 8, 40
-    pool = jnp.asarray(rng.randn(1, pages, ps, rank + rope), jnp.float32)
+    n, heads, rank, rope, ps = len(lens), 8, 16, 8, 8
+    pool = rng.randn(1, pages, ps, rank + rope).astype(np.float32)
     q_lat = jnp.asarray(rng.randn(n, heads, rank), jnp.float32)
     q_rope = jnp.asarray(rng.randn(n, heads, rope), jnp.float32)
-    tables = jnp.asarray(rng.randint(0, pages, (n, width)), jnp.int32)
-    ctx = jnp.asarray(lens, jnp.int32)
-    got = mla_kernels.mla_decode(q_lat, q_rope, pool, tables, ctx, 0.2)
-    want = mla_kernels.mla_decode_reference(q_lat, q_rope, pool, tables, ctx,
-                                            0.2)
+    if tables == "own":
+        need = [-(-max(lens[t * reps:(t + 1) * reps]) // ps)
+                for t in range(n // reps)]
+        assert sum(need) <= pages and max(need) <= width
+        order, at = rng.permutation(pages), 0
+        tables = np.zeros((n // reps, width), np.int32)
+        for t, k in enumerate(need):
+            tables[t, :k] = order[at:at + k]
+            at += k
+    else:
+        tables = rng.randint(0, pages, (n // reps, width)).astype(np.int32)
+    return q_lat, q_rope, pool, tables, np.asarray(lens, np.int32)
+
+
+# (contexts, table width, pool pages, pages a grid step, tables, rows a
+# table)
+DECODE_CASES = {
+    "one_chunk_a_row": ((5, 70, 96), 12, 40, None, "random", 1),
+    "one_token": ((1,), 1, 40, None, "random", 1),
+    "two_rows": ((64, 33), 8, 40, None, "random", 1),
+    # chunks of 32 tokens: 1, 3, 1, 3, 1 live of 3 a row
+    "ragged_with_padded_rows": ((5, 70, 1, 96, 1), 12, 40, 4, "own", 1),
+    # 10 pages are 3 chunks of 4, the table padded to 12
+    "width_not_whole_chunks": ((70, 9, 80), 10, 40, 4, "own", 1),
+    # 5 pages each of a pool of 15, every page of it some row's: 2 chunks a
+    # row, 6 steps where 15 // 4 + 3 rows bound what exclusive pages give
+    "pool_full_of_exclusive_pages": ((33, 40, 36), 16, 15, 4, "own", 1),
+    "room_in_the_pool": ((33, 8, 70, 1), 16, 24, 4, "own", 1),
+    # rows that share 8 pages walk 15 chunks, past any bound the pool gives
+    "rows_share_pages": ((100, 120, 128, 90), 16, 8, 4, "random", 1),
+    # every row walks its whole table: the list is as long as it can be
+    "every_chunk_live": ((128, 128, 127), 16, 8, 4, "random", 1),
+    # a verify call: three rows a table, contexts one apart
+    "verify_rows_share_a_table": ((30, 31, 32, 70, 71, 72), 12, 40, 4, "own",
+                                  3),
+    # chunks of 6 pages fetched by groups of 3, a table of 14
+    "groups_and_a_table_that_do_not_divide": ((100, 17, 49, 90), 14, 40, 6,
+                                              "own", 1),
+    "verify_four_rows_a_table": ((30, 31, 32, 33, 70, 71, 72, 73), 16, 16, 2,
+                                 "own", 4),
+}
+
+
+def _chunks_of(monkeypatch, step):
+    """Chunks of ``step`` pages fetched by groups of half as many (the
+    kernel's own sizes where ``step`` is None)."""
+    if step:
+        monkeypatch.setattr(mla_kernels, "DECODE_PAGES_PER_STEP", step)
+        monkeypatch.setattr(mla_kernels, "DECODE_PAGES_PER_FETCH",
+                            max(step // 2, 1))
+
+
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_mla_decode_kernel(interpreted, monkeypatch, case):
+    lens, width, pages, step, how, reps = DECODE_CASES[case]
+    _chunks_of(monkeypatch, step)
+    q_lat, q_rope, pool, tables, ctx = _decode_case(lens, width, pages, how,
+                                                    reps)
+    got = mla_kernels.mla_decode(q_lat, q_rope, jnp.asarray(pool),
+                                 jnp.asarray(tables), jnp.asarray(ctx), 0.2)
+    want = mla_kernels.mla_decode_reference(
+        q_lat, q_rope, jnp.asarray(pool),
+        jnp.asarray(np.repeat(tables, reps, axis=0)), jnp.asarray(ctx), 0.2)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # the grid is the chunks that hold context, of those the tables span
+    steps, spanned = mla_kernels.decode_walk_counts(ctx, width, 8)
+    pages_a_step, n_chunks = mla_kernels.decode_chunks(width)
+    assert spanned == len(lens) * n_chunks
+    assert steps == sum(min(max(-(-n // (8 * pages_a_step)), 1), n_chunks)
+                        for n in lens) <= spanned
+
+
+@pytest.mark.parametrize("case", ["ragged_with_padded_rows",
+                                  "width_not_whole_chunks",
+                                  "pool_full_of_exclusive_pages",
+                                  "verify_rows_share_a_table"])
+def test_mla_decode_kernel_reads_no_row_past_a_context(interpreted,
+                                                       monkeypatch, case):
+    """Every pool row no context reaches holds NaN (whole pages, and the
+    rest of each table's last page): the result is the finite one the
+    reference gives over a pool with zeros there."""
+    lens, width, pages, step, how, reps = DECODE_CASES[case]
+    _chunks_of(monkeypatch, step)
+    q_lat, q_rope, pool, tables, ctx = _decode_case(lens, width, pages, how,
+                                                    reps)
+    live = np.zeros(pool.shape[1] * pool.shape[2], bool)
+    for b, n in enumerate(lens):
+        live[(tables[b // reps][:, None] * 8 + np.arange(8)).reshape(-1)[:n]] \
+            = True
+    live = live.reshape(pool.shape[1:3])
+    clean = pool.copy()
+    pool[0][~live], clean[0][~live] = np.nan, 0.0
+    got = mla_kernels.mla_decode(q_lat, q_rope, jnp.asarray(pool),
+                                 jnp.asarray(tables), jnp.asarray(ctx), 0.2)
+    want = mla_kernels.mla_decode_reference(
+        q_lat, q_rope, jnp.asarray(clean),
+        jnp.asarray(np.repeat(tables, reps, axis=0)), jnp.asarray(ctx), 0.2)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("lens,tokens,n_chunks", [
+    ((5, 70, 1, 96, 1), 32, 3),              # ragged, padded rows
+    ((33, 40, 36), 32, 2),                   # the list is as long as it gets
+    ((0, 300, 64), 32, 4),                   # no context: one visit; over
+                                             # the table: the table's chunks
+    ((256,) * 4, 256, 1),                    # a chunk a row
+])
+def test_decode_work_list(lens, tokens, n_chunks):
+    ctx = np.asarray(lens, np.int32)
+    visits = len(lens) * n_chunks
+    row, chunk, n_live = map(np.asarray, mla_kernels.decode_work_list(
+        jnp.asarray(ctx), tokens, n_chunks))
+    assert row.shape == chunk.shape == (visits,)
+    per_row = np.clip(-(-ctx // tokens), 1, n_chunks)
+    assert n_live == per_row.sum() <= visits
+    # every live (row, chunk) once, rows in order, a row's chunks ascending
+    want = [(b, i) for b, k in enumerate(per_row) for i in range(k)]
+    assert list(zip(row[:n_live], chunk[:n_live])) == want
+    # nothing past n_live: the rest repeat the last real visit
+    assert (row[n_live:] == row[n_live - 1]).all()
+    assert (chunk[n_live:] == chunk[n_live - 1]).all()
+    assert (per_row == mla_kernels.live_chunks(ctx, tokens, n_chunks)).all()
 
 
 def test_mla_decode_kernel_ignores_what_lies_past_the_context(interpreted):
@@ -445,3 +564,29 @@ def test_engine_through_the_kernels_matches_reference(interpreted):
     eng.run_to_completion()
     worst, _ = served_against_reference(eng, cfg, weights, reqs)
     assert worst <= 8e-2, worst
+
+
+def test_engine_counts_what_mla_decode_walks(interpreted):
+    """The decode form says from its feed what its kernels walk, by phase,
+    in ``eng.stats`` and the registry; a form whose kernel does not engage
+    (4 heads) says nothing."""
+    from paddle_tpu.utils import telemetry as tm
+
+    cfg = MLADecoderConfig(hidden=128, num_heads=8, moe_intermediate=128,
+                           intermediate=256, num_layers=2)
+    eng, cfg, _ = make_engine(cfg, "bfloat16")
+    eng.generate(prompts_of(3, lens=(9, 20)), 4)
+    walk = eng.stats["kernels"]
+    assert set(walk) == {"decode"} and walk is eng.core.kernel_stats
+    st, steps = walk["decode"], eng.stats["decode_steps"]
+    assert st["mla_decode_calls"] == cfg.num_layers * steps
+    # contexts of 10..24 tokens: a chunk a row, two rows a step, and tables
+    # one chunk wide
+    assert st["mla_decode_grid_steps"] == st["mla_decode_table_chunks"] \
+        == 2 * st["mla_decode_calls"]
+    said = tm.snapshot()["mla_decode_grid_steps"]["series"]
+    assert any(s["labels"] == {"phase": "decode"}
+               and s["value"] >= st["mla_decode_grid_steps"] for s in said)
+    plain, _, _ = make_engine(TINY)
+    plain.generate(prompts_of(3, lens=(9,)), 2)
+    assert plain.stats["kernels"] == {}

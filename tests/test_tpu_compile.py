@@ -200,6 +200,32 @@ def test_kernel_compiles_for_v5e(name, v5e, monkeypatch):
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
 
 
+# --- mla_decode at JoyAI-LLM-Flash's widths: (rows, tables, table width) ----
+MLA_POOL = (1, 24576, 16, 640)    # the cell's latent pool, bfloat16
+
+
+@pytest.mark.parametrize("rows,tables,width", [
+    (128, 128, 512),        # the cell's decode step
+    (128, 32, 512),         # a verify call, four rows a table
+    (4, 4, 32),             # the smoke's decode step
+])
+def test_mla_decode_compiles_for_v5e(rows, tables, width, v5e):
+    """The work-list kernel as the chip's compiler takes it, its grid as
+    long as a value on the device says: one Mosaic call, no branch."""
+    from paddle_tpu.ops import mla_kernels as mk
+
+    def f(q_lat, q_rope, pool, bt, cl):
+        return mk._mla_decode_call(q_lat, q_rope, pool, bt, cl, scale=0.1,
+                                   step=mk.DECODE_PAGES_PER_STEP,
+                                   fetch=mk.DECODE_PAGES_PER_FETCH)
+
+    text = _compile(f, v5e, ((rows, 32, 512), jnp.float32),
+                    ((rows, 32, 64), jnp.float32), (MLA_POOL, jnp.bfloat16),
+                    ((tables, width), jnp.int32), ((rows,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " conditional(" not in text
+
+
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("head_dim,page_size,decode_copies", [
     # head_dim under the 128 lanes, a page whole tiles: stored lane-full,
