@@ -86,6 +86,23 @@ SIZES = {
             # contexts and three rows of padding fill one each
             num_pages=512, page_size=16, token_budget=2048, max_batch=8,
             prompts=[300, 20, 280, 31], long_prompt=1100, new_tokens=8),
+        # Kimi-Linear's cut at published widths and one period of depth
+        # (KDA, KDA, KDA, MLA): the dense layer and three expert layers of
+        # 64 held experts of 256, a quarter of the vocabulary (3.5 GB)
+        "hybrid": dict(
+            cfg=dict(vocab_size=40960, hidden=2304, num_heads=32,
+                     num_layers=4, first_k_dense=1, intermediate=9216,
+                     moe_intermediate=1024, n_routed_experts=256,
+                     experts_held=64, n_shared_experts=1,
+                     num_experts_per_tok=8, q_lora_rank=0, kv_lora_rank=512,
+                     qk_nope_head_dim=128, qk_rope_head_dim=64,
+                     v_head_dim=128, rope=False, rms_norm_eps=1e-5,
+                     routed_scaling_factor=2.446,
+                     mixers=("kda", "kda", "kda", "mla"), kda_heads=32,
+                     kda_head_dim=128, kda_gate_rank=128, max_seq_len=2048,
+                     weights_dtype="bfloat16"),
+            num_pages=512, page_size=16, token_budget=2048, max_batch=8,
+            prompts=[300, 20, 280, 31, 1100], new_tokens=8),
     },
     "tiny": {
         "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
@@ -113,12 +130,27 @@ SIZES = {
                      weights_dtype="bfloat16", mtp_layers=1),
             num_pages=64, page_size=16, token_budget=128, max_batch=4,
             prompts=[40, 5, 36, 9], long_prompt=70, new_tokens=6),
+        "hybrid": dict(
+            cfg=dict(vocab_size=256, hidden=128, num_heads=8, num_layers=4,
+                     first_k_dense=1, intermediate=256, moe_intermediate=128,
+                     n_routed_experts=8, experts_held=4,
+                     num_experts_per_tok=2, q_lora_rank=0, kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     rope=False, rms_norm_eps=1e-5,
+                     mixers=("kda", "kda", "kda", "mla"), kda_heads=8,
+                     kda_head_dim=16, kda_gate_rank=16, max_seq_len=128,
+                     weights_dtype="bfloat16"),
+            num_pages=64, page_size=16, token_budget=128, max_batch=4,
+            prompts=[40, 5, 36, 9, 70], new_tokens=6),
     },
 }
 
 # The MLA decoder's served logits against its float32 reference, bfloat16
 # weights and cache: the limits of benchmark/configs/joyai-llm-flash.json
 MLA_LOGIT_ABS_TOL, MLA_ROUTE_SLACK_TOL = 0.06, 0.008
+# the hybrid decoder's, the reference routed as the engine was on the prompt's
+# rows too: the limits of benchmark/configs/kimi-linear-48b-a3b.json
+HYBRID_LOGIT_ABS_TOL, HYBRID_ROUTE_SLACK_TOL = 0.06, 0.008
 
 # mla_decode's grid at the "full" sizes: contexts of 300, 20, 280, 31 and
 # 1,100 tokens and three rows of padding are 1 + 1 + 1 + 1 + 2 + 3 chunks
@@ -397,6 +429,28 @@ class Ctx:
                 "programs_read": len(texts), "pools_held_as": sorted(held),
                 "most_pool_copies_in_a_program": copies,
                 "most_async_pool_prefetches_in_a_program": prefetches}
+
+    def require_state_in_place(self, phase, specs):
+        """The slot pools of a model with recurrent layers (``specs``: name
+        -> (shape, dtype)), in every program the phase compiled: a result of
+        a pool's size may be the decode kernel's (its output aliases its
+        pool operand), an update in place (``dynamic-update-slice``,
+        ``scatter``, or a fusion of one) or a view; a ``copy`` of one is the
+        compiler re-laying a pool, a pass over gigabytes every step, and is
+        refused.  Returns what it read."""
+        texts = self.watch.compiled_texts()
+        forms = sorted({",".join(map(str, shape))
+                        for shape, _dtype in specs.values()})
+        made, copies = {}, []
+        for name, text in texts.items():
+            for op, _dims, line in pool_makers(text, forms):
+                made[op] = made.get(op, 0) + 1
+                if op in ("copy", "transpose", "reshape"):
+                    copies.append(f"{name}: {line.strip()[:200]}")
+        if copies and not self.interpreted:
+            raise RuntimeError(f"{phase}: a program copies or re-lays a "
+                               f"whole state pool: {copies[:3]}")
+        return {"state_pool_shapes": forms, "state_pool_results_by_op": made}
 
     def close(self):
         shutil.rmtree(self.tmp, ignore_errors=True)
@@ -855,6 +909,102 @@ def phase_mla(ctx):
         **ctx.memory())
 
 
+def phase_hybrid(ctx):
+    """The hybrid decoder (KDA layers with a state slot a sequence beside an
+    MLA layer's paged latent rows, expert layers holding a share of their
+    experts) through ServingEngine: its kernels in the lowered programs, no
+    operation of latent-pool or state-pool size in the compiled ones but the
+    in-place writes, the served logits against the plain reference, and
+    pipelined steps leaving greedy tokens unchanged."""
+    import importlib.util
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu.inference.mla_decoder import (MLADecoderConfig,
+                                                  init_mla_weights)
+    from paddle_tpu.inference.serving import Request, ServingEngine
+
+    jax = ctx.jax
+    phase, size = "serve/hybrid", ctx.sizes["hybrid"]
+    cfg = MLADecoderConfig(**size["cfg"])
+    spec = importlib.util.spec_from_file_location(
+        "reference_kimi", os.path.join(os.path.dirname(os.path.abspath(
+            __file__)), "benchmark", "reference", "kimi-linear-48b-a3b.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    weights = {n: jax.device_put(w, ctx.device)
+               for n, w in init_mla_weights(cfg, 0).items()}
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in size["prompts"]]
+
+    def drive(**kw):
+        eng = ServingEngine(
+            cfg=cfg, weights=weights, kv_dtype="bfloat16", place=ctx.place,
+            num_pages=size["num_pages"], page_size=size["page_size"],
+            max_batch=size["max_batch"], token_budget=size["token_budget"],
+            **kw)
+        eng.core.keep_scores = True
+        reqs = [Request(i, p, size["new_tokens"])
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_to_completion()
+        return eng, reqs
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        mark = ctx.watch.mark()
+        plain, reqs = drive()
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(
+            phase, modules, ["kda_prefill", "kda_decode", "mla_decode",
+                             "latent_append", "moe_gmm"])
+        in_place = ctx.require_pool_in_place(
+            phase, plain.core.kv_config,
+            n_pools=len(cfg.cache_pool_names()), append="latent_append")
+        state = ctx.require_state_in_place(
+            phase, cfg.state_pool_specs(size["max_batch"]))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    worst, slack = 0.0, 0.0
+    for r in reqs:
+        got, routes = plain.core.served_scores(r.req_id)
+        ref = reference.served_token_scores(
+            weights, cfg.source_config(), r.prompt, r.out_tokens, routes,
+            pad_to=2048 if ctx.sizes is SIZES["full"] else 128,
+            prompt_routes=plain.core.prompt_routes(r.req_id))
+        worst = max(worst, float(np.abs(got[:, 0] - ref["logit"]).max()),
+                    float(np.abs(got[:, 1] - ref["lse"]).max()))
+        slack = max(slack, float(ref["slack"].max()))
+    if ctx.sizes is SIZES["full"] and (worst > HYBRID_LOGIT_ABS_TOL
+                                       or slack > HYBRID_ROUTE_SLACK_TOL):
+        raise RuntimeError(
+            f"{phase}: served logits lie {worst} from the reference (limit "
+            f"{HYBRID_LOGIT_ABS_TOL}), routing slack {slack} (limit "
+            f"{HYBRID_ROUTE_SLACK_TOL})")
+    stats, slots = plain.stats, plain.kv.stats()["state_slots"]
+    del plain
+    gc.collect()
+    piped, piped_reqs = drive(pipeline=2)
+    if [r.out_tokens for r in piped_reqs] != [r.out_tokens for r in reqs]:
+        raise RuntimeError(f"{phase}: pipelined steps changed the tokens "
+                           f"served: {[r.out_tokens for r in piped_reqs]} "
+                           f"vs {[r.out_tokens for r in reqs]}")
+    del piped
+    gc.collect()
+    say(phase=phase, **{k: v for k, v in size["cfg"].items()},
+        num_pages=size["num_pages"], prompts=size["prompts"],
+        new_tokens=size["new_tokens"], scheduler=stats, state_slots=slots,
+        **seen, kernel_calls=kernels, **in_place, **state,
+        served_logits_worst_gap=worst, route_slack=slack,
+        pipeline="tokens identical with pipelined steps on and off",
+        **ctx.memory())
+
+
 def phase_tp4(ctx):
     """tp=4 decode against tp=1 tokens for the same requests."""
     phase, size = "tp4/decoder", ctx.sizes["serve"]
@@ -906,8 +1056,8 @@ def main(argv=None):
     ap.add_argument("--size", choices=sorted(SIZES), default="full")
     ap.add_argument("--only", default=None,
                     help="comma-separated phases to run instead of all of "
-                         "the chip count's (resnet, bert, serve, mla, dp4, "
-                         "tp4)")
+                         "the chip count's (resnet, bert, serve, mla, hybrid, "
+                         "dp4, tp4)")
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="skip the TPU assertion (and the device's memory "
                          "counters): a rehearsal, never a result")
@@ -930,7 +1080,7 @@ def main(argv=None):
             compile_cache_dir=ctx.cache_dir, cache_entries_before=entries0,
             note="smoke observations, not benchmark metrics")
         phases = (phase_dp4, phase_tp4) if args.chips == 4 else \
-            (phase_resnet, phase_bert, phase_serve, phase_mla)
+            (phase_resnet, phase_bert, phase_serve, phase_mla, phase_hybrid)
         if args.only:
             phases = [globals()["phase_" + name]
                       for name in args.only.split(",")]

@@ -80,6 +80,17 @@ class KVCacheConfig:
     head_dim: int
     num_layers: int = 1
     dtype: str = "float32"
+    # fixed-size per-sequence state beside the pages (a recurrent layer's
+    # state): a live sequence owns one of ``state_slots`` slots, whatever
+    # its length; the pools that hold them have one slot more, the
+    # padding's (``pad_state_slot``).  0: the model keeps no such state
+    state_slots: int = 0
+
+    @property
+    def pad_state_slot(self) -> int:
+        """The slot padded rows carry: the last of the state pools, owned
+        by no sequence."""
+        return self.state_slots
 
     @property
     def pad_slot(self) -> int:
@@ -164,6 +175,7 @@ class _Seq:
     # never inflate the hit numbers)
     pending_hit: int = 0
     pending_shared: int = 0
+    state_slot: Optional[int] = None   # its slot in the state pools
 
 
 def _chain(digest: bytes, tokens) -> bytes:
@@ -185,6 +197,11 @@ class PagedKVCache:
 
             prefix_cache = bool(flag("kv_prefix_cache", False))
         self.prefix_cache = bool(prefix_cache)
+        if config.state_slots and self.prefix_cache:
+            raise ValueError(
+                "a cache with state slots takes no prefix cache: a recurrent "
+                "state is not a function of a shared page, and sharing a "
+                "prefix would need a snapshot of the state at its end")
         from ..utils import telemetry as tm
 
         # the allocator's instruments, resolved once and not by name at
@@ -223,6 +240,11 @@ class PagedKVCache:
         self.seed = int(seed)
         self._free: deque = deque(range(config.num_pages))
         self._seqs: Dict[object, _Seq] = {}
+        # state slots: handed out lowest first, reused in free order
+        self._free_slots: deque = deque(range(config.state_slots))
+        self._stateful = config.state_slots > 0
+        self.peak_state_slots = 0
+        self.state_slots_preempted = 0
         # the sum of every live sequence's length, kept as they change:
         # ``fragmentation`` is published on every append, and summing the
         # sequences there made a decode step of n sequences n * n
@@ -302,7 +324,23 @@ class PagedKVCache:
     def can_append(self, seq_id, n_tokens: int) -> bool:
         return (self.pages_needed(seq_id, n_tokens)
                 + self.cow_fork_need(seq_id, n_tokens)
-                <= self.num_free_pages)
+                <= self.num_free_pages) and (
+                    not self._stateful or self.has_slot_for(seq_id))
+
+    # -- state slots ---------------------------------------------------------
+    def has_slot_for(self, seq_id) -> bool:
+        """A sequence not yet live needs a free state slot (asked only of
+        a cache that has slots: an engine hands out one a sequence of its
+        full batch, so this guards a caller that admits beyond it)."""
+        return seq_id in self._seqs or bool(self._free_slots)
+
+    @property
+    def state_slots_in_use(self) -> int:
+        return self.config.state_slots - len(self._free_slots)
+
+    def state_slot(self, seq_id) -> int:
+        """The live sequence's slot in the state pools."""
+        return self._seqs[seq_id].state_slot
 
     def _publish_gauges(self):
         """Pool state -> telemetry registry (r13): the gauges mirror
@@ -416,9 +454,16 @@ class PagedKVCache:
                     f"{n_tokens} slots")
         need = self.pages_needed(seq_id, n_tokens)
         fork = self.cow_fork_need(seq_id, n_tokens)
-        if need + fork > self.num_free_pages:
+        if need + fork > self.num_free_pages or (
+                self._stateful and not self.has_slot_for(seq_id)):
             return None
-        s = self._seqs.setdefault(seq_id, _Seq())
+        s = self._seqs.get(seq_id)
+        if s is None:
+            s = self._seqs[seq_id] = _Seq()
+            if self._stateful:
+                s.state_slot = self._free_slots.popleft()
+                self.peak_state_slots = max(self.peak_state_slots,
+                                            self.state_slots_in_use)
         ps = self.config.page_size
         if self.prefix_cache:
             if tokens is None and n_tokens:
@@ -584,6 +629,10 @@ class PagedKVCache:
         next write into it."""
         if n_tokens <= 0:
             return
+        if self.config.state_slots:
+            raise ValueError("truncate_tokens: a cache with state slots "
+                             "cannot roll a sequence back (its state holds "
+                             "the tokens to be dropped)")
         s = self._seqs[seq_id]
         if n_tokens > s.length:
             raise ValueError(
@@ -640,14 +689,19 @@ class PagedKVCache:
         out, self._pending_forks = self._pending_forks, []
         return out
 
-    def free_sequence(self, seq_id):
+    def free_sequence(self, seq_id, preempted: bool = False):
         """Decrement the sequence's page refcounts; a page is reclaimed
         only at refcount zero (indexed pages park in the evictable
         cached set, the rest return to the free list — free-on-finish
-        order unchanged)."""
+        order unchanged).  Its state slot returns with them (the next
+        owner's prefill rewrites the whole slot); ``preempted`` counts it
+        as freed by a preemption."""
         s = self._seqs.pop(seq_id, None)
         if s is None:
             return
+        if s.state_slot is not None:
+            self._free_slots.append(s.state_slot)
+            self.state_slots_preempted += bool(preempted)
         self._live_tokens -= s.length
         released = 0
         for page in s.pages:
@@ -696,7 +750,14 @@ class PagedKVCache:
         return self._refs.get(page, 0)
 
     def stats(self) -> dict:
+        slots = {"state_slots": {
+            "total": self.config.state_slots,
+            "in_use": self.state_slots_in_use,
+            "peak": self.peak_state_slots,
+            "freed_by_preemption": self.state_slots_preempted}} \
+            if self.config.state_slots else {}
         return {
+            **slots,
             "dtype": self.config.dtype,
             "pool_stored_shape": list(self.config.pool_shape()),
             "pool_tokens_per_row": self.config.tokens_per_row,

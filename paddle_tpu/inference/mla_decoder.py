@@ -24,6 +24,21 @@ Types: parameters in ``weights_dtype``; every matmul takes operands of
 that type and accumulates in float32; the residual stream, norms, router
 scores and softmax are float32.
 
+**Hybrid layers** (``mixers``).  Each layer names its mixer: ``"mla"`` (the
+block above) or ``"kda"``, the gated delta-rule mixer of Kimi-Linear
+(arXiv:2510.26692; ``ops/kda_ops.py``), which keeps no rows a token but one
+float32 state ``(heads, d_k, d_v)`` and the last ``taps - 1`` inputs of a
+short convolution a sequence, in a SLOT of two pools a layer beside the
+paged latent pools of the MLA layers (``state_pool_specs``; the cache
+manager hands a sequence its slot with its first pages).  ``mixers`` empty is
+"every mixer MLA": the programs of such a configuration are what they were.
+``q_lora_rank`` 0 projects the queries directly (``wq``), ``rope`` false
+leaves ``q_r`` and ``k_r`` unrotated (NoPE), and ``experts_held`` under
+``n_routed_experts`` is one chip's share of an expert-parallel layer: the
+router scores all experts, the weights are normalised over all chosen, and
+the layer computes the part of the sum whose experts it holds (plus the
+shared expert, whole).
+
 The multi-token-prediction module (``mtp_layers`` 1) is one more block
 with its own cache rows (layer index ``num_layers``) behind ``h' = W_p
 [RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))]``; :class:`MTPDrafter` runs it as
@@ -33,7 +48,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -71,6 +86,15 @@ class MLADecoderConfig:
     eos_id: int = -1
     weights_dtype: str = "float32"
     mtp_layers: int = 0              # 1: the MTP block's weights and cache
+    # -- the hybrid description: empty / 0 / True is the plain MLA decoder
+    mixers: Tuple[str, ...] = ()     # per layer "mla" | "kda"; (): all MLA
+    rope: bool = True                # False: NoPE, q_r and k_r unrotated
+    experts_held: int = 0            # experts 0..held-1 of a layer; 0: all
+    kda_heads: int = 0
+    kda_head_dim: int = 0            # d_k = d_v
+    kda_conv_taps: int = 4
+    kda_gate_rank: int = 0           # inner width of the two low-rank gates
+    kda_l2_eps: float = 1e-6
 
     # -- the seam ServingEngine asks a model description through ---------
     @property
@@ -89,6 +113,23 @@ class MLADecoderConfig:
     def param_dtype(self) -> str:
         return self.weights_dtype
 
+    def mixer(self, i: int) -> str:
+        return self.mixers[i] if self.mixers else "mla"
+
+    @property
+    def mla_layers(self) -> List[int]:
+        """The layers that keep latent rows, the MTP block's included."""
+        return [i for i in range(self.num_layers) if self.mixer(i) == "mla"] \
+            + list(range(self.num_layers, self.num_layers + self.mtp_layers))
+
+    @property
+    def kda_layers(self) -> List[int]:
+        return [i for i in range(self.num_layers) if self.mixer(i) == "kda"]
+
+    @property
+    def experts_here(self) -> int:
+        return self.experts_held or self.n_routed_experts
+
     def param_specs(self) -> Dict[str, tuple]:
         return mla_param_specs(self)
 
@@ -98,8 +139,22 @@ class MLADecoderConfig:
                                  kv_dtype=kv_dtype)
 
     def validate(self, tp: int = 1, kv_dtype: str = "float32",
-                 prefix_cache: bool = False, prefill_chunk: int = 0):
+                 prefix_cache: bool = False, prefill_chunk: int = 0,
+                 spec_k: int = 0):
         """What this model is not served with, refused at construction."""
+        if self.mixers and (len(self.mixers) != self.num_layers or
+                            set(self.mixers) - {"mla", "kda"}):
+            raise ValueError(f"mixers must name 'mla' or 'kda' for each of "
+                             f"the {self.num_layers} layers: {self.mixers}")
+        if self.kda_layers:
+            if self.mtp_layers:
+                raise ValueError(
+                    "a model with KDA layers is not served with speculative "
+                    "decoding (a recurrent state cannot be rolled back "
+                    "without a snapshot): mtp_layers must be 0")
+            if "mla" not in self.mixers:
+                raise ValueError("a hybrid model needs an MLA layer: the "
+                                 "engine sizes its page pool by it")
         if int(tp or 1) != 1:
             raise ValueError("the MLA decoder has no tensor-parallel rules: "
                              "serving_tp must be 1")
@@ -110,25 +165,48 @@ class MLADecoderConfig:
             raise ValueError(
                 "the MLA decoder builds no 'chunk' program form: prefix "
                 "caching and chunked prefill are refused for this model")
+        if self.kda_layers and spec_k:
+            raise ValueError(
+                "a model with KDA layers is not served with speculative "
+                "decoding: a recurrent state cannot be rolled back without "
+                "a snapshot")
 
     def tp_rules(self, kv_dtype: str = "float32") -> Dict[str, tuple]:
         return {}
 
     def kv_cache_config(self, num_pages: int, page_size: int,
                         kv_dtype: str) -> KVCacheConfig:
-        """One latent row a token: a single 'head' of ``latent_row``."""
+        """One latent row a token and MLA layer: a single 'head' of
+        ``latent_row``."""
         return KVCacheConfig(
             num_pages=num_pages, page_size=page_size, num_kv_heads=1,
-            head_dim=self.latent_row,
-            num_layers=self.num_layers + self.mtp_layers, dtype=kv_dtype)
+            head_dim=self.latent_row, num_layers=len(self.mla_layers),
+            dtype=kv_dtype)
 
     def cache_pool_names(self) -> List[str]:
-        return [f"kv_lat_{i}"
-                for i in range(self.num_layers + self.mtp_layers)]
+        return [f"kv_lat_{i}" for i in self.mla_layers]
 
     def kv_token_bytes(self, kv_dtype: str, tp: int = 1) -> int:
-        return (self.num_layers + self.mtp_layers) * self.latent_row \
+        return len(self.mla_layers) * self.latent_row \
             * np.dtype(kv_dtype).itemsize
+
+    def state_pool_specs(self, state_slots: int) -> Dict[str, tuple]:
+        """name -> (shape, dtype) of the pools that hold one SLOT a
+        sequence (and one more, the padding's): a KDA layer's state and its
+        convolution's last inputs, float32.  Empty without KDA layers."""
+        h, d = self.kda_heads, self.kda_head_dim
+        specs = {}
+        for i in self.kda_layers:
+            specs[f"kda_state_{i}"] = ((state_slots + 1, h, d, d), "float32")
+            specs[f"kda_conv_{i}"] = (
+                (state_slots + 1, self.kda_conv_taps - 1, 3 * h * d),
+                "float32")
+        return specs
+
+    def state_slot_bytes(self) -> int:
+        """Bytes one sequence's slot holds over the KDA layers."""
+        return sum(int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+                   for shape, dtype in self.state_pool_specs(0).values())
 
     # -- the source's names (its config.json), which the configuration
     # file, the plain reference and the tests speak ----------------------
@@ -150,50 +228,134 @@ class MLADecoderConfig:
         "norm_topk_prob": "norm_topk_prob",
     }
 
+    # Kimi-Linear's config.json names the same things otherwise, and adds
+    # the layers' kinds (``linear_attn_config``, 1-based lists)
+    _HYBRID_KEYS = {
+        "vocab_size": "vocab_size", "hidden": "hidden_size",
+        "num_heads": "num_attention_heads",
+        "num_layers": "num_hidden_layers",
+        "first_k_dense": "first_k_dense_replace",
+        "intermediate": "intermediate_size",
+        "moe_intermediate": "moe_intermediate_size",
+        "n_shared_experts": "num_shared_experts",
+        "num_experts_per_tok": "num_experts_per_token",
+        "kv_lora_rank": "kv_lora_rank",
+        "qk_nope_head_dim": "qk_nope_head_dim",
+        "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+        "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+        "routed_scaling_factor": "routed_scaling_factor",
+        "norm_topk_prob": "moe_renormalize",
+    }
+
     def source_config(self) -> dict:
         """This model under the source's key names."""
-        return {theirs: getattr(self, ours)
-                for ours, theirs in self._SOURCE_KEYS.items()}
+        if not self.mixers:
+            return {theirs: getattr(self, ours)
+                    for ours, theirs in self._SOURCE_KEYS.items()}
+        out = {theirs: getattr(self, ours)
+               for ours, theirs in self._HYBRID_KEYS.items()}
+        out.update(
+            num_experts=self.experts_here,
+            router_experts=self.n_routed_experts,
+            q_lora_rank=self.q_lora_rank or None,
+            mla_use_nope=not self.rope, kda_l2_eps=self.kda_l2_eps,
+            linear_attn_config={
+                "kda_layers": [i + 1 for i in self.kda_layers],
+                "full_attn_layers": [i + 1 for i in self.mla_layers],
+                "head_dim": self.kda_head_dim, "num_heads": self.kda_heads,
+                "short_conv_kernel_size": self.kda_conv_taps})
+        return out
 
     @classmethod
     def from_source(cls, source: dict, **ours) -> "MLADecoderConfig":
-        """From a ``config.json`` of the source's shape; ``ours`` gives what
-        it does not say (``max_seq_len``, ``weights_dtype``, ...)."""
-        return cls(**{mine: source[theirs]
-                      for mine, theirs in cls._SOURCE_KEYS.items()}, **ours)
+        """From a ``config.json`` of the source's shape (JoyAI's, or
+        Kimi-Linear's where it holds ``linear_attn_config``); ``ours`` gives
+        what it does not say (``max_seq_len``, ``weights_dtype``, ...)."""
+        if "linear_attn_config" not in source:
+            return cls(**{mine: source[theirs]
+                          for mine, theirs in cls._SOURCE_KEYS.items()},
+                       **ours)
+        lin = source["linear_attn_config"]
+        layers = source["num_hidden_layers"]
+        routed = source.get("router_experts", source["num_experts"])
+        held = source["num_experts"]
+        kw = {mine: source[theirs]
+              for mine, theirs in cls._HYBRID_KEYS.items()}
+        kw.update(
+            n_routed_experts=routed,
+            experts_held=held if held < routed else 0,
+            q_lora_rank=source.get("q_lora_rank") or 0,
+            rope=not source.get("mla_use_nope", False),
+            mixers=tuple("kda" if i + 1 in lin["kda_layers"] else "mla"
+                         for i in range(layers)),
+            kda_heads=lin["num_heads"], kda_head_dim=lin["head_dim"],
+            kda_conv_taps=lin["short_conv_kernel_size"],
+            kda_gate_rank=source.get("kda_gate_rank", lin["head_dim"]))
+        kw.update(ours)
+        return cls(**kw)
 
 
 def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
     h, heads = cfg.hidden, cfg.num_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     p = f"dec_l{i}_"
-    specs = {
-        p + "attn_norm_scale": (h,),
-        p + "wq_a": (h, cfg.q_lora_rank),
-        p + "q_norm_scale": (cfg.q_lora_rank,),
-        p + "wq_b": (cfg.q_lora_rank, heads * qk),
-        p + "wkv_a": (h, cfg.latent_width),
-        p + "kv_norm_scale": (cfg.kv_lora_rank,),
-        p + "wkv_b": (cfg.kv_lora_rank,
-                      heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-        p + "wo": (heads * cfg.v_head_dim, h),
-        p + "ffn_norm_scale": (h,),
-    }
+    specs = {p + "attn_norm_scale": (h,)}
+    if i < cfg.num_layers and cfg.mixer(i) == "kda":
+        specs.update({p + name: shape
+                      for name, shape in _kda_specs(cfg).items()})
+    else:
+        if cfg.q_lora_rank:
+            specs.update({p + "wq_a": (h, cfg.q_lora_rank),
+                          p + "q_norm_scale": (cfg.q_lora_rank,),
+                          p + "wq_b": (cfg.q_lora_rank, heads * qk)})
+        else:
+            specs[p + "wq"] = (h, heads * qk)
+        specs.update({
+            p + "wkv_a": (h, cfg.latent_width),
+            p + "kv_norm_scale": (cfg.kv_lora_rank,),
+            p + "wkv_b": (cfg.kv_lora_rank,
+                          heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            p + "wo": (heads * cfg.v_head_dim, h),
+        })
+    specs[p + "ffn_norm_scale"] = (h,)
     if not moe:
         f = cfg.intermediate
         specs.update({p + "w_gate": (h, f), p + "w_up": (h, f),
                       p + "w_down": (f, h)})
         return specs
-    f, e = cfg.moe_intermediate, cfg.n_routed_experts
+    f, e, held = cfg.moe_intermediate, cfg.n_routed_experts, cfg.experts_here
     fs = f * cfg.n_shared_experts
     specs.update({
         p + "router": (h, e), p + "router_bias": (e,),
-        p + "experts_gate": (e, h, f), p + "experts_up": (e, h, f),
-        p + "experts_down": (e, f, h),
+        p + "experts_gate": (held, h, f), p + "experts_up": (held, h, f),
+        p + "experts_down": (held, f, h),
         p + "shared_gate": (h, fs), p + "shared_up": (h, fs),
         p + "shared_down": (fs, h),
     })
     return specs
+
+
+#: a KDA mixer's weights: the ``kda_mixer`` op's input slot of each
+_KDA_SLOTS = {"kda_wqkv": "WQKV", "kda_conv": "Conv", "kda_wfa": "WFA",
+              "kda_wfb": "WFB", "kda_a_log": "ALog", "kda_dt_bias": "DtBias",
+              "kda_wbeta": "WBeta", "kda_wga": "WGA", "kda_wgb": "WGB",
+              "kda_onorm_scale": "ONormScale"}
+
+
+def _kda_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
+    """``[q | k | v]`` in one projection, the convolution's taps a channel,
+    the decay's low-rank projection with ``A_log`` a head and ``dt_bias`` a
+    channel, the write strength, the output gate's low-rank projection, the
+    per-head output norm, and ``W_o``."""
+    h, heads, d, r = cfg.hidden, cfg.kda_heads, cfg.kda_head_dim, \
+        cfg.kda_gate_rank
+    return {"kda_wqkv": (h, 3 * heads * d),
+            "kda_conv": (3 * heads * d, cfg.kda_conv_taps),
+            "kda_wfa": (h, r), "kda_wfb": (r, heads * d),
+            "kda_a_log": (heads,), "kda_dt_bias": (heads * d,),
+            "kda_wbeta": (h, heads), "kda_wga": (h, r),
+            "kda_wgb": (r, heads * d), "kda_onorm_scale": (d,),
+            "wo": (heads * d, h)}
 
 
 def mla_param_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
@@ -224,6 +386,13 @@ def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
             w = np.ones(shape, np.float32)
         elif name.endswith("router_bias"):
             w = (0.01 * rng.randn(*shape)).astype(np.float32)
+        elif name.endswith("kda_a_log"):       # decay rates 1..16 a head
+            w = np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
+        elif name.endswith("kda_dt_bias"):     # softplus^-1 of 0.001..0.1
+            dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), shape))
+            w = (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+        elif name.endswith("kda_conv"):
+            w = (rng.randn(*shape) / np.sqrt(shape[-1])).astype(np.float32)
         elif name == "dec_embed":
             w = rng.randn(*shape).astype(np.float32)
         else:
@@ -296,19 +465,52 @@ class _MB:
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
         dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         part = "mla_part"
-        cq = self.norm(self.mm(hn, p + "wq_a", f"l{i}_cq", part),
-                       p + "q_norm_scale", f"l{i}_cqn", part)
-        q = b.reshape(self.mm(cq, p + "wq_b", f"l{i}_q", part),
-                      [-1, cfg.num_heads, dn + dr], f"l{i}_q3")
+        if cfg.q_lora_rank:
+            cq = self.norm(self.mm(hn, p + "wq_a", f"l{i}_cq", part),
+                           p + "q_norm_scale", f"l{i}_cqn", part)
+            q = self.mm(cq, p + "wq_b", f"l{i}_q", part)
+        else:
+            q = self.mm(hn, p + "wq", f"l{i}_q", part)
+        q = b.reshape(q, [-1, cfg.num_heads, dn + dr], f"l{i}_q3")
         q_nope, q_rope = self.split(q, [dn, dr], f"l{i}_qs")
-        q_rope = self.rope(q_rope, positions, f"l{i}_qr")
+        if cfg.rope:
+            q_rope = self.rope(q_rope, positions, f"l{i}_qr")
         c_kv, k_r = self.split(self.mm(hn, p + "wkv_a", f"l{i}_kva", part),
                                [cfg.kv_lora_rank, dr], f"l{i}_kvs")
         c_kv = self.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv", part)
+        if not cfg.rope:      # NoPE: dr more key lanes that all heads share
+            return q_nope, q_rope, c_kv, k_r
         k_r = b.reshape(self.rope(b.reshape(k_r, [-1, 1, dr], f"l{i}_kr3"),
                                   positions, f"l{i}_krr"),
                         [-1, dr], f"l{i}_kr")
         return q_nope, q_rope, c_kv, k_r
+
+    def kda(self, i, hn, mode, valid=None, state_slots=None,
+            last_index=None):
+        """Layer ``i``'s KDA mixer over the normed rows ``hn``: one op (the
+        projections, the convolution, the recurrence, the gated output
+        norm), its state and its convolution's tail in the layer's two slot
+        pools where the form caches."""
+        cfg, p = self.cfg, f"dec_l{i}_"
+        out = self.tmp(f"l{i}_kda")
+        ins = {"X": [hn]}
+        ins.update({slot: [p + name] for name, slot in _KDA_SLOTS.items()})
+        outs = {"Out": [out]}
+        if mode != "reference":
+            state, conv = (self.b.param(f"kda_{kind}_{i}", (),
+                                        dtype=VarType.FP32)
+                           for kind in ("state", "conv"))
+            ins.update({"Valid": [valid], "StateSlots": [state_slots],
+                        "State": [state], "ConvState": [conv]})
+            if last_index is not None:
+                ins["LastIndex"] = [last_index]
+            outs.update({"StateOut": [state], "ConvStateOut": [conv]})
+        self.op("kda_mixer", ins, outs,
+                {"mode": mode, "heads": int(cfg.kda_heads),
+                 "head_dim": int(cfg.kda_head_dim),
+                 "epsilon": float(cfg.rms_norm_eps),
+                 "l2_epsilon": float(cfg.kda_l2_eps)})
+        return out
 
     def pool(self, i, kv_dtype):
         return self.b.param(f"kv_lat_{i}", (), dtype=convert_dtype(kv_dtype))
@@ -325,16 +527,22 @@ class _MB:
         return {"v_head_dim": int(cfg.v_head_dim), "scale": float(
             (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)}
 
-    def block(self, i, hid, positions, attend, valid, counts, routes=None):
+    def block(self, i, hid, positions, attend, valid, counts, routes=None,
+              kda=None, absent=None):
         """One pre-norm block over rows ``hid`` (n, hidden).  ``attend``
         maps ``(i, q_nope, q_rope, c_kv, k_r)`` to the attention's output
-        (n, heads * dv); ``valid`` (or None) marks the rows that are real
+        (n, heads * dv), ``kda`` maps ``(i, normed rows)`` to a KDA layer's
+        mixer output; ``valid`` (or None) marks the rows that are real
         tokens; an expert layer appends its per-expert counts to
-        ``counts``."""
+        ``counts`` (and, where it holds a share of its experts, the number
+        of rows none of whose experts it holds to ``absent``)."""
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
-        hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an", "mla_part")
-        att = attend(i, *self.latents(i, hn, positions))
-        hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o", "mla_part"),
+        mix = "kda_part" if i < cfg.num_layers and cfg.mixer(i) == "kda" \
+            else "mla_part"
+        hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an", mix)
+        att = kda(i, hn) if mix == "kda_part" \
+            else attend(i, *self.latents(i, hn, positions))
+        hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o", mix),
                     f"l{i}_res1")
         dense = i < cfg.first_k_dense
         part = "dense_ffn" if dense else "moe_part"
@@ -358,7 +566,12 @@ class _MB:
                    "WDown": [p + "experts_down"]}
             if valid is not None:
                 ins["Valid"] = [valid]
-            self.op("moe_experts", ins, {"Out": [routed], "Counts": [cnt]})
+            outs = {"Out": [routed], "Counts": [cnt]}
+            if cfg.experts_here < cfg.n_routed_experts:
+                # this chip's share: the rows' other experts are elsewhere
+                outs["Absent"] = [self.tmp(f"l{i}_absent")]
+                absent.append(outs["Absent"][0])
+            self.op("moe_experts", ins, outs)
             counts.append(cnt)
             if routes is not None:
                 routes.append(idx)
@@ -407,6 +620,25 @@ def _decode_walk(feed, kv_config, *, verify: bool, heads: int, layers: int):
             "mla_decode_table_chunks": layers * spanned}
 
 
+def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
+    """``prog._srv_kernel_stats`` of a hybrid model's prefill and decode
+    forms: the KDA kernels' calls, and the real tokens (prefill) or live
+    sequences (decode: rows whose slot is not the padding's) they took,
+    summed over the KDA layers; beside ``_decode_walk``'s counts of the MLA
+    layers."""
+    kda = len(cfg.kda_layers)
+    if mode == "prefill":
+        return {"kda_prefill_calls": kda, "kda_prefill_tokens":
+                kda * (int(np.asarray(feed["last_index"])[0]) + 1)}
+    live = int((np.asarray(feed["state_slots"])
+                < kv_config.state_slots).sum())
+    out = {"kda_decode_calls": kda, "kda_decode_sequences": kda * live}
+    out.update(_decode_walk(feed, kv_config, verify=False,
+                            heads=cfg.num_heads,
+                            layers=len(cfg.mla_layers)) or {})
+    return out
+
+
 def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
     """One program form of the decoder: ``(program, feeds, fetches)``.
@@ -429,6 +661,10 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     if _sampled(sampling) and mode == "reference":
         raise ValueError("the reference form is the greedy oracle; "
                          "sampling applies to serving forms only")
+    hybrid = bool(cfg.kda_layers)
+    if hybrid and mode == "verify":
+        raise ValueError("a model with KDA layers builds no 'verify' form: "
+                         "a recurrent state cannot be rolled back")
     prog = Program()
     prog._label = mode
     m = _MB(prog, cfg)
@@ -467,6 +703,13 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
             seeds = b.feed("sample_seeds", (-1,), VarType.INT32)
             feeds.append("sample_seeds")
 
+    state_slots = None
+    if hybrid and mode != "reference":
+        # the slot of the sequence (a prompt) or of each row (a decode
+        # batch) in the KDA layers' pools; the padding's is the last
+        state_slots = b.feed("state_slots", (1,) if whole else (-1,),
+                             VarType.INT32)
+        feeds.append("state_slots")
     flat_tok = b.reshape(tokens, [-1], "tok_flat")
     flat_pos = b.reshape(positions, [-1], "pos_flat")
     hid = b.tmp("h0")
@@ -502,12 +745,26 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
 
     valid = None
     if mode != "reference":
-        valid = m.live_rows(slot_map, 0, kv_dtype)
+        valid = m.live_rows(slot_map, cfg.mla_layers[0], kv_dtype)
+
+    def kda(i, hn):
+        return m.kda(i, hn, mode if mode != "reference" else "reference",
+                     valid, state_slots,
+                     last_index if mode == "prefill" else None)
+
     counts: List[str] = []
     routes: List[str] = []
+    absent: List[str] = []
     for i in range(cfg.num_layers):
-        hid = m.block(i, hid, flat_pos, attend, valid, counts, routes)
+        hid = m.block(i, hid, flat_pos, attend, valid, counts, routes,
+                      kda=kda, absent=absent)
     prog._srv_hidden = hid
+    # (expert layers, rows, k): every row's routing, a prompt's too.  In a
+    # hybrid model a row's neighbours reach it undiluted (the convolution's
+    # taps, the fast-decaying channels of a state), so a check of the served
+    # logits follows the engine's routing on the prompt's rows as well
+    prog._srv_routes_all = m.stacked(routes, "token_routes_all") \
+        if hybrid and whole and routes else None
     if whole:
         last = b.tmp("hlast")
         m.op("gather", {"X": [hid], "Index": [last_index]}, {"Out": [last]},
@@ -535,7 +792,12 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     # layers, rows, k): the experts each emitting row was routed to
     prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
     prog._srv_routes = m.stacked(routes, "token_routes") if routes else None
-    if not whole:
+    # (expert layers,): the rows none of whose experts this chip holds
+    prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
+    if hybrid and mode != "reference":
+        prog._srv_kernel_stats = functools.partial(
+            _hybrid_walk, mode=mode, cfg=cfg)
+    elif not whole:
         prog._srv_kernel_stats = functools.partial(
             _decode_walk, verify=mode == "verify", heads=cfg.num_heads,
             layers=cfg.num_layers)

@@ -47,6 +47,7 @@ pipeline), and the paged DECODE program.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 from dataclasses import dataclass, field
@@ -1130,7 +1131,8 @@ class _EngineCore:
                  sample_seed: int = 0,
                  kv_dtype: Optional[str] = None,
                  kv_budget_mb: float = 0.0,
-                 tp: Optional[int] = None):
+                 tp: Optional[int] = None,
+                 max_batch: int = 0):
         from ..utils.flags import flag
 
         self.cfg = cfg
@@ -1189,6 +1191,19 @@ class _EngineCore:
             num_pages = max(1, int(kv_budget_mb * (1 << 20)) // page_bytes)
         self.kv_budget_mb = float(kv_budget_mb or 0.0)
         self.kv_config = cfg.kv_cache_config(num_pages, page_size, kv_dtype)
+        # a model with per-sequence state beside its pages (a recurrent
+        # layer) says what it keeps a slot (``state_pool_specs``); the one
+        # cache manager then hands a sequence a slot with its first pages.
+        # A slot a sequence of the engine's full batch (``max_batch``):
+        # fewer would only cap the batch, more would never be owned
+        self._state_specs = getattr(cfg, "state_pool_specs",
+                                    lambda n: {})(int(max_batch))
+        if self._state_specs:
+            if int(max_batch) < 1:
+                raise ValueError("this model keeps a state a sequence: the "
+                                 "core must be told the engine's max_batch")
+            self.kv_config = dataclasses.replace(
+                self.kv_config, state_slots=int(max_batch))
         self.kv = PagedKVCache(self.kv_config, prefix_cache=prefix_cache,
                                seed=prefix_seed)
         # what this model is not served with, refused here and loudly
@@ -1206,6 +1221,9 @@ class _EngineCore:
         self._score_calls: list = []                 # (score, routes) a call
         self._moe_stats: Dict[str, Dict[str, float]] = {}
         self._moe_pending: list = []                 # (phase, counts) a call
+        # (phase, rows with no expert here by expert layer) a call, where
+        # the expert layers hold a share of their experts
+        self._absent_pending: list = []
         self.moe_calls: Optional[list] = None   # a list: every call's counts
         # what a program's kernels report of their own work, summed by
         # phase (``prog._srv_kernel_stats``): host integers, no device read
@@ -1271,6 +1289,10 @@ class _EngineCore:
             pool = self.kv_config.make_scale_pool() if "_scale_" in name \
                 else self.kv_config.make_pool()
             self.scope.set(name, device_put_owned(pool, dev_of(name)))
+        for name, (shape, dtype) in self._state_specs.items():
+            # made on the device: a slot pool is gigabytes of zeros
+            with jax.default_device(dev_of(name)):
+                self.scope.set(name, jax.numpy.zeros(shape, dtype))
         if self.tp > 1:
             # engage-only telemetry (the flag-off registry is untouched):
             # the TP degree gauge plus each device's share of the pool
@@ -1502,6 +1524,9 @@ class _EngineCore:
             if "attn_mask" in self.prefill_feeds:
                 # a form that takes no mask builds it from the positions
                 feed["attn_mask"] = _causal_mask(S)
+            if self._state_specs:
+                feed["state_slots"] = np.array(
+                    [self.kv.state_slot(req.req_id)], np.int32)
             if self.sampling is not None:
                 feed["sample_seeds"] = np.array([self._lane(req)], np.int32)
         if span.recording:
@@ -1623,6 +1648,13 @@ class _EngineCore:
                 feed = {"tokens": toks, "positions": pos,
                         "block_tables": tables,
                         "context_lens": ctx, "slot_mapping": slot_map}
+                if self._state_specs:
+                    # padded rows carry the padding's slot, owned by none
+                    state = np.full(Bp, self.kv_config.pad_state_slot,
+                                    np.int32)
+                    state[:B] = [self.kv.state_slot(st.req.req_id)
+                                 for st in states]
+                    feed["state_slots"] = state
                 if self.sampling is not None:
                     lanes = np.zeros(Bp, np.int32)
                     for i, st in enumerate(states):
@@ -1744,8 +1776,12 @@ class _EngineCore:
             extras["score"] = prog._srv_score
             if getattr(prog, "_srv_routes", None):
                 extras["routes"] = prog._srv_routes
+            if getattr(prog, "_srv_routes_all", None):
+                extras["routes_all"] = prog._srv_routes_all
         if getattr(prog, "_srv_counts", None):
             extras["counts"] = prog._srv_counts
+            if getattr(prog, "_srv_absent", None):
+                extras["absent"] = prog._srv_absent
         if not extras and self.board is None:
             self.last = {}
             return self.exe.run(prog, feed=feed, fetch_list=fetch,
@@ -1756,9 +1792,12 @@ class _EngineCore:
         self.last = {k: t.value() for k, t in zip(extras, out[len(fetch):])}
         if "counts" in self.last:
             self._moe_pending.append((phase, self.last["counts"]))
+            if "absent" in self.last:
+                self._absent_pending.append((phase, self.last["absent"]))
         if "score" in self.last:
             self._score_calls.append((self.last["score"],
-                                      self.last.get("routes")))
+                                      self.last.get("routes"),
+                                      self.last.get("routes_all")))
         if self.board is not None:
             return [t.value() for t in out[:len(fetch)]]
         # the host waits for the device here: the executor's own fetch span
@@ -1769,9 +1808,9 @@ class _EngineCore:
     def _note_kernel_stats(self, phase: str, stats):
         if not stats:
             return
-        st = self.kernel_stats.setdefault(phase, dict.fromkeys(stats, 0))
+        st = self.kernel_stats.setdefault(phase, {})
         for key, value in stats.items():
-            st[key] += value
+            st[key] = st.get(key, 0) + value
             tm.counter(key, "a serving form's own count of its kernels' "
                        "work (calls, grid steps, the chunks its tables "
                        "span), summed over layers and calls",
@@ -1806,6 +1845,14 @@ class _EngineCore:
             return scores, None
         return scores, np.stack([host[c][1][:, i] for c, i in at])
 
+    def prompt_routes(self, req_id):
+        """The experts every row of ``req_id``'s prompt was routed to by
+        the prefill that served it, ``(expert layers, prompt rows, k)``;
+        None where the model's prefill form does not offer them."""
+        call = self.token_scores[req_id][0][0]      # its (last) prefill
+        rows = self._score_calls[call][2]
+        return None if rows is None else np.asarray(rows)
+
     @property
     def moe_stats(self) -> Dict[str, Dict[str, float]]:
         """By phase (``prefill``, ``decode``): expert layers run, experts
@@ -1821,11 +1868,16 @@ class _EngineCore:
         for phase, sums in self.expert_sums(calls).items():
             st = self._moe_stats.setdefault(phase, dict.fromkeys(sums, 0.0))
             for key, value in sums.items():
-                st[key] += value
+                st[key] = st.get(key, 0.0) + value
                 tm.counter("moe_" + key, "expert layers run / experts that "
                            "received a token / fullest expert's tokens over "
                            "the mean: summed over expert layers and calls",
                            labels=("phase",)).labels(phase=phase).inc(value)
+        absent, self._absent_pending = self._absent_pending, []
+        for phase, rows in absent:
+            st = self._moe_stats.setdefault(phase, {})
+            st["rows_all_absent"] = st.get("rows_all_absent", 0.0) \
+                + float(np.asarray(rows).sum())
         return self._moe_stats
 
     @staticmethod
@@ -1980,6 +2032,7 @@ class ServingEngine:
         self.sampling = sampling if _sampled(sampling) else None
         core_kw.setdefault("sampling", self.sampling)
         core_kw.setdefault("sample_seed", seed)
+        core_kw["max_batch"] = max_batch
         if model_dir is not None:
             self.core = _EngineCore.from_model_dir(model_dir, **core_kw)
         else:
@@ -1999,7 +2052,8 @@ class ServingEngine:
         if spec_k is None:
             spec_k = int(flag("spec_decode_k", 0) or 0)
         self.spec_k = max(int(spec_k), 0)
-        self.cfg.validate(prefill_chunk=self.prefill_chunk)
+        self.cfg.validate(prefill_chunk=self.prefill_chunk,
+                          spec_k=self.spec_k)
         if isinstance(proposer, str):
             proposer = get_proposer(proposer)
         self.proposer: Optional[Proposer] = \
@@ -2262,7 +2316,7 @@ class ServingEngine:
                 # fifo: index -1 (youngest); slo_aware: least lost work
                 victim = self.running.pop(
                     self.policy.victim_index(self.running))
-                self.kv.free_sequence(victim.req.req_id)
+                self.kv.free_sequence(victim.req.req_id, preempted=True)
                 victim.req.out_tokens = []
                 victim.req._tm_last = None
                 victim.req._tm_gaps = []
@@ -2364,7 +2418,7 @@ class ServingEngine:
             while self.running and not self._can_grow_all():
                 victim = self.running.pop(
                     self.policy.victim_index(self.running))
-                self.kv.free_sequence(victim.req.req_id)
+                self.kv.free_sequence(victim.req.req_id, preempted=True)
                 self._free_lanes.append(victim.lane)
                 victim.req.out_tokens = []
                 victim.req._tm_last = None

@@ -1,0 +1,374 @@
+"""Pallas TPU kernels of the gated delta-rule mixer (Kimi Delta Attention,
+arXiv:2510.26692 section 3), each beside the jnp composition that is its CPU
+fallback and its test oracle.
+
+A head keeps a state ``S`` of ``(d_k, d_v)`` float32 a sequence.  A token
+with key ``k``, value ``v``, query ``q`` (``k`` and ``q`` normalised by the
+caller), log-decay ``g <= 0`` per channel of ``d_k`` and write strength
+``beta`` does::
+
+    S' = Diag(exp(g)) S;   u = beta (v - S'^T k);   S = S' + k u^T;   o = S^T q
+
+* ``kda_recurrence`` — exactly that, one token at a time (``lax.scan``): the
+  oracle of both kernels and the CPU fallback.
+* ``kda_prefill`` — the same over one whole prompt in chunks of ``CHUNK``
+  tokens, a grid step a (head, chunk) with the head's state carried across
+  the chunks in VMEM.  Inside a chunk ``u`` solves ``(I + A) U = beta (V -
+  Kbar S_0)`` with ``A_ij = beta_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])``
+  for ``j < i`` (``G`` the running sum of ``g``), and ``o = Qbar S_0 + B U``
+  with ``B`` the same sum over ``q_i`` and ``j <= i``.  The textbook form
+  writes ``exp(G_i - G_j)`` as ``exp(G_i) exp(-G_j)``, whose second factor
+  overflows float32 under strong decay.  Here every pair ``j < i`` is split
+  at the boundary ``b`` of the smallest aligned power-of-two block that holds
+  both, ``exp(G_i - G_b) exp(G_b - G_j)``: both factors at most 1, and one
+  level of blocks is one matmul (``log2(CHUNK)`` levels).  The same levels
+  invert ``I + A`` block by block (``[[X, 0], [Y, Z]]^-1 = [[X^-1, 0],
+  [-Z^-1 Y X^-1, Z^-1]]``).  ``G``, the inverse and the state are held in
+  float32 and the solve ``U = T rhs`` is a float32 product; the operands of
+  the other matmuls are bfloat16, accumulated in float32 (``_SOLVE``).
+* ``kda_decode`` — one token a sequence against the state pool ``(slots + 1,
+  heads, d_k, d_v)``, rewritten in place (the pool aliased onto the output):
+  a grid step reads one sequence's states through its slot number, applies
+  the token and writes them back; nothing of pool size moves beside that.
+  Memory-bound: 2 x heads x d_k x d_v x 4 bytes a sequence and layer.
+* ``short_conv`` — the causal depthwise convolution of ``taps`` inputs a
+  channel (jnp: XLA fuses it), its decode step against the ``taps - 1``
+  inputs a sequence keeps.
+
+Engage rules follow ``mla_kernels``: kernel on TPU or under
+``PT_PALLAS_INTERPRET=1``, the jnp composition elsewhere.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_kernels import LANES, _interpret, _use_pallas, own_jit
+
+#: tokens one ``kda_prefill`` grid step takes (a power of two).  Measured on
+#: the chip (PR 36, one layer's call over 8,192 tokens, 32 heads of 128): 64
+#: tokens a step 14.4 ms, 128 11.5, 32 21.0 (a step's fixed cost, and at 64
+#: the chunk's square matrices fill half the lanes)
+CHUNK = 128
+_HI = lax.Precision.HIGHEST
+#: the precision of the products that build the triangular inverse level by
+#: level (float32 operands; DEFAULT is one bfloat16 pass on the MXU, HIGHEST
+#: six).  Measured on the chip (PR 36, same call): HIGHEST 11.5 ms, DEFAULT
+#: 7.2, and against the token-by-token recurrence the output moves from
+#: 3.5e-4 to 4.3e-4 of 0.073 and the state from 2.7e-3 to 3.1e-3: the
+#: inverse's own operand ``A`` is a product of bfloat16 operands already.
+#: The solve itself (``U = T rhs``) and the running sum of the decay stay at
+#: HIGHEST
+_SOLVE = lax.Precision.DEFAULT
+_NT = (((1,), (1,)), ((), ()))       # x @ y^T
+_TN = (((0,), (0,)), ((), ()))       # x^T @ y
+
+
+# ==========================================================================
+# the recurrence: oracle and CPU fallback
+# ==========================================================================
+def kda_recurrence(q, k, v, g, beta, state):
+    """``q``, ``k``, ``g`` (t, heads, d_k), ``v`` (t, heads, d_v), ``beta``
+    (t, heads), ``state`` (heads, d_k, d_v): one token at a time.  Returns
+    ``(o (t, heads, d_v), state)``, float32."""
+    f32 = jnp.float32
+
+    def step(s, x):
+        qt, kt, vt, gt, bt = x
+        s = jnp.exp(gt)[..., None] * s
+        u = bt[:, None] * (vt - jnp.einsum("hkv,hk->hv", s, kt,
+                                           precision=_HI))
+        s = s + kt[..., None] * u[:, None, :]
+        return s, jnp.einsum("hkv,hk->hv", s, qt, precision=_HI)
+
+    state, o = lax.scan(step, state.astype(f32), tuple(
+        x.astype(f32) for x in (q, k, v, g, beta)))
+    return o, state
+
+
+# ==========================================================================
+# kda_prefill
+# ==========================================================================
+def normalised_heads(qkv, heads: int, l2_eps: float):
+    """``qkv`` (n, 3 heads d), the convolution's outputs after SiLU, to ``q``
+    (l2-normalised a head, scaled by ``d^-1/2``), ``k`` (l2-normalised) and
+    ``v``, each ``(n, heads, d)`` float32."""
+    q, k, v = (t.reshape(t.shape[0], heads, -1)
+               for t in jnp.split(qkv.astype(jnp.float32), 3, axis=-1))
+
+    def unit(x):
+        return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + l2_eps)
+
+    return unit(q) * q.shape[-1] ** -0.5, unit(k), v
+
+
+def _kda_prefill_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref,
+                        st_ref, *, chunk, l2_eps):
+    """Grid step ``(h, c)``: chunk ``c`` of head ``h``.  ``q_ref``, ``k_ref``
+    and ``v_ref`` are the head's columns of the convolution's output (before
+    the l2 norm), ``g_ref`` its log-decay a token, ``b_ref`` the chunk's
+    write strengths, a lane a head.  ``st_ref`` (d_v, d_k) is the head's
+    state transposed, so that a decay per channel of ``d_k`` scales lanes."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    h, c = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _fresh():
+        st_ref[...] = jnp.zeros(st_ref.shape, f32)
+
+    def unit(x):
+        return x * lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + l2_eps)
+
+    q = unit(q_ref[...].astype(f32)) * q_ref.shape[1] ** -0.5
+    k = unit(k_ref[...].astype(f32))
+    b = b_ref[...].astype(f32)
+    lane = lax.broadcasted_iota(jnp.int32, b.shape, 1)
+    beta = jnp.sum(jnp.where(lane == h, b, 0.0), axis=1, keepdims=True)
+    kb, vb = k * beta, v_ref[...].astype(f32) * beta
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # the running sum of the log-decay inside the chunk
+    G = jnp.dot((col <= row).astype(f32), g_ref[...].astype(f32),
+                precision=_HI, preferred_element_type=f32)
+    tok = lax.broadcasted_iota(jnp.int32, G.shape, 0)
+    G16 = G.astype(bf16)
+    k16 = k.astype(bf16)
+
+    # the diagonal of B: q_i . k_i, no decay between a token and itself
+    B = jnp.where(row == col, lax.dot_general(
+        q.astype(bf16), k16, _NT, preferred_element_type=f32), 0.0)
+    T = (row == col).astype(f32)
+    level, half = 1, 1
+    while half < chunk:
+        # the boundary of each token's block at this level: the last token
+        # of the block's left half.  Any value near G there will do (it
+        # cancels in the product), so bfloat16 rows picked by a one-hot
+        # matmul are enough
+        bound = ((row >> level) << level) + (half - 1)
+        ref = jnp.dot((col == bound).astype(bf16), G16,
+                      preferred_element_type=f32)
+        right = (tok & half) != 0
+        E = jnp.exp(jnp.where(right, G - ref, ref - G))
+        lhs = jnp.concatenate([kb * E, q * E], axis=0).astype(bf16)
+        P = lax.dot_general(lhs, (k * E).astype(bf16), _NT,
+                            preferred_element_type=f32)
+        pair = ((row >> level) == (col >> level)) & ((row & half) != 0) \
+            & ((col & half) == 0)
+        A = jnp.where(pair, P[:chunk], 0.0)
+        B = B + jnp.where(pair, P[chunk:], 0.0)
+        if level == 1:
+            T = T - A
+        else:
+            T = T - jnp.dot(jnp.dot(T, A, precision=_SOLVE,
+                                    preferred_element_type=f32),
+                            T, precision=_SOLVE, preferred_element_type=f32)
+        level, half = level + 1, half * 2
+
+    st = st_ref[...]                                    # (d_v, d_k)
+    st16 = st.astype(bf16)
+    decay = jnp.exp(G)
+    rhs = vb - lax.dot_general((kb * decay).astype(bf16), st16, _NT,
+                               preferred_element_type=f32)
+    U = jnp.dot(T, rhs, precision=_HI, preferred_element_type=f32)
+    U16 = U.astype(bf16)
+    o = lax.dot_general((q * decay).astype(bf16), st16, _NT,
+                        preferred_element_type=f32) \
+        + jnp.dot(B.astype(bf16), U16, preferred_element_type=f32)
+    o_ref[...] = o.astype(o_ref.dtype)
+    last = G[chunk - 1:chunk, :]                        # (1, d_k)
+    st = st * jnp.exp(last) + lax.dot_general(
+        U16, (k * jnp.exp(last - G)).astype(bf16), _TN,
+        preferred_element_type=f32)
+    st_ref[...] = st
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _out():
+        s_ref[0] = st.T
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "chunk", "l2_eps"))
+def _kda_prefill_call(qkv, g, beta, *, heads, chunk, l2_eps):
+    t = qkv.shape[0]
+    d = qkv.shape[1] // (3 * heads)
+    f32 = jnp.float32
+    n = -(-t // chunk)
+    pad = n * chunk - t
+    qkv, g, beta = qkv.astype(f32), g.astype(f32).reshape(t, heads * d), \
+        beta.astype(f32)
+    if pad:       # rows that decay nothing and write nothing
+        qkv, g, beta = (jnp.pad(x, ((0, pad), (0, 0)))
+                        for x in (qkv, g, beta))
+
+    def head(offset):
+        return pl.BlockSpec((chunk, d), lambda h, c: (c, offset + h))
+
+    o, state = pl.pallas_call(
+        functools.partial(_kda_prefill_kernel, chunk=chunk, l2_eps=l2_eps),
+        name="kda_prefill",
+        grid=(heads, n),
+        # q, k and v: three views of the one array, a head's columns each
+        in_specs=[head(0), head(heads), head(2 * heads), head(0),
+                  pl.BlockSpec((chunk, heads), lambda h, c: (c, 0))],
+        out_specs=[head(0),
+                   pl.BlockSpec((1, d, d), lambda h, c: (h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n * chunk, heads * d), f32),
+                   jax.ShapeDtypeStruct((heads, d, d), f32)],
+        scratch_shapes=[pltpu.VMEM((d, d), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+    )(qkv, qkv, qkv, g, beta)
+    return o[:t].reshape(t, heads, d), state
+
+
+def _pick_chunk(t: int) -> int:
+    c = CHUNK
+    while c > 8 and c > t:
+        c //= 2
+    return c
+
+
+def prefill_engages(d: int) -> bool:
+    return _use_pallas() and (_interpret() or d % LANES == 0)
+
+
+def kda_prefill(qkv, g, beta, heads: int, l2_eps: float = 1e-6):
+    """One whole prompt from an empty state: ``qkv`` (t, 3 heads d) the
+    convolution's outputs after SiLU (``[q | k | v]``, before the l2 norm),
+    ``g`` (t, heads, d) the log-decay, ``beta`` (t, heads).  Returns ``(o (t,
+    heads, d), state (heads, d, d))`` float32.  Rows past the prompt are
+    given ``g = 0`` and ``beta = 0`` by the caller and leave the state as the
+    last real token left it."""
+    t = qkv.shape[0]
+    d = qkv.shape[1] // (3 * heads)
+    if prefill_engages(d):
+        return own_jit(_kda_prefill_call)(
+            qkv, g, beta, heads=heads, chunk=_pick_chunk(t),
+            l2_eps=float(l2_eps))
+    with jax.named_scope("kda_prefill"):
+        q, k, v = normalised_heads(qkv, heads, l2_eps)
+        return kda_recurrence(q, k, v, g, beta,
+                              jnp.zeros((heads, d, d), jnp.float32))
+
+
+# ==========================================================================
+# kda_decode
+# ==========================================================================
+def kda_decode_reference(pool, slots, q, k, v, g, beta):
+    """Gather, one step of the recurrence, scatter: ``pool`` (slots + 1,
+    heads, d_k, d_v), ``slots`` (rows,), the rest (rows, heads, .).  Returns
+    ``(o (rows, heads, d_v), pool)``."""
+    f32 = jnp.float32
+    s = jnp.exp(g.astype(f32))[..., None] * pool[slots]
+    u = beta.astype(f32)[..., None] * (v.astype(f32) - jnp.einsum(
+        "bhkv,bhk->bhv", s, k.astype(f32), precision=_HI))
+    s = s + k.astype(f32)[..., None] * u[:, :, None, :]
+    o = jnp.einsum("bhkv,bhk->bhv", s, q.astype(f32), precision=_HI)
+    return o, pool.at[slots].set(s)
+
+
+def _kda_decode_kernel(slot_ref, cols_ref, v_ref, s_in, o_ref, s_out, *,
+                       heads):
+    """Grid step ``b``: every head's state of sequence ``b`` (block index
+    ``slot[b]``).  ``cols_ref`` (d_k, 4 heads) holds, a column a head, ``k``,
+    ``exp(g)``, ``q`` and ``beta k``: what scales the ROWS of a state, laid
+    so that a column broadcasts along lanes."""
+    del slot_ref
+    cols = cols_ref[0]
+    for h in range(heads):
+        kc = cols[:, h:h + 1]
+        ac = cols[:, heads + h:heads + h + 1]
+        qc = cols[:, 2 * heads + h:2 * heads + h + 1]
+        bk = cols[:, 3 * heads + h:3 * heads + h + 1]
+        s = ac * s_in[0, h]                              # (d_k, d_v)
+        u = v_ref[0, h:h + 1, :] - jnp.sum(s * kc, axis=0, keepdims=True)
+        s = s + bk * u
+        o_ref[0, h:h + 1, :] = jnp.sum(s * qc, axis=0, keepdims=True)
+        s_out[0, h] = s
+
+
+@jax.jit
+def _kda_decode_call(pool, slots, q, k, v, g, beta):
+    rows, heads, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    k32, b = k.astype(f32), beta.astype(f32)[..., None]
+    # (rows, d_k, 4 heads): a column a head of k | exp(g) | q | beta k
+    cols = jnp.concatenate([k32, jnp.exp(g.astype(f32)), q.astype(f32),
+                            k32 * b], axis=1).transpose(0, 2, 1)
+    state = pl.BlockSpec((1, heads, dk, dv), lambda i, slot: (slot[i], 0, 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_kda_decode_kernel, heads=heads),
+        name="kda_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows,),
+            in_specs=[pl.BlockSpec((1, dk, 4 * heads),
+                                   lambda i, slot: (i, 0, 0)),
+                      pl.BlockSpec((1, heads, dv), lambda i, slot: (i, 0, 0)),
+                      state],
+            out_specs=[pl.BlockSpec((1, heads, dv),
+                                    lambda i, slot: (i, 0, 0)), state]),
+        out_shape=[jax.ShapeDtypeStruct((rows, heads, dv), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={3: 1},      # after the prefetch operand
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+    )(slots.astype(jnp.int32), cols, v.astype(f32), pool)
+    return o, pool
+
+
+def decode_engages(dk: int, dv: int) -> bool:
+    return dk == dv and prefill_engages(dk)
+
+
+def kda_decode(pool, slots, q, k, v, g, beta):
+    """One token a row against the states at ``slots`` of ``pool``, in
+    place.  A padded row carries the pad slot (the pool's last), ``g = 0``
+    and ``beta = 0``.  Returns ``(o, pool)``."""
+    if decode_engages(q.shape[-1], v.shape[-1]):
+        return own_jit(_kda_decode_call)(pool, slots, q, k, v, g, beta)
+    with jax.named_scope("kda_decode"):
+        return kda_decode_reference(pool, slots.astype(jnp.int32), q, k, v,
+                                    g, beta)
+
+
+# ==========================================================================
+# short_conv
+# ==========================================================================
+def short_conv(x, w):
+    """Causal depthwise convolution of one sequence: ``x`` (t, channels),
+    ``w`` (channels, taps); ``y_t = sum_j w[:, j] x_{t - taps + 1 + j}``,
+    zeros before the sequence.  float32."""
+    taps = w.shape[1]
+    t = x.shape[0]
+    with jax.named_scope("short_conv"):
+        xp = jnp.pad(x.astype(jnp.float32), ((taps - 1, 0), (0, 0)))
+        w = w.astype(jnp.float32)
+        return sum(xp[j:j + t] * w[:, j] for j in range(taps))
+
+
+def short_conv_tail(x, last_index, taps: int):
+    """The ``taps - 1`` inputs that end at row ``last_index`` (zeros before
+    the sequence): what the next token's convolution needs."""
+    xp = jnp.pad(x.astype(jnp.float32), ((taps - 1, 0), (0, 0)))
+    return lax.dynamic_slice_in_dim(xp, last_index + 1, taps - 1, axis=0)
+
+
+def short_conv_step(tail, x, w):
+    """One token a row: ``tail`` (rows, taps - 1, channels) the inputs kept,
+    ``x`` (rows, channels) the new one.  Returns ``(y (rows, channels), the
+    new tail)``."""
+    with jax.named_scope("short_conv"):
+        window = jnp.concatenate(
+            [tail, x.astype(jnp.float32)[:, None, :]], axis=1)
+        y = jnp.einsum("btc,ct->bc", window, w.astype(jnp.float32),
+                       precision=_HI)
+        return y, window[:, 1:]

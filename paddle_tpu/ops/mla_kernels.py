@@ -57,7 +57,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_kernels import DEFAULT_MASK_VALUE, LANES, _interpret, _use_pallas
+from .pallas_kernels import (DEFAULT_MASK_VALUE, LANES, _interpret,
+                             _use_pallas, own_jit)
 
 #: pages one ``mla_decode`` grid step scores (1,024 tokens at 16 a page).
 #: Measured on the chip (PR 33, 126 rows at 2.7 k of context): a step that
@@ -314,10 +315,10 @@ def mla_decode(q_lat, q_rope, pool, block_tables, context_lens, scale):
     n, heads, _ = q_lat.shape
     page_size = pool.shape[2]
     if decode_engages(page_size, heads):
-        return _mla_decode_call(q_lat, q_rope, pool, block_tables,
-                                context_lens, scale=float(scale),
-                                step=DECODE_PAGES_PER_STEP,
-                                fetch=DECODE_PAGES_PER_FETCH)
+        return own_jit(_mla_decode_call)(
+            q_lat, q_rope, pool, block_tables, context_lens,
+            scale=float(scale), step=DECODE_PAGES_PER_STEP,
+            fetch=DECODE_PAGES_PER_FETCH)
     if block_tables.shape[0] != n:
         block_tables = jnp.repeat(block_tables, n // block_tables.shape[0],
                                   axis=0)

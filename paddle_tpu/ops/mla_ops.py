@@ -130,15 +130,22 @@ def _moe_router(ctx):
     ctx.set_out("Weight", w)
 
 
-def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None):
+def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
+                    share: bool = False):
     """The routed experts over the tokens each received.  ``x`` (n, h);
     ``idx``/``weight`` (n, k); expert weights ``(experts, h, f)`` twice and
     ``(experts, f, h)``; ``valid`` (n,) bool, rows that are padding route
-    nowhere.  Returns ``(y (n, h), counts (experts,) int32)``."""
+    nowhere.  ``share``: the weights are experts ``0 .. experts - 1`` of a
+    layer that routes over more (one chip's share of an expert-parallel
+    layer): a choice past them is another chip's and adds nothing here, its
+    weight is spent all the same.  Returns ``(y (n, h), counts (experts,)
+    int32)``."""
     n, k = idx.shape
     experts = w_gate.shape[0]
     with jax.named_scope("moe_dispatch"):
         flat = idx.reshape(-1)
+        if share:
+            flat = jnp.where(flat < experts, flat, experts)
         if valid is not None:
             flat = jnp.where(jnp.repeat(valid, k), flat, experts)
         order = jnp.argsort(flat).astype(jnp.int32)
@@ -163,13 +170,23 @@ def _moe_experts(ctx):
     """X ``(n, hidden)``, Idx/Weight from ``moe_router``, WGate/WUp
     ``(experts, hidden, f)``, WDown ``(experts, f, hidden)``, optional
     Valid ``(n,)`` (non-zero: a real token) -> Out ``(n, hidden)``, Counts
-    ``(experts,)`` int32 tokens each expert received."""
+    ``(experts,)`` int32 tokens each expert received.  With the output
+    Absent (a scalar: rows with no expert here) the weights are a SHARE of
+    the layer's experts, the first ``experts`` of those Idx ranges over."""
     valid = ctx.in_("Valid") != 0 if ctx.has_input("Valid") else None
+    share = ctx.has_output("Absent")
+    idx = ctx.in_("Idx")
     y, counts = experts_forward(
-        ctx.in_("X"), ctx.in_("Idx"), ctx.in_("Weight"), ctx.in_("WGate"),
-        ctx.in_("WUp"), ctx.in_("WDown"), valid)
+        ctx.in_("X"), idx, ctx.in_("Weight"), ctx.in_("WGate"),
+        ctx.in_("WUp"), ctx.in_("WDown"), valid, share)
     ctx.set_out("Out", y)
     ctx.set_out("Counts", counts)
+    if share:
+        # the rows (real tokens) none of whose experts are held here
+        none = jnp.all(idx >= counts.shape[0], axis=-1)
+        if valid is not None:
+            none = none & valid
+        ctx.set_out("Absent", jnp.sum(none).astype(jnp.int32))
 
 
 def mla_expanded_attention(q_nope, q_rope, c_kv, k_r, w_kvb, v_dim: int,

@@ -50,6 +50,15 @@ def _use_pallas() -> bool:
     return _interpret() or is_compiled_with_tpu()
 
 
+def own_jit(call):
+    """A kernel's jitted wrapper, which the layers of a program share (a
+    kernel's trace is Python time a shape and layer otherwise); interpreted
+    (tests, a rehearsal) the plain function, traced into its caller: a jit
+    of its own hides the kernel's name from the lowered text, where a
+    rehearsal looks for it."""
+    return call.__wrapped__ if _interpret() else call
+
+
 def _pick_block(seq: int, candidates=(512, 256, 128)) -> int | None:
     env = os.environ.get("PT_FLASH_BLOCK")
     if env:

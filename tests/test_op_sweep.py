@@ -989,6 +989,7 @@ COVERED_ELSEWHERE = {
     "swiglu": "test_mla_decoder",
     "moe_router": "test_mla_decoder",
     "moe_experts": "test_mla_decoder",
+    "kda_mixer": "test_hybrid_decoder",
     "mla_prefill_attention": "test_mla_decoder",
     "mla_paged_attention": "test_mla_decoder",
     "latent_cache_append": "test_mla_decoder",

@@ -226,6 +226,57 @@ def test_mla_decode_compiles_for_v5e(rows, tables, width, v5e):
     assert " conditional(" not in text
 
 
+# --- the KDA kernels at Kimi-Linear's widths: 32 heads of 128 ----------------
+KDA_POOL = (129, 32, 128, 128)    # the cell's state pool a layer, float32
+
+
+@pytest.mark.parametrize("tokens", [8192, 2048, 16])
+def test_kda_prefill_compiles_for_v5e(tokens, v5e):
+    """The chunked kernel as the chip's compiler takes it: float32 matmuls
+    at the highest precision (the triangular inverse), a transposed-left
+    product (the state's update) and a transpose of the state, one Mosaic
+    call a layer."""
+    from paddle_tpu.ops import kda_kernels as kk
+
+    def f(qkv, g, beta):
+        return kk._kda_prefill_call(qkv, g, beta, heads=32,
+                                    chunk=kk._pick_chunk(tokens), l2_eps=1e-6)
+
+    text = _compile(f, v5e, ((tokens, 3 * 4096), jnp.float32),
+                    ((tokens, 32, 128), jnp.float32),
+                    ((tokens, 32), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("rows", [128, 1])
+def test_kda_decode_rewrites_its_pool_in_place_on_v5e(rows, v5e):
+    """The decode step against the cell's state pool, donated: the chip's
+    compiler holds ``f32[129,32,128,128]`` row-major in exact tiles by its
+    own choice, the kernel's output aliases it, and the program holds no
+    other result of the pool's size (no copy, no re-layout)."""
+    from paddle_tpu.ops import kda_kernels as kk
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in (
+        (KDA_POOL, jnp.float32), ((rows,), jnp.int32),
+        ((rows, 32, 128), jnp.float32), ((rows, 32, 128), jnp.float32),
+        ((rows, 32, 128), jnp.float32), ((rows, 32, 128), jnp.float32),
+        ((rows, 32), jnp.float32))]
+    text = jax.jit(kk._kda_decode_call, donate_argnums=0) \
+        .lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    pool = "f32[129,32,128,128]"
+    made = [line for line in text.splitlines()
+            if f" = {pool}" in line or f" = ({pool}" in line
+            or f", {pool}" in line.partition(" = ")[2].split("(")[0]]
+    held = [line for line in made if " parameter(" in line]
+    assert held and all("{3,2,1,0:T(8,128)}" in line for line in held)
+    others = [line for line in made if " parameter(" not in line
+              and "tpu_custom_call" not in line
+              and "get-tuple-element" not in line and " tuple(" not in line]
+    assert not others, others[:2]
+    assert "may-alias" in text or "must-alias" in text
+
+
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("head_dim,page_size,decode_copies", [
     # head_dim under the 128 lanes, a page whole tiles: stored lane-full,
