@@ -226,6 +226,39 @@ class Watch:
         return texts
 
 
+def gmm_calls_and_readers(text):
+    """Of one compiled program: how many ``moe_gmm`` custom calls it holds,
+    and the instructions that read a call's output and are neither the
+    next call nor the combine's gather (a select over the whole output,
+    say), each as its line's first 160 characters."""
+    lines = [line.strip() for line in text.splitlines()]
+    calls = [m.group(1) for m in (
+        re.match(r"(?:ROOT )?%(moe_gmm[\w.\-]*) = .* custom-call\(", line)
+        for line in lines) if m]
+    reads = re.compile("|".join(rf"%{re.escape(c)}[,)]" for c in calls))
+    strangers = [
+        line[:160] for line in lines if calls and reads.search(line)
+        and not re.match(r"(?:ROOT )?%moe_gmm[\w.\-]* = ", line)
+        and not ("moe_combine" in line and "gather" in line)]
+    return len(calls), strangers
+
+
+def gmm_walk(phase, eng):
+    """What the engine's prefills' grouped matmuls walked, from its own
+    count: two calls an expert layer, and the (row tile, expert) visits
+    over the row tiles that hold a routed row."""
+    eng.core.moe_stats              # folds the calls' tokens per expert
+    walk = {key: value for key, value in
+            eng.stats["kernels"].get("prefill", {}).items()
+            if key.startswith("moe_gmm_")}
+    if not walk.get("moe_gmm_row_tiles"):
+        raise RuntimeError(f"{phase}: the prefill form counted no moe_gmm "
+                           f"call: {eng.stats['kernels']}")
+    walk["visits_over_row_tiles"] = (walk["moe_gmm_visits"]
+                                     / walk["moe_gmm_row_tiles"])
+    return walk
+
+
 def pool_forms(stored, head_dim):
     """The dims an array the size of a KV pool can show in a compiled
     program, first the shape the pool is stored in (``KVCacheConfig.
@@ -376,6 +409,29 @@ class Ctx:
                 raise RuntimeError(
                     f"{phase}: {name} holds {calls} call(s) of {kernel}, "
                     f"not one a layer ({layers}), or holds a conditional")
+            most = max(most, calls)
+        return most
+
+    def require_gmm_calls(self, phase, expert_layers, before):
+        """Every program compiled since ``before`` (the names
+        ``compiled_texts`` had as the phase began) that holds ``moe_gmm``
+        runs it twice an expert layer (gate-and-up, down: ``expert_layers``
+        names the depths the phase's programs have) and what a call returns
+        is read by the next call or by the combine's gather alone: no
+        operation of the kernel's output size between them.  Returns the
+        most calls found in a program (0 where kernels are interpreted)."""
+        most = 0
+        for name, text in self.watch.compiled_texts().items():
+            if name in before:              # an earlier phase's program
+                continue
+            calls, strangers = gmm_calls_and_readers(text)
+            if calls and (calls not in [2 * n for n in expert_layers]
+                          or strangers):
+                raise RuntimeError(
+                    f"{phase}: {name} holds {calls} call(s) of moe_gmm, not "
+                    f"two an expert layer ({expert_layers}), or reads a "
+                    f"call's output by other than the next call or the "
+                    f"combine's gather: {strangers[:3]}")
             most = max(most, calls)
         return most
 
@@ -826,6 +882,7 @@ def phase_mla(ctx):
     cc.reset_cache()
     try:
         mark = ctx.watch.mark()
+        compiled_before = set(ctx.watch.compiled_texts())
         plain = engine()
         reqs = drive(plain, prompts + [long_prompt])
         seen, modules = ctx.watch.since(mark)
@@ -836,6 +893,9 @@ def phase_mla(ctx):
             n_pools=len(cfg.cache_pool_names()), append="latent_append")
         decode_calls = ctx.require_one_call_a_layer(
             phase, "mla_decode", (cfg.num_layers, cfg.mtp_layers))
+        gmm_calls = ctx.require_gmm_calls(
+            phase, (cfg.num_layers - cfg.first_k_dense, cfg.mtp_layers),
+            compiled_before)
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         cc.reset_cache()
@@ -864,6 +924,7 @@ def phase_mla(ctx):
         raise RuntimeError(
             f"{phase}: mla_decode's grid ran {grid_share:.3f} of the chunks "
             f"its tables span, over {MLA_GRID_OVER_TABLES_MOST}: {walk}")
+    gmm = gmm_walk(phase, plain)
     del plain
     gc.collect()
     # pipelined steps (tokens stay on the device between calls): the same
@@ -902,6 +963,7 @@ def phase_mla(ctx):
         new_tokens=size["new_tokens"], scheduler=spec_eng.stats, **seen, kernel_calls=kernels, **in_place,
         mla_decode_calls_a_program=decode_calls, mla_decode_walk=walk,
         mla_decode_grid_over_tables=grid_share,
+        moe_gmm_calls_a_program=gmm_calls, moe_gmm_prefill_walk=gmm,
         served_logits_worst_gap=worst, route_slack=slack,
         mtp_logits_worst_gap=mtp_gap,
         drafter="tokens identical with the drafter on and off",
@@ -957,6 +1019,7 @@ def phase_hybrid(ctx):
     cc.reset_cache()
     try:
         mark = ctx.watch.mark()
+        compiled_before = set(ctx.watch.compiled_texts())
         plain, reqs = drive()
         seen, modules = ctx.watch.since(mark)
         kernels = ctx.require_kernels(
@@ -967,6 +1030,8 @@ def phase_hybrid(ctx):
             n_pools=len(cfg.cache_pool_names()), append="latent_append")
         state = ctx.require_state_in_place(
             phase, cfg.state_pool_specs(size["max_batch"]))
+        gmm_calls = ctx.require_gmm_calls(
+            phase, (cfg.num_layers - cfg.first_k_dense,), compiled_before)
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         cc.reset_cache()
@@ -986,6 +1051,7 @@ def phase_hybrid(ctx):
             f"{phase}: served logits lie {worst} from the reference (limit "
             f"{HYBRID_LOGIT_ABS_TOL}), routing slack {slack} (limit "
             f"{HYBRID_ROUTE_SLACK_TOL})")
+    gmm = gmm_walk(phase, plain)
     stats, slots = plain.stats, plain.kv.stats()["state_slots"]
     del plain
     gc.collect()
@@ -1000,6 +1066,7 @@ def phase_hybrid(ctx):
         num_pages=size["num_pages"], prompts=size["prompts"],
         new_tokens=size["new_tokens"], scheduler=stats, state_slots=slots,
         **seen, kernel_calls=kernels, **in_place, **state,
+        moe_gmm_calls_a_program=gmm_calls, moe_gmm_prefill_walk=gmm,
         served_logits_worst_gap=worst, route_slack=slack,
         pipeline="tokens identical with pipelined steps on and off",
         **ctx.memory())

@@ -639,6 +639,41 @@ def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
     return out
 
 
+def _gmm_walk(counts, *, rows: int):
+    """What a call's ``moe_gmm`` kernels walked, from the tokens each expert
+    received (``counts``, (expert layers, experts): the call's own
+    ``_srv_counts``, on the host once ``moe_stats`` reads them) and the rows
+    the call's dispatch sorts (token rows times ``k``), by the tile the
+    kernel's wrapper uses (``mla_kernels.gmm_walk_counts``).  An expert
+    layer's two calls walk the same list: the row tiles that hold a row an
+    expert owns and the (row tile, expert) visits, summed over them."""
+    walked = [mla_kernels.gmm_walk_counts(sizes, rows) for sizes in counts]
+    return {"moe_gmm_calls": 2 * len(walked),
+            "moe_gmm_row_tiles": 2 * sum(t for t, _ in walked),
+            "moe_gmm_visits": 2 * sum(v for _, v in walked)}
+
+
+def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
+               routed: bool):
+    """``prog._srv_kernel_stats`` of a serving form: what its attention and
+    state kernels walk, from the feed (:func:`_decode_walk`,
+    :func:`_hybrid_walk`), and under ``from_counts`` what its grouped
+    matmuls will have walked, a function of the call's expert counts
+    (:func:`_gmm_walk`), where the form routes and the kernel engages."""
+    if cfg.kda_layers:
+        out = _hybrid_walk(feed, kv_config, mode=mode, cfg=cfg)
+    elif mode == "prefill":
+        out = {}
+    else:
+        out = _decode_walk(feed, kv_config, verify=mode == "verify",
+                           heads=cfg.num_heads, layers=cfg.num_layers) or {}
+    if routed and mla_kernels.gmm_engages(cfg.hidden, cfg.moe_intermediate):
+        out["from_counts"] = functools.partial(
+            _gmm_walk,
+            rows=int(np.size(feed["tokens"])) * cfg.num_experts_per_tok)
+    return out
+
+
 def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
     """One program form of the decoder: ``(program, feeds, fetches)``.
@@ -646,8 +681,8 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     hook), ``_srv_hidden`` (the rows' last hidden state before the final
     norm: what the MTP drafter consumes), ``_srv_counts`` (tokens per
     expert by expert layer) and ``_srv_score`` (each emitted token's logit
-    and the row's log-sum-exp, two floats a row); the decode and verify
-    forms ``_srv_kernel_stats`` (:func:`_decode_walk`)."""
+    and the row's log-sum-exp, two floats a row); the serving forms
+    ``_srv_kernel_stats`` (:func:`_form_walk`)."""
     from .serving import _emit_head, _sampled
 
     if mode == "chunk":
@@ -794,13 +829,9 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     prog._srv_routes = m.stacked(routes, "token_routes") if routes else None
     # (expert layers,): the rows none of whose experts this chip holds
     prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
-    if hybrid and mode != "reference":
+    if mode != "reference":
         prog._srv_kernel_stats = functools.partial(
-            _hybrid_walk, mode=mode, cfg=cfg)
-    elif not whole:
-        prog._srv_kernel_stats = functools.partial(
-            _decode_walk, verify=mode == "verify", heads=cfg.num_heads,
-            layers=cfg.num_layers)
+            _form_walk, mode=mode, cfg=cfg, routed=bool(counts))
     prog._tp_degree = 1
     return prog, feeds, [out_name]
 

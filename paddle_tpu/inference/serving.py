@@ -1221,6 +1221,9 @@ class _EngineCore:
         self._score_calls: list = []                 # (score, routes) a call
         self._moe_stats: Dict[str, Dict[str, float]] = {}
         self._moe_pending: list = []                 # (phase, counts) a call
+        # (a call's place in ``_moe_pending``, what its form will say of its
+        # kernels once the call's counts are read: ``_note_kernel_stats``)
+        self._stats_owed: list = []
         # (phase, rows with no expert here by expert layer) a call, where
         # the expert layers hold a share of their experts
         self._absent_pending: list = []
@@ -1806,8 +1809,18 @@ class _EngineCore:
             return [np.asarray(t) for t in out[:len(fetch)]]
 
     def _note_kernel_stats(self, phase: str, stats):
+        """``stats``: counts to sum by phase.  Under ``from_counts`` a form
+        may give a function of the call's expert counts instead (what a
+        grouped matmul walks depends on them): owed until ``moe_stats``
+        reads the counts, and found again by the place `_run`, which calls
+        this before the call, gives the call in ``_moe_pending`` after it."""
         if not stats:
             return
+        later = stats.pop("from_counts", None)
+        if later is not None:
+            self._stats_owed.append((len(self._moe_pending), later))
+            if not stats:
+                return
         st = self.kernel_stats.setdefault(phase, {})
         for key, value in stats.items():
             st[key] = st.get(key, 0) + value
@@ -1862,7 +1875,11 @@ class _EngineCore:
         device: they are logged there, and nothing reads them while
         serving."""
         pending, self._moe_pending = self._moe_pending, []
+        owed, self._stats_owed = self._stats_owed, []
         calls = [(phase, np.asarray(c, np.float64)) for phase, c in pending]
+        for at, later in owed:
+            if at < len(calls):
+                self._note_kernel_stats(calls[at][0], later(calls[at][1]))
         if self.moe_calls is not None:
             self.moe_calls += calls
         for phase, sums in self.expert_sums(calls).items():

@@ -27,10 +27,16 @@ CPU fallback and its test oracle.
   aliased onto the output as ``kv_append`` does: only the pages written
   move.
 * ``moe_gmm`` — the grouped expert matmul: rows sorted by expert, one
-  visit per (row tile, expert) pair that share rows, an expert's weight
-  tile fetched once however many row tiles it spans and never where it
+  visit per (row tile, expert) pair that share rows, an expert's matrices
+  fetched once however many row tiles it spans and never where it
   received no row.  ``gated`` computes ``silu(x @ wg) * (x @ wu)`` in one
-  pass over ``x``.
+  pass over ``x``.  Two schedules, chosen from the call's shapes
+  (:func:`gmm_schedule`): a decode step's handful of rows an expert takes
+  small tiles, column blocks and the pipeline's own fetches; groups of a
+  tile or more (prefill) take one grid step a visit at the whole output
+  width, the grid exactly as long as the list of visits, and an expert's
+  matrices copied by the kernel into one of two VMEM slots while the
+  expert before it is still being visited.
 
 The latent pool is stored ``(1, num_pages, page_size, width)``, a row
 ``[c_kv | k_r | zeros]`` with ``width`` the 576 values of the published
@@ -50,9 +56,11 @@ Engage rules follow ``paged_attention``: kernel on TPU or under
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -556,58 +564,96 @@ def moe_gmm_reference(x, weights, group_sizes, gated: bool,
     return jnp.where(live, y, 0.0).astype(out_dtype)
 
 
-def _moe_gmm_kernel(group_ref, tile_ref, ends_ref, n_ref, x_ref, *refs,
-                    gated, tm):
-    """Grid step ``(n, v)``: visit ``v`` pairs row tile ``tile[v]`` with
-    expert ``group[v]``; the rows of the tile that the expert owns take
-    ``x @ w[expert]``, the rest keep what an earlier visit of the tile
-    wrote (zero on the tile's first visit)."""
-    o_ref = refs[-1]
-    v = pl.program_id(1)
-    g, t = group_ref[v], tile_ref[v]
-
-    @pl.when(jnp.logical_or(
-        v == 0, t != tile_ref[jnp.maximum(v - 1, 0)]))
-    def _open():
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
-
-    @pl.when(v < n_ref[0])
-    def _visit():
-        x = x_ref[...]
-        y = jnp.dot(x, refs[0][0], preferred_element_type=jnp.float32)
-        if gated:
-            u = jnp.dot(x, refs[1][0], preferred_element_type=jnp.float32)
-            y = y * jax.nn.sigmoid(y) * u
-        row = t * tm + lax.broadcasted_iota(jnp.int32, y.shape, 0)
-        lo = jnp.where(g == 0, 0, ends_ref[jnp.maximum(g - 1, 0)])
-        mine = (row >= lo) & (row < ends_ref[g])
-        o_ref[...] = jnp.where(mine, y, o_ref[...].astype(jnp.float32)) \
-            .astype(o_ref.dtype)
+#: elements the matrices of ONE expert may hold for a call to keep two
+#: experts' worth in VMEM (the prefill schedule's two slots): 6 M is 48 MiB
+#: of float32 slots, 24 MiB of bfloat16 (Kimi-Linear's gated call: 4.7 M)
+GMM_AHEAD_MAX_ELEMENTS = 6 * 1024 * 1024
 
 
-def _pick_tn(n: int) -> int:
-    for tn in (512, 384, 256, 128):
-        if n % tn == 0:
-            return tn
-    return n
+#: rows of a piece of a taller tile: a visit in which the expert does not
+#: own the whole tile takes the product over the pieces it owns a row of
+GMM_PIECE_ROWS = 128
 
 
-def _moe_gmm_call(x, weights, group_sizes, gated, out_dtype):
-    rows, k = x.shape
-    experts, _, n = weights[0].shape
-    # a tile of 128 rows where the groups are that large (prefill); small
-    # tiles where a step has a handful of rows an expert (decode), which
-    # is bound by the weights it streams and not by the MXU's fill
-    tm = 128 if rows >= 16 * experts else min(32, -(-rows // 16) * 16)
-    padded = -(-rows // tm) * tm
-    if padded != rows:
-        x = jnp.pad(x, ((0, padded - rows), (0, 0)))
-    tiles, tn = padded // tm, _pick_tn(n)
-    sizes = group_sizes.astype(jnp.int32)
+class GmmSchedule(NamedTuple):
+    """How one ``moe_gmm`` call walks: ``tm`` rows a tile, ``tn`` output
+    columns a grid step, and whether the experts' matrices come by the
+    kernel's own copies one expert ahead (``ahead``: the grid is then the
+    list of visits and no longer) or by the pipeline's, a grid step ahead
+    (the grid is ``n // tn`` times the most visits there can be)."""
+    tm: int
+    tn: int
+    ahead: bool
+
+
+def gmm_schedule(rows, experts, k, n, n_weights) -> GmmSchedule:
+    """The schedule of a call, from what the call can observe: its shapes.
+
+    *Decode* (a handful of rows an expert, ``rows < 16 * experts``): small
+    tiles, column blocks, the pipeline's own fetches.  It is bound by the
+    weights it streams and reads 89 % of that bound (PR 33): left as it was.
+
+    *Prefill* (groups of a tile or more): one grid step a (row tile,
+    expert) visit at the whole output width, an expert's matrices fetched
+    by the kernel when the expert BEFORE it starts its visits.  Measured on
+    the chip at the cells' shapes (PR 38, `PERF.md` section 6): the
+    pipeline starts a step's fetches one step ahead, so an expert's 6 MB
+    hid behind the last visit of the one before it alone (3.57 ms a JoyAI
+    gated call of 256 experts astride two tiles each; 2.45 with the copy
+    one expert ahead, which is what the copies alone take, 2.43, the
+    products alone 2.26).  Pieces of 64 or 32 rows change that by 1 %, and
+    tiles of 64 rows read 28 % more.  A tile of 256 rows where the groups
+    are that large, a boundary visit taking the product over the 128-row
+    pieces the expert owns (Kimi-Linear's down call, a quarter of 65,536
+    rows owned by 64 experts: 0.66 ms against 0.83 at tiles of 128, the
+    same products in two thirds of the grid steps, and 0.75 with the whole
+    tile's product on every visit; JoyAI's 128 rows an expert read 3 %
+    more at it than at tiles of 128).  Matrices too large for two experts'
+    worth of VMEM keep column blocks and the pipeline's fetch."""
+    tm = _gmm_tile_rows(rows, experts)
+    if rows < 16 * experts or n_weights * k * n > GMM_AHEAD_MAX_ELEMENTS:
+        return GmmSchedule(tm, _pick_tn(n), False)
+    return GmmSchedule(tm, n, True)
+
+
+def _gmm_tile_rows(rows, experts) -> int:
+    if rows < 16 * experts:
+        return min(32, -(-rows // 16) * 16)
+    return 256 if rows >= 256 * experts else 128
+
+
+def _tiles_of_groups(sizes, tm):
+    """Row tiles of ``tm`` rows each group reaches into, 0 where it owns no
+    row.  Plain operators: numpy on the host, jnp traced."""
+    ends = sizes.cumsum()
+    return ((ends - 1) // tm - (ends - sizes) // tm + 1) * (sizes > 0)
+
+
+def gmm_walk_counts(group_sizes, rows):
+    """What one ``moe_gmm`` call of ``rows`` rows over these groups (a host
+    array, rows an expert) walks, by the tile its wrapper uses: ``(row
+    tiles, visits)``, the tiles that hold a row some expert owns and the
+    (row tile, expert) pairs that take a product.  An expert that straddles
+    a tile boundary visits both tiles: ``visits / row tiles`` is near 2
+    where the groups are about a tile each, near 1 where they are many."""
+    sizes = np.asarray(group_sizes, np.int64)
+    tm = _gmm_tile_rows(rows, len(sizes))
+    return int(-(-sizes.sum() // tm)), int(_tiles_of_groups(sizes, tm).sum())
+
+
+def _gmm_work_list(sizes, tm, tiles):
+    """The walk of ``moe_gmm``: every (row tile, expert) pair in which the
+    expert owns a row, experts in order, an expert's tiles ascending, as
+    ``(group, tile, ends, n_visits, next, slot)``.  The lists are as long
+    as the most visits there can be (static); their first ``n_visits``
+    entries are the walk and the rest repeat the last of them.  ``next``:
+    the next expert that owns a row (-1: none); ``slot``: which of the two
+    weight slots a visit's expert sits in (its ordinal among those that own
+    rows, odd or even)."""
+    experts = sizes.shape[0]
     ends = jnp.cumsum(sizes)
-    starts = ends - sizes
-    first = starts // tm
-    n_tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    first = (ends - sizes) // tm
+    n_tiles = _tiles_of_groups(sizes, tm)
     visits = tiles + experts - 1                # the most there can be
     group = jnp.repeat(jnp.arange(experts, dtype=jnp.int32), n_tiles,
                        total_repeat_length=visits)
@@ -619,36 +665,170 @@ def _moe_gmm_call(x, weights, group_sizes, gated, out_dtype):
     live = jnp.arange(visits) < n_visits
     group = jnp.clip(jnp.where(live, group, group[last]), 0, experts - 1)
     tile = jnp.clip(jnp.where(live, tile, tile[last]), 0, tiles - 1)
+    owns = sizes > 0
+    after = lax.cummin(jnp.where(owns, jnp.arange(experts, dtype=jnp.int32),
+                                 experts), reverse=True)
+    nxt = jnp.concatenate([after[1:], jnp.full((1,), experts, jnp.int32)])
+    nxt = jnp.where(nxt < experts, nxt, -1)
+    slot = (jnp.cumsum(owns.astype(jnp.int32)) - 1) % 2
+    return (group, tile, ends, n_visits[None].astype(jnp.int32), nxt[group],
+            slot[group])
 
-    def _w_idx(j, v, group, tile, ends, nv):
-        return (group[v], 0, j)
 
-    def _x_idx(j, v, group, tile, ends, nv):
-        return (tile[v], 0)
+def _moe_gmm_kernel(group_ref, tile_ref, ends_ref, n_ref, *refs, gated, tm,
+                    ahead):
+    """Visit ``v`` pairs row tile ``tile[v]`` with expert ``group[v]``; the
+    rows of the tile that the expert owns take ``x @ w[expert]``, the rest
+    keep what an earlier visit of the tile wrote (zero on the tile's first
+    visit).  ``ahead``: the weights are whole arrays in HBM and the kernel
+    keeps two experts' matrices in ``bufs``; an expert's first visit waits
+    for its own and starts the NEXT expert's, which then has all of this
+    expert's visits to hide behind."""
+    nw = 2 if gated else 1
+    if ahead:
+        next_ref, slot_ref, *refs = refs
+    x_ref, w_refs, o_ref = refs[0], refs[1:1 + nw], refs[1 + nw]
+    v = pl.program_id(0 if ahead else 1)
+    g, t = group_ref[v], tile_ref[v]
 
-    def _o_idx(j, v, group, tile, ends, nv):
-        return (tile[v], j)
+    @pl.when(jnp.logical_or(
+        v == 0, t != tile_ref[jnp.maximum(v - 1, 0)]))
+    def _open():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    w_spec = pl.BlockSpec((1, k, tn), _w_idx)
+    def lowest():
+        """The first row expert ``g`` owns."""
+        return jnp.where(g == 0, 0, ends_ref[jnp.maximum(g - 1, 0)])
+
+    @pl.when(v < n_ref[0])
+    def _visit():
+        if ahead:
+            bufs, sem = refs[2 + nw:2 + 2 * nw], refs[2 + 2 * nw]
+            slot = slot_ref[v]
+
+            def copy(e, s, i):
+                return pltpu.make_async_copy(w_refs[i].at[e], bufs[i].at[s],
+                                             sem.at[i, s])
+
+            @pl.when(v == 0)
+            def _first():
+                for i in range(nw):
+                    copy(g, slot, i).start()
+
+            @pl.when(jnp.logical_or(
+                v == 0, g != group_ref[jnp.maximum(v - 1, 0)]))
+            def _turn():
+                @pl.when(next_ref[v] >= 0)
+                def _fetch_ahead():
+                    for i in range(nw):
+                        copy(next_ref[v], 1 - slot, i).start()
+
+                for i in range(nw):
+                    copy(g, slot, i).wait()
+
+            def weight(i):
+                return bufs[i][slot]
+        else:
+            def weight(i):
+                return w_refs[i][0]
+
+        def product(r0, size):
+            """Rows ``r0 .. r0 + size`` of the tile (static)."""
+            x = x_ref[r0:r0 + size, :]
+            y = jnp.dot(x, weight(0), preferred_element_type=jnp.float32)
+            if gated:
+                u = jnp.dot(x, weight(1),
+                            preferred_element_type=jnp.float32)
+                y = y * jax.nn.sigmoid(y) * u
+            # no ``+ 0`` at a tile's one piece: the decode-size kernel stays
+            # the module it was, operation for operation
+            row = (t * tm + r0 if r0 else t * tm) \
+                + lax.broadcasted_iota(jnp.int32, y.shape, 0)
+            mine = (row >= lowest()) & (row < ends_ref[g])
+            o_ref[r0:r0 + size, :] = jnp.where(
+                mine, y, o_ref[r0:r0 + size, :].astype(jnp.float32)) \
+                .astype(o_ref.dtype)
+
+        if tm <= GMM_PIECE_ROWS:
+            product(0, tm)
+        else:
+            # a tile of several pieces: the whole tile in one product where
+            # the expert owns all of it, else the pieces it owns a row of
+            lo, hi = lowest(), ends_ref[g]
+            whole = (lo <= t * tm) & (hi >= (t + 1) * tm)
+            pl.when(whole)(functools.partial(product, 0, tm))
+
+            @pl.when(jnp.logical_not(whole))
+            def _pieces():
+                for r0 in range(0, tm, GMM_PIECE_ROWS):
+                    pl.when((hi > t * tm + r0)
+                            & (lo < t * tm + r0 + GMM_PIECE_ROWS))(
+                        functools.partial(product, r0, GMM_PIECE_ROWS))
+
+
+def _pick_tn(n: int) -> int:
+    for tn in (512, 384, 256, 128):
+        if n % tn == 0:
+            return tn
+    return n
+
+
+@functools.partial(jax.jit, static_argnames=("gated", "out_dtype"))
+def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype):
+    """The kernel's call by :func:`gmm_schedule`.  Under a ``jit`` of its
+    own: the layers of a program share one trace and one lowering.  A tile
+    no expert owns a row of is never visited: its rows are whatever the
+    buffer held."""
+    rows, k = x.shape
+    experts, _, n = weights[0].shape
+    tm, tn, ahead = gmm_schedule(rows, experts, k, n, len(weights))
+    padded = -(-rows // tm) * tm
+    if padded != rows:
+        x = jnp.pad(x, ((0, padded - rows), (0, 0)))
+    tiles = padded // tm
+    lists = _gmm_work_list(group_sizes.astype(jnp.int32), tm, tiles)
+    if ahead:
+        # the grid is the list of visits and no longer (a grid may be as
+        # long as a value on the device says); one step where it is empty
+        grid = (jnp.maximum(lists[3][0], 1),)
+        semantics = ("arbitrary",)
+        x_spec = pl.BlockSpec((tm, k), lambda v, group, tile, *_:
+                              (tile[v], 0))
+        o_spec = pl.BlockSpec((tm, n), lambda v, group, tile, *_:
+                              (tile[v], 0))
+        w_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(weights)
+        scratch = [pltpu.VMEM((2, k, n), w.dtype) for w in weights] \
+            + [pltpu.SemaphoreType.DMA((len(weights), 2))]
+    else:
+        lists = lists[:4]           # no expert is fetched ahead
+        grid = (n // tn, tiles + experts - 1)
+        semantics = ("parallel", "arbitrary")
+        x_spec = pl.BlockSpec((tm, k), lambda j, v, group, tile, *_:
+                              (tile[v], 0))
+        o_spec = pl.BlockSpec((tm, tn), lambda j, v, group, tile, *_:
+                              (tile[v], j))
+        w_specs = [pl.BlockSpec((1, k, tn), lambda j, v, group, *_:
+                                (group[v], 0, j))] * len(weights)
+        scratch = []
     out = pl.pallas_call(
-        functools.partial(_moe_gmm_kernel, gated=gated, tm=tm),
+        functools.partial(_moe_gmm_kernel, gated=gated, tm=tm, ahead=ahead),
         name="moe_gmm",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(n // tn, visits),
-            in_specs=[pl.BlockSpec((tm, k), _x_idx)]
-            + [w_spec] * len(weights),
-            out_specs=pl.BlockSpec((tm, tn), _o_idx)),
+            num_scalar_prefetch=len(lists), grid=grid,
+            in_specs=[x_spec] + w_specs, out_specs=o_spec,
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((padded, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            dimension_semantics=semantics,
+            vmem_limit_bytes=(96 if ahead else 64) * 1024 * 1024),
         interpret=_interpret(),
-    )(group, tile, ends, n_visits[None].astype(jnp.int32), x, *weights)
-    # a tile no expert owns a row of was never visited: its rows are
-    # whatever the buffer held
-    keep = (jnp.arange(padded) < ends[-1])[:, None]
-    return jnp.where(keep, out, jnp.zeros((), out.dtype))[:rows]
+    )(*lists, x, *weights)
+    return out[:rows]
+
+
+def gmm_engages(k, n) -> bool:
+    """Whether ``moe_gmm`` runs its kernel for these widths here."""
+    return _use_pallas() and k % LANES == 0 and n % LANES == 0
 
 
 def moe_gmm(x, weights, group_sizes, gated: bool = False,
@@ -656,10 +836,18 @@ def moe_gmm(x, weights, group_sizes, gated: bool = False,
     """Grouped matmul over rows sorted by expert (shapes as
     :func:`moe_gmm_reference`): operands in the weights' type, accumulated
     in float32, the result in ``out_dtype``.  The kernel wants the
-    contraction and the output width in whole lanes."""
+    contraction and the output width in whole lanes.  ROWS PAST THE GROUPS
+    ARE UNSPECIFIED: the kernel writes the tiles it visits, so a tile no
+    expert owns a row of holds whatever the buffer held (a NaN, for all the
+    caller knows), and a pass to blank it would be an XLA operation of
+    output size.  A caller reads no such row unmasked
+    (``mla_ops.experts_forward`` selects in its combine); fed back as
+    ``x`` they reach no row an expert owns: a row's result is its own
+    row's product, and the tiles past the groups are never visited."""
     k, n = weights[0].shape[1:]
     x = x.astype(weights[0].dtype)
-    if _use_pallas() and k % LANES == 0 and n % LANES == 0:
-        return _moe_gmm_call(x, tuple(weights), group_sizes, gated=gated,
-                             out_dtype=jnp.dtype(out_dtype))
+    if gmm_engages(k, n):
+        return own_jit(_moe_gmm_call)(
+            x, tuple(weights), group_sizes, gated=gated,
+            out_dtype=jnp.dtype(out_dtype))
     return moe_gmm_reference(x, weights, group_sizes, gated, out_dtype)

@@ -152,6 +152,9 @@ def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
         counts = jnp.bincount(flat, length=experts + 1)[:experts] \
             .astype(jnp.int32)
         xs = jnp.take(x, order // k, axis=0)
+    # rows past the groups (padding, another chip's experts: three rows in
+    # four of a share) are left unwritten by both calls and selected away
+    # below, in the fusion that gathers them: no pass over a whole output
     hmid = moe_gmm(xs, (w_gate, w_up), counts, gated=True,
                    out_dtype=w_gate.dtype)
     ys = moe_gmm(hmid, (w_down,), counts, gated=False,
@@ -159,6 +162,10 @@ def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
     with jax.named_scope("moe_combine"):
         back = jnp.argsort(order)
         y = jnp.take(ys, back, axis=0).reshape(n, k, -1)
+        if share or valid is not None:
+            # by ``where``, never by a zero weight: an unwritten row may
+            # hold a NaN
+            y = jnp.where((flat < experts).reshape(n, k, 1), y, 0.0)
         w = weight if valid is None else \
             jnp.where(valid[:, None], weight, 0.0)
         y = jnp.einsum("nkh,nk->nh", y, w)

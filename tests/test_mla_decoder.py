@@ -9,6 +9,7 @@ import dataclasses
 import importlib.util
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -533,22 +534,197 @@ def test_latent_append_kernel(interpreted):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("sizes", [(0, 5, 40, 0, 3, 0, 17, 1),
-                                   (0, 0, 0, 0, 0, 0, 0, 0),
-                                   (66, 0, 0, 0, 0, 0, 0, 0),
-                                   (300, 1, 0, 200, 0, 0, 11, 0)])
+# (sizes of the 8 groups, rows no expert owns, n): the first four are a
+# decode step's (a handful of rows an expert: small tiles, column blocks);
+# the rest a prefill's (``rows >= 16 * experts``: one grid step a visit at
+# the whole width, the experts' matrices fetched one expert ahead)
+GMM_CASES = {
+    "decode-mixed": ((0, 5, 40, 0, 3, 0, 17, 1), 14, 256),
+    "decode-empty": ((0, 0, 0, 0, 0, 0, 0, 0), 14, 256),
+    "decode-one-expert": ((66, 0, 0, 0, 0, 0, 0, 0), 14, 256),
+    "two-large-groups": ((300, 1, 0, 200, 0, 0, 11, 0), 14, 256),
+    # every group of a tile's size and every one astride a boundary
+    "every-group-straddles": ((64, 128, 128, 128, 128, 128, 128, 192), 0,
+                              256),
+    "every-group-exactly-a-tile": ((128,) * 8, 0, 256),
+    # groups of one row and of one to three tiles, none on a boundary
+    "ones-and-several-tiles": ((130, 1, 384, 1, 129, 255, 1, 260), 0, 256),
+    "empty-between-full": ((256, 0, 0, 128, 0, 384, 0, 128), 0, 256),
+    "one-expert-owns-all": ((0, 0, 0, 1000, 0, 0, 0, 0), 0, 256),
+    # five whole tiles after the groups that no visit reaches
+    "tail-of-unvisited-tiles": ((90, 200, 0, 130, 7, 0, 300, 40), 640, 256),
+    # widths that are no power of two; 640: 512, 384, 256 do not divide it
+    "width-384": ((150, 0, 129, 3, 0, 260, 0, 64), 30, 384),
+    "width-no-block-divides": ((150, 0, 129, 3, 0, 260, 0, 64), 30, 640),
+    # one chip's share: three rows in four are another chip's
+    "share-three-quarters-unowned": ((70, 130, 0, 128, 55, 1, 60, 68), 1536,
+                                     256),
+    # groups of 256 rows and more: the tile of 256 in pieces of 128
+    "tile-256": ((300, 0, 513, 256, 255, 1, 700, 30), 45, 256),
+    "prefill-empty": ((0, 0, 0, 0, 0, 0, 0, 0), 256, 256),
+}
+
+
+@pytest.mark.parametrize("case", list(GMM_CASES))
 @pytest.mark.parametrize("gated", [True, False])
-def test_moe_gmm_kernel(interpreted, sizes, gated):
+@pytest.mark.parametrize("ahead", [True, False])
+def test_moe_gmm_kernel(interpreted, monkeypatch, case, gated, ahead):
+    """The rows the experts own against the reference; the rows past them
+    are unspecified.  ``ahead`` off: matrices too large for two experts'
+    worth of VMEM, so a prefill call keeps the pipeline's fetches and
+    column blocks."""
+    sizes, tail, n = GMM_CASES[case]
+    if not ahead:
+        monkeypatch.setattr(mla_kernels, "GMM_AHEAD_MAX_ELEMENTS", 0)
     rng = np.random.RandomState(sum(sizes))
-    rows, k, n = sum(sizes) + 14, 128, 256        # 14 rows no expert owns
+    rows, k = sum(sizes) + tail, 128
+    want_tm = 256 if rows >= 2048 else 128 if rows >= 128 else None
+    tm, _, took = mla_kernels.gmm_schedule(rows, 8, k, n, 1 + gated)
+    assert took == (ahead and rows >= 128)
+    assert want_tm is None or tm == want_tm
     x = jnp.asarray(rng.randn(rows, k), jnp.float32)
     ws = tuple(jnp.asarray(rng.randn(8, k, n) / 12, jnp.float32)
                for _ in range(2 if gated else 1))
     group_sizes = jnp.asarray(sizes, jnp.int32)
     got = mla_kernels.moe_gmm(x, ws, group_sizes, gated)
     want = mla_kernels.moe_gmm_reference(x, ws, group_sizes, gated)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
-    assert (np.asarray(got)[sum(sizes):] == 0).all()
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got)[:sum(sizes)],
+                               np.asarray(want)[:sum(sizes)], atol=1e-4)
+    # what is left of the last tile a visit reached was blanked as the
+    # tile was opened
+    reached = -(-sum(sizes) // tm) * tm
+    assert (np.asarray(got)[sum(sizes):reached] == 0).all()
+
+
+def test_moe_gmm_kernel_bfloat16_operands_float32_sums(interpreted):
+    """Operands as served (bfloat16), sums in float32, the gated output in
+    the weights' type and the down projection's float32: against the same
+    products of the bfloat16 values taken in float32."""
+    rng = np.random.RandomState(5)
+    sizes = (100, 28, 0, 300, 129, 1, 66, 16)
+    rows, k, f = sum(sizes) + 128, 256, 384
+    x = jnp.asarray(rng.randn(rows, k), jnp.bfloat16)
+    wg, wu = (jnp.asarray(rng.randn(8, k, f) / 16, jnp.bfloat16)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(8, f, k) / 16, jnp.bfloat16)
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    assert mla_kernels.gmm_schedule(rows, 8, k, f, 2).ahead
+    hmid = mla_kernels.moe_gmm(x, (wg, wu), group_sizes, True,
+                               out_dtype=jnp.bfloat16)
+    assert hmid.dtype == jnp.bfloat16
+    want = mla_kernels.moe_gmm_reference(x, (wg, wu), group_sizes, True)
+    own = sum(sizes)
+    np.testing.assert_allclose(
+        np.asarray(hmid.astype(jnp.float32))[:own], np.asarray(want)[:own],
+        rtol=2 ** -7, atol=2e-3)
+    ys = mla_kernels.moe_gmm(hmid, (wd,), group_sizes, False)
+    assert ys.dtype == jnp.float32
+    want = mla_kernels.moe_gmm_reference(hmid, (wd,), group_sizes, False)
+    np.testing.assert_allclose(np.asarray(ys)[:own], np.asarray(want)[:own],
+                               rtol=1e-5, atol=1e-4)
+
+
+def _experts_reference(x, idx, weight, w_gate, w_up, w_down, valid):
+    """Every token times every expert, the choices summed: float64."""
+    x, wg, wu, wd = (np.asarray(a, np.float64)
+                     for a in (x, w_gate, w_up, w_down))
+    g = np.einsum("nh,ehf->nef", x, wg)
+    h = g / (1 + np.exp(-g)) * np.einsum("nh,ehf->nef", x, wu)
+    per = np.einsum("nef,efh->neh", h, wd)              # (n, experts, h)
+    y = np.zeros_like(x)
+    for t in range(x.shape[0]):
+        for j in range(idx.shape[1]):
+            e = int(idx[t, j])
+            if e < wg.shape[0] and (valid is None or valid[t]):
+                y[t] += float(weight[t, j]) * per[t, e]
+    return y
+
+
+@pytest.mark.parametrize("share", [False, True])
+@pytest.mark.parametrize("padded", [False, True])
+def test_experts_forward_masks_what_moe_gmm_leaves_unwritten(
+        interpreted, monkeypatch, share, padded):
+    """Prefill sizes through the kernel: the rows of padding and of another
+    chip's experts sort past the groups, ``moe_gmm`` leaves their tiles
+    unwritten (NaN here, the worst a buffer can hold: every row past the
+    groups, those of the last visited tile too) and the combine selects
+    them away: no NaN reaches a token's sum, nor the down call's owned
+    rows."""
+    def unwritten(x, ws, sizes, **kw):
+        out = mla_kernels.moe_gmm(x, ws, sizes, **kw)
+        keep = jnp.arange(out.shape[0]) < jnp.sum(sizes)
+        return jnp.where(keep[:, None], out, jnp.nan)
+
+    monkeypatch.setattr(mla_ops, "moe_gmm", unwritten)
+    rng = np.random.RandomState(3 + share)
+    n, k, held, routed, h, f = 96, 4, 8, 32 if share else 8, 128, 128
+    x = jnp.asarray(rng.randn(n, h), jnp.float32)
+    idx = np.stack([rng.choice(routed, k, replace=False) for _ in range(n)])
+    weight = rng.rand(n, k).astype(np.float32)
+    valid = np.arange(n) < 61 if padded else None
+    wg, wu = (jnp.asarray(rng.randn(held, h, f) / 12, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(held, f, h) / 12, jnp.float32)
+    assert mla_kernels.gmm_schedule(n * k, held, h, f, 2).ahead
+    y, counts = mla_ops.experts_forward(
+        x, jnp.asarray(idx, jnp.int32), jnp.asarray(weight), wg, wu, wd,
+        None if valid is None else jnp.asarray(valid), share)
+    live = idx[valid] if padded else idx
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(live[live < held], minlength=held))
+    want = _experts_reference(x, idx, weight, wg, wu, wd, valid)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-4)
+
+
+# sha256 of the float32 bytes of the rows the experts own, as the kernel of
+# commit 0167326 (before PR 38 touched ``mla_kernels.py``) returned them
+DECODE_GMM_DOWN_SHA256 = \
+    "b80def7a9001d00be91ea9b968605abb18a7e005030150de566cc0c82fc650ad"
+DECODE_GMM_GATED_SHA256 = \
+    "3238d5e9e0f48becf5b506bd2fb3b50df66c10c5d1cab74ea046058bb125d555"
+
+
+def _fixed_decode_gmm(gated):
+    """A decode step's sizes (80 rows over 8 experts: small tiles) in small
+    whole numbers over eighths: every product and every sum is exact in
+    float32, so a matmul's bits do not depend on the order of its sums."""
+    rng = np.random.RandomState(38)
+    sizes = np.array((0, 5, 40, 0, 3, 0, 17, 1), np.int32)
+    rows, k, n = int(sizes.sum()) + 14, 128, 256
+    x = jnp.asarray(rng.randint(-3, 4, (rows, k)), jnp.bfloat16)
+    ws = tuple(jnp.asarray(rng.randint(-2, 3, (8, k, n)) / 8.0, jnp.bfloat16)
+               for _ in range(2 if gated else 1))
+    return x, ws, jnp.asarray(sizes)
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_moe_gmm_decode_branch_is_bit_for_bit_the_parents(interpreted, gated):
+    """The decode-size branch (``rows < 16 * experts``) on fixed inputs:
+    the down call's bits against the parent's, recorded before the kernel
+    was touched.  The gated call's depend on the machine's ``exp`` besides:
+    held bit for bit to ``silu(g) * u`` of the exact products, and to the
+    record where this machine computes that as the recording one did."""
+    import hashlib
+
+    x, ws, sizes = _fixed_decode_gmm(gated)
+    assert x.shape[0] < 16 * sizes.shape[0]
+    out = mla_kernels.moe_gmm(x, ws, sizes, gated, out_dtype=jnp.bfloat16
+                              if gated else jnp.float32)
+    owned = np.asarray(out.astype(jnp.float32))[:int(sizes.sum())]
+    said = hashlib.sha256(owned.tobytes()).hexdigest()
+    if not gated:
+        assert said == DECODE_GMM_DOWN_SHA256
+        return
+    owner = np.repeat(np.arange(8), np.asarray(sizes))
+    xs = x[:owner.size].astype(jnp.float32)
+    g, u = (jnp.einsum("mk,mkn->mn", xs, w[owner].astype(jnp.float32))
+            for w in ws)
+    want = np.asarray((g * jax.nn.sigmoid(g) * u).astype(jnp.bfloat16)
+                      .astype(jnp.float32))
+    np.testing.assert_array_equal(owned, want)
+    if hashlib.sha256(want.tobytes()).hexdigest() == DECODE_GMM_GATED_SHA256:
+        assert said == DECODE_GMM_GATED_SHA256
 
 
 def test_engine_through_the_kernels_matches_reference(interpreted):
@@ -566,6 +742,85 @@ def test_engine_through_the_kernels_matches_reference(interpreted):
     assert worst <= 8e-2, worst
 
 
+@pytest.mark.parametrize("rows,experts", [
+    (32768, 256),       # JoyAI's 4096-token prefill: a tile an expert
+    (16384, 256),       # its 2048 bucket
+    (65536, 64),        # Kimi's share: a quarter of the rows, tiles of 256
+    (1024, 256),        # JoyAI's decode step: small tiles
+    (1024, 64),         # Kimi's decode step: the tile of 128
+])
+def test_gmm_walk_counts_against_a_brute_count(rows, experts):
+    """``moe_gmm_row_tiles`` / ``moe_gmm_visits`` as the engine counts them,
+    against every (tile, expert) pair tried one by one and against the work
+    list the kernel is given."""
+    rng = np.random.RandomState(rows + experts)
+    owned = rows // 4 if experts == 64 else rows - rows // 3
+    sizes = rng.multinomial(owned, np.ones(experts) / experts)
+    sizes[rng.choice(experts, experts // 8, replace=False)] = 0
+    tm = mla_kernels.gmm_schedule(rows, experts, 128, 128, 1).tm
+    assert tm == (32 if rows < 16 * experts else
+                  256 if rows >= 256 * experts else 128)
+    ends = np.cumsum(sizes)
+    tiles = -(-rows // tm)
+    shared = [(t, e) for t in range(tiles) for e in range(experts)
+              if sizes[e] and max(t * tm, ends[e] - sizes[e])
+              < min((t + 1) * tm, ends[e])]
+    row_tiles, visits = mla_kernels.gmm_walk_counts(sizes, rows)
+    assert visits == len(shared)
+    assert row_tiles == len({t for t, _ in shared})
+    assert row_tiles <= visits <= row_tiles + (sizes > 0).sum() - 1
+    lists = mla_kernels._gmm_work_list(jnp.asarray(sizes, jnp.int32), tm,
+                                       tiles)
+    group, tile, _, n_visits, nxt, slot = (np.asarray(a) for a in lists)
+    assert int(n_visits[0]) == visits
+    assert list(zip(tile[:visits], group[:visits])) == \
+        sorted(shared, key=lambda p: (p[1], p[0]))
+    # an expert's weights wait in the slot the visit before it did not use
+    turn = np.flatnonzero(np.diff(group[:visits])) + 1
+    assert (slot[turn] != slot[turn - 1]).all()
+    assert (nxt[turn - 1] == group[turn]).all() and nxt[visits - 1] == -1
+    assert mla_kernels.gmm_walk_counts(np.zeros(experts), rows) == (0, 0)
+
+
+def test_engine_counts_what_moe_gmm_walks(interpreted):
+    """``moe_stats`` folds each call's tokens per expert into what its
+    grouped matmuls walked, by phase, in ``eng.stats`` and the registry:
+    two calls an expert layer; a decoder whose kernel does not engage and
+    GPT-2 say nothing."""
+    from paddle_tpu.utils import telemetry as tm
+
+    cfg = MLADecoderConfig(hidden=128, num_heads=8, moe_intermediate=128,
+                           intermediate=256, num_layers=2)
+    eng, cfg, _ = make_engine(cfg, "bfloat16")
+    eng.generate(prompts_of(3, lens=(70, 100)), 4)
+    assert "prefill" not in eng.stats["kernels"]      # nothing read yet
+    assert "moe_gmm_calls" not in eng.stats["kernels"]["decode"]
+    moe = eng.core.moe_stats
+    walk = eng.stats["kernels"]
+    expert_layers = cfg.num_layers - cfg.first_k_dense
+    for phase in ("prefill", "decode"):
+        st = walk[phase]
+        assert st["moe_gmm_calls"] >= 2 * moe[phase]["layer_steps"] > 0
+        assert st["moe_gmm_calls"] % (2 * expert_layers) == 0
+        assert 0 < st["moe_gmm_row_tiles"] <= st["moe_gmm_visits"]
+    # a prompt's bucket of 128 tokens x 2 choices is 32 rows an expert, two
+    # tiles of 128 that the 8 experts' groups share: 2 + 8 - 1 visits at most
+    pre = walk["prefill"]
+    assert pre["moe_gmm_visits"] <= pre["moe_gmm_calls"] * 9
+    assert pre["moe_gmm_row_tiles"] <= pre["moe_gmm_calls"] * 2
+    said = tm.snapshot()["moe_gmm_visits"]["series"]
+    assert any(s["labels"] == {"phase": "prefill"}
+               and s["value"] >= pre["moe_gmm_visits"] for s in said)
+    # read again: nothing is counted twice
+    before = {ph: dict(st) for ph, st in walk.items()}
+    eng.core.moe_stats
+    assert eng.stats["kernels"] == before
+    plain, _, _ = make_engine(TINY)                   # 4 heads, width 32
+    plain.generate(prompts_of(3, lens=(9,)), 2)
+    plain.core.moe_stats
+    assert plain.stats["kernels"] == {}
+
+
 def test_engine_counts_what_mla_decode_walks(interpreted):
     """The decode form says from its feed what its kernels walk, by phase,
     in ``eng.stats`` and the registry; a form whose kernel does not engage
@@ -578,6 +833,8 @@ def test_engine_counts_what_mla_decode_walks(interpreted):
     eng.generate(prompts_of(3, lens=(9, 20)), 4)
     walk = eng.stats["kernels"]
     assert set(walk) == {"decode"} and walk is eng.core.kernel_stats
+    assert set(walk["decode"]) == {"mla_decode_calls", "mla_decode_grid_steps",
+                                   "mla_decode_table_chunks"}
     st, steps = walk["decode"], eng.stats["decode_steps"]
     assert st["mla_decode_calls"] == cfg.num_layers * steps
     # contexts of 10..24 tokens: a chunk a row, two rows a step, and tables

@@ -226,6 +226,84 @@ def test_mla_decode_compiles_for_v5e(rows, tables, width, v5e):
     assert " conditional(" not in text
 
 
+# --- moe_gmm at the two expert cells' shapes: (rows, experts, hidden, f) -----
+@pytest.mark.parametrize("name,rows,experts,hidden,f,want", [
+    ("joyai-prefill-4096", 32768, 256, 2048, 768, (128, None, True)),
+    ("joyai-prefill-2048", 16384, 256, 2048, 768, (128, None, True)),
+    ("kimi-prefill-8192", 65536, 64, 2304, 1024, (256, None, True)),
+    ("kimi-prefill-4096", 32768, 64, 2304, 1024, (256, None, True)),
+    # decode: JoyAI's 126 rows x 8 keep the small tiles and the pipeline's
+    # fetches; Kimi's 1,024 rows over 64 held experts are 16 an expert by
+    # their shape (4 owned) and take the tile of 128, as before PR 38
+    ("joyai-decode", 1024, 256, 2048, 768, (32, 384, False)),
+    ("kimi-decode", 1024, 64, 2304, 1024, (128, None, True)),
+])
+def test_moe_gmm_compiles_for_v5e(name, rows, experts, hidden, f, want, v5e):
+    """A layer's two calls (gated: bfloat16 out; down: float32 out) as the
+    chip's compiler takes them with the blocks ``gmm_schedule`` chooses
+    from the shapes: at prefill sizes one grid step a visit at the whole
+    output width, two experts' matrices in VMEM (19 MB of Kimi's gated
+    call, asked of the compiler and not reckoned), the grid as long as a
+    value on the device says; one Mosaic call each."""
+    from paddle_tpu.ops import mla_kernels as mk
+
+    tm, tn, ahead = want
+    assert mk.gmm_schedule(rows, experts, hidden, f, 2) == \
+        (tm, tn or f, ahead)
+    assert mk.gmm_schedule(rows, experts, f, hidden, 1)[::2] == (tm, ahead)
+    for gated, k, n, out in ((True, hidden, f, jnp.bfloat16),
+                             (False, f, hidden, jnp.float32)):
+        def call(x, sizes, *ws):
+            return mk._moe_gmm_call(x, ws, sizes, gated=gated,
+                                    out_dtype=jnp.dtype(out))
+
+        text = _compile(call, v5e, ((rows, k), jnp.bfloat16),
+                        ((experts,), jnp.int32),
+                        *[((experts, k, n), jnp.bfloat16)] * (1 + gated))
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+# sha256 of the Mosaic module (locations off) that commit 0167326, before
+# PR 38 touched the kernel, lowered at JoyAI's decode shapes: gated, down
+DECODE_GMM_MODULES = {
+    True: "f4bfd2239ff806ad1bb4bcfbf4a8be4b3dc39c7d1d7917e0bbccd58e9fe3f89c",
+    False: "10f880178daf1e4fc151007fc6da5288e9ae7e1a37e3aa3096831bfbee9db8d8",
+}
+
+
+@pytest.mark.parametrize("gated", [True, False])
+def test_moe_gmm_decode_module_is_the_parents(gated, v5e, monkeypatch):
+    """The decode-size branch (JoyAI's 1,024 rows over 256 experts) lowers
+    to the kernel it was: the Mosaic module's text, operation for
+    operation."""
+    import hashlib
+
+    from jax._src import tpu_custom_call
+    from paddle_tpu.ops import mla_kernels as mk
+
+    modules = []
+    lower = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def spy(module, **kw):
+        modules.append(module.operation.get_asm(enable_debug_info=False))
+        return lower(module, **kw)
+
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", spy)
+    k, n, out = (2048, 768, jnp.bfloat16) if gated else \
+        (768, 2048, jnp.float32)
+
+    def call(x, sizes, *ws):
+        return mk._moe_gmm_call.__wrapped__(x, ws, sizes, gated=gated,
+                                            out_dtype=jnp.dtype(out))
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in
+            [((1024, k), jnp.bfloat16), ((256,), jnp.int32)]
+            + [((256, k, n), jnp.bfloat16)] * (1 + gated)]
+    jax.jit(call).lower(*args)
+    assert [hashlib.sha256(m.encode()).hexdigest() for m in modules] == \
+        [DECODE_GMM_MODULES[gated]]
+
+
 # --- the KDA kernels at Kimi-Linear's widths: 32 heads of 128 ----------------
 KDA_POOL = (129, 32, 128, 128)    # the cell's state pool a layer, float32
 
