@@ -34,7 +34,7 @@ from .framework.dtype import to_numpy_dtype
 from .framework.place import CPUPlace, Place, _get_paddle_place
 from .framework.scope import LoDTensor, Scope, global_scope
 from .ops import registry
-from .profiler import RecordEvent
+from .profiler import RecordEvent, note_program
 from .utils import telemetry as tm
 
 logger = logging.getLogger(__name__)
@@ -1078,11 +1078,17 @@ class Executor:
             with RecordEvent("executor_run"):
                 with RecordEvent("executor/bind"):
                     mut, ro = bind()
-                with RecordEvent("executor/call"):
+                with RecordEvent("executor/call") as call:
                     if hybrid:
                         f, ns = compiled.fn(feed_vals, mut)
                     else:
                         f, ns = compiled.fn(mut, ro, feed_vals)
+                    if call.recording:
+                        # which compiled step ran, for the device's rows
+                        # (profiler.device_symbols): once an entry, shapes
+                        # only (the donated arrays keep theirs)
+                        note_program(program_label(program), compiled.fn,
+                                     (mut, ro, feed_vals))
                 return f, ns, ro
 
         try:

@@ -46,6 +46,7 @@ an engine drafter on the speculative path.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Dict, List, Tuple
@@ -423,25 +424,34 @@ class _MB:
     def tmp(self, tag):
         return self.b.tmp(tag)
 
-    # ``part`` names whose time an op is in the device trace (a named
-    # scope): mla_part, moe_part, dense_ffn, head
-    def mm(self, x, w, tag, part):
+    @contextlib.contextmanager
+    def part(self, name):
+        """Every op built inside serves this part of the model (``embed``,
+        ``mla_part``, ``kda_part``, ``moe_part``, ``dense_ffn``, ``head``,
+        ``mtp``): its attr ``part``, which ``registry.run_op`` makes the
+        op's outermost scope, so the compiled program says whose time each
+        of its instructions is (``profiler.device_symbols``)."""
+        was, self.b.part = self.b.part, name
+        try:
+            yield
+        finally:
+            self.b.part = was
+
+    def mm(self, x, w, tag):
         o = self.tmp(tag)
-        self.op("matmul_f32acc", {"X": [x], "Y": [w]}, {"Out": [o]},
-                {"part": part})
+        self.op("matmul_f32acc", {"X": [x], "Y": [w]}, {"Out": [o]})
         return o
 
-    def norm(self, x, scale, tag, part):
+    def norm(self, x, scale, tag):
         o = self.tmp(tag)
         self.op("rms_norm", {"X": [x], "Scale": [scale]}, {"Y": [o]},
-                {"epsilon": float(self.cfg.rms_norm_eps), "part": part})
+                {"epsilon": float(self.cfg.rms_norm_eps)})
         return o
 
     def rope(self, x, positions, tag):
         o = self.tmp(tag)
         self.op("rope_interleaved", {"X": [x], "Positions": [positions]},
-                {"Out": [o]}, {"theta": float(self.cfg.rope_theta),
-                               "part": "mla_part"})
+                {"Out": [o]}, {"theta": float(self.cfg.rope_theta)})
         return o
 
     def split(self, x, sizes, tag):
@@ -450,13 +460,12 @@ class _MB:
                 {"axis": -1, "sections": list(sizes), "num": 0})
         return outs
 
-    def swiglu_ffn(self, x, gate, up, down, tag, part):
-        g = self.mm(x, gate, tag + "_g", part)
-        u = self.mm(x, up, tag + "_u", part)
+    def swiglu_ffn(self, x, gate, up, down, tag):
+        g = self.mm(x, gate, tag + "_g")
+        u = self.mm(x, up, tag + "_u")
         a = self.tmp(tag + "_act")
-        self.op("swiglu", {"Gate": [g], "Up": [u]}, {"Out": [a]},
-                {"part": part})
-        return self.mm(a, down, tag + "_d", part)
+        self.op("swiglu", {"Gate": [g], "Up": [u]}, {"Out": [a]})
+        return self.mm(a, down, tag + "_d")
 
     def latents(self, i, hn, positions):
         """Layer ``i``'s queries and latent row of the normed rows ``hn``
@@ -464,20 +473,19 @@ class _MB:
         (n, r) normed, ``k_r`` (n, dr) after RoPE."""
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
         dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        part = "mla_part"
         if cfg.q_lora_rank:
-            cq = self.norm(self.mm(hn, p + "wq_a", f"l{i}_cq", part),
-                           p + "q_norm_scale", f"l{i}_cqn", part)
-            q = self.mm(cq, p + "wq_b", f"l{i}_q", part)
+            cq = self.norm(self.mm(hn, p + "wq_a", f"l{i}_cq"),
+                           p + "q_norm_scale", f"l{i}_cqn")
+            q = self.mm(cq, p + "wq_b", f"l{i}_q")
         else:
-            q = self.mm(hn, p + "wq", f"l{i}_q", part)
+            q = self.mm(hn, p + "wq", f"l{i}_q")
         q = b.reshape(q, [-1, cfg.num_heads, dn + dr], f"l{i}_q3")
         q_nope, q_rope = self.split(q, [dn, dr], f"l{i}_qs")
         if cfg.rope:
             q_rope = self.rope(q_rope, positions, f"l{i}_qr")
-        c_kv, k_r = self.split(self.mm(hn, p + "wkv_a", f"l{i}_kva", part),
+        c_kv, k_r = self.split(self.mm(hn, p + "wkv_a", f"l{i}_kva"),
                                [cfg.kv_lora_rank, dr], f"l{i}_kvs")
-        c_kv = self.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv", part)
+        c_kv = self.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv")
         if not cfg.rope:      # NoPE: dr more key lanes that all heads share
             return q_nope, q_rope, c_kv, k_r
         k_r = b.reshape(self.rope(b.reshape(k_r, [-1, 1, dr], f"l{i}_kr3"),
@@ -539,46 +547,52 @@ class _MB:
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
         mix = "kda_part" if i < cfg.num_layers and cfg.mixer(i) == "kda" \
             else "mla_part"
-        hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an", mix)
-        att = kda(i, hn) if mix == "kda_part" \
-            else attend(i, *self.latents(i, hn, positions))
-        hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o", mix),
-                    f"l{i}_res1")
+        with self.part(mix):
+            hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an")
+            att = kda(i, hn) if mix == "kda_part" \
+                else attend(i, *self.latents(i, hn, positions))
+            hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o"), f"l{i}_res1")
         dense = i < cfg.first_k_dense
-        part = "dense_ffn" if dense else "moe_part"
-        hn2 = self.norm(hid, p + "ffn_norm_scale", f"l{i}_fn", part)
+        with self.part("dense_ffn" if dense else "moe_part"):
+            return b.add(hid, self._ffn(i, hid, dense, valid, counts, routes,
+                                        absent), f"l{i}_res2")
+
+    def _ffn(self, i, hid, dense, valid, counts, routes, absent):
+        """Layer ``i``'s feed-forward half over ``hid``: its norm and the
+        dense SwiGLU, or the router, the routed experts and the shared
+        expert."""
+        cfg, p, b = self.cfg, f"dec_l{i}_", self.b
+        hn2 = self.norm(hid, p + "ffn_norm_scale", f"l{i}_fn")
         if dense:
-            ff = self.swiglu_ffn(hn2, p + "w_gate", p + "w_up", p + "w_down",
-                                 f"l{i}_ff", part)
-        else:
-            idx, wgt = self.tmp(f"l{i}_ridx"), self.tmp(f"l{i}_rw")
-            self.op("moe_router",
-                    {"X": [hn2], "Gate": [p + "router"],
-                     "Bias": [p + "router_bias"]},
-                    {"Idx": [idx], "Weight": [wgt]},
-                    {"top_k": int(cfg.num_experts_per_tok),
-                     "routed_scaling_factor":
-                         float(cfg.routed_scaling_factor),
-                     "norm_topk_prob": bool(cfg.norm_topk_prob)})
-            routed, cnt = self.tmp(f"l{i}_moe"), self.tmp(f"l{i}_cnt")
-            ins = {"X": [hn2], "Idx": [idx], "Weight": [wgt],
-                   "WGate": [p + "experts_gate"], "WUp": [p + "experts_up"],
-                   "WDown": [p + "experts_down"]}
-            if valid is not None:
-                ins["Valid"] = [valid]
-            outs = {"Out": [routed], "Counts": [cnt]}
-            if cfg.experts_here < cfg.n_routed_experts:
-                # this chip's share: the rows' other experts are elsewhere
-                outs["Absent"] = [self.tmp(f"l{i}_absent")]
-                absent.append(outs["Absent"][0])
-            self.op("moe_experts", ins, outs)
-            counts.append(cnt)
-            if routes is not None:
-                routes.append(idx)
-            shared = self.swiglu_ffn(hn2, p + "shared_gate", p + "shared_up",
-                                     p + "shared_down", f"l{i}_sh", part)
-            ff = b.add(routed, shared, f"l{i}_ff")
-        return b.add(hid, ff, f"l{i}_res2")
+            return self.swiglu_ffn(hn2, p + "w_gate", p + "w_up",
+                                   p + "w_down", f"l{i}_ff")
+        idx, wgt = self.tmp(f"l{i}_ridx"), self.tmp(f"l{i}_rw")
+        self.op("moe_router",
+                {"X": [hn2], "Gate": [p + "router"],
+                 "Bias": [p + "router_bias"]},
+                {"Idx": [idx], "Weight": [wgt]},
+                {"top_k": int(cfg.num_experts_per_tok),
+                 "routed_scaling_factor":
+                     float(cfg.routed_scaling_factor),
+                 "norm_topk_prob": bool(cfg.norm_topk_prob)})
+        routed, cnt = self.tmp(f"l{i}_moe"), self.tmp(f"l{i}_cnt")
+        ins = {"X": [hn2], "Idx": [idx], "Weight": [wgt],
+               "WGate": [p + "experts_gate"], "WUp": [p + "experts_up"],
+               "WDown": [p + "experts_down"]}
+        if valid is not None:
+            ins["Valid"] = [valid]
+        outs = {"Out": [routed], "Counts": [cnt]}
+        if cfg.experts_here < cfg.n_routed_experts:
+            # this chip's share: the rows' other experts are elsewhere
+            outs["Absent"] = [self.tmp(f"l{i}_absent")]
+            absent.append(outs["Absent"][0])
+        self.op("moe_experts", ins, outs)
+        counts.append(cnt)
+        if routes is not None:
+            routes.append(idx)
+        shared = self.swiglu_ffn(hn2, p + "shared_gate", p + "shared_up",
+                                 p + "shared_down", f"l{i}_sh")
+        return b.add(routed, shared, f"l{i}_ff")
 
     def stacked(self, per_layer, name):
         """The expert layers' small int32 results as one fetch, layers
@@ -745,20 +759,24 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
         state_slots = b.feed("state_slots", (1,) if whole else (-1,),
                              VarType.INT32)
         feeds.append("state_slots")
-    flat_tok = b.reshape(tokens, [-1], "tok_flat")
-    flat_pos = b.reshape(positions, [-1], "pos_flat")
-    hid = b.tmp("h0")
-    m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [flat_tok]},
-         {"Out": [hid]})
-    hid32 = b.tmp("h0_f32")
-    m.op("cast", {"X": [hid]}, {"Out": [hid32]},
-         {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
-    hid = hid32
-    if mode == "verify":
-        # a verify row's context ends at its own position
-        ctx_lens = b.tmp("ctx_from_pos")
-        m.op("scale", {"X": [flat_pos]}, {"Out": [ctx_lens]},
-             {"scale": 1.0, "bias": 1.0, "bias_after_scale": True})
+    with m.part("embed"):     # the rows' inputs: ids, embeddings, liveness
+        flat_tok = b.reshape(tokens, [-1], "tok_flat")
+        flat_pos = b.reshape(positions, [-1], "pos_flat")
+        hid = b.tmp("h0")
+        m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [flat_tok]},
+             {"Out": [hid]})
+        hid32 = b.tmp("h0_f32")
+        m.op("cast", {"X": [hid]}, {"Out": [hid32]},
+             {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
+        hid = hid32
+        if mode == "verify":
+            # a verify row's context ends at its own position
+            ctx_lens = b.tmp("ctx_from_pos")
+            m.op("scale", {"X": [flat_pos]}, {"Out": [ctx_lens]},
+                 {"scale": 1.0, "bias": 1.0, "bias_after_scale": True})
+        valid = None
+        if mode != "reference":
+            valid = m.live_rows(slot_map, cfg.mla_layers[0], kv_dtype)
 
     attrs = m.attn_attrs()
 
@@ -778,10 +796,6 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
                   "WKVB": [f"dec_l{i}_wkv_b"]}, {"Out": [out]}, attrs)
         return out
 
-    valid = None
-    if mode != "reference":
-        valid = m.live_rows(slot_map, cfg.mla_layers[0], kv_dtype)
-
     def kda(i, hn):
         return m.kda(i, hn, mode if mode != "reference" else "reference",
                      valid, state_slots,
@@ -798,37 +812,43 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     # hybrid model a row's neighbours reach it undiluted (the convolution's
     # taps, the fast-decaying channels of a state), so a check of the served
     # logits follows the engine's routing on the prompt's rows as well
-    prog._srv_routes_all = m.stacked(routes, "token_routes_all") \
-        if hybrid and whole and routes else None
+    with m.part("moe_part"):
+        prog._srv_routes_all = m.stacked(routes, "token_routes_all") \
+            if hybrid and whole and routes else None
     if whole:
-        last = b.tmp("hlast")
-        m.op("gather", {"X": [hid], "Index": [last_index]}, {"Out": [last]},
-             {"axis": 0})
-        hid = last
+        with m.part("head"):
+            last = b.tmp("hlast")
+            m.op("gather", {"X": [hid], "Index": [last_index]},
+                 {"Out": [last]}, {"axis": 0})
+            hid = last
         # the routing of the one row that emits
         picked = []
-        for j, r in enumerate(routes):
-            o = b.tmp(f"route_last_{j}")
-            m.op("gather", {"X": [r], "Index": [last_index]}, {"Out": [o]},
-                 {"axis": 0})
-            picked.append(o)
+        with m.part("moe_part"):
+            for j, r in enumerate(routes):
+                o = b.tmp(f"route_last_{j}")
+                m.op("gather", {"X": [r], "Index": [last_index]},
+                     {"Out": [o]}, {"axis": 0})
+                picked.append(o)
         routes = picked
     out_name = "next_token" if whole else "next_tokens"
-    logits = m.mm(m.norm(hid, "dec_norm_scale", "fnorm", "head"),
-                  "dec_head", "logits", "head")
-    _emit_head(b, logits, out_name, sampling, seeds)
-    score = b.blk.create_var(name="token_score", dtype=VarType.FP32).name
-    m.op("token_score", {"Logits": [logits], "Token": [out_name]},
-         {"Out": [score]})
+    with m.part("head"):
+        logits = m.mm(m.norm(hid, "dec_norm_scale", "fnorm"), "dec_head",
+                      "logits")
+        _emit_head(b, logits, out_name, sampling, seeds)
+        score = b.blk.create_var(name="token_score", dtype=VarType.FP32).name
+        m.op("token_score", {"Logits": [logits], "Token": [out_name]},
+             {"Out": [score]})
     prog._srv_params = dict.fromkeys(mla_param_specs(cfg))
     prog._srv_logits = logits
     prog._srv_score = score
-    # (expert layers, experts): the tokens each expert received; (expert
-    # layers, rows, k): the experts each emitting row was routed to
-    prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
-    prog._srv_routes = m.stacked(routes, "token_routes") if routes else None
-    # (expert layers,): the rows none of whose experts this chip holds
-    prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
+    with m.part("moe_part"):
+        # (expert layers, experts): the tokens each expert received; (expert
+        # layers, rows, k): the experts each emitting row was routed to
+        prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
+        prog._srv_routes = m.stacked(routes, "token_routes") \
+            if routes else None
+        # (expert layers,): the rows none of whose experts this chip holds
+        prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
     if mode != "reference":
         prog._srv_kernel_stats = functools.partial(
             _form_walk, mode=mode, cfg=cfg, routed=bool(counts))
@@ -860,17 +880,19 @@ def _build_mtp_program(cfg: MLADecoderConfig, sampling, kv_dtype: str):
     slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
     feeds = ["hidden", "tokens", "positions", "block_tables",
              "context_lens", "slot_mapping"]
-    emb = b.tmp("mtp_emb")
-    m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [tokens]},
-         {"Out": [emb]})
-    emb32 = b.tmp("mtp_emb_f32")
-    m.op("cast", {"X": [emb]}, {"Out": [emb32]},
-         {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
-    both = b.tmp("mtp_cat")
-    m.op("concat", {"X": [m.norm(hidden, "mtp_hnorm_scale", "mtp_hn", "mtp"),
-                          m.norm(emb32, "mtp_enorm_scale", "mtp_en", "mtp")]},
-         {"Out": [both]}, {"axis": -1})
-    hid = m.mm(both, "mtp_proj", "mtp_in", "mtp")
+    with m.part("embed"):
+        emb = b.tmp("mtp_emb")
+        m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [tokens]},
+             {"Out": [emb]})
+        emb32 = b.tmp("mtp_emb_f32")
+        m.op("cast", {"X": [emb]}, {"Out": [emb32]},
+             {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
+    with m.part("mtp"):
+        both = b.tmp("mtp_cat")
+        m.op("concat", {"X": [m.norm(hidden, "mtp_hnorm_scale", "mtp_hn"),
+                              m.norm(emb32, "mtp_enorm_scale", "mtp_en")]},
+             {"Out": [both]}, {"axis": -1})
+        hid = m.mm(both, "mtp_proj", "mtp_in")
     layer = cfg.num_layers
     attrs = m.attn_attrs()
 
@@ -883,12 +905,14 @@ def _build_mtp_program(cfg: MLADecoderConfig, sampling, kv_dtype: str):
               "WKVB": [f"dec_l{i}_wkv_b"]}, {"Out": [out]}, attrs)
         return out
 
-    valid = m.live_rows(slot_map, layer, kv_dtype)
+    with m.part("embed"):
+        valid = m.live_rows(slot_map, layer, kv_dtype)
     counts: List[str] = []
     hid = m.block(layer, hid, positions, attend, valid, counts)
-    logits = m.mm(m.norm(hid, "mtp_norm_scale", "mtp_fnorm", "head"),
-                  "dec_head", "mtp_logits", "head")
-    _emit_head(b, logits, "draft_tokens", None, None)
+    with m.part("head"):
+        logits = m.mm(m.norm(hid, "mtp_norm_scale", "mtp_fnorm"), "dec_head",
+                      "mtp_logits")
+        _emit_head(b, logits, "draft_tokens", None, None)
     prog._srv_params = dict.fromkeys(mla_param_specs(cfg))
     prog._srv_logits = logits
     prog._tp_degree = 1

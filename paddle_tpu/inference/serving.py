@@ -201,6 +201,11 @@ def init_decoder_weights(cfg: DecoderConfig, seed: int = 0
 class _B:
     """Tiny block-building helper: explicit var names, direct append_op."""
 
+    #: the part of the model the ops built from here on serve (attr ``part``,
+    #: which ``registry.run_op`` turns into their outermost scope); None,
+    #: as GPT-2's forms have it, adds nothing
+    part: Optional[str] = None
+
     def __init__(self, program: Program):
         self.blk = program.global_block()
         self._n = 0
@@ -218,8 +223,10 @@ class _B:
                                    persistable=True).name
 
     def op(self, type, inputs, outputs, attrs=None):
-        self.blk.append_op(type, inputs=inputs, outputs=outputs,
-                           attrs=attrs or {})
+        attrs = attrs or {}
+        if self.part is not None:
+            attrs = {"part": self.part, **attrs}
+        self.blk.append_op(type, inputs=inputs, outputs=outputs, attrs=attrs)
 
     # common composites --------------------------------------------------
     def matmul(self, x, y, transpose_Y=False, alpha=1.0, tag="mm"):

@@ -78,30 +78,29 @@ def _kda_mixer(ctx):
     w = {slot: ctx.in_(slot) for slot in WEIGHT_SLOTS}
     taps = w["Conv"].shape[1]
     l2_eps = float(ctx.attr("l2_epsilon", 1e-6))
-    with jax.named_scope("kda_part"):
-        pre, g, beta = kda_inputs(x, w, heads, dk)
-        if ctx.has_input("Valid"):
-            live = ctx.in_("Valid") != 0
-            g = jnp.where(live[:, None, None], g, 0.0)
-            beta = jnp.where(live[:, None], beta, 0.0)
-        if mode == "decode":
-            slots = ctx.in_("StateSlots").astype(jnp.int32)
-            tails = ctx.in_("ConvState")
-            conv, tail = short_conv_step(tails[slots], pre, w["Conv"])
-            q, k, v = normalised_heads(jax.nn.silu(conv), heads, l2_eps)
-            o, state = kda_decode(ctx.in_("State"), slots, q, k, v, g, beta)
-            ctx.set_out("StateOut", state)
-            ctx.set_out("ConvStateOut", tails.at[slots].set(tail))
-        else:
-            o, state = kda_prefill(
-                jax.nn.silu(short_conv(pre, w["Conv"])), g, beta, heads,
-                l2_eps)
-            if mode == "prefill":
-                slot = ctx.in_("StateSlots").astype(jnp.int32)[0]
-                last = ctx.in_("LastIndex").astype(jnp.int32)[0]
-                ctx.set_out("StateOut", lax.dynamic_update_index_in_dim(
-                    ctx.in_("State"), state, slot, 0))
-                ctx.set_out("ConvStateOut", lax.dynamic_update_index_in_dim(
-                    ctx.in_("ConvState"), short_conv_tail(pre, last, taps),
-                    slot, 0))
-        ctx.set_out("Out", kda_output(o, x, w, ctx.attr("epsilon", 1e-5)))
+    pre, g, beta = kda_inputs(x, w, heads, dk)
+    if ctx.has_input("Valid"):
+        live = ctx.in_("Valid") != 0
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+    if mode == "decode":
+        slots = ctx.in_("StateSlots").astype(jnp.int32)
+        tails = ctx.in_("ConvState")
+        conv, tail = short_conv_step(tails[slots], pre, w["Conv"])
+        q, k, v = normalised_heads(jax.nn.silu(conv), heads, l2_eps)
+        o, state = kda_decode(ctx.in_("State"), slots, q, k, v, g, beta)
+        ctx.set_out("StateOut", state)
+        ctx.set_out("ConvStateOut", tails.at[slots].set(tail))
+    else:
+        o, state = kda_prefill(
+            jax.nn.silu(short_conv(pre, w["Conv"])), g, beta, heads,
+            l2_eps)
+        if mode == "prefill":
+            slot = ctx.in_("StateSlots").astype(jnp.int32)[0]
+            last = ctx.in_("LastIndex").astype(jnp.int32)[0]
+            ctx.set_out("StateOut", lax.dynamic_update_index_in_dim(
+                ctx.in_("State"), state, slot, 0))
+            ctx.set_out("ConvStateOut", lax.dynamic_update_index_in_dim(
+                ctx.in_("ConvState"), short_conv_tail(pre, last, taps),
+                slot, 0))
+    ctx.set_out("Out", kda_output(o, x, w, ctx.attr("epsilon", 1e-5)))

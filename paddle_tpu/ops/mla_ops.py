@@ -41,31 +41,21 @@ def _mm(x, w):
                       preferred_element_type=jnp.float32)
 
 
-def _part(ctx):
-    """The part of the block an op belongs to (``mla_part``, ``moe_part``,
-    ``dense_ffn``, ``head``), as a named scope: the device trace then says
-    whose time a projection, a norm or an activation is."""
-    return jax.named_scope(ctx.attr("part", "") or "unscoped")
-
-
 @op("matmul_f32acc", no_grad=True)
 def _matmul_f32acc(ctx):
     """X ``(..., k)`` times Y ``(k, n)``: operands in Y's type (bfloat16 in
-    a served model), accumulation and Out in float32.  Attr: part."""
-    with _part(ctx):
-        ctx.set_out("Out", _mm(ctx.in_("X"), ctx.in_("Y")))
+    a served model), accumulation and Out in float32."""
+    ctx.set_out("Out", _mm(ctx.in_("X"), ctx.in_("Y")))
 
 
 @op("rms_norm", no_grad=True)
 def _rms_norm(ctx):
     x = ctx.in_("X")
     eps = ctx.attr("epsilon", 1e-6)
-    with _part(ctx):
-        x32 = x.astype(jnp.float32)
-        y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
-                            + eps)
-        ctx.set_out("Y", (y * ctx.in_("Scale").astype(jnp.float32))
-                    .astype(x.dtype))
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    ctx.set_out("Y", (y * ctx.in_("Scale").astype(jnp.float32))
+                .astype(x.dtype))
 
 
 def rope_interleaved(x, positions, theta: float):
@@ -84,20 +74,17 @@ def rope_interleaved(x, positions, theta: float):
 
 @op("rope_interleaved", no_grad=True)
 def _rope_interleaved(ctx):
-    """X ``(..., heads, d)``, Positions ``(...)`` int32; attrs theta,
-    part."""
-    with _part(ctx):
-        ctx.set_out("Out", rope_interleaved(
-            ctx.in_("X"), ctx.in_("Positions"), ctx.attr("theta", 10000.0)))
+    """X ``(..., heads, d)``, Positions ``(...)`` int32; attr theta."""
+    ctx.set_out("Out", rope_interleaved(
+        ctx.in_("X"), ctx.in_("Positions"), ctx.attr("theta", 10000.0)))
 
 
 @op("swiglu", no_grad=True)
 def _swiglu(ctx):
     g = ctx.in_("Gate").astype(jnp.float32)
     u = ctx.in_("Up")
-    with _part(ctx):
-        ctx.set_out("Out", (jax.nn.silu(g) * u.astype(jnp.float32))
-                    .astype(u.dtype))
+    ctx.set_out("Out", (jax.nn.silu(g) * u.astype(jnp.float32))
+                .astype(u.dtype))
 
 
 def route(x, w_gate, bias, top_k: int, scaling: float, normalize: bool):

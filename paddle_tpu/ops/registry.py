@@ -35,6 +35,9 @@ from ..utils import chaos as _chaos
 _SENTINEL_DIM = 97  # stands in for -1 (dynamic batch) during eval_shape
 
 OPS: Dict[str, "OpDef"] = {}
+#: the parts of a model that ops have been traced under (``run_op``): the
+#: names a scope path is searched for to say whose time an instruction is
+PARTS: set = set()
 
 
 class OpDef:
@@ -418,9 +421,19 @@ def run_op(op: Operator, env: Dict[str, Any], block=None):
     # profiles (jax.profiler / TensorBoard) attribute kernels back to
     # framework ops — the annotation-correlation analog of the
     # reference's CUPTI DeviceTracer (platform/device_tracer.cc).
+    # An op that says which part of the model it serves (attr ``part``: the
+    # serving decoders' builders) lies under that scope first: the compiled
+    # program then says of every instruction, fusions included, whose time
+    # it is (profiler.device_symbols).
+    part = op.attrs.get("part")
     try:
-        with jax.named_scope(op.type):
-            d.lower(ctx)
+        if part:
+            PARTS.add(part)
+            with jax.named_scope(part), jax.named_scope(op.type):
+                d.lower(ctx)
+        else:
+            with jax.named_scope(op.type):
+                d.lower(ctx)
     except Exception as e:
         _raise_with_callstack(op, e)
     if _chaos.nan_poison_target() is not None:

@@ -12,10 +12,17 @@ TPU-native shape:
   executor loop to instrument).
 * device events — XLA owns the device timeline.  The CUPTI analog is
   the JAX/XLA profiler: ``start_profiler`` with a trace dir starts
-  ``jax.profiler`` (TensorBoard trace with per-HLO timing); op→kernel
-  correlation comes from ``jax.named_scope`` annotations emitted by the
-  executor during tracing (the annotation-correlation trick
-  device_tracer.cc uses with CUPTI correlation ids).
+  ``jax.profiler`` (TensorBoard trace with per-HLO timing).  The profile
+  names a device event by its HLO instruction and carries no scope (JAX
+  0.9.0 / libtpu 0.0.34), so op→kernel correlation does not come from the
+  profile: the ``jax.named_scope`` annotations the executor emits while
+  tracing (``registry.run_op``: the part of the model, then the op's type)
+  reach the compiled program's text, the executor notes which compiled
+  steps ran while a span recorded (``note_program``), and
+  ``device_symbols()`` reads their text into a table from instruction to
+  scope path afterwards; ``device_table(rows)`` joins a profile's events to
+  it: seconds by program, part and op type (the correlation
+  device_tracer.cc gets from CUPTI's ids).
 
 Unified timeline (r13): events carry a *lane* (``cat``) — "host" for
 executor RecordEvents, "serving" for scheduler decisions
@@ -50,6 +57,7 @@ import collections
 import contextlib
 import json
 import os
+import re
 import threading
 import time
 from typing import Deque, Dict, List, Optional
@@ -62,6 +70,7 @@ __all__ = [
     "enable_profiler", "disable_profiler", "reset_profiler",
     "start_profiler", "stop_profiler", "profiler", "is_profiler_enabled",
     "get_events", "dropped_events", "npu_profiler", "cuda_profiler", "LANES",
+    "note_program", "device_symbols", "device_table",
 ]
 
 #: lane -> chrome-trace pid.  Lanes not listed get pids allocated past
@@ -80,6 +89,10 @@ _TRACE_DIR: Optional[str] = None
 # holds the last half hour of a busy engine
 _EVENTS: Deque[dict] = collections.deque(maxlen=1 << 16)
 _DROPPED = 0  # events the ring pushed out since the last reset
+# the compiled steps that ran while a span recorded, by id of the jitted
+# callable (which the note keeps alive): label, callable, the abstract
+# signature of the call, calls so far; ``device_symbols`` adds the table
+_PROGRAMS: Dict[int, dict] = {}
 #: a JAX profiler session is active (about 40 ns a call)
 _session_active = _Annotation.is_enabled
 #: every thread's live event stack, keyed by thread ident — the
@@ -284,6 +297,7 @@ def reset_profiler():
     global _DROPPED
     with _GLOBAL_LOCK:
         _EVENTS.clear()
+        _PROGRAMS.clear()
         _DROPPED = 0
         live = {t.ident for t in threading.enumerate()}
         for ident in list(_STACKS):
@@ -303,15 +317,22 @@ def disable_profiler(sorted_key: Optional[str] = None,
     (utils/cost_model.py) so bucket autotune runs on measured rates."""
     global _ENABLED, _TRACE_DIR
     _ENABLED = False
+    device = None
     if _TRACE_DIR is not None:
+        import glob
+
         import jax
 
         jax.profiler.stop_trace()
+        written = sorted(glob.glob(os.path.join(
+            _TRACE_DIR, "plugins", "profile", "*", "*.xplane.pb")))
+        if profile_path and written:
+            device = device_table(written[-1])
         _TRACE_DIR = None
     with _GLOBAL_LOCK:
         events = list(_EVENTS)
     if profile_path:
-        _write_chrome_trace(events, profile_path)
+        _write_chrome_trace(events, profile_path, device)
     summary = summarize(events, sorted_key or "default")
     _feed_calibration(summary)
     if summary and print_summary:
@@ -343,6 +364,329 @@ def get_events() -> List[dict]:
 def dropped_events() -> int:
     """Completed events the ring pushed out since the last reset."""
     return _DROPPED
+
+
+# ---- device symbols ---------------------------------------------------------
+# A device profile names an operation by its HLO instruction (``fusion.123``)
+# and, on the stack this repo supports, says nothing of where it came from.
+# The compiled program does: every instruction carries the path of named
+# scopes it was traced under (``registry.run_op``: the part of the model an
+# op serves, then its type; the lowerings' own scopes below).  So the program
+# notes which compiled steps ran while a span recorded, and afterwards reads
+# their text into a table from instruction to scope path.
+#: what JAX itself puts into a scope path: transforms and call wrappers
+_JAX_WRAPPER = re.compile(
+    r"^(?:\w+\(.*\)|pjit|while|body|cond|body_fun|cond_fun|branch_\d+_fun|"
+    r"closed_call|core_call|custom_jvp_call|custom_vjp_call|checkpoint|"
+    r"remat|scan)$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+)\s+=\s+(.+)$")
+_HLO_SHAPE = re.compile(r"^\(?([a-z0-9]+\[[0-9,]*\])")
+_HLO_OPCODE = re.compile(r"\s([a-z][a-z0-9\-]*)\(")
+#: the computations an instruction runs as device operations of their own
+#: (a fusion's ``calls=`` and a reduction's ``to_apply=`` are not)
+_HLO_CALLEES = {
+    "while": re.compile(r"(?:condition|body)=%?([\w.\-]+)"),
+    "call": re.compile(r"to_apply=%?([\w.\-]+)"),
+    "conditional": re.compile(
+        r"(?:true_computation|false_computation)=%?([\w.\-]+)"
+        r"|branch_computations=\{([^}]*)\}"),
+}
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_FUSED = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
+_HLO_NAME = re.compile(r"[\w.\-]+")
+MOSAIC_TARGET = "tpu_custom_call"
+
+
+def _abstract(x):
+    import jax
+
+    return jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=getattr(x, "sharding", None),
+        weak_type=getattr(x, "weak_type", False))
+
+
+def note_program(label: str, jitted, args: tuple):
+    """One call of a compiled step while a span records (``Executor.
+    _execute``, under its ``executor/call``).  The first call of an entry
+    keeps its label, the jitted callable and the abstract signature of the
+    call (shapes, types and placements: no array); every call is counted.
+    An entry without one executable to read (a hybrid program's segments,
+    the checkify wrapper) is left out."""
+    note = _PROGRAMS.get(id(jitted))
+    if note is None:
+        if not hasattr(jitted, "lower"):
+            return
+        import jax
+
+        note = {"program": label, "jitted": jitted, "calls": 0,
+                "abstract": jax.tree.map(_abstract, args)}
+        with _GLOBAL_LOCK:
+            note = _PROGRAMS.setdefault(id(jitted), note)
+    note["calls"] += 1
+
+
+def _split_path(op_name: str) -> List[str]:
+    """``a/f(b/c)/d`` -> ``[a, f(b/c), d]``: a slash inside a transform's
+    parentheses is part of its name."""
+    out, depth, at = [], 0, 0
+    for i, ch in enumerate(op_name):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        elif ch == "/" and depth == 0:
+            out.append(op_name[at:i])
+            at = i + 1
+    out.append(op_name[at:])
+    return out
+
+
+def scopes_of(op_name: str) -> List[str]:
+    """The named scopes of an instruction's ``op_name``, outer to inner:
+    the path less its last element (the primitive) and less JAX's own
+    wrappers (``jit(pt_prefill)``, ``pjit``, transforms, loop bodies)."""
+    return [p for p in _split_path(op_name)[:-1]
+            if p and not _JAX_WRAPPER.match(p)]
+
+
+def _parse_computations(text: str):
+    """``(module name, entry computation, computation -> [(instruction
+    name, the rest of its line)])`` of a compiled module's text."""
+    computations: Dict[str, List[tuple]] = {}
+    module = entry = current = None
+    for line in text.splitlines():
+        if current is None:
+            if line.startswith("HloModule "):
+                module = line.split()[1].rstrip(",")
+            elif line.endswith("{") and "->" in line \
+                    and not line[0].isspace():
+                words = line.split()
+                is_entry = words[0] == "ENTRY"
+                current = words[1 if is_entry else 0].lstrip("%")
+                computations[current] = []
+                if is_entry:
+                    entry = current
+        elif line.startswith("}"):
+            current = None
+        else:
+            found = _HLO_INSTRUCTION.match(line)
+            if found:
+                computations[current].append(found.groups())
+    return module, entry, computations
+
+
+def _op_name(rest: str) -> Optional[str]:
+    said = _HLO_OP_NAME.search(rest)
+    return said.group(1) if said else None
+
+
+def hlo_symbols(text: str) -> dict:
+    """A compiled module's text as a table: its name, and for every
+    instruction of the entry computation and of the computations it runs
+    (loop bodies and conditions, branches, calls) its ``name`` as a device
+    event has it (``fusion.123``), ``opcode``, first output ``shape``
+    (``f32[8,128]``), ``op_name`` as it stands (or None), ``scopes``
+    (``scopes_of`` it; a Mosaic kernel's name is its innermost scope),
+    ``part`` (the outermost scope that is a part of the model:
+    ``registry.PARTS``) and ``op`` (the outermost scope that is a
+    registered op type).
+
+    XLA:TPU leaves some instructions without a scope: a fusion it built
+    late, and the data movement it adds itself (layout copies, the slices
+    and copies that bring an operand in ahead of its use).  Those take the
+    part they serve, and ``via`` says how it was found: ``fused`` (the
+    instructions inside the fusion name one part), ``operands`` (what it
+    reads comes from one part), ``users`` (what reads it lies in one
+    part).  ``via`` is None where the instruction's own ``op_name`` gave
+    the part; ``part`` is None where nothing did."""
+    from .ops.registry import OPS, PARTS
+
+    def part_of(scopes):
+        return next((s for s in scopes if s in PARTS), None)
+
+    module, entry, computations = _parse_computations(text)
+    rows, seen, todo = [], set(), [entry] if entry else []
+    reads: Dict[str, List[str]] = {}
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in computations:
+            continue
+        seen.add(comp)
+        for name, rest in computations[comp]:
+            shape = _HLO_SHAPE.match(rest)
+            opcode = _HLO_OPCODE.search(rest)
+            operands = []
+            if opcode:
+                operands = _HLO_OPERAND.findall(
+                    rest[opcode.end():].split(")", 1)[0])
+                opcode = opcode.group(1)
+            op_name = _op_name(rest)
+            scopes = scopes_of(op_name) if op_name else []
+            via = None
+            if opcode == "custom-call" and \
+                    f'custom_call_target="{MOSAIC_TARGET}"' in rest:
+                kernel = re.sub(r"\.\d+$", "", name)
+                if not scopes or scopes[-1] != kernel:
+                    scopes.append(kernel)
+            elif opcode == "fusion" and part_of(scopes) is None:
+                fused = _HLO_FUSED.search(rest)
+                inside = [scopes_of(said) for said in (
+                    _op_name(line) for _, line in computations.get(
+                        fused.group(1) if fused else "", ())) if said]
+                inside = [sc for sc in inside if part_of(sc)]
+                if len({part_of(sc) for sc in inside}) == 1:
+                    scopes, via = inside[0], "fused"
+            if opcode in _HLO_CALLEES:
+                for found in _HLO_CALLEES[opcode].findall(rest):
+                    todo += _HLO_NAME.findall(
+                        found if isinstance(found, str) else ",".join(found))
+            reads[name] = operands
+            rows.append({
+                "name": name, "opcode": opcode,
+                "shape": shape.group(1) if shape else None,
+                "op_name": op_name, "scopes": scopes,
+                "part": part_of(scopes), "via": via,
+                "op": next((s for s in scopes if s in OPS), None)})
+    # what has no part yet takes the one part of what it reads, else the one
+    # part of what reads it, again while that settles anything (a chain of
+    # slice-start, slice-done and a layout copy is three deep)
+    by_name = {r["name"]: r for r in rows}
+    read_by: Dict[str, List[str]] = {}
+    for name, operands in reads.items():
+        for o in operands:
+            read_by.setdefault(o, []).append(name)
+    for _ in range(8):
+        settled = False
+        for r in rows:
+            if r["part"] is not None or r["opcode"] == "parameter":
+                continue
+            for via, near in (("operands", reads[r["name"]]),
+                              ("users", read_by.get(r["name"], ()))):
+                parts = {by_name[n]["part"] for n in near
+                         if n in by_name} - {None}
+                if len(parts) == 1:
+                    r["part"], r["via"] = parts.pop(), via
+                    settled = True
+                    break
+        if not settled:
+            break
+    return {"module": module, "instructions": rows}
+
+
+def _read_symbols(note: dict) -> dict:
+    """The table of one noted entry: the text of the executable its calls
+    ran (JAX's in-process caches hand the lowering and the executable back:
+    nothing is traced or compiled again), parsed."""
+    t0 = time.perf_counter()
+    feed = note["abstract"][-1]
+    table = {"program": note["program"], "module": None,
+             "feed": {k: f"{v.dtype.name}[{','.join(map(str, v.shape))}]"
+                      for k, v in sorted(feed.items())}
+             if isinstance(feed, dict) else {},
+             "instructions": []}
+    try:
+        lowered = note["jitted"].lower(*note["abstract"])
+        table.update(hlo_symbols(lowered.compile().as_text()))
+        # JAX's persistent cache keys an executable without its metadata:
+        # one that an older tree compiled comes back under that tree's
+        # scopes.  The lowering is always this process's own trace, so an
+        # outermost pair of scopes (``run_op``'s: the part, the op's type)
+        # that it does not hold gives such an executable away.
+        # (a call's own location ends in a scope, not in a primitive)
+        own = {tuple(scopes_of(path + tail)[:2]) for path in re.findall(
+            r'loc\("([^"]+)"', lowered.as_text(debug_info=True))
+            for tail in ("", "/call")}
+        table["foreign"] = sum(
+            1 for i in table["instructions"]
+            if i["op_name"] and i["opcode"] != "parameter"
+            and tuple(scopes_of(i["op_name"])[:2]) not in own)
+    except Exception as e:   # a table is an observation: never the run's fault
+        table["error"] = f"{type(e).__name__}: {e}"[:240]
+    table["read_s"] = time.perf_counter() - t0
+    return table
+
+
+def device_symbols() -> List[dict]:
+    """For every compiled step that ran while a span recorded: ``program``
+    (its label), ``module`` (``jit_pt_<label>``), ``feed`` (its feeds'
+    shapes), ``calls`` (while recording), ``read_s``, ``instructions``
+    (``hlo_symbols``) and ``foreign`` (how many of them lie under a part
+    and op type that this process's own trace of the step does not hold:
+    above 0 the executable came from the persistent compile cache, where
+    another tree with other scopes had put it, and its parts are that
+    tree's).  An entry's text is read once, at the first call here after it
+    was noted, and never while a JAX profiler session is active: reading
+    belongs after the window it would disturb."""
+    if _session_active():
+        raise RuntimeError("device_symbols() reads every noted executable's "
+                           "text: call it after the profiler session")
+    with _GLOBAL_LOCK:
+        notes = list(_PROGRAMS.values())
+    out = []
+    for note in notes:
+        if "table" not in note:
+            note["table"] = _read_symbols(note)
+        out.append(dict(note["table"], calls=note["calls"]))
+    return out
+
+
+def _event_key(name: str) -> tuple:
+    """``(instruction name, first output shape)`` of a device event: from
+    the whole instruction, as the profile names an event (``%fusion.7 =
+    bf16[8,128]{1,0} fusion(...)``), or from ``<kind> <shape>|<name>``, as
+    the benchmark's ``trace.read_rows`` keeps it."""
+    if " = " in name:
+        short, rest = name.split(" = ", 1)
+        shape = _HLO_SHAPE.match(rest)
+        return short.lstrip("%"), shape.group(1) if shape else None
+    label, _, short = name.partition("|")
+    return short or label, label.partition(" ")[2] or None
+
+
+def _xplane_rows(path: str) -> List[tuple]:
+    """``(plane, line, event name, start_ns, duration_ns)`` of the device
+    planes' ``XLA Ops`` lines in a written ``.xplane.pb``."""
+    from jax.profiler import ProfileData
+
+    return [(plane.name, line.name, ev.name, int(ev.start_ns),
+             int(ev.duration_ns))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/device:")
+            for line in plane.lines if line.name == "XLA Ops"
+            for ev in line.events]
+
+
+def device_table(rows) -> List[dict]:
+    """The device's half of the op summary (reference: profiler.h:208's
+    table of ops, which there came from CUPTI's correlation ids): seconds
+    and events by ``program``, ``part`` and ``op`` type, largest first.
+    ``rows``: device events as ``(plane, line, name, start_ns,
+    duration_ns)``, or the path of a ``.xplane.pb``.  Each event is looked
+    up in ``device_symbols()`` by its instruction's name and output shape;
+    where the noted steps that hold the pair disagree, or none holds it,
+    that column is None."""
+    if isinstance(rows, (str, os.PathLike)):
+        rows = _xplane_rows(os.fspath(rows))
+    known: Dict[tuple, tuple] = {}
+    for table in device_symbols():
+        for ins in table["instructions"]:
+            sets = known.setdefault((ins["name"], ins["shape"]),
+                                    (set(), set(), set()))
+            for held, value in zip(sets, (table["program"], ins["part"],
+                                          ins["op"])):
+                held.add(value)
+    out: Dict[tuple, dict] = {}
+    for plane, _line, name, _start, dur in rows:
+        if not plane.startswith("/device:"):
+            continue
+        group = tuple(next(iter(held)) if len(held) == 1 else None
+                      for held in known.get(_event_key(name), ((),) * 3))
+        row = out.setdefault(group, dict(
+            zip(("program", "part", "op"), group), seconds=0.0, events=0))
+        row["seconds"] += dur / 1e9
+        row["events"] += 1
+    return sorted(out.values(), key=lambda r: -r["seconds"])
 
 
 def _feed_calibration(summary: List[dict]):
@@ -429,7 +773,8 @@ def _lane_pids(events: List[dict]) -> Dict[str, int]:
     return pids
 
 
-def _write_chrome_trace(events: List[dict], path: str):
+def _write_chrome_trace(events: List[dict], path: str,
+                        device: Optional[List[dict]] = None):
     pids = _lane_pids(events)
     used = {e.get("cat", "host") for e in events}
     trace_events = [
@@ -466,6 +811,10 @@ def _write_chrome_trace(events: List[dict], path: str):
             ev["args"] = e["args"]
         trace_events.append(ev)
     trace = {"traceEvents": trace_events}
+    if device:
+        # seconds on the device by program, part and op type
+        # (``device_table``; tools/trace_report.py prints it)
+        trace["deviceTable"] = device
     d = os.path.dirname(path)
     if d:
         os.makedirs(d, exist_ok=True)

@@ -304,6 +304,94 @@ def test_moe_gmm_decode_module_is_the_parents(gated, v5e, monkeypatch):
         [DECODE_GMM_MODULES[gated]]
 
 
+# --- JoyAI's serving forms, whole: every device operation has a part ---------
+@pytest.mark.parametrize("mode", ["prefill", "decode"])
+def test_joyai_form_compiled_for_v5e_names_every_operation(mode, v5e,
+                                                           monkeypatch):
+    """The form's ops lowered as the executor lowers them (``registry.
+    run_op``: the part, then the op's type) and compiled by the chip's
+    compiler at the configuration's widths and a depth of two (one dense,
+    one expert layer; the rehearsal's widths are not the chip's: Mosaic
+    refuses their latent row of 40 lanes): ``profiler.hlo_symbols`` finds a
+    part for every Mosaic kernel and every fusion of the compiled program,
+    the kernels under their own names."""
+    import json
+    import os
+
+    from paddle_tpu import profiler
+    from paddle_tpu.inference.mla_decoder import (MLADecoderConfig,
+                                                  mla_param_specs)
+    from paddle_tpu.ops import kda_kernels, mla_kernels, registry
+
+    for module in (pk, mla_kernels, kda_kernels):
+        monkeypatch.setattr(module, "_use_pallas", lambda: True)
+        monkeypatch.setattr(module, "_interpret", lambda: False)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "joyai-llm-flash.json")) as f:
+        size = dict(json.load(f), num_hidden_layers=2)
+    cfg = MLADecoderConfig.from_source(
+        size, max_seq_len=size["deployment"]["max_context"],
+        weights_dtype=size["weights_dtype"])
+    rows, batch, width = 1024, 128, 32
+    i32 = jnp.int32
+    feed = {"prefill": {"tokens": (1, rows), "positions": (1, rows),
+                        "last_index": (1,), "slot_mapping": (rows,)},
+            "decode": {"tokens": (batch,), "positions": (batch,),
+                       "block_tables": (batch, width),
+                       "context_lens": (batch,),
+                       "slot_mapping": (batch,)}}[mode]
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=v5e)
+
+    pools = {n: shaped(cfg.kv_cache_config(512, 16, "bfloat16").pool_shape(),
+                       "bfloat16") for n in cfg.cache_pool_names()}
+    weights = {n: shaped(s, cfg.weights_dtype)
+               for n, s in mla_param_specs(cfg).items()}
+    prog, feeds, fetch = cfg.build_program(mode, kv_dtype="bfloat16")
+    assert sorted(feeds) == sorted(feed)
+    block = prog.global_block()
+
+    def pt_step(pools, weights, feed):
+        env = {**weights, **pools, **feed}
+        for op_ in block.ops:
+            registry.run_op(op_, env, block)
+        return [env[n] for n in fetch], {n: env[n] for n in pools}
+
+    text = jax.jit(pt_step, donate_argnums=0).lower(
+        pools, weights, {k: shaped(s, i32) for k, s in feed.items()}) \
+        .compile().as_text()
+    table = profiler.hlo_symbols(text)
+    assert table["module"] == "jit_pt_step"
+    ins = table["instructions"]
+    kernels = [i for i in ins if i["opcode"] == "custom-call"
+               and i["scopes"][-1:] == [i["name"].split(".")[0]]]
+    assert len(kernels) == text.count(
+        'custom_call_target="tpu_custom_call"')
+    attention = "mla_prefill" if mode == "prefill" else "mla_decode"
+    # two layers' cache append and attention, one expert layer's two matmuls
+    assert sorted(k["scopes"][-1] for k in kernels) == sorted(
+        ["latent_append", attention] * 2 + ["moe_gmm"] * 2)
+    assert {k["scopes"][-1]: (k["part"], k["op"]) for k in kernels} == {
+        "latent_append": ("mla_part", "latent_cache_append"),
+        attention: ("mla_part", "mla_prefill_attention" if mode == "prefill"
+                    else "mla_paged_attention"),
+        "moe_gmm": ("moe_part", "moe_experts")}
+    fusions = [i for i in ins if i["opcode"] == "fusion"]
+    assert len(fusions) > 50
+    assert [i["name"] for i in fusions if i["part"] is None] == []
+    assert {i["part"] for i in fusions} == {
+        "embed", "mla_part", "moe_part", "dense_ffn", "head"}
+    # what the compiler adds itself (layout copies, operands brought in
+    # ahead of their use) takes the part it serves; what stays bare is a
+    # copy or two that nothing of the model reads (a feed's, a prefetch the
+    # scheduler left without a reader)
+    bare = [i for i in ins if i["part"] is None
+            and i["opcode"] not in ("parameter", "constant", "tuple")]
+    assert len(bare) <= 4 and {i["opcode"] for i in bare} <= {
+        "copy-start", "copy-done"}, bare
+
+
 # --- the KDA kernels at Kimi-Linear's widths: 32 heads of 128 ----------------
 KDA_POOL = (129, 32, 128, 128)    # the cell's state pool a layer, float32
 

@@ -7,6 +7,9 @@ terminal.  This tool turns a trace file into the terminal view: one
 summary row per lane, the top events by total time inside each, and a
 stable one-line ``TRACE={json}`` (the ``SERVING=``/``BENCH=``
 convention) so the driver can diff phase breakdowns across rounds.
+Where the session also traced a device, the file carries the profiler's
+``deviceTable`` (seconds by program, part of the model and op type:
+``profiler.device_table``) and the report prints it below the lanes.
 
 Usage:
   python tools/trace_report.py TRACE.json [--top N] [--json]
@@ -145,12 +148,18 @@ def report(trace: dict, top: int = 10) -> dict:
             key=lambda kv: -kv[1]["total_ms"])[:top])
         for r in row["by_name"].values():
             r["total_ms"] = round(r["total_ms"], 6)
-    return {
+    rep = {
         "n_events": n_events,
         "span_ms": (round((t_max - t_min) / 1e3, 6)
                     if n_events else 0.0),
         "lanes": dict(sorted(lanes.items())),
     }
+    if trace.get("deviceTable"):
+        # the device's seconds by program, part and op type, as the
+        # profiler joined the device profile to its compiled steps
+        # (profiler.device_table); written where a session traced a device
+        rep["device"] = trace["deviceTable"][:top]
+    return rep
 
 
 def validate_request_lane(trace: dict, top: int = 5) -> dict:
@@ -256,6 +265,14 @@ def format_table(rep: dict) -> str:
                      f"{row['total_ms']:>12.3f}  {tops}{inst}{ctr}")
     lines.append(f"span: {rep['span_ms']:.3f} ms over "
                  f"{rep['n_events']} events")
+    if rep.get("device"):
+        lines.append(f"{'Device: program':<18} {'part':<12} {'op':<26} "
+                     f"{'Events':>8} {'Total(ms)':>12}")
+        for r in rep["device"]:
+            lines.append(
+                f"{r['program'] or '-':<18} {r['part'] or '-':<12} "
+                f"{r['op'] or '-':<26} {r['events']:>8} "
+                f"{r['seconds'] * 1e3:>12.3f}")
     req = rep.get("requests")
     if req and req.get("present"):
         lines.append(
