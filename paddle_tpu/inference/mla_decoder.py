@@ -55,7 +55,7 @@ import numpy as np
 
 from ..framework.core import Program
 from ..framework.dtype import VarType, convert_dtype
-from ..ops import mla_kernels
+from ..ops import kda_kernels, mla_kernels
 from .kv_cache import KVCacheConfig
 from .spec_decode import Proposer
 
@@ -638,12 +638,16 @@ def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
     """``prog._srv_kernel_stats`` of a hybrid model's prefill and decode
     forms: the KDA kernels' calls, and the real tokens (prefill) or live
     sequences (decode: rows whose slot is not the padding's) they took,
-    summed over the KDA layers; beside ``_decode_walk``'s counts of the MLA
-    layers."""
+    summed over the KDA layers; a prefill's grid steps, from the bucket it
+    is fed by the sizes the kernel's wrapper uses (``kda_kernels.
+    prefill_grid``); beside ``_decode_walk``'s counts of the MLA layers."""
     kda = len(cfg.kda_layers)
     if mode == "prefill":
+        _, _, (groups, chunks) = kda_kernels.prefill_grid(
+            int(np.size(feed["tokens"])), cfg.kda_heads, cfg.kda_head_dim)
         return {"kda_prefill_calls": kda, "kda_prefill_tokens":
-                kda * (int(np.asarray(feed["last_index"])[0]) + 1)}
+                kda * (int(np.asarray(feed["last_index"])[0]) + 1),
+                "kda_prefill_grid_steps": kda * groups * chunks}
     live = int((np.asarray(feed["state_slots"])
                 < kv_config.state_slots).sum())
     out = {"kda_decode_calls": kda, "kda_decode_sequences": kda * live}

@@ -12,20 +12,32 @@ caller), log-decay ``g <= 0`` per channel of ``d_k`` and write strength
 * ``kda_recurrence`` — exactly that, one token at a time (``lax.scan``): the
   oracle of both kernels and the CPU fallback.
 * ``kda_prefill`` — the same over one whole prompt in chunks of ``CHUNK``
-  tokens, a grid step a (head, chunk) with the head's state carried across
-  the chunks in VMEM.  Inside a chunk ``u`` solves ``(I + A) U = beta (V -
-  Kbar S_0)`` with ``A_ij = beta_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])``
-  for ``j < i`` (``G`` the running sum of ``g``), and ``o = Qbar S_0 + B U``
-  with ``B`` the same sum over ``q_i`` and ``j <= i``.  The textbook form
-  writes ``exp(G_i - G_j)`` as ``exp(G_i) exp(-G_j)``, whose second factor
-  overflows float32 under strong decay.  Here every pair ``j < i`` is split
-  at the boundary ``b`` of the smallest aligned power-of-two block that holds
-  both, ``exp(G_i - G_b) exp(G_b - G_j)``: both factors at most 1, and one
-  level of blocks is one matmul (``log2(CHUNK)`` levels).  The same levels
-  invert ``I + A`` block by block (``[[X, 0], [Y, Z]]^-1 = [[X^-1, 0],
-  [-Z^-1 Y X^-1, Z^-1]]``).  ``G``, the inverse and the state are held in
-  float32 and the solve ``U = T rhs`` is a float32 product; the operands of
-  the other matmuls are bfloat16, accumulated in float32 (``_SOLVE``).
+  tokens.  The grid is ``(heads // group, chunks)`` (``prefill_grid``): a
+  step takes chunk ``c`` of a group of heads, whose columns of ``[q | k |
+  v]``, of the decay and of the output are adjacent and so one block, with
+  the group's states carried across the chunks in VMEM.  Inside a chunk
+  ``u`` solves ``(I + A) U = beta (V - Kbar S_0)`` with ``A_ij = beta_i
+  sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])`` for ``j < i`` (``G`` the
+  running sum of ``g``), and ``o = Qbar S_0 + B U`` with ``B`` the same sum
+  over ``q_i`` and ``j <= i``.  The textbook form writes ``exp(G_i - G_j)``
+  as ``exp(G_i) exp(-G_j)``, whose second factor overflows float32 under
+  strong decay.  Here every pair ``j < i`` is split at the boundary ``b`` of
+  the smallest aligned power-of-two block that holds both, ``exp(G_i - G_b)
+  exp(G_b - G_j)``: both factors at most 1, and one level of blocks is one
+  matmul (``log2(CHUNK)`` levels).  The same levels invert ``I + A`` block
+  by block (``[[X, 0], [Y, Z]]^-1 = [[X^-1, 0], [-Z^-1 Y X^-1, Z^-1]]``).
+  ``G``, the inverse and the state are held in float32 and the solve ``U =
+  T rhs`` is a float32 product; the operands of the other matmuls are
+  bfloat16, accumulated in float32 (``_SOLVE``).  A head's products are a
+  chain of about 17 small ones, each waiting for the one before, and the
+  compiler schedules a step's body alone and much in the order written.  So
+  the heads of a group, which need nothing of each other, are written side
+  by side, stage by stage, and a level's pair products (which need ``G``
+  alone) stand inside the merge of the level before: a product that waits
+  has another beside it.  What depends on the level alone (the boundary's
+  selector, the halves, the pairs' mask, the diagonal and the triangle) is
+  built once a step for the group.  A head's output and state are those of
+  one head a step, bit for bit.
 * ``kda_decode`` — one token a sequence against the state pool ``(slots + 1,
   heads, d_k, d_v)``, rewritten in place (the pool aliased onto the output):
   a grid step reads one sequence's states through its slot number, applies
@@ -51,9 +63,16 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_kernels import LANES, _interpret, _use_pallas, own_jit
 
 #: tokens one ``kda_prefill`` grid step takes (a power of two).  Measured on
-#: the chip (PR 36, one layer's call over 8,192 tokens, 32 heads of 128): 64
-#: tokens a step 14.4 ms, 128 11.5, 32 21.0 (a step's fixed cost, and at 64
-#: the chunk's square matrices fill half the lanes)
+#: the chip (PR 36, one layer's call over 8,192 tokens, 32 heads of 128, one
+#: head a step): 64 tokens a step 14.4 ms, 128 11.5, 32 21.0 (a step's fixed
+#: cost, and at 64 the chunk's square matrices fill half the lanes).  By
+#: heads a step at 128 tokens (PR 40, the same call; one head a step as the
+#: parent had it 7.07 ms): 1 head 6.00 ms, 2 4.80, 4 4.26, 8 4.23; written
+#: a level of every head before the next level of any, but a level's pairs
+#: and its merge one after the other, 7.07, 6.15, 5.84, 5.71; a head after
+#: the other in one body 7.07, 6.88, 6.74, 6.68 (the compiler does not
+#: interleave what is not written side by side); the masks as constant
+#: operands instead of built in the step: no change (5.83 at 4 heads)
 CHUNK = 128
 _HI = lax.Precision.HIGHEST
 #: the precision of the products that build the triangular inverse level by
@@ -65,6 +84,14 @@ _HI = lax.Precision.HIGHEST
 #: The solve itself (``U = T rhs``) and the running sum of the decay stay at
 #: HIGHEST
 _SOLVE = lax.Precision.DEFAULT
+#: VMEM a ``kda_prefill`` grid step may plan for by ``_group_vmem``'s count:
+#: half of what the compiler gives a kernel that asks for nothing (16 MiB on
+#: a v5e), the other half for its spill slots.  At the cell's sizes that is
+#: 4 heads a step and not 8, and the chip agrees for another reason: 8 are
+#: no faster (4.23 against 4.26 ms a call) and their body, twice as long, is
+#: traced and lowered once a prefill bucket (PR 40: the cell's warm set-up
+#: +9.6 s at 8 heads a step)
+_GROUP_VMEM = 8 * 1024 * 1024
 _NT = (((1,), (1,)), ((), ()))       # x @ y^T
 _TN = (((0,), (0,)), ((), ()))       # x^T @ y
 
@@ -108,14 +135,23 @@ def normalised_heads(qkv, heads: int, l2_eps: float):
 
 
 def _kda_prefill_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref,
-                        st_ref, *, chunk, l2_eps):
-    """Grid step ``(h, c)``: chunk ``c`` of head ``h``.  ``q_ref``, ``k_ref``
-    and ``v_ref`` are the head's columns of the convolution's output (before
-    the l2 norm), ``g_ref`` its log-decay a token, ``b_ref`` the chunk's
-    write strengths, a lane a head.  ``st_ref`` (d_v, d_k) is the head's
-    state transposed, so that a decay per channel of ``d_k`` scales lanes."""
+                        st_ref, *, chunk, group, l2_eps):
+    """Grid step ``(hg, c)``: chunk ``c`` of the ``group`` heads ``hg * group
+    + j``.  ``q_ref``, ``k_ref`` and ``v_ref`` are the group's columns of the
+    convolution's output (before the l2 norm), a head's ``d`` beside the
+    next's, ``g_ref`` its log-decay a token, ``b_ref`` the chunk's write
+    strengths, a lane a head.  ``st_ref`` (group, d_v, d_k) holds the heads'
+    states transposed, so that a decay per channel of ``d_k`` scales lanes.
+
+    A head's values are those of one head a step, product for product.  What
+    the group changes is what stands beside what: each stage is written for
+    every head before the next stage of any, and a level's pair products
+    (which need nothing of the inverse) stand between the two products of
+    the level before's merge, so that a product waiting for its operand has
+    another head's, or the next level's, beside it."""
     f32, bf16 = jnp.float32, jnp.bfloat16
-    h, c = pl.program_id(0), pl.program_id(1)
+    hg, c = pl.program_id(0), pl.program_id(1)
+    d = q_ref.shape[1] // group
 
     @pl.when(c == 0)
     def _fresh():
@@ -124,75 +160,120 @@ def _kda_prefill_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref,
     def unit(x):
         return x * lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + l2_eps)
 
-    q = unit(q_ref[...].astype(f32)) * q_ref.shape[1] ** -0.5
-    k = unit(k_ref[...].astype(f32))
-    b = b_ref[...].astype(f32)
-    lane = lax.broadcasted_iota(jnp.int32, b.shape, 1)
-    beta = jnp.sum(jnp.where(lane == h, b, 0.0), axis=1, keepdims=True)
-    kb, vb = k * beta, v_ref[...].astype(f32) * beta
+    # what no head owns: built once a step
     row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    # the running sum of the log-decay inside the chunk
-    G = jnp.dot((col <= row).astype(f32), g_ref[...].astype(f32),
-                precision=_HI, preferred_element_type=f32)
-    tok = lax.broadcasted_iota(jnp.int32, G.shape, 0)
-    G16 = G.astype(bf16)
-    k16 = k.astype(bf16)
+    tok = lax.broadcasted_iota(jnp.int32, (chunk, d), 0)
+    diag = row == col
+    eye, causal = diag.astype(f32), (col <= row).astype(f32)
+    b = b_ref[...].astype(f32)
+    lane = lax.broadcasted_iota(jnp.int32, b.shape, 1)
 
-    # the diagonal of B: q_i . k_i, no decay between a token and itself
-    B = jnp.where(row == col, lax.dot_general(
-        q.astype(bf16), k16, _NT, preferred_element_type=f32), 0.0)
-    T = (row == col).astype(f32)
-    level, half = 1, 1
-    while half < chunk:
+    def masks(level):
+        half = 1 << (level - 1)
         # the boundary of each token's block at this level: the last token
         # of the block's left half.  Any value near G there will do (it
         # cancels in the product), so bfloat16 rows picked by a one-hot
         # matmul are enough
         bound = ((row >> level) << level) + (half - 1)
-        ref = jnp.dot((col == bound).astype(bf16), G16,
-                      preferred_element_type=f32)
-        right = (tok & half) != 0
-        E = jnp.exp(jnp.where(right, G - ref, ref - G))
-        lhs = jnp.concatenate([kb * E, q * E], axis=0).astype(bf16)
-        P = lax.dot_general(lhs, (k * E).astype(bf16), _NT,
-                            preferred_element_type=f32)
         pair = ((row >> level) == (col >> level)) & ((row & half) != 0) \
             & ((col & half) == 0)
-        A = jnp.where(pair, P[:chunk], 0.0)
-        B = B + jnp.where(pair, P[chunk:], 0.0)
-        if level == 1:
-            T = T - A
-        else:
-            T = T - jnp.dot(jnp.dot(T, A, precision=_SOLVE,
-                                    preferred_element_type=f32),
-                            T, precision=_SOLVE, preferred_element_type=f32)
-        level, half = level + 1, half * 2
+        return (col == bound).astype(bf16), (tok & half) != 0, pair
 
-    st = st_ref[...]                                    # (d_v, d_k)
-    st16 = st.astype(bf16)
-    decay = jnp.exp(G)
-    rhs = vb - lax.dot_general((kb * decay).astype(bf16), st16, _NT,
-                               preferred_element_type=f32)
-    U = jnp.dot(T, rhs, precision=_HI, preferred_element_type=f32)
-    U16 = U.astype(bf16)
-    o = lax.dot_general((q * decay).astype(bf16), st16, _NT,
-                        preferred_element_type=f32) \
-        + jnp.dot(B.astype(bf16), U16, preferred_element_type=f32)
-    o_ref[...] = o.astype(o_ref.dtype)
-    last = G[chunk - 1:chunk, :]                        # (1, d_k)
-    st = st * jnp.exp(last) + lax.dot_general(
-        U16, (k * jnp.exp(last - G)).astype(bf16), _TN,
-        preferred_element_type=f32)
-    st_ref[...] = st
+    class Head:
+        """One head's values across the stages."""
+
+        def __init__(self, j):
+            self.j, self.cols = j, slice(j * d, (j + 1) * d)
+            self.q = unit(q_ref[:, self.cols].astype(f32)) * d ** -0.5
+            self.k = unit(k_ref[:, self.cols].astype(f32))
+            beta = jnp.sum(jnp.where(lane == hg * group + j, b, 0.0), axis=1,
+                           keepdims=True)
+            self.kb = self.k * beta
+            self.vb = v_ref[:, self.cols].astype(f32) * beta
+            # the running sum of the log-decay inside the chunk
+            self.G = jnp.dot(causal, g_ref[:, self.cols].astype(f32),
+                             precision=_HI, preferred_element_type=f32)
+            self.G16 = self.G.astype(bf16)
+            # the diagonal of B: q_i . k_i, no decay between a token and
+            # itself
+            self.B = jnp.where(diag, lax.dot_general(
+                self.q.astype(bf16), self.k.astype(bf16), _NT,
+                preferred_element_type=f32), 0.0)
+            self.T = eye
+
+        def pairs(self, pick, right, pair):
+            """A level's pairs: ``B`` gains its, the inverse's are returned."""
+            ref = jnp.dot(pick, self.G16, preferred_element_type=f32)
+            E = jnp.exp(jnp.where(right, self.G - ref, ref - self.G))
+            lhs = jnp.concatenate([self.kb * E, self.q * E],
+                                  axis=0).astype(bf16)
+            P = lax.dot_general(lhs, (self.k * E).astype(bf16), _NT,
+                                preferred_element_type=f32)
+            self.B = self.B + jnp.where(pair, P[chunk:], 0.0)
+            return jnp.where(pair, P[:chunk], 0.0)
+
+        def merge_left(self, level, A):
+            if level == 1:
+                self.T = self.T - A
+            else:
+                self.TA = jnp.dot(self.T, A, precision=_SOLVE,
+                                  preferred_element_type=f32)
+
+        def merge_right(self, level):
+            if level > 1:
+                self.T = self.T - jnp.dot(self.TA, self.T, precision=_SOLVE,
+                                          preferred_element_type=f32)
+
+        def solve(self):
+            self.st = st_ref[self.j]                    # (d_v, d_k)
+            self.st16 = self.st.astype(bf16)
+            self.decay = jnp.exp(self.G)
+            rhs = self.vb - lax.dot_general(
+                (self.kb * self.decay).astype(bf16), self.st16, _NT,
+                preferred_element_type=f32)
+            U = jnp.dot(self.T, rhs, precision=_HI,
+                        preferred_element_type=f32)
+            self.U16 = U.astype(bf16)
+
+        def write(self):
+            o = lax.dot_general((self.q * self.decay).astype(bf16),
+                                self.st16, _NT, preferred_element_type=f32) \
+                + jnp.dot(self.B.astype(bf16), self.U16,
+                          preferred_element_type=f32)
+            o_ref[:, self.cols] = o.astype(o_ref.dtype)
+            last = self.G[chunk - 1:chunk, :]           # (1, d_k)
+            self.st = self.st * jnp.exp(last) + lax.dot_general(
+                self.U16, (self.k * jnp.exp(last - self.G)).astype(bf16),
+                _TN, preferred_element_type=f32)
+            st_ref[self.j] = self.st
+
+    levels = range(1, chunk.bit_length())      # blocks of 2 .. chunk
+    heads = [Head(j) for j in range(group)]
+    shared = masks(levels[0])
+    ahead = [x.pairs(*shared) for x in heads]
+    for level in levels:
+        for x, A in zip(heads, ahead):
+            x.merge_left(level, A)
+        if level + 1 in levels:
+            shared = masks(level + 1)
+            ahead = [x.pairs(*shared) for x in heads]
+        for x in heads:
+            x.merge_right(level)
+    for x in heads:
+        x.solve()
+    for x in heads:
+        x.write()
 
     @pl.when(c == pl.num_programs(1) - 1)
     def _out():
-        s_ref[0] = st.T
+        for x in heads:
+            s_ref[x.j] = x.st.T
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "chunk", "l2_eps"))
-def _kda_prefill_call(qkv, g, beta, *, heads, chunk, l2_eps):
+@functools.partial(jax.jit, static_argnames=("heads", "chunk", "group",
+                                             "l2_eps"))
+def _kda_prefill_call(qkv, g, beta, *, heads, chunk, group, l2_eps):
     t = qkv.shape[0]
     d = qkv.shape[1] // (3 * heads)
     f32 = jnp.float32
@@ -203,22 +284,26 @@ def _kda_prefill_call(qkv, g, beta, *, heads, chunk, l2_eps):
     if pad:       # rows that decay nothing and write nothing
         qkv, g, beta = (jnp.pad(x, ((0, pad), (0, 0)))
                         for x in (qkv, g, beta))
+    groups = heads // group
 
-    def head(offset):
-        return pl.BlockSpec((chunk, d), lambda h, c: (c, offset + h))
+    def cols(offset):
+        # the heads' columns are adjacent: a group is one block
+        return pl.BlockSpec((chunk, group * d),
+                            lambda hg, c: (c, offset + hg))
 
     o, state = pl.pallas_call(
-        functools.partial(_kda_prefill_kernel, chunk=chunk, l2_eps=l2_eps),
+        functools.partial(_kda_prefill_kernel, chunk=chunk, group=group,
+                          l2_eps=l2_eps),
         name="kda_prefill",
-        grid=(heads, n),
-        # q, k and v: three views of the one array, a head's columns each
-        in_specs=[head(0), head(heads), head(2 * heads), head(0),
-                  pl.BlockSpec((chunk, heads), lambda h, c: (c, 0))],
-        out_specs=[head(0),
-                   pl.BlockSpec((1, d, d), lambda h, c: (h, 0, 0))],
+        grid=(groups, n),
+        # q, k and v: three views of the one array, a group's columns each
+        in_specs=[cols(0), cols(groups), cols(2 * groups), cols(0),
+                  pl.BlockSpec((chunk, heads), lambda hg, c: (c, 0))],
+        out_specs=[cols(0),
+                   pl.BlockSpec((group, d, d), lambda hg, c: (hg, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((n * chunk, heads * d), f32),
                    jax.ShapeDtypeStruct((heads, d, d), f32)],
-        scratch_shapes=[pltpu.VMEM((d, d), f32)],
+        scratch_shapes=[pltpu.VMEM((group, d, d), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(),
@@ -226,11 +311,36 @@ def _kda_prefill_call(qkv, g, beta, *, heads, chunk, l2_eps):
     return o[:t].reshape(t, heads, d), state
 
 
+def _group_vmem(chunk: int, group: int, d: int) -> int:
+    """Bytes of VMEM a grid step of ``group`` heads holds: the blocks of
+    ``q``, ``k``, ``v``, ``g`` and ``o`` in two buffers, the states (scratch
+    and the output's two buffers), and a head's float32 values that live
+    across the levels (``q``, ``k``, ``kb``, ``vb``, ``G`` and a level's
+    three; ``B``, ``T`` and a level's four squares).  At 128 tokens of 128
+    channels the count is 13.5 MiB for 8 heads and 6.75 for 4; the compiler
+    takes 8 heads under a limit of 16 MiB and refuses them under 12, takes
+    4 under 8 and refuses them under 6 (compiler, sandbox, PR 40)."""
+    return 4 * group * (10 * chunk * d + 3 * d * d
+                        + 8 * chunk * d + 6 * chunk * chunk)
+
+
 def _pick_chunk(t: int) -> int:
     c = CHUNK
     while c > 8 and c > t:
         c //= 2
     return c
+
+
+def prefill_grid(t: int, heads: int, d: int):
+    """``(chunk, group, grid)`` of a ``kda_prefill`` call over ``t`` tokens:
+    the tokens and the heads a grid step takes and the steps of the call,
+    ``(heads // group, chunks)``.  The group is the largest of 8, 4, 2 that
+    divides ``heads`` and whose blocks and temporaries fit ``_GROUP_VMEM``,
+    else one head."""
+    chunk = _pick_chunk(t)
+    group = next((n for n in (8, 4, 2) if heads % n == 0
+                  and _group_vmem(chunk, n, d) <= _GROUP_VMEM), 1)
+    return chunk, group, (heads // group, -(-t // chunk))
 
 
 def prefill_engages(d: int) -> bool:
@@ -247,8 +357,9 @@ def kda_prefill(qkv, g, beta, heads: int, l2_eps: float = 1e-6):
     t = qkv.shape[0]
     d = qkv.shape[1] // (3 * heads)
     if prefill_engages(d):
+        chunk, group, _ = prefill_grid(t, heads, d)
         return own_jit(_kda_prefill_call)(
-            qkv, g, beta, heads=heads, chunk=_pick_chunk(t),
+            qkv, g, beta, heads=heads, chunk=chunk, group=group,
             l2_eps=float(l2_eps))
     with jax.named_scope("kda_prefill"):
         q, k, v = normalised_heads(qkv, heads, l2_eps)

@@ -136,6 +136,61 @@ def test_rows_past_the_prompt_leave_the_state_alone(interpreted):
     np.testing.assert_allclose(got, want, atol=6e-3)
 
 
+# a grid step takes a group of heads: 1, 2, 4 and 8 heads are one group, 3
+# fall to one head a step; under, at and over a chunk; the three decays above
+@pytest.mark.parametrize("heads", [1, 2, 3, 4, 8])
+@pytest.mark.parametrize("t", [5, 128, 300])
+@pytest.mark.parametrize("decay", [0.05, 3.0, 20.0])
+def test_a_group_of_heads_is_one_head_a_step_bit_for_bit(
+        interpreted, heads, t, decay):
+    (qkv, g, beta), _ = kda_case(t, heads, 16, decay, seed=t + heads)
+    chunk, group, grid = kda_kernels.prefill_grid(t, heads, 16)
+    assert group == (1 if heads == 3 else heads)
+    assert grid == (heads // group, -(-t // chunk))
+    call = kda_kernels._kda_prefill_call.__wrapped__
+    want_o, want_s = call(qkv, g, beta, heads=heads, chunk=chunk, group=1,
+                          l2_eps=1e-6)
+    got_o, got_s = call(qkv, g, beta, heads=heads, chunk=chunk, group=group,
+                        l2_eps=1e-6)
+    np.testing.assert_array_equal(got_o, want_o)
+    np.testing.assert_array_equal(got_s, want_s)
+
+
+def test_the_wrapper_takes_the_group_of_prefill_grid(interpreted):
+    (qkv, g, beta), _ = kda_case(200, 4, 16, 0.5)
+    want_o, want_s = kda_kernels._kda_prefill_call.__wrapped__(
+        qkv, g, beta, heads=4, chunk=128, group=1, l2_eps=1e-6)
+    got_o, got_s = kda_kernels.kda_prefill(qkv, g, beta, 4)
+    np.testing.assert_array_equal(got_o, want_o)
+    np.testing.assert_array_equal(got_s, want_s)
+
+
+@pytest.mark.parametrize("t,heads,d,want", [
+    (8192, 32, 128, (128, 4, (8, 64))),     # the cell's buckets
+    (4096, 32, 128, (128, 4, (8, 32))),
+    (2048, 32, 128, (128, 4, (8, 16))),
+    (16, 32, 128, (16, 8, (4, 1))),         # a prompt under a chunk
+    (300, 3, 16, (128, 1, (3, 3))),         # no group divides three heads
+    (300, 12, 128, (128, 4, (3, 3))),
+    (300, 8, 16, (128, 8, (1, 3))),         # narrow heads: all eight
+    (8192, 32, 256, (128, 2, (16, 64))),    # wider heads: VMEM sets the group
+])
+def test_prefill_grid_comes_from_the_shapes(t, heads, d, want):
+    assert kda_kernels.prefill_grid(t, heads, d) == want
+
+
+def test_hybrid_walk_counts_the_grid_the_wrapper_uses():
+    from paddle_tpu.inference.mla_decoder import _hybrid_walk
+
+    cfg = dataclasses.replace(TINY, kda_heads=32, kda_head_dim=128)
+    feed = {"tokens": np.zeros((1, 8192), np.int32),
+            "last_index": np.asarray([4999], np.int32)}
+    kda = len(cfg.kda_layers)
+    assert _hybrid_walk(feed, None, mode="prefill", cfg=cfg) == {
+        "kda_prefill_calls": kda, "kda_prefill_tokens": 5000 * kda,
+        "kda_prefill_grid_steps": 8 * 64 * kda}
+
+
 def test_decode_kernel_rewrites_its_slots_and_no_other(interpreted):
     r = np.random.RandomState(1)
     pool = jnp.asarray(r.randn(6, 4, 16, 16), jnp.float32)
@@ -201,8 +256,10 @@ def test_engine_through_the_kernels_matches_reference(interpreted):
     assert worst <= 2e-2, worst          # the kernels' bfloat16 operands
     kernels = eng.stats["kernels"]
     kda = len(cfg.kda_layers)
+    # buckets of 16, 128 and 64 rows: each under a chunk, four heads a step
     assert kernels["prefill"] == {"kda_prefill_calls": 3 * kda,
-                                  "kda_prefill_tokens": (9 + 70 + 33) * kda}
+                                  "kda_prefill_tokens": (9 + 70 + 33) * kda,
+                                  "kda_prefill_grid_steps": 3 * kda}
     assert kernels["decode"]["kda_decode_calls"] == 5 * kda
     assert kernels["decode"]["kda_decode_sequences"] == 15 * kda
 

@@ -400,13 +400,19 @@ KDA_POOL = (129, 32, 128, 128)    # the cell's state pool a layer, float32
 def test_kda_prefill_compiles_for_v5e(tokens, v5e):
     """The chunked kernel as the chip's compiler takes it: float32 matmuls
     at the highest precision (the triangular inverse), a transposed-left
-    product (the state's update) and a transpose of the state, one Mosaic
-    call a layer."""
+    product (the state's update) and a transpose of the state, four heads
+    of a whole chunk a grid step (eight of a prompt under a chunk) within
+    the VMEM a kernel is given unasked, one Mosaic call a layer."""
     from paddle_tpu.ops import kda_kernels as kk
 
+    chunk, group, grid = kk.prefill_grid(tokens, 32, 128)
+    want = 4 if tokens >= 128 else 8
+    assert (chunk, group, grid) == (min(tokens, 128), want,
+                                    (32 // want, -(-tokens // 128)))
+
     def f(qkv, g, beta):
-        return kk._kda_prefill_call(qkv, g, beta, heads=32,
-                                    chunk=kk._pick_chunk(tokens), l2_eps=1e-6)
+        return kk._kda_prefill_call(qkv, g, beta, heads=32, chunk=chunk,
+                                    group=group, l2_eps=1e-6)
 
     text = _compile(f, v5e, ((tokens, 3 * 4096), jnp.float32),
                     ((tokens, 32, 128), jnp.float32),
