@@ -103,6 +103,27 @@ SIZES = {
                      weights_dtype="bfloat16"),
             num_pages=512, page_size=16, token_budget=2048, max_batch=8,
             prompts=[300, 20, 280, 31, 1100], new_tokens=8),
+        # Laguna-XS.2's cut at published widths and one period of depth
+        # (full, window, window, window, full): the dense layer and four
+        # expert layers of 64 held experts of 256, a quarter of the
+        # vocabulary (2.3 GB); one prompt of 8,000 tokens, so that a window
+        # layer's walk is held to 33 pages at 8 k of context
+        "gqa": dict(
+            cfg=dict(vocab_size=25088, hidden=2048, num_layers=5,
+                     mixers=("full", "window", "window", "window", "full"),
+                     heads_full=48, heads_window=64, num_kv_heads=8,
+                     head_dim=128, window=512, first_k_dense=1,
+                     intermediate=8192, moe_intermediate=512,
+                     n_routed_experts=256, experts_held=64,
+                     num_experts_per_tok=8, max_seq_len=8704,
+                     weights_dtype="bfloat16"),
+            rope_full=dict(lanes=64, base=500000.0, yarn_factor=64.0,
+                           original_max_position=4096, beta_fast=64.0,
+                           beta_slow=1.0,
+                           attention_factor=1.4158883083359672),
+            rope_window=dict(lanes=128, base=10000.0),
+            num_pages=768, page_size=16, token_budget=8320, max_batch=8,
+            prompts=[300, 20, 600, 31, 8000], new_tokens=8, pad_to=8192),
     },
     "tiny": {
         "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
@@ -142,6 +163,22 @@ SIZES = {
                      weights_dtype="bfloat16"),
             num_pages=64, page_size=16, token_budget=128, max_batch=4,
             prompts=[40, 5, 36, 9, 70], new_tokens=6),
+        "gqa": dict(
+            cfg=dict(vocab_size=256, hidden=128, num_layers=5,
+                     mixers=("full", "window", "window", "window", "full"),
+                     heads_full=6, heads_window=8, num_kv_heads=2,
+                     head_dim=16, window=16, first_k_dense=1,
+                     intermediate=256, moe_intermediate=128,
+                     n_routed_experts=8, experts_held=4,
+                     num_experts_per_tok=2, max_seq_len=256,
+                     weights_dtype="bfloat16"),
+            rope_full=dict(lanes=8, base=500000.0, yarn_factor=64.0,
+                           original_max_position=16, beta_fast=64.0,
+                           beta_slow=1.0,
+                           attention_factor=1.4158883083359672),
+            rope_window=dict(lanes=16, base=10000.0),
+            num_pages=64, page_size=8, token_budget=256, max_batch=4,
+            prompts=[40, 5, 36, 9, 100], new_tokens=6, pad_to=128),
     },
 }
 
@@ -151,6 +188,14 @@ MLA_LOGIT_ABS_TOL, MLA_ROUTE_SLACK_TOL = 0.06, 0.008
 # the hybrid decoder's, the reference routed as the engine was on the prompt's
 # rows too: the limits of benchmark/configs/kimi-linear-48b-a3b.json
 HYBRID_LOGIT_ABS_TOL, HYBRID_ROUTE_SLACK_TOL = 0.06, 0.008
+# the grouped-query decoder's: the limits of benchmark/configs/laguna-xs2.json
+GQA_LOGIT_ABS_TOL, GQA_ROUTE_SLACK_TOL = 0.06, 0.008
+# gqa_decode's walk at the "full" sizes: contexts of 300, 20, 600, 31 and
+# 8,000 tokens are 561 pages of context a layer, of which a window layer
+# walks 89 (at most 33 a row): (2 x 561 + 3 x 89) / (5 x 561) = 0.495 over
+# the two full and three window layers
+GQA_WALK_OVER_CONTEXT_MAX = 0.55
+GQA_WINDOW_WALK_PAGES_MAX = 33
 
 # mla_decode's grid at the "full" sizes: contexts of 300, 20, 280, 31 and
 # 1,100 tokens and three rows of padding are 1 + 1 + 1 + 1 + 2 + 3 chunks
@@ -1072,6 +1117,134 @@ def phase_hybrid(ctx):
         **ctx.memory())
 
 
+def phase_gqa(ctx):
+    """The grouped-query decoder with window layers (K and V pools in two
+    groups of pages, the window layers' freed behind the window) through
+    ServingEngine: its kernels in the lowered programs, no operation of
+    either group's pool size in the compiled ones but the append's, the
+    served logits against the plain reference, the walk of ``gqa_decode``
+    against the context (a window layer's bounded whatever the context), and
+    pipelined steps leaving greedy tokens unchanged."""
+    import dataclasses
+    import importlib.util
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu.inference.gqa_decoder import (GQADecoderConfig, Rope,
+                                                  init_gqa_weights)
+    from paddle_tpu.inference.serving import Request, ServingEngine
+    from paddle_tpu.ops import gqa_kernels
+
+    jax = ctx.jax
+    phase, size = "serve/gqa", ctx.sizes["gqa"]
+    cfg = GQADecoderConfig(rope_full=Rope(**size["rope_full"]),
+                           rope_window=Rope(**size["rope_window"]),
+                           **size["cfg"])
+    spec = importlib.util.spec_from_file_location(
+        "reference_laguna", os.path.join(os.path.dirname(os.path.abspath(
+            __file__)), "benchmark", "reference", "laguna-xs2.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    weights = {n: jax.device_put(w, ctx.device)
+               for n, w in init_gqa_weights(cfg, 0).items()}
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in size["prompts"]]
+
+    def drive(**kw):
+        eng = ServingEngine(
+            cfg=cfg, weights=weights, kv_dtype="bfloat16", place=ctx.place,
+            num_pages=size["num_pages"], page_size=size["page_size"],
+            max_batch=size["max_batch"], token_budget=size["token_budget"],
+            **kw)
+        eng.core.keep_scores = True
+        reqs = [Request(i, p, size["new_tokens"])
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_to_completion()
+        return eng, reqs
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        mark = ctx.watch.mark()
+        plain, reqs = drive()
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(
+            phase, modules, ["gqa_prefill", "gqa_decode", "moe_gmm"]
+            + ([] if ctx.interpreted else ["kv_append"]))
+        kvc = plain.core.kv_config
+        in_place = {
+            "full": ctx.require_pool_in_place(
+                phase, kvc, n_pools=2 * len(cfg.full_layers)),
+            "window": ctx.require_pool_in_place(
+                phase, dataclasses.replace(kvc, num_pages=kvc.window_pages),
+                n_pools=2 * len(cfg.window_layers))}
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    worst, slack = 0.0, 0.0
+    for r in reqs:
+        got, routes = plain.core.served_scores(r.req_id)
+        ref = reference.served_token_scores(
+            weights, cfg.source_config(), r.prompt, r.out_tokens, routes,
+            pad_to=size["pad_to"],
+            prompt_routes=plain.core.prompt_routes(r.req_id))
+        worst = max(worst, float(np.abs(got[:, 0] - ref["logit"]).max()),
+                    float(np.abs(got[:, 1] - ref["lse"]).max()))
+        slack = max(slack, float(ref["slack"].max()))
+    if ctx.sizes is SIZES["full"] and (worst > GQA_LOGIT_ABS_TOL
+                                       or slack > GQA_ROUTE_SLACK_TOL):
+        raise RuntimeError(
+            f"{phase}: served logits lie {worst} from the reference (limit "
+            f"{GQA_LOGIT_ABS_TOL}), routing slack {slack} (limit "
+            f"{GQA_ROUTE_SLACK_TOL})")
+    # the walk, by the engine's own count and by the function the kernel's
+    # wrapper sizes its grid with, at the requests' last contexts
+    counts = plain.stats["kernels"].get("decode", {})
+    over = counts.get("gqa_decode_pages_walked", 0) / max(
+        counts.get("gqa_decode_pages_in_context", 0), 1)
+    ends = np.array([len(r.prompt) + len(r.out_tokens) - 1 for r in reqs])
+    page, window = kvc.page_size, cfg.window
+    first = np.maximum(ends - window - page + 1, 0) // page * page
+    _, _, walked = gqa_kernels.decode_span(ends, first, page, window)
+    if ctx.sizes is SIZES["full"] and (
+            not counts or over > GQA_WALK_OVER_CONTEXT_MAX
+            or walked.max() > GQA_WINDOW_WALK_PAGES_MAX):
+        raise RuntimeError(
+            f"{phase}: gqa_decode walked {over} of the pages in context "
+            f"(limit {GQA_WALK_OVER_CONTEXT_MAX}) and a window layer "
+            f"{walked.tolist()} pages a row at contexts {ends.tolist()} "
+            f"(limit {GQA_WINDOW_WALK_PAGES_MAX}): {counts}")
+    gmm = gmm_walk(phase, plain)
+    stats, groups = plain.stats, plain.kv.stats()["groups"]
+    if groups["window"]["peak_pages"] > size["max_batch"] \
+            * kvc.window_pages_per_seq:
+        raise RuntimeError(f"{phase}: the window group held more than "
+                           f"{kvc.window_pages_per_seq} pages a sequence: "
+                           f"{groups}")
+    del plain
+    gc.collect()
+    piped, piped_reqs = drive(pipeline=2)
+    if [r.out_tokens for r in piped_reqs] != [r.out_tokens for r in reqs]:
+        raise RuntimeError(f"{phase}: pipelined steps changed the tokens "
+                           f"served: {[r.out_tokens for r in piped_reqs]} "
+                           f"vs {[r.out_tokens for r in reqs]}")
+    del piped
+    gc.collect()
+    say(phase=phase, **{k: v for k, v in size["cfg"].items()},
+        num_pages=size["num_pages"], prompts=size["prompts"],
+        new_tokens=size["new_tokens"], scheduler=stats, page_groups=groups,
+        **seen, kernel_calls=kernels, pools_in_place=in_place,
+        moe_gmm_prefill_walk=gmm, gqa_decode_walk_over_context=over,
+        window_walk_pages_a_row=walked.tolist(),
+        served_logits_worst_gap=worst, route_slack=slack,
+        pipeline="tokens identical with pipelined steps on and off",
+        **ctx.memory())
+
+
 def phase_tp4(ctx):
     """tp=4 decode against tp=1 tokens for the same requests."""
     phase, size = "tp4/decoder", ctx.sizes["serve"]
@@ -1124,7 +1297,7 @@ def main(argv=None):
     ap.add_argument("--only", default=None,
                     help="comma-separated phases to run instead of all of "
                          "the chip count's (resnet, bert, serve, mla, hybrid, "
-                         "dp4, tp4)")
+                         "gqa, dp4, tp4)")
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="skip the TPU assertion (and the device's memory "
                          "counters): a rehearsal, never a result")
@@ -1147,7 +1320,8 @@ def main(argv=None):
             compile_cache_dir=ctx.cache_dir, cache_entries_before=entries0,
             note="smoke observations, not benchmark metrics")
         phases = (phase_dp4, phase_tp4) if args.chips == 4 else \
-            (phase_resnet, phase_bert, phase_serve, phase_mla, phase_hybrid)
+            (phase_resnet, phase_bert, phase_serve, phase_mla, phase_hybrid,
+             phase_gqa)
         if args.only:
             phases = [globals()["phase_" + name]
                       for name in args.only.split(",")]

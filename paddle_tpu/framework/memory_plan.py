@@ -223,6 +223,22 @@ def _t_mla_prefill_attention(op_, block, ndev, assumed_batch):
     return 4 * _slot_bytes(op_, block, assumed_batch, "QNope")
 
 
+def _t_gqa_paged_attention(op_, block, ndev, assumed_batch):
+    """As ``paged_attention``: the gather fallback materializes each row's
+    K and V rows over the block-table width, bounded above by the pools it
+    gathers from (the kernel streams pages)."""
+    return _slot_bytes(op_, block, assumed_batch, "KCache") \
+        + _slot_bytes(op_, block, assumed_batch, "VCache")
+
+
+def _t_gqa_prefill_attention(op_, block, ndev, assumed_batch):
+    """Queries, keys and values are laid heads-first for the kernel and its
+    float32 output is laid back and gated: about four arrays the size of Q
+    beyond the declared slots (the jnp fallback's score blocks are smaller
+    at any prompt a test runs)."""
+    return 4 * _slot_bytes(op_, block, assumed_batch, "Q")
+
+
 def _t_kda_mixer(op_, block, ndev, assumed_batch):
     """The whole KDA mixer in one op: per row the convolution's inputs and
     outputs (``[q | k | v]`` twice), the decay, the recurrence's output and
@@ -330,6 +346,8 @@ TRANSIENT_BYTES = {
     "paged_attention": _t_paged_attention,
     "mla_paged_attention": _t_mla_paged_attention,
     "mla_prefill_attention": _t_mla_prefill_attention,
+    "gqa_paged_attention": _t_gqa_paged_attention,
+    "gqa_prefill_attention": _t_gqa_prefill_attention,
     "moe_experts": _t_moe_experts,
     "kda_mixer": _t_kda_mixer,
     "sample_token": _t_sample_token,
@@ -438,8 +456,8 @@ unique_with_counts unpool unsqueeze unsqueeze2 unstack
 update_loss_scaling var_conv_2d warpctc
 where where_index while_loop_grad write_to_array yolo_box yolov3_loss
 select_input select_output kv_cache_append kv_dequant
-latent_cache_append matmul_f32acc moe_router rms_norm rope_interleaved
-slot_is_live swiglu token_score
+latent_cache_append matmul_f32acc moe_router rms_norm rope_half
+rope_interleaved slot_is_live swiglu token_score
 allreduce alltoall barrier broadcast c_allreduce_max c_allreduce_min
 c_allreduce_prod c_allreduce_sum c_broadcast c_comm_init c_comm_init_all
 c_gen_nccl_id c_identity c_reducescatter c_split c_sync_calc_stream

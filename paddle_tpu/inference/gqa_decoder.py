@@ -1,0 +1,520 @@
+"""A grouped-query decoder with window layers beside full ones, a gate on the
+attention output and the sparse-expert feed-forward of ``mla_decoder.py``:
+the Laguna-shaped block (``model_type: laguna``) as a model description
+``ServingEngine`` serves through the same seam as ``DecoderConfig`` and
+``MLADecoderConfig``: parameter specs, program forms, cache pools.
+
+Per layer ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
+feed-forward half is ``mla_decoder._MB._ffn`` as it stands (the first
+``first_k_dense`` layers a SwiGLU, the rest the router, this chip's share of
+the experts and the shared expert).  ``Attn`` of a layer of kind ``"full"``
+or ``"window"``: ``heads_full`` or ``heads_window`` query heads over
+``num_kv_heads`` key/value heads of ``head_dim``, rotary in the half-rotated
+form with the kind's own setting (:class:`Rope`: rotated lanes, base, YaRN's
+numbers, the factor on cos and sin), causal, a window layer attending the
+last ``window`` positions only; the output times ``sigmoid(x W_g)``, one
+value a head (``gate``), then ``W_o``.  No biases, untied head.
+
+The cache holds K and V rows ``(num_kv_heads, head_dim)`` a token and layer
+in pools ``(num_kv_heads, pages, page_size, head_dim)`` (``kv_k_<i>`` /
+``kv_v_<i>``, written by ``kv_cache_append``), in TWO GROUPS of pages
+(``KVCacheConfig.groups``): the full layers' pools keep every page of a
+sequence until it ends, the window layers' pools hold only the pages that
+cover the last ``window`` (+ a page's) positions, with a table and a slot
+mapping of their own among the feeds (``window_slot_mapping``, and for a
+decode step ``window_tables`` and ``window_first``, each row's first held
+position).
+
+Forms: ``reference`` and ``prefill`` attend over one whole prompt
+(``gqa_prefill``), ``decode`` one row a sequence over the pools
+(``gqa_decode``).  ``chunk`` and ``verify`` are not built: the engine refuses
+prefix caching, chunked prefill and speculative decoding for this model at
+construction (a page freed behind a window cannot be brought back).
+
+Types as the other expert decoders: parameters in ``weights_dtype``, every
+matmul with operands of that type accumulated in float32; the residual
+stream, norms, softmax, router scores and the gate float32.
+
+**Why a description of its own** and not two more mixer kinds of
+``MLADecoderConfig``: that description's fields are latent attention's (two
+low-rank projections, nope/rope head dims, one head count, one rotary base),
+none of which this model has, and this one's (K/V heads, heads by kind, two
+rotary settings, the window, the gate) none of which that one has; the two
+share the block and the forms' plumbing, which are functions
+(``_MB.block`` / ``_ffn``, ``open_form``, ``embed_rows``, ``close_form``,
+``ffn_specs``) and are used from here, not copied.
+"""
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass, field
+from typing import Dict, List, Tuple
+
+import numpy as np
+
+from ..framework.core import Program
+from ..framework.dtype import VarType, convert_dtype
+from ..ops import gqa_kernels, mla_kernels
+from .kv_cache import KVCacheConfig
+from .mla_decoder import (_MB, _gmm_walk, close_form, embed_rows, ffn_specs,
+                          open_form)
+
+__all__ = ["GQADecoderConfig", "Rope", "init_gqa_weights"]
+
+
+@dataclass(frozen=True)
+class Rope:
+    """One rotary setting: the first ``lanes`` of a head turn (half-rotated),
+    at frequencies ``base^(-2i/lanes)`` or, with ``yarn_factor``, YaRN's blend
+    of those and those over the factor; cos and sin times
+    ``attention_factor``."""
+    lanes: int = 0
+    base: float = 10000.0
+    yarn_factor: float = 0.0         # 0: plain rotary
+    original_max_position: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def inv_freq(self) -> np.ndarray:
+        yarn = {"factor": self.yarn_factor,
+                "original_max_position_embeddings":
+                    self.original_max_position,
+                "beta_fast": self.beta_fast, "beta_slow": self.beta_slow} \
+            if self.yarn_factor else None
+        return gqa_kernels.rope_frequencies(self.lanes, self.base, yarn)
+
+    @classmethod
+    def from_source(cls, p: dict, head_dim: int) -> "Rope":
+        """From one entry of the source's ``rope_parameters``."""
+        lanes = int(round(head_dim * float(p.get("partial_rotary_factor",
+                                                 1.0))))
+        if p.get("rope_type", "default") != "yarn":
+            return cls(lanes=lanes, base=float(p["rope_theta"]))
+        return cls(lanes=lanes, base=float(p["rope_theta"]),
+                   yarn_factor=float(p["factor"]),
+                   original_max_position=int(
+                       p["original_max_position_embeddings"]),
+                   beta_fast=float(p["beta_fast"]),
+                   beta_slow=float(p["beta_slow"]),
+                   attention_factor=float(p.get("attention_factor", 1.0)))
+
+    def to_source(self, head_dim: int) -> dict:
+        out = {"rope_theta": self.base,
+               "partial_rotary_factor": self.lanes / head_dim,
+               "rope_type": "yarn" if self.yarn_factor else "default"}
+        if self.yarn_factor:
+            out.update(factor=self.yarn_factor,
+                       original_max_position_embeddings=
+                       self.original_max_position,
+                       beta_fast=self.beta_fast, beta_slow=self.beta_slow,
+                       attention_factor=self.attention_factor)
+        return out
+
+
+_KINDS = {"full_attention": "full", "sliding_attention": "window"}
+_KIND_NAMES = {ours: theirs for theirs, ours in _KINDS.items()}
+
+
+@dataclass(frozen=True)
+class GQADecoderConfig:
+    vocab_size: int = 128
+    hidden: int = 64
+    num_layers: int = 4
+    mixers: Tuple[str, ...] = ("full", "window", "window", "window")
+    heads_full: int = 6
+    heads_window: int = 8
+    num_kv_heads: int = 2
+    head_dim: int = 16
+    window: int = 8
+    gate: bool = True                # sigmoid(x W_g), one value a head
+    rope_full: Rope = field(default_factory=lambda: Rope(lanes=8))
+    rope_window: Rope = field(default_factory=lambda: Rope(lanes=16))
+    first_k_dense: int = 1
+    intermediate: int = 128          # the dense layers' SwiGLU width
+    moe_intermediate: int = 32       # one expert's (and the shared one's)
+    n_routed_experts: int = 8
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 2
+    experts_held: int = 0            # experts 0..held-1 of a layer; 0: all
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    rms_norm_eps: float = 1e-6
+    max_seq_len: int = 256
+    eos_id: int = -1
+    weights_dtype: str = "float32"
+
+    # -- the seam ServingEngine asks a model description through ---------
+    @property
+    def param_dtype(self) -> str:
+        return self.weights_dtype
+
+    def mixer(self, i: int) -> str:
+        return self.mixers[i]
+
+    def heads(self, kind: str) -> int:
+        return self.heads_full if kind == "full" else self.heads_window
+
+    def rope(self, kind: str) -> Rope:
+        return self.rope_full if kind == "full" else self.rope_window
+
+    @property
+    def full_layers(self) -> List[int]:
+        return [i for i, k in enumerate(self.mixers) if k == "full"]
+
+    @property
+    def window_layers(self) -> List[int]:
+        return [i for i, k in enumerate(self.mixers) if k == "window"]
+
+    @property
+    def experts_here(self) -> int:
+        return self.experts_held or self.n_routed_experts
+
+    def param_specs(self) -> Dict[str, tuple]:
+        h, d = self.hidden, self.head_dim
+        specs = {"dec_embed": (self.vocab_size, h),
+                 "dec_head": (h, self.vocab_size), "dec_norm_scale": (h,)}
+        for i, kind in enumerate(self.mixers):
+            p, heads = f"dec_l{i}_", self.heads(kind)
+            specs.update({p + "attn_norm_scale": (h,),
+                          p + "wq": (h, heads * d),
+                          p + "wk": (h, self.num_kv_heads * d),
+                          p + "wv": (h, self.num_kv_heads * d),
+                          p + "wo": (heads * d, h)})
+            if self.gate:
+                specs[p + "wg"] = (h, heads)
+            specs.update(ffn_specs(self, i, moe=i >= self.first_k_dense))
+        return specs
+
+    def build_program(self, mode: str, sampling=None,
+                      kv_dtype: str = "float32", tp: int = 1) -> tuple:
+        return build_gqa_program(self, mode, sampling=sampling,
+                                 kv_dtype=kv_dtype)
+
+    def validate(self, tp: int = 1, kv_dtype: str = "float32",
+                 prefix_cache: bool = False, prefill_chunk: int = 0,
+                 spec_k: int = 0):
+        """What this model is not served with, refused at construction."""
+        if len(self.mixers) != self.num_layers or \
+                set(self.mixers) - {"full", "window"}:
+            raise ValueError(f"mixers must name 'full' or 'window' for each "
+                             f"of the {self.num_layers} layers: {self.mixers}")
+        if "full" not in self.mixers:
+            raise ValueError("the decoder needs a full-attention layer: the "
+                             "engine sizes its page pool by it")
+        for kind in set(self.mixers):
+            if self.heads(kind) % self.num_kv_heads:
+                raise ValueError(f"{self.heads(kind)} query heads of a "
+                                 f"{kind} layer do not divide into "
+                                 f"{self.num_kv_heads} K/V heads")
+        if self.window_layers and self.window < 1:
+            raise ValueError("window layers need a window")
+        if int(tp or 1) != 1:
+            raise ValueError("the grouped-query decoder has no "
+                             "tensor-parallel rules: serving_tp must be 1")
+        if kv_dtype == "int8":
+            raise ValueError("the grouped-query decoder's pools have no int8 "
+                             "storage: kv_dtype must be float32 or bfloat16")
+        if prefix_cache or prefill_chunk:
+            raise ValueError(
+                "the grouped-query decoder builds no 'chunk' program form: "
+                "prefix caching and chunked prefill are refused for this "
+                "model")
+        if spec_k:
+            raise ValueError(
+                "a model with window layers is not served with speculative "
+                "decoding: truncate_tokens cannot bring back a page freed "
+                "behind a window")
+
+    def tp_rules(self, kv_dtype: str = "float32") -> Dict[str, tuple]:
+        return {}
+
+    def kv_cache_config(self, num_pages: int, page_size: int,
+                        kv_dtype: str) -> KVCacheConfig:
+        """K and V rows of ``num_kv_heads`` heads a token and layer, in two
+        groups of pages: ``num_pages`` for the full layers, and for the
+        window layers what the engine's batch can hold
+        (``window_pages_per_seq`` a sequence: ``_EngineCore`` sizes it)."""
+        return KVCacheConfig(
+            num_pages=num_pages, page_size=page_size,
+            num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
+            num_layers=self.num_layers, dtype=kv_dtype,
+            window=self.window if self.window_layers else 0,
+            window_layers=tuple(self.window_layers))
+
+    def cache_pool_names(self) -> List[str]:
+        return [f"kv_{kind}_{i}" for i in range(self.num_layers)
+                for kind in ("k", "v")]
+
+    def window_pool_names(self) -> List[str]:
+        """The pools of the window group, ``window_pages`` pages each."""
+        return [f"kv_{kind}_{i}" for i in self.window_layers
+                for kind in ("k", "v")]
+
+    def kv_token_bytes(self, kv_dtype: str, tp: int = 1) -> int:
+        """What a token costs in the full layers' pools, the group
+        ``num_pages`` sizes; a window layer's rows are a constant a
+        sequence."""
+        return len(self.full_layers) * self.kv_layer_token_bytes(kv_dtype)
+
+    def kv_layer_token_bytes(self, kv_dtype: str) -> int:
+        return 2 * self.num_kv_heads * self.head_dim \
+            * np.dtype(kv_dtype).itemsize
+
+    # -- the source's names (its config.json) ------------------------------
+    _SOURCE_KEYS = {
+        "vocab_size": "vocab_size", "hidden": "hidden_size",
+        "num_layers": "num_hidden_layers",
+        "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
+        "window": "sliding_window", "gate": "gating",
+        "intermediate": "intermediate_size",
+        "moe_intermediate": "moe_intermediate_size",
+        "num_experts_per_tok": "num_experts_per_tok",
+        "routed_scaling_factor": "moe_routed_scaling_factor",
+        "rms_norm_eps": "rms_norm_eps",
+    }
+
+    def source_config(self) -> dict:
+        """This model under the source's key names."""
+        out = {theirs: getattr(self, ours)
+               for ours, theirs in self._SOURCE_KEYS.items()}
+        out.update(
+            num_experts=self.experts_here,
+            router_experts=self.n_routed_experts,
+            shared_expert_intermediate_size=
+            self.moe_intermediate * self.n_shared_experts,
+            layer_types=[_KIND_NAMES[k] for k in self.mixers],
+            mlp_layer_types=["dense" if i < self.first_k_dense else "sparse"
+                             for i in range(self.num_layers)],
+            num_attention_heads_per_layer=[self.heads(k)
+                                           for k in self.mixers],
+            num_attention_heads=self.heads_full,
+            rope_parameters={
+                "full_attention": self.rope_full.to_source(self.head_dim),
+                "sliding_attention":
+                    self.rope_window.to_source(self.head_dim)})
+        return out
+
+    @classmethod
+    def from_source(cls, source: dict, **ours) -> "GQADecoderConfig":
+        """From a ``config.json`` of the source's shape; its per-layer lists
+        may name more layers than ``num_hidden_layers`` holds (a cut model:
+        the first are taken).  ``router_experts`` (ours): the experts the
+        router scores, where ``num_experts`` is the share held.  ``ours``
+        gives what it does not say (``max_seq_len``, ``weights_dtype``)."""
+        layers = source["num_hidden_layers"]
+        kinds = tuple(_KINDS[k] for k in source["layer_types"][:layers])
+        per_layer = source["num_attention_heads_per_layer"][:layers]
+        by_kind = {k: {h for h, kk in zip(per_layer, kinds) if kk == k}
+                   for k in ("full", "window")}
+        if any(len(v) > 1 for v in by_kind.values()):
+            raise ValueError(f"layers of one kind differ in heads: {by_kind}")
+        mlp = source["mlp_layer_types"][:layers]
+        dense = next((i for i, t in enumerate(mlp) if t != "dense"), layers)
+        if "dense" in mlp[dense:]:
+            raise ValueError("dense feed-forward layers must lead")
+        routed = source.get("router_experts", source["num_experts"])
+        held = source["num_experts"]
+        d = source["head_dim"]
+        ropes = source["rope_parameters"]
+        kw = {mine: source[theirs]
+              for mine, theirs in cls._SOURCE_KEYS.items()}
+        kw.update(
+            mixers=kinds,
+            heads_full=next(iter(by_kind["full"]),
+                            source["num_attention_heads"]),
+            heads_window=next(iter(by_kind["window"]),
+                              source["num_attention_heads"]),
+            first_k_dense=dense, n_routed_experts=routed,
+            experts_held=held if held < routed else 0,
+            n_shared_experts=source["shared_expert_intermediate_size"]
+            // source["moe_intermediate_size"],
+            gate=bool(source.get("gating", False)),
+            rope_full=Rope.from_source(ropes["full_attention"], d),
+            rope_window=Rope.from_source(ropes["sliding_attention"], d))
+        kw.update(ours)
+        return cls(**kw)
+
+
+def init_gqa_weights(cfg: GQADecoderConfig, seed: int = 0
+                     ) -> Dict[str, np.ndarray]:
+    """Seeded weights for tests and smokes: norm scales 1, the router's
+    correction bias small, the embedding normal, every matrix normal over
+    sqrt(fan-in)."""
+    rng = np.random.RandomState(seed)
+    out = {}
+    for name, shape in cfg.param_specs().items():
+        if name.endswith("_scale"):
+            w = np.ones(shape, np.float32)
+        elif name.endswith("router_bias"):
+            w = 0.01 * rng.randn(*shape)
+        elif name == "dec_embed":
+            w = rng.randn(*shape)
+        else:
+            w = rng.randn(*shape) / np.sqrt(shape[-2])
+        out[name] = w.astype(np.dtype(cfg.weights_dtype))
+    return out
+
+
+# ==========================================================================
+# Program builder
+# ==========================================================================
+def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
+               routed: bool):
+    """``prog._srv_kernel_stats`` of a serving form: what its attention
+    kernels walk, from the feed and the sizes the kernels' wrappers use
+    (``gqa_kernels.prefill_walk`` / ``decode_walk_counts``), summed over the
+    layers; under ``from_counts`` what its grouped matmuls will have walked
+    (``mla_decoder._gmm_walk``)."""
+    full, win = len(cfg.full_layers), len(cfg.window_layers)
+    d, page = cfg.head_dim, kv_config.page_size
+    out = {}
+    if mode == "prefill":
+        s = int(np.size(feed["tokens"]))
+        if gqa_kernels.prefill_engages(s, d):
+            _, _, seen, causal = gqa_kernels.prefill_walk(s)
+            _, _, seen_w, _ = gqa_kernels.prefill_walk(s, cfg.window)
+            kvh = cfg.num_kv_heads
+            n = int(np.asarray(feed["last_index"])[0]) + 1
+            inside = min(n, cfg.window)       # rows whose window is not full
+            out = {"gqa_prefill_calls": full + win,
+                   "gqa_prefill_tokens": (full + win) * n,
+                   "gqa_prefill_blocks_visited":
+                       kvh * (full * seen + win * seen_w),
+                   "gqa_prefill_blocks_causal": kvh * (full + win) * causal,
+                   # the unmasked (query, key) pairs of the real tokens, a
+                   # head: what the attention needs whatever walks it
+                   "gqa_prefill_pairs_full": full * n * (n + 1) // 2,
+                   "gqa_prefill_pairs_window": win * (
+                       inside * (inside + 1) // 2
+                       + (n - inside) * cfg.window)}
+    elif gqa_kernels.decode_engages(page, d):
+        ctx = np.asarray(feed["context_lens"])
+        live = int((np.asarray(feed["slot_mapping"])
+                    < kv_config.pad_slot).sum())
+        _, walked, held = gqa_kernels.decode_walk_counts(
+            ctx, np.zeros_like(ctx), feed["block_tables"].shape[1], page, 0)
+        walked_w = 0
+        if win:
+            _, walked_w, _ = gqa_kernels.decode_walk_counts(
+                ctx, np.asarray(feed["window_first"]),
+                feed["window_tables"].shape[1], page, cfg.window)
+        out = {"gqa_decode_calls": full + win,
+               "gqa_decode_sequences": (full + win) * live,
+               "gqa_decode_pages_walked": full * walked + win * walked_w,
+               "gqa_decode_pages_in_context": (full + win) * held}
+    if routed and mla_kernels.gmm_engages(cfg.hidden, cfg.moe_intermediate):
+        out["from_counts"] = functools.partial(
+            _gmm_walk,
+            rows=int(np.size(feed["tokens"])) * cfg.num_experts_per_tok)
+    return out
+
+
+def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
+                      kv_dtype: str = "float32") -> tuple:
+    """One program form of the decoder: ``(program, feeds, fetches)``, with
+    what rides on a call as ``mla_decoder.close_form`` leaves it and, on the
+    serving forms, ``_srv_kernel_stats`` (:func:`_form_walk`)."""
+    from .serving import _kv_append, _kv_pool_params, _sampled
+
+    if mode not in ("reference", "prefill", "decode"):
+        raise ValueError(f"the grouped-query decoder builds no {mode!r} form")
+    if kv_dtype not in ("float32", "bfloat16"):
+        raise ValueError(f"bad kv_dtype {kv_dtype!r}")
+    if _sampled(sampling) and mode == "reference":
+        raise ValueError("the reference form is the greedy oracle; "
+                         "sampling applies to serving forms only")
+    prog = Program()
+    prog._label = mode
+    m = _MB(prog, cfg)
+    b = m.b
+    whole = mode in ("reference", "prefill")
+    f = open_form(b, mode, sampling)
+    feeds = f["feeds"]
+    cached = mode != "reference"
+    windowed = cached and bool(cfg.window_layers)
+    win_slots = win_tables = win_first = None
+    if windowed:
+        # the window group's own slots, and for a decode step its table (the
+        # pages from each row's first held position on) and that position
+        win_slots = b.feed("window_slot_mapping", (-1,), VarType.INT32)
+        feeds.append("window_slot_mapping")
+        if not whole:
+            win_tables = b.feed("window_tables", (-1, -1), VarType.INT32)
+            win_first = b.feed("window_first", (-1,), VarType.INT32)
+            feeds += ["window_tables", "window_first"]
+    flat_pos, hid = embed_rows(m, f["tokens"], f["positions"])
+    pools = {i: _kv_pool_params(b, i, False, kv_dtype)[:2]
+             for i in range(cfg.num_layers)} if cached else {}
+    valid = None
+    if cached:
+        with m.part("embed"):
+            valid = b.tmp("valid")
+            m.op("slot_is_live", {"SlotMapping": [f["slot_mapping"]],
+                                  "Cache": [pools[cfg.full_layers[0]][0]]},
+                 {"Out": [valid]})
+    kv_type = convert_dtype(kv_dtype)
+
+    def heads_of(x, heads, tag):
+        return b.reshape(x, [-1, heads, cfg.head_dim], tag)
+
+    def turned(x, rope, tag):
+        o = m.tmp(tag)
+        m.op("rope_half", {"X": [x], "Positions": [flat_pos]}, {"Out": [o]},
+             {"inv_freq": [float(v) for v in rope.inv_freq()],
+              "factor": float(rope.attention_factor)})
+        return o
+
+    def stored(x, tag):
+        """``x`` in the pools' type: what the append writes, and what the
+        prompt's own attention reads, so both phases see the same rows."""
+        o = m.tmp(tag)
+        m.op("cast", {"X": [x]}, {"Out": [o]},
+             {"in_dtype": int(VarType.FP32), "out_dtype": int(kv_type)})
+        return o
+
+    def mixer(i, hn):
+        kind, p = cfg.mixer(i), f"dec_l{i}_"
+        heads, rope, window = cfg.heads(kind), cfg.rope(kind), \
+            cfg.window if kind == "window" else 0
+        q = turned(heads_of(m.mm(hn, p + "wq", f"l{i}_q"), heads,
+                            f"l{i}_q3"), rope, f"l{i}_qr")
+        k = turned(heads_of(m.mm(hn, p + "wk", f"l{i}_k"), cfg.num_kv_heads,
+                            f"l{i}_k3"), rope, f"l{i}_kr")
+        v = heads_of(m.mm(hn, p + "wv", f"l{i}_v"), cfg.num_kv_heads,
+                     f"l{i}_v3")
+        attrs = {"scale": float(cfg.head_dim ** -0.5), "window": int(window)}
+        out = m.tmp(f"l{i}_att")
+        ins = {"Q": [q]}
+        if cfg.gate:
+            ins["Gate"] = [m.mm(hn, p + "wg", f"l{i}_g")]
+        if cached:
+            k, v = stored(k, f"l{i}_ks"), stored(v, f"l{i}_vs")
+            kc, vc = pools[i]
+            _kv_append(b, k, v, win_slots if window else f["slot_mapping"],
+                       kc, vc, None, None)
+        if whole:
+            ins.update({"K": [k], "V": [v]})
+            m.op("gqa_prefill_attention", ins, {"Out": [out]}, attrs)
+            return out
+        ins.update({"KCache": [kc], "VCache": [vc],
+                    "ContextLens": [f["context_lens"]],
+                    "BlockTables": [win_tables if window else f["tables"]]})
+        if window:
+            ins["First"] = [win_first]
+        m.op("gqa_paged_attention", ins, {"Out": [out]}, attrs)
+        return out
+
+    counts: List[str] = []
+    routes: List[str] = []
+    absent: List[str] = []
+    for i in range(cfg.num_layers):
+        hid = m.block(i, hid, flat_pos, None, valid, counts, routes,
+                      kda=mixer, absent=absent)
+    out_name = close_form(m, prog, hid, f["last_index"] if whole else None,
+                          routes, counts, absent, sampling, f["seeds"],
+                          routes_all=mode == "prefill")
+    if cached:
+        prog._srv_kernel_stats = functools.partial(
+            _form_walk, mode=mode, cfg=cfg, routed=bool(counts))
+    return prog, feeds, [out_name]

@@ -85,6 +85,39 @@ class KVCacheConfig:
     # its length; the pools that hold them have one slot more, the
     # padding's (``pad_state_slot``).  0: the model keeps no such state
     state_slots: int = 0
+    # a second GROUP of pages with a lifetime rule of its own: the layers
+    # ``window_layers`` (indices among the cache's ``num_layers``) attend the
+    # last ``window`` positions only, keep their rows in pools of
+    # ``window_pages`` pages with their own free list and per-sequence table,
+    # and give back the pages behind the window as a sequence advances.  The
+    # other layers' group is the cache as it ever was.  0 / (): one group
+    window: int = 0
+    window_pages: int = 0
+    window_layers: Tuple[int, ...] = ()
+
+    @property
+    def window_pages_per_seq(self) -> int:
+        """The most pages a sequence holds in the window group: the pages
+        that cover positions ``> ctx - window - page_size``."""
+        return self.window // self.page_size + 2 if self.window else 0
+
+    @property
+    def window_pad_slot(self) -> int:
+        """The window group's pad sentinel, past its pools' end."""
+        return self.window_pages * self.page_size
+
+    def groups(self) -> Dict[str, dict]:
+        """The page groups by name: their layers, window (0: every position
+        kept until the sequence ends) and pages."""
+        held = tuple(i for i in range(self.num_layers)
+                     if i not in self.window_layers)
+        out = {"full": {"layers": held, "window": 0,
+                        "pages": self.num_pages}}
+        if self.window:
+            out["window"] = {"layers": tuple(self.window_layers),
+                             "window": self.window,
+                             "pages": self.window_pages}
+        return out
 
     @property
     def pad_state_slot(self) -> int:
@@ -117,7 +150,7 @@ class KVCacheConfig:
         whole = (self.page_size * self.head_dim) % (_TILE_ROWS * _LANES) == 0
         return t if whole else 1
 
-    def pool_shape(self):
+    def pool_shape(self, window: bool = False):
         """The shape a pool is STORED in (scope, programs, kernels).
         Logically a pool is ``(kv_heads, num_pages, page_size,
         head_dim)`` — the contract of the allocator, its flat slots,
@@ -130,15 +163,17 @@ class KVCacheConfig:
         kernels work; a head_dim-64 pool in the logical shape it holds
         page-minor, and ``paged_decode`` cost a re-layout of every pool
         on every call.  With ``tokens_per_row`` 1 (head_dim 128 and
-        over, the tiny test models) the two shapes are one."""
+        over, the tiny test models) the two shapes are one.  ``window``: a
+        pool of the window group, ``window_pages`` pages."""
         t = self.tokens_per_row
-        return (self.num_kv_heads, self.num_pages, self.page_size // t,
-                self.head_dim * t)
+        return (self.num_kv_heads,
+                self.window_pages if window else self.num_pages,
+                self.page_size // t, self.head_dim * t)
 
-    def make_pool(self) -> np.ndarray:
+    def make_pool(self, window: bool = False) -> np.ndarray:
         """One zeroed host-side pool (K or V, one layer); the engine
         stages it to the device once via scope.set + device_put."""
-        return np.zeros(self.pool_shape(), dtype=self.dtype)
+        return np.zeros(self.pool_shape(window), dtype=self.dtype)
 
     def scale_shape(self):
         """Per-(kv_head, page) absmax scale pool (int8 only)."""
@@ -176,6 +211,11 @@ class _Seq:
     pending_hit: int = 0
     pending_shared: int = 0
     state_slot: Optional[int] = None   # its slot in the state pools
+    # the window group: the pages that cover logical pages ``win_first ..
+    # win_first + len(win_pages) - 1`` of the sequence
+    win_pages: List[int] = field(default_factory=list)
+    win_first: int = 0
+    win_slots: Optional[np.ndarray] = None   # of the last append
 
 
 def _chain(digest: bytes, tokens) -> bytes:
@@ -231,6 +271,9 @@ class PagedKVCache:
             quant_capacity=("gauge", "kv_quant_capacity_tokens",
                             "token slots the quantized pool holds at its "
                             "fixed byte budget"),
+            window_in_use=("gauge", "kv_window_pool_pages_in_use",
+                           "pages of the window group owned by live "
+                           "sequences"),
             alloc=("counter", "kv_pool_pages_alloc_total",
                    "KV pages handed out"),
             freed=("counter", "kv_pool_pages_freed_total",
@@ -245,6 +288,17 @@ class PagedKVCache:
         self._stateful = config.state_slots > 0
         self.peak_state_slots = 0
         self.state_slots_preempted = 0
+        # the window group: its own pages, handed out as the full group's
+        if config.window and self.prefix_cache:
+            raise ValueError(
+                "a cache with a window group takes no prefix cache: a page "
+                "freed behind the window cannot be shared by a later prompt")
+        if config.window and config.window_pages < 1:
+            raise ValueError("a cache with a window group needs window_pages")
+        self._windowed = config.window > 0
+        self._win_free: deque = deque(range(config.window_pages))
+        self.peak_window_pages = 0
+        self.freed_behind_window = 0
         # the sum of every live sequence's length, kept as they change:
         # ``fragmentation`` is published on every append, and summing the
         # sequences there made a decode step of n sequences n * n
@@ -325,7 +379,91 @@ class PagedKVCache:
         return (self.pages_needed(seq_id, n_tokens)
                 + self.cow_fork_need(seq_id, n_tokens)
                 <= self.num_free_pages) and (
-                    not self._stateful or self.has_slot_for(seq_id))
+                    not self._stateful or self.has_slot_for(seq_id)) and (
+                    not self._windowed
+                    or self.window_fits([(seq_id, n_tokens)]))
+
+    # -- the window group ----------------------------------------------------
+    def _window_span(self, length: int) -> Tuple[int, int]:
+        """The logical pages ``[first, end)`` a sequence of ``length`` tokens
+        holds in the window group: those that cover positions ``> length -
+        window - page_size``."""
+        ps = self.config.page_size
+        return (max(0, length - self.config.window - ps + 1) // ps,
+                -(-length // ps))
+
+    def window_pages_needed(self, seq_id, n_tokens: int) -> int:
+        """Net window-group pages appending ``n_tokens`` to ``seq_id`` takes
+        from the free list: the fresh ones less those it gives back behind
+        the window first (may be negative)."""
+        s = self._seqs.get(seq_id)
+        first, end = self._window_span((s.length if s else 0) + n_tokens)
+        return (end - first) - (len(s.win_pages) if s else 0)
+
+    @property
+    def num_free_window_pages(self) -> int:
+        return len(self._win_free)
+
+    @property
+    def window_pages_in_use(self) -> int:
+        return self.config.window_pages - len(self._win_free)
+
+    def window_fits(self, asks) -> bool:
+        """Whether the window group covers every ``(seq_id, n_tokens)`` of
+        ``asks`` appended together; True of a cache with one group."""
+        if not self._windowed:
+            return True
+        return sum(self.window_pages_needed(sid, n) for sid, n in asks) \
+            <= len(self._win_free)
+
+    def _window_append(self, s: _Seq, old_len: int, n_tokens: int):
+        """Advance ``s`` (its length already ``old_len + n_tokens``) in the
+        window group: give back the pages behind the new window, take the
+        fresh ones, and return the appended positions' flat slots in the
+        group's pools; a position behind the window (the head of a long
+        prompt) carries the group's pad sentinel and is never written."""
+        ps = self.config.page_size
+        first, end = self._window_span(s.length)
+        drop = min(max(0, first - s.win_first), len(s.win_pages))
+        if drop:
+            self._win_free.extend(s.win_pages[:drop])
+            del s.win_pages[:drop]
+            self.freed_behind_window += drop
+        if not s.win_pages:
+            s.win_first = first
+        else:
+            s.win_first += drop
+        for _ in range(end - (s.win_first + len(s.win_pages))):
+            s.win_pages.append(self._win_free.popleft())
+        self.peak_window_pages = max(self.peak_window_pages,
+                                     self.window_pages_in_use)
+        pos = old_len + np.arange(n_tokens)
+        page = pos // ps - s.win_first
+        held = page >= 0
+        pages = np.asarray(s.win_pages, np.int64)
+        slots = np.where(held, pages[np.where(held, page, 0)] * ps + pos % ps,
+                         self.config.window_pad_slot)
+        s.win_slots = slots.astype(np.int32)
+
+    def window_slots(self, seq_id) -> np.ndarray:
+        """The window group's flat slots of the positions the sequence's
+        last ``append_tokens`` appended."""
+        return self._seqs[seq_id].win_slots
+
+    def window_first(self, seq_id) -> int:
+        """The position of the first slot of the sequence's window table."""
+        return self._seqs[seq_id].win_first * self.config.page_size
+
+    def window_table(self, seq_id, width: int) -> np.ndarray:
+        """The sequence's pages in the window group, entry 0 the page of
+        position ``window_first``, padded to ``width`` with page 0."""
+        pages = self._seqs[seq_id].win_pages
+        out = np.zeros(width, np.int32)
+        out[: len(pages)] = pages
+        return out
+
+    def num_window_pages_of(self, seq_id) -> int:
+        return len(self._seqs[seq_id].win_pages)
 
     # -- state slots ---------------------------------------------------------
     def has_slot_for(self, seq_id) -> bool:
@@ -350,6 +488,8 @@ class PagedKVCache:
         handles.pages_in_use.set(self.pages_in_use)
         handles.utilization.set(self.utilization())
         handles.fragmentation.set(self.fragmentation())
+        if self._windowed:
+            handles.window_in_use.set(self.window_pages_in_use)
         if self.prefix_cache:
             handles.prefix_cached.set(len(self._cached_free))
             handles.prefix_shared.set(
@@ -455,7 +595,9 @@ class PagedKVCache:
         need = self.pages_needed(seq_id, n_tokens)
         fork = self.cow_fork_need(seq_id, n_tokens)
         if need + fork > self.num_free_pages or (
-                self._stateful and not self.has_slot_for(seq_id)):
+                self._stateful and not self.has_slot_for(seq_id)) or (
+                self._windowed
+                and not self.window_fits([(seq_id, n_tokens)])):
             return None
         s = self._seqs.get(seq_id)
         if s is None:
@@ -515,6 +657,8 @@ class PagedKVCache:
                 slots[j] = s.pages[pos // ps] * ps + pos % ps
         s.length += n_tokens
         self._live_tokens += n_tokens
+        if self._windowed:
+            self._window_append(s, s.length - n_tokens, n_tokens)
         if self.prefix_cache:
             # only pages covering the appended range can change — a
             # whole-sequence rescan here would be O(len^2) host work
@@ -633,6 +777,10 @@ class PagedKVCache:
             raise ValueError("truncate_tokens: a cache with state slots "
                              "cannot roll a sequence back (its state holds "
                              "the tokens to be dropped)")
+        if self._windowed:
+            raise ValueError("truncate_tokens: a cache with a window group "
+                             "cannot roll a sequence back (a page freed "
+                             "behind the window does not come back)")
         s = self._seqs[seq_id]
         if n_tokens > s.length:
             raise ValueError(
@@ -702,6 +850,8 @@ class PagedKVCache:
         if s.state_slot is not None:
             self._free_slots.append(s.state_slot)
             self.state_slots_preempted += bool(preempted)
+        if s.win_pages:
+            self._win_free.extend(s.win_pages)
         self._live_tokens -= s.length
         released = 0
         for page in s.pages:
@@ -756,6 +906,20 @@ class PagedKVCache:
             "peak": self.peak_state_slots,
             "freed_by_preemption": self.state_slots_preempted}} \
             if self.config.state_slots else {}
+        if self._windowed:
+            # each group's own count; the keys below stay the full group's
+            slots["groups"] = {
+                "full": {"layers": len(self.config.groups()["full"]["layers"]),
+                         "window": 0, "pages_total": self.config.num_pages,
+                         "pages_in_use": self.pages_in_use,
+                         "peak_pages": self.peak_pages,
+                         "freed_behind_window": 0},
+                "window": {"layers": len(self.config.window_layers),
+                           "window": self.config.window,
+                           "pages_total": self.config.window_pages,
+                           "pages_in_use": self.window_pages_in_use,
+                           "peak_pages": self.peak_window_pages,
+                           "freed_behind_window": self.freed_behind_window}}
         return {
             **slots,
             "dtype": self.config.dtype,

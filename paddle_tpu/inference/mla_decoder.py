@@ -318,7 +318,16 @@ def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
                           heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
             p + "wo": (heads * cfg.v_head_dim, h),
         })
-    specs[p + "ffn_norm_scale"] = (h,)
+    specs.update(ffn_specs(cfg, i, moe))
+    return specs
+
+
+def ffn_specs(cfg, i: int, moe: bool) -> Dict[str, tuple]:
+    """The feed-forward half of layer ``i``: its norm and the dense SwiGLU,
+    or the router, this chip's experts and the shared expert (what
+    ``_MB._ffn`` builds, for any description with these fields)."""
+    h, p = cfg.hidden, f"dec_l{i}_"
+    specs = {p + "ffn_norm_scale": (h,)}
     if not moe:
         f = cfg.intermediate
         specs.update({p + "w_gate": (h, f), p + "w_up": (h, f),
@@ -405,6 +414,11 @@ def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
 # ==========================================================================
 # Program builders
 # ==========================================================================
+#: a layer's mixer kind -> the part of the model its ops serve
+MIXER_PARTS = {"mla": "mla_part", "kda": "kda_part", "full": "attn_full",
+               "window": "attn_window"}
+
+
 class _MB:
     """The block builder of serving.py's ``_B`` plus this model's
     composites.  Parameters take the configuration's weights type."""
@@ -415,7 +429,7 @@ class _MB:
         self.b = _B(program)
         self.cfg = cfg
         self.wdt = convert_dtype(cfg.weights_dtype)
-        for name, shape in mla_param_specs(cfg).items():
+        for name, shape in cfg.param_specs().items():
             self.b.param(name, shape, dtype=self.wdt)
 
     def op(self, *a, **kw):
@@ -427,8 +441,8 @@ class _MB:
     @contextlib.contextmanager
     def part(self, name):
         """Every op built inside serves this part of the model (``embed``,
-        ``mla_part``, ``kda_part``, ``moe_part``, ``dense_ffn``, ``head``,
-        ``mtp``): its attr ``part``, which ``registry.run_op`` makes the
+        ``mla_part``, ``kda_part``, ``attn_full``, ``attn_window``,
+        ``moe_part``, ``dense_ffn``, ``head``, ``mtp``): its attr ``part``, which ``registry.run_op`` makes the
         op's outermost scope, so the compiled program says whose time each
         of its instructions is (``profiler.device_symbols``)."""
         was, self.b.part = self.b.part, name
@@ -539,17 +553,18 @@ class _MB:
               kda=None, absent=None):
         """One pre-norm block over rows ``hid`` (n, hidden).  ``attend``
         maps ``(i, q_nope, q_rope, c_kv, k_r)`` to the attention's output
-        (n, heads * dv), ``kda`` maps ``(i, normed rows)`` to a KDA layer's
-        mixer output; ``valid`` (or None) marks the rows that are real
+        (n, heads * dv), ``kda`` maps ``(i, normed rows)`` to the mixer
+        output of a layer of any other kind (a KDA layer's; a grouped-query
+        layer's, full or windowed: ``MIXER_PARTS``), before its ``wo``;
+        ``valid`` (or None) marks the rows that are real
         tokens; an expert layer appends its per-expert counts to
         ``counts`` (and, where it holds a share of its experts, the number
         of rows none of whose experts it holds to ``absent``)."""
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
-        mix = "kda_part" if i < cfg.num_layers and cfg.mixer(i) == "kda" \
-            else "mla_part"
+        mix = MIXER_PARTS[cfg.mixer(i) if i < cfg.num_layers else "mla"]
         with self.part(mix):
             hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an")
-            att = kda(i, hn) if mix == "kda_part" \
+            att = kda(i, hn) if mix != "mla_part" \
                 else attend(i, *self.latents(i, hn, positions))
             hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o"), f"l{i}_res1")
         dense = i < cfg.first_k_dense
@@ -692,6 +707,122 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
     return out
 
 
+def open_form(b, mode: str, sampling) -> dict:
+    """The feeds a program form of ``mode`` opens with, by name (``tables``
+    the block tables under the form's own feed name), ``feeds`` their names
+    in order and ``seeds`` the sampling lanes' feed or None.  A description
+    adds its own feeds after these."""
+    from .serving import _sampled
+
+    f = {"seeds": None}
+    if mode in ("reference", "prefill"):
+        f["tokens"] = b.feed("tokens", (1, -1), VarType.INT32)
+        f["positions"] = b.feed("positions", (1, -1), VarType.INT32)
+        f["last_index"] = b.feed("last_index", (1,), VarType.INT32)
+        feeds = ["tokens", "positions", "last_index"]
+        if mode == "prefill":
+            f["slot_mapping"] = b.feed("slot_mapping", (-1,), VarType.INT32)
+            feeds.append("slot_mapping")
+        if _sampled(sampling):
+            f["seeds"] = b.feed("sample_seeds", (1,), VarType.INT32)
+            feeds.append("sample_seeds")
+    elif mode == "decode":
+        f["tokens"] = b.feed("tokens", (-1,), VarType.INT32)
+        f["positions"] = b.feed("positions", (-1,), VarType.INT32)
+        f["tables"] = b.feed("block_tables", (-1, -1), VarType.INT32)
+        f["context_lens"] = b.feed("context_lens", (-1,), VarType.INT32)
+        f["slot_mapping"] = b.feed("slot_mapping", (-1,), VarType.INT32)
+        feeds = ["tokens", "positions", "block_tables", "context_lens",
+                 "slot_mapping"]
+        if _sampled(sampling):
+            f["seeds"] = b.feed("sample_seeds", (-1,), VarType.INT32)
+            feeds.append("sample_seeds")
+    else:
+        f["tokens"] = b.feed("tokens", (-1, -1), VarType.INT32)     # (B, S)
+        f["positions"] = b.feed("positions", (-1, -1), VarType.INT32)
+        f["slot_mapping"] = b.feed("slot_mapping", (-1,), VarType.INT32)
+        f["tables"] = b.feed("verify_tables", (-1, -1), VarType.INT32)
+        feeds = ["tokens", "positions", "slot_mapping", "verify_tables"]
+        if _sampled(sampling):
+            f["seeds"] = b.feed("sample_seeds", (-1,), VarType.INT32)
+            feeds.append("sample_seeds")
+    f["feeds"] = feeds
+    return f
+
+
+def embed_rows(m: "_MB", tokens, positions):
+    """The rows' inputs under the part ``embed``: the flat positions and the
+    float32 embeddings of the flat ids."""
+    b = m.b
+    with m.part("embed"):
+        flat_tok = b.reshape(tokens, [-1], "tok_flat")
+        flat_pos = b.reshape(positions, [-1], "pos_flat")
+        hid = b.tmp("h0")
+        m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [flat_tok]},
+             {"Out": [hid]})
+        hid32 = b.tmp("h0_f32")
+        m.op("cast", {"X": [hid]}, {"Out": [hid32]},
+             {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
+    return flat_pos, hid32
+
+
+def close_form(m: "_MB", prog, hid, last_index, routes, counts, absent,
+               sampling, seeds, routes_all: bool = False) -> str:
+    """The end of a form, from the last block's rows ``hid``: the emitting
+    row of a whole prompt (``last_index``; None: every row emits), the final
+    norm, the head, the token and what rides on a call (``_srv_hidden``,
+    ``_srv_logits``, ``_srv_score``, ``_srv_counts``, ``_srv_routes``,
+    ``_srv_absent``, and with ``routes_all`` every row's routing).  Returns
+    the token's name."""
+    from .serving import _emit_head
+
+    b, whole = m.b, last_index is not None
+    prog._srv_hidden = hid
+    # (expert layers, rows, k): every row's routing, a prompt's too.  In a
+    # hybrid model a row's neighbours reach it undiluted (the convolution's
+    # taps, the fast-decaying channels of a state), so a check of the served
+    # logits follows the engine's routing on the prompt's rows as well
+    with m.part("moe_part"):
+        prog._srv_routes_all = m.stacked(routes, "token_routes_all") \
+            if routes_all and routes else None
+    if whole:
+        with m.part("head"):
+            last = b.tmp("hlast")
+            m.op("gather", {"X": [hid], "Index": [last_index]},
+                 {"Out": [last]}, {"axis": 0})
+            hid = last
+        # the routing of the one row that emits
+        picked = []
+        with m.part("moe_part"):
+            for j, r in enumerate(routes):
+                o = b.tmp(f"route_last_{j}")
+                m.op("gather", {"X": [r], "Index": [last_index]},
+                     {"Out": [o]}, {"axis": 0})
+                picked.append(o)
+        routes = picked
+    out_name = "next_token" if whole else "next_tokens"
+    with m.part("head"):
+        logits = m.mm(m.norm(hid, "dec_norm_scale", "fnorm"), "dec_head",
+                      "logits")
+        _emit_head(b, logits, out_name, sampling, seeds)
+        score = b.blk.create_var(name="token_score", dtype=VarType.FP32).name
+        m.op("token_score", {"Logits": [logits], "Token": [out_name]},
+             {"Out": [score]})
+    prog._srv_params = dict.fromkeys(m.cfg.param_specs())
+    prog._srv_logits = logits
+    prog._srv_score = score
+    with m.part("moe_part"):
+        # (expert layers, experts): the tokens each expert received; (expert
+        # layers, rows, k): the experts each emitting row was routed to
+        prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
+        prog._srv_routes = m.stacked(routes, "token_routes") \
+            if routes else None
+        # (expert layers,): the rows none of whose experts this chip holds
+        prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
+    prog._tp_degree = 1
+    return out_name
+
+
 def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
     """One program form of the decoder: ``(program, feeds, fetches)``.
@@ -701,7 +832,7 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     expert by expert layer) and ``_srv_score`` (each emitted token's logit
     and the row's log-sum-exp, two floats a row); the serving forms
     ``_srv_kernel_stats`` (:func:`_form_walk`)."""
-    from .serving import _emit_head, _sampled
+    from .serving import _sampled
 
     if mode == "chunk":
         raise ValueError("the MLA decoder builds no 'chunk' form")
@@ -723,38 +854,11 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     m = _MB(prog, cfg)
     b = m.b
     whole = mode in ("reference", "prefill")
-    seeds = None
-    if whole:
-        tokens = b.feed("tokens", (1, -1), VarType.INT32)
-        positions = b.feed("positions", (1, -1), VarType.INT32)
-        last_index = b.feed("last_index", (1,), VarType.INT32)
-        feeds = ["tokens", "positions", "last_index"]
-        if mode == "prefill":
-            slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
-            feeds.append("slot_mapping")
-        if _sampled(sampling):
-            seeds = b.feed("sample_seeds", (1,), VarType.INT32)
-            feeds.append("sample_seeds")
-    elif mode == "decode":
-        tokens = b.feed("tokens", (-1,), VarType.INT32)
-        positions = b.feed("positions", (-1,), VarType.INT32)
-        tables = b.feed("block_tables", (-1, -1), VarType.INT32)
-        ctx_lens = b.feed("context_lens", (-1,), VarType.INT32)
-        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
-        feeds = ["tokens", "positions", "block_tables", "context_lens",
-                 "slot_mapping"]
-        if _sampled(sampling):
-            seeds = b.feed("sample_seeds", (-1,), VarType.INT32)
-            feeds.append("sample_seeds")
-    else:
-        tokens = b.feed("tokens", (-1, -1), VarType.INT32)          # (B, S)
-        positions = b.feed("positions", (-1, -1), VarType.INT32)
-        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)     # (B*S,)
-        tables = b.feed("verify_tables", (-1, -1), VarType.INT32)   # (B, W)
-        feeds = ["tokens", "positions", "slot_mapping", "verify_tables"]
-        if _sampled(sampling):
-            seeds = b.feed("sample_seeds", (-1,), VarType.INT32)
-            feeds.append("sample_seeds")
+    f = open_form(b, mode, sampling)
+    tokens, positions, feeds, seeds = f["tokens"], f["positions"], \
+        f["feeds"], f["seeds"]
+    last_index, slot_map = f.get("last_index"), f.get("slot_mapping")
+    tables, ctx_lens = f.get("tables"), f.get("context_lens")
 
     state_slots = None
     if hybrid and mode != "reference":
@@ -763,16 +867,8 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
         state_slots = b.feed("state_slots", (1,) if whole else (-1,),
                              VarType.INT32)
         feeds.append("state_slots")
-    with m.part("embed"):     # the rows' inputs: ids, embeddings, liveness
-        flat_tok = b.reshape(tokens, [-1], "tok_flat")
-        flat_pos = b.reshape(positions, [-1], "pos_flat")
-        hid = b.tmp("h0")
-        m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [flat_tok]},
-             {"Out": [hid]})
-        hid32 = b.tmp("h0_f32")
-        m.op("cast", {"X": [hid]}, {"Out": [hid32]},
-             {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
-        hid = hid32
+    flat_pos, hid = embed_rows(m, tokens, positions)
+    with m.part("embed"):     # the rows' contexts and liveness
         if mode == "verify":
             # a verify row's context ends at its own position
             ctx_lens = b.tmp("ctx_from_pos")
@@ -811,52 +907,12 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     for i in range(cfg.num_layers):
         hid = m.block(i, hid, flat_pos, attend, valid, counts, routes,
                       kda=kda, absent=absent)
-    prog._srv_hidden = hid
-    # (expert layers, rows, k): every row's routing, a prompt's too.  In a
-    # hybrid model a row's neighbours reach it undiluted (the convolution's
-    # taps, the fast-decaying channels of a state), so a check of the served
-    # logits follows the engine's routing on the prompt's rows as well
-    with m.part("moe_part"):
-        prog._srv_routes_all = m.stacked(routes, "token_routes_all") \
-            if hybrid and whole and routes else None
-    if whole:
-        with m.part("head"):
-            last = b.tmp("hlast")
-            m.op("gather", {"X": [hid], "Index": [last_index]},
-                 {"Out": [last]}, {"axis": 0})
-            hid = last
-        # the routing of the one row that emits
-        picked = []
-        with m.part("moe_part"):
-            for j, r in enumerate(routes):
-                o = b.tmp(f"route_last_{j}")
-                m.op("gather", {"X": [r], "Index": [last_index]},
-                     {"Out": [o]}, {"axis": 0})
-                picked.append(o)
-        routes = picked
-    out_name = "next_token" if whole else "next_tokens"
-    with m.part("head"):
-        logits = m.mm(m.norm(hid, "dec_norm_scale", "fnorm"), "dec_head",
-                      "logits")
-        _emit_head(b, logits, out_name, sampling, seeds)
-        score = b.blk.create_var(name="token_score", dtype=VarType.FP32).name
-        m.op("token_score", {"Logits": [logits], "Token": [out_name]},
-             {"Out": [score]})
-    prog._srv_params = dict.fromkeys(mla_param_specs(cfg))
-    prog._srv_logits = logits
-    prog._srv_score = score
-    with m.part("moe_part"):
-        # (expert layers, experts): the tokens each expert received; (expert
-        # layers, rows, k): the experts each emitting row was routed to
-        prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
-        prog._srv_routes = m.stacked(routes, "token_routes") \
-            if routes else None
-        # (expert layers,): the rows none of whose experts this chip holds
-        prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
+    out_name = close_form(m, prog, hid, last_index if whole else None,
+                          routes, counts, absent, sampling, seeds,
+                          routes_all=hybrid and whole)
     if mode != "reference":
         prog._srv_kernel_stats = functools.partial(
             _form_walk, mode=mode, cfg=cfg, routed=bool(counts))
-    prog._tp_degree = 1
     return prog, feeds, [out_name]
 
 

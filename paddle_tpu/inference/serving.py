@@ -1211,6 +1211,22 @@ class _EngineCore:
                                  "core must be told the engine's max_batch")
             self.kv_config = dataclasses.replace(
                 self.kv_config, state_slots=int(max_batch))
+        # a model whose window layers keep their own group of pages says
+        # which pools those are (``window_pool_names``); the group holds
+        # what the engine's full batch can: its most pages a sequence
+        # (``window_pages_per_seq``) times ``max_batch``, so a running
+        # sequence never waits for a window page
+        self._window_pools = frozenset(
+            getattr(cfg, "window_pool_names", list)()) \
+            if self.kv_config.window else frozenset()
+        if self.kv_config.window:
+            if int(max_batch) < 1:
+                raise ValueError("this model keeps a window group of pages: "
+                                 "the core must be told the engine's "
+                                 "max_batch")
+            self.kv_config = dataclasses.replace(
+                self.kv_config, window_pages=int(max_batch)
+                * self.kv_config.window_pages_per_seq)
         self.kv = PagedKVCache(self.kv_config, prefix_cache=prefix_cache,
                                seed=prefix_seed)
         # what this model is not served with, refused here and loudly
@@ -1297,7 +1313,7 @@ class _EngineCore:
             # the pools are DONATED every prefill/decode step: they must
             # be XLA-owned buffers, never zero-copy host aliases
             pool = self.kv_config.make_scale_pool() if "_scale_" in name \
-                else self.kv_config.make_pool()
+                else self.kv_config.make_pool(name in self._window_pools)
             self.scope.set(name, device_put_owned(pool, dev_of(name)))
         for name, (shape, dtype) in self._state_specs.items():
             # made on the device: a slot pool is gigabytes of zeros
@@ -1537,6 +1553,12 @@ class _EngineCore:
             if self._state_specs:
                 feed["state_slots"] = np.array(
                     [self.kv.state_slot(req.req_id)], np.int32)
+            if self.kv_config.window:
+                # the window group's slots: the head of a long prompt, behind
+                # the window, carries its pad sentinel and is not written
+                window = np.full(S, self.kv_config.window_pad_slot, np.int32)
+                window[:L] = self.kv.window_slots(req.req_id)
+                feed["window_slot_mapping"] = window
             if self.sampling is not None:
                 feed["sample_seeds"] = np.array([self._lane(req)], np.int32)
         if span.recording:
@@ -1665,6 +1687,22 @@ class _EngineCore:
                     state[:B] = [self.kv.state_slot(st.req.req_id)
                                  for st in states]
                     feed["state_slots"] = state
+                if self.kv_config.window:
+                    # the window group: a table of fixed width (the most a
+                    # sequence holds), its entry 0 the page of the row's
+                    # first held position
+                    kvc, ids = self.kv_config, [st.req.req_id
+                                                for st in states]
+                    Ww = kvc.window_pages_per_seq
+                    slots = np.full(Bp, kvc.window_pad_slot, np.int32)
+                    slots[:B] = [self.kv.window_slots(r)[0] for r in ids]
+                    wtab = np.zeros((Bp, Ww), np.int32)
+                    first = np.zeros(Bp, np.int32)
+                    for i, r in enumerate(ids):
+                        wtab[i] = self.kv.window_table(r, Ww)
+                        first[i] = self.kv.window_first(r)
+                    feed.update(window_slot_mapping=slots,
+                                window_tables=wtab, window_first=first)
                 if self.sampling is not None:
                     lanes = np.zeros(Bp, np.int32)
                     for i, st in enumerate(states):
@@ -1981,7 +2019,13 @@ class _EngineCore:
         per_pool = int(np.prod(self.kv_config.pool_shape())) * \
             np.dtype(self.kv_config.dtype).itemsize
         per_pool += self.kv_config.scale_bytes()
-        return len(self.cfg.cache_pool_names()) * per_pool // self.tp
+        names = self.cfg.cache_pool_names()
+        held = (len(names) - len(self._window_pools)) * per_pool
+        if self._window_pools:      # the window group's pools are smaller
+            held += len(self._window_pools) * np.dtype(
+                self.kv_config.dtype).itemsize * int(np.prod(
+                    self.kv_config.pool_shape(window=True)))
+        return held // self.tp
 
     def memory_stats(self) -> dict:
         """The serving-side memory section (tools/mem_report.py):
@@ -2675,7 +2719,8 @@ class ServingEngine:
         need = sum(self.kv.pages_needed(st.req.req_id, 1)
                    + self.kv.cow_fork_need(st.req.req_id, 1)
                    for st in self.running)
-        return need <= self.kv.num_free_pages
+        return need <= self.kv.num_free_pages and self.kv.window_fits(
+            (st.req.req_id, 1) for st in self.running)
 
     def _admission_fits(self, req: Request,
                         n_tokens: Optional[int] = None) -> bool:
@@ -2701,7 +2746,12 @@ class ServingEngine:
             # room for it would livelock a prompt that exactly fills
             # its page budget
             growth += -(-(P + 1) // ps) - -(-P // ps)
-        return prompt_pages + growth <= self.kv.num_free_pages
+        # the window group is counted too: the prompt and its first token
+        # there, and one token's growth of every running sequence
+        return prompt_pages + growth <= self.kv.num_free_pages and \
+            self.kv.window_fits(
+                [(req.req_id, L + 1)]
+                + [(st.req.req_id, 1) for st in self.running])
 
     def _shed(self, req: Request, now: float):
         """Terminal `shed` outcome for a queued request: the policy

@@ -41,3 +41,4 @@ from . import paged_ops  # noqa: F401
 from . import sampling_ops  # noqa: F401
 from . import mla_ops  # noqa: F401
 from . import kda_ops  # noqa: F401
+from . import gqa_ops  # noqa: F401

@@ -491,14 +491,30 @@ def _expert_layer(seed=0, n=24, h=64, f=32, experts=8, k=2):
     return jnp.asarray(r.randn(n, h), jnp.float32), w, k
 
 
-def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
+# a configuration whose expert layer holds a share: its reference, the keys
+# its ``_moe`` reads, its scaling factor and how that ``_moe`` is called
+SHARED_LAYERS = {
+    "kimi-linear-48b-a3b": (
+        REF, lambda k: {"num_experts_per_token": k, "moe_renormalize": True,
+                        "routed_scaling_factor": 2.446}, 2.446,
+        lambda ref, x, w, cfg: ref._moe(x, w, "", cfg, None, None)),
+    "laguna-xs2": (
+        _load("laguna-xs2"),
+        lambda k: {"num_experts_per_tok": k,
+                   "moe_routed_scaling_factor": 2.5}, 2.5,
+        lambda ref, x, w, cfg: ref._moe(x, w, cfg, None, None)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SHARED_LAYERS))
+def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer(name):
+    ref, keys, scaling, moe = SHARED_LAYERS[name]
     x, w, k = _expert_layer()
-    cfg = {"num_experts_per_token": k, "moe_renormalize": True,
-           "routed_scaling_factor": 2.446}
-    whole, _, _ = REF._moe(x, w, "", cfg, None, None)
-    shared = REF._swiglu(x, w["shared_gate"], w["shared_up"],
+    cfg = keys(k)
+    whole, _, _ = moe(ref, x, w, cfg)
+    shared = ref._swiglu(x, w["shared_gate"], w["shared_up"],
                          w["shared_down"], None)
-    idx, weight = mla_ops.route(x, w["router"], w["router_bias"], k, 2.446,
+    idx, weight = mla_ops.route(x, w["router"], w["router_bias"], k, scaling,
                                 True)
     total = shared
     for lo in range(0, 8, 2):        # four chips, two experts each
@@ -515,7 +531,7 @@ def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer():
         if lo == 0:
             held = {n: (v[:2] if n.startswith("experts_") else v)
                     for n, v in w.items()}
-            ref_part, _, _ = REF._moe(x, held, "", cfg, None, None)
+            ref_part, _, _ = moe(ref, x, held, cfg)
             np.testing.assert_allclose(part + shared, ref_part, atol=1e-5)
     np.testing.assert_allclose(total, whole, atol=1e-5)
 

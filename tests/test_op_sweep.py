@@ -995,6 +995,12 @@ COVERED_ELSEWHERE = {
     "latent_cache_append": "test_mla_decoder",
     "slot_is_live": "test_mla_decoder",
     "token_score": "test_mla_decoder",
+    # the grouped-query decoder's ops — tests/test_gqa_decoder.py (each
+    # against the definition or benchmark/reference/laguna-xs2.py; paged
+    # pools and page tables don't fit the one-op sweep harness)
+    "rope_half": "test_gqa_decoder",
+    "gqa_prefill_attention": "test_gqa_decoder",
+    "gqa_paged_attention": "test_gqa_decoder",
     # in-program sampling head — tests/test_spec_decode.py (RNG-lane
     # determinism + filter-support oracles; the categorical draw has no
     # closed-form reference for the one-op sweep harness)

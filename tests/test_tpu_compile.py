@@ -515,3 +515,48 @@ def test_pool_kernels_work_where_the_chip_holds_the_pool(
     moved, _, _ = pool_traffic(compiled.as_text(), forms,
                                views_free=not lane_full)
     assert [op for op, _ in moved] == ["copy"] * decode_copies, moved
+
+
+# --- the grouped-query kernels at Laguna-XS.2's widths -------------------------
+@pytest.mark.parametrize("heads,window,tokens", [
+    (48, 0, 8192), (64, 512, 8192), (64, 512, 256), (48, 0, 256)])
+def test_gqa_prefill_compiles_for_v5e(heads, window, tokens, v5e):
+    """A K/V head's 6 or 8 query heads in one product a block (1,536 or
+    2,048 rows of 256 keys), the merged rows reshaped back a head for the
+    mask: within the VMEM a kernel is given unasked, one Mosaic call."""
+    from paddle_tpu.ops import gqa_kernels as gk
+
+    block, steps, seen, causal = gk.prefill_walk(tokens, window)
+    assert block == 256 and (steps <= 3 if window else seen == causal)
+
+    def f(q, k, v):
+        return gk._gqa_prefill_call(q, k, v, scale=128 ** -0.5,
+                                    window=window)
+
+    text = _compile(f, v5e, ((heads, tokens, 128), jnp.bfloat16),
+                    ((8, tokens, 128), jnp.bfloat16),
+                    ((8, tokens, 128), jnp.bfloat16))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("heads,window,rows,width,pages", [
+    (48, 0, 128, 1024, 36864), (64, 512, 128, 34, 4352),
+    (48, 0, 1, 32, 36864), (64, 512, 8, 34, 4352)])
+def test_gqa_decode_compiles_for_v5e(heads, window, rows, width, pages, v5e):
+    """A page's eight K/V-head slabs in one strided copy a pool, a chunk of
+    32 pages in two buffers, the products batched over the K/V heads; the
+    full layers' table (1,024 wide at 8.7 k of context) and the window
+    layers' (34) in scalar memory."""
+    from paddle_tpu.ops import gqa_kernels as gk
+
+    def f(q, kp, vp, bt, cl, first):
+        return gk._gqa_decode_call(q, kp, vp, bt, cl, first,
+                                   scale=128 ** -0.5, window=window,
+                                   step=gk.DECODE_PAGES_PER_STEP,
+                                   fetch=gk.DECODE_PAGES_PER_FETCH)
+
+    pool = ((8, pages, 16, 128), jnp.bfloat16)
+    text = _compile(f, v5e, ((rows, heads, 128), jnp.float32), pool, pool,
+                    ((rows, width), jnp.int32), ((rows,), jnp.int32),
+                    ((rows,), jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
