@@ -372,8 +372,8 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
     if mode == "prefill":
         s = int(np.size(feed["tokens"]))
         if gqa_kernels.prefill_engages(s, d):
-            _, _, seen, causal = gqa_kernels.prefill_walk(s)
-            _, _, seen_w, _ = gqa_kernels.prefill_walk(s, cfg.window)
+            seen, causal = gqa_kernels.prefill_walk(s)[3:]
+            seen_w, causal_w = gqa_kernels.prefill_walk(s, cfg.window)[3:]
             kvh = cfg.num_kv_heads
             n = int(np.asarray(feed["last_index"])[0]) + 1
             inside = min(n, cfg.window)       # rows whose window is not full
@@ -381,7 +381,10 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
                    "gqa_prefill_tokens": (full + win) * n,
                    "gqa_prefill_blocks_visited":
                        kvh * (full * seen + win * seen_w),
-                   "gqa_prefill_blocks_causal": kvh * (full + win) * causal,
+                   # both in each layer kind's own blocks (the keys of a
+                   # block differ by kind: ``prefill_walk``)
+                   "gqa_prefill_blocks_causal":
+                       kvh * (full * causal + win * causal_w),
                    # the unmasked (query, key) pairs of the real tokens, a
                    # head: what the attention needs whatever walks it
                    "gqa_prefill_pairs_full": full * n * (n + 1) // 2,

@@ -15,7 +15,11 @@ fallback and its test oracle.
   once for its 6 or 8 query heads.  With a window the key blocks wholly
   behind it are not visited, like those wholly in the future: the grid's
   last axis is as long as the longest walk of any query block
-  (:func:`prefill_walk`), not the prompt.
+  (:func:`prefill_walk`), not the prompt.  The running maximum is tiled
+  over the scores lane tile by lane tile and the running sum kept as
+  lane-partial sums (one reduction across lanes and one broadcast a row
+  group and visit, where there were two and two), and a layer without a
+  window scores 512 keys an update.
 * ``gqa_decode`` — one query row a sequence over the paged K/V pools
   ``(kv_heads, pages, page_size, head_dim)``.  A grid step is one (sequence,
   chunk of pages) and serves EVERY query head from one read of each page: a
@@ -48,8 +52,11 @@ from .mla_kernels import decode_work_list
 from .pallas_kernels import (DEFAULT_MASK_VALUE, LANES, _interpret,
                              _use_pallas, own_jit)
 
-#: query rows a head (and key rows) a grid step of ``gqa_prefill`` scores
+#: query rows a head a grid step of ``gqa_prefill`` scores, and the keys of
+#: a window layer's step
 PREFILL_BLOCK = 256
+#: keys a grid step scores in a layer without a window
+PREFILL_KEYS = 512
 #: pages one ``gqa_decode`` grid step scores (512 tokens at 16 a page: a
 #: window's walk is one chunk, or two where it straddles)
 DECODE_PAGES_PER_STEP = 32
@@ -143,36 +150,76 @@ def gqa_prefill_reference(q, k, v, scale, window: int = 0):
     return o.reshape(heads, s, d)
 
 
-def _first_key_block(qi, block: int, window: int):
+def _first_key_block(qi, block: int, keys: int, window: int):
     """The first key block query block ``qi`` reaches (plain operators: a
     host integer, or traced in an index map)."""
     if not window:
         return qi * 0
     lo = qi * block - window + 1
-    return (lo + abs(lo)) // 2 // block          # max(lo, 0) // block
+    return (lo + abs(lo)) // 2 // keys           # max(lo, 0) // keys
 
 
-def prefill_walk(s: int, window: int = 0, block: int | None = None):
+def _last_key_block(qi, block: int, keys: int):
+    """The key block that holds query block ``qi``'s last row: its diagonal."""
+    return ((qi + 1) * block - 1) // keys
+
+
+def prefill_walk(s: int, window: int = 0):
     """What one ``gqa_prefill`` call over a bucket of ``s`` rows walks, by
-    the sizes the kernel's wrapper uses: ``(block, steps, visited,
-    causal)``: rows a block, the length of the grid's key axis (the longest
-    walk of any query block), the (query block, key block) pairs that hold an
-    unmasked pair, and what a causal walk without a window would visit."""
-    block = block or min(PREFILL_BLOCK, s)
-    n = -(-s // block)
-    spans = [qi - int(_first_key_block(qi, block, window)) + 1
-             for qi in range(n)]
-    return block, max(spans), sum(spans), n * (n + 1) // 2
+    the sizes the kernel's wrapper uses: ``(block, keys, steps, visited,
+    causal)``: query rows and keys a block, the length of the grid's key
+    axis (the longest walk of any query block), the (query block, key
+    block) pairs that hold an unmasked pair, and what a causal walk without
+    a window would visit.
+    Without a window the keys are ``PREFILL_KEYS`` wide where the bucket
+    holds whole ones: half as many updates of the running statistics a key.
+    A window layer keeps the block: its walk is the window's edge and the
+    diagonal, and a wider block scores more keys than it spares updates."""
+    block = min(PREFILL_BLOCK, s)
+    keys = PREFILL_KEYS if not window and s % PREFILL_KEYS == 0 else block
+    spans, causal = [], 0
+    for qi in range(-(-s // block)):
+        last = _last_key_block(qi, block, keys)
+        spans.append(last - int(_first_key_block(qi, block, keys, window))
+                     + 1)
+        causal += last + 1
+    return block, keys, max(spans), sum(spans), causal
+
+
+def _lanes(x, width: int):
+    """Lane-replicated statistics ``(rows, LANES)`` at ``width`` lanes: whole
+    lane tiles side by side, never a column broadcast back over the lanes
+    (on the chip that is a cross-lane permute a row group, and those
+    units, not the vector slots, set the pace of a visit).  The slice serves
+    the tests' blocks of 8 and 16 alone: the chip's widths are whole tiles."""
+    reps = -(-width // LANES)
+    x = jnp.tile(x, (1, reps)) if reps > 1 else x
+    return x if x.shape[1] == width else x[:, :width]
+
+
+def _lane_sums(p):
+    """``p`` (rows, width) summed into ``LANES`` partial sums a row: lane
+    tile on lane tile, no reduction across lanes (a width that is no whole
+    number of tiles, the tests' blocks, is padded with zeros)."""
+    if p.shape[1] % LANES:
+        p = jnp.pad(p, ((0, 0), (0, -p.shape[1] % LANES)))
+    out = p[:, :LANES]
+    for j in range(LANES, p.shape[1], LANES):
+        out = out + p[:, j:j + LANES]
+    return out
 
 
 def _gqa_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                        scale, block, steps, window):
+                        scale, block, keys, steps, window):
     """Grid step ``(h, qi, r)``: query block ``qi`` of the ``group`` heads of
     K/V head ``h`` against key block ``first(qi) + r``, online softmax down
     ``r``.  Steps past the diagonal do nothing (their index maps stay on
-    it, so nothing is fetched for them either)."""
+    it, so nothing is fetched for them either).  The running maximum is
+    lane-replicated and the running sum 128 lane-partial sums a row, summed
+    across lanes once, at the end: a visit reduces across lanes once (the
+    maximum) and broadcasts once (it, into ``m``)."""
     qi, r = pl.program_id(1), pl.program_id(2)
-    ki = _first_key_block(qi, block, window) + r
+    ki = _first_key_block(qi, block, keys, window) + r
     group, _, d = q_ref.shape[1:]
 
     @pl.when(r == 0)
@@ -181,34 +228,35 @@ def _gqa_prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    @pl.when(ki <= qi)
+    @pl.when(ki <= _last_key_block(qi, block, keys))
     def _score():
         q = q_ref[0].reshape(group * block, d)
         v = v_ref[0]
         s = lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-        rows = qi * block + lax.broadcasted_iota(jnp.int32, (block, block), 0)
-        cols = ki * block + lax.broadcasted_iota(jnp.int32, (block, block), 1)
+        rows = qi * block + lax.broadcasted_iota(jnp.int32, (block, keys), 0)
+        cols = ki * keys + lax.broadcasted_iota(jnp.int32, (block, keys), 1)
         # a row wholly masked in this block (behind its window) scores the
-        # mask value everywhere and is wiped by its first real key: the
-        # diagonal block, visited last, holds the row's own
+        # mask value everywhere and is wiped by its first real key
+        # (``alpha`` 0): the diagonal block, visited last, holds the row's own
         s = jnp.where(_mask(rows, cols, window)[None],
-                      s.reshape(group, block, block) * scale,
-                      DEFAULT_MASK_VALUE).reshape(group * block, block)
-        m_prev, l_prev = m_scr[...], l_scr[...]
+                      s.reshape(group, block, keys) * scale,
+                      DEFAULT_MASK_VALUE).reshape(group * block, keys)
+        m_prev = m_scr[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next[:, :1])
-        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha[:, :1] + lax.dot_general(
+        p = jnp.exp(s - _lanes(m_next, keys))
+        l_scr[...] = alpha * l_scr[...] + _lane_sums(p)
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, d) + lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_next
 
     @pl.when(r == steps - 1)
     def _done():
-        o_ref[0] = (acc_scr[...] / l_scr[...][:, :1]) \
-            .reshape(group, block, d).astype(o_ref.dtype)
+        l = jnp.sum(l_scr[...], axis=1, keepdims=True)
+        o_ref[0] = (acc_scr[...] / l).reshape(group, block, d) \
+            .astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window"))
@@ -216,25 +264,24 @@ def _gqa_prefill_call(q, k, v, *, scale, window):
     heads, s, d = q.shape
     kvh = k.shape[0]
     group = heads // kvh
-    block, steps, _, _ = prefill_walk(s, window)
-    n = s // block
+    block, keys, steps = prefill_walk(s, window)[:3]
 
     def _q_idx(h, qi, r):
         return (h, 0, qi, 0)
 
     def _k_idx(h, qi, r):
-        return (h, jnp.minimum(_first_key_block(qi, block, window) + r, qi),
-                0)
+        return (h, jnp.minimum(_first_key_block(qi, block, keys, window) + r,
+                               _last_key_block(qi, block, keys)), 0)
 
     out = pl.pallas_call(
         functools.partial(_gqa_prefill_kernel, scale=scale, block=block,
-                          steps=steps, window=window),
+                          keys=keys, steps=steps, window=window),
         name="gqa_prefill",
-        grid=(kvh, n, steps),
+        grid=(kvh, s // block, steps),
         in_specs=[
             pl.BlockSpec((1, group, block, d), _q_idx),
-            pl.BlockSpec((1, block, d), _k_idx),
-            pl.BlockSpec((1, block, d), _k_idx),
+            pl.BlockSpec((1, keys, d), _k_idx),
+            pl.BlockSpec((1, keys, d), _k_idx),
         ],
         out_specs=pl.BlockSpec((1, group, block, d), _q_idx),
         out_shape=jax.ShapeDtypeStruct((kvh, group, s, d), jnp.float32),

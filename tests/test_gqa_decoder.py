@@ -121,19 +121,34 @@ def naive_attention(q, k, v, window, ctx=None):
     return np.einsum("kgqt,ktd->kgqd", p, v).reshape(heads, s, d)
 
 
+def prefill_case(group, s, seed, d=16):
+    r = np.random.RandomState(seed)
+    q = r.randn(2 * group, s, d).astype(np.float32)
+    k, v = (r.randn(2, s, d).astype(np.float32) for _ in range(2))
+    return q, k, v
+
+
+# keys a step of a layer without a window scores at the tests' block of 8:
+# the block itself, the cell's ratio (512 to 256) and a run of four blocks
 @pytest.mark.parametrize("group", [6, 8])
-@pytest.mark.parametrize("window", [0, 8, 20])
-@pytest.mark.parametrize("s", [8, 32, 64])
+@pytest.mark.parametrize("window,keys", [(0, 8), (0, 16), (0, 32), (5, 8),
+                                         (8, 8), (16, 8), (20, 8)])
+@pytest.mark.parametrize("s", [8, 32, 64, 160])
 def test_prefill_kernel_is_its_reference(interpreted, monkeypatch, group,
-                                         window, s):
+                                         window, s, keys):
     """Blocks of 8 rows: prompts of one block (shorter than the window), of
-    several, and a window that ends inside a block."""
+    several, of many interior blocks (160: 20 blocks); a window that ends
+    inside a block (20), that is shorter than one (5), that is one (8) and
+    that is two whole blocks (16: the cell's 512 over 256, an edge, an
+    interior and a diagonal block a query block, and the edge's last row
+    wholly masked in it)."""
     monkeypatch.setattr(gk, "PREFILL_BLOCK", 8)
-    r = np.random.RandomState(s + window)
-    q = r.randn(2 * group, s, 16).astype(np.float32)
-    k, v = (r.randn(2, s, 16).astype(np.float32) for _ in range(2))
+    monkeypatch.setattr(gk, "PREFILL_KEYS", keys)
+    q, k, v = prefill_case(group, s, s + window)
     want = naive_attention(q, k, v, window)
     assert gk.prefill_engages(s, 16)
+    assert gk.prefill_walk(s, window)[:2] == \
+        (8, keys if not window and s % keys == 0 else 8)
     got = gk.gqa_prefill(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                          0.25, window)
     ref = gk.gqa_prefill_reference(jnp.asarray(q), jnp.asarray(k),
@@ -142,14 +157,61 @@ def test_prefill_kernel_is_its_reference(interpreted, monkeypatch, group,
     np.testing.assert_allclose(np.asarray(ref), want, atol=2e-5)
 
 
+def test_a_row_wholly_masked_in_its_first_block_ends_right(interpreted,
+                                                           monkeypatch):
+    """Window 16 at blocks of 8: query block ``qi``'s walk starts at block
+    ``qi - 2``, in which its LAST row attends nothing (keys ``> row - 16``
+    start in the next block).  There the row scores the mask value
+    everywhere, its running sum and accumulator hold garbage, and its first
+    real key wipes both."""
+    monkeypatch.setattr(gk, "PREFILL_BLOCK", 8)
+    rows, cols = np.arange(64)[:, None], np.arange(64)[None]
+    ok = (cols <= rows) & (cols > rows - 16)
+    first = [int(gk._first_key_block(qi, 8, 8, 16)) for qi in range(8)]
+    wholly = [not ok[qi * 8 + 7, first[qi] * 8:first[qi] * 8 + 8].any()
+              for qi in range(8)]
+    assert wholly == [False, False] + [True] * 6
+    q, k, v = prefill_case(6, 64, 5)
+    # keys far larger behind the window than inside it: a row that kept
+    # anything of its masked block would show it
+    k[:, :40] *= 30.0
+    got = gk.gqa_prefill(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                         0.25, 16)
+    np.testing.assert_allclose(np.asarray(got),
+                               naive_attention(q, k, v, 16), atol=2e-5)
+
+
+def brute_walk(s, window, block, keys):
+    """The visited blocks from the mask itself: the (query block, key block)
+    tiles from each query block's first tile with an attended pair to its
+    diagonal."""
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None]
+    ok = cols <= rows
+    if window:
+        ok &= cols > rows - window
+    tiles = ok.reshape(s // block, block, s // keys, keys).swapaxes(1, 2)
+    visited = 0
+    for row in tiles:
+        live = np.flatnonzero(row.any(axis=(1, 2)))
+        visited += live[-1] - live[0] + 1
+    return visited
+
+
 @pytest.mark.parametrize("s,window,want", [
-    (8192, 0, (256, 32, 528, 528)),         # every block under the diagonal
-    (8192, 512, (256, 3, 93, 528)),         # three blocks a query block
-    (256, 512, (256, 1, 1, 1)),
-    (1024, 512, (256, 3, 9, 10)),
+    # without a window 512 keys a block: every block under the diagonal
+    (8192, 0, (256, 512, 16, 272, 272)),
+    # three blocks a query block: the edge, an interior one, the diagonal
+    (8192, 512, (256, 256, 3, 93, 528)),
+    (256, 512, (256, 256, 1, 1, 1)),
+    (1024, 512, (256, 256, 3, 9, 10)),
+    (4096, 0, (256, 512, 8, 72, 72)),
+    (256, 0, (256, 256, 1, 1, 1)),
 ])
 def test_prefill_walk_skips_what_the_window_hides(s, window, want):
-    assert gk.prefill_walk(s, window) == want
+    got = gk.prefill_walk(s, window)
+    assert got == want
+    block, keys = got[:2]
+    assert got[3] == brute_walk(s, window, block, keys)
 
 
 def paged_case(seed, group, ctxs, window, ps=4, pages=96, d=16, kvh=2):
@@ -294,8 +356,11 @@ def test_engine_through_the_kernels_matches_reference(interpreted):
     k = eng.stats["kernels"]
     assert k["prefill"]["gqa_prefill_calls"] == 5 * len(reqs)
     assert k["prefill"]["gqa_prefill_tokens"] == 5 * sum(PROMPT_LENS)
+    # every prompt's bucket is one block: a visit a K/V head, layer and
+    # prompt
     assert k["prefill"]["gqa_prefill_blocks_visited"] \
-        <= k["prefill"]["gqa_prefill_blocks_causal"]
+        == k["prefill"]["gqa_prefill_blocks_causal"] \
+        == cfg.num_kv_heads * 5 * len(reqs)
     d = k["decode"]
     assert d["gqa_decode_calls"] % 5 == 0 and d["gqa_decode_sequences"] > 0
     assert d["gqa_decode_pages_walked"] < d["gqa_decode_pages_in_context"]

@@ -519,15 +519,19 @@ def test_pool_kernels_work_where_the_chip_holds_the_pool(
 
 # --- the grouped-query kernels at Laguna-XS.2's widths -------------------------
 @pytest.mark.parametrize("heads,window,tokens", [
-    (48, 0, 8192), (64, 512, 8192), (64, 512, 256), (48, 0, 256)])
+    (48, 0, 8192), (64, 512, 8192), (64, 512, 256), (48, 0, 256),
+    (48, 0, 4096), (64, 512, 4096)])
 def test_gqa_prefill_compiles_for_v5e(heads, window, tokens, v5e):
-    """A K/V head's 6 or 8 query heads in one product a block (1,536 or
-    2,048 rows of 256 keys), the merged rows reshaped back a head for the
-    mask: within the VMEM a kernel is given unasked, one Mosaic call."""
+    """A K/V head's 6 or 8 query heads in one product a block: 1,536 rows
+    of 512 keys in a layer without a window, 2,048 of 256 in a window layer
+    (one block of 256 keys in the smallest bucket of either), the merged
+    rows reshaped back a head for the mask: within the VMEM a kernel is
+    given unasked, one Mosaic call."""
     from paddle_tpu.ops import gqa_kernels as gk
 
-    block, steps, seen, causal = gk.prefill_walk(tokens, window)
-    assert block == 256 and (steps <= 3 if window else seen == causal)
+    block, keys, steps, seen, causal = gk.prefill_walk(tokens, window)
+    assert block == 256 and keys == (256 if window or tokens < 512 else 512)
+    assert steps <= 3 if window else seen == causal
 
     def f(q, k, v):
         return gk._gqa_prefill_call(q, k, v, scale=128 ** -0.5,
