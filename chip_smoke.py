@@ -124,6 +124,23 @@ SIZES = {
             rope_window=dict(lanes=128, base=10000.0),
             num_pages=768, page_size=16, token_budget=8320, max_batch=8,
             prompts=[300, 20, 600, 31, 8000], new_tokens=8, pad_to=8192),
+        # Olmo-Hybrid's cut at published widths and one period of depth
+        # (linear, linear, linear, full): 30 heads of 96 x 192 and of 128,
+        # every feed-forward dense, the whole vocabulary (3.2 GB); one prompt
+        # past a prefill bucket of 2,048
+        "olmo": dict(
+            cfg=dict(vocab_size=100352, hidden=3840, num_layers=4,
+                     mixers=("linear", "linear", "linear", "full"),
+                     heads_full=30, heads_window=30, num_kv_heads=30,
+                     head_dim=128, window=0, gate=False, first_k_dense=4,
+                     intermediate=11008, n_routed_experts=0,
+                     n_shared_experts=0, num_experts_per_tok=0,
+                     linear_heads=30, linear_key_dim=96,
+                     linear_value_dim=192, linear_neg_eigval=True,
+                     norm_after=True, qk_norm=True, max_seq_len=2592,
+                     weights_dtype="bfloat16"),
+            num_pages=512, page_size=16, token_budget=4224, max_batch=8,
+            prompts=[300, 20, 280, 31, 2100], new_tokens=8, pad_to=4096),
     },
     "tiny": {
         "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
@@ -179,6 +196,18 @@ SIZES = {
             rope_window=dict(lanes=16, base=10000.0),
             num_pages=64, page_size=8, token_budget=256, max_batch=4,
             prompts=[40, 5, 36, 9, 100], new_tokens=6, pad_to=128),
+        "olmo": dict(
+            cfg=dict(vocab_size=256, hidden=128, num_layers=4,
+                     mixers=("linear", "linear", "linear", "full"),
+                     heads_full=8, heads_window=8, num_kv_heads=8,
+                     head_dim=16, window=0, gate=False, first_k_dense=4,
+                     intermediate=256, n_routed_experts=0,
+                     n_shared_experts=0, num_experts_per_tok=0,
+                     linear_heads=6, linear_key_dim=24, linear_value_dim=48,
+                     linear_neg_eigval=True, norm_after=True, qk_norm=True,
+                     max_seq_len=256, weights_dtype="bfloat16"),
+            num_pages=64, page_size=8, token_budget=256, max_batch=4,
+            prompts=[40, 5, 36, 9, 150], new_tokens=6, pad_to=256),
     },
 }
 
@@ -190,6 +219,9 @@ MLA_LOGIT_ABS_TOL, MLA_ROUTE_SLACK_TOL = 0.06, 0.008
 HYBRID_LOGIT_ABS_TOL, HYBRID_ROUTE_SLACK_TOL = 0.06, 0.008
 # the grouped-query decoder's: the limits of benchmark/configs/laguna-xs2.json
 GQA_LOGIT_ABS_TOL, GQA_ROUTE_SLACK_TOL = 0.06, 0.008
+# the Olmo-Hybrid-shaped decoder's (no router): the limit of
+# benchmark/configs/olmo-hybrid-7b.json
+OLMO_LOGIT_ABS_TOL = 0.12
 # gqa_decode's walk at the "full" sizes: contexts of 300, 20, 600, 31 and
 # 8,000 tokens are 561 pages of context a layer, of which a window layer
 # walks 89 (at most 33 a row): (2 x 561 + 3 x 89) / (5 x 561) = 0.495 over
@@ -1016,6 +1048,24 @@ def phase_mla(ctx):
         **ctx.memory())
 
 
+def serve_prompts(ctx, cfg, weights, size, prompts, **kw):
+    """An engine of ``size`` over bfloat16 pools, its scores kept, and
+    ``prompts`` served to ``size["new_tokens"]`` each: ``(engine,
+    requests)``."""
+    from paddle_tpu.inference.serving import Request, ServingEngine
+
+    eng = ServingEngine(
+        cfg=cfg, weights=weights, kv_dtype="bfloat16", place=ctx.place,
+        num_pages=size["num_pages"], page_size=size["page_size"],
+        max_batch=size["max_batch"], token_budget=size["token_budget"], **kw)
+    eng.core.keep_scores = True
+    reqs = [Request(i, p, size["new_tokens"]) for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.submit(r)
+    eng.run_to_completion()
+    return eng, reqs
+
+
 def phase_hybrid(ctx):
     """The hybrid decoder (KDA layers with a state slot a sequence beside an
     MLA layer's paged latent rows, expert layers holding a share of their
@@ -1029,7 +1079,6 @@ def phase_hybrid(ctx):
 
     from paddle_tpu.inference.mla_decoder import (MLADecoderConfig,
                                                   init_mla_weights)
-    from paddle_tpu.inference.serving import Request, ServingEngine
 
     jax = ctx.jax
     phase, size = "serve/hybrid", ctx.sizes["hybrid"]
@@ -1046,18 +1095,7 @@ def phase_hybrid(ctx):
                for n in size["prompts"]]
 
     def drive(**kw):
-        eng = ServingEngine(
-            cfg=cfg, weights=weights, kv_dtype="bfloat16", place=ctx.place,
-            num_pages=size["num_pages"], page_size=size["page_size"],
-            max_batch=size["max_batch"], token_budget=size["token_budget"],
-            **kw)
-        eng.core.keep_scores = True
-        reqs = [Request(i, p, size["new_tokens"])
-                for i, p in enumerate(prompts)]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_to_completion()
-        return eng, reqs
+        return serve_prompts(ctx, cfg, weights, size, prompts, **kw)
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -1132,7 +1170,6 @@ def phase_gqa(ctx):
 
     from paddle_tpu.inference.gqa_decoder import (GQADecoderConfig, Rope,
                                                   init_gqa_weights)
-    from paddle_tpu.inference.serving import Request, ServingEngine
     from paddle_tpu.ops import gqa_kernels
 
     jax = ctx.jax
@@ -1152,18 +1189,7 @@ def phase_gqa(ctx):
                for n in size["prompts"]]
 
     def drive(**kw):
-        eng = ServingEngine(
-            cfg=cfg, weights=weights, kv_dtype="bfloat16", place=ctx.place,
-            num_pages=size["num_pages"], page_size=size["page_size"],
-            max_batch=size["max_batch"], token_budget=size["token_budget"],
-            **kw)
-        eng.core.keep_scores = True
-        reqs = [Request(i, p, size["new_tokens"])
-                for i, p in enumerate(prompts)]
-        for r in reqs:
-            eng.submit(r)
-        eng.run_to_completion()
-        return eng, reqs
+        return serve_prompts(ctx, cfg, weights, size, prompts, **kw)
 
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -1245,6 +1271,94 @@ def phase_gqa(ctx):
         **ctx.memory())
 
 
+def phase_olmo(ctx):
+    """The Olmo-Hybrid-shaped decoder (Gated DeltaNet layers with a state
+    slot a sequence beside a full layer's paged K/V rows, dense throughout)
+    through ServingEngine: its four kernels in the lowered programs, no
+    operation of K/V-pool or state-pool size in the compiled ones but the
+    in-place writes, the served logits against the plain reference, and
+    pipelined steps leaving greedy tokens unchanged."""
+    import importlib.util
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu.inference.gqa_decoder import (GQADecoderConfig, Rope,
+                                                  init_gqa_weights)
+
+    jax = ctx.jax
+    phase, size = "serve/olmo", ctx.sizes["olmo"]
+    cfg = GQADecoderConfig(rope_full=Rope(lanes=0), rope_window=Rope(lanes=0),
+                           **size["cfg"])
+    spec = importlib.util.spec_from_file_location(
+        "reference_olmo", os.path.join(os.path.dirname(os.path.abspath(
+            __file__)), "benchmark", "reference", "olmo-hybrid-7b.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    weights = {n: jax.device_put(w, ctx.device)
+               for n, w in init_gqa_weights(cfg, 0).items()}
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in size["prompts"]]
+
+    def drive(**kw):
+        return serve_prompts(ctx, cfg, weights, size, prompts, **kw)
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        mark = ctx.watch.mark()
+        plain, reqs = drive()
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(
+            phase, modules, ["gdn_prefill", "gdn_decode", "gqa_prefill",
+                             "gqa_decode"]
+            + ([] if ctx.interpreted else ["kv_append"]))
+        in_place = ctx.require_pool_in_place(
+            phase, plain.core.kv_config, n_pools=len(cfg.cache_pool_names()))
+        state = ctx.require_state_in_place(
+            phase, cfg.state_pool_specs(size["max_batch"]))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    worst = 0.0
+    for r in reqs:
+        got, _ = plain.core.served_scores(r.req_id)
+        ref = reference.served_token_scores(
+            weights, cfg.source_config(), r.prompt, r.out_tokens,
+            pad_to=size["pad_to"])
+        worst = max(worst, float(np.abs(got[:, 0] - ref["logit"]).max()),
+                    float(np.abs(got[:, 1] - ref["lse"]).max()))
+    if ctx.sizes is SIZES["full"] and worst > OLMO_LOGIT_ABS_TOL:
+        raise RuntimeError(
+            f"{phase}: served logits lie {worst} from the reference (limit "
+            f"{OLMO_LOGIT_ABS_TOL})")
+    stats, slots = plain.stats, plain.kv.stats()["state_slots"]
+    counted = {k: v for part in ("prefill", "decode")
+               for k, v in stats["kernels"].get(part, {}).items()}
+    if not counted.get("gdn_prefill_tokens") \
+            or not counted.get("gdn_decode_sequences"):
+        raise RuntimeError(f"{phase}: the forms counted no gdn kernel's "
+                           f"work: {stats['kernels']}")
+    del plain
+    gc.collect()
+    piped, piped_reqs = drive(pipeline=2)
+    if [r.out_tokens for r in piped_reqs] != [r.out_tokens for r in reqs]:
+        raise RuntimeError(f"{phase}: pipelined steps changed the tokens "
+                           f"served: {[r.out_tokens for r in piped_reqs]} "
+                           f"vs {[r.out_tokens for r in reqs]}")
+    del piped
+    gc.collect()
+    say(phase=phase, **{k: v for k, v in size["cfg"].items()},
+        num_pages=size["num_pages"], prompts=size["prompts"],
+        new_tokens=size["new_tokens"], scheduler=stats, state_slots=slots,
+        state_slot_bytes=cfg.state_slot_bytes(),
+        **seen, kernel_calls=kernels, **in_place, **state,
+        served_logits_worst_gap=worst,
+        pipeline="tokens identical with pipelined steps on and off",
+        **ctx.memory())
+
+
 def phase_tp4(ctx):
     """tp=4 decode against tp=1 tokens for the same requests."""
     phase, size = "tp4/decoder", ctx.sizes["serve"]
@@ -1297,7 +1411,7 @@ def main(argv=None):
     ap.add_argument("--only", default=None,
                     help="comma-separated phases to run instead of all of "
                          "the chip count's (resnet, bert, serve, mla, hybrid, "
-                         "gqa, dp4, tp4)")
+                         "gqa, olmo, dp4, tp4)")
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="skip the TPU assertion (and the device's memory "
                          "counters): a rehearsal, never a result")
@@ -1321,7 +1435,7 @@ def main(argv=None):
             note="smoke observations, not benchmark metrics")
         phases = (phase_dp4, phase_tp4) if args.chips == 4 else \
             (phase_resnet, phase_bert, phase_serve, phase_mla, phase_hybrid,
-             phase_gqa)
+             phase_gqa, phase_olmo)
         if args.only:
             phases = [globals()["phase_" + name]
                       for name in args.only.split(",")]

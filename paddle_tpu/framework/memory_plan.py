@@ -243,13 +243,15 @@ def _t_kda_mixer(op_, block, ndev, assumed_batch):
     """The whole KDA mixer in one op: per row the convolution's inputs and
     outputs (``[q | k | v]`` twice), the decay, the recurrence's output and
     the gate, all float32: about ten arrays of a row's ``heads * head_dim``
-    values, whatever X's own width."""
+    values, whatever X's own width.  ``gdn_mixer`` the same, by its
+    ``value_dim`` (the wider of its two head sizes)."""
     x = op_.inputs.get("X", [])
     v = block._find_var_recursive(x[0]) if x else None
     shape = getattr(v, "shape", None) or ()
     rows = shape[0] if shape and isinstance(shape[0], int) and shape[0] > 0 \
         else assumed_batch
-    width = int(op_.attrs.get("heads", 1)) * int(op_.attrs.get("head_dim", 1))
+    width = int(op_.attrs.get("heads", 1)) * int(
+        op_.attrs.get("head_dim", 0) or op_.attrs.get("value_dim", 1))
     return 10 * 4 * rows * width
 
 
@@ -350,6 +352,7 @@ TRANSIENT_BYTES = {
     "gqa_prefill_attention": _t_gqa_prefill_attention,
     "moe_experts": _t_moe_experts,
     "kda_mixer": _t_kda_mixer,
+    "gdn_mixer": _t_kda_mixer,
     "sample_token": _t_sample_token,
     "while": _t_subblock,
     "while_loop": _t_subblock,

@@ -2,7 +2,10 @@
 attention output and the sparse-expert feed-forward of ``mla_decoder.py``:
 the Laguna-shaped block (``model_type: laguna``) as a model description
 ``ServingEngine`` serves through the same seam as ``DecoderConfig`` and
-``MLADecoderConfig``: parameter specs, program forms, cache pools.
+``MLADecoderConfig``: parameter specs, program forms, cache pools.  The same
+description holds the Olmo-Hybrid-shaped decoder (``model_type:
+olmo_hybrid``; below): Gated DeltaNet layers beside full multi-head ones,
+dense throughout, the norms on the outputs.
 
 Per layer ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
 feed-forward half is ``mla_decoder._MB._ffn`` as it stands (the first
@@ -35,6 +38,23 @@ Types as the other expert decoders: parameters in ``weights_dtype``, every
 matmul with operands of that type accumulated in float32; the residual
 stream, norms, softmax, router scores and the gate float32.
 
+**Linear layers** (``mixers`` naming ``"linear"``; arXiv:2412.06464,
+``ops/kda_ops.py``'s ``gdn_mixer``).  Such a layer keeps no rows a token but
+one float32 state ``(linear_heads, linear_key_dim, linear_value_dim)`` and the
+last ``taps - 1`` inputs of its short convolution a sequence, in a SLOT of two
+pools a layer (``state_pool_specs``: ``gdn_state_<i>`` as
+``kda_kernels.gdn_state_shape`` stores it, ``gdn_conv_<i>``) beside the K/V
+pools, which the attention layers alone have: the cache manager hands a
+sequence its slot with its first pages, and the serving forms take the feed
+``state_slots``.  One decay a head, ``beta`` up to 2 with
+``linear_neg_eigval``, the output gated by ``SiLU(z)`` of full rank.  With
+``norm_after`` the block's two RMSNorms sit on the outputs (``h = x +
+RMSNorm(Mix(x))``, ``y = h + RMSNorm(FFN(h))``: ``_MB.block``), with
+``qk_norm`` an RMSNorm over the whole of ``q`` and of ``k`` stands before the
+heads are split, a rotary setting of no lanes turns nothing, and
+``first_k_dense`` equal to the depth is a decoder with no expert layer: its
+forms carry no counts and no routes.
+
 **Why a description of its own** and not two more mixer kinds of
 ``MLADecoderConfig``: that description's fields are latent attention's (two
 low-rank projections, nope/rope head dims, one head count, one rotary base),
@@ -54,10 +74,10 @@ import numpy as np
 
 from ..framework.core import Program
 from ..framework.dtype import VarType, convert_dtype
-from ..ops import gqa_kernels, mla_kernels
+from ..ops import gqa_kernels, kda_kernels, mla_kernels
 from .kv_cache import KVCacheConfig
-from .mla_decoder import (_MB, _gmm_walk, close_form, embed_rows, ffn_specs,
-                          open_form)
+from .mla_decoder import (DELTA_RULE_SEEDS, _MB, _gmm_walk, close_form,
+                          delta_rule_seed, embed_rows, ffn_specs, open_form)
 
 __all__ = ["GQADecoderConfig", "Rope", "init_gqa_weights"]
 
@@ -112,7 +132,8 @@ class Rope:
         return out
 
 
-_KINDS = {"full_attention": "full", "sliding_attention": "window"}
+_KINDS = {"full_attention": "full", "sliding_attention": "window",
+          "linear_attention": "linear"}
 _KIND_NAMES = {ours: theirs for theirs, ours in _KINDS.items()}
 
 
@@ -143,6 +164,15 @@ class GQADecoderConfig:
     max_seq_len: int = 256
     eos_id: int = -1
     weights_dtype: str = "float32"
+    # -- the layers of kind "linear" (Gated DeltaNet) and the Olmo block
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv_taps: int = 4
+    linear_neg_eigval: bool = False  # beta = 2 sigmoid(b)
+    linear_l2_eps: float = 1e-6
+    norm_after: bool = False         # the block's norms on the outputs
+    qk_norm: bool = False            # RMSNorm over all of q and of k
 
     # -- the seam ServingEngine asks a model description through ---------
     @property
@@ -167,6 +197,15 @@ class GQADecoderConfig:
         return [i for i, k in enumerate(self.mixers) if k == "window"]
 
     @property
+    def linear_layers(self) -> List[int]:
+        return [i for i, k in enumerate(self.mixers) if k == "linear"]
+
+    @property
+    def attn_layers(self) -> List[int]:
+        """The layers that keep K and V rows."""
+        return [i for i, k in enumerate(self.mixers) if k != "linear"]
+
+    @property
     def experts_here(self) -> int:
         return self.experts_held or self.n_routed_experts
 
@@ -175,16 +214,34 @@ class GQADecoderConfig:
         specs = {"dec_embed": (self.vocab_size, h),
                  "dec_head": (h, self.vocab_size), "dec_norm_scale": (h,)}
         for i, kind in enumerate(self.mixers):
-            p, heads = f"dec_l{i}_", self.heads(kind)
-            specs.update({p + "attn_norm_scale": (h,),
-                          p + "wq": (h, heads * d),
-                          p + "wk": (h, self.num_kv_heads * d),
-                          p + "wv": (h, self.num_kv_heads * d),
-                          p + "wo": (heads * d, h)})
-            if self.gate:
-                specs[p + "wg"] = (h, heads)
+            p = f"dec_l{i}_"
+            specs[p + "attn_norm_scale"] = (h,)
+            if kind == "linear":
+                specs.update({p + name: shape
+                              for name, shape in self._linear_specs().items()})
+            else:
+                heads, kv = self.heads(kind), self.num_kv_heads * d
+                specs.update({p + "wq": (h, heads * d), p + "wk": (h, kv),
+                              p + "wv": (h, kv), p + "wo": (heads * d, h)})
+                if self.gate:
+                    specs[p + "wg"] = (h, heads)
+                if self.qk_norm:
+                    specs.update({p + "q_norm_scale": (heads * d,),
+                                  p + "k_norm_scale": (kv,)})
             specs.update(ffn_specs(self, i, moe=i >= self.first_k_dense))
         return specs
+
+    def _linear_specs(self) -> Dict[str, tuple]:
+        """A Gated DeltaNet mixer's weights: ``[q | k | v | z]`` in one
+        projection and ``[b | a]`` in another, the convolution's taps a
+        channel of ``[q | k | v]``, ``A_log`` and ``dt_bias`` a head, the
+        per-head output norm, and ``W_o``."""
+        h, n = self.hidden, self.linear_heads
+        dk, dv = self.linear_key_dim, self.linear_value_dim
+        return {"gdn_wqkvz": (h, 2 * n * (dk + dv)), "gdn_wba": (h, 2 * n),
+                "gdn_conv": (n * (2 * dk + dv), self.linear_conv_taps),
+                "gdn_a_log": (n,), "gdn_dt_bias": (n,),
+                "gdn_onorm_scale": (dv,), "wo": (n * dv, h)}
 
     def build_program(self, mode: str, sampling=None,
                       kv_dtype: str = "float32", tp: int = 1) -> tuple:
@@ -196,13 +253,21 @@ class GQADecoderConfig:
                  spec_k: int = 0):
         """What this model is not served with, refused at construction."""
         if len(self.mixers) != self.num_layers or \
-                set(self.mixers) - {"full", "window"}:
-            raise ValueError(f"mixers must name 'full' or 'window' for each "
-                             f"of the {self.num_layers} layers: {self.mixers}")
+                set(self.mixers) - {"full", "window", "linear"}:
+            raise ValueError(f"mixers must name 'full' or 'window' or "
+                             f"'linear' for each of the {self.num_layers} "
+                             f"layers: {self.mixers}")
         if "full" not in self.mixers:
             raise ValueError("the decoder needs a full-attention layer: the "
                              "engine sizes its page pool by it")
-        for kind in set(self.mixers):
+        if self.linear_layers and min(self.linear_heads, self.linear_key_dim,
+                                      self.linear_value_dim) < 1:
+            raise ValueError("linear layers need linear_heads, "
+                             "linear_key_dim and linear_value_dim")
+        if self.norm_after and self.first_k_dense < self.num_layers:
+            raise ValueError("norm_after is built for dense layers: "
+                             "first_k_dense must be the depth")
+        for kind in set(self.mixers) - {"linear"}:
             if self.heads(kind) % self.num_kv_heads:
                 raise ValueError(f"{self.heads(kind)} query heads of a "
                                  f"{kind} layer do not divide into "
@@ -222,9 +287,10 @@ class GQADecoderConfig:
                 "model")
         if spec_k:
             raise ValueError(
-                "a model with window layers is not served with speculative "
-                "decoding: truncate_tokens cannot bring back a page freed "
-                "behind a window")
+                "a model with window or linear layers is not served with "
+                "speculative decoding: truncate_tokens cannot bring back a "
+                "page freed behind a window, nor roll a recurrent state back "
+                "without a snapshot")
 
     def tp_rules(self, kv_dtype: str = "float32") -> Dict[str, tuple]:
         return {}
@@ -235,15 +301,16 @@ class GQADecoderConfig:
         groups of pages: ``num_pages`` for the full layers, and for the
         window layers what the engine's batch can hold
         (``window_pages_per_seq`` a sequence: ``_EngineCore`` sizes it)."""
+        attn = self.attn_layers       # the cache's layers: those with rows
         return KVCacheConfig(
             num_pages=num_pages, page_size=page_size,
             num_kv_heads=self.num_kv_heads, head_dim=self.head_dim,
-            num_layers=self.num_layers, dtype=kv_dtype,
+            num_layers=len(attn), dtype=kv_dtype,
             window=self.window if self.window_layers else 0,
-            window_layers=tuple(self.window_layers))
+            window_layers=tuple(attn.index(i) for i in self.window_layers))
 
     def cache_pool_names(self) -> List[str]:
-        return [f"kv_{kind}_{i}" for i in range(self.num_layers)
+        return [f"kv_{kind}_{i}" for i in self.attn_layers
                 for kind in ("k", "v")]
 
     def window_pool_names(self) -> List[str]:
@@ -261,6 +328,30 @@ class GQADecoderConfig:
         return 2 * self.num_kv_heads * self.head_dim \
             * np.dtype(kv_dtype).itemsize
 
+    def state_pool_specs(self, state_slots: int) -> Dict[str, tuple]:
+        """name -> (shape, dtype) of the pools that hold one SLOT a sequence
+        (and one more, the padding's): a linear layer's state, stored with
+        ``d_k`` on sublanes under the lanes of as many heads as fill whole
+        tiles (``kda_kernels.gdn_state_shape``), and its convolution's last
+        inputs, float32.  Empty without linear layers."""
+        n, dk, dv = self.linear_heads, self.linear_key_dim, \
+            self.linear_value_dim
+        specs = {}
+        for i in self.linear_layers:
+            specs[f"gdn_state_{i}"] = (
+                (state_slots + 1,) + kda_kernels.gdn_state_shape(n, dk, dv),
+                "float32")
+            specs[f"gdn_conv_{i}"] = (
+                (state_slots + 1, self.linear_conv_taps - 1,
+                 n * (2 * dk + dv)), "float32")
+        return specs
+
+    def state_slot_bytes(self) -> int:
+        """Bytes one sequence's slot takes over the linear layers, as the
+        pools store it."""
+        return sum(int(np.prod(shape[1:])) * np.dtype(dtype).itemsize
+                   for shape, dtype in self.state_pool_specs(0).values())
+
     # -- the source's names (its config.json) ------------------------------
     _SOURCE_KEYS = {
         "vocab_size": "vocab_size", "hidden": "hidden_size",
@@ -274,8 +365,34 @@ class GQADecoderConfig:
         "rms_norm_eps": "rms_norm_eps",
     }
 
+    #: Olmo-Hybrid's config.json names the linear layers' sizes so (the
+    #: keys of the open Gated DeltaNet), and says nothing of experts,
+    #: windows or gates
+    _LINEAR_KEYS = {
+        "linear_heads": "linear_num_value_heads",
+        "linear_key_dim": "linear_key_head_dim",
+        "linear_value_dim": "linear_value_head_dim",
+        "linear_conv_taps": "linear_conv_kernel_dim",
+        "linear_neg_eigval": "linear_allow_neg_eigval",
+    }
+    _DENSE_KEYS = ("vocab_size", "hidden", "num_layers", "num_kv_heads",
+                   "intermediate", "rms_norm_eps")
+
     def source_config(self) -> dict:
         """This model under the source's key names."""
+        if self.linear_layers:
+            out = {self._SOURCE_KEYS[ours]: getattr(self, ours)
+                   for ours in self._DENSE_KEYS}
+            out.update({theirs: getattr(self, ours)
+                        for ours, theirs in self._LINEAR_KEYS.items()})
+            out.update(
+                num_attention_heads=self.heads_full,
+                linear_num_key_heads=self.linear_heads,
+                layer_types=[_KIND_NAMES[k] for k in self.mixers],
+                rope_parameters={"rope_theta": None},
+                norm_after=self.norm_after, qk_norm=self.qk_norm,
+                linear_l2_eps=self.linear_l2_eps)
+            return out
         out = {theirs: getattr(self, ours)
                for ours, theirs in self._SOURCE_KEYS.items()}
         out.update(
@@ -304,6 +421,8 @@ class GQADecoderConfig:
         gives what it does not say (``max_seq_len``, ``weights_dtype``)."""
         layers = source["num_hidden_layers"]
         kinds = tuple(_KINDS[k] for k in source["layer_types"][:layers])
+        if "linear" in kinds:
+            return cls._from_linear_source(source, kinds, ours)
         per_layer = source["num_attention_heads_per_layer"][:layers]
         by_kind = {k: {h for h, kk in zip(per_layer, kinds) if kk == k}
                    for k in ("full", "window")}
@@ -336,11 +455,43 @@ class GQADecoderConfig:
         return cls(**kw)
 
 
+    @classmethod
+    def _from_linear_source(cls, source: dict, kinds, ours: dict):
+        """Olmo-Hybrid's shape of ``config.json``: one head count, no
+        ``head_dim`` (hidden over heads), every feed-forward dense, no gate,
+        ``rope_parameters`` one setting whose ``rope_theta`` may be null (no
+        rotary); ``norm_after``, ``qk_norm`` and ``linear_l2_eps`` are ours
+        (the configuration file's ``assumed``)."""
+        if source["linear_num_key_heads"] != source["linear_num_value_heads"]:
+            raise ValueError("the linear layers' key and value heads differ: "
+                             "value heads sharing a key head are not built")
+        heads, layers = source["num_attention_heads"], len(kinds)
+        d = source.get("head_dim") or source["hidden_size"] // heads
+        theta = source["rope_parameters"].get("rope_theta")
+        rope = Rope(lanes=0) if theta is None else \
+            Rope.from_source(source["rope_parameters"], d)
+        kw = {mine: source[cls._SOURCE_KEYS[mine]]
+              for mine in cls._DENSE_KEYS}
+        kw.update({mine: source[theirs]
+                   for mine, theirs in cls._LINEAR_KEYS.items()})
+        kw.update(
+            mixers=kinds, heads_full=heads, heads_window=heads, head_dim=d,
+            window=0, gate=False, rope_full=rope, rope_window=rope,
+            first_k_dense=layers, n_routed_experts=0, n_shared_experts=0,
+            num_experts_per_tok=0,
+            norm_after=bool(source.get("norm_after", False)),
+            qk_norm=bool(source.get("qk_norm", False)),
+            linear_l2_eps=float(source.get("linear_l2_eps", 1e-6)))
+        kw.update(ours)
+        return cls(**kw)
+
+
 def init_gqa_weights(cfg: GQADecoderConfig, seed: int = 0
                      ) -> Dict[str, np.ndarray]:
     """Seeded weights for tests and smokes: norm scales 1, the router's
     correction bias small, the embedding normal, every matrix normal over
-    sqrt(fan-in)."""
+    sqrt(fan-in); a linear layer's decay as ``init_mla_weights`` seeds a KDA
+    layer's."""
     rng = np.random.RandomState(seed)
     out = {}
     for name, shape in cfg.param_specs().items():
@@ -348,6 +499,8 @@ def init_gqa_weights(cfg: GQADecoderConfig, seed: int = 0
             w = np.ones(shape, np.float32)
         elif name.endswith("router_bias"):
             w = 0.01 * rng.randn(*shape)
+        elif name.endswith(DELTA_RULE_SEEDS):
+            w = delta_rule_seed(name, shape, rng)
         elif name == "dec_embed":
             w = rng.randn(*shape)
         else:
@@ -359,53 +512,73 @@ def init_gqa_weights(cfg: GQADecoderConfig, seed: int = 0
 # ==========================================================================
 # Program builder
 # ==========================================================================
+#: a Gated DeltaNet mixer's weights: the ``gdn_mixer`` op's input slot of each
+_GDN_SLOTS = {"gdn_wqkvz": "WQKVZ", "gdn_wba": "WBA", "gdn_conv": "Conv",
+              "gdn_a_log": "ALog", "gdn_dt_bias": "DtBias",
+              "gdn_onorm_scale": "ONormScale"}
+
+
 def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
                routed: bool):
     """``prog._srv_kernel_stats`` of a serving form: what its attention
     kernels walk, from the feed and the sizes the kernels' wrappers use
     (``gqa_kernels.prefill_walk`` / ``decode_walk_counts``), summed over the
-    layers; under ``from_counts`` what its grouped matmuls will have walked
-    (``mla_decoder._gmm_walk``)."""
+    layers; the linear layers' ``gdn_prefill`` / ``gdn_decode`` calls with
+    the real tokens and the chunks of a prompt's bucket, or the live
+    sequences (rows whose slot is not the padding's); under ``from_counts``
+    what its grouped matmuls will have walked (``mla_decoder._gmm_walk``)."""
     full, win = len(cfg.full_layers), len(cfg.window_layers)
+    lin = len(cfg.linear_layers) if kda_kernels.gdn_engages(
+        cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim) else 0
     d, page = cfg.head_dim, kv_config.page_size
     out = {}
     if mode == "prefill":
         s = int(np.size(feed["tokens"]))
+        n = int(np.asarray(feed["last_index"])[0]) + 1
+        if lin:
+            chunks = kda_kernels.gdn_prefill_grid(s, cfg.linear_heads)[2][1]
+            out.update(gdn_prefill_calls=lin, gdn_prefill_tokens=lin * n,
+                       gdn_prefill_chunks=lin * chunks)
         if gqa_kernels.prefill_engages(s, d):
             seen, causal = gqa_kernels.prefill_walk(s)[3:]
             seen_w, causal_w = gqa_kernels.prefill_walk(s, cfg.window)[3:]
             kvh = cfg.num_kv_heads
-            n = int(np.asarray(feed["last_index"])[0]) + 1
             inside = min(n, cfg.window)       # rows whose window is not full
-            out = {"gqa_prefill_calls": full + win,
-                   "gqa_prefill_tokens": (full + win) * n,
-                   "gqa_prefill_blocks_visited":
-                       kvh * (full * seen + win * seen_w),
-                   # both in each layer kind's own blocks (the keys of a
-                   # block differ by kind: ``prefill_walk``)
-                   "gqa_prefill_blocks_causal":
-                       kvh * (full * causal + win * causal_w),
-                   # the unmasked (query, key) pairs of the real tokens, a
-                   # head: what the attention needs whatever walks it
-                   "gqa_prefill_pairs_full": full * n * (n + 1) // 2,
-                   "gqa_prefill_pairs_window": win * (
-                       inside * (inside + 1) // 2
-                       + (n - inside) * cfg.window)}
-    elif gqa_kernels.decode_engages(page, d):
-        ctx = np.asarray(feed["context_lens"])
-        live = int((np.asarray(feed["slot_mapping"])
-                    < kv_config.pad_slot).sum())
-        _, walked, held = gqa_kernels.decode_walk_counts(
-            ctx, np.zeros_like(ctx), feed["block_tables"].shape[1], page, 0)
-        walked_w = 0
-        if win:
-            _, walked_w, _ = gqa_kernels.decode_walk_counts(
-                ctx, np.asarray(feed["window_first"]),
-                feed["window_tables"].shape[1], page, cfg.window)
-        out = {"gqa_decode_calls": full + win,
-               "gqa_decode_sequences": (full + win) * live,
-               "gqa_decode_pages_walked": full * walked + win * walked_w,
-               "gqa_decode_pages_in_context": (full + win) * held}
+            out.update(
+                gqa_prefill_calls=full + win,
+                gqa_prefill_tokens=(full + win) * n,
+                gqa_prefill_blocks_visited=kvh * (full * seen + win * seen_w),
+                # both in each layer kind's own blocks (the keys of a
+                # block differ by kind: ``prefill_walk``)
+                gqa_prefill_blocks_causal=kvh * (full * causal
+                                                 + win * causal_w),
+                # the unmasked (query, key) pairs of the real tokens, a
+                # head: what the attention needs whatever walks it
+                gqa_prefill_pairs_full=full * n * (n + 1) // 2,
+                gqa_prefill_pairs_window=win * (
+                    inside * (inside + 1) // 2 + (n - inside) * cfg.window))
+    else:
+        if lin:
+            live = int((np.asarray(feed["state_slots"])
+                        < kv_config.state_slots).sum())
+            out.update(gdn_decode_calls=lin, gdn_decode_sequences=lin * live)
+        if gqa_kernels.decode_engages(page, d):
+            ctx = np.asarray(feed["context_lens"])
+            live = int((np.asarray(feed["slot_mapping"])
+                        < kv_config.pad_slot).sum())
+            _, walked, held = gqa_kernels.decode_walk_counts(
+                ctx, np.zeros_like(ctx), feed["block_tables"].shape[1], page,
+                0)
+            walked_w = 0
+            if win:
+                _, walked_w, _ = gqa_kernels.decode_walk_counts(
+                    ctx, np.asarray(feed["window_first"]),
+                    feed["window_tables"].shape[1], page, cfg.window)
+            out.update(
+                gqa_decode_calls=full + win,
+                gqa_decode_sequences=(full + win) * live,
+                gqa_decode_pages_walked=full * walked + win * walked_w,
+                gqa_decode_pages_in_context=(full + win) * held)
     if routed and mla_kernels.gmm_engages(cfg.hidden, cfg.moe_intermediate):
         out["from_counts"] = functools.partial(
             _gmm_walk,
@@ -446,9 +619,16 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
             win_tables = b.feed("window_tables", (-1, -1), VarType.INT32)
             win_first = b.feed("window_first", (-1,), VarType.INT32)
             feeds += ["window_tables", "window_first"]
+    state_slots = None
+    if cached and cfg.linear_layers:
+        # the slot of the sequence (a prompt) or of each row (a decode
+        # batch) in the linear layers' pools; the padding's is the last
+        state_slots = b.feed("state_slots", (1,) if whole else (-1,),
+                             VarType.INT32)
+        feeds.append("state_slots")
     flat_pos, hid = embed_rows(m, f["tokens"], f["positions"])
     pools = {i: _kv_pool_params(b, i, False, kv_dtype)[:2]
-             for i in range(cfg.num_layers)} if cached else {}
+             for i in cfg.attn_layers} if cached else {}
     valid = None
     if cached:
         with m.part("embed"):
@@ -462,6 +642,8 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
         return b.reshape(x, [-1, heads, cfg.head_dim], tag)
 
     def turned(x, rope, tag):
+        if not rope.lanes:
+            return x
         o = m.tmp(tag)
         m.op("rope_half", {"X": [x], "Positions": [flat_pos]}, {"Out": [o]},
              {"inv_freq": [float(v) for v in rope.inv_freq()],
@@ -476,14 +658,48 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
              {"in_dtype": int(VarType.FP32), "out_dtype": int(kv_type)})
         return o
 
+    def linear(i, hn):
+        """Layer ``i``'s Gated DeltaNet mixer: one op, its state and its
+        convolution's tail in the layer's two slot pools where the form
+        caches (as ``_MB.kda``)."""
+        p, out = f"dec_l{i}_", m.tmp(f"l{i}_gdn")
+        ins = {"X": [hn]}
+        ins.update({slot: [p + name] for name, slot in _GDN_SLOTS.items()})
+        outs = {"Out": [out]}
+        if cached:
+            state, conv = (b.param(f"gdn_{kind}_{i}", (), dtype=VarType.FP32)
+                           for kind in ("state", "conv"))
+            ins.update({"Valid": [valid], "StateSlots": [state_slots],
+                        "State": [state], "ConvState": [conv]})
+            if whole:
+                ins["LastIndex"] = [f["last_index"]]
+            outs.update({"StateOut": [state], "ConvStateOut": [conv]})
+        m.op("gdn_mixer", ins, outs,
+             {"mode": mode, "heads": int(cfg.linear_heads),
+              "key_dim": int(cfg.linear_key_dim),
+              "value_dim": int(cfg.linear_value_dim),
+              "neg_eigval": bool(cfg.linear_neg_eigval),
+              "epsilon": float(cfg.rms_norm_eps),
+              "l2_epsilon": float(cfg.linear_l2_eps)})
+        return out
+
+    def projected(i, hn, name, heads, rope):
+        """``q`` or ``k``: ``hn W_name`` normed whole (``qk_norm``), by
+        heads, turned."""
+        x = m.mm(hn, f"dec_l{i}_w{name}", f"l{i}_{name}")
+        if cfg.qk_norm:
+            x = m.norm(x, f"dec_l{i}_{name}_norm_scale", f"l{i}_{name}n")
+        return turned(heads_of(x, heads, f"l{i}_{name}3"), rope,
+                      f"l{i}_{name}r")
+
     def mixer(i, hn):
         kind, p = cfg.mixer(i), f"dec_l{i}_"
+        if kind == "linear":
+            return linear(i, hn)
         heads, rope, window = cfg.heads(kind), cfg.rope(kind), \
             cfg.window if kind == "window" else 0
-        q = turned(heads_of(m.mm(hn, p + "wq", f"l{i}_q"), heads,
-                            f"l{i}_q3"), rope, f"l{i}_qr")
-        k = turned(heads_of(m.mm(hn, p + "wk", f"l{i}_k"), cfg.num_kv_heads,
-                            f"l{i}_k3"), rope, f"l{i}_kr")
+        q = projected(i, hn, "q", heads, rope)
+        k = projected(i, hn, "k", cfg.num_kv_heads, rope)
         v = heads_of(m.mm(hn, p + "wv", f"l{i}_v"), cfg.num_kv_heads,
                      f"l{i}_v3")
         attrs = {"scale": float(cfg.head_dim ** -0.5), "window": int(window)}

@@ -383,6 +383,24 @@ def mla_param_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
     return specs
 
 
+#: the weights of a delta-rule mixer (``kda_*``, ``gdn_*``) that are no
+#: matrix over sqrt(fan-in)
+DELTA_RULE_SEEDS = ("_a_log", "_dt_bias", "kda_conv", "gdn_conv")
+
+
+def delta_rule_seed(name: str, shape, rng) -> np.ndarray:
+    """A delta-rule mixer's decay and taps, seeded: ``A_log`` the log of a
+    rate uniform in [1, 16], ``dt_bias`` the inverse softplus of a step
+    log-uniform in [0.001, 0.1], the convolution's taps normal over
+    sqrt(taps)."""
+    if name.endswith("_a_log"):
+        return np.log(rng.uniform(1.0, 16.0, shape))
+    if name.endswith("_dt_bias"):
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), shape))
+        return dt + np.log(-np.expm1(-dt))
+    return rng.randn(*shape) / np.sqrt(shape[-1])
+
+
 def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
                      ) -> Dict[str, np.ndarray]:
     """Seeded weights for tests and smokes: norm scales 1, the router's
@@ -396,13 +414,8 @@ def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
             w = np.ones(shape, np.float32)
         elif name.endswith("router_bias"):
             w = (0.01 * rng.randn(*shape)).astype(np.float32)
-        elif name.endswith("kda_a_log"):       # decay rates 1..16 a head
-            w = np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
-        elif name.endswith("kda_dt_bias"):     # softplus^-1 of 0.001..0.1
-            dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), shape))
-            w = (dt + np.log(-np.expm1(-dt))).astype(np.float32)
-        elif name.endswith("kda_conv"):
-            w = (rng.randn(*shape) / np.sqrt(shape[-1])).astype(np.float32)
+        elif name.endswith(DELTA_RULE_SEEDS):
+            w = delta_rule_seed(name, shape, rng).astype(np.float32)
         elif name == "dec_embed":
             w = rng.randn(*shape).astype(np.float32)
         else:
@@ -416,7 +429,7 @@ def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
 # ==========================================================================
 #: a layer's mixer kind -> the part of the model its ops serve
 MIXER_PARTS = {"mla": "mla_part", "kda": "kda_part", "full": "attn_full",
-               "window": "attn_window"}
+               "window": "attn_window", "linear": "gdn_part"}
 
 
 class _MB:
@@ -441,8 +454,9 @@ class _MB:
     @contextlib.contextmanager
     def part(self, name):
         """Every op built inside serves this part of the model (``embed``,
-        ``mla_part``, ``kda_part``, ``attn_full``, ``attn_window``,
-        ``moe_part``, ``dense_ffn``, ``head``, ``mtp``): its attr ``part``, which ``registry.run_op`` makes the
+        ``mla_part``, ``kda_part``, ``gdn_part``, ``attn_full``,
+        ``attn_window``, ``moe_part``, ``dense_ffn``, ``head``, ``mtp``): its
+        attr ``part``, which ``registry.run_op`` makes the
         op's outermost scope, so the compiled program says whose time each
         of its instructions is (``profiler.device_symbols``)."""
         was, self.b.part = self.b.part, name
@@ -551,11 +565,15 @@ class _MB:
 
     def block(self, i, hid, positions, attend, valid, counts, routes=None,
               kda=None, absent=None):
-        """One pre-norm block over rows ``hid`` (n, hidden).  ``attend``
+        """One block over rows ``hid`` (n, hidden): pre-norm, ``h = x +
+        Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, or where the
+        description says ``norm_after`` the same two scales on the OUTPUTS,
+        ``h = x + RMSNorm(Mix(x))``, ``y = h + RMSNorm(FFN(h))``.  ``attend``
         maps ``(i, q_nope, q_rope, c_kv, k_r)`` to the attention's output
-        (n, heads * dv), ``kda`` maps ``(i, normed rows)`` to the mixer
-        output of a layer of any other kind (a KDA layer's; a grouped-query
-        layer's, full or windowed: ``MIXER_PARTS``), before its ``wo``;
+        (n, heads * dv), ``kda`` maps ``(i, the mixer's input rows)`` to the
+        mixer output of a layer of any other kind (a KDA layer's; a
+        grouped-query layer's, full or windowed; a Gated DeltaNet layer's:
+        ``MIXER_PARTS``), before its ``wo``;
         ``valid`` (or None) marks the rows that are real
         tokens; an expert layer appends its per-expert counts to
         ``counts`` (and, where it holds a share of its experts, the number
@@ -563,10 +581,15 @@ class _MB:
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
         mix = MIXER_PARTS[cfg.mixer(i) if i < cfg.num_layers else "mla"]
         with self.part(mix):
-            hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an")
-            att = kda(i, hn) if mix != "mla_part" \
-                else attend(i, *self.latents(i, hn, positions))
-            hid = b.add(hid, self.mm(att, p + "wo", f"l{i}_o"), f"l{i}_res1")
+            if getattr(cfg, "norm_after", False):
+                out = self.norm(self.mm(kda(i, hid), p + "wo", f"l{i}_o"),
+                                p + "attn_norm_scale", f"l{i}_an")
+            else:
+                hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an")
+                att = kda(i, hn) if mix != "mla_part" \
+                    else attend(i, *self.latents(i, hn, positions))
+                out = self.mm(att, p + "wo", f"l{i}_o")
+            hid = b.add(hid, out, f"l{i}_res1")
         dense = i < cfg.first_k_dense
         with self.part("dense_ffn" if dense else "moe_part"):
             return b.add(hid, self._ffn(i, hid, dense, valid, counts, routes,
@@ -575,8 +598,14 @@ class _MB:
     def _ffn(self, i, hid, dense, valid, counts, routes, absent):
         """Layer ``i``'s feed-forward half over ``hid``: its norm and the
         dense SwiGLU, or the router, the routed experts and the shared
-        expert."""
+        expert; with ``norm_after`` the dense SwiGLU and then the norm."""
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
+        if getattr(cfg, "norm_after", False):
+            if not dense:
+                raise ValueError("norm_after is built for dense layers")
+            return self.norm(
+                self.swiglu_ffn(hid, p + "w_gate", p + "w_up", p + "w_down",
+                                f"l{i}_ff"), p + "ffn_norm_scale", f"l{i}_fn")
         hn2 = self.norm(hid, p + "ffn_norm_scale", f"l{i}_fn")
         if dense:
             return self.swiglu_ffn(hn2, p + "w_gate", p + "w_up",

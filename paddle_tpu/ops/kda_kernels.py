@@ -1,6 +1,6 @@
-"""Pallas TPU kernels of the gated delta-rule mixer (Kimi Delta Attention,
-arXiv:2510.26692 section 3), each beside the jnp composition that is its CPU
-fallback and its test oracle.
+"""Pallas TPU kernels of the gated delta-rule mixers (Kimi Delta Attention,
+arXiv:2510.26692 section 3, and Gated DeltaNet, arXiv:2412.06464), each
+beside the jnp composition that is its CPU fallback and its test oracle.
 
 A head keeps a state ``S`` of ``(d_k, d_v)`` float32 a sequence.  A token
 with key ``k``, value ``v``, query ``q`` (``k`` and ``q`` normalised by the
@@ -43,9 +43,26 @@ caller), log-decay ``g <= 0`` per channel of ``d_k`` and write strength
   a grid step reads one sequence's states through its slot number, applies
   the token and writes them back; nothing of pool size moves beside that.
   Memory-bound: 2 x heads x d_k x d_v x 4 bytes a sequence and layer.
+* ``gdn_prefill``, ``gdn_decode`` — the same rule as Gated DeltaNet writes it
+  (arXiv:2412.06464): ONE log-decay a head, a rectangular state (``d_k`` 96
+  under ``d_v`` 192, neither a multiple of the 128 lanes) and ``beta`` up to
+  2; the oracle is ``kda_recurrence`` with the decay broadcast over the
+  channels.  With one decay a head ``exp(G_i - G_j)`` is one chunk x chunk
+  matrix, at most 1 for ``j <= i``, so the chunked form needs none of the
+  levels of split pair products above: ``A`` and ``B`` come of one product a
+  chunk and the levels that are left are the triangular inverse's merges
+  (two products each).  ``q``, ``k`` and ``v`` reach the kernel normalised
+  and laid heads-first by XLA (a block's last dimension is then the whole
+  ``d_k`` or ``d_v``: 30 heads give no group whose columns fill whole
+  tiles), six heads a grid step where they divide.  The state pool stores
+  ``gdn_pack`` heads SIDE BY SIDE along the lanes, ``(slots + 1, heads /
+  pack, d_k, pack d_v)``: two heads of 192 are three whole tiles, so nothing
+  is padded and a decode step moves the arithmetic's 2 x heads x d_k x d_v x
+  4 bytes a sequence and layer; ``gdn_decode`` takes a pool row (a pair) at a
+  time, a head's columns spread over its own lanes by a select.
 * ``short_conv`` — the causal depthwise convolution of ``taps`` inputs a
   channel (jnp: XLA fuses it), its decode step against the ``taps - 1``
-  inputs a sequence keeps.
+  inputs a sequence keeps; shared by both mixers, as ``normalised_heads``.
 
 Engage rules follow ``mla_kernels``: kernel on TPU or under
 ``PT_PALLAS_INTERPRET=1``, the jnp composition elsewhere.
@@ -121,12 +138,15 @@ def kda_recurrence(q, k, v, g, beta, state):
 # ==========================================================================
 # kda_prefill
 # ==========================================================================
-def normalised_heads(qkv, heads: int, l2_eps: float):
+def normalised_heads(qkv, heads: int, l2_eps: float, dk: int = 0):
     """``qkv`` (n, 3 heads d), the convolution's outputs after SiLU, to ``q``
     (l2-normalised a head, scaled by ``d^-1/2``), ``k`` (l2-normalised) and
-    ``v``, each ``(n, heads, d)`` float32."""
+    ``v``, each ``(n, heads, d)`` float32.  With ``dk`` the heads are
+    rectangular: ``qkv`` (n, heads (2 d_k + d_v)), ``q`` and ``k`` ``(n,
+    heads, d_k)`` and ``v`` the rest."""
+    at = [heads * dk, 2 * heads * dk] if dk else 3
     q, k, v = (t.reshape(t.shape[0], heads, -1)
-               for t in jnp.split(qkv.astype(jnp.float32), 3, axis=-1))
+               for t in jnp.split(qkv.astype(jnp.float32), at, axis=-1))
 
     def unit(x):
         return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + l2_eps)
@@ -448,6 +468,335 @@ def kda_decode(pool, slots, q, k, v, g, beta):
         return own_jit(_kda_decode_call)(pool, slots, q, k, v, g, beta)
     with jax.named_scope("kda_decode"):
         return kda_decode_reference(pool, slots.astype(jnp.int32), q, k, v,
+                                    g, beta)
+
+
+# ==========================================================================
+# gdn_prefill, gdn_decode: one decay a head, a rectangular state
+# ==========================================================================
+#: heads a ``gdn_prefill`` grid step takes: the largest that divides the
+#: layer's (30 = 2 x 3 x 5 has no 4 and no 8)
+_GDN_GROUPS = (6, 5, 4, 3, 2)
+
+
+def gdn_pack(heads: int, dv: int) -> int:
+    """Heads that lie SIDE BY SIDE along the lanes of the state pool: two
+    where one head's ``d_v`` does not fill whole 128-lane tiles and the heads
+    pair up (192 -> 384 = three tiles: nothing padded), else one."""
+    return 2 if dv % LANES and heads % 2 == 0 else 1
+
+
+def gdn_state_shape(heads: int, dk: int, dv: int) -> tuple:
+    """One sequence's states as the pool stores them: ``(heads / pack, d_k,
+    pack d_v)``, ``d_k`` on sublanes under the lanes of ``pack`` heads."""
+    pack = gdn_pack(heads, dv)
+    return (heads // pack, dk, pack * dv)
+
+
+def gdn_pack_states(state):
+    """``(..., heads, d_k, d_v)`` -> ``(..., heads / pack, d_k, pack d_v)``."""
+    *lead, heads, dk, dv = state.shape
+    pack = gdn_pack(heads, dv)
+    if pack == 1:
+        return state
+    n = len(lead)
+    return state.reshape(*lead, heads // pack, pack, dk, dv) \
+        .transpose(*range(n), n, n + 2, n + 1, n + 3) \
+        .reshape(*lead, heads // pack, dk, pack * dv)
+
+
+def gdn_unpack_states(packed, heads: int):
+    """The inverse of :func:`gdn_pack_states`."""
+    *lead, groups, dk, width = packed.shape
+    pack = heads // groups
+    if pack == 1:
+        return packed
+    n = len(lead)
+    return packed.reshape(*lead, groups, dk, pack, width // pack) \
+        .transpose(*range(n), n, n + 2, n + 1, n + 3) \
+        .reshape(*lead, heads, dk, width // pack)
+
+
+def _gdn_prefill_kernel(q_ref, k_ref, v_ref, gb_ref, gt_ref, o_ref, s_ref,
+                        st_ref, *, chunk, group):
+    """Grid step ``(hg, c)``: chunk ``c`` of the ``group`` heads ``hg * group
+    + j``.  ``q_ref`` and ``k_ref`` (group, chunk, d_k) are normalised,
+    ``v_ref`` (group, chunk, d_v); ``gb_ref`` (1, chunk, 2 group) holds a
+    column a head of the log-decay and then of the write strength, ``gt_ref``
+    (1, group, chunk) the log-decay again, a row a head.  ``st_ref`` (group,
+    d_k, d_v) carries the states across the chunks.
+
+    With ONE decay a head ``exp(G_i - G_j)`` is one chunk x chunk matrix
+    ``D``, at most 1 for ``j <= i``: ``A = (beta K K^T) . D`` below the
+    diagonal and ``B = (Q K^T) . D`` on and below it come of one product, and
+    the levels that are left are those of the triangular inverse alone
+    (``kda_prefill``'s merges: two products a level).  The heads of a group
+    are written side by side, stage by stage, as there."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    c = pl.program_id(1)
+
+    @pl.when(c == 0)
+    def _fresh():
+        st_ref[...] = jnp.zeros(st_ref.shape, f32)
+
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal, strict = col <= row, col < row
+    eye, tri = (row == col).astype(f32), causal.astype(f32)
+    gb = gb_ref[0].astype(f32)                            # (chunk, 2 group)
+    # the running sum of the log-decay, a column and a row a head
+    Gc = jnp.dot(tri, gb[:, :group], precision=_HI,
+                 preferred_element_type=f32)              # (chunk, group)
+    Gr = lax.dot_general(gt_ref[0].astype(f32), tri, _NT, precision=_HI,
+                         preferred_element_type=f32)      # (group, chunk)
+
+    def pair_mask(level):
+        half = 1 << (level - 1)
+        return ((row >> level) == (col >> level)) & ((row & half) != 0) \
+            & ((col & half) == 0)
+
+    class Head:
+        def __init__(self, j):
+            self.j = j
+            self.q, self.k = q_ref[j].astype(f32), k_ref[j].astype(f32)
+            beta = gb[:, group + j:group + j + 1]          # (chunk, 1)
+            self.G = Gc[:, j:j + 1]
+            self.kb = self.k * beta
+            self.vb = v_ref[j].astype(f32) * beta
+            D = jnp.exp(jnp.minimum(self.G - Gr[j:j + 1, :], 0.0))
+            P = lax.dot_general(
+                jnp.concatenate([self.kb, self.q], axis=0).astype(bf16),
+                self.k.astype(bf16), _NT, preferred_element_type=f32)
+            self.A = jnp.where(strict, P[:chunk] * D, 0.0)
+            self.B = jnp.where(causal, P[chunk:] * D, 0.0)
+            self.T = eye
+
+        def merge_left(self, level, pair):
+            A = jnp.where(pair, self.A, 0.0)
+            if level == 1:
+                self.T = self.T - A
+            else:
+                self.TA = jnp.dot(self.T, A, precision=_SOLVE,
+                                  preferred_element_type=f32)
+
+        def merge_right(self, level):
+            if level > 1:
+                self.T = self.T - jnp.dot(self.TA, self.T, precision=_SOLVE,
+                                          preferred_element_type=f32)
+
+        def solve(self):
+            self.st = st_ref[self.j]                       # (d_k, d_v)
+            self.st16 = self.st.astype(bf16)
+            self.decay = jnp.exp(self.G)
+            rhs = self.vb - jnp.dot((self.kb * self.decay).astype(bf16),
+                                    self.st16, preferred_element_type=f32)
+            self.U16 = jnp.dot(self.T, rhs, precision=_HI,
+                               preferred_element_type=f32).astype(bf16)
+
+        def write(self):
+            o = jnp.dot((self.q * self.decay).astype(bf16), self.st16,
+                        preferred_element_type=f32) \
+                + jnp.dot(self.B.astype(bf16), self.U16,
+                          preferred_element_type=f32)
+            o_ref[self.j] = o.astype(o_ref.dtype)
+            last = self.G[chunk - 1:chunk, :]              # (1, 1)
+            # (1, 1) to a column first: the compiler broadcasts along one
+            # of sublanes and lanes at a time
+            keep = jnp.exp(jnp.broadcast_to(last, (self.st.shape[0], 1)))
+            self.st = self.st * keep + lax.dot_general(
+                (self.k * jnp.exp(last - self.G)).astype(bf16), self.U16,
+                _TN, preferred_element_type=f32)
+            st_ref[self.j] = self.st
+
+    heads = [Head(j) for j in range(group)]
+    for level in range(1, chunk.bit_length()):
+        pair = pair_mask(level)
+        for x in heads:
+            x.merge_left(level, pair)
+        for x in heads:
+            x.merge_right(level)
+    for x in heads:
+        x.solve()
+    for x in heads:
+        x.write()
+
+    @pl.when(c == pl.num_programs(1) - 1)
+    def _out():
+        for x in heads:
+            s_ref[x.j] = x.st
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "group"))
+def _gdn_prefill_call(q, k, v, g, beta, *, chunk, group):
+    t, heads, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    n = -(-t // chunk)
+    pad = n * chunk - t
+    groups = heads // group
+
+    def head_major(x):                 # (t, heads, d) -> (heads, n chunk, d)
+        return jnp.pad(x.astype(f32).transpose(1, 0, 2),
+                       ((0, 0), (0, pad), (0, 0)))
+
+    def by_group(x):       # (t, heads) -> (groups, n chunk, group); the rows
+        # past the prompt decay nothing and write nothing
+        return jnp.pad(x.astype(f32), ((0, pad), (0, 0))) \
+            .reshape(n * chunk, groups, group).transpose(1, 0, 2)
+
+    gg = by_group(g)
+    gb = jnp.concatenate([gg, by_group(beta)], axis=-1)
+
+    def rows(d):
+        return pl.BlockSpec((group, chunk, d), lambda hg, c: (hg, c, 0))
+
+    o, state = pl.pallas_call(
+        functools.partial(_gdn_prefill_kernel, chunk=chunk, group=group),
+        name="gdn_prefill",
+        grid=(groups, n),
+        in_specs=[rows(dk), rows(dk), rows(dv),
+                  pl.BlockSpec((1, chunk, 2 * group),
+                               lambda hg, c: (hg, c, 0)),
+                  pl.BlockSpec((1, group, chunk), lambda hg, c: (hg, 0, c))],
+        out_specs=[rows(dv),
+                   pl.BlockSpec((group, dk, dv), lambda hg, c: (hg, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((heads, n * chunk, dv), f32),
+                   jax.ShapeDtypeStruct((heads, dk, dv), f32)],
+        scratch_shapes=[pltpu.VMEM((group, dk, dv), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(),
+    )(head_major(q), head_major(k), head_major(v), gb,
+      gg.transpose(0, 2, 1))
+    return o[:, :t].transpose(1, 0, 2), state
+
+
+def gdn_prefill_grid(t: int, heads: int):
+    """``(chunk, group, grid)`` of a ``gdn_prefill`` call over ``t`` tokens:
+    the tokens and the heads a grid step takes, and the steps of the call."""
+    chunk = _pick_chunk(t)
+    group = next((n for n in _GDN_GROUPS if heads % n == 0), 1)
+    return chunk, group, (heads // group, -(-t // chunk))
+
+
+def gdn_engages(heads: int, dk: int, dv: int) -> bool:
+    """Both kernels: interpreted at any size; on the chip where ``d_k`` is
+    whole sublane groups and the lanes of a pool row (``gdn_pack`` heads of
+    ``d_v``) whole tiles: 96 x 192 with an even number of heads, 128 x
+    128."""
+    return _use_pallas() and (_interpret() or (
+        dk % 8 == 0 and (gdn_pack(heads, dv) * dv) % LANES == 0))
+
+
+def gdn_prefill(q, k, v, g, beta):
+    """One whole prompt from an empty state: ``q`` and ``k`` (t, heads, d_k)
+    normalised (``normalised_heads``), ``v`` (t, heads, d_v), ``g`` (t,
+    heads) the log-decay, ONE a head, ``beta`` (t, heads), which may reach 2.
+    Returns ``(o (t, heads, d_v), state (heads, d_k, d_v))`` float32.  Rows
+    past the prompt are given ``g = 0`` and ``beta = 0`` by the caller."""
+    t, heads, dk = q.shape
+    dv = v.shape[-1]
+    if gdn_engages(heads, dk, dv):
+        chunk, group, _ = gdn_prefill_grid(t, heads)
+        return own_jit(_gdn_prefill_call)(q, k, v, g, beta, chunk=chunk,
+                                          group=group)
+    with jax.named_scope("gdn_prefill"):
+        return kda_recurrence(
+            q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta,
+            jnp.zeros((heads, dk, dv), jnp.float32))
+
+
+def gdn_decode_reference(pool, slots, q, k, v, g, beta):
+    """Gather, one step of the recurrence, scatter, over the pool's packed
+    rows (:func:`gdn_state_shape`)."""
+    heads = q.shape[1]
+    o, states = kda_decode_reference(
+        gdn_unpack_states(pool[slots], heads), jnp.arange(slots.shape[0]),
+        q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta)
+    return o, pool.at[slots].set(gdn_pack_states(states))
+
+
+def _gdn_decode_kernel(slot_ref, cols_ref, rows_ref, s_in, o_ref, s_out, *,
+                       heads, dv):
+    """Grid step ``b``: every state of sequence ``b`` (block index
+    ``slot[b]``), a pool row (``pack`` heads side by side) after the other.
+    ``cols_ref`` (d_k, 2 heads) holds a column a head of ``k`` and then of
+    ``q``: what scales the ROWS of a state; ``rows_ref`` (3, heads d_v) a lane
+    a value of ``exp(g)``, ``beta`` (each head's repeated over its ``d_v``)
+    and ``v``: what scales or meets its columns."""
+    del slot_ref
+    cols = cols_ref[0]
+    groups, _, width = s_in.shape[1:]
+    pack = heads // groups
+    second = lax.broadcasted_iota(jnp.int32, (1, width), 1) >= dv
+
+    def spread(at):
+        """The columns ``at`` of the row's heads, each over its own lanes."""
+        if pack == 1:
+            return cols[:, at:at + 1]
+        return jnp.where(second, cols[:, at + 1:at + 2], cols[:, at:at + 1])
+
+    for r in range(groups):
+        lanes = slice(r * width, (r + 1) * width)
+        kc, qc = spread(r * pack), spread(heads + r * pack)
+        s = s_in[0, r] * rows_ref[0, 0:1, lanes]           # (d_k, width)
+        u = rows_ref[0, 1:2, lanes] * (
+            rows_ref[0, 2:3, lanes] - jnp.sum(s * kc, axis=0, keepdims=True))
+        s = s + kc * u
+        o_ref[0, 0:1, lanes] = jnp.sum(s * qc, axis=0, keepdims=True)
+        s_out[0, r] = s
+
+
+@jax.jit
+def _gdn_decode_call(pool, slots, q, k, v, g, beta):
+    n, heads, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    # (n, d_k, 2 heads): a column a head of k | q
+    cols = jnp.concatenate([k.astype(f32), q.astype(f32)],
+                           axis=1).transpose(0, 2, 1)
+
+    def lanes(x):                       # (n, heads) -> (n, heads d_v)
+        return jnp.repeat(x.astype(f32), dv, axis=1)
+
+    rows = jnp.stack([lanes(jnp.exp(g.astype(f32))), lanes(beta),
+                      v.astype(f32).reshape(n, heads * dv)], axis=1)
+    state = pl.BlockSpec((1,) + pool.shape[1:],
+                         lambda i, slot: (slot[i], 0, 0, 0))
+    o, pool = pl.pallas_call(
+        functools.partial(_gdn_decode_kernel, heads=heads, dv=dv),
+        name="gdn_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n,),
+            in_specs=[pl.BlockSpec((1, dk, 2 * heads),
+                                   lambda i, slot: (i, 0, 0)),
+                      pl.BlockSpec((1, 3, heads * dv),
+                                   lambda i, slot: (i, 0, 0)),
+                      state],
+            out_specs=[pl.BlockSpec((1, 1, heads * dv),
+                                    lambda i, slot: (i, 0, 0)), state]),
+        out_shape=[jax.ShapeDtypeStruct((n, 1, heads * dv), f32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        input_output_aliases={3: 1},      # after the prefetch operand
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+    )(slots.astype(jnp.int32), cols, rows, pool)
+    return o.reshape(n, heads, dv), pool
+
+
+def gdn_decode(pool, slots, q, k, v, g, beta):
+    """One token a row against the states at ``slots`` of ``pool`` (slots +
+    1, heads / pack, d_k, pack d_v), in place; ``g`` (rows, heads) one decay
+    a head.  A padded row carries the pad slot (the pool's last), ``g = 0``
+    and ``beta = 0``.  Returns ``(o (rows, heads, d_v), pool)``."""
+    heads, dk = q.shape[1:]
+    if gdn_engages(heads, dk, v.shape[-1]):
+        return own_jit(_gdn_decode_call)(pool, slots, q, k, v, g, beta)
+    with jax.named_scope("gdn_decode"):
+        return gdn_decode_reference(pool, slots.astype(jnp.int32), q, k, v,
                                     g, beta)
 
 

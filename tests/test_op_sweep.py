@@ -1001,6 +1001,10 @@ COVERED_ELSEWHERE = {
     "rope_half": "test_gqa_decoder",
     "gqa_prefill_attention": "test_gqa_decoder",
     "gqa_paged_attention": "test_gqa_decoder",
+    # the Gated DeltaNet mixer of the same description's linear layers —
+    # tests/test_olmo_decoder.py (against benchmark/reference/
+    # olmo-hybrid-7b.py; slot pools don't fit the one-op sweep harness)
+    "gdn_mixer": "test_olmo_decoder",
     # in-program sampling head — tests/test_spec_decode.py (RNG-lane
     # determinism + filter-support oracles; the categorical draw has no
     # closed-form reference for the one-op sweep harness)

@@ -517,6 +517,64 @@ def test_pool_kernels_work_where_the_chip_holds_the_pool(
     assert [op for op, _ in moved] == ["copy"] * decode_copies, moved
 
 
+
+# --- the Gated DeltaNet kernels at Olmo-Hybrid's widths: 30 heads of 96 x 192 ----
+GDN_POOL = (129, 15, 96, 384)     # the cell's state pool a layer, float32
+
+
+@pytest.mark.parametrize("tokens", [4096, 1024, 16])
+def test_gdn_prefill_compiles_for_v5e(tokens, v5e):
+    """The chunked kernel at a state that fills no lane tile: blocks whose
+    last dimension is the whole 96 or 192, products that contract over 96,
+    a transposed-left product (the state's update), float32 products at the
+    highest precision (the solve), six heads a grid step within the VMEM a
+    kernel is given unasked, one Mosaic call a layer."""
+    from paddle_tpu.ops import kda_kernels as kk
+
+    chunk, group, grid = kk.gdn_prefill_grid(tokens, 30)
+    assert (chunk, group, grid) == (min(tokens, 128), 6,
+                                    (5, -(-tokens // 128)))
+
+    def f(q, k, v, g, beta):
+        return kk._gdn_prefill_call(q, k, v, g, beta, chunk=chunk,
+                                    group=group)
+
+    text = _compile(f, v5e, ((tokens, 30, 96), jnp.float32),
+                    ((tokens, 30, 96), jnp.float32),
+                    ((tokens, 30, 192), jnp.float32),
+                    ((tokens, 30), jnp.float32), ((tokens, 30), jnp.float32))
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("rows", [128, 1])
+def test_gdn_decode_rewrites_its_pool_in_place_on_v5e(rows, v5e):
+    """The decode step against the cell's state pool, donated: two heads
+    side by side fill three whole tiles of lanes, the chip's compiler holds
+    ``f32[129,15,96,384]`` row-major in exact tiles by its own choice, the
+    kernel's output aliases it, and the program holds no other result of the
+    pool's size (no copy, no re-layout)."""
+    from paddle_tpu.ops import kda_kernels as kk
+
+    assert (129,) + kk.gdn_state_shape(30, 96, 192) == GDN_POOL
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in (
+        (GDN_POOL, jnp.float32), ((rows,), jnp.int32),
+        ((rows, 30, 96), jnp.float32), ((rows, 30, 96), jnp.float32),
+        ((rows, 30, 192), jnp.float32), ((rows, 30), jnp.float32),
+        ((rows, 30), jnp.float32))]
+    text = jax.jit(kk._gdn_decode_call, donate_argnums=0) \
+        .lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    pool = "f32[129,15,96,384]"
+    made = [line for line in text.splitlines()
+            if f" = {pool}" in line or f" = ({pool}" in line
+            or f", {pool}" in line.partition(" = ")[2].split("(")[0]]
+    held = [line for line in made if " parameter(" in line]
+    assert held and all("{3,2,1,0:T(8,128)}" in line for line in held)
+    others = [line for line in made if " parameter(" not in line
+              and "custom-call(" not in line
+              and "get-tuple-element(" not in line and " tuple(" not in line]
+    assert not others, others
+
 # --- the grouped-query kernels at Laguna-XS.2's widths -------------------------
 @pytest.mark.parametrize("heads,window,tokens", [
     (48, 0, 8192), (64, 512, 8192), (64, 512, 256), (48, 0, 256),
