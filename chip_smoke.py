@@ -306,8 +306,9 @@ class Watch:
 def gmm_calls_and_readers(text):
     """Of one compiled program: how many ``moe_gmm`` custom calls it holds,
     and the instructions that read a call's output and are neither the
-    next call nor the combine's gather (a select over the whole output,
-    say), each as its line's first 160 characters."""
+    next call nor the combine (its kernel ``moe_combine``, or XLA's gather
+    where a decode step's handful of rows keeps it): a select over the
+    whole output, say; each as its line's first 160 characters."""
     lines = [line.strip() for line in text.splitlines()]
     calls = [m.group(1) for m in (
         re.match(r"(?:ROOT )?%(moe_gmm[\w.\-]*) = .* custom-call\(", line)
@@ -315,7 +316,7 @@ def gmm_calls_and_readers(text):
     reads = re.compile("|".join(rf"%{re.escape(c)}[,)]" for c in calls))
     strangers = [
         line[:160] for line in lines if calls and reads.search(line)
-        and not re.match(r"(?:ROOT )?%moe_gmm[\w.\-]* = ", line)
+        and not re.match(r"(?:ROOT )?%moe_(gmm|combine)[\w.\-]* = ", line)
         and not ("moe_combine" in line and "gather" in line)]
     return len(calls), strangers
 

@@ -581,7 +581,7 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
                 gqa_decode_pages_in_context=(full + win) * held)
     if routed and mla_kernels.gmm_engages(cfg.hidden, cfg.moe_intermediate):
         out["from_counts"] = functools.partial(
-            _gmm_walk,
+            _gmm_walk, hidden=cfg.hidden,
             rows=int(np.size(feed["tokens"])) * cfg.num_experts_per_tok)
     return out
 

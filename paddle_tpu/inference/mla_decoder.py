@@ -701,18 +701,29 @@ def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
     return out
 
 
-def _gmm_walk(counts, *, rows: int):
+def _gmm_walk(counts, *, rows: int, hidden: int):
     """What a call's ``moe_gmm`` kernels walked, from the tokens each expert
     received (``counts``, (expert layers, experts): the call's own
     ``_srv_counts``, on the host once ``moe_stats`` reads them) and the rows
     the call's dispatch sorts (token rows times ``k``), by the tile the
     kernel's wrapper uses (``mla_kernels.gmm_walk_counts``).  An expert
     layer's two calls walk the same list: the row tiles that hold a row an
-    expert owns and the (row tile, expert) visits, summed over them."""
+    expert owns and the (row tile, expert) visits, summed over them.  And
+    the rows around them: ``moe_rows_sorted`` the ``n * k`` choices a
+    layer's dispatch sorts, ``moe_rows_moved`` those of them that were moved
+    in and out of the matmuls: the rows the experts here own where
+    ``moe_rows_in`` / ``moe_combine`` take the call, all of them where
+    XLA's ``take`` does."""
+    counts = np.asarray(counts)
     walked = [mla_kernels.gmm_walk_counts(sizes, rows) for sizes in counts]
+    by_kernel = mla_kernels.moe_rows_engage(rows, counts.shape[1], hidden)
+    sorted_rows = rows * len(walked)
     return {"moe_gmm_calls": 2 * len(walked),
             "moe_gmm_row_tiles": 2 * sum(t for t, _ in walked),
-            "moe_gmm_visits": 2 * sum(v for _, v in walked)}
+            "moe_gmm_visits": 2 * sum(v for _, v in walked),
+            "moe_rows_sorted": sorted_rows,
+            "moe_rows_moved": int(counts.sum()) if by_kernel
+            else sorted_rows}
 
 
 def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
@@ -731,7 +742,7 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
                            heads=cfg.num_heads, layers=cfg.num_layers) or {}
     if routed and mla_kernels.gmm_engages(cfg.hidden, cfg.moe_intermediate):
         out["from_counts"] = functools.partial(
-            _gmm_walk,
+            _gmm_walk, hidden=cfg.hidden,
             rows=int(np.size(feed["tokens"])) * cfg.num_experts_per_tok)
     return out
 

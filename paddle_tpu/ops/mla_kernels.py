@@ -37,6 +37,14 @@ CPU fallback and its test oracle.
   width, the grid exactly as long as the list of visits, and an expert's
   matrices copied by the kernel into one of two VMEM slots while the
   expert before it is still being visited.
+* ``moe_rows_in`` / ``moe_combine`` — the rows into and out of the grouped
+  matmuls through the sort order, the rows the experts here own and no
+  others: a copy a row by hand, as many as a list built on the device
+  says.  The chip holds a ``(rows, h)`` array in tiles of eight rows and
+  no copy takes one row out of a tile, so what is fetched a row at a time
+  is stored with its ROWS APART, ``(rows, 1, h)``: the tokens' rows on the
+  way in, the down call's result (``moe_gmm``'s ``rows_apart``) on the way
+  out.
 
 The latent pool is stored ``(1, num_pages, page_size, width)``, a row
 ``[c_kv | k_r | zeros]`` with ``width`` the 576 values of the published
@@ -688,6 +696,8 @@ def _moe_gmm_kernel(group_ref, tile_ref, ends_ref, n_ref, *refs, gated, tm,
     if ahead:
         next_ref, slot_ref, *refs = refs
     x_ref, w_refs, o_ref = refs[0], refs[1:1 + nw], refs[1 + nw]
+    if len(o_ref.shape) == 3:       # rows apart: ``(tm, 1, n)``
+        o_ref = o_ref.at[:, 0]
     v = pl.program_id(0 if ahead else 1)
     g, t = group_ref[v], tile_ref[v]
 
@@ -773,12 +783,17 @@ def _pick_tn(n: int) -> int:
     return n
 
 
-@functools.partial(jax.jit, static_argnames=("gated", "out_dtype"))
-def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype):
+@functools.partial(jax.jit,
+                   static_argnames=("gated", "out_dtype", "rows_apart"))
+def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype,
+                  rows_apart=False):
     """The kernel's call by :func:`gmm_schedule`.  Under a ``jit`` of its
     own: the layers of a program share one trace and one lowering.  A tile
     no expert owns a row of is never visited: its rows are whatever the
-    buffer held."""
+    buffer held.  ``rows_apart``: the result is ``(rows, 1, n)``, which the
+    chip stores a row after a row (a ``(rows, n)`` array lies in tiles of
+    eight rows, and no copy can take one row out of a tile), for
+    ``moe_combine`` to fetch rows of."""
     rows, k = x.shape
     experts, _, n = weights[0].shape
     tm, tn, ahead = gmm_schedule(rows, experts, k, n, len(weights))
@@ -787,6 +802,12 @@ def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype):
         x = jnp.pad(x, ((0, padded - rows), (0, 0)))
     tiles = padded // tm
     lists = _gmm_work_list(group_sizes.astype(jnp.int32), tm, tiles)
+    def out_block(r, c):
+        return (r, 1, c) if rows_apart else (r, c)
+
+    def out_at(t, j):
+        return (t, 0, j) if rows_apart else (t, j)
+
     if ahead:
         # the grid is the list of visits and no longer (a grid may be as
         # long as a value on the device says); one step where it is empty
@@ -794,8 +815,8 @@ def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype):
         semantics = ("arbitrary",)
         x_spec = pl.BlockSpec((tm, k), lambda v, group, tile, *_:
                               (tile[v], 0))
-        o_spec = pl.BlockSpec((tm, n), lambda v, group, tile, *_:
-                              (tile[v], 0))
+        o_spec = pl.BlockSpec(out_block(tm, n), lambda v, group, tile, *_:
+                              out_at(tile[v], 0))
         w_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(weights)
         scratch = [pltpu.VMEM((2, k, n), w.dtype) for w in weights] \
             + [pltpu.SemaphoreType.DMA((len(weights), 2))]
@@ -805,8 +826,9 @@ def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype):
         semantics = ("parallel", "arbitrary")
         x_spec = pl.BlockSpec((tm, k), lambda j, v, group, tile, *_:
                               (tile[v], 0))
-        o_spec = pl.BlockSpec((tm, tn), lambda j, v, group, tile, *_:
-                              (tile[v], j))
+        o_spec = pl.BlockSpec(out_block(tm, tn),
+                              lambda j, v, group, tile, *_:
+                              out_at(tile[v], j))
         w_specs = [pl.BlockSpec((1, k, tn), lambda j, v, group, *_:
                                 (group[v], 0, j))] * len(weights)
         scratch = []
@@ -817,7 +839,7 @@ def _moe_gmm_call(x, weights, group_sizes, *, gated, out_dtype):
             num_scalar_prefetch=len(lists), grid=grid,
             in_specs=[x_spec] + w_specs, out_specs=o_spec,
             scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((padded, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct(out_block(padded, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
             vmem_limit_bytes=(96 if ahead else 64) * 1024 * 1024),
@@ -832,7 +854,7 @@ def gmm_engages(k, n) -> bool:
 
 
 def moe_gmm(x, weights, group_sizes, gated: bool = False,
-            out_dtype=jnp.float32):
+            out_dtype=jnp.float32, rows_apart: bool = False):
     """Grouped matmul over rows sorted by expert (shapes as
     :func:`moe_gmm_reference`): operands in the weights' type, accumulated
     in float32, the result in ``out_dtype``.  The kernel wants the
@@ -841,13 +863,264 @@ def moe_gmm(x, weights, group_sizes, gated: bool = False,
     expert owns a row of holds whatever the buffer held (a NaN, for all the
     caller knows), and a pass to blank it would be an XLA operation of
     output size.  A caller reads no such row unmasked
-    (``mla_ops.experts_forward`` selects in its combine); fed back as
+    (``mla_ops.experts_forward``'s combine fetches the owned rows alone, or
+    selects); fed back as
     ``x`` they reach no row an expert owns: a row's result is its own
-    row's product, and the tiles past the groups are never visited."""
+    row's product, and the tiles past the groups are never visited.
+    ``rows_apart``: the result is ``(rows, 1, n)``, for ``moe_combine``."""
     k, n = weights[0].shape[1:]
     x = x.astype(weights[0].dtype)
     if gmm_engages(k, n):
         return own_jit(_moe_gmm_call)(
             x, tuple(weights), group_sizes, gated=gated,
-            out_dtype=jnp.dtype(out_dtype))
-    return moe_gmm_reference(x, weights, group_sizes, gated, out_dtype)
+            out_dtype=jnp.dtype(out_dtype), rows_apart=rows_apart)
+    out = moe_gmm_reference(x, weights, group_sizes, gated, out_dtype)
+    return out[:, None, :] if rows_apart else out
+
+
+# ==========================================================================
+# moe_rows_in, moe_combine
+# ==========================================================================
+#: sorted rows a grid step of ``moe_rows_in`` fetches
+ROWS_IN_TILE = 256
+#: tokens a grid step of ``moe_combine`` sums
+COMBINE_TOKENS = 128
+
+
+def moe_rows_in_reference(x, order, total, k: int, dtype):
+    """Oracle and fallback: EVERY sorted row, ``x[order[p] // k]``."""
+    del total
+    return jnp.take(x, order // k, axis=0).astype(dtype)
+
+
+def _rows_in_kernel(tok_ref, x_ref, o_ref, buf, sem, *, tile):
+    """Grid step ``i`` of ``ceil(total / tile)``: sorted rows ``i * tile``
+    and on, a copy a row out of ``x`` (in HBM, rows apart) through ``tok``
+    into buffer ``i % 2``; started by step ``i - 1`` (by itself where ``i ==
+    0``).  A tile's copies signal one semaphore, so one wait the size of
+    the tile takes them all: the rows of the last tile past ``total`` are
+    fetched too (``tok`` names a real token for every sorted row), a tile's
+    worth a call at most."""
+    i = pl.program_id(0)
+
+    def start(u):
+        slot, base = u % 2, u * tile
+
+        def eight(g, carry):
+            for r in range(8):      # a rolled loop a row is slow
+                pltpu.make_async_copy(
+                    x_ref.at[tok_ref[base + g * 8 + r]],
+                    buf.at[slot, g * 8 + r], sem.at[slot]).start()
+            return carry
+
+        lax.fori_loop(0, tile // 8, eight, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        start(0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        start(i + 1)
+
+    rows = buf.at[i % 2]
+    pltpu.make_async_copy(rows, rows, sem.at[i % 2]).wait()
+    o_ref[...] = rows[:, 0, :].astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "dtype"))
+def _moe_rows_in_call(x, order, total, *, k, dtype):
+    """The kernel's call over a grid as long as the tiles that hold an
+    owned row (a value on the device).  Under a ``jit`` of its own: the
+    layers of a program share one trace."""
+    rows, h = order.shape[0], x.shape[1]
+    tile = min(ROWS_IN_TILE, -(-rows // 8) * 8)
+    padded = -(-rows // tile) * tile
+    tok = (order // k).astype(jnp.int32)
+    if padded != rows:
+        tok = jnp.pad(tok, (0, padded - rows))
+    out = pl.pallas_call(
+        functools.partial(_rows_in_kernel, tile=tile),
+        name="moe_rows_in",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(jnp.maximum(-(-total // tile), 1),),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, h), lambda i, tok: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((2, tile, 1, h), x.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((padded, h), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(tok, x[:, None, :])
+    return out[:rows]
+
+
+def moe_combine_reference(ys, order, total, weight):
+    """Oracle and fallback: ``back`` the sorted row of every choice, all of
+    them gathered, those past ``total`` (padding, another chip's experts:
+    ``moe_gmm`` wrote none of them) selected away, and a token's ``k`` rows
+    summed under their weights.  ``ys`` (rows, h) float32, ``order`` (rows,)
+    the choice of each sorted row, ``weight`` (n, k)."""
+    n, k = weight.shape
+    back = jnp.argsort(order)
+    y = jnp.take(ys, back, axis=0).reshape(n, k, -1)
+    # by ``where``, never by a zero weight: an unwritten row may hold a NaN
+    y = jnp.where((back < total).reshape(n, k, 1), y, 0.0)
+    return jnp.einsum("nkh,nk->nh", y, weight)
+
+
+def _combine_kernel(src_ref, place_ref, start_ref, ys_ref, w_ref, o_ref, buf,
+                    sem, *, k):
+    """Grid step ``i``: tokens ``i * tokens`` and on.  The owned choices of
+    a step are entries ``start[i] .. start[i + 1]`` of the lists, in the
+    tokens' order: ``src`` the sorted row of ``ys`` (in HBM, rows apart),
+    ``place`` the choice's place in the step, ``t * k + j``.  A copy each
+    into the next row of buffer ``i % 2``, started by step ``i - 1``; then,
+    entry by entry, the running sum of a token's rows under their weights,
+    opened anew where the token changes and stored at every entry (the last
+    store of a token is its sum: no load of the output, no chain through
+    memory).  A choice that is not owned has no entry: its row of ``ys`` is
+    never read.  Loops by eights, a rolled loop an entry is slow."""
+    i = pl.program_id(0)
+    h = o_ref.shape[-1]
+
+    def by_eights(lo, hi, eight, one, carry):
+        """``one(q, carry)`` for ``q`` in ``lo .. hi``; ``eight(q, carry)``
+        takes eight entries from ``q`` on."""
+        groups = (hi - lo) // 8
+        carry = lax.fori_loop(
+            0, groups, lambda g, c: eight(lo + g * 8, c), carry)
+        return lax.fori_loop(lo + groups * 8, hi, one, carry)
+
+    def start(u):
+        slot, base = u % 2, start_ref[u]
+
+        def one(q, carry):
+            pltpu.make_async_copy(ys_ref.at[src_ref[q]],
+                                  buf.at[slot, q - base],
+                                  sem.at[slot]).start()
+            return carry
+
+        def eight(q, carry):
+            for r in range(8):
+                one(q + r, carry)
+            return carry
+
+        by_eights(base, start_ref[u + 1], eight, one, 0)
+
+    @pl.when(i == 0)
+    def _first():
+        start(0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        start(i + 1)
+
+    slot, base, end = i % 2, start_ref[i], start_ref[i + 1]
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def wait(rows):
+        def go(q, carry):
+            pltpu.make_async_copy(ys_ref.at[pl.ds(0, rows)],
+                                  buf.at[slot, pl.ds(0, rows)],
+                                  sem.at[slot]).wait()
+            return carry
+        return go
+
+    # copies may land in any order: all of the step's before any is read
+    by_eights(base, end, wait(8), wait(1), 0)
+
+    def add(q, carry):
+        was, acc = carry
+        place = place_ref[q]
+        t = place // k
+        acc = jnp.where(t == was, acc, 0.0) + w_ref[place] * buf[slot,
+                                                                 q - base]
+        o_ref[t] = acc
+        return t, acc
+
+    def add_eight(q, carry):
+        for r in range(8):
+            carry = add(q + r, carry)
+        return carry
+
+    by_eights(base, end, add_eight, add,
+              (jnp.int32(-1), jnp.zeros((1, h), jnp.float32)))
+
+
+def combine_work_list(order, total, n: int, k: int):
+    """``moe_combine``'s lists, built on the device: the owned choices
+    (sorted rows ``p < total``) in the tokens' order, ``src`` the sorted row
+    of each, ``place`` its place ``t * k + j`` in its grid step's tokens,
+    and ``start`` (steps + 1,) where each step's entries begin, the last the
+    number owned.  One sort (the path before took an ``argsort`` for
+    ``back``); a choice that is not owned sorts past every step's entries,
+    also where the last step's tokens run past ``n``.  Beside them the
+    tokens a step sums and the steps."""
+    tokens = min(COMBINE_TOKENS, -(-n // 8) * 8)
+    steps = -(-n // tokens)
+    width = k * tokens
+    p = jnp.arange(n * k, dtype=jnp.int32)
+    choice, src = lax.sort(
+        (jnp.where(p < total, order.astype(jnp.int32), steps * width), p),
+        num_keys=1)
+    start = jnp.searchsorted(
+        choice, jnp.arange(steps + 1, dtype=jnp.int32) * width,
+        method="compare_all").astype(jnp.int32)
+    return (src, choice % width, start), tokens, steps
+
+
+@jax.jit
+def _moe_combine_call(ys, order, total, weight):
+    """The kernel's call over :func:`combine_work_list`.  Under a ``jit`` of
+    its own."""
+    n, k = weight.shape
+    rows, _, h = ys.shape
+    lists, tokens, tiles = combine_work_list(order, total, n, k)
+    width = k * tokens
+    weight = weight.reshape(-1)
+    if tiles * width != rows:
+        weight = jnp.pad(weight, (0, tiles * width - rows))
+    out = pl.pallas_call(
+        functools.partial(_combine_kernel, k=k),
+        name="moe_combine",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((width,), lambda i, *_: (i,),
+                                   memory_space=pltpu.SMEM)],
+            out_specs=pl.BlockSpec((tokens, 1, h), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, width, 1, h), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((tiles * tokens, 1, h), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+    )(*lists, ys, weight)
+    return out[:n, 0, :]
+
+
+def moe_rows_engage(rows, experts, h) -> bool:
+    """Whether a call of ``rows`` sorted rows ``h`` wide moves them by
+    ``moe_rows_in`` and ``moe_combine`` here."""
+    return _use_pallas() and h % LANES == 0 and rows >= 16 * experts
+
+
+def moe_rows_in(x, order, total, k: int, dtype):
+    """The rows of ``x`` (n, h) in the sorted order of the ``n * k``
+    choices: row ``p`` is ``x[order[p] // k]`` in ``dtype`` for ``p <
+    total``, the rows the experts here own; THE ROWS PAST THEM ARE
+    UNSPECIFIED (``moe_gmm`` visits no tile of them)."""
+    return own_jit(_moe_rows_in_call)(x, order, total, k=k,
+                                      dtype=jnp.dtype(dtype))
+
+
+def moe_combine(ys, order, total, weight):
+    """A token's sum of its owned choices' rows of ``ys`` (rows, 1, h)
+    float32 (``moe_gmm``'s ``rows_apart`` result) under their weights:
+    ``(n, h)`` float32.  A row past ``total`` is never read."""
+    return own_jit(_moe_combine_call)(ys, order, total, weight)

@@ -30,7 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mla_kernels import latent_append, mla_decode, mla_prefill, moe_gmm
+from .mla_kernels import (latent_append, mla_decode, mla_prefill, moe_combine,
+                          moe_combine_reference, moe_gmm, moe_rows_engage,
+                          moe_rows_in, moe_rows_in_reference)
 from .registry import op
 
 
@@ -129,6 +131,7 @@ def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
     int32)``."""
     n, k = idx.shape
     experts = w_gate.shape[0]
+    by_kernel = moe_rows_engage(n * k, experts, x.shape[1])
     with jax.named_scope("moe_dispatch"):
         flat = idx.reshape(-1)
         if share:
@@ -136,26 +139,27 @@ def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
         if valid is not None:
             flat = jnp.where(jnp.repeat(valid, k), flat, experts)
         order = jnp.argsort(flat).astype(jnp.int32)
-        counts = jnp.bincount(flat, length=experts + 1)[:experts] \
-            .astype(jnp.int32)
-        xs = jnp.take(x, order // k, axis=0)
+        # a compare and a sum: ``bincount`` is a scatter, half a
+        # millisecond of the chip's time at 65,536 choices
+        counts = jnp.sum(
+            flat[None, :] == jnp.arange(experts, dtype=flat.dtype)[:, None],
+            axis=1, dtype=jnp.int32)
+        total = jnp.sum(counts)
+        xs = (moe_rows_in if by_kernel else moe_rows_in_reference)(
+            x, order, total, k, w_gate.dtype)
     # rows past the groups (padding, another chip's experts: three rows in
-    # four of a share) are left unwritten by both calls and selected away
-    # below, in the fusion that gathers them: no pass over a whole output
+    # four of a share) are left unwritten by both calls: the combine's
+    # kernel never reads them, and XLA's gather, where a call keeps it,
+    # selects them away
     hmid = moe_gmm(xs, (w_gate, w_up), counts, gated=True,
                    out_dtype=w_gate.dtype)
     ys = moe_gmm(hmid, (w_down,), counts, gated=False,
-                 out_dtype=jnp.float32)
+                 out_dtype=jnp.float32, rows_apart=by_kernel)
     with jax.named_scope("moe_combine"):
-        back = jnp.argsort(order)
-        y = jnp.take(ys, back, axis=0).reshape(n, k, -1)
-        if share or valid is not None:
-            # by ``where``, never by a zero weight: an unwritten row may
-            # hold a NaN
-            y = jnp.where((flat < experts).reshape(n, k, 1), y, 0.0)
         w = weight if valid is None else \
             jnp.where(valid[:, None], weight, 0.0)
-        y = jnp.einsum("nkh,nk->nh", y, w)
+        y = (moe_combine if by_kernel else moe_combine_reference)(
+            ys, order, total, w)
     return y, counts
 
 

@@ -185,6 +185,8 @@ ENTRY %main.9 (x: f32[8,128], w: bf16[128,128]) -> f32[8,128] {
   %copy-done.1 = bf16[128,128]{1,0:S(1)} copy-done(%copy-start.1)
   %moe_gmm.5 = bf16[8,128]{1,0:T(8,128)(2,1)} custom-call(%x, %copy-done.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(pt_decode)/moe_part/moe_experts/jit(_moe_gmm_call)/pallas_call"}
   %fusion.8 = f32[8,128]{1,0} fusion(%moe_gmm.5), kind=kLoop, calls=%fused_computation.2
+  %moe_rows_in.6 = bf16[8,128]{1,0:T(8,128)(2,1)} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(pt_decode)/moe_part/moe_experts/moe_dispatch/jit(_moe_rows_in_call)/moe_rows_in/pallas_call"}
+  %moe_combine.7 = f32[8,1,128]{2,1,0:T(1,128)} custom-call(%moe_rows_in.6), custom_call_target="tpu_custom_call", metadata={op_name="jit(pt_decode)/moe_part/moe_experts/moe_combine/jit(_moe_combine_call)/moe_combine/pallas_call"}
   %while.2 = (s32[], f32[8,128]{1,0}) while(%fusion.8), condition=%cond.4, body=%body.3, metadata={op_name="jit(pt_decode)/mla_part/rms_norm/while"}
   %custom-call.3 = f32[4]{0} custom-call(), custom_call_target="AllocateBuffer"
   ROOT %gte.1 = f32[8,128]{1,0} get-tuple-element(%while.2), index=1
@@ -201,7 +203,8 @@ def test_a_compiled_modules_text_as_a_table(monkeypatch):
     rows = {r["name"]: r for r in table["instructions"]}
     # the entry, the loop's body and condition; not a fusion's inside
     assert set(rows) == {"x", "w", "copy-start.1", "copy-done.1",
-                         "moe_gmm.5", "fusion.8", "while.2", "custom-call.3",
+                         "moe_gmm.5", "fusion.8", "moe_rows_in.6",
+                         "moe_combine.7", "while.2", "custom-call.3",
                          "gte.1", "p", "fusion.7", "tuple.1", "p.1", "lt.1"}
     kernel = rows["moe_gmm.5"]
     assert (kernel["opcode"], kernel["shape"]) == ("custom-call",
@@ -209,6 +212,16 @@ def test_a_compiled_modules_text_as_a_table(monkeypatch):
     assert kernel["scopes"] == ["moe_part", "moe_experts", "moe_gmm"]
     assert (kernel["part"], kernel["op"], kernel["via"]) == \
         ("moe_part", "moe_experts", None)
+    # the kernels around the grouped matmuls, under the scopes of the XLA
+    # they took the place of: the same part and op, so "the part less its
+    # ``moe_gmm`` kernels" goes on holding them
+    assert rows["moe_rows_in.6"]["scopes"] == [
+        "moe_part", "moe_experts", "moe_dispatch", "moe_rows_in"]
+    assert rows["moe_combine.7"]["scopes"] == [
+        "moe_part", "moe_experts", "moe_combine", "moe_combine"]
+    for name in ("moe_rows_in.6", "moe_combine.7"):
+        assert (rows[name]["part"], rows[name]["op"]) == \
+            ("moe_part", "moe_experts")
     inner = rows["fusion.7"]
     assert (inner["shape"], inner["part"], inner["op"]) == \
         ("f32[8,128]", "mla_part", "rms_norm")

@@ -536,6 +536,36 @@ def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer(name):
     np.testing.assert_allclose(total, whole, atol=1e-5)
 
 
+@pytest.mark.parametrize("name", sorted(SHARED_LAYERS))
+def test_four_shares_by_the_kernels_are_the_uncut_layer(interpreted, name):
+    """The same sum with lanes of 128 and a prompt's worth of rows, so that
+    every share's rows go in by ``moe_rows_in`` and out by ``moe_combine``:
+    three choices in four are another share's on each, and a padded tail
+    routes nowhere on any."""
+    from paddle_tpu.ops import mla_kernels
+
+    ref, keys, scaling, moe = SHARED_LAYERS[name]
+    x, w, k = _expert_layer(2, n=80, h=128, f=128)
+    whole, _, _ = moe(ref, x, w, keys(k))
+    shared = ref._swiglu(x, w["shared_gate"], w["shared_up"],
+                         w["shared_down"], None)
+    idx, weight = mla_ops.route(x, w["router"], w["router_bias"], k, scaling,
+                                True)
+    assert mla_kernels.moe_rows_engage(80 * k, 2, 128)
+    valid = jnp.arange(80) < 67
+    total = shared
+    for lo in range(0, 8, 2):
+        part, counts = mla_ops.experts_forward(
+            x, (idx - lo) % 8, weight, w["experts_gate"][lo:lo + 2],
+            w["experts_up"][lo:lo + 2], w["experts_down"][lo:lo + 2],
+            valid, share=True)
+        assert int(counts.sum()) == int(
+            ((idx[:67] >= lo) & (idx[:67] < lo + 2)).sum())
+        np.testing.assert_array_equal(np.asarray(part[67:]), 0.0)
+        total = total + part
+    np.testing.assert_allclose(total[:67], np.asarray(whole)[:67], atol=2e-5)
+
+
 def test_every_expert_held_is_the_uncut_path_bit_for_bit():
     x, w, k = _expert_layer(1)
     idx, weight = mla_ops.route(x, w["router"], w["router_bias"], k, 2.5,
@@ -563,6 +593,37 @@ def test_engine_counts_the_held_experts_and_the_rows_with_none():
     rows = 29 * layers
     assert 0.05 * rows < moe["prefill"]["rows_all_absent"] < 0.45 * rows
     assert "rows_all_absent" in moe["decode"]
+
+
+def test_engine_counts_the_rows_its_share_moved(interpreted):
+    """``moe_rows_sorted`` / ``moe_rows_moved`` by phase against a brute
+    count on a share (4 of 8 experts held, top-2, lanes of 128 so that the
+    kernels take the prompts' calls): a prefill sorts its bucket's rows
+    times ``k`` a layer and moves the choices of its real rows that name an
+    expert held here, counted from the routes the prefill itself reports; a
+    decode step's handful of rows goes by XLA's ``take``, all of them."""
+    eng, cfg, _ = make_engine(
+        dataclasses.replace(TINY, hidden=128, moe_intermediate=128))
+    lens = (70, 100, 40)
+    for i, p in enumerate(prompts_of(4, lens=lens)):
+        eng.submit(Request(i, p, 4))
+    eng.run_to_completion()
+    eng.core.moe_stats
+    layers = cfg.num_layers - cfg.first_k_dense
+    k = cfg.num_experts_per_tok
+    pre, dec = (eng.stats["kernels"][ph] for ph in ("prefill", "decode"))
+    assert pre["moe_rows_sorted"] == (128 + 128 + 64) * k * layers
+    moved = 0
+    for i, n in enumerate(lens):
+        routes = eng.core.prompt_routes(i)          # (layers, rows, k)
+        assert routes.shape[0] == layers
+        moved += int((routes[:, :n] < cfg.experts_held).sum())
+    assert pre["moe_rows_moved"] == moved
+    # top-2 of 8 with 4 held: about half the real rows' choices, and none of
+    # the buckets' padding
+    assert 0.3 * sum(lens) * k * layers < moved < 0.7 * sum(lens) * k * layers
+    assert dec["moe_rows_moved"] == dec["moe_rows_sorted"] > 0
+    assert dec["moe_rows_sorted"] % (k * layers) == 0
 
 
 # -- what the engine refuses for this model ------------------------------------

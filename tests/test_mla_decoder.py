@@ -641,6 +641,15 @@ def _experts_reference(x, idx, weight, w_gate, w_up, w_down, valid):
     return y
 
 
+def _gmm_with_nan_past_the_groups(x, ws, sizes, **kw):
+    """``moe_gmm`` with every row it owes nobody filled with NaN, the worst
+    a buffer can hold (rows flat or apart)."""
+    out = mla_kernels.moe_gmm(x, ws, sizes, **kw)
+    keep = jnp.arange(out.shape[0]) < jnp.sum(sizes)
+    return jnp.where(keep.reshape((-1,) + (1,) * (out.ndim - 1)), out,
+                     jnp.nan)
+
+
 @pytest.mark.parametrize("share", [False, True])
 @pytest.mark.parametrize("padded", [False, True])
 def test_experts_forward_masks_what_moe_gmm_leaves_unwritten(
@@ -651,12 +660,7 @@ def test_experts_forward_masks_what_moe_gmm_leaves_unwritten(
     groups, those of the last visited tile too) and the combine selects
     them away: no NaN reaches a token's sum, nor the down call's owned
     rows."""
-    def unwritten(x, ws, sizes, **kw):
-        out = mla_kernels.moe_gmm(x, ws, sizes, **kw)
-        keep = jnp.arange(out.shape[0]) < jnp.sum(sizes)
-        return jnp.where(keep[:, None], out, jnp.nan)
-
-    monkeypatch.setattr(mla_ops, "moe_gmm", unwritten)
+    monkeypatch.setattr(mla_ops, "moe_gmm", _gmm_with_nan_past_the_groups)
     rng = np.random.RandomState(3 + share)
     n, k, held, routed, h, f = 96, 4, 8, 32 if share else 8, 128, 128
     x = jnp.asarray(rng.randn(n, h), jnp.float32)
@@ -675,6 +679,158 @@ def test_experts_forward_masks_what_moe_gmm_leaves_unwritten(
         np.asarray(counts), np.bincount(live[live < held], minlength=held))
     want = _experts_reference(x, idx, weight, wg, wu, wd, valid)
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-4)
+
+
+def _take_and_einsum_path(x, idx, weight, w_gate, w_up, w_down, valid, share):
+    """The dispatch and combine as they were before ``moe_rows_in`` and
+    ``moe_combine``: XLA's ``take`` of all ``n * k`` rows in, the grouped
+    matmuls' oracle, ``take`` of all rows back, the select and the einsum."""
+    n, k = idx.shape
+    experts = w_gate.shape[0]
+    flat = idx.reshape(-1)
+    if share:
+        flat = jnp.where(flat < experts, flat, experts)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, k), flat, experts)
+    order = jnp.argsort(flat)
+    counts = jnp.bincount(flat, length=experts + 1)[:experts]
+    xs = jnp.take(x, order // k, axis=0)
+    hmid = mla_kernels.moe_gmm_reference(xs, (w_gate, w_up), counts, True,
+                                         w_gate.dtype)
+    ys = mla_kernels.moe_gmm_reference(hmid, (w_down,), counts, False)
+    y = jnp.take(ys, jnp.argsort(order), axis=0).reshape(n, k, -1)
+    y = jnp.where((flat < experts).reshape(n, k, 1), y, 0.0)
+    w = weight if valid is None else jnp.where(valid[:, None], weight, 0.0)
+    return jnp.einsum("nkh,nk->nh", y, w), counts
+
+
+# name -> (tokens, k, experts held, experts routed over, real tokens or None,
+#          experts that receive nothing, by the kernels)
+ROW_CASES = {
+    "every-expert-held": (96, 4, 8, 8, None, (), True),
+    "a-share-most-rows-absent": (96, 4, 8, 32, None, (), True),
+    "padded-rows": (96, 4, 8, 8, 61, (), True),
+    "a-share-with-padded-rows": (96, 4, 8, 32, 61, (), True),
+    "an-expert-with-no-token": (96, 4, 8, 8, None, (0, 5), True),
+    "no-row-owned-at-all": (64, 2, 8, 32, None, tuple(range(8)), True),
+    "rows-no-multiple-of-a-tile": (203, 2, 8, 16, 150, (3,), True),
+    "more-tokens-than-a-step-sums": (300, 2, 8, 16, None, (), True),
+    "a-decode-step": (12, 4, 8, 32, None, (), False),
+}
+
+
+def _row_case(name):
+    n, k, held, routed, real, empty, _ = ROW_CASES[name]
+    rng = np.random.RandomState(len(name))
+    h = f = 128
+    take = [e for e in range(routed) if e not in empty]
+    idx = np.stack([rng.choice(take, k, replace=False) for _ in range(n)])
+    # a token none of whose experts are held, where the layer is a share
+    if routed > held:
+        idx[5] = np.arange(held, held + k)
+    x = jnp.asarray(rng.randn(n, h), jnp.float32)
+    weight = jnp.asarray(rng.rand(n, k), jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(held, h, f) / 12, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(held, f, h) / 12, jnp.float32)
+    valid = None if real is None else jnp.arange(n) < real
+    return (x, jnp.asarray(idx, jnp.int32), weight, wg, wu, wd, valid,
+            routed > held)
+
+
+@pytest.mark.parametrize("name", sorted(ROW_CASES))
+def test_experts_forward_moves_the_owned_rows_alone(interpreted, monkeypatch,
+                                                    name):
+    """``moe_rows_in`` and ``moe_combine`` in ``experts_forward`` against
+    the path they replace (XLA's ``take`` both ways, the select and the
+    einsum over ``moe_gmm_reference``) and against every token times every
+    expert in float64, with every row ``moe_gmm`` owes nobody filled with
+    NaN: the kernels read none of them.  A decode step's handful of rows
+    keeps the ``take``."""
+    monkeypatch.setattr(mla_ops, "moe_gmm", _gmm_with_nan_past_the_groups)
+    args = _row_case(name)
+    n, k = args[1].shape
+    assert mla_kernels.moe_rows_engage(n * k, 8, 128) == ROW_CASES[name][-1]
+    y, counts = mla_ops.experts_forward(*args)
+    was, counts_were = _take_and_einsum_path(*args)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_were))
+    for e in ROW_CASES[name][5]:
+        assert int(counts[e]) == 0
+    y = np.asarray(y)
+    assert y.shape == (n, 128) and np.isfinite(y).all()
+    # the same k products a token in float32; only the order of their sum
+    np.testing.assert_allclose(y, np.asarray(was), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        y, _experts_reference(*(np.asarray(a) if a is not None else None
+                                for a in args[:7])), atol=2e-4)
+    if args[7]:
+        np.testing.assert_array_equal(y[5], 0.0)     # none of its experts here
+
+
+@pytest.mark.parametrize("name", sorted(n for n, c in ROW_CASES.items()
+                                        if c[-1]))
+def test_rows_in_and_combine_against_their_oracles(interpreted, name):
+    """Each kernel alone against its jnp oracle on the rows that are owed:
+    ``moe_rows_in`` row for row the bits of ``x`` in the weights' type, and
+    nothing specified past ``total``; ``moe_combine`` over a ``ys`` that is
+    NaN past ``total`` (and, a row apart, the last owned row exactly)."""
+    x, idx, weight, wg, _, _, valid, share = _row_case(name)
+    n, k = idx.shape
+    flat = idx.reshape(-1)
+    flat = jnp.where(flat < 8, flat, 8)
+    if valid is not None:
+        flat = jnp.where(jnp.repeat(valid, k), flat, 8)
+    order = jnp.argsort(flat).astype(jnp.int32)
+    total = jnp.sum(flat < 8)
+    owned = int(total)
+    xs = mla_kernels.moe_rows_in(x, order, total, k, jnp.bfloat16)
+    assert xs.shape == (n * k, 128) and xs.dtype == jnp.bfloat16
+    want = mla_kernels.moe_rows_in_reference(x, order, total, k, jnp.bfloat16)
+    np.testing.assert_array_equal(np.asarray(xs[:owned], np.float32),
+                                  np.asarray(want[:owned], np.float32))
+    rng = np.random.RandomState(owned)
+    ys = rng.randn(n * k, 128).astype(np.float32)
+    ys[owned:] = np.nan
+    y = mla_kernels.moe_combine(jnp.asarray(ys)[:, None, :], order, total,
+                                weight)
+    want = mla_kernels.moe_combine_reference(jnp.asarray(ys), order, total,
+                                             weight)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("name", sorted(n for n, c in ROW_CASES.items()
+                                        if c[-1]))
+def test_combine_work_list_holds_the_owned_choices_alone(name):
+    """What ``moe_combine`` is told to copy, against a brute list: every
+    owned choice once, in the tokens' order, under the grid step of its
+    token and at its place there; no entry for a choice that is not owned
+    (a row ``moe_gmm`` never wrote), so no step has more entries than its
+    buffer has rows -- also where the tokens are no multiple of a step's."""
+    _, idx, _, _, _, _, valid, _ = _row_case(name)
+    n, k = idx.shape
+    flat = np.where(np.asarray(idx).reshape(-1) < 8,
+                    np.asarray(idx).reshape(-1), 8)
+    if valid is not None:
+        flat = np.where(np.repeat(np.asarray(valid), k), flat, 8)
+    order = np.argsort(flat, kind="stable").astype(np.int32)
+    owned = int((flat < 8).sum())
+    (src, place, start), tokens, steps = mla_kernels.combine_work_list(
+        jnp.asarray(order), jnp.int32(owned), n, k)
+    src, place, start = (np.asarray(a) for a in (src, place, start))
+    width = k * tokens
+    assert steps * tokens >= n > (steps - 1) * tokens
+    assert start.shape == (steps + 1,) and start[0] == 0
+    assert start[-1] == owned
+    assert (np.diff(start) <= width).all()
+    step_of = np.repeat(np.arange(steps), np.diff(start))
+    choice = step_of * width + place[:owned]
+    # the owned choices, each once, in the tokens' order
+    np.testing.assert_array_equal(choice, np.sort(order[:owned]))
+    # beside each the sorted row that holds its product
+    assert (src[:owned] < owned).all()
+    np.testing.assert_array_equal(order[src[:owned]], choice)
 
 
 # sha256 of the float32 bytes of the rows the experts own, as the kernel of

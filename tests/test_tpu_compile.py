@@ -263,6 +263,45 @@ def test_moe_gmm_compiles_for_v5e(name, rows, experts, hidden, f, want, v5e):
         assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+# --- the kernels that move the owned rows in and out of moe_gmm ---------------
+@pytest.mark.parametrize("name,tokens,experts,hidden,f", [
+    ("kimi-prefill-8192", 8192, 64, 2304, 1024),    # 65,536 rows, 16 k owned
+    ("kimi-prefill-512", 512, 64, 2304, 1024),
+    ("laguna-prefill-4096", 4096, 64, 2048, 512),
+    ("joyai-prefill-4096", 4096, 256, 2048, 768),   # every row owned
+    ("joyai-prefill-1024", 1024, 256, 2048, 768),
+])
+def test_moe_rows_kernels_compile_for_v5e(name, tokens, experts, hidden, f,
+                                          v5e):
+    """``moe_rows_in``, the down call with its rows apart and
+    ``moe_combine`` at the three expert cells' shapes (8 choices a token):
+    the chip's compiler takes a copy of ONE row out of ``(rows, 1, h)``
+    (it refuses one out of ``(rows, h)``, which lies in tiles of eight),
+    lists of ``rows`` entries in SMEM (256 KB at Kimi's largest bucket) and
+    two buffers of a step's rows in VMEM; one Mosaic call each, and every
+    one of these shapes is a call the kernels take."""
+    from paddle_tpu.ops import mla_kernels as mk
+
+    k = 8
+    rows = tokens * k
+    assert rows >= 16 * experts                 # what ``moe_rows_engage`` asks
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    for call, shapes in (
+        (lambda x, order, total: mk._moe_rows_in_call(
+            x, order, total, k=k, dtype=jnp.dtype(bf16)),
+         [((tokens, hidden), f32), ((rows,), i32), ((), i32)]),
+        (lambda x, sizes, w: mk._moe_gmm_call(
+            x, (w,), sizes, gated=False, out_dtype=jnp.dtype(f32),
+            rows_apart=True),
+         [((rows, f), bf16), ((experts,), i32), ((experts, f, hidden), bf16)]),
+        (mk._moe_combine_call,
+         [((rows, 1, hidden), f32), ((rows,), i32), ((), i32),
+          ((tokens, k), f32)]),
+    ):
+        text = _compile(call, v5e, *shapes)
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 # sha256 of the Mosaic module (locations off) that commit 0167326, before
 # PR 38 touched the kernel, lowered at JoyAI's decode shapes: gated, down
 DECODE_GMM_MODULES = {
@@ -370,13 +409,22 @@ def test_joyai_form_compiled_for_v5e_names_every_operation(mode, v5e,
         'custom_call_target="tpu_custom_call"')
     attention = "mla_prefill" if mode == "prefill" else "mla_decode"
     # two layers' cache append and attention, one expert layer's two matmuls
+    # and, where the rows are a prompt's (8,192 of them; a decode step's
+    # 1,024 keep XLA's take), the two kernels that move the owned rows in
+    # and out of them
+    rows_by = ["moe_rows_in", "moe_combine"] if mode == "prefill" else []
     assert sorted(k["scopes"][-1] for k in kernels) == sorted(
-        ["latent_append", attention] * 2 + ["moe_gmm"] * 2)
+        ["latent_append", attention] * 2 + ["moe_gmm"] * 2 + rows_by)
     assert {k["scopes"][-1]: (k["part"], k["op"]) for k in kernels} == {
         "latent_append": ("mla_part", "latent_cache_append"),
         attention: ("mla_part", "mla_prefill_attention" if mode == "prefill"
                     else "mla_paged_attention"),
-        "moe_gmm": ("moe_part", "moe_experts")}
+        "moe_gmm": ("moe_part", "moe_experts"),
+        **{name: ("moe_part", "moe_experts") for name in rows_by}}
+    assert [k["scopes"][-2:] for k in kernels
+            if k["scopes"][-1] in rows_by] == [
+        ["moe_dispatch", "moe_rows_in"], ["moe_combine", "moe_combine"]][
+            :len(rows_by)]          # the scope of the XLA it replaced, itself
     fusions = [i for i in ins if i["opcode"] == "fusion"]
     assert len(fusions) > 50
     assert [i["name"] for i in fusions if i["part"] is None] == []
