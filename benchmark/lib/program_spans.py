@@ -161,11 +161,11 @@ def attribute(intervals: Sequence[trace_lib.Interval],
     out of it.)"""
     out: Dict[str, float] = {}
     ordered = sorted(spans, key=lambda s: s[2] - s[1])
-    for piece in intervals:
+    for piece, over in trace_lib.meeting(intervals, ordered):
         free = [piece]
-        for name, a, b in ordered:
-            if b <= piece[0] or a >= piece[1] or not free:
-                continue
+        for name, a, b in over:
+            if not free:
+                break
             taken = trace_lib.clip(free, (a, b))
             if taken:
                 out[name] = out.get(name, 0.0) + trace_lib.total(taken) / 1e9

@@ -177,6 +177,25 @@ def name_events(rows: Sequence[Row], device: int, window: Interval,
             and rx.search(_label(n, 1))]
 
 
+def meeting(pieces: Sequence[Interval], ordered: Sequence[tuple]):
+    """Each of ``pieces`` (taken in ascending order) with the spans of
+    ``ordered`` (``(name, start, end)``) that overlap it, in ``ordered``'s
+    own order.  One pass over both: a span is looked at from the first piece
+    that ends after its start to the last that starts before its end, so a
+    chat trace's 250,000 pieces against 3,000 spans cost their sum and not
+    their product (which was two minutes of a traced run)."""
+    by_start = sorted(range(len(ordered)), key=lambda i: ordered[i][1])
+    live: List[int] = []
+    k = 0
+    for piece in sorted(pieces):
+        while k < len(by_start) and ordered[by_start[k]][1] < piece[1]:
+            live.append(by_start[k])
+            k += 1
+        live = [i for i in live if ordered[i][2] > piece[0]]
+        yield piece, [ordered[i] for i in sorted(live)
+                      if ordered[i][1] < piece[1]]
+
+
 def attribute_gaps(idle: Sequence[Interval],
                    host_spans: Sequence[Tuple[str, int, int]]
                    ) -> Dict[str, float]:
@@ -185,11 +204,9 @@ def attribute_gaps(idle: Sequence[Interval],
     where several do) and what no span covers is ``outside-spans``."""
     out: Dict[str, float] = {}
     ordered = sorted(host_spans, key=lambda s: s[2] - s[1])
-    for gap in idle:
+    for gap, over in meeting(idle, ordered):
         free = [gap]
-        for name, a, b in ordered:
-            if b <= gap[0] or a >= gap[1]:
-                continue
+        for name, a, b in over:
             taken = clip(free, (a, b))
             if taken:
                 out[name] = out.get(name, 0.0) + total(taken) / 1e9

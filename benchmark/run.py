@@ -69,6 +69,17 @@ def prepare_environment(rehearsal: bool, chips: int):
             ).strip()
 
 
+def refuse_empty_trace(traced: bool, reduction: dict, say):
+    """A traced run has to have seen the device work: where its trace holds
+    no device operation (the engine had drained before the traced seconds),
+    say so as a fault line, then leave with no result."""
+    if traced and not reduction:
+        say(fault="the trace holds no device operation",
+            why="no step ran on the device inside the traced seconds, the "
+                "window's last: the queue had drained, or nothing was due")
+        sys.exit("benchmark: the trace holds no device operation")
+
+
 def main(argv=None):
     args = parse(argv)
     manifest = manifest_lib.load_manifest()
@@ -170,8 +181,7 @@ def main(argv=None):
             rehearsal_metrics=metrics, attempted=record["attempted"],
             failed=record["failed"])
         sys.exit(0 if correct else 1)
-    if cell.trace and not reduction:
-        sys.exit("benchmark: the trace holds no device operation")
+    refuse_empty_trace(cell.trace, reduction, say)
     print(json.dumps(result), flush=True)
 
 

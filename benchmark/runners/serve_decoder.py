@@ -206,13 +206,13 @@ def run(cell, env, reference) -> dict:
         + sum(p.refused is not None for p in carried)
     say(window="serve", due=len(rows),
         samples_beyond={q: samples_beyond(len(rows), q) for q in (90, 95)},
-        completed_in_window=sum(1 for r in rows if r["finished"]
-                                is not None
-                                and r["finished"] <= raw["closed_at"]),
+        completed_of_due=sum(1 for r in rows if r["finished"] is not None
+                             and r["finished"] <= raw["closed_at"]),
+        latency=loadgen.latency_note(raw, rows),
         carried_into_window=len(carried), failed=failed,
         cut_by_drain=sum(1 for r in rows
                          if not r["failed"] and r["finished"] is None),
-        ended_at=raw["ended_at"], closed_at=raw["closed_at"],
+        ended_at=raw["ended_at"], **loadgen.window_note(raw),
         queue_half=raw["queue_half"], queue_end=raw["queue_end"],
         engine_steps=len(raw["steps"]), steps=longest(raw["steps"]),
         gc=env.gc_watch.since(t_replay), scheduler=eng.stats,

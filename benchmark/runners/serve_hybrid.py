@@ -324,11 +324,8 @@ def run(cell, env, reference) -> dict:
                   for key, value in traced.get("kda_to", {}).items()}
     say(window="serve", due=len(rows),
         samples_beyond={q: samples_beyond(len(rows), q) for q in (90, 95)},
-        completed_in_window=sum(
-            1 for p in raw["requests"] if p.finished is not None
-            and 0.0 <= p.finished <= raw["closed_at"]),
         carried_into_window=len(carried), failed=failed,
-        ended_at=raw["ended_at"], closed_at=raw["closed_at"],
+        ended_at=raw["ended_at"], **loadgen.window_note(raw),
         queue_half=raw["queue_half"], queue_end=raw["queue_end"],
         engine_steps=len(raw["steps"]), steps=longest(raw["steps"]),
         gc=env.gc_watch.since(t_replay), scheduler=eng.stats,

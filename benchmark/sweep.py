@@ -107,9 +107,7 @@ def main(argv=None):
                 between_steps=between)
             table = loadgen.request_table(raw,
                                           lambda p: p.handle.admitted_at)
-            close = raw["closed_at"]
-            completed = sum(1 for p in raw["requests"] if p.finished
-                            is not None and 0.0 <= p.finished <= close)
+            completed = len(loadgen.completed(raw))
             ttft = [r["ttft_s"] for r in table if r["ttft_s"] is not None]
             gaps = [r["mean_gap_s"] for r in table
                     if r["mean_gap_s"] is not None]
@@ -121,9 +119,10 @@ def main(argv=None):
                 "completed": completed, "queue_half": raw["queue_half"],
                 "queue_end": raw["queue_end"],
                 "running_end": len(eng.running),
-                "ttft_p50_ms": 1e3 * percentile(ttft, 50) if ttft else None,
+                # middle, 90th percentile and mean of first-token times, of
+                # requests' mean gaps (itl) and of all gaps pooled (gap)
+                **loadgen.latency_note(raw, table),
                 "ttft_p95_ms": 1e3 * percentile(ttft, 95) if ttft else None,
-                "itl_p50_ms": 1e3 * percentile(gaps, 50) if gaps else None,
                 "itl_p95_ms": 1e3 * percentile(gaps, 95) if gaps else None,
                 "decode_batch_mean": (b["decode_tokens"]
                                       - a.get("decode_tokens", 0)) / dsteps

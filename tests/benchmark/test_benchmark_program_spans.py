@@ -253,8 +253,8 @@ def test_the_grown_manifest_passes_and_names_the_new_metrics():
         assert by_name[name]["workloads"] == resnet
         assert by_name[name]["moves"] == "train_samples_per_s"
         assert by_name[name]["source"] == "program_span"
-    assert by_name["engine_host_ms_p50.chat"]["moves"] == "itl_p90_ms"
-    assert by_name["prefill_device_share_pct.chat"]["moves"] == "ttft_p90_ms"
+    assert by_name["engine_host_ms_p50.chat"]["moves"] == "itl_p50_ms"
+    assert by_name["prefill_device_share_pct.chat"]["moves"] == "itl_p50_ms"
     for name in ("engine_host_ms_p50.backlog",
                  "prefill_device_share_pct.backlog"):
         assert by_name[name]["moves"] == "serve_tokens_per_s"
