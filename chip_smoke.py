@@ -865,7 +865,8 @@ def compare_tokens(phase, what, core, prompt, got, want):
 
 
 def export(size):
-    from paddle_tpu.inference.serving import DecoderConfig, export_decoder
+    from paddle_tpu.inference.gpt2_decoder import (DecoderConfig,
+                                                   export_decoder)
 
     model_dir = tempfile.mkdtemp(prefix="chip_smoke_decoder_")
     export_decoder(model_dir, DecoderConfig(**size["cfg"]), seed=0)

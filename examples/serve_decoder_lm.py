@@ -20,8 +20,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from paddle_tpu.inference.gpt2_decoder import (  # noqa: E402
+    DecoderConfig, export_decoder)
 from paddle_tpu.inference.serving import (  # noqa: E402
-    DecoderConfig, Request, ServingEngine, export_decoder)
+    Request, ServingEngine)
 
 
 def main():

@@ -2494,7 +2494,8 @@ class FuseOptimizerOpsPass(Pass):
 @register_pass("serving_tp_pass")
 class ServingTPPass(Pass):
     """Insert the Megatron combine collectives into a serving decoder
-    SHARD program (one built with ``build_decoder_program(..., tp>1)``,
+    SHARD program (one built with ``gpt2_decoder.build_decoder_program(...,
+    tp>1)``,
     whose head/width reshapes already bake the local sizes):
 
     * after the token+position embedding sum (``_srv_h0_*`` — both
@@ -2572,8 +2573,9 @@ class ServingTPPass(Pass):
                                  outputs={"Out": [red]},
                                  attrs=dict(attrs))
                 self._redirect(block, i + 3, out, red)
-                if getattr(program, "_srv_logits", None) == out:
-                    program._srv_logits = red
+                if program._form_extras.logits == out:
+                    program._form_extras = \
+                        program._form_extras._replace(logits=red)
                 inserted += 2
                 i += 3
                 continue

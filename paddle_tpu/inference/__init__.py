@@ -27,12 +27,8 @@ from .admission import (
     SLOAwarePolicy,
     get_policy,
 )
-from .serving import (
-    DecoderConfig,
-    Request,
-    ServingEngine,
-    export_decoder,
-)
+from .gpt2_decoder import DecoderConfig, export_decoder
+from .serving import Request, ServingEngine
 
 __all__ = [
     "AnalysisConfig", "Config", "NativeConfig", "AnalysisPredictor",

@@ -1,14 +1,15 @@
 """A grouped-query decoder with window layers beside full ones, a gate on the
-attention output and the sparse-expert feed-forward of ``mla_decoder.py``:
-the Laguna-shaped block (``model_type: laguna``) as a model description
-``ServingEngine`` serves through the same seam as ``DecoderConfig`` and
-``MLADecoderConfig``: parameter specs, program forms, cache pools.  The same
+attention output and the sparse-expert feed-forward of
+``decoder_program.py``: the Laguna-shaped block (``model_type: laguna``) as a
+model description ``ServingEngine`` serves through the same seam as
+``DecoderConfig`` and ``MLADecoderConfig`` (``decoder_program.ServedModel``):
+parameter specs, program forms, cache pools.  The same
 description holds the Olmo-Hybrid-shaped decoder (``model_type:
 olmo_hybrid``; below): Gated DeltaNet layers beside full multi-head ones,
 dense throughout, the norms on the outputs.
 
 Per layer ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
-feed-forward half is ``mla_decoder._MB._ffn`` as it stands (the first
+feed-forward half is ``decoder_program._MB._ffn`` as it stands (the first
 ``first_k_dense`` layers a SwiGLU, the rest the router, this chip's share of
 the experts and the shared expert).  ``Attn`` of a layer of kind ``"full"``
 or ``"window"``: ``heads_full`` or ``heads_window`` query heads over
@@ -60,8 +61,8 @@ forms carry no counts and no routes.
 low-rank projections, nope/rope head dims, one head count, one rotary base),
 none of which this model has, and this one's (K/V heads, heads by kind, two
 rotary settings, the window, the gate) none of which that one has; the two
-share the block and the forms' plumbing, which are functions
-(``_MB.block`` / ``_ffn``, ``open_form``, ``embed_rows``, ``close_form``,
+share the block and the forms' skeleton, which are functions of
+``decoder_program.py`` (``build_form``, ``_MB.block`` / ``_ffn``,
 ``ffn_specs``) and are used from here, not copied.
 """
 from __future__ import annotations
@@ -72,12 +73,13 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-from ..framework.core import Program
 from ..framework.dtype import VarType, convert_dtype
 from ..ops import gqa_kernels, kda_kernels, mla_kernels
+from .decoder_program import (DELTA_RULE_SEEDS, _gmm_walk, _kv_append,
+                              _kv_pool_params, add_feed, build_form,
+                              delta_rule_seed,
+                              ffn_specs, live_rows)
 from .kv_cache import KVCacheConfig
-from .mla_decoder import (DELTA_RULE_SEEDS, _MB, _gmm_walk, close_form,
-                          delta_rule_seed, embed_rows, ffn_specs, open_form)
 
 __all__ = ["GQADecoderConfig", "Rope", "init_gqa_weights"]
 
@@ -520,13 +522,14 @@ _GDN_SLOTS = {"gdn_wqkvz": "WQKVZ", "gdn_wba": "WBA", "gdn_conv": "Conv",
 
 def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
                routed: bool):
-    """``prog._srv_kernel_stats`` of a serving form: what its attention
+    """``FormExtras.kernel_stats`` of a serving form: what its attention
     kernels walk, from the feed and the sizes the kernels' wrappers use
     (``gqa_kernels.prefill_walk`` / ``decode_walk_counts``), summed over the
     layers; the linear layers' ``gdn_prefill`` / ``gdn_decode`` calls with
     the real tokens and the chunks of a prompt's bucket, or the live
     sequences (rows whose slot is not the padding's); under ``from_counts``
-    what its grouped matmuls will have walked (``mla_decoder._gmm_walk``)."""
+    what its grouped matmuls will have walked (``decoder_program.
+    _gmm_walk``)."""
     full, win = len(cfg.full_layers), len(cfg.window_layers)
     lin = len(cfg.linear_layers) if kda_kernels.gdn_engages(
         cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim) else 0
@@ -588,54 +591,50 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
 
 def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
-    """One program form of the decoder: ``(program, feeds, fetches)``, with
-    what rides on a call as ``mla_decoder.close_form`` leaves it and, on the
-    serving forms, ``_srv_kernel_stats`` (:func:`_form_walk`)."""
-    from .serving import _kv_append, _kv_pool_params, _sampled
-
-    if mode not in ("reference", "prefill", "decode"):
-        raise ValueError(f"the grouped-query decoder builds no {mode!r} form")
-    if kv_dtype not in ("float32", "bfloat16"):
-        raise ValueError(f"bad kv_dtype {kv_dtype!r}")
-    if _sampled(sampling) and mode == "reference":
-        raise ValueError("the reference form is the greedy oracle; "
-                         "sampling applies to serving forms only")
-    prog = Program()
-    prog._label = mode
-    m = _MB(prog, cfg)
-    b = m.b
+    """One program form of the decoder: ``(program, feeds, fetches)``
+    through ``decoder_program.build_form``, with what rides on a call as
+    ``close_form`` leaves it and, on the serving forms, ``kernel_stats``
+    (:func:`_form_walk`)."""
     whole = mode in ("reference", "prefill")
-    f = open_form(b, mode, sampling)
-    feeds = f["feeds"]
     cached = mode != "reference"
-    windowed = cached and bool(cfg.window_layers)
-    win_slots = win_tables = win_first = None
-    if windowed:
-        # the window group's own slots, and for a decode step its table (the
-        # pages from each row's first held position on) and that position
-        win_slots = b.feed("window_slot_mapping", (-1,), VarType.INT32)
-        feeds.append("window_slot_mapping")
-        if not whole:
-            win_tables = b.feed("window_tables", (-1, -1), VarType.INT32)
-            win_first = b.feed("window_first", (-1,), VarType.INT32)
-            feeds += ["window_tables", "window_first"]
-    state_slots = None
-    if cached and cfg.linear_layers:
-        # the slot of the sequence (a prompt) or of each row (a decode
-        # batch) in the linear layers' pools; the padding's is the last
-        state_slots = b.feed("state_slots", (1,) if whole else (-1,),
-                             VarType.INT32)
-        feeds.append("state_slots")
-    flat_pos, hid = embed_rows(m, f["tokens"], f["positions"])
-    pools = {i: _kv_pool_params(b, i, False, kv_dtype)[:2]
-             for i in cfg.attn_layers} if cached else {}
-    valid = None
-    if cached:
-        with m.part("embed"):
-            valid = b.tmp("valid")
-            m.op("slot_is_live", {"SlotMapping": [f["slot_mapping"]],
-                                  "Cache": [pools[cfg.full_layers[0]][0]]},
-                 {"Out": [valid]})
+
+    def feeds(m, f):
+        if cached and cfg.window_layers:
+            # the window group's own slots, and for a decode step its table
+            # (the pages from each row's first held position on) and that
+            # position
+            add_feed(m.b, f, "window_slot_mapping", (-1,))
+            if not whole:
+                add_feed(m.b, f, "window_tables", (-1, -1))
+                add_feed(m.b, f, "window_first", (-1,))
+        if cached and cfg.linear_layers:
+            # the slot of the sequence (a prompt) or of each row (a decode
+            # batch) in the linear layers' pools; the padding's is the last
+            add_feed(m.b, f, "state_slots", (1,) if whole else (-1,))
+
+    def rows(m, f, flat_pos):
+        pools = {i: _kv_pool_params(m.b, i, False, kv_dtype)[:2]
+                 for i in cfg.attn_layers} if cached else {}
+        valid = None
+        if cached:
+            with m.part("embed"):
+                valid = live_rows(m, f["slot_mapping"],
+                                  pools[cfg.full_layers[0]][0])
+        return valid, _mixer(m, f, mode, kv_dtype, flat_pos, valid, pools)
+
+    return build_form(cfg, mode, sampling, kv_dtype,
+                      modes=("reference", "prefill", "decode"), feeds=feeds,
+                      rows=rows, walk=_form_walk, routes_all=("prefill",))
+
+
+def _mixer(m, f, mode: str, kv_dtype: str, flat_pos, valid, pools):
+    """The one hook of ``block``: ``mix(i, x)``, layer ``i``'s attention
+    (full or windowed) or Gated DeltaNet mixer over the rows ``x``, before
+    its ``wo``; ``f`` the form's feeds, ``pools`` the attention layers' K and
+    V pools where the form caches."""
+    cfg, b = m.cfg, m.b
+    whole = mode in ("reference", "prefill")
+    cached = mode != "reference"
     kv_type = convert_dtype(kv_dtype)
 
     def heads_of(x, heads, tag):
@@ -661,7 +660,7 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
     def linear(i, hn):
         """Layer ``i``'s Gated DeltaNet mixer: one op, its state and its
         convolution's tail in the layer's two slot pools where the form
-        caches (as ``_MB.kda``)."""
+        caches."""
         p, out = f"dec_l{i}_", m.tmp(f"l{i}_gdn")
         ins = {"X": [hn]}
         ins.update({slot: [p + name] for name, slot in _GDN_SLOTS.items()})
@@ -669,7 +668,7 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
         if cached:
             state, conv = (b.param(f"gdn_{kind}_{i}", (), dtype=VarType.FP32)
                            for kind in ("state", "conv"))
-            ins.update({"Valid": [valid], "StateSlots": [state_slots],
+            ins.update({"Valid": [valid], "StateSlots": [f["state_slots"]],
                         "State": [state], "ConvState": [conv]})
             if whole:
                 ins["LastIndex"] = [f["last_index"]]
@@ -710,30 +709,19 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
         if cached:
             k, v = stored(k, f"l{i}_ks"), stored(v, f"l{i}_vs")
             kc, vc = pools[i]
-            _kv_append(b, k, v, win_slots if window else f["slot_mapping"],
-                       kc, vc, None, None)
+            _kv_append(b, k, v, f["window_slot_mapping" if window
+                                  else "slot_mapping"], kc, vc, None, None)
         if whole:
             ins.update({"K": [k], "V": [v]})
             m.op("gqa_prefill_attention", ins, {"Out": [out]}, attrs)
             return out
         ins.update({"KCache": [kc], "VCache": [vc],
                     "ContextLens": [f["context_lens"]],
-                    "BlockTables": [win_tables if window else f["tables"]]})
+                    "BlockTables": [f["window_tables" if window
+                                      else "tables"]]})
         if window:
-            ins["First"] = [win_first]
+            ins["First"] = [f["window_first"]]
         m.op("gqa_paged_attention", ins, {"Out": [out]}, attrs)
         return out
 
-    counts: List[str] = []
-    routes: List[str] = []
-    absent: List[str] = []
-    for i in range(cfg.num_layers):
-        hid = m.block(i, hid, flat_pos, None, valid, counts, routes,
-                      kda=mixer, absent=absent)
-    out_name = close_form(m, prog, hid, f["last_index"] if whole else None,
-                          routes, counts, absent, sampling, f["seeds"],
-                          routes_all=mode == "prefill")
-    if cached:
-        prog._srv_kernel_stats = functools.partial(
-            _form_walk, mode=mode, cfg=cfg, routed=bool(counts))
-    return prog, feeds, [out_name]
+    return mixer

@@ -2,8 +2,8 @@
 expert and a multi-token-prediction head: the DeepSeek-V3-shaped block
 (arXiv:2405.04434 section 2.1, arXiv:2412.19437 sections 2.1-2.2) as a
 model description ``ServingEngine`` serves through the same seam as
-``DecoderConfig`` (inference/serving.py): parameter specs, program forms,
-cache pools.
+``DecoderConfig`` (``decoder_program.ServedModel``): parameter specs,
+program forms (``decoder_program.build_form``), cache pools.
 
 Per layer ``h = x + MLA(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``; the
 first ``first_k_dense`` layers' FFN is a SwiGLU of width ``intermediate``,
@@ -46,7 +46,6 @@ an engine drafter on the speculative path.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 from dataclasses import dataclass
 from typing import Dict, List, Tuple
@@ -56,6 +55,9 @@ import numpy as np
 from ..framework.core import Program
 from ..framework.dtype import VarType, convert_dtype
 from ..ops import kda_kernels, mla_kernels
+from .decoder_program import (DELTA_RULE_SEEDS, _MB, FormExtras, _emit_head,
+                              _gmm_walk, _pow2_bucket, add_feed, build_form,
+                              delta_rule_seed, ffn_specs, live_rows)
 from .kv_cache import KVCacheConfig
 from .spec_decode import Proposer
 
@@ -115,7 +117,8 @@ class MLADecoderConfig:
         return self.weights_dtype
 
     def mixer(self, i: int) -> str:
-        return self.mixers[i] if self.mixers else "mla"
+        """Layer ``i``'s mixer; the MTP block's (``num_layers``) is MLA."""
+        return self.mixers[i] if i < len(self.mixers) else "mla"
 
     @property
     def mla_layers(self) -> List[int]:
@@ -186,6 +189,9 @@ class MLADecoderConfig:
 
     def cache_pool_names(self) -> List[str]:
         return [f"kv_lat_{i}" for i in self.mla_layers]
+
+    def window_pool_names(self) -> List[str]:
+        return []
 
     def kv_token_bytes(self, kv_dtype: str, tp: int = 1) -> int:
         return len(self.mla_layers) * self.latent_row \
@@ -301,7 +307,7 @@ def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     p = f"dec_l{i}_"
     specs = {p + "attn_norm_scale": (h,)}
-    if i < cfg.num_layers and cfg.mixer(i) == "kda":
+    if cfg.mixer(i) == "kda":
         specs.update({p + name: shape
                       for name, shape in _kda_specs(cfg).items()})
     else:
@@ -319,29 +325,6 @@ def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
             p + "wo": (heads * cfg.v_head_dim, h),
         })
     specs.update(ffn_specs(cfg, i, moe))
-    return specs
-
-
-def ffn_specs(cfg, i: int, moe: bool) -> Dict[str, tuple]:
-    """The feed-forward half of layer ``i``: its norm and the dense SwiGLU,
-    or the router, this chip's experts and the shared expert (what
-    ``_MB._ffn`` builds, for any description with these fields)."""
-    h, p = cfg.hidden, f"dec_l{i}_"
-    specs = {p + "ffn_norm_scale": (h,)}
-    if not moe:
-        f = cfg.intermediate
-        specs.update({p + "w_gate": (h, f), p + "w_up": (h, f),
-                      p + "w_down": (f, h)})
-        return specs
-    f, e, held = cfg.moe_intermediate, cfg.n_routed_experts, cfg.experts_here
-    fs = f * cfg.n_shared_experts
-    specs.update({
-        p + "router": (h, e), p + "router_bias": (e,),
-        p + "experts_gate": (held, h, f), p + "experts_up": (held, h, f),
-        p + "experts_down": (held, f, h),
-        p + "shared_gate": (h, fs), p + "shared_up": (h, fs),
-        p + "shared_down": (fs, h),
-    })
     return specs
 
 
@@ -383,24 +366,6 @@ def mla_param_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
     return specs
 
 
-#: the weights of a delta-rule mixer (``kda_*``, ``gdn_*``) that are no
-#: matrix over sqrt(fan-in)
-DELTA_RULE_SEEDS = ("_a_log", "_dt_bias", "kda_conv", "gdn_conv")
-
-
-def delta_rule_seed(name: str, shape, rng) -> np.ndarray:
-    """A delta-rule mixer's decay and taps, seeded: ``A_log`` the log of a
-    rate uniform in [1, 16], ``dt_bias`` the inverse softplus of a step
-    log-uniform in [0.001, 0.1], the convolution's taps normal over
-    sqrt(taps)."""
-    if name.endswith("_a_log"):
-        return np.log(rng.uniform(1.0, 16.0, shape))
-    if name.endswith("_dt_bias"):
-        dt = np.exp(rng.uniform(np.log(1e-3), np.log(0.1), shape))
-        return dt + np.log(-np.expm1(-dt))
-    return rng.randn(*shape) / np.sqrt(shape[-1])
-
-
 def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
                      ) -> Dict[str, np.ndarray]:
     """Seeded weights for tests and smokes: norm scales 1, the router's
@@ -427,236 +392,108 @@ def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
 # ==========================================================================
 # Program builders
 # ==========================================================================
-#: a layer's mixer kind -> the part of the model its ops serve
-MIXER_PARTS = {"mla": "mla_part", "kda": "kda_part", "full": "attn_full",
-               "window": "attn_window", "linear": "gdn_part"}
+def _rope(m: _MB, x, positions, tag):
+    o = m.tmp(tag)
+    m.op("rope_interleaved", {"X": [x], "Positions": [positions]},
+         {"Out": [o]}, {"theta": float(m.cfg.rope_theta)})
+    return o
 
 
-class _MB:
-    """The block builder of serving.py's ``_B`` plus this model's
-    composites.  Parameters take the configuration's weights type."""
+def _split(m: _MB, x, sizes, tag):
+    outs = [m.tmp(f"{tag}_{j}") for j in range(len(sizes))]
+    m.op("split", {"X": [x]}, {"Out": outs},
+         {"axis": -1, "sections": list(sizes), "num": 0})
+    return outs
 
-    def __init__(self, program: Program, cfg: MLADecoderConfig):
-        from .serving import _B
 
-        self.b = _B(program)
-        self.cfg = cfg
-        self.wdt = convert_dtype(cfg.weights_dtype)
-        for name, shape in cfg.param_specs().items():
-            self.b.param(name, shape, dtype=self.wdt)
-
-    def op(self, *a, **kw):
-        self.b.op(*a, **kw)
-
-    def tmp(self, tag):
-        return self.b.tmp(tag)
-
-    @contextlib.contextmanager
-    def part(self, name):
-        """Every op built inside serves this part of the model (``embed``,
-        ``mla_part``, ``kda_part``, ``gdn_part``, ``attn_full``,
-        ``attn_window``, ``moe_part``, ``dense_ffn``, ``head``, ``mtp``): its
-        attr ``part``, which ``registry.run_op`` makes the
-        op's outermost scope, so the compiled program says whose time each
-        of its instructions is (``profiler.device_symbols``)."""
-        was, self.b.part = self.b.part, name
-        try:
-            yield
-        finally:
-            self.b.part = was
-
-    def mm(self, x, w, tag):
-        o = self.tmp(tag)
-        self.op("matmul_f32acc", {"X": [x], "Y": [w]}, {"Out": [o]})
-        return o
-
-    def norm(self, x, scale, tag):
-        o = self.tmp(tag)
-        self.op("rms_norm", {"X": [x], "Scale": [scale]}, {"Y": [o]},
-                {"epsilon": float(self.cfg.rms_norm_eps)})
-        return o
-
-    def rope(self, x, positions, tag):
-        o = self.tmp(tag)
-        self.op("rope_interleaved", {"X": [x], "Positions": [positions]},
-                {"Out": [o]}, {"theta": float(self.cfg.rope_theta)})
-        return o
-
-    def split(self, x, sizes, tag):
-        outs = [self.tmp(f"{tag}_{j}") for j in range(len(sizes))]
-        self.op("split", {"X": [x]}, {"Out": outs},
-                {"axis": -1, "sections": list(sizes), "num": 0})
-        return outs
-
-    def swiglu_ffn(self, x, gate, up, down, tag):
-        g = self.mm(x, gate, tag + "_g")
-        u = self.mm(x, up, tag + "_u")
-        a = self.tmp(tag + "_act")
-        self.op("swiglu", {"Gate": [g], "Up": [u]}, {"Out": [a]})
-        return self.mm(a, down, tag + "_d")
-
-    def latents(self, i, hn, positions):
-        """Layer ``i``'s queries and latent row of the normed rows ``hn``
-        (n, hidden): ``(q_nope, q_rope) (n, heads, dn | dr)``, ``c_kv``
-        (n, r) normed, ``k_r`` (n, dr) after RoPE."""
-        cfg, p, b = self.cfg, f"dec_l{i}_", self.b
-        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        if cfg.q_lora_rank:
-            cq = self.norm(self.mm(hn, p + "wq_a", f"l{i}_cq"),
-                           p + "q_norm_scale", f"l{i}_cqn")
-            q = self.mm(cq, p + "wq_b", f"l{i}_q")
-        else:
-            q = self.mm(hn, p + "wq", f"l{i}_q")
-        q = b.reshape(q, [-1, cfg.num_heads, dn + dr], f"l{i}_q3")
-        q_nope, q_rope = self.split(q, [dn, dr], f"l{i}_qs")
-        if cfg.rope:
-            q_rope = self.rope(q_rope, positions, f"l{i}_qr")
-        c_kv, k_r = self.split(self.mm(hn, p + "wkv_a", f"l{i}_kva"),
-                               [cfg.kv_lora_rank, dr], f"l{i}_kvs")
-        c_kv = self.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv")
-        if not cfg.rope:      # NoPE: dr more key lanes that all heads share
-            return q_nope, q_rope, c_kv, k_r
-        k_r = b.reshape(self.rope(b.reshape(k_r, [-1, 1, dr], f"l{i}_kr3"),
-                                  positions, f"l{i}_krr"),
-                        [-1, dr], f"l{i}_kr")
+def _latents(m: _MB, i, hn, positions):
+    """Layer ``i``'s queries and latent row of the normed rows ``hn``
+    (n, hidden): ``(q_nope, q_rope) (n, heads, dn | dr)``, ``c_kv``
+    (n, r) normed, ``k_r`` (n, dr) after RoPE."""
+    cfg, p, b = m.cfg, f"dec_l{i}_", m.b
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if cfg.q_lora_rank:
+        cq = m.norm(m.mm(hn, p + "wq_a", f"l{i}_cq"),
+                    p + "q_norm_scale", f"l{i}_cqn")
+        q = m.mm(cq, p + "wq_b", f"l{i}_q")
+    else:
+        q = m.mm(hn, p + "wq", f"l{i}_q")
+    q = b.reshape(q, [-1, cfg.num_heads, dn + dr], f"l{i}_q3")
+    q_nope, q_rope = _split(m, q, [dn, dr], f"l{i}_qs")
+    if cfg.rope:
+        q_rope = _rope(m, q_rope, positions, f"l{i}_qr")
+    c_kv, k_r = _split(m, m.mm(hn, p + "wkv_a", f"l{i}_kva"),
+                       [cfg.kv_lora_rank, dr], f"l{i}_kvs")
+    c_kv = m.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv")
+    if not cfg.rope:      # NoPE: dr more key lanes that all heads share
         return q_nope, q_rope, c_kv, k_r
+    k_r = b.reshape(_rope(m, b.reshape(k_r, [-1, 1, dr], f"l{i}_kr3"),
+                          positions, f"l{i}_krr"),
+                    [-1, dr], f"l{i}_kr")
+    return q_nope, q_rope, c_kv, k_r
 
-    def kda(self, i, hn, mode, valid=None, state_slots=None,
-            last_index=None):
-        """Layer ``i``'s KDA mixer over the normed rows ``hn``: one op (the
-        projections, the convolution, the recurrence, the gated output
-        norm), its state and its convolution's tail in the layer's two slot
-        pools where the form caches."""
-        cfg, p = self.cfg, f"dec_l{i}_"
-        out = self.tmp(f"l{i}_kda")
-        ins = {"X": [hn]}
-        ins.update({slot: [p + name] for name, slot in _KDA_SLOTS.items()})
-        outs = {"Out": [out]}
-        if mode != "reference":
-            state, conv = (self.b.param(f"kda_{kind}_{i}", (),
-                                        dtype=VarType.FP32)
-                           for kind in ("state", "conv"))
-            ins.update({"Valid": [valid], "StateSlots": [state_slots],
-                        "State": [state], "ConvState": [conv]})
-            if last_index is not None:
-                ins["LastIndex"] = [last_index]
-            outs.update({"StateOut": [state], "ConvStateOut": [conv]})
-        self.op("kda_mixer", ins, outs,
-                {"mode": mode, "heads": int(cfg.kda_heads),
-                 "head_dim": int(cfg.kda_head_dim),
-                 "epsilon": float(cfg.rms_norm_eps),
-                 "l2_epsilon": float(cfg.kda_l2_eps)})
-        return out
 
-    def pool(self, i, kv_dtype):
-        return self.b.param(f"kv_lat_{i}", (), dtype=convert_dtype(kv_dtype))
+def _pool(m: _MB, i, kv_dtype):
+    return m.b.param(f"kv_lat_{i}", (), dtype=convert_dtype(kv_dtype))
 
-    def append(self, i, c_kv, k_r, slot_map, kv_dtype):
-        pool = self.pool(i, kv_dtype)
-        self.op("latent_cache_append",
-                {"CKV": [c_kv], "KRope": [k_r], "SlotMapping": [slot_map],
-                 "Cache": [pool]}, {"CacheOut": [pool]})
-        return pool
 
-    def attn_attrs(self):
-        cfg = self.cfg
-        return {"v_head_dim": int(cfg.v_head_dim), "scale": float(
-            (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)}
+def _mla(m: _MB, i, hn, positions, kv_dtype, slot_map=None, tables=None,
+         ctx_lens=None):
+    """Layer ``i``'s latent attention over the normed rows ``hn``: the rows'
+    latents enter the layer's pool first where the form caches
+    (``slot_map``), then the expanded attention over the whole prompt or,
+    given ``tables``, the absorbed one over the paged pool."""
+    cfg = m.cfg
+    q_nope, q_rope, c_kv, k_r = _latents(m, i, hn, positions)
+    out = m.tmp(f"l{i}_att")
+    ins = {"QNope": [q_nope], "QRope": [q_rope],
+           "WKVB": [f"dec_l{i}_wkv_b"]}
+    if slot_map is not None:
+        pool = _pool(m, i, kv_dtype)
+        m.op("latent_cache_append",
+             {"CKV": [c_kv], "KRope": [k_r], "SlotMapping": [slot_map],
+              "Cache": [pool]}, {"CacheOut": [pool]})
+    if tables is None:
+        kind = "mla_prefill_attention"
+        ins.update({"CKV": [c_kv], "KRope": [k_r]})
+    else:
+        kind = "mla_paged_attention"
+        ins.update({"Cache": [pool], "BlockTables": [tables],
+                    "ContextLens": [ctx_lens]})
+    m.op(kind, ins, {"Out": [out]},
+         {"v_head_dim": int(cfg.v_head_dim), "scale": float(
+             (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5)})
+    return out
 
-    def block(self, i, hid, positions, attend, valid, counts, routes=None,
-              kda=None, absent=None):
-        """One block over rows ``hid`` (n, hidden): pre-norm, ``h = x +
-        Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, or where the
-        description says ``norm_after`` the same two scales on the OUTPUTS,
-        ``h = x + RMSNorm(Mix(x))``, ``y = h + RMSNorm(FFN(h))``.  ``attend``
-        maps ``(i, q_nope, q_rope, c_kv, k_r)`` to the attention's output
-        (n, heads * dv), ``kda`` maps ``(i, the mixer's input rows)`` to the
-        mixer output of a layer of any other kind (a KDA layer's; a
-        grouped-query layer's, full or windowed; a Gated DeltaNet layer's:
-        ``MIXER_PARTS``), before its ``wo``;
-        ``valid`` (or None) marks the rows that are real
-        tokens; an expert layer appends its per-expert counts to
-        ``counts`` (and, where it holds a share of its experts, the number
-        of rows none of whose experts it holds to ``absent``)."""
-        cfg, p, b = self.cfg, f"dec_l{i}_", self.b
-        mix = MIXER_PARTS[cfg.mixer(i) if i < cfg.num_layers else "mla"]
-        with self.part(mix):
-            if getattr(cfg, "norm_after", False):
-                out = self.norm(self.mm(kda(i, hid), p + "wo", f"l{i}_o"),
-                                p + "attn_norm_scale", f"l{i}_an")
-            else:
-                hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an")
-                att = kda(i, hn) if mix != "mla_part" \
-                    else attend(i, *self.latents(i, hn, positions))
-                out = self.mm(att, p + "wo", f"l{i}_o")
-            hid = b.add(hid, out, f"l{i}_res1")
-        dense = i < cfg.first_k_dense
-        with self.part("dense_ffn" if dense else "moe_part"):
-            return b.add(hid, self._ffn(i, hid, dense, valid, counts, routes,
-                                        absent), f"l{i}_res2")
 
-    def _ffn(self, i, hid, dense, valid, counts, routes, absent):
-        """Layer ``i``'s feed-forward half over ``hid``: its norm and the
-        dense SwiGLU, or the router, the routed experts and the shared
-        expert; with ``norm_after`` the dense SwiGLU and then the norm."""
-        cfg, p, b = self.cfg, f"dec_l{i}_", self.b
-        if getattr(cfg, "norm_after", False):
-            if not dense:
-                raise ValueError("norm_after is built for dense layers")
-            return self.norm(
-                self.swiglu_ffn(hid, p + "w_gate", p + "w_up", p + "w_down",
-                                f"l{i}_ff"), p + "ffn_norm_scale", f"l{i}_fn")
-        hn2 = self.norm(hid, p + "ffn_norm_scale", f"l{i}_fn")
-        if dense:
-            return self.swiglu_ffn(hn2, p + "w_gate", p + "w_up",
-                                   p + "w_down", f"l{i}_ff")
-        idx, wgt = self.tmp(f"l{i}_ridx"), self.tmp(f"l{i}_rw")
-        self.op("moe_router",
-                {"X": [hn2], "Gate": [p + "router"],
-                 "Bias": [p + "router_bias"]},
-                {"Idx": [idx], "Weight": [wgt]},
-                {"top_k": int(cfg.num_experts_per_tok),
-                 "routed_scaling_factor":
-                     float(cfg.routed_scaling_factor),
-                 "norm_topk_prob": bool(cfg.norm_topk_prob)})
-        routed, cnt = self.tmp(f"l{i}_moe"), self.tmp(f"l{i}_cnt")
-        ins = {"X": [hn2], "Idx": [idx], "Weight": [wgt],
-               "WGate": [p + "experts_gate"], "WUp": [p + "experts_up"],
-               "WDown": [p + "experts_down"]}
-        if valid is not None:
-            ins["Valid"] = [valid]
-        outs = {"Out": [routed], "Counts": [cnt]}
-        if cfg.experts_here < cfg.n_routed_experts:
-            # this chip's share: the rows' other experts are elsewhere
-            outs["Absent"] = [self.tmp(f"l{i}_absent")]
-            absent.append(outs["Absent"][0])
-        self.op("moe_experts", ins, outs)
-        counts.append(cnt)
-        if routes is not None:
-            routes.append(idx)
-        shared = self.swiglu_ffn(hn2, p + "shared_gate", p + "shared_up",
-                                 p + "shared_down", f"l{i}_sh")
-        return b.add(routed, shared, f"l{i}_ff")
-
-    def stacked(self, per_layer, name):
-        """The expert layers' small int32 results as one fetch, layers
-        first."""
-        out = self.b.blk.create_var(name=name, dtype=VarType.INT32).name
-        self.op("stack", {"X": list(per_layer)}, {"Y": [out]}, {"axis": 0})
-        return out
-
-    def live_rows(self, slot_map, layer, kv_dtype):
-        """Rows whose slot lies in the pool are real tokens; bucket padding
-        carries the pad sentinel, the first slot past it."""
-        o = self.tmp("valid")
-        self.op("slot_is_live", {"SlotMapping": [slot_map],
-                                 "Cache": [self.pool(layer, kv_dtype)]},
-                {"Out": [o]})
-        return o
+def _kda(m: _MB, i, hn, mode, valid=None, state_slots=None, last_index=None):
+    """Layer ``i``'s KDA mixer over the normed rows ``hn``: one op (the
+    projections, the convolution, the recurrence, the gated output
+    norm), its state and its convolution's tail in the layer's two slot
+    pools where the form caches."""
+    cfg, p = m.cfg, f"dec_l{i}_"
+    out = m.tmp(f"l{i}_kda")
+    ins = {"X": [hn]}
+    ins.update({slot: [p + name] for name, slot in _KDA_SLOTS.items()})
+    outs = {"Out": [out]}
+    if mode != "reference":
+        state, conv = (m.b.param(f"kda_{kind}_{i}", (), dtype=VarType.FP32)
+                       for kind in ("state", "conv"))
+        ins.update({"Valid": [valid], "StateSlots": [state_slots],
+                    "State": [state], "ConvState": [conv]})
+        if last_index is not None:
+            ins["LastIndex"] = [last_index]
+        outs.update({"StateOut": [state], "ConvStateOut": [conv]})
+    m.op("kda_mixer", ins, outs,
+         {"mode": mode, "heads": int(cfg.kda_heads),
+          "head_dim": int(cfg.kda_head_dim),
+          "epsilon": float(cfg.rms_norm_eps),
+          "l2_epsilon": float(cfg.kda_l2_eps)})
+    return out
 
 
 def _decode_walk(feed, kv_config, *, verify: bool, heads: int, layers: int):
-    """``prog._srv_kernel_stats`` of the decode and verify forms: what the
+    """``FormExtras.kernel_stats`` of the decode and verify forms: what the
     call's ``mla_decode`` kernels walk, from the contexts the call is fed
     and the sizes the kernel's wrapper uses (``mla_kernels.
     decode_chunks``), summed over the layers: the grid's steps (the chunks
@@ -679,7 +516,7 @@ def _decode_walk(feed, kv_config, *, verify: bool, heads: int, layers: int):
 
 
 def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
-    """``prog._srv_kernel_stats`` of a hybrid model's prefill and decode
+    """``FormExtras.kernel_stats`` of a hybrid model's prefill and decode
     forms: the KDA kernels' calls, and the real tokens (prefill) or live
     sequences (decode: rows whose slot is not the padding's) they took,
     summed over the KDA layers; a prefill's grid steps, from the bucket it
@@ -701,34 +538,9 @@ def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
     return out
 
 
-def _gmm_walk(counts, *, rows: int, hidden: int):
-    """What a call's ``moe_gmm`` kernels walked, from the tokens each expert
-    received (``counts``, (expert layers, experts): the call's own
-    ``_srv_counts``, on the host once ``moe_stats`` reads them) and the rows
-    the call's dispatch sorts (token rows times ``k``), by the tile the
-    kernel's wrapper uses (``mla_kernels.gmm_walk_counts``).  An expert
-    layer's two calls walk the same list: the row tiles that hold a row an
-    expert owns and the (row tile, expert) visits, summed over them.  And
-    the rows around them: ``moe_rows_sorted`` the ``n * k`` choices a
-    layer's dispatch sorts, ``moe_rows_moved`` those of them that were moved
-    in and out of the matmuls: the rows the experts here own where
-    ``moe_rows_in`` / ``moe_combine`` take the call, all of them where
-    XLA's ``take`` does."""
-    counts = np.asarray(counts)
-    walked = [mla_kernels.gmm_walk_counts(sizes, rows) for sizes in counts]
-    by_kernel = mla_kernels.moe_rows_engage(rows, counts.shape[1], hidden)
-    sorted_rows = rows * len(walked)
-    return {"moe_gmm_calls": 2 * len(walked),
-            "moe_gmm_row_tiles": 2 * sum(t for t, _ in walked),
-            "moe_gmm_visits": 2 * sum(v for _, v in walked),
-            "moe_rows_sorted": sorted_rows,
-            "moe_rows_moved": int(counts.sum()) if by_kernel
-            else sorted_rows}
-
-
 def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
                routed: bool):
-    """``prog._srv_kernel_stats`` of a serving form: what its attention and
+    """``FormExtras.kernel_stats`` of a serving form: what its attention and
     state kernels walk, from the feed (:func:`_decode_walk`,
     :func:`_hybrid_walk`), and under ``from_counts`` what its grouped
     matmuls will have walked, a function of the call's expert counts
@@ -747,213 +559,52 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
     return out
 
 
-def open_form(b, mode: str, sampling) -> dict:
-    """The feeds a program form of ``mode`` opens with, by name (``tables``
-    the block tables under the form's own feed name), ``feeds`` their names
-    in order and ``seeds`` the sampling lanes' feed or None.  A description
-    adds its own feeds after these."""
-    from .serving import _sampled
-
-    f = {"seeds": None}
-    if mode in ("reference", "prefill"):
-        f["tokens"] = b.feed("tokens", (1, -1), VarType.INT32)
-        f["positions"] = b.feed("positions", (1, -1), VarType.INT32)
-        f["last_index"] = b.feed("last_index", (1,), VarType.INT32)
-        feeds = ["tokens", "positions", "last_index"]
-        if mode == "prefill":
-            f["slot_mapping"] = b.feed("slot_mapping", (-1,), VarType.INT32)
-            feeds.append("slot_mapping")
-        if _sampled(sampling):
-            f["seeds"] = b.feed("sample_seeds", (1,), VarType.INT32)
-            feeds.append("sample_seeds")
-    elif mode == "decode":
-        f["tokens"] = b.feed("tokens", (-1,), VarType.INT32)
-        f["positions"] = b.feed("positions", (-1,), VarType.INT32)
-        f["tables"] = b.feed("block_tables", (-1, -1), VarType.INT32)
-        f["context_lens"] = b.feed("context_lens", (-1,), VarType.INT32)
-        f["slot_mapping"] = b.feed("slot_mapping", (-1,), VarType.INT32)
-        feeds = ["tokens", "positions", "block_tables", "context_lens",
-                 "slot_mapping"]
-        if _sampled(sampling):
-            f["seeds"] = b.feed("sample_seeds", (-1,), VarType.INT32)
-            feeds.append("sample_seeds")
-    else:
-        f["tokens"] = b.feed("tokens", (-1, -1), VarType.INT32)     # (B, S)
-        f["positions"] = b.feed("positions", (-1, -1), VarType.INT32)
-        f["slot_mapping"] = b.feed("slot_mapping", (-1,), VarType.INT32)
-        f["tables"] = b.feed("verify_tables", (-1, -1), VarType.INT32)
-        feeds = ["tokens", "positions", "slot_mapping", "verify_tables"]
-        if _sampled(sampling):
-            f["seeds"] = b.feed("sample_seeds", (-1,), VarType.INT32)
-            feeds.append("sample_seeds")
-    f["feeds"] = feeds
-    return f
-
-
-def embed_rows(m: "_MB", tokens, positions):
-    """The rows' inputs under the part ``embed``: the flat positions and the
-    float32 embeddings of the flat ids."""
-    b = m.b
-    with m.part("embed"):
-        flat_tok = b.reshape(tokens, [-1], "tok_flat")
-        flat_pos = b.reshape(positions, [-1], "pos_flat")
-        hid = b.tmp("h0")
-        m.op("lookup_table_v2", {"W": ["dec_embed"], "Ids": [flat_tok]},
-             {"Out": [hid]})
-        hid32 = b.tmp("h0_f32")
-        m.op("cast", {"X": [hid]}, {"Out": [hid32]},
-             {"in_dtype": int(m.wdt), "out_dtype": int(VarType.FP32)})
-    return flat_pos, hid32
-
-
-def close_form(m: "_MB", prog, hid, last_index, routes, counts, absent,
-               sampling, seeds, routes_all: bool = False) -> str:
-    """The end of a form, from the last block's rows ``hid``: the emitting
-    row of a whole prompt (``last_index``; None: every row emits), the final
-    norm, the head, the token and what rides on a call (``_srv_hidden``,
-    ``_srv_logits``, ``_srv_score``, ``_srv_counts``, ``_srv_routes``,
-    ``_srv_absent``, and with ``routes_all`` every row's routing).  Returns
-    the token's name."""
-    from .serving import _emit_head
-
-    b, whole = m.b, last_index is not None
-    prog._srv_hidden = hid
-    # (expert layers, rows, k): every row's routing, a prompt's too.  In a
-    # hybrid model a row's neighbours reach it undiluted (the convolution's
-    # taps, the fast-decaying channels of a state), so a check of the served
-    # logits follows the engine's routing on the prompt's rows as well
-    with m.part("moe_part"):
-        prog._srv_routes_all = m.stacked(routes, "token_routes_all") \
-            if routes_all and routes else None
-    if whole:
-        with m.part("head"):
-            last = b.tmp("hlast")
-            m.op("gather", {"X": [hid], "Index": [last_index]},
-                 {"Out": [last]}, {"axis": 0})
-            hid = last
-        # the routing of the one row that emits
-        picked = []
-        with m.part("moe_part"):
-            for j, r in enumerate(routes):
-                o = b.tmp(f"route_last_{j}")
-                m.op("gather", {"X": [r], "Index": [last_index]},
-                     {"Out": [o]}, {"axis": 0})
-                picked.append(o)
-        routes = picked
-    out_name = "next_token" if whole else "next_tokens"
-    with m.part("head"):
-        logits = m.mm(m.norm(hid, "dec_norm_scale", "fnorm"), "dec_head",
-                      "logits")
-        _emit_head(b, logits, out_name, sampling, seeds)
-        score = b.blk.create_var(name="token_score", dtype=VarType.FP32).name
-        m.op("token_score", {"Logits": [logits], "Token": [out_name]},
-             {"Out": [score]})
-    prog._srv_params = dict.fromkeys(m.cfg.param_specs())
-    prog._srv_logits = logits
-    prog._srv_score = score
-    with m.part("moe_part"):
-        # (expert layers, experts): the tokens each expert received; (expert
-        # layers, rows, k): the experts each emitting row was routed to
-        prog._srv_counts = m.stacked(counts, "moe_counts") if counts else None
-        prog._srv_routes = m.stacked(routes, "token_routes") \
-            if routes else None
-        # (expert layers,): the rows none of whose experts this chip holds
-        prog._srv_absent = m.stacked(absent, "moe_absent") if absent else None
-    prog._tp_degree = 1
-    return out_name
-
-
 def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
-    """One program form of the decoder: ``(program, feeds, fetches)``.
-    Besides its token the program carries ``_srv_logits`` (the parity
-    hook), ``_srv_hidden`` (the rows' last hidden state before the final
-    norm: what the MTP drafter consumes), ``_srv_counts`` (tokens per
-    expert by expert layer) and ``_srv_score`` (each emitted token's logit
-    and the row's log-sum-exp, two floats a row); the serving forms
-    ``_srv_kernel_stats`` (:func:`_form_walk`)."""
-    from .serving import _sampled
-
-    if mode == "chunk":
-        raise ValueError("the MLA decoder builds no 'chunk' form")
+    """One program form of the decoder: ``(program, feeds, fetches)``
+    through ``decoder_program.build_form``.  Its ``FormExtras`` offer the
+    logits (the parity hook), the rows' last hidden state before the final
+    norm (what the MTP drafter consumes), the tokens per expert by expert
+    layer and each emitted token's logit and the row's log-sum-exp, two
+    floats a row; the serving forms' ``kernel_stats`` is
+    :func:`_form_walk`."""
     if mode == "mtp":
         return _build_mtp_program(cfg, sampling, kv_dtype)
-    if mode not in ("reference", "prefill", "decode", "verify"):
-        raise ValueError(f"bad mode {mode!r}")
-    if kv_dtype not in ("float32", "bfloat16"):
-        raise ValueError(f"bad kv_dtype {kv_dtype!r}")
-    if _sampled(sampling) and mode == "reference":
-        raise ValueError("the reference form is the greedy oracle; "
-                         "sampling applies to serving forms only")
     hybrid = bool(cfg.kda_layers)
-    if hybrid and mode == "verify":
-        raise ValueError("a model with KDA layers builds no 'verify' form: "
-                         "a recurrent state cannot be rolled back")
-    prog = Program()
-    prog._label = mode
-    m = _MB(prog, cfg)
-    b = m.b
     whole = mode in ("reference", "prefill")
-    f = open_form(b, mode, sampling)
-    tokens, positions, feeds, seeds = f["tokens"], f["positions"], \
-        f["feeds"], f["seeds"]
-    last_index, slot_map = f.get("last_index"), f.get("slot_mapping")
-    tables, ctx_lens = f.get("tables"), f.get("context_lens")
 
-    state_slots = None
-    if hybrid and mode != "reference":
-        # the slot of the sequence (a prompt) or of each row (a decode
-        # batch) in the KDA layers' pools; the padding's is the last
-        state_slots = b.feed("state_slots", (1,) if whole else (-1,),
-                             VarType.INT32)
-        feeds.append("state_slots")
-    flat_pos, hid = embed_rows(m, tokens, positions)
-    with m.part("embed"):     # the rows' contexts and liveness
-        if mode == "verify":
-            # a verify row's context ends at its own position
-            ctx_lens = b.tmp("ctx_from_pos")
-            m.op("scale", {"X": [flat_pos]}, {"Out": [ctx_lens]},
-                 {"scale": 1.0, "bias": 1.0, "bias_after_scale": True})
-        valid = None
-        if mode != "reference":
-            valid = m.live_rows(slot_map, cfg.mla_layers[0], kv_dtype)
+    def feeds(m, f):
+        if hybrid and mode != "reference":
+            # the slot of the sequence (a prompt) or of each row (a decode
+            # batch) in the KDA layers' pools; the padding's is the last
+            add_feed(m.b, f, "state_slots", (1,) if whole else (-1,))
 
-    attrs = m.attn_attrs()
+    def rows(m, f, flat_pos):
+        slot_map, ctx_lens = f.get("slot_mapping"), f.get("context_lens")
+        with m.part("embed"):     # the rows' contexts and liveness
+            if mode == "verify":
+                # a verify row's context ends at its own position
+                ctx_lens = m.tmp("ctx_from_pos")
+                m.op("scale", {"X": [flat_pos]}, {"Out": [ctx_lens]},
+                     {"scale": 1.0, "bias": 1.0, "bias_after_scale": True})
+            valid = None if slot_map is None else live_rows(
+                m, slot_map, _pool(m, cfg.mla_layers[0], kv_dtype))
 
-    def attend(i, q_nope, q_rope, c_kv, k_r):
-        out = b.tmp(f"l{i}_att")
-        if mode != "reference":
-            pool = m.append(i, c_kv, k_r, slot_map, kv_dtype)
-        if whole:
-            m.op("mla_prefill_attention",
-                 {"QNope": [q_nope], "QRope": [q_rope], "CKV": [c_kv],
-                  "KRope": [k_r], "WKVB": [f"dec_l{i}_wkv_b"]},
-                 {"Out": [out]}, attrs)
-        else:
-            m.op("mla_paged_attention",
-                 {"QNope": [q_nope], "QRope": [q_rope], "Cache": [pool],
-                  "BlockTables": [tables], "ContextLens": [ctx_lens],
-                  "WKVB": [f"dec_l{i}_wkv_b"]}, {"Out": [out]}, attrs)
-        return out
+        def mix(i, hn):
+            if cfg.mixer(i) == "kda":
+                return _kda(m, i, hn, mode, valid, f.get("state_slots"),
+                            f["last_index"] if mode == "prefill" else None)
+            return _mla(m, i, hn, flat_pos, kv_dtype, slot_map,
+                        f.get("tables"), ctx_lens)
+        return valid, mix
 
-    def kda(i, hn):
-        return m.kda(i, hn, mode if mode != "reference" else "reference",
-                     valid, state_slots,
-                     last_index if mode == "prefill" else None)
-
-    counts: List[str] = []
-    routes: List[str] = []
-    absent: List[str] = []
-    for i in range(cfg.num_layers):
-        hid = m.block(i, hid, flat_pos, attend, valid, counts, routes,
-                      kda=kda, absent=absent)
-    out_name = close_form(m, prog, hid, last_index if whole else None,
-                          routes, counts, absent, sampling, seeds,
-                          routes_all=hybrid and whole)
-    if mode != "reference":
-        prog._srv_kernel_stats = functools.partial(
-            _form_walk, mode=mode, cfg=cfg, routed=bool(counts))
-    return prog, feeds, [out_name]
+    # no 'chunk' form; with KDA layers no 'verify' form either (a recurrent
+    # state cannot be rolled back), and every row's routing with a prompt
+    prompts = ("reference", "prefill")
+    return build_form(
+        cfg, mode, sampling, kv_dtype, feeds=feeds, rows=rows,
+        walk=_form_walk, routes_all=prompts if hybrid else (),
+        modes=prompts + (("decode",) if hybrid else ("decode", "verify")))
 
 
 def _build_mtp_program(cfg: MLADecoderConfig, sampling, kv_dtype: str):
@@ -964,8 +615,6 @@ def _build_mtp_program(cfg: MLADecoderConfig, sampling, kv_dtype: str):
     prompt (contexts 1, 2, ...) are its causal prefill and the rows of a
     batch its decode step.  Emits each row's draft of the token at ``p +
     2`` (greedy: a draft is a guess, the verify samples)."""
-    from .serving import _emit_head
-
     if not cfg.mtp_layers:
         raise ValueError("this configuration holds no MTP module")
     prog = Program()
@@ -994,28 +643,15 @@ def _build_mtp_program(cfg: MLADecoderConfig, sampling, kv_dtype: str):
              {"Out": [both]}, {"axis": -1})
         hid = m.mm(both, "mtp_proj", "mtp_in")
     layer = cfg.num_layers
-    attrs = m.attn_attrs()
-
-    def attend(i, q_nope, q_rope, c_kv, k_r):
-        pool = m.append(i, c_kv, k_r, slot_map, kv_dtype)
-        out = b.tmp(f"l{i}_att")
-        m.op("mla_paged_attention",
-             {"QNope": [q_nope], "QRope": [q_rope], "Cache": [pool],
-              "BlockTables": [tables], "ContextLens": [ctx_lens],
-              "WKVB": [f"dec_l{i}_wkv_b"]}, {"Out": [out]}, attrs)
-        return out
-
     with m.part("embed"):
-        valid = m.live_rows(slot_map, layer, kv_dtype)
-    counts: List[str] = []
-    hid = m.block(layer, hid, positions, attend, valid, counts)
+        valid = live_rows(m, slot_map, _pool(m, layer, kv_dtype))
+    hid = m.block(layer, hid, lambda i, hn: _mla(
+        m, i, hn, positions, kv_dtype, slot_map, tables, ctx_lens), valid, [])
     with m.part("head"):
         logits = m.mm(m.norm(hid, "mtp_norm_scale", "mtp_fnorm"), "dec_head",
                       "mtp_logits")
         _emit_head(b, logits, "draft_tokens", None, None)
-    prog._srv_params = dict.fromkeys(mla_param_specs(cfg))
-    prog._srv_logits = logits
-    prog._tp_degree = 1
+    prog._form_extras = FormExtras(logits=logits)
     return prog, feeds, ["draft_tokens"]
 
 
@@ -1051,8 +687,6 @@ class MTPDrafter(Proposer):
 
     def _run(self, hidden, tokens, positions, tables, ctx, slots,
              keep_logits: bool = False):
-        from .serving import _pow2_bucket
-
         core, n = self.core, len(tokens)
         width = tables.shape[1]
         npad = _pow2_bucket(max(n, 1))
@@ -1068,7 +702,7 @@ class MTPDrafter(Proposer):
                          ("positions", positions), ("block_tables", tables),
                          ("context_lens", ctx), ("slot_mapping", slots)):
             feed[key][:n] = val
-        fetch = list(self.fetch) + ([self.prog._srv_logits]
+        fetch = list(self.fetch) + ([self.prog._form_extras.logits]
                                     if keep_logits else [])
         out = core.exe.run(self.prog, feed=feed, fetch_list=fetch,
                            scope=core.scope)
@@ -1089,8 +723,6 @@ class MTPDrafter(Proposer):
         """``hidden`` (L, hidden): the prompt's rows.  Row ``i`` pairs with
         the token at ``i + 1`` (the first generated token for the last
         row); the last row's output drafts the second generated token."""
-        from .serving import _pow2_bucket
-
         core, L = self.core, len(req.prompt)
         tokens = list(req.prompt[1:]) + [int(first_token)]
         pos = np.arange(L, dtype=np.int32)
@@ -1106,8 +738,6 @@ class MTPDrafter(Proposer):
         accepting ``accepts[i]`` drafts.  The rows of the positions that
         now hold served tokens enter the MTP layer; the last one's output
         drafts the next step."""
-        from .serving import _pow2_bucket
-
         core = self.core
         alive = set(core.kv.live_sequences())    # a finished one was freed
         live = [(i, st) for i, (st, _d) in enumerate(items)

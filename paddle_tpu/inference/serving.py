@@ -36,27 +36,20 @@ shape bucketing (batch sizes and block-table widths are powers of two,
 prompt lengths power-of-two bucketed), so batch composition never
 recompiles.
 
-The decoder model itself is a standard pre-LN transformer LM built
-three ways from ONE layer description: a full-sequence REFERENCE
-program in the naive attention composition (matmul/softmax/matmul —
-what an exported user model looks like; also the one-at-a-time oracle
-the tests pin token-identity against), a PREFILL program (reference
-body + ``kv_cache_append`` of the prompt's K/V, with
-``fuse_multihead_attention_pass`` applied over it — the serving pass
-pipeline), and the paged DECODE program.
+The engine serves a MODEL DESCRIPTION and never asks which: what it asks
+is ``decoder_program.ServedModel``, what a program form offers a call
+beyond its tokens is ``decoder_program.FormExtras``.  The descriptions
+live in modules of their own (``gpt2_decoder.py``, ``mla_decoder.py``,
+``gqa_decoder.py``), none of which imports this one.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
-import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..framework.core import Program
-from ..framework.dtype import VarType, convert_dtype
 from ..framework.place import CPUPlace
 from ..framework.scope import Scope, scope_guard
 from ..executor import Executor
@@ -65,686 +58,25 @@ from ..utils import chaos
 from ..utils import telemetry as tm
 from ..utils import tracing
 from .admission import RequestRejected, get_policy
+from .decoder_program import (SERVING_TP_AXIS, SERVING_TP_RING_ID,
+                              ServedModel, _pow2_bucket, _sampled)
+# DecoderConfig and decoder_param_specs stay importable from here for
+# benchmark/runners/serve_decoder.py (and the tests that take the GPT-2
+# description from the engine's module); load_decoder_config is
+# ``from_model_dir``'s
+from .gpt2_decoder import (DecoderConfig, decoder_param_specs,  # noqa: F401
+                           load_decoder_config)
 from .kv_cache import KVCacheConfig, PagedKVCache
 from .spec_decode import NGramProposer, Proposer, SamplingParams, \
     get_proposer, rng_lane
 
 __all__ = [
     "DecoderConfig", "Request", "StepEvent", "ServingEngine",
-    "export_decoder", "load_decoder_config",
-    "build_decoder_program", "init_decoder_weights", "RequestRejected",
-    "SamplingParams", "decoder_tp_rules", "validate_tp_degree",
+    "RequestRejected", "SamplingParams",
     "SERVING_TP_AXIS", "SERVING_TP_RING_ID",
 ]
 
 NEG_INF = -1e9  # additive causal-mask value (finite: padded rows stay NaN-free)
-
-# tensor-parallel decode (FLAGS_serving_tp): the mesh axis the decoder
-# shards over, and the dedicated collective ring its allreduces run on
-# (ring 0 belongs to the data-parallel paths — the serving mesh must
-# never capture it)
-SERVING_TP_AXIS = "mp"
-SERVING_TP_RING_ID = 7
-
-
-# ==========================================================================
-# Model description
-# ==========================================================================
-@dataclass(frozen=True)
-class DecoderConfig:
-    vocab_size: int = 128
-    hidden: int = 64
-    num_heads: int = 4
-    num_layers: int = 2
-    ffn_hidden: int = 0          # 0 -> 4 * hidden
-    max_seq_len: int = 256
-    eos_id: int = -1             # -1: no EOS, run to max_new_tokens
-
-    @property
-    def head_dim(self) -> int:
-        return self.hidden // self.num_heads
-
-    @property
-    def ffn(self) -> int:
-        return self.ffn_hidden or 4 * self.hidden
-
-    def to_dict(self) -> dict:
-        return {k: getattr(self, k) for k in (
-            "vocab_size", "hidden", "num_heads", "num_layers",
-            "ffn_hidden", "max_seq_len", "eos_id")}
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "DecoderConfig":
-        return cls(**{k: d[k] for k in cls().to_dict() if k in d})
-
-    # -- the seam: what the engine asks of a model description ------------
-    # (parameter specs, program forms, cache pools).  Another decoder
-    # answers the same questions from a module of its own
-    # (inference/mla_decoder.py); the engine never asks which it serves.
-    param_dtype = "float32"
-
-    def param_specs(self) -> Dict[str, tuple]:
-        return decoder_param_specs(self)
-
-    def init_weights(self, seed: int = 0) -> Dict[str, np.ndarray]:
-        return init_decoder_weights(self, seed)
-
-    def build_program(self, mode: str, sampling=None,
-                      kv_dtype: str = "float32", tp: int = 1) -> tuple:
-        return build_decoder_program(self, mode, sampling=sampling,
-                                     kv_dtype=kv_dtype, tp=tp)
-
-    def validate(self, tp: int = 1, **_served_with) -> None:
-        validate_tp_degree(self, tp)
-
-    def tp_rules(self, kv_dtype: str = "float32") -> Dict[str, tuple]:
-        return decoder_tp_rules(self, kv_dtype=kv_dtype)
-
-    def kv_cache_config(self, num_pages: int, page_size: int,
-                        kv_dtype: str) -> KVCacheConfig:
-        return KVCacheConfig(
-            num_pages=num_pages, page_size=page_size,
-            num_kv_heads=self.num_heads, head_dim=self.head_dim,
-            num_layers=self.num_layers, dtype=kv_dtype)
-
-    def cache_pool_names(self) -> List[str]:
-        """The pool vars of the serving forms, a K and a V a layer."""
-        return [f"kv_{side}_{i}" for i in range(self.num_layers)
-                for side in ("k", "v")]
-
-    def kv_token_bytes(self, kv_dtype: str, tp: int = 1) -> int:
-        """Bytes one token holds in one device's pools, all layers."""
-        return (2 * self.num_layers * (self.num_heads // tp)
-                * self.head_dim * np.dtype(kv_dtype).itemsize)
-
-
-def decoder_param_specs(cfg: DecoderConfig) -> Dict[str, tuple]:
-    """name -> shape for every weight var (shared by all three program
-    forms; the decode/prefill builders re-declare the SAME names so one
-    scope serves them all)."""
-    h, f = cfg.hidden, cfg.ffn
-    specs = {
-        "dec_embed": (cfg.vocab_size, h),
-        "dec_pos_embed": (cfg.max_seq_len, h),
-        "dec_lnf_scale": (h,), "dec_lnf_bias": (h,),
-    }
-    for i in range(cfg.num_layers):
-        p = f"dec_l{i}_"
-        specs.update({
-            p + "ln1_scale": (h,), p + "ln1_bias": (h,),
-            p + "wq": (h, h), p + "wk": (h, h), p + "wv": (h, h),
-            p + "wo": (h, h),
-            p + "ln2_scale": (h,), p + "ln2_bias": (h,),
-            p + "w1": (h, f), p + "w2": (f, h),
-        })
-    return specs
-
-
-def init_decoder_weights(cfg: DecoderConfig, seed: int = 0
-                         ) -> Dict[str, np.ndarray]:
-    rng = np.random.RandomState(seed)
-    out = {}
-    for name, shape in decoder_param_specs(cfg).items():
-        if name.endswith("_scale"):
-            out[name] = np.ones(shape, np.float32)
-        elif name.endswith("_bias"):
-            out[name] = np.zeros(shape, np.float32)
-        else:
-            out[name] = (rng.randn(*shape) / np.sqrt(shape[-1])) \
-                .astype(np.float32)
-    return out
-
-
-# ==========================================================================
-# Program builders
-# ==========================================================================
-class _B:
-    """Tiny block-building helper: explicit var names, direct append_op."""
-
-    #: the part of the model the ops built from here on serve (attr ``part``,
-    #: which ``registry.run_op`` turns into their outermost scope); None,
-    #: as GPT-2's forms have it, adds nothing
-    part: Optional[str] = None
-
-    def __init__(self, program: Program):
-        self.blk = program.global_block()
-        self._n = 0
-
-    def tmp(self, tag: str):
-        self._n += 1
-        return self.blk.create_var(name=f"_srv_{tag}_{self._n}").name
-
-    def feed(self, name, shape, dtype=VarType.FP32):
-        return self.blk.create_var(name=name, shape=shape, dtype=dtype,
-                                   is_data=True).name
-
-    def param(self, name, shape, dtype=VarType.FP32):
-        return self.blk.create_var(name=name, shape=shape, dtype=dtype,
-                                   persistable=True).name
-
-    def op(self, type, inputs, outputs, attrs=None):
-        attrs = attrs or {}
-        if self.part is not None:
-            attrs = {"part": self.part, **attrs}
-        self.blk.append_op(type, inputs=inputs, outputs=outputs, attrs=attrs)
-
-    # common composites --------------------------------------------------
-    def matmul(self, x, y, transpose_Y=False, alpha=1.0, tag="mm"):
-        o = self.tmp(tag)
-        self.op("matmul", {"X": [x], "Y": [y]}, {"Out": [o]},
-                {"transpose_X": False, "transpose_Y": transpose_Y,
-                 "alpha": float(alpha)})
-        return o
-
-    def add(self, x, y, tag="add"):
-        o = self.tmp(tag)
-        self.op("elementwise_add", {"X": [x], "Y": [y]}, {"Out": [o]},
-                {"axis": -1})
-        return o
-
-    def reshape(self, x, shape, tag="rs"):
-        o = self.tmp(tag)
-        self.op("reshape2", {"X": [x]}, {"Out": [o]},
-                {"shape": list(shape)})
-        return o
-
-    def transpose(self, x, perm, tag="tr"):
-        o = self.tmp(tag)
-        self.op("transpose2", {"X": [x]}, {"Out": [o]},
-                {"axis": list(perm)})
-        return o
-
-    def layer_norm(self, x, scale, bias, begin, tag="ln"):
-        o = self.tmp(tag)
-        self.op("layer_norm",
-                {"X": [x], "Scale": [scale], "Bias": [bias]},
-                {"Y": [o], "Mean": [self.tmp(tag + "_m")],
-                 "Variance": [self.tmp(tag + "_v")]},
-                {"begin_norm_axis": begin, "epsilon": 1e-5})
-        return o
-
-    def lookup(self, table, ids, tag="emb"):
-        o = self.tmp(tag)
-        self.op("lookup_table_v2", {"W": [table], "Ids": [ids]},
-                {"Out": [o]})
-        return o
-
-    def gelu(self, x):
-        o = self.tmp("gelu")
-        self.op("gelu", {"X": [x]}, {"Out": [o]})
-        return o
-
-
-def _sampled(sampling) -> bool:
-    return sampling is not None and not sampling.greedy
-
-
-def _emit_head(b: _B, logits: str, out_name: str, sampling,
-               seeds: Optional[str]) -> str:
-    """The token head every program form shares: argmax by default (the
-    bit-identity baseline), the in-program ``sample_token`` op when
-    sampling is armed — sampling params are baked as attrs, the per-row
-    RNG lanes arrive through the ``seeds`` feed."""
-    out = b.blk.create_var(name=out_name, dtype=VarType.INT64).name
-    if _sampled(sampling):
-        b.op("sample_token", {"Logits": [logits], "Seeds": [seeds]},
-             {"Out": [out]},
-             {"temperature": float(sampling.temperature),
-              "top_k": int(sampling.top_k),
-              "top_p": float(sampling.top_p)})
-    else:
-        b.op("arg_max", {"X": [logits]}, {"Out": [out]},
-             {"axis": -1, "keepdims": False, "flatten": False})
-    return out
-
-
-def _kv_pool_params(b: _B, i: int, quant: bool, kv_dtype: str = "float32"):
-    """Declare layer ``i``'s K/V pool vars (plus the int8 scale pools
-    when ``quant``); returns ``(kc, vc, ksc, vsc)`` — scale names are
-    None for unquantized storage, so the default program grows NO new
-    vars (the byte-identity pin).  The pool var descs carry the STORAGE
-    dtype (shape stays (): the runtime pools are scope-priced), so an
-    offline ``progcheck --mem`` of a serialized program can still
-    report what the pool stores."""
-    dt = convert_dtype(kv_dtype)
-    kc = b.param(f"kv_k_{i}", (), dtype=dt)
-    vc = b.param(f"kv_v_{i}", (), dtype=dt)
-    if not quant:
-        return kc, vc, None, None
-    return kc, vc, b.param(f"kv_k_scale_{i}", ()), \
-        b.param(f"kv_v_scale_{i}", ())
-
-
-def _kv_append(b: _B, k3, v3, slot_map, kc, vc, ksc, vsc):
-    """One ``kv_cache_append`` — quantize-on-write when the scale pools
-    ride along (int8 storage)."""
-    ins = {"K": [k3], "V": [v3], "SlotMapping": [slot_map],
-           "KCache": [kc], "VCache": [vc]}
-    outs = {"KCacheOut": [kc], "VCacheOut": [vc]}
-    if ksc is not None:
-        ins["KScale"], ins["VScale"] = [ksc], [vsc]
-        outs["KScaleOut"], outs["VScaleOut"] = [ksc], [vsc]
-    b.op("kv_cache_append", ins, outs)
-
-
-def _kv_gather_deq(b: _B, pool, scale, tables, kv_dtype, tag):
-    """Pool gather for the dense (chunk/verify) attention forms, with
-    the storage-dtype read path: gather pages through the block table,
-    then ``kv_dequant`` back to f32 (int8: the SAME gather applied to
-    the scale pool rides along, so each page meets its own scale).  The
-    f32 path emits the plain gather — byte-identical to the unquantized
-    program.  The gather runs on the pool AS STORED (``KVCacheConfig.
-    pool_shape``: pages on axis 1, a page ``(rows, width)``); the callers'
-    reshape of the GATHERED pages to ``(…, tokens, D)`` reads them back
-    as token rows, both forms being row-major — never a reshape of a
-    pool."""
-    g = b.tmp(tag)
-    b.op("gather", {"X": [pool], "Index": [tables]}, {"Out": [g]},
-         {"axis": 1})
-    if kv_dtype == "float32":
-        return g
-    ins = {"X": [g]}
-    if scale is not None:
-        sg = b.tmp(tag + "_sc")
-        b.op("gather", {"X": [scale], "Index": [tables]}, {"Out": [sg]},
-             {"axis": 1})
-        ins["Scale"] = [sg]
-    dq = b.tmp(tag + "_dq")
-    b.op("kv_dequant", ins, {"Out": [dq]})
-    return dq
-
-
-def validate_tp_degree(cfg: DecoderConfig, tp: int) -> None:
-    """Bugfix rider: reject infeasible TP degrees at engine/program
-    construction with a clear error, instead of a shape crash
-    mid-prefill.  Every sharded dimension — attention/KV heads (the
-    pool's split axis AND the kernel's head grouping), the hidden
-    width, and the MLP width — must divide evenly by ``tp``."""
-    tp = int(tp or 1)
-    if tp < 1:
-        raise ValueError(f"serving_tp must be >= 1, got {tp}")
-    if tp == 1:
-        return
-    bad = []
-    if cfg.num_heads % tp:
-        bad.append(f"num_heads={cfg.num_heads} (the KV pool and the "
-                   f"paged_attention head grouping shard on kv_heads)")
-    if cfg.hidden % tp:
-        bad.append(f"hidden={cfg.hidden}")
-    if cfg.ffn % tp:
-        bad.append(f"ffn={cfg.ffn}")
-    if bad:
-        raise ValueError(
-            f"serving_tp={tp} does not divide " + ", ".join(bad) +
-            "; pick a degree that splits every sharded dim evenly")
-
-
-def decoder_tp_rules(cfg: DecoderConfig, axis: str = SERVING_TP_AXIS,
-                     kv_dtype: str = "float32"
-                     ) -> Dict[str, tuple]:
-    """Regex -> logical-axis spec for the serving decoder, composed
-    from the generic partition-rule constructors
-    (parallel/tensor_parallel.py): Megatron attention-head + MLP
-    column/row sharding per block, hidden-sharded embeddings (the
-    positional table follows the token table so the embed sum stays
-    local), plus the paged KV pools split on their ``kv_heads`` dim
-    (layout ``(kv_heads, pages, page_size, head_dim)``) and the int8
-    scale pools alongside.  LayerNorm scales/biases stay replicated
-    (no rule).  The derivation is pinned against hand-written specs by
-    tests/test_serving_tp.py."""
-    from ..parallel.tensor_parallel import attention_head_rules, \
-        embedding_rules, megatron_mlp_rules
-
-    rules: Dict[str, tuple] = {}
-    rules.update(attention_head_rules(
-        r"dec_l\d+_wq", r"dec_l\d+_wk", r"dec_l\d+_wv", r"dec_l\d+_wo",
-        axis=axis))
-    rules.update(megatron_mlp_rules(
-        [r"dec_l\d+_w1", r"dec_l\d+_w2"], axis=axis))
-    rules.update(embedding_rules("dec_embed", axis=axis, mode="hidden"))
-    rules["dec_pos_embed"] = (None, axis)
-    rules[r"kv_[kv]_\d+"] = (axis, None, None, None)
-    if kv_dtype == "int8":
-        rules[r"kv_[kv]_scale_\d+"] = (axis, None)
-    return {k: tuple(v) for k, v in rules.items()}
-
-
-def build_decoder_program(cfg: DecoderConfig, mode: str,
-                          sampling: Optional[SamplingParams] = None,
-                          kv_dtype: str = "float32", tp: int = 1) -> tuple:
-    """Build one of the program forms; returns
-    ``(program, feed_names, fetch_names)``.
-
-    mode="reference": full-sequence next-token program (naive attention
-      composition) — the export form and the one-at-a-time oracle.
-    mode="prefill":   reference body + kv_cache_append of every prompt
-      position's K/V at allocator-assigned slots.
-    mode="decode":    single-token batched step over the paged cache.
-    mode="chunk":     a SLICE of one prompt at an offset: the chunk's
-      K/V enter the pool at allocator slots, and its attention runs
-      over the POOL-RESIDENT prefix (cached/previous-chunk pages
-      gathered through the sequence's block table) plus the chunk
-      itself — the program form prefix-cache-hit suffixes and chunked
-      prefill share.  The host-built mask carries both the causal
-      structure and the valid-context bound.
-    mode="verify":    the chunk form BATCHED over B sequences — the
-      spec-decode accept-prefix verify kernel.  Each row is one
-      request's ``[last_token, draft...]`` slice; ALL row positions'
-      logits are scored (no last_index), so row j yields the target
-      model's next token after chunk position j — exactly what
-      accept-prefix compares the draft against.  One call scores
-      K+1 positions for the whole batch.
-
-    ``sampling`` (serving forms only): when armed (temperature > 0) the
-    argmax head is replaced by the in-program ``sample_token`` op and
-    the program grows a ``sample_seeds`` RNG-lane feed (one lane per
-    emitted row).  ``None``/greedy builds the exact default programs.
-
-    ``kv_dtype`` (serving forms only; FLAGS_kv_cache_dtype): the KV
-    pool storage dtype.  "float32" (default) builds the exact legacy
-    programs.  "bfloat16" adds a ``kv_dequant`` cast after every pool
-    gather; "int8" also threads the per-(kv_head, page) scale pools
-    through ``kv_cache_append`` (quantize-on-write) and the reads, so
-    attention always accumulates in f32.  The reference form never
-    touches the pool and ignores it.
-
-    ``tp`` > 1 builds the tensor-parallel SHARD body: every head/width
-    reshape bakes the LOCAL head count (``num_heads // tp``) and local
-    context width (``hidden // tp``) — the per-device program each mesh
-    rank runs under shard_map.  The combines (per-block allreduces, the
-    embedding all-gather, the logits split/reduce) are NOT built here;
-    the verifier-bracketed ``serving_tp_pass`` inserts them.  ``tp=1``
-    is byte-identical to the unsharded builder (pinned).
-    """
-    if mode not in ("reference", "prefill", "decode", "chunk", "verify"):
-        raise ValueError(f"bad mode {mode!r}")
-    if kv_dtype not in ("float32", "bfloat16", "int8"):
-        raise ValueError(f"bad kv_dtype {kv_dtype!r}")
-    quant = kv_dtype == "int8"
-    if _sampled(sampling) and mode == "reference":
-        raise ValueError("the reference form is the greedy oracle; "
-                         "sampling applies to serving forms only")
-    tp = int(tp or 1)
-    validate_tp_degree(cfg, tp)
-    # H/h below are the PER-DEVICE head count and attention-context
-    # width (== the global values at tp=1): the sharded body computes
-    # on 1/tp of the heads; full-width sites (residual stream, final
-    # layer norm, hflat) keep cfg.hidden because the inserted
-    # collectives re-assemble the hidden dim before them
-    H, D, h = cfg.num_heads // tp, cfg.head_dim, cfg.hidden
-    hl = h // tp
-    prog = Program()
-    prog._label = mode  # names the compiled step pt_<mode> and its spans
-    b = _B(prog)
-    params = {n: b.param(n, s) for n, s in decoder_param_specs(cfg).items()}
-
-    if mode == "chunk":
-        # NOTE: this branch repeats the decoder body because its
-        # attention reads K/V through a pool gather — a shape the
-        # shared loop below can't express without growing a third
-        # conditional axis.  Any model change must land in both; drift
-        # is NOT silent: the chunked==monolithic token-identity tests
-        # (tests/test_prefix_cache.py) pin the two bodies together.
-        tokens = b.feed("tokens", (1, -1), VarType.INT32)
-        positions = b.feed("positions", (1, -1), VarType.INT32)
-        mask = b.feed("attn_mask", (1, 1, -1, -1), VarType.FP32)
-        last_index = b.feed("last_index", (1,), VarType.INT32)
-        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
-        tables = b.feed("chunk_tables", (-1,), VarType.INT32)
-        feeds = ["tokens", "positions", "attn_mask", "last_index",
-                 "slot_mapping", "chunk_tables"]
-        seeds = None
-        if _sampled(sampling):
-            seeds = b.feed("sample_seeds", (1,), VarType.INT32)
-            feeds.append("sample_seeds")
-        x = b.lookup("dec_embed", tokens)
-        pos = b.lookup("dec_pos_embed", positions)
-        hid = b.add(x, pos, "h0")
-        for i in range(cfg.num_layers):
-            p = f"dec_l{i}_"
-            hn = b.layer_norm(hid, p + "ln1_scale", p + "ln1_bias", 2,
-                              f"l{i}_ln1")
-            q = b.matmul(hn, p + "wq", tag=f"l{i}_q")
-            k = b.matmul(hn, p + "wk", tag=f"l{i}_k")
-            v = b.matmul(hn, p + "wv", tag=f"l{i}_v")
-            # the chunk's K/V enter the pool FIRST, so the gather below
-            # sees prefix AND chunk through one block table
-            k3 = b.reshape(k, [-1, H, D], f"l{i}_k3")
-            v3 = b.reshape(v, [-1, H, D], f"l{i}_v3")
-            kc, vc, ksc, vsc = _kv_pool_params(b, i, quant, kv_dtype)
-            _kv_append(b, k3, v3, slot_map, kc, vc, ksc, vsc)
-            q4 = b.transpose(b.reshape(q, [0, 0, H, D]), [0, 2, 1, 3],
-                             f"l{i}_q4")                 # (1, H, S, D)
-            kg = _kv_gather_deq(b, kc, ksc, tables, kv_dtype,
-                                f"l{i}_kg")      # (H, W) + a stored page
-            k4 = b.reshape(kg, [1, H, -1, D], f"l{i}_k4")  # (1, H, C, D)
-            vg = _kv_gather_deq(b, vc, vsc, tables, kv_dtype,
-                                f"l{i}_vg")
-            v4 = b.reshape(vg, [1, H, -1, D], f"l{i}_v4")
-            s = b.matmul(q4, k4, transpose_Y=True, alpha=D ** -0.5,
-                         tag=f"l{i}_qk")                 # (1, H, S, C)
-            s = b.add(s, mask, f"l{i}_masked")
-            sm = b.tmp(f"l{i}_probs")
-            b.op("softmax", {"X": [s]}, {"Out": [sm]}, {"axis": -1})
-            av = b.matmul(sm, v4, tag=f"l{i}_av")        # (1, H, S, D)
-            ctxv = b.reshape(b.transpose(av, [0, 2, 1, 3]), [0, 0, hl],
-                             f"l{i}_ctx")
-            hid = b.add(hid, b.matmul(ctxv, p + "wo", tag=f"l{i}_o"),
-                        f"l{i}_res1")
-            hn2 = b.layer_norm(hid, p + "ln2_scale", p + "ln2_bias", 2,
-                               f"l{i}_ln2")
-            ff = b.matmul(b.gelu(b.matmul(hn2, p + "w1", tag=f"l{i}_ff1")),
-                          p + "w2", tag=f"l{i}_ff2")
-            hid = b.add(hid, ff, f"l{i}_res2")
-        h2d = b.reshape(hid, [-1, h], "hflat")
-        hid = b.tmp("hlast")
-        b.op("gather", {"X": [h2d], "Index": [last_index]},
-             {"Out": [hid]}, {"axis": 0})
-        hf = b.layer_norm(hid, "dec_lnf_scale", "dec_lnf_bias", 1, "lnf")
-        logits = b.matmul(hf, "dec_embed", transpose_Y=True, tag="logits")
-        out = _emit_head(b, logits, "next_token", sampling, seeds)
-        prog._srv_params = params
-        prog._tp_degree = tp
-        return prog, feeds, [out]
-
-    if mode == "verify":
-        # NOTE: the chunk body again, batched — same drift guard: the
-        # verify==reference logits-parity test (tests/test_spec_decode)
-        # pins this body to the reference composition.
-        tokens = b.feed("tokens", (-1, -1), VarType.INT32)         # (B, S)
-        positions = b.feed("positions", (-1, -1), VarType.INT32)
-        mask = b.feed("attn_mask", (-1, 1, -1, -1), VarType.FP32)  # (B,1,S,C)
-        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)    # (B*S,)
-        tables = b.feed("verify_tables", (-1, -1), VarType.INT32)  # (B, W)
-        feeds = ["tokens", "positions", "attn_mask", "slot_mapping",
-                 "verify_tables"]
-        seeds = None
-        if _sampled(sampling):
-            seeds = b.feed("sample_seeds", (-1,), VarType.INT32)   # (B*S,)
-            feeds.append("sample_seeds")
-        x = b.lookup("dec_embed", tokens)
-        pos = b.lookup("dec_pos_embed", positions)
-        hid = b.add(x, pos, "h0")
-        for i in range(cfg.num_layers):
-            p = f"dec_l{i}_"
-            hn = b.layer_norm(hid, p + "ln1_scale", p + "ln1_bias", 2,
-                              f"l{i}_ln1")
-            q = b.matmul(hn, p + "wq", tag=f"l{i}_q")
-            k = b.matmul(hn, p + "wk", tag=f"l{i}_k")
-            v = b.matmul(hn, p + "wv", tag=f"l{i}_v")
-            # every row's K/V enter the pool first (flattened over the
-            # batch), so the per-row gather sees prefix AND chunk
-            k3 = b.reshape(k, [-1, H, D], f"l{i}_k3")       # (B*S, H, D)
-            v3 = b.reshape(v, [-1, H, D], f"l{i}_v3")
-            kc, vc, ksc, vsc = _kv_pool_params(b, i, quant, kv_dtype)
-            _kv_append(b, k3, v3, slot_map, kc, vc, ksc, vsc)
-            q4 = b.transpose(b.reshape(q, [0, 0, H, D]), [0, 2, 1, 3],
-                             f"l{i}_q4")                    # (B, H, S, D)
-            # per-row block-table gather: the stored pool (H, P, rows,
-            # width) indexed by the (B, W) tables -> (H, B, W, rows,
-            # width) (dequantized back to f32 for quantized storage),
-            # batch-major, flattened to each row's context window
-            kg = _kv_gather_deq(b, kc, ksc, tables, kv_dtype, f"l{i}_kg")
-            k4 = b.reshape(b.transpose(kg, [1, 0, 2, 3, 4]),
-                           [0, 0, -1, D], f"l{i}_k4")       # (B, H, C, D)
-            vg = _kv_gather_deq(b, vc, vsc, tables, kv_dtype, f"l{i}_vg")
-            v4 = b.reshape(b.transpose(vg, [1, 0, 2, 3, 4]),
-                           [0, 0, -1, D], f"l{i}_v4")
-            s = b.matmul(q4, k4, transpose_Y=True, alpha=D ** -0.5,
-                         tag=f"l{i}_qk")                    # (B, H, S, C)
-            s = b.add(s, mask, f"l{i}_masked")
-            sm = b.tmp(f"l{i}_probs")
-            b.op("softmax", {"X": [s]}, {"Out": [sm]}, {"axis": -1})
-            av = b.matmul(sm, v4, tag=f"l{i}_av")           # (B, H, S, D)
-            ctxv = b.reshape(b.transpose(av, [0, 2, 1, 3]), [0, 0, hl],
-                             f"l{i}_ctx")
-            hid = b.add(hid, b.matmul(ctxv, p + "wo", tag=f"l{i}_o"),
-                        f"l{i}_res1")
-            hn2 = b.layer_norm(hid, p + "ln2_scale", p + "ln2_bias", 2,
-                               f"l{i}_ln2")
-            ff = b.matmul(b.gelu(b.matmul(hn2, p + "w1", tag=f"l{i}_ff1")),
-                          p + "w2", tag=f"l{i}_ff2")
-            hid = b.add(hid, ff, f"l{i}_res2")
-        h2d = b.reshape(hid, [-1, h], "hflat")              # (B*S, h)
-        hf = b.layer_norm(h2d, "dec_lnf_scale", "dec_lnf_bias", 1, "lnf")
-        logits = b.matmul(hf, "dec_embed", transpose_Y=True, tag="logits")
-        out = _emit_head(b, logits, "next_tokens", sampling, seeds)
-        prog._srv_params = params
-        prog._srv_logits = logits   # the verify==reference parity hook
-        prog._tp_degree = tp
-        return prog, feeds, [out]
-
-    paged = mode == "decode"
-    if paged:
-        tokens = b.feed("tokens", (-1,), VarType.INT32)
-        positions = b.feed("positions", (-1,), VarType.INT32)
-        tables = b.feed("block_tables", (-1, -1), VarType.INT32)
-        ctx_lens = b.feed("context_lens", (-1,), VarType.INT32)
-        slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
-        feeds = ["tokens", "positions", "block_tables", "context_lens",
-                 "slot_mapping"]
-    else:
-        tokens = b.feed("tokens", (1, -1), VarType.INT32)
-        positions = b.feed("positions", (1, -1), VarType.INT32)
-        mask = b.feed("attn_mask", (1, 1, -1, -1), VarType.FP32)
-        last_index = b.feed("last_index", (1,), VarType.INT32)
-        feeds = ["tokens", "positions", "attn_mask", "last_index"]
-        if mode == "prefill":
-            slot_map = b.feed("slot_mapping", (-1,), VarType.INT32)
-            feeds.append("slot_mapping")
-    seeds = None
-    if _sampled(sampling):
-        # one RNG lane per emitted row: B lanes for the paged decode
-        # batch, a single lane for the prefill's first token
-        seeds = b.feed("sample_seeds", (-1,) if paged else (1,),
-                       VarType.INT32)
-        feeds.append("sample_seeds")
-
-    x = b.lookup("dec_embed", tokens)
-    pos = b.lookup("dec_pos_embed", positions)
-    hid = b.add(x, pos, "h0")
-
-    for i in range(cfg.num_layers):
-        p = f"dec_l{i}_"
-        hn = b.layer_norm(hid, p + "ln1_scale", p + "ln1_bias",
-                          2 if not paged else 1, f"l{i}_ln1")
-        q = b.matmul(hn, p + "wq", tag=f"l{i}_q")
-        k = b.matmul(hn, p + "wk", tag=f"l{i}_k")
-        v = b.matmul(hn, p + "wv", tag=f"l{i}_v")
-        if paged:
-            q3 = b.reshape(q, [0, H, D], f"l{i}_q3")     # (B, H, D)
-            k3 = b.reshape(k, [0, H, D], f"l{i}_k3")
-            v3 = b.reshape(v, [0, H, D], f"l{i}_v3")
-            kc, vc, ksc, vsc = _kv_pool_params(b, i, quant, kv_dtype)
-            _kv_append(b, k3, v3, slot_map, kc, vc, ksc, vsc)
-            att = b.tmp(f"l{i}_att")
-            pa_ins = {"Q": [q3], "KCache": [kc], "VCache": [vc],
-                      "BlockTables": [tables], "ContextLens": [ctx_lens]}
-            if quant:
-                # the kernel dequantizes per page inside its online-
-                # softmax loop — quantized pages never round-trip
-                # through a dense f32 gather
-                pa_ins["KScale"], pa_ins["VScale"] = [ksc], [vsc]
-            b.op("paged_attention", pa_ins,
-                 {"Out": [att]}, {"scale": float(D ** -0.5)})
-            ctxv = b.reshape(att, [0, hl], f"l{i}_ctx")
-        else:
-            # the NAIVE composition on (1, S, h): 4-D q/k/v + the
-            # matmul/softmax/matmul chain fuse_multihead_attention_pass
-            # rewrites to the flash op
-            q4 = b.transpose(b.reshape(q, [0, 0, H, D]), [0, 2, 1, 3],
-                             f"l{i}_q4")
-            k4 = b.transpose(b.reshape(k, [0, 0, H, D]), [0, 2, 1, 3],
-                             f"l{i}_k4")
-            v4 = b.transpose(b.reshape(v, [0, 0, H, D]), [0, 2, 1, 3],
-                             f"l{i}_v4")
-            if mode == "prefill":
-                # the prompt's K/V enter the pool HERE, at allocator
-                # slots; padded bucket positions carry the drop sentinel
-                k3 = b.reshape(k, [-1, H, D], f"l{i}_k3")
-                v3 = b.reshape(v, [-1, H, D], f"l{i}_v3")
-                kc, vc, ksc, vsc = _kv_pool_params(b, i, quant, kv_dtype)
-                _kv_append(b, k3, v3, slot_map, kc, vc, ksc, vsc)
-            s = b.matmul(q4, k4, transpose_Y=True, alpha=D ** -0.5,
-                         tag=f"l{i}_qk")
-            s = b.add(s, mask, f"l{i}_masked")
-            sm = b.tmp(f"l{i}_probs")
-            b.op("softmax", {"X": [s]}, {"Out": [sm]}, {"axis": -1})
-            av = b.matmul(sm, v4, tag=f"l{i}_av")
-            ctxv = b.reshape(b.transpose(av, [0, 2, 1, 3]), [0, 0, hl],
-                             f"l{i}_ctx")
-        hid = b.add(hid, b.matmul(ctxv, p + "wo", tag=f"l{i}_o"),
-                    f"l{i}_res1")
-        hn2 = b.layer_norm(hid, p + "ln2_scale", p + "ln2_bias",
-                           2 if not paged else 1, f"l{i}_ln2")
-        ff = b.matmul(b.gelu(b.matmul(hn2, p + "w1", tag=f"l{i}_ff1")),
-                      p + "w2", tag=f"l{i}_ff2")
-        hid = b.add(hid, ff, f"l{i}_res2")
-
-    if not paged:
-        # last REAL position's hidden row (feed-indexed: bucket padding
-        # never reaches the logits)
-        h2d = b.reshape(hid, [-1, h], "hflat")
-        hid = b.tmp("hlast")
-        b.op("gather", {"X": [h2d], "Index": [last_index]},
-             {"Out": [hid]}, {"axis": 0})
-    hf = b.layer_norm(hid, "dec_lnf_scale", "dec_lnf_bias", 1, "lnf")
-    logits = b.matmul(hf, "dec_embed", transpose_Y=True, tag="logits")
-    out_name = "next_tokens" if paged else "next_token"
-    _emit_head(b, logits, out_name, sampling, seeds)
-    prog._srv_params = params  # introspection/debug
-    prog._srv_logits = logits  # the verify==reference parity hook
-    prog._tp_degree = tp
-    return prog, feeds, [out_name]
-
-
-# ==========================================================================
-# Export / load ("the converted decoder")
-# ==========================================================================
-def export_decoder(model_dir: str, cfg: DecoderConfig, seed: int = 0,
-                   weights: Optional[Dict[str, np.ndarray]] = None) -> None:
-    """Export the decoder in its REFERENCE form (naive attention
-    composition — what a converted/exported user model looks like) plus
-    a ``decoder.json`` sidecar so the serving engine can rebuild the
-    prefill/decode forms around the same weights."""
-    prog, feeds, fetches = build_decoder_program(cfg, "reference")
-    scope = Scope()
-    for name, arr in (weights or init_decoder_weights(cfg, seed)).items():
-        scope.set(name, arr)
-    exe = Executor(CPUPlace())
-    from .. import io as pt_io
-
-    with scope_guard(scope):
-        pt_io.save_inference_model(
-            model_dir, feeds, [prog.global_block().var(fetches[0])], exe,
-            main_program=prog)
-    with open(os.path.join(model_dir, "decoder.json"), "w") as f:
-        json.dump(cfg.to_dict(), f)
-
-
-def load_decoder_config(model_dir: str) -> DecoderConfig:
-    with open(os.path.join(model_dir, "decoder.json")) as f:
-        return DecoderConfig.from_dict(json.load(f))
 
 
 # ==========================================================================
@@ -1004,13 +336,6 @@ def _trace_finish(req: Request, now: float):
             prompt_tokens=len(req.prompt))
 
 
-def _pow2_bucket(n: int, lo: int = 1, hi: Optional[int] = None) -> int:
-    b = lo
-    while b < n:
-        b *= 2
-    return min(b, hi) if hi is not None else b
-
-
 _MASK_CACHE: Dict[int, np.ndarray] = {}
 
 
@@ -1090,7 +415,7 @@ def _board_fns():
     return _BOARD_FNS
 
 
-def _reject_unservable(req: Request, cfg: DecoderConfig,
+def _reject_unservable(req: Request, cfg: ServedModel,
                        kv_config: KVCacheConfig):
     """Shared submit-time gate: a request that cannot complete even
     with the whole pool to itself would hang any scheduler (prefill
@@ -1128,7 +453,7 @@ class _EngineCore:
     """Programs + scope + executor + KV pools, shared by the continuous
     and static drivers (one model, two scheduling policies)."""
 
-    def __init__(self, cfg: DecoderConfig, weights: Dict[str, np.ndarray],
+    def __init__(self, cfg: ServedModel, weights: Dict[str, np.ndarray],
                  num_pages: int = 64, page_size: int = 16,
                  place=None, use_mha_fusion: bool = True,
                  prefill_bucket_min: int = 16,
@@ -1203,8 +528,7 @@ class _EngineCore:
         # cache manager then hands a sequence a slot with its first pages.
         # A slot a sequence of the engine's full batch (``max_batch``):
         # fewer would only cap the batch, more would never be owned
-        self._state_specs = getattr(cfg, "state_pool_specs",
-                                    lambda n: {})(int(max_batch))
+        self._state_specs = cfg.state_pool_specs(int(max_batch))
         if self._state_specs:
             if int(max_batch) < 1:
                 raise ValueError("this model keeps a state a sequence: the "
@@ -1216,9 +540,7 @@ class _EngineCore:
         # what the engine's full batch can: its most pages a sequence
         # (``window_pages_per_seq``) times ``max_batch``, so a running
         # sequence never waits for a window page
-        self._window_pools = frozenset(
-            getattr(cfg, "window_pool_names", list)()) \
-            if self.kv_config.window else frozenset()
+        self._window_pools = frozenset(cfg.window_pool_names())
         if self.kv_config.window:
             if int(max_batch) < 1:
                 raise ValueError("this model keeps a window group of pages: "
@@ -1252,7 +574,7 @@ class _EngineCore:
         self._absent_pending: list = []
         self.moe_calls: Optional[list] = None   # a list: every call's counts
         # what a program's kernels report of their own work, summed by
-        # phase (``prog._srv_kernel_stats``): host integers, no device read
+        # phase (``FormExtras.kernel_stats``): host integers, no device read
         self.kernel_stats: Dict[str, Dict[str, int]] = {}
         self._chunk = None   # (prog, feeds, fetch) — built on first use
         # (begin, end) of the last engine/decode span when a request in
@@ -1807,29 +1129,30 @@ class _EngineCore:
 
     def _run(self, prog, feed, fetch, phase: str):
         """One call of a serving form.  A program may offer more than its
-        tokens (``_srv_hidden``, ``_srv_score``, ``_srv_routes``,
-        ``_srv_counts``: the MLA decoder's forms do, GPT-2's offer none and
-        are run exactly as before).  What is offered and wanted rides on
-        the same call and STAYS ON THE DEVICE (``self.last``, and the logs
-        below): a call's one host read is its tokens, as ever.  A form
-        that knows what its kernels will walk says so from the feed
-        (``_srv_kernel_stats``): summed by phase into ``kernel_stats``."""
-        walk = getattr(prog, "_srv_kernel_stats", None)
-        if walk is not None:
-            self._note_kernel_stats(phase, walk(feed, self.kv_config))
+        tokens (its ``FormExtras``: the expert decoders' forms do, GPT-2's
+        offer their logits alone and are run exactly as before).  What is
+        offered and wanted rides on the same call and STAYS ON THE DEVICE
+        (``self.last``, and the logs below): a call's one host read is its
+        tokens, as ever.  A form that knows what its kernels will walk says
+        so from the feed (``kernel_stats``): summed by phase into
+        ``self.kernel_stats``."""
+        offers = prog._form_extras
+        if offers.kernel_stats is not None:
+            self._note_kernel_stats(
+                phase, offers.kernel_stats(feed, self.kv_config))
         extras = {}
-        if self.keep_hidden and getattr(prog, "_srv_hidden", None):
-            extras["hidden"] = prog._srv_hidden
-        if self.keep_scores and getattr(prog, "_srv_score", None):
-            extras["score"] = prog._srv_score
-            if getattr(prog, "_srv_routes", None):
-                extras["routes"] = prog._srv_routes
-            if getattr(prog, "_srv_routes_all", None):
-                extras["routes_all"] = prog._srv_routes_all
-        if getattr(prog, "_srv_counts", None):
-            extras["counts"] = prog._srv_counts
-            if getattr(prog, "_srv_absent", None):
-                extras["absent"] = prog._srv_absent
+        if self.keep_hidden and offers.hidden:
+            extras["hidden"] = offers.hidden
+        if self.keep_scores and offers.score:
+            extras["score"] = offers.score
+            if offers.routes:
+                extras["routes"] = offers.routes
+            if offers.routes_all:
+                extras["routes_all"] = offers.routes_all
+        if offers.counts:
+            extras["counts"] = offers.counts
+            if offers.absent:
+                extras["absent"] = offers.absent
         if not extras and self.board is None:
             self.last = {}
             return self.exe.run(prog, feed=feed, fetch_list=fetch,
@@ -1986,7 +1309,8 @@ class _EngineCore:
         """The reference program's next-token logits after ``seq`` —
         what parity checks compare where an argmax could flip on a
         near-tie."""
-        out = self._reference_run(seq, [self.ref_prog._srv_logits])
+        out = self._reference_run(seq,
+                                  [self.ref_prog._form_extras.logits])
         return np.asarray(out[0]).reshape(-1)
 
     def greedy_reference(self, prompt: Sequence[int],
@@ -2078,7 +1402,7 @@ class ServingEngine:
     preemption on pool exhaustion — so a seeded trace replays
     bit-identically (pinned by test)."""
 
-    def __init__(self, cfg: Optional[DecoderConfig] = None,
+    def __init__(self, cfg: Optional[ServedModel] = None,
                  weights: Optional[Dict[str, np.ndarray]] = None,
                  model_dir: Optional[str] = None,
                  max_batch: int = 8, token_budget: int = 256,

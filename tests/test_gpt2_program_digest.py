@@ -14,8 +14,8 @@ import json
 
 import pytest
 
-from paddle_tpu.inference.serving import (DecoderConfig, ServingEngine,
-                                          build_decoder_program)
+from paddle_tpu.inference.gpt2_decoder import build_decoder_program
+from paddle_tpu.inference.serving import DecoderConfig, ServingEngine
 
 MODES = ("reference", "prefill", "decode", "chunk", "verify")
 GPT2_SMALL = DecoderConfig(vocab_size=50257, hidden=768, num_heads=12,

@@ -35,10 +35,10 @@ jnp = pytest.importorskip("jax.numpy")
 
 from paddle_tpu.framework import memory_plan as mp
 from paddle_tpu.inference.kv_cache import KVCacheConfig, PagedKVCache
+from paddle_tpu.inference.gpt2_decoder import init_decoder_weights
 from paddle_tpu.inference.serving import (DecoderConfig, Request,
                                           ServingEngine, _EngineCore,
-                                          _fork_copy_fn,
-                                          init_decoder_weights)
+                                          _fork_copy_fn)
 from paddle_tpu.ops import paged_ops
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.ops import registry as op_registry

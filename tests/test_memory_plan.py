@@ -535,8 +535,8 @@ def test_kv_pool_is_fixed_resident_block(tiny_engine=None):
     """The serving decode program's K/V pools model as a fixed
     kv_pool-class resident block equal to the engine's
     kv_pool_resident_bytes."""
-    from paddle_tpu.inference.serving import (DecoderConfig, _EngineCore,
-                                              init_decoder_weights)
+    from paddle_tpu.inference.gpt2_decoder import init_decoder_weights
+    from paddle_tpu.inference.serving import DecoderConfig, _EngineCore
 
     cfg = DecoderConfig(vocab_size=32, hidden=16, num_heads=2,
                         num_layers=2, max_seq_len=32)

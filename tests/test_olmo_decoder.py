@@ -371,8 +371,9 @@ def test_pools_are_pages_for_the_full_layers_and_slots_for_the_linear():
 def test_a_dense_decoder_carries_no_counts_and_no_routes():
     for mode in ("reference", "prefill", "decode"):
         prog = TINY.build_program(mode)[0]
-        assert prog._srv_counts is None and prog._srv_routes is None
-        assert prog._srv_absent is None and prog._srv_routes_all is None
+        offers = prog._form_extras
+        assert offers.counts is None and offers.routes is None
+        assert offers.absent is None and offers.routes_all is None
         kinds = {op.type for op in prog.global_block().ops}
         assert "gdn_mixer" in kinds and not kinds & {"moe_router",
                                                      "moe_experts",

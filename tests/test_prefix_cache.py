@@ -454,8 +454,8 @@ def test_pool_spike_never_seizes_live_shared_prefix():
 # ==========================================================================
 def test_kv_pool_block_counts_shared_pages_once():
     from paddle_tpu.framework import memory_plan as mp
-    from paddle_tpu.inference.serving import (_EngineCore,
-                                              init_decoder_weights)
+    from paddle_tpu.inference.gpt2_decoder import init_decoder_weights
+    from paddle_tpu.inference.serving import _EngineCore
 
     cfg = DecoderConfig(vocab_size=32, hidden=16, num_heads=2,
                         num_layers=2, max_seq_len=64)

@@ -346,7 +346,7 @@ def test_compile_counters_move_on_a_miss_and_not_for_a_plain_jit():
     ("reference", "pt_reference")])
 def test_serving_programs_carry_their_form_as_label(mode, label):
     from paddle_tpu.executor import program_label
-    from paddle_tpu.inference.serving import build_decoder_program
+    from paddle_tpu.inference.gpt2_decoder import build_decoder_program
 
     prog, _feeds, _fetch = build_decoder_program(CFG, mode)
     assert "pt_" + program_label(prog) == label

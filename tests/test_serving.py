@@ -28,10 +28,10 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.inference.kv_cache import KVCacheConfig, PagedKVCache
-from paddle_tpu.inference.serving import (
-    DecoderConfig, Request, ServingEngine, export_decoder,
-    load_decoder_config,
+from paddle_tpu.inference.gpt2_decoder import (
+    DecoderConfig, export_decoder, load_decoder_config,
 )
+from paddle_tpu.inference.serving import Request, ServingEngine
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.ops.registry import eager_call
 

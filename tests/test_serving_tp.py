@@ -29,10 +29,12 @@ import pytest
 
 from paddle_tpu.framework import unique_name
 from paddle_tpu.framework.ir import get_pass
+from paddle_tpu.inference.gpt2_decoder import (
+    build_decoder_program, decoder_tp_rules, validate_tp_degree,
+)
 from paddle_tpu.inference.serving import (
     SERVING_TP_AXIS, SERVING_TP_RING_ID, DecoderConfig, Request,
-    ServingEngine, build_decoder_program, decoder_tp_rules,
-    validate_tp_degree,
+    ServingEngine,
 )
 from paddle_tpu.utils import flags as F
 
@@ -220,8 +222,8 @@ def test_capacity_scales_tp_x_at_fixed_budget():
 @pytest.mark.parametrize("kv_dtype", ["float32", "bfloat16", "int8"])
 def test_planner_tp_division_reconciles_with_census(kv_dtype):
     from paddle_tpu.framework import memory_plan as mp
-    from paddle_tpu.inference.serving import (_EngineCore,
-                                              init_decoder_weights)
+    from paddle_tpu.inference.gpt2_decoder import init_decoder_weights
+    from paddle_tpu.inference.serving import _EngineCore
 
     cfg = DecoderConfig(vocab_size=32, hidden=16, num_heads=2,
                         num_layers=2, max_seq_len=32)

@@ -39,9 +39,9 @@ from paddle_tpu.framework import verifier
 from paddle_tpu.framework.core import Program
 from paddle_tpu.framework.dtype import VarType
 from paddle_tpu.framework.ir import get_pass
-from paddle_tpu.inference.serving import (SERVING_TP_RING_ID,
-                                          DecoderConfig,
-                                          build_decoder_program)
+from paddle_tpu.inference.gpt2_decoder import (DecoderConfig,
+                                               build_decoder_program)
+from paddle_tpu.inference.serving import SERVING_TP_RING_ID
 from paddle_tpu.parallel import mesh as mesh_mod
 from paddle_tpu.utils import flags as _flags
 

@@ -325,7 +325,7 @@ def test_verify_logits_match_reference():
                 # re-fetch the logits under the same feed (the KV
                 # append rewrites identical values into the same slots)
                 rec["logits"] = np.asarray(orig_run(
-                    prog, feed=feed, fetch_list=[prog._srv_logits],
+                    prog, feed=feed, fetch_list=[prog._form_extras.logits],
                     scope=scope)[0])
                 rec["S"] = _pow2_bucket(max(1 + len(d) for _, d in items))
                 core.exe.run = orig_run

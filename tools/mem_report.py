@@ -238,8 +238,9 @@ def serving_kv_rows(tp: int = 2):
     the global bytes), and the pages the budget buys must scale exactly
     tp x (the capacity headline)."""
     from paddle_tpu.framework import memory_plan as mp
-    from paddle_tpu.inference.serving import (DecoderConfig, _EngineCore,
-                                              init_decoder_weights)
+    from paddle_tpu.inference.gpt2_decoder import (DecoderConfig,
+                                                   init_decoder_weights)
+    from paddle_tpu.inference.serving import _EngineCore
 
     cfg = DecoderConfig(vocab_size=32, hidden=16, num_heads=2,
                         num_layers=2, max_seq_len=32)
