@@ -141,6 +141,26 @@ SIZES = {
                      weights_dtype="bfloat16"),
             num_pages=512, page_size=16, token_budget=4224, max_batch=8,
             prompts=[300, 20, 280, 31, 2100], new_tokens=8, pad_to=4096),
+        # LongCat-Flash-Chat's cut at published widths and one layer of
+        # depth: two MLA sub-layers of 64 heads with both low-rank scales,
+        # two dense halves of 12,288, 16 held of 512 routed experts beside
+        # 256 identity experts, top-12 by softmax, an eighth of the
+        # vocabulary (2.9 GB of bfloat16)
+        "longcat": dict(
+            cfg=dict(vocab_size=16384, hidden=6144, num_heads=64,
+                     num_layers=1, first_k_dense=0, intermediate=12288,
+                     moe_intermediate=2048, n_routed_experts=512,
+                     experts_held=16, n_shared_experts=0,
+                     num_experts_per_tok=12, q_lora_rank=1536,
+                     kv_lora_rank=512, qk_nope_head_dim=128,
+                     qk_rope_head_dim=64, v_head_dim=128, rope_theta=1e7,
+                     rms_norm_eps=1e-5, routed_scaling_factor=6.0,
+                     norm_topk_prob=False, shortcut=True,
+                     router_scoring="softmax", zero_experts=256,
+                     scale_q_lora=True, scale_kv_lora=True, max_seq_len=2048,
+                     weights_dtype="bfloat16"),
+            num_pages=512, page_size=16, token_budget=2048, max_batch=8,
+            prompts=[300, 20, 280, 31, 1100], new_tokens=8),
     },
     "tiny": {
         "resnet": dict(depth=18, image=32, classes=10, batch=8, steps=5,
@@ -208,6 +228,19 @@ SIZES = {
                      max_seq_len=256, weights_dtype="bfloat16"),
             num_pages=64, page_size=8, token_budget=256, max_batch=4,
             prompts=[40, 5, 36, 9, 150], new_tokens=6, pad_to=256),
+        "longcat": dict(
+            cfg=dict(vocab_size=256, hidden=128, num_heads=8, num_layers=2,
+                     first_k_dense=0, intermediate=256, moe_intermediate=128,
+                     n_routed_experts=8, experts_held=2, n_shared_experts=0,
+                     num_experts_per_tok=3, q_lora_rank=64, kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     rope_theta=1e7, rms_norm_eps=1e-5,
+                     routed_scaling_factor=6.0, norm_topk_prob=False,
+                     shortcut=True, router_scoring="softmax", zero_experts=4,
+                     scale_q_lora=True, scale_kv_lora=True, max_seq_len=128,
+                     weights_dtype="bfloat16"),
+            num_pages=64, page_size=16, token_budget=128, max_batch=4,
+            prompts=[40, 5, 36, 9, 70], new_tokens=6),
     },
 }
 
@@ -217,6 +250,9 @@ MLA_LOGIT_ABS_TOL, MLA_ROUTE_SLACK_TOL = 0.06, 0.008
 # the hybrid decoder's, the reference routed as the engine was on the prompt's
 # rows too: the limits of benchmark/configs/kimi-linear-48b-a3b.json
 HYBRID_LOGIT_ABS_TOL, HYBRID_ROUTE_SLACK_TOL = 0.06, 0.008
+# the shortcut-connected decoder's: the limits of
+# benchmark/configs/longcat-flash-chat.json, the slack in units of 1 / 768
+LONGCAT_LOGIT_ABS_TOL, LONGCAT_ROUTE_SLACK_TOL = 0.06, 0.05
 # the grouped-query decoder's: the limits of benchmark/configs/laguna-xs2.json
 GQA_LOGIT_ABS_TOL, GQA_ROUTE_SLACK_TOL = 0.06, 0.008
 # the Olmo-Hybrid-shaped decoder's (no router): the limit of
@@ -1157,6 +1193,99 @@ def phase_hybrid(ctx):
         **ctx.memory())
 
 
+def phase_longcat(ctx):
+    """The shortcut-connected decoder with zero-computation experts (two MLA
+    sub-layers and two dense halves a layer, the expert layer across them,
+    a 1/32 share of the routed experts) through ServingEngine: its kernels
+    in the lowered programs, no operation of latent-pool size in the
+    compiled ones but the in-place writes, two ``moe_gmm`` calls a layer, the
+    served logits against the plain reference, the choices it counts by
+    kind, and pipelined steps leaving greedy tokens unchanged."""
+    import importlib.util
+
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from paddle_tpu.inference.mla_decoder import (MLADecoderConfig,
+                                                  init_mla_weights)
+
+    jax = ctx.jax
+    phase, size = "serve/longcat", ctx.sizes["longcat"]
+    cfg = MLADecoderConfig(**size["cfg"])
+    spec = importlib.util.spec_from_file_location(
+        "reference_longcat", os.path.join(os.path.dirname(os.path.abspath(
+            __file__)), "benchmark", "reference", "longcat-flash-chat.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+    weights = {n: jax.device_put(w, ctx.device)
+               for n, w in init_mla_weights(cfg, 0).items()}
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in size["prompts"]]
+
+    def drive(**kw):
+        return serve_prompts(ctx, cfg, weights, size, prompts, **kw)
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        mark = ctx.watch.mark()
+        compiled_before = set(ctx.watch.compiled_texts())
+        plain, reqs = drive()
+        seen, modules = ctx.watch.since(mark)
+        kernels = ctx.require_kernels(
+            phase, modules, ["mla_decode", "latent_append", "moe_gmm"])
+        in_place = ctx.require_pool_in_place(
+            phase, plain.core.kv_config,
+            n_pools=len(cfg.cache_pool_names()), append="latent_append")
+        gmm_calls = ctx.require_gmm_calls(phase, (cfg.num_layers,),
+                                          compiled_before)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    full = ctx.sizes is SIZES["full"]
+    worst, slack = 0.0, 0.0
+    for r in reqs:
+        got, routes = plain.core.served_scores(r.req_id)
+        ref = reference.served_token_scores(
+            weights, cfg.source_config(), r.prompt, r.out_tokens, routes,
+            pad_to=2048 if full else 128)
+        worst = max(worst, float(np.abs(got[:, 0] - ref["logit"]).max()),
+                    float(np.abs(got[:, 1] - ref["lse"]).max()))
+        slack = max(slack, float(ref["slack"].max()))
+    if full and (worst > LONGCAT_LOGIT_ABS_TOL
+                 or slack > LONGCAT_ROUTE_SLACK_TOL):
+        raise RuntimeError(
+            f"{phase}: served logits lie {worst} from the reference (limit "
+            f"{LONGCAT_LOGIT_ABS_TOL}), routing slack {slack} (limit "
+            f"{LONGCAT_ROUTE_SLACK_TOL})")
+    gmm = gmm_walk(phase, plain)
+    stats, moe = plain.stats, plain.core.moe_stats
+    choices = {p: {k: v for k, v in st.items() if k.startswith("choices_")}
+               for p, st in moe.items()}
+    if not 0 < choices["prefill"]["choices_identity"] \
+            < choices["prefill"]["choices_all"]:
+        raise RuntimeError(f"{phase}: the identity experts' choices are not "
+                           f"counted: {choices}")
+    del plain
+    gc.collect()
+    piped, piped_reqs = drive(pipeline=2)
+    if [r.out_tokens for r in piped_reqs] != [r.out_tokens for r in reqs]:
+        raise RuntimeError(f"{phase}: pipelined steps changed the tokens "
+                           f"served: {[r.out_tokens for r in piped_reqs]} "
+                           f"vs {[r.out_tokens for r in reqs]}")
+    del piped
+    gc.collect()
+    say(phase=phase, **{k: v for k, v in size["cfg"].items()},
+        num_pages=size["num_pages"], prompts=size["prompts"],
+        new_tokens=size["new_tokens"], scheduler=stats, **seen,
+        kernel_calls=kernels, **in_place, moe_gmm_calls_a_program=gmm_calls,
+        moe_gmm_prefill_walk=gmm, choices=choices,
+        served_logits_worst_gap=worst, route_slack=slack,
+        pipeline="tokens identical with pipelined steps on and off",
+        **ctx.memory())
+
+
 def phase_gqa(ctx):
     """The grouped-query decoder with window layers (K and V pools in two
     groups of pages, the window layers' freed behind the window) through
@@ -1413,7 +1542,7 @@ def main(argv=None):
     ap.add_argument("--only", default=None,
                     help="comma-separated phases to run instead of all of "
                          "the chip count's (resnet, bert, serve, mla, hybrid, "
-                         "gqa, olmo, dp4, tp4)")
+                         "gqa, olmo, longcat, dp4, tp4)")
     ap.add_argument("--rehearse-on-cpu", action="store_true",
                     help="skip the TPU assertion (and the device's memory "
                          "counters): a rehearsal, never a result")
@@ -1437,7 +1566,7 @@ def main(argv=None):
             note="smoke observations, not benchmark metrics")
         phases = (phase_dp4, phase_tp4) if args.chips == 4 else \
             (phase_resnet, phase_bert, phase_serve, phase_mla, phase_hybrid,
-             phase_gqa, phase_olmo)
+             phase_gqa, phase_olmo, phase_longcat)
         if args.only:
             phases = [globals()["phase_" + name]
                       for name in args.only.split(",")]

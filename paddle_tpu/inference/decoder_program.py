@@ -15,7 +15,9 @@ module imports neither.  A new model's author starts here:
   program as ``_form_extras``.
 * :func:`build_form` builds one form of a decoder whose layers are
   :meth:`_MB.block`: ``h = x + Mix(RMSNorm(x))``, ``y = h +
-  FFN(RMSNorm(h))``, the feed-forward half a SwiGLU or the routed experts.
+  FFN(RMSNorm(h))``, the feed-forward half a SwiGLU or the routed experts
+  (or, where the description says ``shortcut``, :meth:`_MB.shortcut_pair`:
+  two such sub-blocks with the expert layer on a shortcut across them).
   The model gives its own feeds, its live-row mask and ONE hook,
   ``mix(i, x)``, the mixer of layer ``i`` before its ``wo`` (latent
   attention, KDA, grouped-query attention full or windowed, Gated
@@ -111,6 +113,8 @@ class FormExtras(NamedTuple):
     routes_all: Optional[str] = None   # (expert layers, rows, k)
     counts: Optional[str] = None       # (expert layers, experts)
     absent: Optional[str] = None       # (expert layers,): rows held elsewhere
+    #: (expert layers, 3): choices on held experts, on identity experts, all
+    choices: Optional[str] = None
     #: ``(feed, kv_config) -> counts`` of what the call's kernels walk
     kernel_stats: Optional[Callable] = None
 
@@ -340,7 +344,8 @@ class _MB:
         self.op("swiglu", {"Gate": [g], "Up": [u]}, {"Out": [a]})
         return self.mm(a, down, tag + "_d")
 
-    def block(self, i, hid, mix, valid, counts, routes=None, absent=None):
+    def block(self, i, hid, mix, valid, counts, routes=None, absent=None,
+              choices=None):
         """One block over rows ``hid`` (n, hidden): pre-norm, ``h = x +
         Mix(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, or where the
         description says ``norm_after`` the same two scales on the OUTPUTS,
@@ -350,7 +355,22 @@ class _MB:
         None) marks the rows that are real tokens; an expert layer appends
         its per-expert counts to ``counts`` (and, where it holds a share of
         its experts, the number of rows none of whose experts it holds to
-        ``absent``)."""
+        ``absent``; where it has identity experts, its choices by kind to
+        ``choices``).  Where the description says ``shortcut`` layer ``i`` is
+        :meth:`shortcut_pair`."""
+        cfg, b = self.cfg, self.b
+        if getattr(cfg, "shortcut", False):
+            return self.shortcut_pair(i, hid, mix, valid, counts, routes,
+                                      absent, choices)
+        hid = self._mixed(i, hid, mix)
+        dense = i < cfg.first_k_dense
+        with self.part("dense_ffn" if dense else "moe_part"):
+            return b.add(hid, self._ffn(i, hid, dense, valid, counts, routes,
+                                        absent, choices), f"l{i}_res2")
+
+    def _mixed(self, i, hid, mix):
+        """``hid`` plus (sub-)layer ``i``'s mixer over it, under the mixer's
+        part."""
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
         with self.part(MIXER_PARTS[cfg.mixer(i)]):
             if getattr(cfg, "norm_after", False):
@@ -359,16 +379,47 @@ class _MB:
             else:
                 hn = self.norm(hid, p + "attn_norm_scale", f"l{i}_an")
                 out = self.mm(mix(i, hn), p + "wo", f"l{i}_o")
-            hid = b.add(hid, out, f"l{i}_res1")
-        dense = i < cfg.first_k_dense
-        with self.part("dense_ffn" if dense else "moe_part"):
-            return b.add(hid, self._ffn(i, hid, dense, valid, counts, routes,
-                                        absent), f"l{i}_res2")
+            return b.add(hid, out, f"l{i}_res1")
 
-    def _ffn(self, i, hid, dense, valid, counts, routes, absent):
+    def shortcut_pair(self, layer, hid, mix, valid, counts, routes, absent,
+                      choices):
+        """A layer of a shortcut-connected model (arXiv:2509.01322): two
+        sub-blocks ``2 * layer`` and ``2 * layer + 1``, each a mixer and a
+        dense SwiGLU with weights, norms and cache rows of its own, and ONE
+        expert layer that reads the first sub-block's normed stream and is
+        added after the second's dense half, so that it has the second mixer
+        and two dense halves to run beside::
+
+            h1 = x  + Mix_0(RMSNorm(x))      n1 = RMSNorm(h1)
+            m  = Experts(n1)
+            h2 = h1 + SwiGLU_0(n1)
+            h3 = h2 + Mix_1(RMSNorm(h2))     n3 = RMSNorm(h3)
+            y  = h3 + SwiGLU_1(n3) + m
+        """
+        b, first, second = self.b, 2 * layer, 2 * layer + 1
+        p = f"dec_l{first}_"
+        h1 = self._mixed(first, hid, mix)
+        with self.part("dense_ffn"):
+            n1 = self.norm(h1, p + "ffn_norm_scale", f"l{first}_fn")
+        with self.part("moe_part"):
+            m = self._experts(first, n1, valid, counts, routes, absent,
+                              choices)
+        with self.part("dense_ffn"):
+            h2 = b.add(h1, self.swiglu_ffn(
+                n1, p + "w_gate", p + "w_up", p + "w_down", f"l{first}_ff"),
+                f"l{first}_res2")
+        h3 = self._mixed(second, h2, mix)
+        with self.part("dense_ffn"):
+            y = b.add(h3, self._ffn(second, h3, True, valid, counts, routes,
+                                    absent, choices), f"l{second}_res2")
+        with self.part("moe_part"):
+            return b.add(y, m, f"l{first}_short")
+
+    def _ffn(self, i, hid, dense, valid, counts, routes, absent, choices):
         """Layer ``i``'s feed-forward half over ``hid``: its norm and the
         dense SwiGLU, or the router, the routed experts and the shared
-        expert; with ``norm_after`` the dense SwiGLU and then the norm."""
+        expert where there is one; with ``norm_after`` the dense SwiGLU and
+        then the norm."""
         cfg, p, b = self.cfg, f"dec_l{i}_", self.b
         if getattr(cfg, "norm_after", False):
             if not dense:
@@ -380,17 +431,32 @@ class _MB:
         if dense:
             return self.swiglu_ffn(hn2, p + "w_gate", p + "w_up",
                                    p + "w_down", f"l{i}_ff")
+        routed = self._experts(i, hn2, valid, counts, routes, absent,
+                               choices)
+        if not cfg.n_shared_experts:
+            return routed
+        shared = self.swiglu_ffn(hn2, p + "shared_gate", p + "shared_up",
+                                 p + "shared_down", f"l{i}_sh")
+        return b.add(routed, shared, f"l{i}_ff")
+
+    def _experts(self, i, hn, valid, counts, routes, absent, choices):
+        """The router and the routed experts of layer ``i`` over the normed
+        rows ``hn``: the held experts' part of the sum and, where the model
+        has zero-computation experts, their identity term."""
+        cfg, p = self.cfg, f"dec_l{i}_"
+        zero = getattr(cfg, "zero_experts", 0)
         idx, wgt = self.tmp(f"l{i}_ridx"), self.tmp(f"l{i}_rw")
+        attrs = {"top_k": int(cfg.num_experts_per_tok),
+                 "routed_scaling_factor": float(cfg.routed_scaling_factor),
+                 "norm_topk_prob": bool(cfg.norm_topk_prob)}
+        if getattr(cfg, "router_scoring", "sigmoid") != "sigmoid":
+            attrs["scoring_func"] = str(cfg.router_scoring)
         self.op("moe_router",
-                {"X": [hn2], "Gate": [p + "router"],
+                {"X": [hn], "Gate": [p + "router"],
                  "Bias": [p + "router_bias"]},
-                {"Idx": [idx], "Weight": [wgt]},
-                {"top_k": int(cfg.num_experts_per_tok),
-                 "routed_scaling_factor":
-                     float(cfg.routed_scaling_factor),
-                 "norm_topk_prob": bool(cfg.norm_topk_prob)})
+                {"Idx": [idx], "Weight": [wgt]}, attrs)
         routed, cnt = self.tmp(f"l{i}_moe"), self.tmp(f"l{i}_cnt")
-        ins = {"X": [hn2], "Idx": [idx], "Weight": [wgt],
+        ins = {"X": [hn], "Idx": [idx], "Weight": [wgt],
                "WGate": [p + "experts_gate"], "WUp": [p + "experts_up"],
                "WDown": [p + "experts_down"]}
         if valid is not None:
@@ -400,13 +466,18 @@ class _MB:
             # this chip's share: the rows' other experts are elsewhere
             outs["Absent"] = [self.tmp(f"l{i}_absent")]
             absent.append(outs["Absent"][0])
-        self.op("moe_experts", ins, outs)
+        if zero:
+            # outputs of the router past the routed experts are identity
+            # experts: no weights, so every chip computes its own tokens'
+            outs["Choices"] = [self.tmp(f"l{i}_choices")]
+            choices.append(outs["Choices"][0])
+        self.op("moe_experts", ins, outs,
+                {"routed_experts": int(cfg.n_routed_experts)} if zero
+                else None)
         counts.append(cnt)
         if routes is not None:
             routes.append(idx)
-        shared = self.swiglu_ffn(hn2, p + "shared_gate", p + "shared_up",
-                                 p + "shared_down", f"l{i}_sh")
-        return b.add(routed, shared, f"l{i}_ff")
+        return routed
 
     def stacked(self, per_layer, name):
         """The expert layers' small int32 results as one fetch, layers
@@ -418,8 +489,8 @@ class _MB:
 
 def ffn_specs(cfg, i: int, moe: bool) -> Dict[str, tuple]:
     """The feed-forward half of layer ``i``: its norm and the dense SwiGLU,
-    or the router, this chip's experts and the shared expert (what
-    ``_MB._ffn`` builds, for any description with these fields)."""
+    or :func:`expert_specs` (what ``_MB._ffn`` builds, for any description
+    with these fields)."""
     h, p = cfg.hidden, f"dec_l{i}_"
     specs = {p + "ffn_norm_scale": (h,)}
     if not moe:
@@ -427,15 +498,26 @@ def ffn_specs(cfg, i: int, moe: bool) -> Dict[str, tuple]:
         specs.update({p + "w_gate": (h, f), p + "w_up": (h, f),
                       p + "w_down": (f, h)})
         return specs
-    f, e, held = cfg.moe_intermediate, cfg.n_routed_experts, cfg.experts_here
-    fs = f * cfg.n_shared_experts
-    specs.update({
+    specs.update(expert_specs(cfg, i))
+    return specs
+
+
+def expert_specs(cfg, i: int) -> Dict[str, tuple]:
+    """The expert layer ``_MB._experts`` builds under layer ``i``'s names:
+    the router over every output (the routed experts, then the
+    zero-computation ones), this chip's experts and, where the model has
+    one, the shared expert."""
+    h, p = cfg.hidden, f"dec_l{i}_"
+    f, held = cfg.moe_intermediate, cfg.experts_here
+    e = cfg.n_routed_experts + getattr(cfg, "zero_experts", 0)
+    specs = {
         p + "router": (h, e), p + "router_bias": (e,),
         p + "experts_gate": (held, h, f), p + "experts_up": (held, h, f),
-        p + "experts_down": (held, f, h),
-        p + "shared_gate": (h, fs), p + "shared_up": (h, fs),
-        p + "shared_down": (fs, h),
-    })
+        p + "experts_down": (held, f, h)}
+    if cfg.n_shared_experts:
+        fs = f * cfg.n_shared_experts
+        specs.update({p + "shared_gate": (h, fs), p + "shared_up": (h, fs),
+                      p + "shared_down": (fs, h)})
     return specs
 
 
@@ -530,7 +612,7 @@ def embed_rows(m: "_MB", tokens, positions):
 
 
 def close_form(m: "_MB", hid, last_index, routes, counts, absent, sampling,
-               seeds, routes_all: bool = False) -> tuple:
+               seeds, routes_all: bool = False, choices=()) -> tuple:
     """The end of a form, from the last block's rows ``hid``: the emitting
     row of a whole prompt (``last_index``; None: every row emits), the final
     norm, the head, the token and what rides on a call (with ``routes_all``
@@ -574,9 +656,11 @@ def close_form(m: "_MB", hid, last_index, routes, counts, absent, sampling,
         counts = m.stacked(counts, "moe_counts") if counts else None
         routes = m.stacked(routes, "token_routes") if routes else None
         absent = m.stacked(absent, "moe_absent") if absent else None
+        choices = m.stacked(choices, "moe_choices") if choices else None
     return out_name, FormExtras(logits=logits, hidden=hidden, score=score,
                                 routes=routes, routes_all=every,
-                                counts=counts, absent=absent)
+                                counts=counts, absent=absent,
+                                choices=choices)
 
 
 def live_rows(m: "_MB", slot_map, pool):
@@ -625,11 +709,12 @@ def build_form(cfg, mode: str, sampling, kv_dtype: str, *, modes, feeds,
     counts: List[str] = []
     routes: List[str] = []
     absent: List[str] = []
+    choices: List[str] = []
     for i in range(cfg.num_layers):
-        hid = m.block(i, hid, mix, valid, counts, routes, absent)
+        hid = m.block(i, hid, mix, valid, counts, routes, absent, choices)
     out_name, extras = close_form(
         m, hid, f.get("last_index"), routes, counts, absent, sampling,
-        f["seeds"], routes_all=mode in routes_all)
+        f["seeds"], routes_all=mode in routes_all, choices=choices)
     if mode != "reference":
         extras = extras._replace(kernel_stats=functools.partial(
             walk, mode=mode, cfg=cfg, routed=bool(counts)))

@@ -39,6 +39,19 @@ router scores all experts, the weights are normalised over all chosen, and
 the layer computes the part of the sum whose experts it holds (plus the
 shared expert, whole).
 
+**Shortcut-connected layers** (``shortcut``, LongCat-Flash,
+arXiv:2509.01322).  A layer is TWO sub-blocks, each a latent attention and a
+dense SwiGLU with weights, norms and cache rows of its own (sub-layers ``2l``
+and ``2l + 1``: ``mla_layers`` counts sub-layers, two latent pools a layer),
+and one expert layer that reads the first sub-block's normed stream and is
+added after the second's dense half (``decoder_program._MB.shortcut_pair``).
+Its router scores by a softmax over ALL its outputs (``router_scoring``),
+``n_routed_experts`` with weights and then ``zero_experts`` identity experts
+that compute nothing: a token's weight on them multiplies its own normed row.
+``scale_q_lora`` / ``scale_kv_lora`` multiply the normed low-rank streams by
+``sqrt(hidden / rank)``.  No shared expert (``n_shared_experts`` 0), no
+leading dense layer.
+
 The multi-token-prediction module (``mtp_layers`` 1) is one more block
 with its own cache rows (layer index ``num_layers``) behind ``h' = W_p
 [RMSNorm(h_i) ; RMSNorm(Emb(t_{i+1}))]``; :class:`MTPDrafter` runs it as
@@ -57,11 +70,13 @@ from ..framework.dtype import VarType, convert_dtype
 from ..ops import kda_kernels, mla_kernels
 from .decoder_program import (DELTA_RULE_SEEDS, _MB, FormExtras, _emit_head,
                               _gmm_walk, _pow2_bucket, add_feed, build_form,
-                              delta_rule_seed, ffn_specs, live_rows)
+                              delta_rule_seed, expert_specs, ffn_specs,
+                              live_rows)
 from .kv_cache import KVCacheConfig
 from .spec_decode import Proposer
 
-__all__ = ["MLADecoderConfig", "MTPDrafter", "init_mla_weights"]
+__all__ = ["MLADecoderConfig", "MTPDrafter", "init_mla_weights",
+           "seed_fan_in"]
 
 
 @dataclass(frozen=True)
@@ -98,6 +113,13 @@ class MLADecoderConfig:
     kda_conv_taps: int = 4
     kda_gate_rank: int = 0           # inner width of the two low-rank gates
     kda_l2_eps: float = 1e-6
+    # -- the shortcut-connected description: False / 0 / "sigmoid" is the
+    # block above
+    shortcut: bool = False           # a layer: two sub-blocks, one shortcut
+    router_scoring: str = "sigmoid"  # | "softmax", over every output
+    zero_experts: int = 0            # identity experts after the routed ones
+    scale_q_lora: bool = False       # c_q  * sqrt(hidden / q_lora_rank)
+    scale_kv_lora: bool = False      # c_kv * sqrt(hidden / kv_lora_rank)
 
     # -- the seam ServingEngine asks a model description through ---------
     @property
@@ -117,12 +139,21 @@ class MLADecoderConfig:
         return self.weights_dtype
 
     def mixer(self, i: int) -> str:
-        """Layer ``i``'s mixer; the MTP block's (``num_layers``) is MLA."""
+        """Layer ``i``'s mixer; the MTP block's (``num_layers``) is MLA, and
+        so is every sub-layer of a shortcut-connected model."""
         return self.mixers[i] if i < len(self.mixers) else "mla"
 
     @property
+    def sub_layers(self) -> int:
+        """The mixers over the depth: two a layer where shortcut-connected."""
+        return self.num_layers * (2 if self.shortcut else 1)
+
+    @property
     def mla_layers(self) -> List[int]:
-        """The layers that keep latent rows, the MTP block's included."""
+        """The layers that keep latent rows, the MTP block's included; of a
+        shortcut-connected model its sub-layers, two a layer."""
+        if self.shortcut:
+            return list(range(self.sub_layers))
         return [i for i in range(self.num_layers) if self.mixer(i) == "mla"] \
             + list(range(self.num_layers, self.num_layers + self.mtp_layers))
 
@@ -159,6 +190,21 @@ class MLADecoderConfig:
             if "mla" not in self.mixers:
                 raise ValueError("a hybrid model needs an MLA layer: the "
                                  "engine sizes its page pool by it")
+        if self.router_scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"router_scoring is 'sigmoid' or 'softmax': "
+                             f"{self.router_scoring!r}")
+        if self.zero_experts and self.router_scoring != "softmax":
+            raise ValueError(
+                "zero-computation experts are published with a softmax "
+                "router over all outputs (a sigmoid scores each output "
+                "alone, so an identity expert would take weight from none): "
+                "router_scoring must be 'softmax'")
+        if self.shortcut and (self.mixers or self.mtp_layers
+                              or self.first_k_dense):
+            raise ValueError(
+                "a shortcut-connected layer is two MLA sub-blocks around one "
+                "expert layer: it is not built with KDA mixers, an MTP "
+                "block or leading dense layers")
         if int(tp or 1) != 1:
             raise ValueError("the MLA decoder has no tensor-parallel rules: "
                              "serving_tp must be 1")
@@ -254,8 +300,31 @@ class MLADecoderConfig:
         "norm_topk_prob": "moe_renormalize",
     }
 
+    # LongCat-Flash's config.json: its own names, a layer two sub-layers
+    _SHORTCUT_KEYS = {
+        "vocab_size": "vocab_size", "hidden": "hidden_size",
+        "num_heads": "num_attention_heads", "num_layers": "num_layers",
+        "intermediate": "ffn_hidden_size",
+        "moe_intermediate": "expert_ffn_hidden_size",
+        "num_experts_per_tok": "moe_topk", "zero_experts": "zero_expert_num",
+        "q_lora_rank": "q_lora_rank", "kv_lora_rank": "kv_lora_rank",
+        "qk_nope_head_dim": "qk_nope_head_dim",
+        "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+        "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+        "routed_scaling_factor": "routed_scaling_factor",
+        "scale_q_lora": "mla_scale_q_lora",
+        "scale_kv_lora": "mla_scale_kv_lora",
+    }
+
     def source_config(self) -> dict:
         """This model under the source's key names."""
+        if self.shortcut:
+            out = {theirs: getattr(self, ours)
+                   for ours, theirs in self._SHORTCUT_KEYS.items()}
+            out.update(n_routed_experts=self.experts_here,
+                       router_experts=self.n_routed_experts,
+                       zero_expert_type="identity")
+            return out
         if not self.mixers:
             return {theirs: getattr(self, ours)
                     for ours, theirs in self._SOURCE_KEYS.items()}
@@ -275,9 +344,27 @@ class MLADecoderConfig:
 
     @classmethod
     def from_source(cls, source: dict, **ours) -> "MLADecoderConfig":
-        """From a ``config.json`` of the source's shape (JoyAI's, or
-        Kimi-Linear's where it holds ``linear_attn_config``); ``ours`` gives
-        what it does not say (``max_seq_len``, ``weights_dtype``, ...)."""
+        """From a ``config.json`` of the source's shape (JoyAI's,
+        Kimi-Linear's where it holds ``linear_attn_config``, LongCat-Flash's
+        where it holds ``zero_expert_num``); ``ours`` gives what it does not
+        say (``max_seq_len``, ``weights_dtype``, ...)."""
+        if "zero_expert_num" in source:
+            if source.get("zero_expert_type", "identity") != "identity":
+                raise ValueError("zero-computation experts are built as the "
+                                 "identity: zero_expert_type "
+                                 f"{source['zero_expert_type']!r}")
+            held = source["n_routed_experts"]
+            routed = source.get("router_experts", held)
+            kw = {mine: source[theirs]
+                  for mine, theirs in cls._SHORTCUT_KEYS.items()}
+            # the published router: softmax over every output, the chosen
+            # weights not normalised, no shared expert, no dense layer
+            kw.update(shortcut=True, router_scoring="softmax",
+                      norm_topk_prob=False, n_shared_experts=0,
+                      first_k_dense=0, n_routed_experts=routed,
+                      experts_held=held if held < routed else 0)
+            kw.update(ours)
+            return cls(**kw)
         if "linear_attn_config" not in source:
             return cls(**{mine: source[theirs]
                           for mine, theirs in cls._SOURCE_KEYS.items()},
@@ -303,6 +390,9 @@ class MLADecoderConfig:
 
 
 def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
+    """(Sub-)layer ``i``: its mixer and its feed-forward half; the first
+    sub-layer of a shortcut-connected layer holds the layer's experts beside
+    its dense half."""
     h, heads = cfg.hidden, cfg.num_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     p = f"dec_l{i}_"
@@ -324,7 +414,9 @@ def _layer_specs(cfg: MLADecoderConfig, i: int, moe: bool) -> Dict[str, tuple]:
                           heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
             p + "wo": (heads * cfg.v_head_dim, h),
         })
-    specs.update(ffn_specs(cfg, i, moe))
+    specs.update(ffn_specs(cfg, i, moe and not cfg.shortcut))
+    if cfg.shortcut and moe:
+        specs.update(expert_specs(cfg, i))
     return specs
 
 
@@ -357,8 +449,12 @@ def mla_param_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
     h = cfg.hidden
     specs = {"dec_embed": (cfg.vocab_size, h), "dec_head": (h, cfg.vocab_size),
              "dec_norm_scale": (h,)}
-    for i in range(cfg.num_layers):
-        specs.update(_layer_specs(cfg, i, moe=i >= cfg.first_k_dense))
+    if cfg.shortcut:
+        for i in range(cfg.sub_layers):
+            specs.update(_layer_specs(cfg, i, moe=i % 2 == 0))
+    else:
+        for i in range(cfg.num_layers):
+            specs.update(_layer_specs(cfg, i, moe=i >= cfg.first_k_dense))
     if cfg.mtp_layers:
         specs.update({"mtp_hnorm_scale": (h,), "mtp_enorm_scale": (h,),
                       "mtp_proj": (2 * h, h), "mtp_norm_scale": (h,)})
@@ -369,24 +465,43 @@ def mla_param_specs(cfg: MLADecoderConfig) -> Dict[str, tuple]:
 def init_mla_weights(cfg: MLADecoderConfig, seed: int = 0
                      ) -> Dict[str, np.ndarray]:
     """Seeded weights for tests and smokes: norm scales 1, the router's
-    correction bias small and seeded, the rest normal over sqrt(fan-in)
-    (the fan-in is the second-to-last axis: weights multiply on the
-    right)."""
+    correction bias small and seeded (against a softmax router's scores of
+    order ``1 / outputs``: a tenth of that), the rest normal over
+    sqrt(fan-in) (the fan-in is the second-to-last axis: weights multiply on
+    the right; :func:`seed_fan_in` where a low-rank stream is scaled)."""
     rng = np.random.RandomState(seed)
     out = {}
     for name, shape in mla_param_specs(cfg).items():
         if name.endswith("_scale"):
             w = np.ones(shape, np.float32)
         elif name.endswith("router_bias"):
-            w = (0.01 * rng.randn(*shape)).astype(np.float32)
+            size = 0.01 if cfg.router_scoring == "sigmoid" \
+                else 0.1 / shape[0]
+            w = (size * rng.randn(*shape)).astype(np.float32)
         elif name.endswith(DELTA_RULE_SEEDS):
             w = delta_rule_seed(name, shape, rng).astype(np.float32)
         elif name == "dec_embed":
             w = rng.randn(*shape).astype(np.float32)
         else:
-            w = (rng.randn(*shape) / np.sqrt(shape[-2])).astype(np.float32)
+            w = (rng.randn(*shape) / np.sqrt(seed_fan_in(cfg, name, shape))
+                 ).astype(np.float32)
         out[name] = w.astype(np.dtype(cfg.weights_dtype))
     return out
+
+
+def seed_fan_in(cfg: MLADecoderConfig, name: str, shape) -> float:
+    """The fan-in a seeded matrix is drawn over: its rows, or, for the two
+    matrices that read a low-rank stream the description scales by
+    ``sqrt(hidden / rank)`` (``wq_b``, ``wkv_b``), ``hidden``: the scales
+    exist to align those paths' variance with the full-width paths'
+    (arXiv:2509.01322, scale-correction for MLA), so ``q``, ``k_nope`` and
+    ``v`` come out at the variance of ``k_r``, as without the scales.  Drawn
+    over the rank the scores would have six times the spread and the softmax
+    would be an argmax that bfloat16 rounding flips."""
+    if (name.endswith("wq_b") and cfg.scale_q_lora) or \
+            (name.endswith("wkv_b") and cfg.scale_kv_lora):
+        return float(cfg.hidden)
+    return float(shape[-2])
 
 
 # ==========================================================================
@@ -396,6 +511,13 @@ def _rope(m: _MB, x, positions, tag):
     o = m.tmp(tag)
     m.op("rope_interleaved", {"X": [x], "Positions": [positions]},
          {"Out": [o]}, {"theta": float(m.cfg.rope_theta)})
+    return o
+
+
+def _times(m: _MB, x, factor: float, tag):
+    o = m.tmp(tag)
+    m.op("scale", {"X": [x]}, {"Out": [o]},
+         {"scale": float(factor), "bias": 0.0, "bias_after_scale": True})
     return o
 
 
@@ -409,12 +531,18 @@ def _split(m: _MB, x, sizes, tag):
 def _latents(m: _MB, i, hn, positions):
     """Layer ``i``'s queries and latent row of the normed rows ``hn``
     (n, hidden): ``(q_nope, q_rope) (n, heads, dn | dr)``, ``c_kv``
-    (n, r) normed, ``k_r`` (n, dr) after RoPE."""
+    (n, r) normed, ``k_r`` (n, dr) after RoPE.  Where the description says
+    so the normed low-rank streams ``c_q`` and ``c_kv`` are multiplied by
+    ``sqrt(hidden / rank)`` (hence both halves of ``q``, and ``k_nope`` and
+    ``v``; never ``k_r``)."""
     cfg, p, b = m.cfg, f"dec_l{i}_", m.b
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     if cfg.q_lora_rank:
         cq = m.norm(m.mm(hn, p + "wq_a", f"l{i}_cq"),
                     p + "q_norm_scale", f"l{i}_cqn")
+        if cfg.scale_q_lora:
+            cq = _times(m, cq, (cfg.hidden / cfg.q_lora_rank) ** 0.5,
+                        f"l{i}_cqs")
         q = m.mm(cq, p + "wq_b", f"l{i}_q")
     else:
         q = m.mm(hn, p + "wq", f"l{i}_q")
@@ -425,6 +553,9 @@ def _latents(m: _MB, i, hn, positions):
     c_kv, k_r = _split(m, m.mm(hn, p + "wkv_a", f"l{i}_kva"),
                        [cfg.kv_lora_rank, dr], f"l{i}_kvs")
     c_kv = m.norm(c_kv, p + "kv_norm_scale", f"l{i}_ckv")
+    if cfg.scale_kv_lora:
+        c_kv = _times(m, c_kv, (cfg.hidden / cfg.kv_lora_rank) ** 0.5,
+                      f"l{i}_ckvs")
     if not cfg.rope:      # NoPE: dr more key lanes that all heads share
         return q_nope, q_rope, c_kv, k_r
     k_r = b.reshape(_rope(m, b.reshape(k_r, [-1, 1, dr], f"l{i}_kr3"),
@@ -550,8 +681,9 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
     elif mode == "prefill":
         out = {}
     else:
-        out = _decode_walk(feed, kv_config, verify=mode == "verify",
-                           heads=cfg.num_heads, layers=cfg.num_layers) or {}
+        out = _decode_walk(
+            feed, kv_config, verify=mode == "verify", heads=cfg.num_heads,
+            layers=cfg.sub_layers) or {}
     if routed and mla_kernels.gmm_engages(cfg.hidden, cfg.moe_intermediate):
         out["from_counts"] = functools.partial(
             _gmm_walk, hidden=cfg.hidden,

@@ -569,9 +569,11 @@ class _EngineCore:
         # (a call's place in ``_moe_pending``, what its form will say of its
         # kernels once the call's counts are read: ``_note_kernel_stats``)
         self._stats_owed: list = []
-        # (phase, rows with no expert here by expert layer) a call, where
-        # the expert layers hold a share of their experts
-        self._absent_pending: list = []
+        # (phase, keys, values by expert layer) a call: the rows with no
+        # expert here where the layers hold a share of their experts, the
+        # choices on held experts, on identity experts and all of them
+        # (expert layers, 3) where the model has identity experts
+        self._sums_pending: list = []
         self.moe_calls: Optional[list] = None   # a list: every call's counts
         # what a program's kernels report of their own work, summed by
         # phase (``FormExtras.kernel_stats``): host integers, no device read
@@ -1153,6 +1155,8 @@ class _EngineCore:
             extras["counts"] = offers.counts
             if offers.absent:
                 extras["absent"] = offers.absent
+            if offers.choices:
+                extras["choices"] = offers.choices
         if not extras and self.board is None:
             self.last = {}
             return self.exe.run(prog, feed=feed, fetch_list=fetch,
@@ -1164,7 +1168,12 @@ class _EngineCore:
         if "counts" in self.last:
             self._moe_pending.append((phase, self.last["counts"]))
             if "absent" in self.last:
-                self._absent_pending.append((phase, self.last["absent"]))
+                self._sums_pending.append(
+                    (phase, ("rows_all_absent",), self.last["absent"]))
+            if "choices" in self.last:
+                self._sums_pending.append(
+                    (phase, ("choices_held", "choices_identity",
+                             "choices_all"), self.last["choices"]))
         if "score" in self.last:
             self._score_calls.append((self.last["score"],
                                       self.last.get("routes"),
@@ -1239,9 +1248,11 @@ class _EngineCore:
         """By phase (``prefill``, ``decode``): expert layers run, experts
         that received a token and the fullest expert's load over the mean,
         each summed over expert layers and calls (a reader divides by
-        ``layer_steps``).  Reading it reads the calls' counts off the
-        device: they are logged there, and nothing reads them while
-        serving."""
+        ``layer_steps``); where the layers hold a share, the rows with no
+        expert here; where the model has identity experts, the real tokens'
+        choices on held experts, on identity experts and all of them.
+        Reading it reads the calls' counts off the device: they are logged
+        there, and nothing reads them while serving."""
         pending, self._moe_pending = self._moe_pending, []
         owed, self._stats_owed = self._stats_owed, []
         calls = [(phase, np.asarray(c, np.float64)) for phase, c in pending]
@@ -1258,11 +1269,13 @@ class _EngineCore:
                            "received a token / fullest expert's tokens over "
                            "the mean: summed over expert layers and calls",
                            labels=("phase",)).labels(phase=phase).inc(value)
-        absent, self._absent_pending = self._absent_pending, []
-        for phase, rows in absent:
+        sums, self._sums_pending = self._sums_pending, []
+        for phase, keys, by_layer in sums:
             st = self._moe_stats.setdefault(phase, {})
-            st["rows_all_absent"] = st.get("rows_all_absent", 0.0) \
-                + float(np.asarray(rows).sum())
+            over_layers = np.asarray(by_layer, np.float64) \
+                .reshape(-1, len(keys)).sum(axis=0)
+            for key, value in zip(keys, over_layers):
+                st[key] = st.get(key, 0.0) + float(value)
         return self._moe_stats
 
     @staticmethod

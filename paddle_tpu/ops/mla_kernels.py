@@ -881,10 +881,23 @@ def moe_gmm(x, weights, group_sizes, gated: bool = False,
 # ==========================================================================
 # moe_rows_in, moe_combine
 # ==========================================================================
-#: sorted rows a grid step of ``moe_rows_in`` fetches
+#: sorted rows a grid step of ``moe_rows_in`` fetches, and the bytes its two
+#: buffers of them may take (a row of 6,144 float32 lanes: 128 rows a step)
 ROWS_IN_TILE = 256
-#: tokens a grid step of ``moe_combine`` sums
+ROWS_IN_BUFFER_BYTES = 8 * 1024 * 1024
+#: tokens a grid step of ``moe_combine`` sums, and the bytes its two buffers
+#: of their ``k`` rows each may take (12 rows of 6,144 lanes a token: 32)
 COMBINE_TOKENS = 128
+COMBINE_BUFFER_BYTES = 40 * 1024 * 1024
+#: a block of a list in SMEM is a whole number of these, or the whole list
+SMEM_BLOCK = 1024
+
+
+def _halved_to_fit(rows: int, row_bytes: int, room: int) -> int:
+    """``rows``, halved until two buffers of them fit ``room``."""
+    while 2 * rows * row_bytes > room and rows > 8:
+        rows //= 2
+    return rows
 
 
 def moe_rows_in_reference(x, order, total, k: int, dtype):
@@ -934,7 +947,8 @@ def _moe_rows_in_call(x, order, total, *, k, dtype):
     owned row (a value on the device).  Under a ``jit`` of its own: the
     layers of a program share one trace."""
     rows, h = order.shape[0], x.shape[1]
-    tile = min(ROWS_IN_TILE, -(-rows // 8) * 8)
+    tile = min(_halved_to_fit(ROWS_IN_TILE, h * x.dtype.itemsize,
+                              ROWS_IN_BUFFER_BYTES), -(-rows // 8) * 8)
     padded = -(-rows // tile) * tile
     tok = (order // k).astype(jnp.int32)
     if padded != rows:
@@ -1050,7 +1064,7 @@ def _combine_kernel(src_ref, place_ref, start_ref, ys_ref, w_ref, o_ref, buf,
               (jnp.int32(-1), jnp.zeros((1, h), jnp.float32)))
 
 
-def combine_work_list(order, total, n: int, k: int):
+def combine_work_list(order, total, n: int, k: int, h: int = 0):
     """``moe_combine``'s lists, built on the device: the owned choices
     (sorted rows ``p < total``) in the tokens' order, ``src`` the sorted row
     of each, ``place`` its place ``t * k + j`` in its grid step's tokens,
@@ -1058,8 +1072,10 @@ def combine_work_list(order, total, n: int, k: int):
     number owned.  One sort (the path before took an ``argsort`` for
     ``back``); a choice that is not owned sorts past every step's entries,
     also where the last step's tokens run past ``n``.  Beside them the
-    tokens a step sums and the steps."""
-    tokens = min(COMBINE_TOKENS, -(-n // 8) * 8)
+    tokens a step sums (fewer where ``k`` rows of ``h`` lanes a token would
+    outgrow the step's buffers) and the steps."""
+    tokens = min(_halved_to_fit(COMBINE_TOKENS, k * h * 4,
+                                COMBINE_BUFFER_BYTES), -(-n // 8) * 8)
     steps = -(-n // tokens)
     width = k * tokens
     p = jnp.arange(n * k, dtype=jnp.int32)
@@ -1077,12 +1093,15 @@ def _moe_combine_call(ys, order, total, weight):
     """The kernel's call over :func:`combine_work_list`.  Under a ``jit`` of
     its own."""
     n, k = weight.shape
-    rows, _, h = ys.shape
-    lists, tokens, tiles = combine_work_list(order, total, n, k)
-    width = k * tokens
-    weight = weight.reshape(-1)
-    if tiles * width != rows:
-        weight = jnp.pad(weight, (0, tiles * width - rows))
+    h = ys.shape[-1]
+    lists, tokens, tiles = combine_work_list(order, total, n, k, h)
+    span = k * tokens               # a step's choices, and their weights:
+    # a block of the list in SMEM, so a whole number of SMEM_BLOCK where
+    # there is more than one (8 choices x 128 tokens are; 12 x 32 are not)
+    width = span if tiles == 1 else -(-span // SMEM_BLOCK) * SMEM_BLOCK
+    weight = jnp.pad(weight, ((0, tiles * tokens - n), (0, 0))) \
+        .reshape(tiles, span)
+    weight = jnp.pad(weight, ((0, 0), (0, width - span))).reshape(-1)
     out = pl.pallas_call(
         functools.partial(_combine_kernel, k=k),
         name="moe_combine",
@@ -1093,7 +1112,7 @@ def _moe_combine_call(ys, order, total, weight):
                       pl.BlockSpec((width,), lambda i, *_: (i,),
                                    memory_space=pltpu.SMEM)],
             out_specs=pl.BlockSpec((tokens, 1, h), lambda i, *_: (i, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, width, 1, h), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2, span, 1, h), jnp.float32),
                             pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((tiles * tokens, 1, h), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -10,11 +10,13 @@ the GPT-2-shaped one does not.
 * ``matmul_f32acc`` — a projection with its types stated: operands in the
   weight's type (bfloat16 in a served model), accumulation and result in
   float32, which ``matmul`` on two bfloat16 operands does not promise.
-* ``moe_router`` — sigmoid scores, the top-k of ``score + bias``, weights
-  from the scores without the bias, normalised and scaled.
+* ``moe_router`` — sigmoid scores, or a softmax over every output, the
+  top-k of ``score + bias``, weights from the scores without the bias,
+  normalised where the model says so, and scaled.
 * ``moe_experts`` — the routed experts' SwiGLU over the tokens each expert
-  received (``moe_gmm``), no capacity and no dropped token, and the count
-  of tokens per expert.
+  received (``moe_gmm``), no capacity and no dropped token, the identity
+  term of a model with zero-computation experts, and the count of tokens
+  per expert.
 * ``mla_prefill_attention`` — expanded latent attention of one whole
   prompt: ``W_kvb`` widens the latent rows to per-head keys and values,
   causal softmax by blocks (``mla_prefill``).
@@ -89,16 +91,23 @@ def _swiglu(ctx):
                 .astype(u.dtype))
 
 
-def route(x, w_gate, bias, top_k: int, scaling: float, normalize: bool):
-    """``noaux_tc`` with one group: scores ``sigmoid(x @ w_gate)`` in
-    float32; the chosen experts are the top-k of ``score + bias``; their
-    weights are the scores themselves, normalised to sum 1 where
-    ``normalize`` and scaled.  Returns ``(idx (n, k) int32, weight (n, k)
-    float32)``."""
+def route(x, w_gate, bias, top_k: int, scaling: float, normalize: bool,
+          scoring: str = "sigmoid"):
+    """``noaux_tc`` with one group, in float32: the scores are ``sigmoid(x @
+    w_gate)``, each output alone, or (``scoring`` ``"softmax"``) a softmax
+    over ALL of ``w_gate``'s outputs, the zero-computation experts' after the
+    routed ones'; the chosen experts are the top-k of ``score + bias``; their
+    weights are the scores themselves without the bias, normalised to sum 1
+    where ``normalize``, and scaled.  Returns ``(idx (n, k) int32, weight (n,
+    k) float32)``."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"the router scores by sigmoid or softmax: "
+                         f"{scoring!r}")
     with jax.named_scope("moe_router"):
-        s = jax.nn.sigmoid(jnp.matmul(
-            x.astype(jnp.float32), w_gate.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
+        z = jnp.matmul(x.astype(jnp.float32), w_gate.astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(z) if scoring == "sigmoid" \
+            else jax.nn.softmax(z, axis=-1)
         _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if normalize:
@@ -110,31 +119,37 @@ def route(x, w_gate, bias, top_k: int, scaling: float, normalize: bool):
 def _moe_router(ctx):
     """X ``(n, hidden)``, Gate ``(hidden, experts)``, Bias ``(experts,)``
     -> Idx ``(n, k)`` int32, Weight ``(n, k)`` float32.  Attrs: top_k,
-    routed_scaling_factor, norm_topk_prob."""
+    routed_scaling_factor, norm_topk_prob, scoring_func (sigmoid where
+    absent)."""
     idx, w = route(ctx.in_("X"), ctx.in_("Gate"), ctx.in_("Bias"),
                    int(ctx.attr("top_k", 1)),
                    float(ctx.attr("routed_scaling_factor", 1.0)),
-                   bool(ctx.attr("norm_topk_prob", True)))
+                   bool(ctx.attr("norm_topk_prob", True)),
+                   str(ctx.attr("scoring_func", "sigmoid")))
     ctx.set_out("Idx", idx)
     ctx.set_out("Weight", w)
 
 
 def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
-                    share: bool = False):
+                    share: bool = False, routed=None):
     """The routed experts over the tokens each received.  ``x`` (n, h);
     ``idx``/``weight`` (n, k); expert weights ``(experts, h, f)`` twice and
     ``(experts, f, h)``; ``valid`` (n,) bool, rows that are padding route
     nowhere.  ``share``: the weights are experts ``0 .. experts - 1`` of a
     layer that routes over more (one chip's share of an expert-parallel
     layer): a choice past them is another chip's and adds nothing here, its
-    weight is spent all the same.  Returns ``(y (n, h), counts (experts,)
-    int32)``."""
+    weight is spent all the same.  ``routed``: how many of the router's
+    outputs are experts with weights, here or elsewhere; a choice at or past
+    it is a zero-computation expert, the identity, which has no weights and
+    so acts here, on this chip's own tokens, whatever experts are held: the
+    row gains ``x`` times the sum of such choices' weights.  Returns ``(y
+    (n, h), counts (experts,) int32)``."""
     n, k = idx.shape
     experts = w_gate.shape[0]
     by_kernel = moe_rows_engage(n * k, experts, x.shape[1])
     with jax.named_scope("moe_dispatch"):
         flat = idx.reshape(-1)
-        if share:
+        if share or routed is not None:
             flat = jnp.where(flat < experts, flat, experts)
         if valid is not None:
             flat = jnp.where(jnp.repeat(valid, k), flat, experts)
@@ -160,6 +175,10 @@ def experts_forward(x, idx, weight, w_gate, w_up, w_down, valid=None,
             jnp.where(valid[:, None], weight, 0.0)
         y = (moe_combine if by_kernel else moe_combine_reference)(
             ys, order, total, w)
+    if routed is not None:
+        with jax.named_scope("moe_identity"):
+            own = jnp.sum(jnp.where(idx >= routed, w, 0.0), axis=-1)
+            y = y + own[:, None] * x.astype(jnp.float32)
     return y, counts
 
 
@@ -170,21 +189,35 @@ def _moe_experts(ctx):
     Valid ``(n,)`` (non-zero: a real token) -> Out ``(n, hidden)``, Counts
     ``(experts,)`` int32 tokens each expert received.  With the output
     Absent (a scalar: rows with no expert here) the weights are a SHARE of
-    the layer's experts, the first ``experts`` of those Idx ranges over."""
+    the layer's experts, the first ``experts`` of those Idx ranges over.
+    With the attr ``routed_experts`` the outputs of the router at and past
+    it are zero-computation experts: Out holds their identity term, and the
+    output Choices ``(3,)`` int32 counts the real tokens' choices: on the
+    experts held, on identity experts, and all of them."""
     valid = ctx.in_("Valid") != 0 if ctx.has_input("Valid") else None
     share = ctx.has_output("Absent")
+    routed = ctx.attr("routed_experts", None)
+    routed = None if routed is None else int(routed)
     idx = ctx.in_("Idx")
     y, counts = experts_forward(
         ctx.in_("X"), idx, ctx.in_("Weight"), ctx.in_("WGate"),
-        ctx.in_("WUp"), ctx.in_("WDown"), valid, share)
+        ctx.in_("WUp"), ctx.in_("WDown"), valid, share, routed)
     ctx.set_out("Out", y)
     ctx.set_out("Counts", counts)
+    live = jnp.ones(idx.shape[:1], bool) if valid is None else valid
     if share:
-        # the rows (real tokens) none of whose experts are held here
-        none = jnp.all(idx >= counts.shape[0], axis=-1)
-        if valid is not None:
-            none = none & valid
-        ctx.set_out("Absent", jnp.sum(none).astype(jnp.int32))
+        # the rows (real tokens) none of whose experts are held here: an
+        # identity expert is held by every chip
+        held = idx < counts.shape[0]
+        if routed is not None:
+            held = held | (idx >= routed)
+        ctx.set_out("Absent", jnp.sum(~jnp.any(held, axis=-1) & live)
+                    .astype(jnp.int32))
+    if ctx.has_output("Choices"):
+        identity = jnp.sum((idx >= routed) & live[:, None])
+        ctx.set_out("Choices", jnp.stack(
+            [jnp.sum(counts), identity, jnp.sum(live) * idx.shape[1]])
+            .astype(jnp.int32))
 
 
 def mla_expanded_attention(q_nope, q_rope, c_kv, k_r, w_kvb, v_dim: int,

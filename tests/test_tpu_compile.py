@@ -237,6 +237,11 @@ def test_mla_decode_compiles_for_v5e(rows, tables, width, v5e):
     # their shape (4 owned) and take the tile of 128, as before PR 38
     ("joyai-decode", 1024, 256, 2048, 768, (32, 384, False)),
     ("kimi-decode", 1024, 64, 2304, 1024, (128, None, True)),
+    # LongCat's 1/32 share: 16 experts whose matrices (2 x 6144 x 2048) are
+    # too large for two experts' worth of VMEM keep column blocks and the
+    # pipeline's fetch, at a prompt's 2,048 x 12 rows and a step's 128 x 12
+    ("longcat-prefill-2048", 24576, 16, 6144, 2048, (256, 512, False)),
+    ("longcat-decode", 1536, 16, 6144, 2048, (128, 512, False)),
 ])
 def test_moe_gmm_compiles_for_v5e(name, rows, experts, hidden, f, want, v5e):
     """A layer's two calls (gated: bfloat16 out; down: float32 out) as the
@@ -297,6 +302,39 @@ def test_moe_rows_kernels_compile_for_v5e(name, tokens, experts, hidden, f,
         (mk._moe_combine_call,
          [((rows, 1, hidden), f32), ((rows,), i32), ((), i32),
           ((tokens, k), f32)]),
+    ):
+        text = _compile(call, v5e, *shapes)
+        assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("tokens", [2048, 128])
+def test_longcat_rows_kernels_compile_for_v5e(tokens, v5e):
+    """The same three calls at LongCat's widths, 12 choices a token and rows
+    of 6,144 lanes: ``moe_rows_in`` fetches 128 rows a step (256 of them
+    outgrow its buffers), ``moe_combine`` sums 32 tokens a step (128 x 12
+    rows would take 72 MB), its weights a block of 1,024 in SMEM of which
+    384 are used; and ``mla_decode`` with 64 heads over the cell's pool."""
+    from paddle_tpu.ops import mla_kernels as mk
+
+    k, experts, hidden, f = 12, 16, 6144, 2048
+    rows = tokens * k
+    i32, f32, bf16 = jnp.int32, jnp.float32, jnp.bfloat16
+    for call, shapes in (
+        (lambda x, order, total: mk._moe_rows_in_call(
+            x, order, total, k=k, dtype=jnp.dtype(bf16)),
+         [((tokens, hidden), f32), ((rows,), i32), ((), i32)]),
+        (lambda x, sizes, w: mk._moe_gmm_call(
+            x, (w,), sizes, gated=False, out_dtype=jnp.dtype(f32),
+            rows_apart=True),
+         [((rows, f), bf16), ((experts,), i32), ((experts, f, hidden), bf16)]),
+        (mk._moe_combine_call,
+         [((rows, 1, hidden), f32), ((rows,), i32), ((), i32),
+          ((tokens, k), f32)]),
+        (lambda ql, qr, pool, bt, cl: mk._mla_decode_call(
+            ql, qr, pool, bt, cl, scale=0.1, step=mk.DECODE_PAGES_PER_STEP,
+            fetch=mk.DECODE_PAGES_PER_FETCH),
+         [((128, 64, 512), f32), ((128, 64, 64), f32),
+          ((1, 16384, 16, 640), bf16), ((128, 192), i32), ((128,), i32)]),
     ):
         text = _compile(call, v5e, *shapes)
         assert text.count('custom_call_target="tpu_custom_call"') == 1
