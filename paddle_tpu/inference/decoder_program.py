@@ -117,6 +117,12 @@ class FormExtras(NamedTuple):
     choices: Optional[str] = None
     #: ``(feed, kv_config) -> counts`` of what the call's kernels walk
     kernel_stats: Optional[Callable] = None
+    #: a decode form whose kernels walk the chunks that hold context and no
+    #: column beyond them: ``kv_config -> pages``, the widest block table
+    #: that costs such a call its int32s alone (None from it: the kernel
+    #: does not engage for these pages).  The engine feeds such a form one
+    #: table width; a form without it is fed the contexts' own bucket
+    live_walk_pages: Optional[Callable] = None
 
 
 # ==========================================================================
@@ -679,7 +685,7 @@ def add_feed(b: _B, f: dict, name: str, shape):
 
 
 def build_form(cfg, mode: str, sampling, kv_dtype: str, *, modes, feeds,
-               rows, walk, routes_all=()) -> tuple:
+               rows, walk, live_walk=None, routes_all=()) -> tuple:
     """One program form ``(program, feeds, fetches)`` of a decoder whose
     layers are :meth:`_MB.block`.  The model says which ``modes`` it builds
     and gives what is its own:
@@ -691,6 +697,8 @@ def build_form(cfg, mode: str, sampling, kv_dtype: str, *, modes, feeds,
       the form caches nothing) and the one mixer hook of ``block``;
     * ``walk(feed, kv_config, mode=, cfg=, routed=)`` is what a serving
       form's kernels walk, the form's ``FormExtras.kernel_stats``;
+    * ``live_walk(kv_config, cfg=)``, where the model gives one, is its
+      decode form's ``FormExtras.live_walk_pages``;
     * ``routes_all`` names the modes that offer every row's routing."""
     if mode not in modes:
         raise ValueError(f"this decoder builds no {mode!r} form: {modes}")
@@ -718,6 +726,9 @@ def build_form(cfg, mode: str, sampling, kv_dtype: str, *, modes, feeds,
     if mode != "reference":
         extras = extras._replace(kernel_stats=functools.partial(
             walk, mode=mode, cfg=cfg, routed=bool(counts)))
+    if mode == "decode" and live_walk is not None:
+        extras = extras._replace(
+            live_walk_pages=functools.partial(live_walk, cfg=cfg))
     prog._form_extras = extras
     return prog, f["feeds"], [out_name]
 

@@ -589,6 +589,16 @@ def _form_walk(feed, kv_config, *, mode: str, cfg: GQADecoderConfig,
     return out
 
 
+def _live_walk(kv_config, *, cfg: GQADecoderConfig):
+    """``FormExtras.live_walk_pages`` of the decode form: where
+    ``gqa_decode`` runs its kernel (the predicate of :func:`_form_walk`) a
+    full layer's grid is the chunks of each row's walk, whatever the tables
+    span; the window group's table has one width already."""
+    if gqa_kernels.decode_engages(kv_config.page_size, cfg.head_dim):
+        return gqa_kernels.DECODE_TABLE_PAGES
+    return None
+
+
 def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
                       kv_dtype: str = "float32") -> tuple:
     """One program form of the decoder: ``(program, feeds, fetches)``
@@ -624,7 +634,8 @@ def build_gqa_program(cfg: GQADecoderConfig, mode: str, sampling=None,
 
     return build_form(cfg, mode, sampling, kv_dtype,
                       modes=("reference", "prefill", "decode"), feeds=feeds,
-                      rows=rows, walk=_form_walk, routes_all=("prefill",))
+                      rows=rows, walk=_form_walk, live_walk=_live_walk,
+                      routes_all=("prefill",))
 
 
 def _mixer(m, f, mode: str, kv_dtype: str, flat_pos, valid, pools):

@@ -669,6 +669,15 @@ def _hybrid_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig):
     return out
 
 
+def _live_walk(kv_config, *, cfg: MLADecoderConfig):
+    """``FormExtras.live_walk_pages`` of the decode form: where
+    ``mla_decode`` runs its kernel (the predicate of :func:`_decode_walk`)
+    its grid is the chunks that hold context, whatever the tables span."""
+    if mla_kernels.decode_engages(kv_config.page_size, cfg.num_heads):
+        return mla_kernels.DECODE_TABLE_PAGES
+    return None
+
+
 def _form_walk(feed, kv_config, *, mode: str, cfg: MLADecoderConfig,
                routed: bool):
     """``FormExtras.kernel_stats`` of a serving form: what its attention and
@@ -735,7 +744,8 @@ def build_mla_program(cfg: MLADecoderConfig, mode: str, sampling=None,
     prompts = ("reference", "prefill")
     return build_form(
         cfg, mode, sampling, kv_dtype, feeds=feeds, rows=rows,
-        walk=_form_walk, routes_all=prompts if hybrid else (),
+        walk=_form_walk, live_walk=_live_walk,
+        routes_all=prompts if hybrid else (),
         modes=prompts + (("decode",) if hybrid else ("decode", "verify")))
 
 

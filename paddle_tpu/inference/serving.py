@@ -596,6 +596,20 @@ class _EngineCore:
         self.decode_prog, self.decode_feeds, self.decode_fetch = \
             self._build_form("decode", sampling=self.sampling,
                              kv_dtype=kv_dtype)
+        # a decode form whose kernels walk the chunks that hold context
+        # (``FormExtras.live_walk_pages``) is fed ONE block-table width, the
+        # bucket of the longest context the model serves up to the width the
+        # form offers: one program a batch bucket.  Wider contexts, and every
+        # form that offers none (0), take the contexts' own bucket
+        offer = self.decode_prog._form_extras.live_walk_pages
+        widest = offer(self.kv_config) if offer is not None else None
+        self.decode_table_floor = 0 if widest is None else min(
+            int(widest),
+            _pow2_bucket(-(-cfg.max_seq_len // self.kv_config.page_size)))
+        # by phase, the distinct (padded batch, table width) the decode form
+        # has been fed: the programs it costs to build
+        self.decode_feed_shapes: Dict[str, int] = {}
+        self._decode_shapes_seen: Dict[str, set] = {}
         self.mha_fused = 0
         if use_mha_fusion:
             # the serving pass pipeline: the naive composition the
@@ -964,8 +978,11 @@ class _EngineCore:
         """One continuous decode step for ``states`` (each sequence's
         pending token enters the pool, then attends at its true length).
         The caller guarantees page capacity.  Feed shapes bucket to the
-        next power of two in batch AND block-table width, so the jit
-        cache is bounded by (log max_batch x log max_pages) shapes.
+        next power of two in batch and, for a form that pays for every
+        table column (``paged_decode``, the gather-and-mask fallbacks), in
+        block-table width: (log max_batch x log max_pages) shapes.  A form
+        whose kernels walk live chunks only (``decode_table_floor``) is fed
+        one width up to the one it offers: log max_batch shapes.
         ``decode_wall`` keeps the ``engine/decode`` span's stamps for
         the traced requests' decode-step spans.  With the token board open
         the pending tokens are read from their lanes and the new ones
@@ -992,9 +1009,9 @@ class _EngineCore:
                     slot_map[i] = slots[0]
                     ctx[i] = self.kv.context_len(st.req.req_id)
                 self._apply_forks()
-                W = _pow2_bucket(max(
+                W = max(self.decode_table_floor, _pow2_bucket(max(
                     (self.kv.num_pages_of(st.req.req_id) for st in states),
-                    default=1))
+                    default=1)))
                 tables = np.zeros((Bp, W), np.int32)
                 for i, st in enumerate(states):
                     tables[i] = self.kv.block_table(st.req.req_id, W)
@@ -1139,6 +1156,8 @@ class _EngineCore:
         so from the feed (``kernel_stats``): summed by phase into
         ``self.kernel_stats``."""
         offers = prog._form_extras
+        if prog is self.decode_prog:
+            feed = self._decode_feed_as_run(feed, phase)
         if offers.kernel_stats is not None:
             self._note_kernel_stats(
                 phase, offers.kernel_stats(feed, self.kv_config))
@@ -1184,6 +1203,27 @@ class _EngineCore:
         # closed on arrays it did not read
         with RecordEvent("executor/fetch"):
             return [np.asarray(t) for t in out[:len(fetch)]]
+
+    def _decode_feed_as_run(self, feed, phase: str):
+        """The decode form's feed as it is run: a ``block_tables`` narrower
+        than ``decode_table_floor`` widened to it with page 0, the value
+        ``PagedKVCache.block_table`` pads with (``decode_batch`` builds its
+        own at that width; a caller that enumerates widths, a warm-up, runs
+        one program for all of them).  Counts the distinct (padded batch,
+        table width) run, by phase."""
+        tables = feed["block_tables"]
+        short = self.decode_table_floor - tables.shape[1]
+        if short > 0:
+            tables = np.pad(np.asarray(tables), ((0, 0), (0, short)))
+            feed = {**feed, "block_tables": tables}
+        seen = self._decode_shapes_seen.setdefault(phase, set())
+        if tables.shape not in seen:
+            seen.add(tables.shape)
+            self.decode_feed_shapes[phase] = len(seen)
+            tm.counter("decode_feed_shapes", "distinct (padded batch, "
+                       "block-table width) the decode form has been fed",
+                       labels=("phase",)).labels(phase=phase).inc()
+        return feed
 
     def _note_kernel_stats(self, phase: str, stats):
         """``stats``: counts to sum by phase.  Under ``from_counts`` a form
@@ -1485,7 +1525,10 @@ class ServingEngine:
                       "spec_proposed": 0, "spec_accepted": 0,
                       # by phase, what the forms' kernels say of their own
                       # work (the core's dict itself: it fills as calls go)
-                      "kernels": self.core.kernel_stats}
+                      "kernels": self.core.kernel_stats,
+                      # by phase, the distinct (padded batch, table width)
+                      # the decode form was fed (the core's dict, likewise)
+                      "decode_feed_shapes": self.core.decode_feed_shapes}
         self._step_no = 0
         self._submit_seq = 0
         # pipelined steps: step N is dispatched before the tokens of step

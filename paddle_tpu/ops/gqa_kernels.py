@@ -63,6 +63,11 @@ DECODE_PAGES_PER_STEP = 32
 #: pages one group of a chunk's copies moves; groups past the context are
 #: not fetched
 DECODE_PAGES_PER_FETCH = 8
+#: the widest block table, in pages, a serving engine feeds a decode form
+#: of this kernel whatever its contexts hold: as ``mla_kernels.
+#: DECODE_TABLE_PAGES`` (the grid is the work list, a column past a row's
+#: walk is never visited)
+DECODE_TABLE_PAGES = 1024
 
 
 # ==========================================================================

@@ -89,6 +89,14 @@ DECODE_PAGES_PER_STEP = 64
 #: pages one group of a chunk's copies moves (256 tokens): the groups past
 #: the context are not fetched, a loop over groups and not over pages
 DECODE_PAGES_PER_FETCH = 16
+#: the widest block table, in pages, a serving engine feeds a decode form
+#: of this kernel whatever its contexts hold (the widest any cell has run):
+#: the grid is the list of chunks that hold context, so a column past a
+#: row's context is int32 in SMEM (512 KiB at 128 rows) and an entry of the
+#: list that repeats its last, never a step and never a fetch.  One width is
+#: one program a batch bucket where a width a bucket of contexts was three
+#: to six; beyond this many pages the engine buckets by the contexts again
+DECODE_TABLE_PAGES = 1024
 
 
 # ==========================================================================

@@ -63,7 +63,7 @@ def test_form_carries_one_record_that_names_its_vars(name, mode):
     assert isinstance(offers, FormExtras)
     block = prog.global_block()
     for field, value in offers._asdict().items():
-        if field == "kernel_stats":
+        if field in ("kernel_stats", "live_walk_pages"):
             assert value is None or callable(value)
         elif value is not None:
             assert block.has_var(value), (field, value)
@@ -77,6 +77,37 @@ def test_form_carries_one_record_that_names_its_vars(name, mode):
         routed = name != "olmo-hybrid-7b"
         assert bool(offers.counts) == bool(offers.routes) == routed
     assert all(block.has_var(n) for n in list(feeds) + list(fetches))
+    # one table width is offered by the decode form of a description whose
+    # decode kernel walks live chunks, and by no other form
+    assert (offers.live_walk_pages is not None) == (
+        mode == "decode" and name != "gpt2-small")
+
+
+#: name -> the page size of the model's cell
+PAGES = {"joyai-llm-flash": 16, "kimi-linear-48b-a3b": 16, "laguna-xs2": 16,
+         "olmo-hybrid-7b": 16}
+
+
+@pytest.mark.parametrize("name", PAGES)
+def test_decode_form_offers_a_width_where_its_kernel_engages(name,
+                                                             monkeypatch):
+    """``live_walk_pages`` answers by the predicate ``kernel_stats`` uses:
+    the kernels' widest table where the decode kernel runs (here: in the
+    interpreter), None on the CPU without it (the gather fallback pays for
+    every column) and for a page the kernel does not take."""
+    from paddle_tpu.ops import gqa_kernels, mla_kernels
+
+    cfg = MODELS[name][0]()
+    offer = cfg.build_program("decode", kv_dtype="bfloat16")[0] \
+        ._form_extras.live_walk_pages
+    kvc = cfg.kv_cache_config(64, PAGES[name], "bfloat16")
+    assert offer(kvc) is None                         # the CPU: no kernel
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    kernels = mla_kernels if isinstance(cfg, MLADecoderConfig) \
+        else gqa_kernels
+    assert offer(kvc) == kernels.DECODE_TABLE_PAGES == 1024
+    if kernels is mla_kernels:                        # a page of 4 rows
+        assert offer(cfg.kv_cache_config(64, 4, "bfloat16")) is None
 
 
 def imports_of(path):
